@@ -11,8 +11,8 @@ Run:
 
 On CPU smoke-test with:
   JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-      python examples/train_lm.py --mesh dp=2,mp=4 --layers 2 --d-model 128 \
-      --seq 256 --steps 3
+      python examples/train_lm.py --cpu --mesh dp=2,mp=4 --layers 2 \
+      --d-model 128 --seq 256 --steps 3
 """
 import os as _os, sys as _sys
 _sys.path.insert(0, _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))))  # run from anywhere
@@ -44,6 +44,9 @@ def main():
                     help="microbatches per step when the mesh has pp")
     ap.add_argument("--pp-schedule", choices=["gpipe", "interleaved"],
                     default="gpipe")
+    ap.add_argument("--cpu", action="store_true",
+                    help="run on the host CPU (the default place is the "
+                         "TPU, and fails without one)")
     ap.add_argument("--amp", action=argparse.BooleanOptionalAction,
                     default=True, help="bf16 mixed precision (--no-amp off)")
     args = ap.parse_args()
@@ -77,7 +80,8 @@ def main():
         "labels": r.randint(0, args.vocab, (rows, args.seq), np.int64),
     }
 
-    fluid.Executor().run(startup)  # init params in the global scope
+    place = fluid.CPUPlace() if args.cpu else fluid.TPUPlace()
+    fluid.Executor(place).run(startup)  # init params in the global scope
     if args.mesh:
         from paddle_tpu.parallel import (ParallelExecutor, make_mesh,
                                          megatron_transformer_plan,
@@ -115,7 +119,7 @@ def main():
                                 mesh=mesh, **kw)
         run = lambda fetch: pexe.run(feed=feed, fetch_list=fetch)
     else:
-        sexe = fluid.Executor(fluid.TPUPlace())
+        sexe = fluid.Executor(place)
         run = lambda fetch: sexe.run(main_p, feed=feed, fetch_list=fetch)
 
     if args.loop:

@@ -16,7 +16,7 @@ Typical use — identical in shape to fluid:
     loss = fluid.layers.mean(
         fluid.layers.softmax_with_cross_entropy(logits, y))
     fluid.optimizer.Adam(1e-3).minimize(loss)
-    exe = fluid.Executor(fluid.TPUPlace(0))
+    exe = fluid.Executor(fluid.TPUPlace(0))  # CPUPlace() for the host
     exe.run(fluid.default_startup_program())
     exe.run(feed={"x": xs, "y": ys}, fetch_list=[loss])
 """

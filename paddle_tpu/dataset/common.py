@@ -20,8 +20,12 @@ import numpy as np
 
 __all__ = ["DATA_HOME", "data_home", "rng_for", "synthetic_size"]
 
-DATA_HOME = os.path.expanduser(
-    os.environ.get("PADDLE_TPU_DATA_HOME", "~/.cache/paddle_tpu/dataset"))
+# beside the package, never under the home directory: the repo reads and
+# writes nothing around its checkout (.gitignore lists it)
+DATA_HOME = os.path.expanduser(os.environ.get(
+    "PADDLE_TPU_DATA_HOME",
+    os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".dataset_cache")))
 
 
 def data_home(*parts: str) -> str:

@@ -13,7 +13,7 @@ from __future__ import annotations
 from typing import Dict, Optional
 
 __all__ = ["Scope", "CPUPlace", "TPUPlace", "CUDAPlace", "CUDAPinnedPlace",
-           "global_scope", "scope_guard"]
+           "default_place", "current_device", "global_scope", "scope_guard"]
 
 
 class Scope:
@@ -59,7 +59,8 @@ class Scope:
 
 
 class Place:
-    """Base device place. Resolves to a concrete jax.Device."""
+    """Base device place. Resolves to a concrete jax.Device of ITS OWN
+    platform or raises: a place never stands for another device."""
 
     _kind = "cpu"
 
@@ -69,10 +70,17 @@ class Place:
     def jax_device(self):
         import jax
 
-        devs = [d for d in jax.devices() if d.platform == self._kind]
-        if not devs:  # fall back to default backend (e.g. tests force CPU)
-            devs = jax.devices()
-        return devs[self.device_id % len(devs)]
+        try:  # this process's own devices (a multi-host job sees all)
+            devs = jax.local_devices(backend=self._kind)
+        except RuntimeError as e:
+            raise RuntimeError(
+                "%r: no %s device is visible to JAX (default backend %r): %s"
+                % (self, self._kind, jax.default_backend(), e)) from None
+        if self.device_id >= len(devs):
+            raise RuntimeError(
+                "%r: only %d %s device(s) visible"
+                % (self, len(devs), self._kind))
+        return devs[self.device_id]
 
     def __eq__(self, other):
         return type(self) is type(other) and self.device_id == other.device_id
@@ -102,6 +110,31 @@ class CUDAPlace(TPUPlace):
 # feeds stage through the C++ arena instead, so this is plain host memory.
 class CUDAPinnedPlace(CPUPlace):
     pass
+
+
+def default_place() -> Place:
+    """The place of JAX's default device: TPUPlace(0) where a chip is
+    attached, CPUPlace(0) otherwise. What an Executor built with no place
+    runs on."""
+    import jax
+
+    return TPUPlace() if jax.default_backend() == "tpu" else CPUPlace()
+
+
+def current_device():
+    """The device a computation traced NOW will run on: the one an
+    enclosing ``jax.default_device`` names (an Executor runs under its
+    place's), else JAX's default. Kernel dispatch and the AOT cache key
+    ask this, not ``jax.default_backend()``, so a CPUPlace executor on a
+    chip machine gets CPU kernels and CPU cache entries."""
+    import jax
+
+    dev = jax.config.jax_default_device
+    if dev is None:
+        return jax.local_devices()[0]
+    if isinstance(dev, str):
+        return jax.local_devices(backend=dev)[0]
+    return dev
 
 
 _global_scope = Scope()

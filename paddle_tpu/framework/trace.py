@@ -113,11 +113,13 @@ _TRACE_MESH = threading.local()
 
 
 @contextlib.contextmanager
-def mesh_context(mesh):
+def mesh_context(mesh, plan=None):
+    """`plan` (parallel.sharding.ShardingPlan) tells kernels that must
+    shard_map themselves which mesh axes split batch and heads."""
     stack = getattr(_TRACE_MESH, "stack", None)
     if stack is None:
         stack = _TRACE_MESH.stack = []
-    stack.append(mesh)
+    stack.append((mesh, plan))
     try:
         yield
     finally:
@@ -126,7 +128,12 @@ def mesh_context(mesh):
 
 def current_trace_mesh():
     stack = getattr(_TRACE_MESH, "stack", None)
-    return stack[-1] if stack else None
+    return stack[-1][0] if stack else None
+
+
+def current_trace_plan():
+    stack = getattr(_TRACE_MESH, "stack", None)
+    return stack[-1][1] if stack else None
 
 
 class RngStream:

@@ -65,6 +65,7 @@ class Predictor:
         self.model_dir = model_dir
         self._scope = Scope()
         exe = Executor(place, opt_level=0)
+        self._device = exe._device
         if not aot_cache:
             # aot_cache=False promises NO disk persistence — that covers
             # the loader Executor's own compiles (load/startup programs
@@ -96,7 +97,7 @@ class Predictor:
         # PADDLE_TPU_AOT_CACHE=0 kill switch) turns it off.
         self._disk = _aot.AotDiskCache(cache_dir=self._cache_dir,
                                        enabled=aot_cache)
-        _aot.maybe_enable_jax_cache()
+        _aot.enable_compile_cache()
         # the shared compile/execute core (serving.engine.Engine): the
         # SAME feed-plan + AOT-key + load-or-compile code path the
         # training Executor uses — the two can no longer diverge
@@ -136,7 +137,7 @@ class Predictor:
         from .executor import analyze_state
 
         state_in, _ = analyze_state(self._program, set(self._feed_names))
-        dev = jax.devices()[0]
+        dev = self._device
         state = {}
         for n in state_in:
             val = self._scope.find_var(n)
@@ -241,8 +242,7 @@ class Predictor:
 
     def _preload_executables(self):
         """Load cached executables for this (program, backend, jax) at
-        construction (VERDICT r3 weak #4: first-call latency was
-        dominated by lazy AOT deserialization). Signatures come from the
+        construction. Signatures come from the
         shared store's sidecars; keys that don't re-hash to their
         filename belong to another program/backend/jax version and are
         skipped. Construction cost is bounded: only the

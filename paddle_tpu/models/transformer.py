@@ -51,7 +51,7 @@ def multi_head_attention(
     (D, 3D) matmul whose output columns are grouped per head
     [h0:q,k,v | h1:q,k,v | ...], so the Megatron column-parallel split
     over `mp` keeps whole (q,k,v) head groups on each device — tp-safe.
-    Opt-in pending on-hardware measurement (tools/sweep_bench.sh)."""
+    Opt-in pending on-hardware measurement."""
     B, Tq, _ = q_in.shape
     Tk = kv_in.shape[1]
     d_head = d_model // n_head
@@ -278,8 +278,8 @@ def transformer_lm(
     tie_embeddings=True shares the token-embedding table with the vocab
     projection (head logits = x @ emb^T): one less (V, D) parameter, so
     the Adam f32 moment traffic and gradient convert chains on the two
-    largest tensors halve — the profiled ~1.5%-of-step lever
-    (PERF_NOTES). Off by default: the reference benchmark model keeps
+    largest tensors halve — the profiled ~1.5%-of-step lever.
+    Off by default: the reference benchmark model keeps
     the matrices separate (reference
     benchmark/fluid/models/machine_translation.py:1). Under a
     tensor-parallel mesh pass megatron_transformer_plan(tied=True) —
@@ -463,8 +463,7 @@ def transformer_lm_prefill(
     chip's dense-bucket range prefill sharded, then decode continues
     from the same dense (B, S, H, Dh) slabs. On a single device the
     ring op falls back to exact attention, so the graph is portable
-    (and CPU-testable; the multi-chip chunked path needs lax.pvary —
-    jax >= 0.5 — and is gated accordingly in tests)."""
+    (and CPU-testable)."""
     x = _embed(tokens, vocab_size, d_model, max_len, prefix)
     B, S = tokens.shape
     caches = []

@@ -38,7 +38,7 @@ __all__ = [
     "LOADER_BLOCKED_MS", "LOADER_WORKER_BUSY_MS", "LOADER_QUEUE_DEPTH",
     "LOADER_WORKERS", "PREDICT_LATENCY_MS", "PREDICT_REQUESTS",
     "PREDICT_BATCH_ROWS", "PREDICT_FAILURES", "PROFILER_EVENT_MS",
-    "BENCH_ANOMALY_RETRIES", "SERVER_ROWS", "SERVER_BUCKET_FILL",
+    "SERVER_ROWS", "SERVER_BUCKET_FILL",
     "SERVER_INFLIGHT_DEPTH", "SERVER_STAGE_MS", "AOT_CACHE_BYTES",
     "AOT_CACHE_WRITTEN_BYTES", "AOT_CACHE_EVICTIONS", "AOT_CACHE_CORRUPT",
     "AOT_CACHE_ERRORS", "AOT_COMPILE_MS", "ANALYSIS_ISSUES",
@@ -361,9 +361,6 @@ tracing._SPANS_TOTAL = TRACE_SPANS
 PROFILER_EVENT_MS = REGISTRY.summary(
     "paddle_tpu_profiler_event_ms",
     "Legacy profiler event table (exact count/sum/min/max per event)")
-BENCH_ANOMALY_RETRIES = REGISTRY.counter(
-    "paddle_tpu_bench_anomaly_retry_total",
-    "bench.py transient-contention re-measurements, by phase")
 
 
 # -- helpers -------------------------------------------------------------

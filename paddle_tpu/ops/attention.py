@@ -33,13 +33,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:  # pallas import kept optional: CPU-only environments still work
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pl = None
-    pltpu = None
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
+from ..framework.scope import current_device
+from ..framework.trace import current_trace_mesh, current_trace_plan
 from .registry import register_op
 
 _NEG = -1e30
@@ -142,20 +141,10 @@ def _tpu_params(*dimension_semantics):
     """compiler_params kwargs marking grid axes "parallel" (Mosaic may
     split them across megacore on v4/v5p) or "arbitrary" (sequential —
     REQUIRED for axes whose output blocks are revisited/accumulated:
-    the lse row in the fwd kernel, dk/dv in the fused backward). No-op
-    when the TPU pallas backend is unavailable (interpret-mode tests).
-    """
-    if pltpu is None:
-        return {}
+    the lse row in the fwd kernel, dk/dv in the fused backward)."""
     if os.environ.get("PADDLE_TPU_DIM_SEMANTICS", "1") == "0":
         return {}  # kill-switch: restores the pre-semantics kernels
-    # CompilerParams was TPUCompilerParams before jax 0.6.1; degrade to
-    # no semantics (not an error) on jax versions with neither
-    cp = getattr(pltpu, "CompilerParams",
-                 getattr(pltpu, "TPUCompilerParams", None))
-    if cp is None:
-        return {}
-    return {"compiler_params": cp(
+    return {"compiler_params": pltpu.CompilerParams(
         dimension_semantics=tuple(dimension_semantics))}
 
 
@@ -345,12 +334,9 @@ _FUSED_BWD_VMEM_BUDGET = 12 * 1024 * 1024
 
 
 def _fused_bwd_fits(tk: int, d: int, kv_itemsize: int) -> bool:
-    """True when the single-pass backward's whole-row VMEM residents fit;
-    callers fall back to the split dq+dkv kernels (whose k/v or q/do
-    rows are half the footprint and have no f32 row accumulators).
-    Pure predicate — bench.py also calls it to label its config record
-    honestly; the dispatch sites warn when it overrides an explicit
-    PADDLE_TPU_FLASH_FUSED_BWD=1 (see _fused_bwd_dispatchable)."""
+    """True when the single-pass backward's whole-row VMEM residents
+    fit. Pure predicate — bench.py also calls it to label its config
+    record."""
     kv_rows = 2 * tk * d * kv_itemsize * 2  # k+v, double-buffered
     acc_rows = 2 * tk * d * 4               # dk+dv f32 accumulators
     # strict <: a footprint exactly AT the budget (f32 rows, T=4096) has
@@ -359,21 +345,18 @@ def _fused_bwd_fits(tk: int, d: int, kv_itemsize: int) -> bool:
 
 
 def _fused_bwd_dispatchable(tk: int, d: int, kv_itemsize: int) -> bool:
-    """Dispatch-site gate: fused requested AND its VMEM residents fit.
-    Warns (once per trace) when the budget overrides the explicit
-    opt-in, so a sweep log shows its 'fused' row ran the split kernels."""
+    """Dispatch-site gate: the fused backward was asked for. Where its
+    VMEM residents do not fit, that is an error naming the shape — a
+    run labelled 'fused' never measures the split kernels."""
     if not _fused_bwd_enabled():
         return False
-    if _fused_bwd_fits(tk, d, kv_itemsize):
-        return True
-    import warnings
-
-    warnings.warn(
-        "PADDLE_TPU_FLASH_FUSED_BWD=1 but the fused backward's VMEM "
-        "residents exceed the %.0f MB budget at seq_k=%d, d_head=%d; "
-        "dispatching the split dq+dkv backward instead"
-        % (_FUSED_BWD_VMEM_BUDGET / 2**20, tk, d))
-    return False
+    if not _fused_bwd_fits(tk, d, kv_itemsize):
+        raise ValueError(
+            "PADDLE_TPU_FLASH_FUSED_BWD=1 but the fused backward's VMEM "
+            "residents exceed the %.0f MB budget at seq_k=%d, d_head=%d, "
+            "itemsize=%d; unset it to use the split dq+dkv backward"
+            % (_FUSED_BWD_VMEM_BUDGET / 2**20, tk, d, kv_itemsize))
+    return True
 
 
 def _mha_fwd_call(qs, k, v, causal, block_q, block_k, interpret):
@@ -760,11 +743,11 @@ def _fused_attention(ctx):
     if layout == "bthd":
         t, tk, d_head = q.shape[1], k.shape[1], q.shape[-1]
         if d_head % 128 == 0 and _use_pallas(t, tk, lengths, dropout_rate):
-            bq = _env_block("PADDLE_TPU_FLASH_BQ", 512)
-            bk = _env_block("PADDLE_TPU_FLASH_BK", block_k)
-            return {"Out": pallas_flash_attention_bthd(
-                q, k, v, causal=causal, scale=scale, block_q=bq,
-                block_k=bk)}
+            kern = functools.partial(
+                pallas_flash_attention_bthd, causal=causal, scale=scale,
+                block_q=_env_block("PADDLE_TPU_FLASH_BQ", 512),
+                block_k=_env_block("PADDLE_TPU_FLASH_BK", block_k))
+            return {"Out": _per_shard(kern, q, k, v, head_dim=2)}
         out = _attention_bhtd(
             jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
             jnp.swapaxes(v, 1, 2), lengths, causal, scale, dropout_rate,
@@ -778,16 +761,51 @@ def _fused_attention(ctx):
 def _attention_bhtd(q, k, v, lengths, causal, scale, dropout_rate, block_k,
                     rng):
     """The (B, H, T, Dh) dispatch: Pallas fwd+bwd kernels when eligible,
-    XLA flash fallback (CPU tests, dropout, KV padding masks) otherwise."""
+    XLA flash path (CPU, dropout, KV padding masks) otherwise."""
     if _use_pallas(q.shape[2], k.shape[2], lengths, dropout_rate):
         # block sizes: env overrides (on-hardware sweeps) > op attr > 512
-        bq = _env_block("PADDLE_TPU_FLASH_BQ", 512)
-        bk = _env_block("PADDLE_TPU_FLASH_BK", block_k)
-        return pallas_flash_attention(q, k, v, causal=causal, scale=scale,
-                                      block_q=bq, block_k=bk)
+        kern = functools.partial(
+            pallas_flash_attention, causal=causal, scale=scale,
+            block_q=_env_block("PADDLE_TPU_FLASH_BQ", 512),
+            block_k=_env_block("PADDLE_TPU_FLASH_BK", block_k))
+        return _per_shard(kern, q, k, v, head_dim=1)
     return flash_attention(
         q, k, v, causal=causal, scale=scale, lengths=lengths,
         dropout_rate=dropout_rate, rng_key=rng, block_k=block_k)
+
+
+def _per_shard(kern, q, k, v, head_dim):
+    """Run a Pallas attention kernel where the step is being traced:
+    bare on one device; under a multi-device trace mesh
+    (framework.trace.mesh_context) inside a shard_map — Mosaic kernels
+    cannot be partitioned by GSPMD. Batch splits over the plan's batch
+    axes and heads over its tensor axis; attention is independent per
+    (batch, head), so no collective runs inside. Mesh axes that split
+    neither dim compute replicated. A dim the axes do not divide is an
+    error naming the shape, never a quiet switch to another path."""
+    mesh = current_trace_mesh()
+    if mesh is None or mesh.size == 1:
+        return kern(q, k, v)
+    plan = current_trace_plan()
+    batch_axes = tuple(a for a in getattr(plan, "batch_axes", ())
+                       if mesh.shape[a] > 1)
+    head_axis = getattr(plan, "tensor_axis", None)
+    if head_axis is not None and mesh.shape[head_axis] == 1:
+        head_axis = None
+    b_ways = math.prod(mesh.shape[a] for a in batch_axes)
+    h_ways = mesh.shape[head_axis] if head_axis else 1
+    if q.shape[0] % b_ways or q.shape[head_dim] % h_ways:
+        raise ValueError(
+            "fused_attention: q %s (head dim %d) does not divide over "
+            "mesh %s with batch axes %s and tensor axis %r"
+            % (q.shape, head_dim, dict(mesh.shape), batch_axes, head_axis))
+    dims = [None] * 4
+    dims[0] = batch_axes or None
+    dims[head_dim] = head_axis
+    spec = P(*dims)
+    # check_vma off: pallas_call outputs carry no varying-axes type
+    return jax.shard_map(kern, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 def _env_block(var: str, default: int) -> int:
@@ -809,22 +827,18 @@ def _env_block(var: str, default: int) -> int:
 
 
 def _use_pallas(t, tk, lengths, dropout_rate) -> bool:
-    """Pallas fwd+bwd path: TPU only, no KV padding mask, no dropout, and
-    block-aligned sequence lengths (256 keeps small models on XLA).
-    PADDLE_TPU_FORCE_PALLAS=1 skips only the backend check — for tracing
-    a TPU-bound program on a CPU host (offline Mosaic-lowering
-    validation via jax.export; tools/lower_bench_step.py is the
-    consumer). Executing such a trace on CPU fails — this is a
-    lowering/debug lever, not a CPU execution mode."""
-    if pl is None or lengths is not None or dropout_rate:
+    """Pallas fwd+bwd path: a step bound for a TPU, no KV padding mask,
+    no dropout, and block-aligned sequence lengths (256 keeps small
+    models on XLA). PADDLE_TPU_FORCE_PALLAS=1 skips only the device
+    check — for compiling a TPU-bound program on a host without a chip
+    (tests/test_tpu_compile.py). Executing such a trace on CPU fails —
+    this is a compile/debug lever, not a CPU execution mode."""
+    if lengths is not None or dropout_rate:
         return False
     if os.environ.get("PADDLE_TPU_NO_PALLAS", "0") == "1":
         return False
     force = os.environ.get("PADDLE_TPU_FORCE_PALLAS", "0") == "1"
-    try:
-        if not force and jax.default_backend() in ("cpu", "gpu"):
-            return False
-    except Exception:  # pragma: no cover
+    if not force and current_device().platform != "tpu":
         return False
     # 128 matches _fit_block's floor so the dispatch gate and the kernel
     # entry can never disagree; tiny sequences stay on the XLA path
@@ -844,7 +858,6 @@ def _ring_attention_op(ctx):
     are position-stable (keyed on global coordinates), so the two
     dispatches stay numerically identical — the same Program produces
     the same losses on one chip and on an sp mesh."""
-    from ..framework.trace import current_trace_mesh
     from ..parallel.ring_attention import full_attention, ring_self_attention
 
     q, k, v = ctx.input("Q"), ctx.input("K"), ctx.input("V")
@@ -888,7 +901,6 @@ def _moe_ffn_op(ctx):
     mesh context) experts shard across devices with all_to_all dispatch
     (parallel/moe.py); otherwise the identical-math single-device path
     runs, so one Program serves both worlds."""
-    from ..framework.trace import current_trace_mesh
     from ..parallel.moe import MoEParams, expert_parallel_ffn, moe_ffn_local
 
     params = MoEParams(

@@ -32,7 +32,7 @@ _NEG = -1e30
 
 
 def _unroll_chunks(nblk: int) -> bool:
-    """Sweep lever (tools/sweep_bench.sh): PADDLE_TPU_LMHEAD_UNROLL=N
+    """Sweep lever: PADDLE_TPU_LMHEAD_UNROLL=N
     unrolls the vocab-chunk loop when nblk <= N. Off by default — the
     rolled loop compiles faster and the win is hardware-dependent."""
     import os
@@ -239,7 +239,7 @@ def _fused_lm_head_loss(ctx):
     w = ctx.input("W")
     labels = ctx.input("Label")
     transpose_w = bool(ctx.attr("transpose_w", False))
-    # env override for on-hardware sweeps (tools/sweep_bench.sh),
+    # env override for on-hardware sweeps,
     # validated like the flash-attention block knobs
     block_v = _env_block("PADDLE_TPU_LMHEAD_BLOCK",
                          ctx.attr("block_v", 4096))

@@ -22,7 +22,7 @@ Three ops:
   Lengths (B,) -> (B, 1, H, Dh). A Pallas TPU kernel (one grid cell per
   (batch, head); online softmax over KV blocks in VMEM, the
   single-query sibling of ops/attention.py's ``_mha_fwd_kernel``) with
-  a pure-``lax`` fallback for CPU/GPU and non-aligned shapes; the
+  the exact pure-``lax`` path on CPU/GPU and non-aligned shapes; the
   kernel also runs under ``interpret=True`` so parity is testable off
   TPU.
 - ``cache_append``: scatter one new K or V row per sequence at its
@@ -41,12 +41,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:  # pallas import kept optional: CPU-only environments still work
-    from jax.experimental import pallas as pl
-except ImportError:  # pragma: no cover
-    pl = None
+from jax.experimental import pallas as pl
 
-from .attention import _tpu_params
+from ..framework.scope import current_device
+from .attention import _fit_block, _tpu_params
 from .registry import register_op
 
 _NEG = -1e30
@@ -133,8 +131,6 @@ def pallas_decode_attention(q, k_cache, v_cache, lengths, scale=None,
     s = k_cache.shape[1]
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    from .attention import _fit_block
-
     block_s = _fit_block(s, block_s)
     if s % block_s:
         raise ValueError("slab length %d must divide block_s %d"
@@ -166,16 +162,12 @@ def pallas_decode_attention(q, k_cache, v_cache, lengths, scale=None,
 
 
 def _use_pallas_decode(s: int, d: int) -> bool:
-    """TPU only, lane-aligned head dim, block-aligned slab (mirrors
-    ops/attention.py:_use_pallas; PADDLE_TPU_NO_PALLAS opts out)."""
-    if pl is None:
-        return False
+    """A step bound for a TPU, lane-aligned head dim, block-aligned slab
+    (mirrors ops/attention.py:_use_pallas; PADDLE_TPU_NO_PALLAS opts
+    out)."""
     if os.environ.get("PADDLE_TPU_NO_PALLAS", "0") == "1":
         return False
-    try:
-        if jax.default_backend() in ("cpu", "gpu"):
-            return False
-    except Exception:  # pragma: no cover
+    if current_device().platform != "tpu":
         return False
     return d % 128 == 0 and s % 128 == 0 and s >= 128
 

@@ -237,7 +237,7 @@ def _mm2d_dwt(x2, y2):
     """Same forward as _mm2d; the backward computes dY in TRANSPOSED form
     (dY^T = g^T @ X, then a weight-sized transpose) instead of X^T @ g.
     Sweep lever PADDLE_TPU_MUL_DWT=1: the profiled FFN-hidden relayout
-    copies (~4.7% of LM step time, PERF_NOTES) are XLA's layout
+    copies (~4.7% of LM step time) are XLA's layout
     assignment materializing a column-major view of the (B, T, d_inner)
     activation for exactly the X^T @ g contraction; flipping the operand
     order moves any relayout to the 4x-smaller gradient tensor, at the

@@ -1,56 +1,33 @@
-"""Version-compat shims shared by the parallel modules."""
+"""shard_map helpers shared by the parallel modules."""
 from __future__ import annotations
 
 import jax
+from jax import lax
 
-try:  # jax>=0.6 top level; older: experimental
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+shard_map = jax.shard_map
 
 __all__ = ["shard_map", "shard_map_partial", "pvary"]
 
 
 def pvary(x, axes):
-    """Mark x varying over manual mesh axes (shard_map vma typing);
-    lax.pvary is deprecated in favor of lax.pcast(..., to='varying') on
-    newer jax. `axes`: one axis name or a tuple. IDEMPOTENT: axes x
-    already varies over are skipped (pcast rejects varying->varying,
-    and callers often promote loop carries that are invariant only on
-    the first ring/pipeline step)."""
-    from jax import lax
-
+    """Mark x varying over manual mesh axes (shard_map vma typing).
+    `axes`: one axis name or a tuple. IDEMPOTENT: axes x already varies
+    over are skipped (pcast rejects varying->varying, and callers often
+    promote loop carries that are invariant only on the first
+    ring/pipeline step)."""
     if not isinstance(axes, tuple):
         axes = (axes,)
-    typeof = getattr(jax, "typeof", None)
-    if typeof is not None:
-        try:
-            have = set(getattr(typeof(x), "vma", ()) or ())
-        except Exception:
-            have = set()
-        axes = tuple(a for a in axes if a not in have)
+    have = jax.typeof(x).vma
+    axes = tuple(a for a in axes if a not in have)
     if not axes:
         return x
-    pcast = getattr(lax, "pcast", None)
-    if pcast is not None:
-        return pcast(x, axes, to="varying")
-    return lax.pvary(x, axes)
+    return lax.pcast(x, axes, to="varying")
 
 
 def shard_map_partial(f, mesh, in_specs, out_specs, manual_axes):
     """shard_map manual over `manual_axes` only; any other mesh axes stay
     automatic (GSPMD partitions over them inside the manual region —
     e.g. the pipeline tick loop is manual over (dp, pp) while tensor
-    parallelism rides an auto mp axis). Newer jax spells this
-    ``axis_names=...``; older jax ``auto=<complement>``."""
-    manual = frozenset(manual_axes)
-    try:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, axis_names=set(manual))
-    except TypeError:  # pragma: no cover — older jax
-        auto = frozenset(mesh.axis_names) - manual
-        if not auto:
-            return shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, auto=auto)
+    parallelism rides an auto mp axis)."""
+    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                     axis_names=set(manual_axes))

@@ -147,13 +147,12 @@ def default_mesh(axis_name: str = "dp") -> Mesh:
 def get_places(device_count_: Optional[int] = None):
     """Parity with fluid.layers.device.get_places (reference:
     python/paddle/fluid/layers/device.py): enumerate execution places.
-    Returns TPUPlace list on accelerator backends, CPUPlace otherwise."""
-    from ..framework.scope import CPUPlace, TPUPlace
+    Returns TPUPlace list where chips are attached, CPUPlace otherwise."""
+    from ..framework.scope import default_place
 
     devs = jax.devices()
     n = len(devs) if device_count_ is None else min(device_count_, len(devs))
-    cls = CPUPlace if devs[0].platform == "cpu" else TPUPlace
-    return [cls(i) for i in range(n)]
+    return [type(default_place())(i) for i in range(n)]
 
 
 def init_distributed(

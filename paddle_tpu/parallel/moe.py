@@ -183,11 +183,7 @@ def expert_parallel_ffn(x, params: MoEParams, mesh: Mesh, axis: str = "ep",
     # the replication/VMA check is disabled: with replicated tokens
     # (batch_dim_sharded=False) the output is mathematically replicated
     # over `axis` but the checker cannot prove it through the all_to_all
-    # pair. jax<0.6 spells the kwarg check_rep.
-    kwargs = dict(mesh=mesh, in_specs=(xspec, tuple(pspec)),
-                  out_specs=xspec)
-    try:
-        fn = shard_map(device_fn, check_vma=False, **kwargs)
-    except TypeError:  # pragma: no cover - older jax
-        fn = shard_map(device_fn, check_rep=False, **kwargs)
+    # pair.
+    fn = shard_map(device_fn, check_vma=False, mesh=mesh,
+                   in_specs=(xspec, tuple(pspec)), out_specs=xspec)
     return fn(x, tuple(params))

@@ -225,7 +225,7 @@ class ParallelExecutor:
             state_aval[n] = jax.ShapeDtypeStruct(tuple(arr.shape), arr.dtype)
         key_aval = jax.eval_shape(lambda: jax.random.PRNGKey(0))
         step_aval = jax.ShapeDtypeStruct((), np.uint32)
-        with trace_mod.mesh_context(self._mesh):
+        with trace_mod.mesh_context(self._mesh, self._plan):
             _, out_state_aval = jax.eval_shape(stepfn, feeds_aval, state_aval,
                                                key_aval, step_aval)
 
@@ -372,7 +372,7 @@ class ParallelExecutor:
         # jit traces lazily inside the first call: distributed-capable
         # kernels (ring_attention) read the mesh from this context
         t0 = time.perf_counter()
-        with trace_mod.mesh_context(self._mesh):
+        with trace_mod.mesh_context(self._mesh, self._plan):
             if loop:
                 fetches, new_state = compiled.fn(feeds, state,
                                                  self._base_keys[seed], step,

@@ -45,6 +45,9 @@ class ShardingPlan:
         self.batch_axes = tuple(a for a in batch_axes if a in mesh.axis_names)
         # sequence-parallel plans shard feed dim 1 (time) over this axis
         self.seq_axis: Optional[str] = None
+        # tensor-parallel plans split attention heads over this axis
+        # (read by kernels that shard_map themselves, ops/attention.py)
+        self.tensor_axis: Optional[str] = None
         self._exact: Dict[str, P] = {}
         self._regex: list = []
 
@@ -167,6 +170,8 @@ def megatron_transformer_plan(
     state where that plan composes.
     """
     plan = ShardingPlan(mesh, batch_axes=batch_axes)
+    if mp_axis in mesh.axis_names:
+        plan.tensor_axis = mp_axis
     col_w = P(None, mp_axis)  # (in, out) split on out
     row_w = P(mp_axis, None)  # (in, out) split on in
     col_b = P(mp_axis)

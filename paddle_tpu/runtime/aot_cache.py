@@ -37,15 +37,26 @@ names, env fingerprint, creation time) that lets `Predictor` preload
 executables without knowing their feed signatures up front and lets
 `tools/aot_cache_ls.py` inspect entries without jax.
 
+Where the caches live — ONE rule (`compile_cache_dir`), for this tier
+and for jax's own persistent compilation cache (the second tier: XLA
+output keyed on HLO, so even a *changed* program whose subcomputations
+match compiles faster):
+
+- ``JAX_COMPILATION_CACHE_DIR`` set: jax reads it itself (this package
+  then never touches ``jax_compilation_cache_dir``), and the AOT tier is
+  the fixed-name subdirectory ``paddle_tpu_aot`` of it;
+- unset: both live under ``<checkout>/.xla_cache``, resolved from this
+  package's own path — the same directory in every process, because the
+  path is part of jax's cache key and a directory that moves never hits.
+
+`Predictor` / `DecodePredictor` keep their per-model
+``<model_dir>/__aot_cache__`` for the AOT tier.
+
 Env knobs:
 - ``PADDLE_TPU_AOT_CACHE=0``        — kill switch (memory-only compiles)
-- ``PADDLE_TPU_AOT_CACHE_DIR``      — training-side cache directory
-  (default ``$XDG_CACHE_HOME/paddle_tpu/aot`` or ``~/.cache/...``);
-  `Predictor` keeps its per-model ``<model_dir>/__aot_cache__``
+- ``PADDLE_TPU_AOT_CACHE_DIR``      — AOT-tier override; exists for
+  `tests/conftest.py` to isolate the suite from the checkout's cache
 - ``PADDLE_TPU_AOT_CACHE_MAX_BYTES``— GC bound (default 1 GiB, 0 = off)
-- ``PADDLE_TPU_JAX_CACHE_DIR``      — opt-in SECOND tier: jax's own
-  persistent compilation cache (caches XLA output keyed on HLO, so even a
-  *changed* program whose subcomputations match still compiles faster)
 """
 from __future__ import annotations
 
@@ -59,10 +70,11 @@ from typing import Any, Dict, List, Optional, Tuple
 from .. import observability as obs
 
 __all__ = [
-    "AotDiskCache", "default_cache_dir", "enabled_by_env",
+    "AotDiskCache", "compile_cache_dir", "default_cache_dir",
+    "enable_compile_cache", "enabled_by_env",
     "max_bytes_from_env", "env_fingerprint", "trace_env_fingerprint",
     "serialize_executable", "deserialize_executable",
-    "maybe_enable_jax_cache", "FORMAT_VERSION", "BLOB_SUFFIX",
+    "FORMAT_VERSION", "BLOB_SUFFIX",
     "META_SUFFIX", "QUARANTINE_SUFFIX", "DEFAULT_MAX_BYTES",
 ]
 
@@ -93,13 +105,40 @@ _TRACE_ENV = (
 )
 
 
+_CHECKOUT_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".xla_cache")
+AOT_SUBDIR = "paddle_tpu_aot"
+
+
+def compile_cache_dir() -> str:
+    """The directory both compile-cache tiers live under (module doc)."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or _CHECKOUT_CACHE
+
+
 def default_cache_dir() -> str:
-    d = os.environ.get("PADDLE_TPU_AOT_CACHE_DIR")
-    if d:
-        return os.path.expanduser(d)
-    base = os.environ.get("XDG_CACHE_HOME") or os.path.join(
-        os.path.expanduser("~"), ".cache")
-    return os.path.join(base, "paddle_tpu", "aot")
+    """The AOT tier's directory."""
+    return (os.environ.get("PADDLE_TPU_AOT_CACHE_DIR")
+            or os.path.join(compile_cache_dir(), AOT_SUBDIR))
+
+
+def enable_compile_cache() -> str:
+    """Point jax's persistent compilation cache at `compile_cache_dir()`
+    and return that directory. With ``JAX_COMPILATION_CACHE_DIR`` set
+    jax has already read it and nothing is updated here; thresholds stay
+    jax's own (``JAX_PERSISTENT_CACHE_*``) either way. On a CPU-only
+    process the jax tier stays off unless the variable asks for it:
+    jaxlib 0.9.0 fails to dispatch some XLA:CPU executables reloaded
+    from that cache ("Function ... not found"), and a CPU run is a
+    rehearsal whose compiles are cheap; the AOT tier covers it."""
+    d = compile_cache_dir()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        import jax
+
+        if (jax.default_backend() != "cpu"
+                and jax.config.jax_compilation_cache_dir != d):
+            jax.config.update("jax_compilation_cache_dir", d)
+    return d
 
 
 def enabled_by_env() -> bool:
@@ -134,17 +173,15 @@ def env_fingerprint() -> Tuple:
     import jax
     import jaxlib
 
-    try:
-        dev = jax.devices()[0]
-        device_kind = getattr(dev, "device_kind", "?")
-    except Exception:  # backend init failure: still produce a stable key
-        device_kind = "?"
+    from ..framework.scope import current_device
+
+    dev = current_device()
     return (
         "fmt%d" % FORMAT_VERSION,
         jax.__version__,
         jaxlib.__version__,
-        jax.default_backend(),
-        device_kind,
+        dev.platform,
+        dev.device_kind,
         bool(jax.config.jax_enable_x64),
         os.environ.get("XLA_FLAGS", ""),
         trace_env_fingerprint(),
@@ -162,51 +199,16 @@ def serialize_executable(compiled) -> bytes:
 def deserialize_executable(payload: bytes):
     """bytes -> jax Compiled (raises on any corruption — callers go
     through AotDiskCache.load, which quarantines)."""
-    import jax
     from jax.experimental import serialize_executable as se
 
+    from ..framework.scope import current_device
+
     blob, in_tree, out_tree = pickle.loads(payload)
-    try:
-        # pin execution to one device: the executable was compiled
-        # single-device, and the default (all local devices) breaks under
-        # a multi-device runtime (e.g. the 8-virtual-CPU test mesh)
-        return se.deserialize_and_load(
-            blob, in_tree, out_tree, execution_devices=jax.devices()[:1])
-    except TypeError:
-        # jax without the execution_devices kwarg: the serialized
-        # executable carries its own single-device assignment, so the
-        # unpinned load is equivalent there
-        return se.deserialize_and_load(blob, in_tree, out_tree)
-
-
-_JAX_CACHE_APPLIED = False
-
-
-def maybe_enable_jax_cache():
-    """Opt-in second tier: jax's persistent compilation cache, keyed on
-    HLO rather than our Program-level key — it helps even when OUR key
-    misses (e.g. a program edit that leaves most subcomputations
-    intact). Enabled once per process when PADDLE_TPU_JAX_CACHE_DIR is
-    set; thresholds drop to 0 so small test-sized programs cache too."""
-    global _JAX_CACHE_APPLIED
-    if _JAX_CACHE_APPLIED:
-        return
-    d = os.environ.get("PADDLE_TPU_JAX_CACHE_DIR")
-    if not d:
-        return
-    _JAX_CACHE_APPLIED = True  # one attempt per process, success or not
-    try:
-        import jax
-
-        jax.config.update("jax_compilation_cache_dir", os.path.expanduser(d))
-        for knob, val in (("jax_persistent_cache_min_compile_time_secs", 0),
-                          ("jax_persistent_cache_min_entry_size_bytes", 0)):
-            try:
-                jax.config.update(knob, val)
-            except Exception:
-                pass  # knob renamed/absent on this jax: dir alone suffices
-    except Exception as e:
-        warnings.warn("PADDLE_TPU_JAX_CACHE_DIR could not be applied: %s" % e)
+    # pin execution to the one device the executable was compiled for
+    # (the same `current_device` its key was derived from): the default
+    # (all local devices) breaks under a multi-device runtime
+    return se.deserialize_and_load(
+        blob, in_tree, out_tree, execution_devices=[current_device()])
 
 
 class AotDiskCache:

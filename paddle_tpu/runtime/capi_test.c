@@ -13,8 +13,7 @@
  * Exit 0 iff the model loads, runs, and fetch 0 matches the expected
  * buffer elementwise within rtol. With bench_iters > 0, additionally
  * times cold start (dlopen + predictor_load), the first run, and
- * bench_iters steady-state runs, printing one BENCH line (VERDICT r3
- * weak #4: the serving path's characteristics, measured not asserted).
+ * bench_iters steady-state runs, printing one BENCH line.
  */
 #include <dlfcn.h>
 #include <math.h>

@@ -30,7 +30,7 @@
  * behind paddle_tpu.inference.PredictorServer, whose dynamic batching
  * coalesces concurrent single-row requests into padded fixed-signature
  * batches (measured: >25k rows/s vs ~13k calls/s through parallel ptrt
- * calls on the same MLP; PERF_NOTES.md).
+ * calls on the same MLP).
  */
 #ifndef PTRT_CAPI_H
 #define PTRT_CAPI_H

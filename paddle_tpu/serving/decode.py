@@ -54,6 +54,7 @@ import jax.numpy as jnp
 from .. import observability as obs
 from ..observability import tracing as _tracing
 from ..runtime import aot_cache as _aot
+from ..framework.scope import current_device
 from ..runtime import recordio as _rio
 
 __all__ = ["DecodeConfig", "save_decode_model", "DecodePredictor",
@@ -238,9 +239,9 @@ class DecodePredictor:
         self._disk = _aot.AotDiskCache(
             cache_dir=cache_dir or os.path.join(model_dir, _AOT_DIR),
             enabled=aot_cache)
-        _aot.maybe_enable_jax_cache()
+        _aot.enable_compile_cache()
         state_in, _ = analyze_state(self._program, set(self._feed_names))
-        dev = jax.devices()[0]
+        dev = self._device = exe._device
         self._state = {}
         for n in state_in:
             val = self._scope.find_var(n)
@@ -412,6 +413,12 @@ class DecodePredictor:
     def acquire(self, kind: str, batch: int, seq: int,
                 strategy: Optional[str] = None,
                 kv_dtype: str = "float32", window: int = 0):
+        # keyed, traced and compiled for the predictor's own device
+        with jax.default_device(self._device):
+            return self._acquire(kind, batch, seq, strategy, kv_dtype,
+                                 window)
+
+    def _acquire(self, kind, batch, seq, strategy, kv_dtype, window):
         """Executable for one (kind, batch, seq, strategy, kv_dtype,
         window) signature: memory hit, else the shared Engine's
         disk-load-or-compile path. Returns (executable, fetch_names).
@@ -471,13 +478,8 @@ class DecodePredictor:
             # verify executable after drafting — donation would consume
             # them (the draft's appended rows are hypotheses; its
             # returned slabs are discarded each round)
-            donate = ()
-            try:
-                if kind != "draft" \
-                        and jax.default_backend() not in ("cpu",):
-                    donate = (0,)
-            except Exception:  # pragma: no cover
-                pass
+            donate = ((0,) if kind != "draft"
+                      and current_device().platform != "cpu" else ())
             fn = jax.jit(step_fn, donate_argnums=donate)
             state_structs = {n: jax.ShapeDtypeStruct(a.shape, a.dtype)
                              for n, a in self._state.items()}

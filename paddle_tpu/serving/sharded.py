@@ -147,7 +147,7 @@ class ShardedPredictor:
         fn = jax.jit(self._step_fn(), in_shardings=in_shardings,
                      out_shardings=out_shardings)
         t0 = time.perf_counter()
-        with trace_mod.mesh_context(self.mesh):
+        with trace_mod.mesh_context(self.mesh, self._plan):
             lowered = fn.lower(
                 {n: jax.ShapeDtypeStruct(s, np.dtype(d))
                  for n, s, d in feed_sig},

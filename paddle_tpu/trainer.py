@@ -21,7 +21,7 @@ from .data_feeder import DataFeeder
 from .executor import Executor
 from .framework import core as framework
 from .framework.core import Program, program_guard
-from .framework.scope import CPUPlace, Scope, TPUPlace, scope_guard
+from .framework.scope import Scope, default_place, scope_guard
 from .framework import unique_name
 
 __all__ = [
@@ -77,11 +77,7 @@ class CheckpointConfig(object):
 def check_and_get_place(place):
     """Default to the TPU when one is visible (reference
     check_and_get_place prefers CUDA)."""
-    if place is not None:
-        return place
-    import jax
-
-    return CPUPlace() if jax.devices()[0].platform == "cpu" else TPUPlace()
+    return place if place is not None else default_place()
 
 
 def build_feed_var_list(program: Program, feed_order):

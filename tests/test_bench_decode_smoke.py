@@ -2,7 +2,7 @@
 seconds and every emitted JSON line matches the schema downstream sweep
 tooling parses — the decode bench cannot silently rot between device
 windows. This pins the CONTRACT, not the numbers (the speedup
-acceptance lives in PERF_NOTES, measured at the real config). The
+is a chip measurement at the real config, not made here). The
 in-window test covers the base phases over a two-rung DECODE_STEPS
 ladder; the PR-14 arms (--speculative --prefix-share) run in a
 slow-marked sibling (tier-1 budget triage — the arms compile extra

@@ -293,7 +293,7 @@ def _ref_pool3d_with_index(x, k, s, p):
 
 
 def test_max_pool3d_with_index():
-    """VERDICT r4 item 4: the 3-D sibling of max_pool2d_with_index
+    """the 3-D sibling of max_pool2d_with_index
     (reference pool_with_index_op.cc:276), incl. a padded config where
     the argmax must never land in the padding."""
     x = R.randn(2, 2, 4, 4, 4).astype(np.float32)

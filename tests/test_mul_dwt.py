@@ -1,7 +1,7 @@
 """PADDLE_TPU_MUL_DWT (sweep lever): transposed-form dW backward for the
 `mul` op is a pure schedule change — same forward, same gradients
 (kernel: paddle_tpu/ops/math.py _mm2d_dwt; motivation: the FFN-hidden
-relayout copies named in PERF_NOTES)."""
+relayout copies on the LM step's profile)."""
 import numpy as np
 
 import jax

@@ -137,7 +137,7 @@ def test_conv2d_transpose():
 
 
 def test_depthwise_conv2d_transpose():
-    """VERDICT r4 item 4 (reference conv_transpose_op.cc:338): each input
+    """Reference conv_transpose_op.cc:338: each input
     channel deconvolves independently — groups == C_in, paddle filter
     layout (C, 1, kh, kw) — so the per-channel numpy transpose-conv is
     the reference."""

@@ -455,14 +455,6 @@ def test_ring_attention_dropout_mask_statistics():
     np.testing.assert_allclose(kept, 1.0 / (1 - rate), rtol=1e-6)
 
 
-@pytest.mark.skipif(
-    not (hasattr(jax.lax, "pvary") or hasattr(jax.lax, "pcast")),
-    reason="this jax build has neither lax.pvary nor lax.pcast, which "
-           "the chunked ring-attention loop carries need at trace time "
-           "(present from jax 0.6; this box runs 0.4.37) — each param "
-           "burned ~120 s of sp-mesh tracing before dying on the "
-           "missing symbol, eating the tier-1 window for a known "
-           "non-regression")
 @pytest.mark.parametrize("chunk", [8, 16, 32])
 def test_ring_attention_chunked_matches_unchunked(chunk):
     """KV sub-chunking (the transient-memory bound for 100k+ sequences)

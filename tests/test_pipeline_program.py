@@ -1,4 +1,4 @@
-"""Program-level pipeline parallelism (VERDICT r2 #2/#5): the SAME fluid
+"""Program-level pipeline parallelism: the SAME fluid
 Program that trains dp/tp runs pipelined — no hand-written stage_fn.
 plan_pipeline's stage cut is exercised on the flagship transformer LM and
 a dp×pp training step checks loss + updated-parameter parity against
@@ -108,7 +108,7 @@ def _param_names(program):
 ])
 def test_transformer_pipeline_parity(mesh_shape, axes):
     """12 layers / 4 stages / microbatched: loss and updated params match
-    sequential full-batch execution (VERDICT r2 next-round #5). The
+    sequential full-batch execution. The
     Program declares the PER-DEVICE microbatch; feeds carry
     M x dp x that in dim 0."""
     n_layer, M, B_mb, lr = 12, 4, 2, 0.1
@@ -419,7 +419,7 @@ def test_pipeline_run_loop_matches_stepwise():
 
 
 def test_pipeline_composes_dp_pp_mp():
-    """VERDICT r3 weak #5: the full 3-axis hybrid — manual tick loop over
+    """the full 3-axis hybrid — manual tick loop over
     (dp, pp) with the Megatron mp axis left automatic for GSPMD — in ONE
     [2,2,2] mesh. Loss + updated params must match sequential full-batch
     execution, proving the 'hybrid mesh' story end to end."""

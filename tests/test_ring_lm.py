@@ -1,6 +1,6 @@
 """Flagship long-context LM: transformer_lm(use_ring_attention=True) on a
 sequence-parallel mesh matches the single-device model exactly (same seed),
-and trains. SURVEY §2 models commitment; VERDICT r1 item 6."""
+and trains. SURVEY §2 models commitment."""
 from __future__ import annotations
 
 import numpy as np
@@ -85,7 +85,7 @@ def test_ring_lm_dp_x_sp():
 
 
 def test_ring_lm_with_dropout_matches_single_device():
-    """VERDICT r3 item 4: the flagship long-context path must train the
+    """the flagship long-context path must train the
     SAME model as the single-device path even with attention dropout on.
     The ring op's dropout mask is a pure function of (seed, global q,
     global k) — independent of the sp shard count — and both executors
@@ -166,11 +166,6 @@ def _run_sp(monkeypatch, chunk_env, seed=3):
         return float(pexe.run(feed=_feed(), fetch_list=[loss])[0])
 
 
-@pytest.mark.skipif(
-    not (hasattr(jax.lax, "pvary") or hasattr(jax.lax, "pcast")),
-    reason="explicit ring chunking needs lax.pvary/pcast for its loop "
-           "carries (present from jax 0.6; this box runs 0.4.37) — "
-           "known non-regression, see test_parallel's chunked gate")
 def test_ring_chunk_env_override(monkeypatch):
     """PADDLE_TPU_RING_CHUNK through the op route on an sp mesh: 0 means
     auto (not a crash), an explicit chunk is numerically invisible, junk

@@ -2,9 +2,8 @@
 after the tier-1 870s cutoff — run it directly): PrefixStore semantics
 (block-aligned partial hits, byte-bounded LRU eviction, refcount
 pinning), speculative server fault tolerance, spec+prefix composition,
-ring-attention prefill (single-device structural parity always; the
-true sequence-parallel chunked path is version-gated on lax.pvary, the
-PR-11 CPU gate pattern), and decode crash-requeue through the Router
+ring-attention prefill (single-device structural parity and the true
+sequence-parallel chunked path), and decode crash-requeue through the Router
 with both levers live (mid-speculation / prefix-shared sequences
 re-prefill on a survivor, zero misversioned)."""
 from __future__ import annotations
@@ -385,15 +384,9 @@ def test_ring_prefill_structural_parity(model_dir):
     np.testing.assert_allclose(rl, dl, rtol=2e-5, atol=1e-5)
 
 
-@pytest.mark.skipif(
-    not (hasattr(jax.lax, "pvary") or hasattr(jax.lax, "pcast")),
-    reason="the chunked sequence-parallel ring path needs lax.pvary/"
-           "pcast (jax >= 0.5); the single-device fallback parity above "
-           "still pins the graph — device numbers are PERF_NOTES "
-           "residue")
 def test_ring_prefill_sequence_parallel_mesh():
     """The true long-context path: the ring prefill under an sp mesh
-    matches the single-device prefill (version-gated, PR-11 pattern)."""
+    matches the single-device prefill."""
     from paddle_tpu.parallel import (ParallelExecutor, make_mesh,
                                      seq_parallel_plan)
 

@@ -1,0 +1,274 @@
+"""Compile the main path's kernels and step programs for a DESCRIBED TPU
+v5e at the widths the chip runs them at (chip_smoke.py's model: d_model
+1024, 8 heads of 128, vocab 32768, seq 1024) — the chip's own compiler,
+no chip attached. What it refuses here (a block Mosaic cannot tile, a
+kernel over its VMEM, a Mosaic call GSPMD cannot partition, a step that
+does not fit 16 GB) costs no chip time. Nothing runs, so nothing here
+says anything about results or speed.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may hold the TPU library, and every xdist
+worker imports every test file. All such compiles live in THIS file so
+one worker owns the library. Dispatch code sees the CPU here, so the
+whole-step cases steer it in the test (PADDLE_TPU_FORCE_PALLAS, a patch
+of `_use_pallas_decode`) and every case asserts `tpu_custom_call` in the
+compiled text: a case that fell to the XLA path fails instead of passing
+empty.
+"""
+from __future__ import annotations
+
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, SingleDeviceSharding
+
+from paddle_tpu.ops import attention as A
+from paddle_tpu.ops import kv_cache as KV
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+import chip_smoke  # noqa: E402  (the model and sizes under test are its)
+
+_FULL = chip_smoke.FULL
+B, T, D_MODEL, D_INNER, VOCAB = (_FULL["batch"], _FULL["seq"],
+                                 _FULL["d_model"], _FULL["d_inner"],
+                                 _FULL["vocab"])
+HBM_BYTES = 16 * 2**30
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    # a compile for a described chip is written to jax's persistent cache
+    # but cannot be read back without the chip: keep the cache out of it
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *avals, **jit_kw):
+    compiled = jax.jit(fn, **jit_kw).lower(*avals).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the compiled text"
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < HBM_BYTES, "does not fit one chip: %r" % (mem,)
+    return text
+
+
+# -- the kernels, alone -----------------------------------------------------
+
+def _attn_loss(kern):
+    def loss(q, k, v):
+        return jnp.sum(jnp.sin(kern(q, k, v, causal=True)
+                               .astype(jnp.float32)))
+    return loss
+
+
+_BTHD = (B, T, 8, 128)
+_ATTN_CASES = [
+    # id, kernel, shape, grads?, fused backward?
+    ("bthd-fwd", "pallas_flash_attention_bthd", _BTHD, False, False),
+    ("bthd-bwd-split", "pallas_flash_attention_bthd", _BTHD, True, False),
+    ("bthd-bwd-fused", "pallas_flash_attention_bthd", _BTHD, True, True),
+    ("bhtd-h8d128-bwd-split", "pallas_flash_attention", (B, 8, T, 128),
+     True, False),
+    ("bhtd-h8d128-bwd-fused", "pallas_flash_attention", (B, 8, T, 128),
+     True, True),
+    ("bhtd-h16d64-bwd-split", "pallas_flash_attention", (B, 16, T, 64),
+     True, False),
+    ("bhtd-h16d64-bwd-fused", "pallas_flash_attention", (B, 16, T, 64),
+     True, True),
+]
+
+
+@pytest.mark.parametrize("kernel,shape,grads,fused",
+                         [c[1:] for c in _ATTN_CASES],
+                         ids=[c[0] for c in _ATTN_CASES])
+def test_flash_attention_kernel_compiles(one_chip, monkeypatch, kernel,
+                                         shape, grads, fused):
+    monkeypatch.setenv("PADDLE_TPU_FLASH_FUSED_BWD", "1" if fused else "0")
+    fn = _attn_loss(getattr(A, kernel))
+    if grads:
+        fn = jax.value_and_grad(fn, argnums=(0, 1, 2))
+    av = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    text = _compile(fn, av, av, av)
+    # fwd alone is one kernel; split backward adds dq + dkv, fused adds one
+    want = 1 if not grads else (2 if fused else 3)
+    assert text.count("tpu_custom_call") >= want
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_decode_attention_kernel_compiles(one_chip, dtype):
+    sds = jax.ShapeDtypeStruct
+    q = sds((8, 1, 8, 128), dtype, sharding=one_chip)
+    kv = sds((8, 1024, 8, 128), dtype, sharding=one_chip)
+    lens = sds((8,), jnp.int32, sharding=one_chip)
+    _compile(KV.pallas_decode_attention, q, kv, kv, lens)
+
+
+def test_lm_head_loss_gradient_compiles(one_chip):
+    """(16384 x 1024) . (1024 x 32768): the chunked fused head; it holds
+    no Pallas kernel, so only fit and compile are asserted."""
+    from paddle_tpu.ops.fused_loss import lm_head_loss
+
+    sds = jax.ShapeDtypeStruct
+    x = sds((B * T, D_MODEL), jnp.bfloat16, sharding=one_chip)
+    w = sds((D_MODEL, VOCAB), jnp.float32, sharding=one_chip)
+    b = sds((VOCAB,), jnp.float32, sharding=one_chip)
+    y = sds((B * T,), jnp.int32, sharding=one_chip)
+
+    def loss(x, w, b, y):
+        return jnp.mean(lm_head_loss(4096, x, w, b, y))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, w, b, y).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
+# -- whole step programs ----------------------------------------------------
+
+def _lm_programs(n_layer, tie=False):
+    """chip_smoke.py's own training program, cut to `n_layer`."""
+    return chip_smoke._build_lm(dict(chip_smoke.FULL, n_layer=n_layer),
+                                tie_embeddings=tie)
+
+
+def _train_step_avals(main_p, startup, loss, place_state, place_other):
+    """(stepfn, avals) of the training step, state shapes taken from an
+    abstract evaluation of the startup program — nothing is allocated.
+    `place_state(name, aval)` / `place_other(aval)` attach shardings."""
+    from paddle_tpu.executor import analyze_state, build_step_fn
+
+    sds = jax.ShapeDtypeStruct
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    step = sds((), np.uint32)
+    _, init_out = analyze_state(startup, set())
+    _, init = jax.eval_shape(build_step_fn(startup, (), [], init_out),
+                             {}, {}, key, step)
+    feed_names = ("ids", "labels")
+    state_in, state_out = analyze_state(main_p, set(feed_names))
+    stepfn = build_step_fn(main_p, (loss.name,), state_in, state_out)
+    feeds = {n: place_other(sds((B, T), np.int32), batch=True)
+             for n in feed_names}
+    state = {n: place_state(n, init[n]) for n in state_in}
+    return stepfn, (feeds, state, place_other(key), place_other(step))
+
+
+@pytest.mark.parametrize("tie", [False, True], ids=["untied", "tied"])
+def test_training_step_compiles(one_chip, monkeypatch, tie):
+    """The LM training step, 2 layers at full width, AMP O2, fused
+    backward, fused head — as chip_smoke.py trains it at 12 layers."""
+    monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
+    monkeypatch.setenv("PADDLE_TPU_FLASH_FUSED_BWD", "1")
+    main_p, startup, loss = _lm_programs(2, tie=tie)
+
+    def on_chip(aval, batch=False):
+        return jax.ShapeDtypeStruct(aval.shape, aval.dtype,
+                                    sharding=one_chip)
+
+    stepfn, avals = _train_step_avals(
+        main_p, startup, loss, lambda n, a: on_chip(a), on_chip)
+    text = _compile(stepfn, *avals, donate_argnums=(1,))
+    # per layer: flash fwd + fused bwd
+    assert text.count("tpu_custom_call") >= 2 * 2
+
+
+def test_training_step_compiles_on_2x2_mesh(topo, monkeypatch):
+    """The same step as one program over four chips: batch over dp, heads
+    over mp (megatron plan). Mosaic kernels cannot be partitioned by
+    GSPMD; `fused_attention` shard_maps them under the trace mesh."""
+    from paddle_tpu.framework import trace as trace_mod
+    from paddle_tpu.parallel import megatron_transformer_plan
+
+    monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
+    monkeypatch.setenv("PADDLE_TPU_FLASH_FUSED_BWD", "1")
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("dp", "mp"))
+    plan = megatron_transformer_plan(mesh)
+    main_p, startup, loss = _lm_programs(2)
+    sds = jax.ShapeDtypeStruct
+
+    def place_state(name, aval):
+        return sds(aval.shape, aval.dtype,
+                   sharding=plan.sharding(name, shape=aval.shape))
+
+    def place_other(aval, batch=False):
+        sh = (plan.feed_sharding(len(aval.shape)) if batch
+              else plan.replicated())
+        return sds(aval.shape, aval.dtype, sharding=sh)
+
+    with trace_mod.mesh_context(mesh, plan):
+        stepfn, avals = _train_step_avals(main_p, startup, loss,
+                                          place_state, place_other)
+        text = _compile(stepfn, *avals, donate_argnums=(1,))
+    assert text.count("tpu_custom_call") >= 2 * 2
+    assert "all-reduce" in text
+    # an mp-split weight is half per device: fc1.w is (1024, 4096) f32
+    fc1 = next(n for n in avals[1] if n.endswith(".fc1.w"))
+    assert avals[1][fc1].sharding.shard_shape(avals[1][fc1].shape) == (
+        D_MODEL, D_INNER // 2)
+
+
+@pytest.mark.parametrize("kind,batch,seq",
+                         [("decode", 8, 1024), ("prefill", 8, 512)],
+                         ids=["decode-8x1024", "prefill-8x512"])
+def test_serving_step_compiles(one_chip, monkeypatch, kind, batch, seq):
+    """The programs DecodePredictor builds for chip_smoke.py's serve
+    phase, 2 layers at full width: the decode step at 8 slots x 1024 (the
+    Pallas decode kernel, feeds donated) and the burst prefill."""
+    from paddle_tpu.executor import analyze_state
+    from paddle_tpu.framework.trace import RngStream, trace_block
+    from paddle_tpu.serving.decode import DecodeConfig, DecodePredictor
+
+    monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
+    monkeypatch.setattr(
+        KV, "_use_pallas_decode",
+        lambda s, d: d % 128 == 0 and s % 128 == 0 and s >= 128)
+    pred = DecodePredictor.__new__(DecodePredictor)  # graph builder only
+    pred.config = DecodeConfig(vocab_size=VOCAB, n_layer=2, n_head=8,
+                               d_model=D_MODEL, d_inner=D_INNER, max_len=T)
+    pred.sample_k, pred.sample_p, pred.temperature = 40, 0.9, 1.0
+    pred.draft_n_layer = 1
+    program, feed_names, fetch_names = pred._build(kind, batch, seq,
+                                                   "greedy")
+    sds = jax.ShapeDtypeStruct
+    feeds = {n: sds(a.shape, a.dtype, sharding=one_chip)
+             for n, a in pred._feed_structs(program, feed_names).items()}
+    gb = program.global_block()
+    state = {}
+    for n in analyze_state(program, set(feed_names))[0]:
+        var = gb._find_var_recursive(n)
+        state[n] = sds(tuple(var.shape), np.float32, sharding=one_chip)
+
+    def step_fn(feeds, state):
+        env = dict(state)
+        env.update(feeds)
+        trace_block(gb, env, RngStream(jax.random.PRNGKey(0)))
+        return tuple(env[n] for n in fetch_names)
+
+    text = _compile(step_fn, feeds, state, donate_argnums=(0,))
+    assert text.count("tpu_custom_call") >= 2  # one per layer
+    if kind == "decode":
+        assert "input_output_alias" in text

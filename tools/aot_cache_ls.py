@@ -96,8 +96,8 @@ def _fmt_age(s):
 def main():
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--dir", default=None,
-                    help="cache directory (default: PADDLE_TPU_AOT_CACHE_DIR"
-                         " or ~/.cache/paddle_tpu/aot)")
+                    help="cache directory (default: the AOT tier of "
+                         "runtime/aot_cache.py's one cache rule)")
     ap.add_argument("--json", action="store_true",
                     help="print the pinned-schema JSON snapshot")
     ap.add_argument("--gc", action="store_true",

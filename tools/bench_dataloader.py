@@ -6,8 +6,7 @@ decode that HOLDS the GIL (a PIL/cv2 stand-in — python-loop checksum +
 numpy conversion over a raw byte blob). Threaded xmap_readers serializes
 on it no matter how many workers; process workers scale with cores.
 
-One JSON line per sweep config (PERF_NOTES methodology: modes alternate
-round-robin in ONE process, medians reported):
+One JSON line per sweep config:
 
   {"phase": "dataloader_sweep", "mode": "threads"|"process",
    "workers": W, "sample_kb": K, "batches_per_sec": ..., ...}
@@ -183,7 +182,7 @@ def quick_metric(workers=None, sample_kb=16, batch=16, n_batches=48,
     input-pipeline metric: `rounds` alternating threads/process rounds
     (medians — single rounds are hostage to neighbor noise), no sweep.
     Defaults are the measured sweet spot (2 workers, 16 KB samples,
-    batch 16 — see PERF_NOTES)."""
+    batch 16)."""
     workers = workers or min(2, os.cpu_count() or 2)
     nbytes = int(sample_kb * 1024)
     measure_process(max(2, n_batches // 8), batch, nbytes, workers)

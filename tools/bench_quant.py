@@ -26,8 +26,8 @@ pinned by tests/test_bench_quant_smoke.py):
 
 CPU honesty (the PR-8/PR-9 lesson): this box's XLA CPU GEMM has no
 int8 fast path — the device-window claim (>=1.5x rows/s on MLP/DeepFM
-at matched accuracy, int8 on the MXU) is banked as residue in
-PERF_NOTES with this exact command; the numbers here measure the
+at matched accuracy, int8 on the MXU) has not been measured on a
+chip; the numbers here measure the
 mechanism and the parity, not the silicon win.
 
 Usage:

@@ -10,7 +10,7 @@ request submitted under an SLO class (priority + deadline). It records
 per-class latency percentiles, every structured shed reject, and the
 fleet counters, and emits ONE JSON verdict line per run (schema
 ``loadgen/2``; ``--curve`` sweeps offered load and emits one line per
-level — the latency-vs-offered-load curve for PERF_NOTES).
+level — the latency-vs-offered-load curve).
 
 loadgen/2 adds ``trace_phases``: per-phase p50/p99 latency attribution
 pulled from the distributed-tracing flight recorder
