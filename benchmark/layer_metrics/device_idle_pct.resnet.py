@@ -1,0 +1,12 @@
+"""Share of the traced sub-window in which no operation ran on the
+device: 1 - (union of device-operation intervals) / window, averaged
+over the chips of the cell."""
+from benchmark.lib import trace_reduce
+
+LAYER = "device"
+UNIT = "%"
+MOVES = "train_images_per_s"
+SOURCE = "device_trace"
+
+
+read = trace_reduce.idle_pct
