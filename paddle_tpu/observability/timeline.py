@@ -107,12 +107,13 @@ class StepTimeline:
             ev["device_ms"] = round(device_ms, 4)
         self._append(ev)
         # mirror into the distributed-tracing flight recorder (rate-
-        # sampled like request traces, under the process-scoped id) so a
-        # trainer's steps land on the same trace_dump waterfall/clock as
-        # the serving spans; free when PADDLE_TPU_TRACE_SAMPLE is 0
+        # sampled like request traces, through the one entry point for
+        # spans that belong to no request) so a trainer's steps land on
+        # the same trace_dump waterfall/clock as the serving spans and
+        # loop iterations; free when PADDLE_TPU_TRACE_SAMPLE is 0
         if tracing.sampled():
-            tracing.record_span(tracing.process_trace_id(), "train.step",
-                                dur_ms=wall_ms, kind=kind, steps=steps)
+            tracing.record_process_span("train.step", dur_ms=wall_ms,
+                                        kind=kind, steps=steps)
 
     def record_compile(self, kind: str, program: Optional[str] = None, *,
                        wall_ms: Optional[float] = None,

@@ -43,6 +43,13 @@ from .registry import register_op
 
 _NEG = -1e30
 
+# stable names of the Pallas kernels (`named_pallas_call`): the fused
+# backward is one kernel, the split one two
+FLASH_FWD = "ptpu.flash_fwd"
+FLASH_BWD = "ptpu.flash_bwd"
+FLASH_BWD_DQ = "ptpu.flash_bwd_dq"
+FLASH_BWD_DKV = "ptpu.flash_bwd_dkv"
+
 
 def _ceil_to(x: int, m: int) -> int:
     return (x + m - 1) // m * m
@@ -147,6 +154,23 @@ def _tpu_params(*dimension_semantics):
     return {"compiler_params": pltpu.CompilerParams(
         dimension_semantics=tuple(dimension_semantics))}
 
+
+def named_pallas_call(name, kernel, **kw):
+    """``pl.pallas_call`` under a stable name: a ``jax.named_scope``
+    around the call. The TPU compiler names a Mosaic custom-call after
+    the innermost component of its ``op_name``, so the forward kernel
+    is ``jvp_ptpu.flash_fwd_.N`` in a training step's device trace, the
+    backward ``transpose_jvp_ptpu.flash_bwd__.N``, and ``ptpu.<kernel>.N``
+    outside autodiff or under ``shard_map``. ``pallas_call``'s own
+    ``name=`` is left alone: it pushes a scope of its own, which hides
+    the ``jvp`` that the benchmark's accepted readers anchor on.
+    Metadata only: the compiled program is the same."""
+    call = pl.pallas_call(kernel, **kw)
+
+    def run(*operands):
+        with jax.named_scope(name):
+            return call(*operands)
+    return run
 
 def _causal_mask(s, row0, col0):
     """Mask score tile `s` (BQ, BK) whose top-left element is global
@@ -365,8 +389,8 @@ def _mha_fwd_call(qs, k, v, causal, block_q, block_k, interpret):
     kernel = functools.partial(
         _mha_fwd_kernel, block_q=block_q, block_k=block_k, seq_k=tk,
         causal=causal)
-    return pl.pallas_call(
-        kernel,
+    return named_pallas_call(
+        FLASH_FWD, kernel,
         grid=(bh, t // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
@@ -409,8 +433,8 @@ def _pallas_mha_bwd(causal, block_q, block_k, interpret, res, do):
         kernel = functools.partial(
             _mha_bwd_fused_kernel, block_q=block_q, block_k=block_k,
             seq_k=tk, causal=causal)
-        dq, dk, dv = pl.pallas_call(
-            kernel,
+        dq, dk, dv = named_pallas_call(
+            FLASH_BWD, kernel,
             grid=(bh, t // block_q),
             in_specs=[
                 pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
@@ -438,8 +462,8 @@ def _pallas_mha_bwd(causal, block_q, block_k, interpret, res, do):
     dq_kernel = functools.partial(
         _mha_dq_kernel, block_q=block_q, block_k=block_k, seq_k=tk,
         causal=causal)
-    dq = pl.pallas_call(
-        dq_kernel,
+    dq = named_pallas_call(
+        FLASH_BWD_DQ, dq_kernel,
         grid=(bh, t // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
@@ -458,8 +482,8 @@ def _pallas_mha_bwd(causal, block_q, block_k, interpret, res, do):
     dkv_kernel = functools.partial(
         _mha_dkv_kernel, block_q=block_q, block_k=block_k, seq_q=t,
         causal=causal)
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
+    dk, dv = named_pallas_call(
+        FLASH_BWD_DKV, dkv_kernel,
         grid=(bh, tk // block_k),
         in_specs=[
             pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0)),
@@ -519,8 +543,8 @@ def _mha_fwd_call_bthd(qs, k, v, h, causal, block_q, block_k, interpret):
     kernel = functools.partial(
         _mha_fwd_kernel, block_q=block_q, block_k=block_k, seq_k=tk,
         causal=causal, pid_axis=2)
-    return pl.pallas_call(
-        kernel,
+    return named_pallas_call(
+        FLASH_FWD, kernel,
         grid=(b, h, t // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bi, hi, qi: (bi, qi, hi)),
@@ -571,8 +595,8 @@ def _pallas_mha_bthd_bwd(h, causal, block_q, block_k, interpret, res, do):
         kernel = functools.partial(
             _mha_bwd_fused_kernel, block_q=block_q, block_k=block_k,
             seq_k=tk, causal=causal, pid_axis=2)
-        dq, dk, dv = pl.pallas_call(
-            kernel,
+        dq, dk, dv = named_pallas_call(
+            FLASH_BWD, kernel,
             grid=(b, h, t // block_q),
             in_specs=[
                 pl.BlockSpec((1, block_q, d), lambda bi, hi, qi: (bi, qi, hi)),
@@ -601,8 +625,8 @@ def _pallas_mha_bthd_bwd(h, causal, block_q, block_k, interpret, res, do):
     dq_kernel = functools.partial(
         _mha_dq_kernel, block_q=block_q, block_k=block_k, seq_k=tk,
         causal=causal, pid_axis=2)
-    dq = pl.pallas_call(
-        dq_kernel,
+    dq = named_pallas_call(
+        FLASH_BWD_DQ, dq_kernel,
         grid=(b, h, t // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bi, hi, qi: (bi, qi, hi)),
@@ -622,8 +646,8 @@ def _pallas_mha_bthd_bwd(h, causal, block_q, block_k, interpret, res, do):
     dkv_kernel = functools.partial(
         _mha_dkv_kernel, block_q=block_q, block_k=block_k, seq_q=t,
         causal=causal, pid_axis=2)
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
+    dk, dv = named_pallas_call(
+        FLASH_BWD_DKV, dkv_kernel,
         grid=(b, h, tk // block_k),
         in_specs=[
             pl.BlockSpec((1, t, d), lambda bi, hi, kj: (bi, 0, hi)),

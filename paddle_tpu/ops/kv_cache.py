@@ -44,10 +44,13 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 from ..framework.scope import current_device
-from .attention import _fit_block, _tpu_params
+from .attention import _fit_block, _tpu_params, named_pallas_call
 from .registry import register_op
 
 _NEG = -1e30
+
+# the decode kernel's stable name in lowered text and device traces
+DECODE_ATTN = "ptpu.decode_attn"
 
 
 # ---------------------------------------------------------------------------
@@ -144,8 +147,8 @@ def pallas_decode_attention(q, k_cache, v_cache, lengths, scale=None,
                                seq_s=s)
     kf = k_cache.reshape(b, s, h * d)
     vf = v_cache.reshape(b, s, h * d)
-    out = pl.pallas_call(
-        kernel,
+    out = named_pallas_call(
+        DECODE_ATTN, kernel,
         grid=(b, h),
         in_specs=[
             pl.BlockSpec((1, 1, d), lambda bi, hi: (bi, 0, hi)),

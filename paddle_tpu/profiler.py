@@ -16,8 +16,9 @@ count/sum/min/max per event — the reference report's columns), and
 start/stop window; this window only gates the legacy event table.
 
 Each event also lands as a span in the distributed-tracing flight
-recorder (``observability.tracing``), under the process-scoped trace id
-— so a legacy ``with profiler.profiler():`` window gets a timeline in
+recorder (``observability.tracing.record_process_span``, the one way in
+for spans that belong to no request) — so a legacy ``with
+profiler.profiler():`` window gets a timeline in
 ``tools/trace_dump.py`` (text waterfall / Chrome trace JSON) for free,
 on the same clock as the serving spans. The start/stop window IS the
 opt-in; the spans cost nothing while profiling is off.
@@ -25,7 +26,6 @@ opt-in; the spans cost nothing while profiling is off.
 from __future__ import annotations
 
 import contextlib
-import time
 import warnings
 from typing import Optional
 
@@ -51,26 +51,13 @@ def is_profiling() -> bool:
 def record_event(name: str, seconds: float):
     if _enabled:
         _obs.PROFILER_EVENT_MS.observe(seconds * 1e3, event=name)
-        _tracing.record_span(_tracing.process_trace_id(),
-                             "profiler." + name, dur_ms=seconds * 1e3)
+        _tracing.record_process_span("profiler." + name,
+                                     dur_ms=seconds * 1e3)
 
 
 def record_cache(hit: bool):
     if _enabled:
         _cache_stats["hits" if hit else "misses"] += 1
-
-
-@contextlib.contextmanager
-def timed(name: str):
-    """Time a block into the profile (no-op when profiling is off)."""
-    if not _enabled:
-        yield
-        return
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        record_event(name, time.perf_counter() - t0)
 
 
 def cache_stats():

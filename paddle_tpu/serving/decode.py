@@ -94,6 +94,27 @@ def kv_slab_slots(budget_bytes: int, config: "DecodeConfig", seq: int,
     return max(int(budget_bytes) // per_slot, 0)
 
 
+def _executable_name(kind, batch, seq, strategy="", kv_dtype="float32",
+                     window=0, draft_layers=0, ring=False) -> str:
+    """Stable module name of one decode-path executable, from what
+    ``DecodePredictor._acquire`` keys it by: ``ptpu_prefill_b1_s512``,
+    ``ptpu_decode_b8_s2048``, ``ptpu_verify_b8_s2048_w5``,
+    ``ptpu_draft_b8_s2048_l2``; a sampling strategy, int8 slabs and
+    ring prefill add their own suffix."""
+    parts = ["ptpu", kind, "b%d" % batch, "s%d" % seq]
+    if window:
+        parts.append("w%d" % window)
+    if draft_layers:
+        parts.append("l%d" % draft_layers)
+    if strategy and strategy != "greedy":
+        parts.append(strategy)
+    if kv_dtype == "int8":
+        parts.append("kv8")
+    if ring:
+        parts.append("ring")
+    return "_".join(parts)
+
+
 def _kv_dtype_from_env() -> str:
     """PADDLE_TPU_QUANT=kv8|int8 opts DecodeServer slabs into int8."""
     raw = (os.environ.get("PADDLE_TPU_QUANT") or "").strip().lower()
@@ -468,6 +489,10 @@ class DecodePredictor:
             rng = RngStream(jax.random.PRNGKey(0))
             trace_block(program.global_block(), env, rng)
             return tuple(env[n] for n in fetch_names)
+
+        # jit names the module after the function: a device trace's
+        # module line then tells a prefill from a decode step
+        step_fn.__name__ = step_fn.__qualname__ = _executable_name(*ck)
 
         def lower():
             # donate the feeds (the KV slabs dominate them) so XLA
@@ -1180,12 +1205,13 @@ class DecodeServer:
             tokens[i, :len(p)] = p
             plens[i] = len(p)
         pexe, _ = self.predictor.acquire("prefill", bb, sp)
-        t0 = time.perf_counter()
-        outs = pexe({"tokens": tokens, "lengths": plens},
-                    self.predictor._state)
+        with _tracing.phase("decode.loop.prefill") as ph:
+            t0 = ph.t0 or time.perf_counter()
+            outs = pexe({"tokens": tokens, "lengths": plens},
+                        self.predictor._state)
         self.prefill_executions += 1
-        obs.DECODE_STEP_MS.observe((time.perf_counter() - t0) * 1e3,
-                                   stage="prefill")
+        obs.DECODE_STEP_MS.observe(
+            ((ph.t1 or time.perf_counter()) - t0) * 1e3, stage="prefill")
         obs.DECODE_TOKENS.inc(int(plens[:len(prompts)].sum()),
                               kind="prefill")
         return outs, sp
@@ -1216,42 +1242,24 @@ class DecodeServer:
             for rid, _p, _mn, _seed in batch:
                 self._fail(rid, e)
             return caches
-        first = np.array(self.predictor._sample_host(
-            outs[0], self.strategy, self._seed_ctr))  # writable copy
-        self._seed_ctr += 1
-        # a request that carried its own seed gets ITS first token from
-        # that seed (matching DecodePredictor.generate(..., seed=s) for
-        # the first sample); later steps draw from the server's stream —
-        # full per-request reproducibility under continuous batching is
-        # a greedy/direct-predictor property, not a server one
-        for i, (_rid, _p, _mn, seed) in enumerate(batch):
-            if seed is not None and self.strategy not in ("greedy",):
-                first[i] = self.predictor._sample_host(
-                    outs[0][i:i + 1], self.strategy, seed)[0]
-        slot_idx = jnp.asarray(np.array(free[:n], np.int32))
-        sub = list(outs[1:])  # (k, v) float sub-slabs per layer
-        # scatter the (n, sp, H, Dh) prefill rows into the slab's first
-        # sp positions; rows past sp keep old garbage, masked by length
-        if self.kv_dtype == "int8":
-            # prefill emits float rows; quantize per (slot, position)
-            # at scatter time — the same row-scale scheme the in-graph
-            # cache_append_quant applies to decoded rows
-            from ..ops.quant import quantize_kv_rows
-
-            per = self._cache_per_layer
-            caches = list(caches)
-            for li in range(len(sub) // 2):
-                for j in (0, 1):  # K then V
-                    rows = jnp.asarray(sub[2 * li + j])[:n]
-                    q, sc = quantize_kv_rows(rows)
-                    caches[per * li + j] = (
-                        caches[per * li + j].at[slot_idx, :sp].set(q))
-                    caches[per * li + 2 + j] = (
-                        caches[per * li + 2 + j].at[slot_idx, :sp]
-                        .set(sc))
-        else:
-            caches = [c.at[slot_idx, :sp].set(jnp.asarray(s)[:n])
-                      for c, s in zip(caches, sub)]
+        with _tracing.phase("decode.loop.first_token"):
+            # the host waits here for the prefill's logits
+            first = np.array(self.predictor._sample_host(
+                outs[0], self.strategy, self._seed_ctr))  # writable copy
+            self._seed_ctr += 1
+            # a request that carried its own seed gets ITS first token
+            # from that seed (matching DecodePredictor.generate(...,
+            # seed=s) for the first sample); later steps draw from the
+            # server's stream — full per-request reproducibility under
+            # continuous batching is a greedy/direct-predictor
+            # property, not a server one
+            for i, (_rid, _p, _mn, seed) in enumerate(batch):
+                if seed is not None and self.strategy not in ("greedy",):
+                    first[i] = self.predictor._sample_host(
+                        outs[0][i:i + 1], self.strategy, seed)[0]
+        with _tracing.phase("decode.loop.scatter"):
+            caches = self._scatter_prefill(caches, list(outs[1:]),
+                                           free[:n], sp)
         for i, (rid, prompt, max_new, seed) in enumerate(batch):
             slot = free[i]
             tok = int(first[i])
@@ -1271,15 +1279,45 @@ class DecodeServer:
                 lens[slot] = 0
         return caches
 
+    def _scatter_prefill(self, caches, sub, slots, sp):
+        """The slab rebuild of a plain admission: scatter the (n, sp, H,
+        Dh) prefill rows ``sub`` ((k, v) float sub-slabs per layer)
+        into the first ``sp`` positions of ``slots``; rows past sp keep
+        old garbage, masked by length. Out of place: every slab is
+        copied once per admission wave."""
+        n = len(slots)
+        slot_idx = jnp.asarray(np.array(slots, np.int32))
+        if self.kv_dtype != "int8":
+            return [c.at[slot_idx, :sp].set(jnp.asarray(s)[:n])
+                    for c, s in zip(caches, sub)]
+        # prefill emits float rows; quantize per (slot, position) at
+        # scatter time — the same row-scale scheme the in-graph
+        # cache_append_quant applies to decoded rows
+        from ..ops.quant import quantize_kv_rows
+
+        per = self._cache_per_layer
+        caches = list(caches)
+        for li in range(len(sub) // 2):
+            for j in (0, 1):  # K then V
+                rows = jnp.asarray(sub[2 * li + j])[:n]
+                q, sc = quantize_kv_rows(rows)
+                caches[per * li + j] = (
+                    caches[per * li + j].at[slot_idx, :sp].set(q))
+                caches[per * li + 2 + j] = (
+                    caches[per * li + 2 + j].at[slot_idx, :sp].set(sc))
+        return caches
+
     def _first_token(self, logits_row, seed):
         """First sampled token for one admitted sequence, honoring the
         per-request seed contract the plain admission path applies."""
-        first = int(self.predictor._sample_host(
-            logits_row.reshape(1, -1), self.strategy, self._seed_ctr)[0])
-        self._seed_ctr += 1
-        if seed is not None and self.strategy not in ("greedy",):
+        with _tracing.phase("decode.loop.first_token"):
             first = int(self.predictor._sample_host(
-                logits_row.reshape(1, -1), self.strategy, seed)[0])
+                logits_row.reshape(1, -1), self.strategy,
+                self._seed_ctr)[0])
+            self._seed_ctr += 1
+            if seed is not None and self.strategy not in ("greedy",):
+                first = int(self.predictor._sample_host(
+                    logits_row.reshape(1, -1), self.strategy, seed)[0])
         return first
 
     def _activate(self, slot, rid, prompt, max_new, first, lens, active,
@@ -1342,8 +1380,10 @@ class DecodeServer:
                 t_pf = time.perf_counter()
                 outs, _sp = self._prefill_prompts(uniq_prompts)
                 pf_ms = (time.perf_counter() - t_pf) * 1e3
-                sub = [np.asarray(c) for c in outs[1:]]
-                logits_all = np.asarray(outs[0])
+                with _tracing.phase("decode.loop.first_token"):
+                    # the host waits for the prefill: logits AND rows
+                    sub = [np.asarray(c) for c in outs[1:]]
+                    logits_all = np.asarray(outs[0])
                 for i, p in enumerate(uniq_prompts):
                     rows = [s[i, :len(p)] for s in sub]
                     uniq_rows.append(rows)
@@ -1394,14 +1434,15 @@ class DecodeServer:
             lmax = max(L for _s, _r, L in seeds_rows)
             slot_idx = jnp.asarray(np.array(
                 [s for s, _r, _l in seeds_rows], np.int32))
-            for j in range(len(caches)):
-                stacked = np.zeros(
-                    (len(seeds_rows), lmax) + tuple(caches[j].shape[2:]),
-                    np.float32)
-                for i, (_s, rows, L) in enumerate(seeds_rows):
-                    stacked[i, :L] = rows[j]
-                caches[j] = caches[j].at[slot_idx, :lmax].set(
-                    jnp.asarray(stacked))
+            with _tracing.phase("decode.loop.scatter"):
+                for j in range(len(caches)):
+                    stacked = np.zeros(
+                        (len(seeds_rows), lmax)
+                        + tuple(caches[j].shape[2:]), np.float32)
+                    for i, (_s, rows, L) in enumerate(seeds_rows):
+                        stacked[i, :L] = rows[j]
+                    caches[j] = caches[j].at[slot_idx, :lmax].set(
+                        jnp.asarray(stacked))
         except Exception as e:
             # pre-extension admission failed (prefill compile/run,
             # store insert, host scatter): fail THIS batch, free its
@@ -1469,11 +1510,14 @@ class DecodeServer:
             feeds = {"tokens": tokens, "positions": positions,
                      "lengths": lens.copy(), "last_idx": last_idx}
             feeds.update(zip(self._cache_feed_names, caches))
-            t0 = time.perf_counter()
-            vouts = vexe(feeds, self.predictor._state)
+            with _tracing.phase("decode.loop.dispatch") as ph:
+                t0 = ph.t0 or time.perf_counter()
+                vouts = vexe(feeds, self.predictor._state)
             obs.DECODE_STEP_MS.observe(
-                (time.perf_counter() - t0) * 1e3, stage="extend")
-            last_logits = np.asarray(vouts[2])
+                ((ph.t1 or time.perf_counter()) - t0) * 1e3,
+                stage="extend")
+            with _tracing.phase("decode.loop.fetch"):
+                last_logits = np.asarray(vouts[2])
             caches = list(vouts[3:])
             done = []
             for slot, cl in chunk_lens.items():
@@ -1527,32 +1571,56 @@ class DecodeServer:
                 lens[i] = 0
         return self._fresh_slabs()
 
+    @staticmethod
+    def _step_counts(lens, n_active):
+        """What a step's ``decode.loop.dispatch`` phase carries:
+        ``active`` live slots and ``attended``, the K/V rows its
+        attention reads: each live slot's length with the row this step
+        appends (free slots hold length 0)."""
+        return {"active": n_active, "attended": int(lens.sum()) + n_active}
+
     def _spec_round(self, drexe, vexe, caches, lens, active, n_active):
         """One speculative round across every active slot: spec_k draft
         steps propose, ONE verify window call checks, each slot
         advances by its accept+1 tokens (capped by budget and slab
         room). Greedy-lossless: the emitted tokens are the target's own
         argmaxes, token-for-token what the plain loop would emit."""
-        k, T = self.spec_k, self._win
-        cur = np.zeros((self.slots,), np.int64)
-        for i, st in enumerate(active):
-            if st is not None:
-                cur[i] = st["cur"]
+        k = self.spec_k
+        with _tracing.phase("decode.loop.feeds"):
+            cur = np.zeros((self.slots,), np.int64)
+            for i, st in enumerate(active):
+                if st is not None:
+                    cur[i] = st["cur"]
         try:
-            window, positions = self.predictor.draft_window(
-                drexe, caches, cur, lens, k)
-            feeds = {"tokens": window, "positions": positions,
-                     "lengths": lens.copy(),
-                     "last_idx": np.zeros((self.slots,), np.int32)}
-            feeds.update(zip(self._cache_feed_names, caches))
-            t0 = time.perf_counter()
-            vouts = vexe(feeds, self.predictor._state)
-            next_ids = np.asarray(vouts[0]).astype(np.int64)
-            accept = np.asarray(vouts[1]).astype(np.int64)
+            # the spec_k draft steps, each a dispatch and a fetch
+            with _tracing.phase("decode.loop.draft"):
+                window, positions = self.predictor.draft_window(
+                    drexe, caches, cur, lens, k)
+            with _tracing.phase("decode.loop.feeds"):
+                feeds = {"tokens": window, "positions": positions,
+                         "lengths": lens.copy(),
+                         "last_idx": np.zeros((self.slots,), np.int32)}
+                feeds.update(zip(self._cache_feed_names, caches))
+            with _tracing.phase("decode.loop.dispatch",
+                                **self._step_counts(lens, n_active)) as ph:
+                t0 = ph.t0 or time.perf_counter()
+                vouts = vexe(feeds, self.predictor._state)
+            with _tracing.phase("decode.loop.fetch") as ph:
+                next_ids = np.asarray(vouts[0]).astype(np.int64)
+                accept = np.asarray(vouts[1]).astype(np.int64)
+            t1 = ph.t1 or time.perf_counter()
         except Exception as e:
             return self._fail_all_active(active, lens, e)
-        obs.DECODE_STEP_MS.observe((time.perf_counter() - t0) * 1e3,
-                                   stage="verify")
+        obs.DECODE_STEP_MS.observe((t1 - t0) * 1e3, stage="verify")
+        with _tracing.phase("decode.loop.retire"):
+            return self._spec_commit(vouts, next_ids, accept, lens,
+                                     active, n_active)
+
+    def _spec_commit(self, vouts, next_ids, accept, lens, active,
+                     n_active):
+        """The bookkeeping half of a speculative round: each slot takes
+        its accepted tokens, finished ones retire."""
+        k = self.spec_k
         self.step_active_counts.append(n_active)
         caches = list(vouts[3:])
         obs.DECODE_SPEC_PROPOSED.inc(k * n_active)
@@ -1606,104 +1674,127 @@ class DecodeServer:
                                              self.seq, window=self._win)
         closed = False
         while True:
-            n_active = sum(1 for a in active if a is not None)
-            free = self.slots - n_active
-            if not closed:
-                if n_active == 0 and not pending:
-                    # idle: park on the channel until work (or close)
-                    batch = self._chan.recv_batch(self.slots, None)
-                elif free > 0 and (self.continuous or n_active == 0):
-                    # mid-flight admission: non-blocking drain, bounded
-                    # by the free slots (leaving the rest in the channel
-                    # keeps submit()'s backpressure intact)
-                    batch = self._chan.recv_batch(free, 0)
-                else:
-                    batch = []
-                if batch is None:
-                    closed = True
-                    batch = []
-            else:
-                batch = []
-            for msg in batch:
-                try:
-                    rid, prompt, max_new, seed = self._decode_request(msg)
-                    if len(prompt) + max_new > self.seq:
-                        raise ValueError(
-                            "prompt %d + max_new %d exceeds the server's "
-                            "%d-token slab" % (len(prompt), max_new,
-                                               self.seq))
-                    if len(prompt) < 1:
-                        raise ValueError("empty prompt")
-                    pending.append((rid, prompt, max_new, seed))
-                except Exception as e:
-                    try:
-                        self._fail(_rio.frame_tag(bytes(msg)), e)
-                    except Exception:
-                        pass
-            admit_ok = (free > 0 and pending
-                        and (self.continuous or n_active == 0))
-            if admit_ok:
-                caches = self._admit(pending, caches, lens, active)
+            # one iteration = one record of the flight recorder's
+            # process ring when the sample rate asks for it; every
+            # boundary below is a phase of it (docs/performance.md),
+            # and at rate 0 each is the shared no-op
+            with _tracing.phase("decode.loop.iter"):
                 n_active = sum(1 for a in active if a is not None)
-            self._set_slot_gauges(n_active)
-            if n_active == 0:
-                if closed and not pending:
-                    return
-                continue
-            if self.speculative:
-                caches = self._spec_round(drexe, vexe, caches, lens,
-                                          active, n_active)
-                self._set_slot_gauges(
-                    sum(1 for a in active if a is not None))
-                continue
-            # one token across every active slot
-            cur = np.zeros((self.slots,), np.int64)
-            for i, st in enumerate(active):
-                if st is not None:
-                    cur[i] = st["cur"]
-            feeds = {"tokens": cur.reshape(self.slots, 1),
-                     "positions": lens.reshape(self.slots, 1).astype(
-                         np.int64),
-                     "lengths": lens.copy(),
-                     "seed": np.array([self._seed_ctr], np.int64)}
-            self._seed_ctr += 1
-            feeds.update(zip(self._cache_feed_names, caches))
-            try:
-                t0 = time.perf_counter()
-                outs = dexe(feeds, self.predictor._state)
-                nxt = np.asarray(outs[0]).astype(np.int64)
-            except Exception as e:
-                # a decode step that dies (device OOM, donated-buffer
-                # misuse, backend loss) must not kill the serving loop
-                # and strand every future: fail the ACTIVE sequences
-                # (their cache state is no longer trustworthy), free the
-                # slots, keep serving the queue
-                caches = self._fail_all_active(active, lens, e)
-                self._set_slot_gauges(0)
-                continue
-            obs.DECODE_STEP_MS.observe((time.perf_counter() - t0) * 1e3,
-                                       stage="step")
-            self.step_active_counts.append(n_active)
-            caches = list(outs[2:])
-            emitted = 0
-            for i, st in enumerate(active):
-                if st is None:
+                free = self.slots - n_active
+                batch = []
+                drain = not closed and free > 0 and (
+                    self.continuous or n_active == 0)
+                if not closed and n_active == 0 and not pending:
+                    # idle: park on the channel until work (or close)
+                    with _tracing.phase("decode.loop.park"):
+                        batch = self._chan.recv_batch(self.slots, None)
+                    drain = False
+                with _tracing.phase("decode.loop.recv"):
+                    if drain:
+                        # mid-flight admission: non-blocking drain,
+                        # bounded by the free slots (leaving the rest
+                        # in the channel keeps submit()'s backpressure
+                        # intact)
+                        batch = self._chan.recv_batch(free, 0)
+                    if batch is None:
+                        closed = True
+                        batch = []
+                    for msg in batch:
+                        try:
+                            rid, prompt, max_new, seed = \
+                                self._decode_request(msg)
+                            if len(prompt) + max_new > self.seq:
+                                raise ValueError(
+                                    "prompt %d + max_new %d exceeds the "
+                                    "server's %d-token slab"
+                                    % (len(prompt), max_new, self.seq))
+                            if len(prompt) < 1:
+                                raise ValueError("empty prompt")
+                            pending.append((rid, prompt, max_new, seed))
+                        except Exception as e:
+                            try:
+                                self._fail(_rio.frame_tag(bytes(msg)), e)
+                            except Exception:
+                                pass
+                admit_ok = (free > 0 and pending
+                            and (self.continuous or n_active == 0))
+                if admit_ok:
+                    with _tracing.phase("decode.loop.admit",
+                                        admitted=min(free, len(pending))):
+                        caches = self._admit(pending, caches, lens, active)
+                    n_active = sum(1 for a in active if a is not None)
+                self._set_slot_gauges(n_active)
+                if n_active == 0:
+                    if closed and not pending:
+                        return
                     continue
-                lens[i] += 1
-                tok = int(nxt[i])
-                st["generated"].append(tok)
-                st["cur"] = tok
-                st["count"] += 1
-                emitted += 1
-                if (self.eos_id is not None and tok == self.eos_id) \
-                        or st["count"] >= st["max_new"] \
-                        or lens[i] + 1 >= self.seq:
-                    self._retire(st)
-                    active[i] = None
-                    lens[i] = 0
-            obs.DECODE_TOKENS.inc(emitted, kind="decode")
-            # refresh occupancy AFTER retirements: an idle server must
-            # scrape as 0 active, not as its pre-retirement count (the
-            # next iteration may park on the channel before updating)
-            self._set_slot_gauges(
-                sum(1 for a in active if a is not None))
+                if self.speculative:
+                    caches = self._spec_round(drexe, vexe, caches, lens,
+                                              active, n_active)
+                    self._set_slot_gauges(
+                        sum(1 for a in active if a is not None))
+                    continue
+                # one token across every active slot
+                with _tracing.phase("decode.loop.feeds"):
+                    cur = np.zeros((self.slots,), np.int64)
+                    for i, st in enumerate(active):
+                        if st is not None:
+                            cur[i] = st["cur"]
+                    feeds = {"tokens": cur.reshape(self.slots, 1),
+                             "positions": lens.reshape(
+                                 self.slots, 1).astype(np.int64),
+                             "lengths": lens.copy(),
+                             "seed": np.array([self._seed_ctr], np.int64)}
+                    self._seed_ctr += 1
+                    feeds.update(zip(self._cache_feed_names, caches))
+                try:
+                    # dispatch and fetch are DECODE_STEP_MS's "step"
+                    # stage, split: a traced iteration hands the
+                    # histogram its own clock readings
+                    with _tracing.phase(
+                            "decode.loop.dispatch",
+                            **self._step_counts(lens, n_active)) as ph:
+                        t0 = ph.t0 or time.perf_counter()
+                        outs = dexe(feeds, self.predictor._state)
+                    with _tracing.phase("decode.loop.fetch") as ph:
+                        # the host waits here for the step
+                        nxt = np.asarray(outs[0]).astype(np.int64)
+                    t1 = ph.t1 or time.perf_counter()
+                except Exception as e:
+                    # a decode step that dies (device OOM, donated-
+                    # buffer misuse, backend loss) must not kill the
+                    # serving loop and strand every future: fail the
+                    # ACTIVE sequences (their cache state is no longer
+                    # trustworthy), free the slots, keep serving the
+                    # queue
+                    caches = self._fail_all_active(active, lens, e)
+                    self._set_slot_gauges(0)
+                    continue
+                obs.DECODE_STEP_MS.observe((t1 - t0) * 1e3, stage="step")
+                with _tracing.phase("decode.loop.retire"):
+                    self.step_active_counts.append(n_active)
+                    caches = list(outs[2:])
+                    emitted = 0
+                    for i, st in enumerate(active):
+                        if st is None:
+                            continue
+                        lens[i] += 1
+                        tok = int(nxt[i])
+                        st["generated"].append(tok)
+                        st["cur"] = tok
+                        st["count"] += 1
+                        emitted += 1
+                        if (self.eos_id is not None
+                                and tok == self.eos_id) \
+                                or st["count"] >= st["max_new"] \
+                                or lens[i] + 1 >= self.seq:
+                            self._retire(st)
+                            active[i] = None
+                            lens[i] = 0
+                    obs.DECODE_TOKENS.inc(emitted, kind="decode")
+                    # refresh occupancy AFTER retirements: an idle
+                    # server must scrape as 0 active, not as its
+                    # pre-retirement count (the next iteration may park
+                    # on the channel before updating)
+                    self._set_slot_gauges(
+                        sum(1 for a in active if a is not None))

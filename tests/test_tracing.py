@@ -268,3 +268,386 @@ def test_crash_requeue_keeps_trace_alive(model):
     replies = sum(1 for spans in by_tid.values()
                   for s in spans if s["name"] == "router.reply")
     assert replies == 40
+
+
+# -- process-scoped phases (ISSUE 24) --------------------------------------
+#
+# `tracing.phase` is the one span primitive for work that belongs to no
+# request: the outermost phase of a thread is one record of the
+# recorder's process ring, the phases opened inside it add their self
+# time to it, and each is a `jax.profiler.TraceAnnotation("ptpu.<name>")`
+# on the profiler's clock. `DecodeServer._loop` opens one per boundary.
+
+def _iters():
+    return [s for s in tracing.get_recorder().spans()
+            if s["name"] == "decode.loop.iter"]
+
+
+def _phase(record, name, parent="decode.loop.iter"):
+    return next(p for p in record["phases"]
+                if p["name"] == name and p["parent"] == parent)
+
+
+def test_phase_off_is_one_shared_noop_and_records_nothing():
+    a = tracing.phase("decode.loop.iter")
+    b = tracing.phase("decode.loop.dispatch", active=8, attended=4000)
+    assert a is b and a.t0 is None and a.t1 is None
+    with a as opened:
+        with tracing.phase("decode.loop.fetch") as inner:
+            assert inner is a
+    assert opened is a
+    snap = tracing.snapshot()
+    assert snap["spans"] == [] and snap["recorded"] == 0
+
+
+def test_nested_phases_give_one_record_with_self_times_and_parents():
+    tracing.set_sample_rate(1.0)
+    with tracing.phase("decode.loop.iter") as it:
+        assert it.t0 is not None
+        with tracing.phase("decode.loop.admit", admitted=2):
+            time.sleep(0.004)
+            with tracing.phase("decode.loop.prefill"):
+                time.sleep(0.003)
+            with tracing.phase("decode.loop.scatter"):
+                time.sleep(0.002)
+        for _ in range(2):  # one name twice in an iteration: summed
+            with tracing.phase("decode.loop.dispatch", active=3,
+                               attended=40) as ph:
+                time.sleep(0.001)
+            assert ph.t1 > ph.t0
+    rec, = tracing.get_recorder().spans()
+    assert rec["name"] == "decode.loop.iter"
+    assert rec["trace_id"] == tracing.process_trace_id()
+    # the counts of the phases inside land on the record
+    assert (rec["active"], rec["attended"], rec["admitted"]) == (3, 40, 2)
+    by = {p["name"]: p for p in rec["phases"]}
+    assert by["decode.loop.prefill"]["parent"] == "decode.loop.admit"
+    assert by["decode.loop.scatter"]["parent"] == "decode.loop.admit"
+    assert by["decode.loop.admit"]["parent"] == "decode.loop.iter"
+    assert by["decode.loop.dispatch"]["n"] == 2
+    admit = by["decode.loop.admit"]
+    assert admit["ms"] >= 9.0 and 3.5 <= admit["self_ms"] < admit["ms"] - 4.5
+    selfs = sum(p["self_ms"] for p in rec["phases"])
+    assert selfs <= rec["dur_ms"] + 1e-3
+    assert selfs + rec["self_ms"] == pytest.approx(rec["dur_ms"], abs=0.01)
+    assert all(p["end_ms"] <= rec["dur_ms"] + 1e-3 for p in rec["phases"])
+
+
+def test_an_iteration_is_traced_whole_or_not_at_all(monkeypatch):
+    tracing.set_sample_rate(0.5)
+    draws = iter([0.9, 0.1])  # the first root loses the draw
+    monkeypatch.setattr(tracing._rand, "random", lambda: next(draws))
+    for _ in range(2):
+        with tracing.phase("decode.loop.iter"):
+            # no draw of its own: a lost root silences what is inside
+            with tracing.phase("decode.loop.fetch"):
+                pass
+    rec, = tracing.get_recorder().spans()
+    assert [p["name"] for p in rec["phases"]] == ["decode.loop.fetch"]
+
+
+def test_iteration_records_never_evict_a_request_span():
+    rec = tracing.TraceRecorder(capacity=16, process_capacity=4096)
+    rec.record("t1", "client.submit", rid=1)
+    for i in range(10_000):
+        rec.record_process("decode.loop.iter", dur_ms=1.0, active=8)
+    for i in range(20):
+        rec.record("t2", "stage%d" % i)
+    snap = rec.snapshot()
+    assert snap["rings"]["process"] == {
+        "capacity": 4096, "recorded": 10_000, "dropped": 10_000 - 4096}
+    assert snap["rings"]["request"] == {
+        "capacity": 16, "recorded": 21, "dropped": 5}
+    assert snap["recorded"] == 10_021
+    assert snap["dropped"] == 10_000 - 4096 + 5
+    # both rings in one seq-ordered list; the submit span outlived ten
+    # thousand iteration records and fell only to its own ring's spans
+    seqs = [s["seq"] for s in snap["spans"]]
+    assert seqs == sorted(seqs) and len(seqs) == 4096 + 16
+    assert [s["name"] for s in rec.spans("t2")][0] == "stage4"
+    # the default process ring holds a traced window's iterations
+    assert tracing.snapshot()["rings"]["process"]["capacity"] == 32768
+
+
+def test_train_steps_and_profiler_events_share_the_entry_point():
+    import sys
+
+    from paddle_tpu import profiler
+
+    sys.path.insert(0, "tools")
+    import trace_dump
+
+    tracing.set_sample_rate(1.0)
+    obs.TIMELINE.record_step("run", 12.5, steps=3)
+    profiler.start_profiler()
+    try:
+        profiler.record_event("compile", 0.25)
+    finally:
+        profiler._enabled = False
+    with tracing.phase("decode.loop.iter"):
+        with tracing.phase("decode.loop.fetch"):
+            time.sleep(0.001)
+    assert not hasattr(profiler, "timed")
+    snap = tracing.snapshot()
+    assert [s["name"] for s in snap["spans"]] == [
+        "train.step", "profiler.compile", "decode.loop.iter"]
+    assert snap["rings"]["process"]["recorded"] == 3
+    assert snap["rings"]["request"]["recorded"] == 0
+    assert {s["trace_id"] for s in snap["spans"]} == {
+        tracing.process_trace_id()}
+    # one waterfall for all three; the record's phases are child slices
+    merged = tracing.merge_snapshots([snap])
+    text = trace_dump.render_text(merged)
+    assert "train.step" in text and "profiler.compile" in text
+    assert "  decode.loop.fetch" in text
+    slices = [e for e in trace_dump.to_chrome(merged)["traceEvents"]
+              if e.get("ph") == "X"]
+    assert [e["name"] for e in slices][-2:] == ["decode.loop.iter",
+                                                "decode.loop.fetch"]
+    it, fetch = slices[-2:]
+    assert it["ts"] <= fetch["ts"]
+    assert fetch["ts"] + fetch["dur"] <= it["ts"] + it["dur"] + 1.0
+
+
+# -- the decode loop's phases ----------------------------------------------
+
+DV, DL, DH, DD, DI_, DML = 37, 2, 2, 16, 32, 64
+
+
+@pytest.fixture(scope="module")
+def decode_dir(tmp_path_factory):
+    """A tiny LM exported for decode serving (random weights do)."""
+    from paddle_tpu.models import transformer as T
+    from paddle_tpu.serving.decode import DecodeConfig, save_decode_model
+
+    d = str(tmp_path_factory.mktemp("trace_decode_model"))
+    prog, startup = fluid.Program(), fluid.Program()
+    prog.random_seed = startup.random_seed = 7
+    with fluid.program_guard(prog, startup), fluid.unique_name.guard():
+        ids = layers.data(name="ids", shape=[2, 16], dtype="int64",
+                          append_batch_size=False)
+        T.transformer_lm(ids, ids, DV, n_layer=DL, n_head=DH, d_model=DD,
+                         d_inner=DI_, dropout_rate=0.0, max_len=DML,
+                         fused_head=False)
+    exe = fluid.Executor(fluid.CPUPlace())
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+        save_decode_model(d, DecodeConfig(
+            vocab_size=DV, n_layer=DL, n_head=DH, d_model=DD, d_inner=DI_,
+            max_len=DML), exe, scope=scope)
+    return d
+
+
+def _serve(decode_dir, prompts, max_new, **kw):
+    """Submit everything, then start: admission is one wave, so the
+    loop's course is the same every time."""
+    from paddle_tpu.serving.decode import DecodePredictor, DecodeServer
+
+    srv = DecodeServer(DecodePredictor(decode_dir), slots=4, max_seq=32,
+                       max_new_tokens=16, **kw)
+    futs = [srv.submit((p, np.array([n], np.int64)))
+            for p, n in zip(prompts, max_new)]
+    srv.start()
+    outs = [f.result(timeout=120) for f in futs]
+    srv.stop()
+    return srv, outs
+
+
+def test_decode_server_gives_one_record_per_loop_iteration(decode_dir):
+    r = np.random.RandomState(3)
+    plens, max_new = [5, 9, 3], [4, 7, 2]
+    prompts = [r.randint(1, DV, n).astype(np.int64) for n in plens]
+    _serve(decode_dir, prompts[:1], max_new[:1])  # compile, untraced
+    tracing.reset()
+    before = obs.DECODE_STEP_MS.stats(stage="step")
+    tracing.set_sample_rate(1.0)
+    srv, outs = _serve(decode_dir, prompts, max_new)
+    tracing.set_sample_rate(0.0)
+    assert [len(o[0]) for o in outs] == max_new
+    steps = [s for s in _iters() if "active" in s]
+    counts = list(srv.step_active_counts)
+    # one record per iteration that stepped, in order, with its counts
+    assert [s["active"] for s in steps] == counts == [3, 2, 2, 1, 1, 1]
+    # `attended`: the K/V rows the step's attention reads = each live
+    # slot's length with the row this step appends. The admission's
+    # first token is the prompt's own last logits, so step k of a
+    # sequence attends len(prompt) + k rows.
+    want = []
+    for k in range(1, 7):
+        want.append(sum(n + k for n, m in zip(plens, max_new) if k < m))
+    assert [s["attended"] for s in steps] == want
+    first = steps[0]
+    assert first["admitted"] == 3
+    for name in ("decode.loop.recv", "decode.loop.admit",
+                 "decode.loop.feeds", "decode.loop.dispatch",
+                 "decode.loop.fetch", "decode.loop.retire"):
+        assert _phase(first, name)["n"] == 1
+    for name in ("decode.loop.prefill", "decode.loop.first_token",
+                 "decode.loop.scatter"):
+        assert _phase(first, name, "decode.loop.admit")["ms"] > 0
+    # started with its requests queued, the idle server's first
+    # iteration still parks on the channel (and returns at once)
+    assert any(p["name"] == "decode.loop.park" for s in _iters()
+               for p in s["phases"])
+    # dispatch + fetch are the histogram's "step" stage, split in two
+    after = obs.DECODE_STEP_MS.stats(stage="step")
+    assert after["count"] - before["count"] == len(steps)
+    hist_ms = after["sum"] - before["sum"]
+    split_ms = sum(_phase(s, "decode.loop.dispatch")["self_ms"]
+                   + _phase(s, "decode.loop.fetch")["self_ms"]
+                   for s in steps)
+    assert split_ms == pytest.approx(hist_ms, rel=0.05)
+    # request spans and iteration records side by side, nothing dropped
+    snap = tracing.snapshot()
+    assert snap["dropped"] == 0
+    assert snap["rings"]["request"]["recorded"] == 3 * 3  # submit/admit/retire
+
+
+def test_phases_land_on_the_profilers_host_plane(decode_dir, tmp_path):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    r = np.random.RandomState(4)
+    prompts = [r.randint(1, DV, n).astype(np.int64) for n in (6, 4)]
+    _serve(decode_dir, prompts[:1], [2])  # compile outside the trace
+    tracing.reset()
+    tracing.set_sample_rate(1.0)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _serve(decode_dir, prompts, [3, 3])
+    finally:
+        jax.profiler.stop_trace()
+        tracing.set_sample_rate(0.0)
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("ptpu.decode.loop."):
+                    events.setdefault(e.name[len("ptpu.decode.loop."):],
+                                      []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns,
+                         dict(e.stats), line.name))
+    for name in ("iter", "recv", "admit", "prefill", "first_token",
+                 "scatter", "feeds", "dispatch", "fetch", "retire"):
+        assert events.get(name), name
+
+    def inside(child, parents):
+        return any(p[0] <= child[0] and child[1] <= p[1]
+                   and p[3] == child[3] for p in parents)
+
+    # nested as the loop nests them, on the loop's one thread
+    for name in ("recv", "admit", "feeds", "dispatch", "fetch", "retire"):
+        assert all(inside(e, events["iter"]) for e in events[name]), name
+    for name in ("prefill", "first_token", "scatter"):
+        assert all(inside(e, events["admit"]) for e in events[name]), name
+    steps = [s for s in _iters() if "active" in s]
+    stats = [e[2] for e in sorted(events["dispatch"])]
+    assert [(s["active"], s["attended"]) for s in stats] == [
+        (s["active"], s["attended"]) for s in steps]
+    assert dict(events["admit"][0][2]) == {"admitted": 2}
+
+
+# -- stable names on the device side ----------------------------------------
+
+def _lowered(fn, *args):
+    import jax
+
+    return jax.jit(fn).lower(*args).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("case", ["train-fused", "train-split",
+                                  "train-bhtd", "shard_map", "decode"])
+def test_lowered_text_names_the_kernels(case, monkeypatch):
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from paddle_tpu.ops import attention as A
+    from paddle_tpu.ops import kv_cache as KV
+
+    monkeypatch.setenv("PADDLE_TPU_FLASH_FUSED_BWD",
+                       "1" if case == "train-fused" else "0")
+    bwd = ([A.FLASH_BWD] if case == "train-fused"
+           else [A.FLASH_BWD_DQ, A.FLASH_BWD_DKV])
+    if case == "decode":
+        q = jnp.ones((2, 1, 2, 128), jnp.float32)
+        kv = jnp.ones((2, 128, 2, 128), jnp.float32)
+        text = _lowered(
+            lambda q, k, v, n: KV.pallas_decode_attention(
+                q, k, v, n, interpret=True),
+            q, kv, kv, jnp.array([5, 9], jnp.int32))
+        assert KV.DECODE_ATTN == "ptpu.decode_attn"
+        assert '/ptpu.decode_attn/' in text
+        return
+    if case == "train-bhtd":
+        kern = lambda q, k, v: A.pallas_flash_attention(  # noqa: E731
+            q, k, v, causal=True, block_q=128, block_k=128, interpret=True)
+        x = jnp.ones((2, 2, 256, 64), jnp.bfloat16)
+    else:
+        kern = lambda q, k, v: A.pallas_flash_attention_bthd(  # noqa: E731
+            q, k, v, causal=True, block_q=128, block_k=128, interpret=True)
+        x = jnp.ones((2, 256, 2, 128), jnp.bfloat16)
+    if case == "shard_map":
+        mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("dp", "mp"))
+        spec = P("dp", None, "mp", None)
+        inner = kern
+        kern = lambda q, k, v: jax.shard_map(  # noqa: E731
+            inner, mesh=mesh, in_specs=(spec,) * 3, out_specs=spec,
+            check_vma=False)(q, k, v)
+
+    def step(q, k, v):
+        return jax.value_and_grad(
+            lambda q, k, v: jnp.sum(kern(q, k, v).astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    text = _lowered(step, x, x, x)
+    assert (A.FLASH_FWD, A.FLASH_BWD, A.FLASH_BWD_DQ, A.FLASH_BWD_DKV) == (
+        "ptpu.flash_fwd", "ptpu.flash_bwd", "ptpu.flash_bwd_dq",
+        "ptpu.flash_bwd_dkv")
+    if case == "shard_map":
+        # the body is a function of its own, its names relative to the
+        # `shard_map` call: the innermost scope is the kernel's alone,
+        # and the compiler names a Mosaic call after that
+        assert 'loc("ptpu.flash_fwd/pallas_call"' in text
+        assert all('loc("%s/pallas_call"' % b in text for b in bwd)
+    else:
+        # ... which autodiff wraps: the accepted readers' `^jvp_` and
+        # `^transpose_jvp` still hold on the chip
+        assert "jvp(ptpu.flash_fwd)/" in text
+        assert all("transpose(jvp(%s))/" % b in text for b in bwd)
+
+
+def test_decode_executables_carry_distinct_module_names(decode_dir):
+    import re
+
+    from paddle_tpu.serving.decode import (DecodePredictor,
+                                           _executable_name)
+
+    pred = DecodePredictor(decode_dir)
+    names = {}
+    for key in [("prefill", 1, 16, {}), ("prefill", 2, 32, {}),
+                ("decode", 4, 32, {}), ("decode", 2, 32, {}),
+                ("verify", 4, 32, {"window": 3}),
+                ("draft", 4, 32, {})]:
+        kind, batch, seq, kw = key
+        exe, _ = pred.acquire(kind, batch, seq, **kw)
+        names[key[:3]] = re.match(r"HloModule (\S+?),",
+                                  exe.as_text()).group(1)
+    assert names == {
+        ("prefill", 1, 16): "jit_ptpu_prefill_b1_s16",
+        ("prefill", 2, 32): "jit_ptpu_prefill_b2_s32",
+        ("decode", 4, 32): "jit_ptpu_decode_b4_s32",
+        ("decode", 2, 32): "jit_ptpu_decode_b2_s32",
+        ("verify", 4, 32): "jit_ptpu_verify_b4_s32_w3",
+        ("draft", 4, 32): "jit_ptpu_draft_b4_s32_l%d" % pred.draft_n_layer}
+    assert _executable_name("decode", 8, 2048, "topk", "int8") == \
+        "ptpu_decode_b8_s2048_topk_kv8"
+    assert _executable_name("prefill", 1, 4096, ring=True) == \
+        "ptpu_prefill_b1_s4096_ring"

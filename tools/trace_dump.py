@@ -15,7 +15,10 @@ the fly. Three output modes:
   time and total extent, plus the fleet ring accounting.
 * ``--chrome`` — Chrome trace-event JSON (the ``traceEvents`` array
   format): load it in Perfetto / chrome://tracing and every replica is
-  a process row, every trace a thread row, every span a slice.
+  a process row, every trace a thread row, every span a slice. A
+  process-scoped record with phases (``tracing.phase``: one
+  ``decode.loop.iter`` per loop iteration) renders its phases as child
+  slices, here and in the waterfall.
 
 Stays OFF the jax import path entirely (the metrics_dump --merge
 trick): rendering is pure dict arithmetic and the observability
@@ -45,7 +48,7 @@ SCHEMA = "trace_dump/1"
 # span keys that are structure, not user attrs (everything else prints
 # in the waterfall's attr column)
 _CORE_KEYS = frozenset(("trace_id", "name", "ts", "dur_ms", "seq",
-                        "replica"))
+                        "replica", "phases", "depth"))
 
 
 def _import_tracing():
@@ -140,6 +143,37 @@ def group_traces(merged: Dict) -> List[Dict]:
     return traces
 
 
+def phase_slices(span: Dict) -> List[Dict]:
+    """Child slices of one process-scoped record (``tracing.phase``: a
+    ``decode.loop.iter`` carries ``phases``, each with its summed
+    ``ms`` and the offset ``end_ms`` at which it last ended): one
+    pseudo-span per phase, placed so that it ends where the phase
+    did, ``depth`` levels under the record. A phase that ran ``n`` > 1
+    times shows as one slice of its summed duration."""
+    depth = {span["name"]: 0}
+    out = []
+    # parents close after their children, so walk the list backwards
+    for ph in reversed(span.get("phases") or ()):
+        d = depth.get(ph["parent"], 0) + 1
+        depth[ph["name"]] = d
+        out.append({
+            "trace_id": span["trace_id"], "name": ph["name"],
+            "ts": span["ts"] + (ph["end_ms"] - ph["ms"]) / 1e3,
+            "dur_ms": ph["ms"], "seq": span.get("seq", 0),
+            "replica": span.get("replica", ""), "depth": d,
+            "self_ms": ph["self_ms"], "n": ph["n"]})
+    out.sort(key=lambda s: (s["ts"], s["depth"]))
+    return out
+
+
+def with_phase_slices(spans: List[Dict]) -> List[Dict]:
+    out = []
+    for s in spans:
+        out.append(s)
+        out.extend(phase_slices(s))
+    return out
+
+
 def _attr_str(span: Dict) -> str:
     attrs = {k: v for k, v in span.items() if k not in _CORE_KEYS}
     if not attrs:
@@ -161,7 +195,7 @@ def render_text(merged: Dict, width: int = 32) -> str:
         lines.append("trace %s  (%d spans, %.3f ms)"
                      % (tr["trace_id"], len(tr["spans"]),
                         tr["total_ms"]))
-        for s in tr["spans"]:
+        for s in with_phase_slices(tr["spans"]):
             off_ms = (s["ts"] - tr["start_ts"]) * 1e3
             dur = float(s.get("dur_ms", 0.0))
             lo = int(round(off_ms / extent * width))
@@ -173,7 +207,8 @@ def render_text(merged: Dict, width: int = 32) -> str:
                 bar = " " * lo + "|"
             lines.append(
                 "  +%9.3fms %-16s %-8s %9.3fms  [%-*s] %s"
-                % (off_ms, s["name"], s.get("replica", "") or "router",
+                % (off_ms, "  " * s.get("depth", 0) + s["name"],
+                   s.get("replica", "") or "router",
                    dur, width, bar, _attr_str(s)))
     return "\n".join(lines)
 
@@ -197,7 +232,7 @@ def to_chrome(merged: Dict) -> Dict:
     events = []
     pids: Dict[str, int] = {}
     tids: Dict[str, int] = {}
-    for s in merged.get("spans", ()):
+    for s in with_phase_slices(merged.get("spans", ())):
         replica = s.get("replica", "") or "router"
         if replica not in pids:
             pids[replica] = len(pids) + 1
