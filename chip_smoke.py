@@ -356,6 +356,10 @@ def phase_serve(cfg, place):
     # before start() so admission is one burst and the run is repeatable
     srv = DecodeServer(pred, slots=slots, max_seq=cfg["seq"],
                        max_new_tokens=new)
+    if cfg["require_tpu"]:
+        assert srv._stream_rows, (
+            "the server counts whole slabs as streamed: its decode step "
+            "does not run the in-place kernel")
     futs = [srv.submit((p,)) for p in prompts]
     t0 = time.perf_counter()
     srv.start()
@@ -380,7 +384,8 @@ def phase_serve(cfg, place):
     _emit("serve", prompt_lens=[len(p) for p in prompts], new_tokens=new,
           generate_s=generate_s, server_s=serve_s,
           rollout_tokens_agreeing=agree, decode_tpu_custom_calls=n_kernels,
-          decode_step_donated=donated, first_predictor_traces=pred.traces,
+          decode_step_donated=donated, decode_stream_rows=srv._stream_rows,
+          first_predictor_traces=pred.traces,
           second_predictor_traces=pred2.traces, ok=True)
     shutil.rmtree(model_dir, ignore_errors=True)
 

@@ -19,12 +19,21 @@ like the `Lengths` input of fused_attention.
 Three ops:
 
 - ``decode_attention``: Q (B, 1, H, Dh) x cache K/V (B, S, H, Dh) with
-  Lengths (B,) -> (B, 1, H, Dh). A Pallas TPU kernel (one grid cell per
-  (batch, head); online softmax over KV blocks in VMEM, the
-  single-query sibling of ops/attention.py's ``_mha_fwd_kernel``) with
-  the exact pure-``lax`` path on CPU/GPU and non-aligned shapes; the
-  kernel also runs under ``interpret=True`` so parity is testable off
-  TPU.
+  Lengths (B,) -> (B, 1, H, Dh). A Pallas TPU kernel that reads the
+  slab WHERE IT LIES: the (B, S*H, Dh) view of the feed (a bitcast on
+  the chip when the heads fill whole 8-row sublane tiles of a 32-bit
+  type), one grid cell per (slot, sequence block), every head of the
+  block in one contiguous copy, a head's rows picked by a strided
+  load, online softmax over the slot's blocks in VMEM scratch. The
+  lengths are scalar-prefetched, so the block index stops at a slot's
+  last live block: dead rows are neither fetched nor computed. Slabs
+  without that free view (heads not a multiple of 8; 16- and 8-bit
+  types, whose packed rows Mosaic cannot load strided) run the older
+  kernel, one grid cell per (batch, head) over the (B, S, H*Dh) view,
+  which costs a physical copy of the slab a call. Shape and dtype
+  alone choose (``decode_block_rows``). The exact pure-``lax`` path
+  serves CPU/GPU and non-aligned shapes; the kernels also run under
+  ``interpret=True`` so parity is testable off TPU.
 - ``cache_append``: scatter one new K or V row per sequence at its
   current length (functional update — callers thread the slab through
   the step function; XLA aliases it in place under donation).
@@ -42,6 +51,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..framework.scope import current_device
 from .attention import _fit_block, _tpu_params, named_pallas_call
@@ -86,67 +96,168 @@ def decode_attention_reference(q, k_cache, v_cache, lengths, scale=None):
     return out[:, None].astype(q.dtype)
 
 
+def _online_softmax_row(q, kb, vb, col0, length, acc, m, l):
+    """One KV block of the single-row online softmax (the body of
+    ops/attention.py's ``_mha_fwd_kernel`` at block_q == 1). q (1, D)
+    pre-scaled; kb/vb (BS, D), rows col0.. of the slot; acc (1, D), m
+    and l (1, 1). Rows at or past ``length`` are masked out."""
+    s = jnp.dot(q, kb.T, preferred_element_type=jnp.float32)  # (1, BS)
+    live = col0 + lax.broadcasted_iota(jnp.int32, s.shape, 1) < length
+    s = jnp.where(live, s, _NEG)
+    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+    corr = jnp.exp(m - m_new)
+    l = l * corr + jnp.sum(p, axis=1, keepdims=True)
+    acc = acc * corr + jnp.dot(p.astype(vb.dtype), vb,
+                               preferred_element_type=jnp.float32)
+    return acc, m_new, l
+
+
 def _decode_attn_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, *, block_s,
                         seq_s):
     """One (batch, head) grid cell: the single query row attends its
     slab. q_ref (1, 1, D) pre-scaled; k/v (1, S, D) — the head's column
-    slice of the BTHD slab; len_ref (1, 1) int32 in SMEM-like lane; the
-    online-softmax loop is ops/attention.py's ``_mha_fwd_kernel`` body
-    at block_q == 1."""
+    slice of the (B, S, H*D) view of the slab; len_ref (1, 1, 1) int32."""
     q = q_ref[0]                       # (1, D), pre-scaled
     length = len_ref[0, 0, 0]
     nblk = seq_s // block_s
 
     def blk(j, carry):
-        acc, m, l = carry
         kb = k_ref[0, pl.ds(j * block_s, block_s), :]
         vb = v_ref[0, pl.ds(j * block_s, block_s), :]
-        s = jnp.dot(q, kb.T, preferred_element_type=jnp.float32)  # (1, BS)
-        col = j * block_s + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(col < length, s, _NEG)
-        m_new = jnp.maximum(m, jnp.max(s, axis=1))
-        p = jnp.where(col < length, jnp.exp(s - m_new[:, None]), 0.0)
-        corr = jnp.exp(m - m_new)
-        l = l * corr + jnp.sum(p, axis=1)
-        acc = acc * corr[:, None] + jnp.dot(
-            p.astype(vb.dtype), vb, preferred_element_type=jnp.float32)
-        return acc, m_new, l
+        return _online_softmax_row(q, kb, vb, j * block_s, length, *carry)
 
-    d = q.shape[-1]
-    init = (jnp.zeros((1, d), jnp.float32),
-            jnp.full((1,), _NEG, jnp.float32),
-            jnp.zeros((1,), jnp.float32))
+    init = (jnp.zeros((1, q.shape[-1]), jnp.float32),
+            jnp.full((1, 1), _NEG, jnp.float32),
+            jnp.zeros((1, 1), jnp.float32))
     # KV blocks at or past this slot's length contribute nothing — stop
-    # the loop there (decode cost tracks the LIVE prefix, not the slab)
+    # the loop there (the whole column is fetched all the same)
     upper = lax.min((length + block_s - 1) // block_s, nblk)
     acc, m, l = lax.fori_loop(0, upper, blk, init)
-    o_ref[0] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+def _decode_attn_inplace_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
+                                acc_ref, m_ref, l_ref, *, block_s, n_head):
+    """One (slot, sequence block) grid cell over the slab where it lies.
+    len_ref (B,) int32, scalar-prefetched; q_ref/o_ref (1, 1, H, D), q
+    pre-scaled; k/v (1, BS * H, D): rows [j*BS, (j+1)*BS) of the slot
+    with the heads interleaved, row r of head h at sublane r * H + h, so
+    a head's block is a strided load. The accumulators (H, D), (H, 1),
+    (H, 1) live in VMEM scratch across the slot's blocks (an "arbitrary"
+    axis) and are written out at the last one. Blocks past the slot's
+    length are neither computed (the ``pl.when``) nor fetched (the index
+    map repeats the last live block, and a block whose index did not
+    change is not copied again)."""
+    j = pl.program_id(1)
+    length = len_ref[pl.program_id(0)]
+
+    @pl.when(j == 0)
+    def _():
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+        m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+
+    @pl.when(j * block_s < length)
+    def _():
+        for h in range(n_head):
+            rows = pl.ds(h, block_s, stride=n_head)
+            hh = slice(h, h + 1)
+            acc_ref[hh, :], m_ref[hh, :], l_ref[hh, :] = _online_softmax_row(
+                q_ref[0, 0, hh, :], k_ref[0, rows, :], v_ref[0, rows, :],
+                j * block_s, length, acc_ref[hh, :], m_ref[hh, :],
+                l_ref[hh, :])
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                       ).astype(o_ref.dtype)
+
+
+# a K or V block of the in-place kernel: 128 rows of the 32 x 128 float32
+# slab, a whole MXU tile a head (at 64 rows a call takes 0.30 ms where it
+# takes 0.24; PERF.md, PR 25). Both slabs' blocks, double-buffered, are
+# 8 MiB of the 16 MiB of VMEM a v5e kernel may use.
+_INPLACE_BLOCK_BYTES = 2 * 2**20
+
+
+def decode_block_rows(s, h, d, dtype, block_s=512):
+    """Sequence rows per block of the in-place kernel for (B, s, h, d)
+    slabs of ``dtype``, or None where the slab has no free (B, s*h, d)
+    view and the per-head kernel runs instead. The view is a bitcast on
+    the chip when the heads fill whole sublane tiles (8 rows of 32 bits:
+    8 heads of f32). Narrower types pack two or four rows a sublane, and
+    Mosaic has no strided load of them ("Strided load with non 32-bit
+    data"), so they keep the per-head kernel."""
+    if jnp.dtype(dtype).itemsize != 4 or h % 8:
+        return None
+    want = min(block_s, s, _INPLACE_BLOCK_BYTES // (h * d * 4))
+    rows = 8
+    while rows * 2 <= want:
+        rows *= 2
+    while rows > 8 and s % rows:
+        rows //= 2
+    return None if s % rows else rows
 
 
 def pallas_decode_attention(q, k_cache, v_cache, lengths, scale=None,
                             block_s=512, interpret=False):
     """Pallas decode attention over BTHD slabs; same contract as
-    ``decode_attention_reference``. Grid (B, H); each cell streams its
-    head's KV column blocks through VMEM with an online softmax —
-    no (B, H, S) score tensor in HBM. Requires S % block_s == 0 (the
-    dispatch shrinks block_s to fit)."""
+    ``decode_attention_reference``. An online softmax over KV blocks in
+    VMEM, no (B, H, S) score tensor in HBM, in one of two shapes chosen
+    from the slab's own shape and dtype (``decode_block_rows``):
+
+    - in place: the slab viewed (B, S*H, D), grid (B, S // block_s),
+      every head of a sequence block in one contiguous copy, dead
+      blocks skipped. No relayout of the slab anywhere in the step.
+    - per head: the slab viewed (B, S, H*D), grid (B, H), each cell its
+      head's whole column. On the chip that view is a physical copy of
+      the slab: the path of shapes the free view does not exist for.
+
+    Requires S % block_s == 0 (block_s is shrunk to fit)."""
     b, one, h, d = q.shape
     s = k_cache.shape[1]
     if scale is None:
         scale = 1.0 / math.sqrt(d)
+    qs = q * jnp.asarray(scale, q.dtype)
+    lens = lengths.reshape(-1).astype(jnp.int32)
+    rows = decode_block_rows(s, h, d, k_cache.dtype, block_s)
+    if rows is not None:
+        def kv_block(bi, j, lens_ref):
+            # past the slot's last live block: the same block again
+            last = jnp.maximum(lens_ref[bi] + rows - 1, rows) // rows - 1
+            return bi, jnp.minimum(j, last), 0
+
+        def qo_block(bi, j, lens_ref):
+            return bi, 0, 0, 0
+
+        kernel = functools.partial(_decode_attn_inplace_kernel,
+                                   block_s=rows, n_head=h)
+        return named_pallas_call(
+            DECODE_ATTN, kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(b, s // rows),
+                in_specs=[
+                    pl.BlockSpec((1, 1, h, d), qo_block),
+                    pl.BlockSpec((1, rows * h, d), kv_block),
+                    pl.BlockSpec((1, rows * h, d), kv_block),
+                ],
+                out_specs=pl.BlockSpec((1, 1, h, d), qo_block),
+                scratch_shapes=[pltpu.VMEM((h, d), jnp.float32),
+                                pltpu.VMEM((h, 1), jnp.float32),
+                                pltpu.VMEM((h, 1), jnp.float32)]),
+            out_shape=jax.ShapeDtypeStruct((b, 1, h, d), q.dtype),
+            interpret=interpret,
+            **_tpu_params("parallel", "arbitrary"),
+        )(lens, qs, k_cache.reshape(b, s * h, d),
+          v_cache.reshape(b, s * h, d))
     block_s = _fit_block(s, block_s)
     if s % block_s:
         raise ValueError("slab length %d must divide block_s %d"
                          % (s, block_s))
-    qs = (q * jnp.asarray(scale, q.dtype)).reshape(b, 1, h * d)
-    # (B, 1, 1): singleton minor block dims are FULL dims, which Mosaic's
-    # block-shape tiling accepts (the _lse_spec_bthd layout lesson —
-    # a (1, 1) block under a B-sized second-minor dim is rejected)
-    lens = lengths.reshape(-1).astype(jnp.int32)[:, None, None]
     kernel = functools.partial(_decode_attn_kernel, block_s=block_s,
                                seq_s=s)
-    kf = k_cache.reshape(b, s, h * d)
-    vf = v_cache.reshape(b, s, h * d)
     out = named_pallas_call(
         DECODE_ATTN, kernel,
         grid=(b, h),
@@ -154,13 +265,17 @@ def pallas_decode_attention(q, k_cache, v_cache, lengths, scale=None,
             pl.BlockSpec((1, 1, d), lambda bi, hi: (bi, 0, hi)),
             pl.BlockSpec((1, s, d), lambda bi, hi: (bi, 0, hi)),
             pl.BlockSpec((1, s, d), lambda bi, hi: (bi, 0, hi)),
+            # (B, 1, 1): singleton minor block dims are FULL dims, which
+            # Mosaic's block-shape tiling accepts (a (1, 1) block under
+            # a B-sized second-minor dim is rejected)
             pl.BlockSpec((1, 1, 1), lambda bi, hi: (bi, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, d), lambda bi, hi: (bi, 0, hi)),
         out_shape=jax.ShapeDtypeStruct((b, 1, h * d), q.dtype),
         interpret=interpret,
         **_tpu_params("parallel", "parallel"),
-    )(qs, kf, vf, lens)
+    )(qs.reshape(b, 1, h * d), k_cache.reshape(b, s, h * d),
+      v_cache.reshape(b, s, h * d), lens[:, None, None])
     return out.reshape(b, 1, h, d)
 
 
@@ -173,6 +288,15 @@ def _use_pallas_decode(s: int, d: int) -> bool:
     if current_device().platform != "tpu":
         return False
     return d % 128 == 0 and s % 128 == 0 and s >= 128
+
+
+def decode_stream_rows(s, h, d, dtype, block_s=512):
+    """Rows a block of ``decode_attention`` brings in for (B, s, h, d)
+    slabs on the device a step traced now is bound for, or None where
+    it reads whole slabs (the lax path; the per-head kernel)."""
+    if not _use_pallas_decode(s, d):
+        return None
+    return decode_block_rows(s, h, d, dtype, block_s)
 
 
 def decode_attention(q, k_cache, v_cache, lengths, scale=None,

@@ -55,6 +55,7 @@ from .. import observability as obs
 from ..observability import tracing as _tracing
 from ..runtime import aot_cache as _aot
 from ..framework.scope import current_device
+from ..ops import kv_cache as _KV
 from ..runtime import recordio as _rio
 
 __all__ = ["DecodeConfig", "save_decode_model", "DecodePredictor",
@@ -1030,6 +1031,14 @@ class DecodeServer:
                 names += ["kscale_%d" % i, "vscale_%d" % i]
         self._cache_feed_names = names
         self._cache_per_layer = 4 if self.kv_dtype == "int8" else 2
+        # rows a block of the float32 decode kernel brings in, or None
+        # where a step reads whole slabs (int8 slabs and the speculative
+        # verify window are lax paths of their own)
+        self._stream_rows = None
+        if self.kv_dtype == "float32" and not self.speculative:
+            with jax.default_device(predictor._device):  # as acquire()
+                self._stream_rows = _KV.decode_stream_rows(
+                    self.seq, cfg.n_head, cfg.d_head, jnp.float32)
 
     # -- submission (PredictorServer-compatible surface) -------------------
     def submit(self, sample: Sequence[np.ndarray]):
@@ -1571,13 +1580,22 @@ class DecodeServer:
                 lens[i] = 0
         return self._fresh_slabs()
 
-    @staticmethod
-    def _step_counts(lens, n_active):
+    def _step_counts(self, lens, n_active):
         """What a step's ``decode.loop.dispatch`` phase carries:
-        ``active`` live slots and ``attended``, the K/V rows its
-        attention reads: each live slot's length with the row this step
-        appends (free slots hold length 0)."""
-        return {"active": n_active, "attended": int(lens.sum()) + n_active}
+        ``active`` live slots; ``attended``, the K/V rows its attention
+        reads: each live slot's length with the row this step appends
+        (free slots hold length 0); and ``streamed``, the rows a layer's
+        attention brings in for them: every slot's length with that row,
+        rounded up to the decode kernel's block (a free slot still costs
+        one block), or the whole slab where the step does not run the
+        in-place kernel. ``attended / streamed`` is how much of what is
+        fetched is live."""
+        rows = self._stream_rows
+        streamed = (self.slots * self.seq if rows is None
+                    else int((lens // rows + 1).sum()) * rows)
+        return {"active": n_active,
+                "attended": int(lens.sum()) + n_active,
+                "streamed": streamed}
 
     def _spec_round(self, drexe, vexe, caches, lens, active, n_active):
         """One speculative round across every active slot: spec_k draft

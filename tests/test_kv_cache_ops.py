@@ -108,6 +108,61 @@ def test_pallas_decode_kernel_partial_block(qkv):
                                rtol=1e-5, atol=1e-5)
 
 
+# the in-place kernel: slabs with whole sublane tiles of heads (8 of f32)
+_IP_S, _IP_D, _IP_BLOCK = 128, 128, 32
+_IP_LENGTHS = {
+    # 0, 1, one less than / exactly / one more than a block boundary, full
+    "edges": [0, 1, _IP_BLOCK - 1, _IP_BLOCK, _IP_BLOCK + 1, _IP_S],
+    "second-boundary": [2 * _IP_BLOCK - 1, 2 * _IP_BLOCK, 2 * _IP_BLOCK + 1,
+                        _IP_S - 1, 7, 0],
+    "full": [_IP_S] * 6,
+    "empty": [0] * 6,
+}
+
+
+@pytest.mark.parametrize("lengths", list(_IP_LENGTHS.values()),
+                         ids=list(_IP_LENGTHS))
+@pytest.mark.parametrize("heads", [8, 32])
+def test_pallas_decode_kernel_in_place_parity(heads, lengths):
+    """The kernel that reads the (B, S, H, D) slab where it lies (grid
+    over sequence blocks, every head of a block in one copy, dead blocks
+    skipped), interpret mode against the lax reference."""
+    b = len(lengths)
+    assert kc.decode_block_rows(_IP_S, heads, _IP_D, np.float32,
+                                _IP_BLOCK) == _IP_BLOCK
+    q = jnp.asarray(_rand((b, 1, heads, _IP_D), 0))
+    k = jnp.asarray(_rand((b, _IP_S, heads, _IP_D), 1))
+    v = jnp.asarray(_rand((b, _IP_S, heads, _IP_D), 2))
+    lens = jnp.asarray(lengths, jnp.int32)
+    got = np.asarray(kc.pallas_decode_attention(
+        q, k, v, lens, interpret=True, block_s=_IP_BLOCK))
+    want = np.asarray(kc.decode_attention_reference(q, k, v, lens))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=2e-6)
+    for i, n in enumerate(lengths):
+        if n == 0:
+            np.testing.assert_array_equal(got[i], 0.0)
+
+
+@pytest.mark.parametrize("shape,dtype,block_s,want", [
+    # the serving cell's slab: 2 MiB blocks
+    ((2048, 32, 128), "float32", 512, 128),
+    ((1024, 8, 128), "float32", 512, 512),
+    ((1024, 8, 128), "float32", 64, 64),
+    ((2048, 24, 128), "float32", 512, 128),
+    ((96, 8, 128), "float32", 512, 32),
+    # heads that do not fill a sublane tile, packed rows: per-head kernel
+    ((32, 2, 8), "float32", 8, None),
+    ((1024, 12, 128), "float32", 512, None),
+    ((2048, 32, 128), "bfloat16", 512, None),
+    ((2048, 32, 128), "int8", 512, None),
+], ids=["cell", "h8", "h8-small-block", "h24", "s96", "h2", "h12", "bf16",
+        "int8"])
+def test_decode_block_rows_follows_shape_and_dtype(shape, dtype, block_s,
+                                                   want):
+    s, h, d = shape
+    assert kc.decode_block_rows(s, h, d, dtype, block_s) == want
+
+
 def test_cache_append():
     cache = _rand((B, S, H, D), 4)
     new = _rand((B, 1, H, D), 5)

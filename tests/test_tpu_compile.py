@@ -18,6 +18,7 @@ empty.
 from __future__ import annotations
 
 import os
+import re
 import sys
 
 import numpy as np
@@ -66,15 +67,19 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(fn, *avals, **jit_kw):
+def _compiled(fn, *avals, **jit_kw):
     compiled = jax.jit(fn, **jit_kw).lower(*avals).compile()
-    text = compiled.as_text()
-    assert "tpu_custom_call" in text, "no Mosaic kernel in the compiled text"
+    assert "tpu_custom_call" in compiled.as_text(), (
+        "no Mosaic kernel in the compiled text")
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert total < HBM_BYTES, "does not fit one chip: %r" % (mem,)
-    return text
+    return compiled
+
+
+def _compile(fn, *avals, **jit_kw):
+    return _compiled(fn, *avals, **jit_kw).as_text()
 
 
 # -- the kernels, alone -----------------------------------------------------
@@ -231,13 +236,69 @@ def test_training_step_compiles_on_2x2_mesh(topo, monkeypatch):
         D_MODEL, D_INNER // 2)
 
 
-@pytest.mark.parametrize("kind,batch,seq",
-                         [("decode", 8, 1024), ("prefill", 8, 512)],
-                         ids=["decode-8x1024", "prefill-8x512"])
-def test_serving_step_compiles(one_chip, monkeypatch, kind, batch, seq):
+# shape of a whole-slab instruction in compiled text, any view of it:
+# `%name = f32[8,1024,8,128]{3,2,1,0:T(8,128)} opcode(%operands...)`
+_HLO_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]+)\](\{\S*)? ([\w-]+)\((.*)$")
+
+
+def _whole_slab_ops(text, slab_shape):
+    """[(opcode, name, changes layout?)] of the top-level instructions
+    whose result holds a whole slab's elements, whatever its view."""
+    n = int(np.prod(slab_shape))
+    entry = text[text.index("ENTRY "):]
+    shapes, out = {}, []
+    for line in entry.splitlines():
+        m = _HLO_INSTR.match(line)
+        if not m:
+            continue
+        name, dims, layout, opcode, operands = m.groups()
+        # tiling and dimension order; `S(n)` is a memory space, not a layout
+        shape = (dims, re.sub(r"S\(\d+\)", "", (layout or "").split("}")[0]))
+        shapes[name] = shape
+        if int(np.prod([int(d) for d in dims.split(",")])) != n:
+            continue
+        src = re.match(r"%(\S+?)[,)]", operands)
+        changed = bool(src) and shapes.get(src.group(1), shape) != shape
+        out.append((opcode, name, changed))
+    return out
+
+
+_SERVING_CASES = [
+    # id, kind, batch, seq, layers, heads, d_model, d_inner, vocab, tied,
+    # same-layout copies of donated slabs left in the step
+    ("decode-8x1024", "decode", 8, 1024, 2, 8, D_MODEL, D_INNER, VOCAB,
+     False, 2),
+    ("prefill-8x512", "prefill", 8, 512, 2, 8, D_MODEL, D_INNER, VOCAB,
+     False, 0),
+    # the serving cell's own decode step (OPT-6.7B widths: 32 heads of
+    # 128, 2048 positions, tied table, 4 layers)
+    ("decode-8x2048-h32", "decode", 8, 2048, 4, 32, 4096, 16384, 50272,
+     True, 8),
+]
+
+
+@pytest.mark.parametrize(
+    "kind,batch,seq,n_layer,n_head,d_model,d_inner,vocab,tied,"
+    "pairing_copies",
+    [c[1:] for c in _SERVING_CASES], ids=[c[0] for c in _SERVING_CASES])
+def test_serving_step_compiles(one_chip, monkeypatch, kind, batch, seq,
+                               n_layer, n_head, d_model, d_inner, vocab,
+                               tied, pairing_copies):
     """The programs DecodePredictor builds for chip_smoke.py's serve
     phase, 2 layers at full width: the decode step at 8 slots x 1024 (the
-    Pallas decode kernel, feeds donated) and the burst prefill."""
+    Pallas decode kernel, feeds donated) and the burst prefill; and the
+    decode step at the benchmark's serving widths.
+
+    A decode step moves no slab: the kernel reads the (slots, seq, heads,
+    d_head) feed where it lies, so the compiled step holds no `reshape`,
+    `transpose` or layout-changing `copy` of a whole slab (each was a
+    268 MB relayout, two a layer a step on the chip: PERF.md, PR 25).
+    What stays is known and counted: same-layout copies of donated
+    slabs, made because jax pairs a donated feed with the first fetch of
+    its type and the fetches come (k0, v0, k1, ..) where the feeds
+    flatten (kcache_0.., vcache_0..): `pairing_copies`, 8 in the serving
+    cell's step, the next PR's to take to 0."""
     from paddle_tpu.executor import analyze_state
     from paddle_tpu.framework.trace import RngStream, trace_block
     from paddle_tpu.serving.decode import DecodeConfig, DecodePredictor
@@ -247,8 +308,10 @@ def test_serving_step_compiles(one_chip, monkeypatch, kind, batch, seq):
         KV, "_use_pallas_decode",
         lambda s, d: d % 128 == 0 and s % 128 == 0 and s >= 128)
     pred = DecodePredictor.__new__(DecodePredictor)  # graph builder only
-    pred.config = DecodeConfig(vocab_size=VOCAB, n_layer=2, n_head=8,
-                               d_model=D_MODEL, d_inner=D_INNER, max_len=T)
+    pred.config = DecodeConfig(vocab_size=vocab, n_layer=n_layer,
+                               n_head=n_head, d_model=d_model,
+                               d_inner=d_inner, max_len=max(T, seq),
+                               tie_embeddings=tied)
     pred.sample_k, pred.sample_p, pred.temperature = 40, 0.9, 1.0
     pred.draft_n_layer = 1
     program, feed_names, fetch_names = pred._build(kind, batch, seq,
@@ -268,7 +331,22 @@ def test_serving_step_compiles(one_chip, monkeypatch, kind, batch, seq):
         trace_block(gb, env, RngStream(jax.random.PRNGKey(0)))
         return tuple(env[n] for n in fetch_names)
 
-    text = _compile(step_fn, feeds, state, donate_argnums=(0,))
-    assert text.count("tpu_custom_call") >= 2  # one per layer
-    if kind == "decode":
-        assert "input_output_alias" in text
+    compiled = _compiled(step_fn, feeds, state, donate_argnums=(0,))
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= n_layer  # one per layer
+    if kind != "decode":
+        return
+    assert "input_output_alias" in text
+    slab = (batch, seq, n_head, d_model // n_head)
+    ops = _whole_slab_ops(text, slab)
+    moved = [(op, name) for op, name, changed in ops
+             if op in ("reshape", "transpose") or (op == "copy" and changed)]
+    assert not moved, "whole-slab relayouts in the decode step: %r" % moved
+    # the kernel's views of K and V are free
+    assert sum(op == "bitcast" for op, _, _ in ops) >= 2 * n_layer
+    same_layout_copies = [name for op, name, changed in ops
+                          if op == "copy" and not changed]
+    assert len(same_layout_copies) == pairing_copies, same_layout_copies
+    # one slab's worth of temporaries for those copies, not two a layer
+    temps = compiled.memory_analysis().temp_size_in_bytes
+    assert temps < 300 * 2**20, temps

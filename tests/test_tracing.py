@@ -477,6 +477,9 @@ def test_decode_server_gives_one_record_per_loop_iteration(decode_dir):
     for k in range(1, 7):
         want.append(sum(n + k for n, m in zip(plens, max_new) if k < m))
     assert [s["attended"] for s in steps] == want
+    # `streamed`: the rows a layer's attention brings in. The lax path
+    # of a CPU run reads the whole (4 slots x 32 rows) slab every step.
+    assert [s["streamed"] for s in steps] == [4 * 32] * len(steps)
     first = steps[0]
     assert first["admitted"] == 3
     for name in ("decode.loop.recv", "decode.loop.admit",
@@ -502,6 +505,25 @@ def test_decode_server_gives_one_record_per_loop_iteration(decode_dir):
     snap = tracing.snapshot()
     assert snap["dropped"] == 0
     assert snap["rings"]["request"]["recorded"] == 3 * 3  # submit/admit/retire
+
+
+@pytest.mark.parametrize("rows,lens,n_active,want", [
+    # whole slabs: the lax paths and the per-head kernel
+    (None, [5, 0, 70, 0], 2, {"attended": 77, "streamed": 4 * 256}),
+    # the in-place kernel: every slot's length with the row this step
+    # appends, rounded up to the block; a free slot costs one block
+    (64, [5, 0, 70, 0], 2, {"attended": 77, "streamed": 64 * (1 + 1 + 2 + 1)}),
+    (64, [63, 64, 127, 255], 4, {"attended": 513,
+                                 "streamed": 64 * (1 + 2 + 2 + 4)}),
+    (128, [0, 0, 0, 0], 0, {"attended": 0, "streamed": 4 * 128}),
+], ids=["whole-slab", "blocks", "boundaries", "idle"])
+def test_step_counts_streamed_rows(rows, lens, n_active, want):
+    from paddle_tpu.serving.decode import DecodeServer
+
+    srv = DecodeServer.__new__(DecodeServer)
+    srv.slots, srv.seq, srv._stream_rows = 4, 256, rows
+    got = srv._step_counts(np.array(lens, np.int32), n_active)
+    assert got == dict(want, active=n_active)
 
 
 def test_phases_land_on_the_profilers_host_plane(decode_dir, tmp_path):
