@@ -1065,8 +1065,9 @@ def _infer_fused_attention(ctx: InferContext):
 
 @register_infer("decode_attention")
 def _infer_decode_attention(ctx: InferContext):
-    """Q (B, 1, H, Dh) x KCache/VCache (B, S, H, Dh) -> Out = Q shape.
-    The slab's batch/head/depth dims must match the query's."""
+    """Q (B, 1, H, Dh) x KCache/VCache (B, S, Hkv, Dh) -> Out = Q shape.
+    The slab's batch and depth dims must match the query's; its heads
+    must divide the query's (equal but for grouped queries)."""
     q = ctx.in_info("Q")
     qs = q.shape
     if qs is not None and len(qs) != 4:
@@ -1083,13 +1084,17 @@ def _infer_decode_attention(ctx: InferContext):
         if len(c) != 4:
             raise InferError("%s must be rank 4 (B, S, H, Dh), got rank "
                              "%d" % (slot, len(c)))
-        for qi, ci, label in ((0, 0, "batch"), (2, 2, "head"),
-                              (3, 3, "depth")):
+        for qi, ci, label in ((0, 0, "batch"), (3, 3, "depth")):
             if qs[qi] is not None and c[ci] is not None \
                     and qs[qi] != c[ci]:
                 raise InferError(
                     "%s %s dim %d does not match Q%s"
                     % (slot, label, c[ci], render_shape(qs)))
+        # grouped queries: the slab may hold fewer heads, which divide
+        if qs[2] is not None and c[2] is not None and qs[2] % c[2]:
+            raise InferError(
+                "%s head dim %d does not divide Q%s"
+                % (slot, c[2], render_shape(qs)))
     return {"Out": VarInfo(qs, q.dtype)}
 
 
@@ -1112,6 +1117,86 @@ def _infer_cache_append(ctx: InferContext):
                 "New%s row shape does not match Cache%s rows"
                 % (render_shape(n), render_shape(c.shape)))
     return {"Out": VarInfo(c.shape, c.dtype)}
+
+
+@register_infer("rms_norm")
+def _infer_rms_norm(ctx: InferContext):
+    """Out mirrors X; Scale is X's last axis."""
+    x = ctx.in_info("X")
+    sc = ctx.in_shape("Scale")
+    if (x.shape is not None and sc is not None and sc[-1] is not None
+            and x.shape[-1] is not None and sc[-1] != x.shape[-1]):
+        raise InferError("Scale%s does not match X%s's last axis"
+                         % (render_shape(sc), render_shape(x.shape)))
+    return {"Out": VarInfo(x.shape, x.dtype)}
+
+
+def _ssm_state_shape(ctx: InferContext):
+    """(B, Di, N) from X (B, T, Di) and A (Di, N), with what is known."""
+    x, a = ctx.in_shape("X"), ctx.in_shape("A")
+    if x is not None and a is not None and len(a) == 2:
+        if x[-1] is not None and a[0] is not None and x[-1] != a[0]:
+            raise InferError("A%s rows do not match X%s's width"
+                             % (render_shape(a), render_shape(x)))
+        return (x[0], x[-1], a[1])
+    return None
+
+
+@register_infer("ssm_scan")
+def _infer_ssm_scan(ctx: InferContext):
+    """Y mirrors X (B, T, Di); State is (B, Di, N) with A (Di, N)."""
+    x = ctx.in_info("X")
+    if x.shape is not None and len(x.shape) != 3:
+        raise InferError("X must be rank 3 (B, T, Di), got rank %d"
+                         % len(x.shape))
+    return {"Y": VarInfo(x.shape, x.dtype),
+            "State": VarInfo(_ssm_state_shape(ctx), x.dtype)}
+
+
+@register_infer("ssm_step")
+def _infer_ssm_step(ctx: InferContext):
+    """Y mirrors X; StateOut mirrors State (B, Di, N)."""
+    x, st = ctx.in_info("X"), ctx.in_info("State")
+    want = _ssm_state_shape(ctx)
+    if (want is not None and st.shape is not None
+            and any(a is not None and b is not None and a != b
+                    for a, b in zip(want, st.shape))):
+        raise InferError("State%s is not (B, Di, N) = %s"
+                         % (render_shape(st.shape), render_shape(want)))
+    return {"Y": VarInfo(x.shape, x.dtype),
+            "StateOut": VarInfo(st.shape, st.dtype)}
+
+
+@register_infer("causal_conv1d")
+def _infer_causal_conv1d(ctx: InferContext):
+    """Y mirrors X (B, T, C); Window is (B, K - 1, C) with W (C, K)."""
+    x = ctx.in_info("X")
+    w = ctx.in_shape("W")
+    if x.shape is not None and len(x.shape) != 3:
+        raise InferError("X must be rank 3 (B, T, C), got rank %d"
+                         % len(x.shape))
+    win = None
+    if x.shape is not None and w is not None and len(w) == 2:
+        if (x.shape[2] is not None and w[0] is not None
+                and x.shape[2] != w[0]):
+            raise InferError("W%s rows do not match X%s channels"
+                             % (render_shape(w), render_shape(x.shape)))
+        win = (x.shape[0], None if w[1] is None else w[1] - 1, x.shape[2])
+    return {"Y": VarInfo(x.shape, x.dtype), "Window": VarInfo(win, x.dtype)}
+
+
+@register_infer("causal_conv1d_step")
+def _infer_causal_conv1d_step(ctx: InferContext):
+    """Y mirrors X; WindowOut mirrors Window (B, K - 1, C)."""
+    x, win = ctx.in_info("X"), ctx.in_info("Window")
+    w = ctx.in_shape("W")
+    if (win.shape is not None and w is not None and len(w) == 2
+            and win.shape[1] is not None and w[1] is not None
+            and win.shape[1] != w[1] - 1):
+        raise InferError("Window%s does not hold K - 1 = %d inputs"
+                         % (render_shape(win.shape), w[1] - 1))
+    return {"Y": VarInfo(x.shape, x.dtype),
+            "WindowOut": VarInfo(win.shape, win.dtype)}
 
 
 @register_infer("cache_gather")
@@ -1164,13 +1249,17 @@ def _infer_decode_attention_window(ctx: InferContext):
         if len(c) != 4:
             raise InferError("%s must be rank 4 (B, S, H, Dh), got rank "
                              "%d" % (slot, len(c)))
-        for qi, ci, label in ((0, 0, "batch"), (2, 2, "head"),
-                              (3, 3, "depth")):
+        for qi, ci, label in ((0, 0, "batch"), (3, 3, "depth")):
             if qs[qi] is not None and c[ci] is not None \
                     and qs[qi] != c[ci]:
                 raise InferError(
                     "%s %s dim %d does not match Q%s"
                     % (slot, label, c[ci], render_shape(qs)))
+        # grouped queries: the slab may hold fewer heads, which divide
+        if qs[2] is not None and c[2] is not None and qs[2] % c[2]:
+            raise InferError(
+                "%s head dim %d does not divide Q%s"
+                % (slot, c[2], render_shape(qs)))
     return {"Out": VarInfo(qs, q.dtype)}
 
 
@@ -1321,13 +1410,17 @@ def _infer_decode_attention_quant(ctx: InferContext):
         if len(c) != 4:
             raise InferError("%s must be rank 4 (B, S, H, Dh), got rank "
                              "%d" % (slot, len(c)))
-        for qi, ci, label in ((0, 0, "batch"), (2, 2, "head"),
-                              (3, 3, "depth")):
+        for qi, ci, label in ((0, 0, "batch"), (3, 3, "depth")):
             if qs[qi] is not None and c[ci] is not None \
                     and qs[qi] != c[ci]:
                 raise InferError(
                     "%s %s dim %d does not match Q%s"
                     % (slot, label, c[ci], render_shape(qs)))
+        # grouped queries: the slab may hold fewer heads, which divide
+        if qs[2] is not None and c[2] is not None and qs[2] % c[2]:
+            raise InferError(
+                "%s head dim %d does not divide Q%s"
+                % (slot, c[2], render_shape(qs)))
     for cslot, sslot in (("KCache", "KScales"), ("VCache", "VScales")):
         c = ctx.in_shape(cslot)
         s = ctx.in_shape(sslot)

@@ -119,6 +119,11 @@ __all__ = [
     "greedy_sample",
     "top_k_sample",
     "top_p_sample",
+    "rms_norm",
+    "ssm_scan",
+    "ssm_step",
+    "causal_conv1d",
+    "causal_conv1d_step",
 ]
 
 from .ops import elementwise_add  # re-export for parity
@@ -2431,3 +2436,92 @@ def fused_lm_head_loss(input, label, size, param_attr=None, bias_attr=None,
         attrs={"block_v": block_v, "transpose_w": bool(transpose_w)},
     )
     return loss
+
+
+# ---------------------------------------------------------------------------
+# state-space layers (kernels: ops/ssm.py)
+# ---------------------------------------------------------------------------
+
+
+def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
+    """Root-mean-square normalization over the last axis with a learned
+    gain (no mean subtraction, no shift): gain * x / sqrt(mean(x^2) +
+    epsilon)."""
+    from ..initializer import ConstantInitializer
+
+    helper = LayerHelper("rms_norm", **locals())
+    gain = helper.create_parameter(
+        attr=helper.param_attr, shape=[int(input.shape[-1])],
+        dtype=input.dtype, default_initializer=ConstantInitializer(1.0))
+    out = helper.create_variable_for_type_inference(
+        input.dtype, shape=input.shape)
+    helper.append_op(
+        type="rms_norm", inputs={"X": [input], "Scale": [gain]},
+        outputs={"Out": [out]}, attrs={"epsilon": epsilon})
+    return out
+
+
+def ssm_scan(x, delta, a, b, c, d, lengths=None, name=None):
+    """Selective state-space scan from a zero state over padded
+    sequences: x, delta (B, T, Di), a (Di, N), b, c (B, T, N), d (Di,),
+    lengths (B,) -> (y (B, T, Di), state (B, Di, N) after each row's
+    last real token; padding leaves a state untouched)."""
+    helper = LayerHelper("ssm_scan", name=name)
+    y = helper.create_variable_for_type_inference(x.dtype, shape=x.shape)
+    state = helper.create_variable_for_type_inference(
+        x.dtype, shape=(x.shape[0], x.shape[2], a.shape[1]))
+    inputs = {"X": [x], "Delta": [delta], "A": [a], "B": [b], "C": [c],
+              "D": [d]}
+    if lengths is not None:
+        inputs["Lengths"] = [lengths]
+    helper.append_op(type="ssm_scan", inputs=inputs,
+                     outputs={"Y": [y], "State": [state]}, attrs={})
+    return y, state
+
+
+def ssm_step(x, delta, a, b, c, d, state, name=None):
+    """One token of ``ssm_scan``: x, delta (B, 1, Di), b, c (B, 1, N),
+    state (B, Di, N) in -> (y (B, 1, Di), state out)."""
+    helper = LayerHelper("ssm_step", name=name)
+    y = helper.create_variable_for_type_inference(x.dtype, shape=x.shape)
+    new = helper.create_variable_for_type_inference(
+        state.dtype, shape=state.shape)
+    helper.append_op(
+        type="ssm_step",
+        inputs={"X": [x], "Delta": [delta], "A": [a], "B": [b], "C": [c],
+                "D": [d], "State": [state]},
+        outputs={"Y": [y], "StateOut": [new]}, attrs={})
+    return y, new
+
+
+def causal_conv1d(x, w, bias=None, lengths=None, name=None):
+    """Causal depthwise convolution over time: x (B, T, C), w (C, K),
+    bias (C,) -> (y (B, T, C), window (B, K - 1, C): the inputs before
+    each row's length, which the one-token step carries on from)."""
+    helper = LayerHelper("causal_conv1d", name=name)
+    y = helper.create_variable_for_type_inference(x.dtype, shape=x.shape)
+    window = helper.create_variable_for_type_inference(
+        x.dtype, shape=(x.shape[0], w.shape[1] - 1, x.shape[2]))
+    inputs = {"X": [x], "W": [w]}
+    if bias is not None:
+        inputs["Bias"] = [bias]
+    if lengths is not None:
+        inputs["Lengths"] = [lengths]
+    helper.append_op(type="causal_conv1d", inputs=inputs,
+                     outputs={"Y": [y], "Window": [window]}, attrs={})
+    return y, window
+
+
+def causal_conv1d_step(x, window, w, bias=None, name=None):
+    """One token of ``causal_conv1d``: x (B, 1, C), window (B, K - 1,
+    C) -> (y (B, 1, C), the window moved on by one)."""
+    helper = LayerHelper("causal_conv1d_step", name=name)
+    y = helper.create_variable_for_type_inference(x.dtype, shape=x.shape)
+    new = helper.create_variable_for_type_inference(
+        window.dtype, shape=window.shape)
+    inputs = {"X": [x], "Window": [window], "W": [w]}
+    if bias is not None:
+        inputs["Bias"] = [bias]
+    helper.append_op(type="causal_conv1d_step", inputs=inputs,
+                     outputs={"Y": [y], "WindowOut": [new]}, attrs={})
+    return y, new

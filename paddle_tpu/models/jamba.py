@@ -1,0 +1,205 @@
+"""Hybrid state-space / attention decoder LM, as AI21's Jamba builds it
+(`model_type: jamba`): Mamba-1 layers with one attention layer every
+``attn_layer_period``, each followed by a gated-SiLU MLP, RMS
+normalization before every mixer and MLP and after the last layer, NO
+position term (the state-space layers carry order), no biases but the
+convolution's and ``dt_proj``'s, logits through the tied token table.
+
+The serving graphs only (serving/decode.py): ``hybrid_lm_prefill``
+walks padded prompts and returns every layer's cache entries AT EACH
+ROW'S LENGTH; ``hybrid_lm_decode`` advances them by one token. Both
+are derived from ONE description of a layer, ``_layer``: its kind
+(``mamba`` | ``attention``) and whether it is handed a cache entry
+decide what it builds, and the parameter set is written once.
+
+Cache entries, by feed name (``serving.decode.cache_spec``): a Mamba
+layer ``i`` keeps ``conv_i`` (B, K - 1, d_inner), the convolution's
+window, and ``ssm_i`` (B, d_inner, N), the recurrent state: fixed
+size, no row per position. An attention layer keeps ``kcache_i`` /
+``vcache_i`` (B, S, n_kv_head, d_head) slabs: the key/value heads as
+they are, never repeated for the query heads that share them.
+"""
+from __future__ import annotations
+
+import numpy as np
+
+from .. import layers
+from ..framework import default_main_program
+from ..initializer import ConstantInitializer, NormalInitializer
+from ..param_attr import ParamAttr
+from .transformer import sample_next
+
+
+def cache_names(kind: str, i: int):
+    """Feed names of layer ``i``'s cache entries, in the order ``_layer``
+    takes and returns them."""
+    if kind == "mamba":
+        return ["conv_%d" % i, "ssm_%d" % i]
+    return ["kcache_%d" % i, "vcache_%d" % i]
+
+
+def _proj(x, size, name, bias=False):
+    """(B, T, in) -> (B, T, size); N(0, 0.02) weight ``name.w``."""
+    return layers.fc(
+        x, size, num_flatten_dims=2,
+        param_attr=ParamAttr(name=name + ".w",
+                             initializer=NormalInitializer(0.0, 0.02)),
+        bias_attr=ParamAttr(name=name + ".b") if bias else False)
+
+
+def _rms(x, name, eps):
+    return layers.rms_norm(x, epsilon=eps,
+                           param_attr=ParamAttr(name=name + ".w"))
+
+
+def _param(shape, name, init, is_bias=False):
+    return layers.create_parameter(
+        shape=shape, dtype="float32", is_bias=is_bias,
+        attr=ParamAttr(name=name, initializer=init))
+
+
+def _mamba_mixer(u, cfg, name, lengths, cache):
+    """Mamba-1 with Jamba's RMS norms on delta, B and C. ``cache`` is
+    None (prefill: scan from zero, state and window at ``lengths``) or
+    (window, state) (one token). Returns (out, (window, state))."""
+    di, n, r, k = (cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_dt_rank,
+                   cfg.mamba_d_conv)
+    x, z = layers.split(_proj(u, 2 * di, name + ".in_proj"), 2, dim=-1)
+    conv_w = _param([di, k], name + ".conv.w", NormalInitializer(0.0, 0.02))
+    conv_b = _param([di], name + ".conv.b", ConstantInitializer(0.0),
+                    is_bias=True)
+    if cache is None:
+        x, window = layers.causal_conv1d(x, conv_w, conv_b, lengths)
+    else:
+        x, window = layers.causal_conv1d_step(x, cache[0], conv_w, conv_b)
+    x = layers.swish(x, beta=1.0)  # SiLU
+    dt, b, c = layers.split(_proj(x, r + 2 * n, name + ".x_proj"),
+                            [r, n, n], dim=-1)
+    dt = _rms(dt, name + ".dt_norm", cfg.norm_eps)
+    b = _rms(b, name + ".b_norm", cfg.norm_eps)
+    c = _rms(c, name + ".c_norm", cfg.norm_eps)
+    delta = layers.softplus(_proj(dt, di, name + ".dt_proj", bias=True))
+    a_log = _param([di, n], name + ".A_log", ConstantInitializer(0.0))
+    a = layers.scale(layers.exp(a_log), scale=-1.0)
+    d = _param([di], name + ".D", ConstantInitializer(1.0))
+    if cache is None:
+        y, state = layers.ssm_scan(x, delta, a, b, c, d, lengths)
+    else:
+        y, state = layers.ssm_step(x, delta, a, b, c, d, cache[1])
+    y = layers.elementwise_mul(y, layers.swish(z, beta=1.0))
+    return _proj(y, cfg.d_model, name + ".out_proj"), (window, state)
+
+
+def _attention_mixer(u, cfg, name, lengths, cache):
+    """``n_head`` query heads on ``n_kv_head`` key/value heads, no
+    bias, no rotation. ``cache`` is None (prefill: causal flash
+    attention; the slab entries are this prompt's k and v) or
+    (k slab, v slab) (one token: append at ``lengths``, attend
+    lengths + 1 rows). Returns (out, (k, v))."""
+    B, T, _ = u.shape
+    h, hkv, dh = cfg.n_head, cfg.n_kv_head, cfg.d_head
+    q = layers.reshape(_proj(u, h * dh, name + ".q"), shape=[B, T, h, dh])
+    k = layers.reshape(_proj(u, hkv * dh, name + ".k"),
+                       shape=[B, T, hkv, dh])
+    v = layers.reshape(_proj(u, hkv * dh, name + ".v"),
+                       shape=[B, T, hkv, dh])
+    if cache is None:
+        # the op repeats k and v for the query heads that share them
+        ctx = layers.fused_attention(q, k, v, causal=True, layout="bthd")
+    else:
+        k = layers.cache_append(cache[0], k, lengths)
+        v = layers.cache_append(cache[1], v, lengths)
+        kv_lengths = layers.elementwise_add(
+            layers.cast(lengths, "int32"),
+            layers.fill_constant(shape=[B], dtype="int32", value=1))
+        ctx = layers.decode_attention(q, k, v, kv_lengths)
+    out = _proj(layers.reshape(ctx, shape=[B, T, h * dh]), cfg.d_model,
+                name + ".o")
+    return out, (k, v)
+
+
+def _mlp(x, cfg, name):
+    gate = layers.swish(_proj(x, cfg.d_inner, name + ".gate"), beta=1.0)
+    up = _proj(x, cfg.d_inner, name + ".up")
+    return _proj(layers.elementwise_mul(gate, up), cfg.d_model,
+                 name + ".down")
+
+
+def _layer(x, kind, i, cfg, lengths, cache=None):
+    """THE description of layer ``i``: x (B, T, D) -> (x, cache
+    entries in ``cache_names(kind, i)`` order). Prefill and decode
+    differ only in ``cache``."""
+    name = "%s.l%d" % (cfg.prefix, i)
+    mixer = _mamba_mixer if kind == "mamba" else _attention_mixer
+    mixed, entries = mixer(_rms(x, name + ".norm_in", cfg.norm_eps), cfg,
+                           name + "." + kind, lengths, cache)
+    x = layers.elementwise_add(x, mixed)
+    ffn = _mlp(_rms(x, name + ".norm_ff", cfg.norm_eps), cfg, name + ".mlp")
+    return layers.elementwise_add(x, ffn), entries
+
+
+def _check(cfg):
+    if (cfg.norm != "rms_norm" or cfg.ffn != "gated_silu" or cfg.positions
+            or cfg.biases or not cfg.tie_embeddings):
+        raise ValueError(
+            "the hybrid builders write Jamba's block: RMS norms, a gated-"
+            "SiLU MLP, no positions, no biases, a tied table; got norm=%r "
+            "ffn=%r positions=%r biases=%r tie_embeddings=%r"
+            % (cfg.norm, cfg.ffn, cfg.positions, cfg.biases,
+               cfg.tie_embeddings))
+
+
+def _embed(tokens, cfg):
+    return layers.embedding(
+        input=tokens, size=[cfg.vocab_size, cfg.d_model],
+        param_attr=ParamAttr(name=cfg.prefix + ".tok_emb",
+                             initializer=NormalInitializer(0.0, 0.02)))
+
+
+def _head(last, cfg):
+    """(B, D) -> (B, V) through the tied table, no bias."""
+    emb = default_main_program().global_block().var(cfg.prefix + ".tok_emb")
+    return layers.matmul(last, emb, transpose_y=True)
+
+
+def hybrid_lm_prefill(tokens, lengths, cfg):
+    """Padded prompts ``tokens`` (B, S), ``lengths`` (B,) -> (logits
+    (B, V) of each row's last real position, {feed name: cache entry}):
+    slab entries hold the prompt's k and v rows (garbage past a row's
+    length, masked by length later), state entries the state and the
+    window after each row's LAST REAL token."""
+    _check(cfg)
+    B, S = tokens.shape
+    x = _embed(tokens, cfg)
+    caches = {}
+    for i, kind in enumerate(cfg.layer_kinds()):
+        x, entries = _layer(x, kind, i, cfg, lengths)
+        caches.update(zip(cache_names(kind, i), entries))
+    x = _rms(x, cfg.prefix + ".norm_f", cfg.norm_eps)
+    flat = layers.reshape(x, shape=[B * S, cfg.d_model])
+    base = layers.assign((np.arange(B, dtype=np.int32) * S - 1).reshape(B))
+    idx = layers.elementwise_add(layers.cast(lengths, "int32"), base)
+    return _head(layers.gather(flat, idx), cfg), caches
+
+
+def hybrid_lm_decode(tokens, lengths, caches, cfg, strategy="greedy",
+                     seed=None, sample_k=40, sample_p=0.9, temperature=1.0):
+    """One token per slot: ``tokens`` (B, 1), ``lengths`` (B,) tokens
+    each slot holds BEFORE this one, ``caches`` {feed name: entry} ->
+    (next_ids (B,) or None, logits (B, V), {feed name: updated
+    entry})."""
+    _check(cfg)
+    B = tokens.shape[0]
+    # embedding squeezes the trailing ids dim of 1: restore the time axis
+    x = layers.reshape(_embed(tokens, cfg), shape=[B, 1, cfg.d_model])
+    new = {}
+    for i, kind in enumerate(cfg.layer_kinds()):
+        names = cache_names(kind, i)
+        x, entries = _layer(x, kind, i, cfg, lengths,
+                            cache=tuple(caches[n] for n in names))
+        new.update(zip(names, entries))
+    x = _rms(x, cfg.prefix + ".norm_f", cfg.norm_eps)
+    logits = _head(layers.reshape(x, shape=[B, cfg.d_model]), cfg)
+    next_ids = sample_next(logits, strategy, seed, sample_k, sample_p,
+                           temperature)
+    return next_ids, logits, new

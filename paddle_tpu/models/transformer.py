@@ -488,6 +488,25 @@ def transformer_lm_prefill(
     return _lm_head_logits(last, vocab_size, tie_embeddings, prefix), caches
 
 
+def sample_next(logits, strategy="greedy", seed=None, sample_k=40,
+                sample_p=0.9, temperature=1.0):
+    """The in-graph sampler of a decode step: next ids (B,) int64 from
+    logits (B, V) per ``strategy``, or None for "logits" (host-side
+    beam search samples for itself)."""
+    if strategy == "greedy":
+        return layers.greedy_sample(logits)
+    if strategy == "topk":
+        return layers.top_k_sample(logits, seed=seed, k=sample_k,
+                                   temperature=temperature)
+    if strategy == "topp":
+        return layers.top_p_sample(logits, seed=seed, p=sample_p,
+                                   temperature=temperature)
+    if strategy == "logits":
+        return None
+    raise ValueError("unknown decode strategy %r (greedy | topk | "
+                     "topp | logits)" % (strategy,))
+
+
 def transformer_lm_decode(
     tokens, positions, lengths, k_caches, v_caches, vocab_size,
     n_layer=4, n_head=8, d_model=512, d_inner=2048, max_len=2048,
@@ -552,19 +571,8 @@ def transformer_lm_decode(
     x = _pre_norm(x)
     last = layers.reshape(x, shape=[B, d_model])
     logits = _lm_head_logits(last, vocab_size, tie_embeddings, prefix)
-    if strategy == "greedy":
-        next_ids = layers.greedy_sample(logits)
-    elif strategy == "topk":
-        next_ids = layers.top_k_sample(logits, seed=seed, k=sample_k,
-                                       temperature=temperature)
-    elif strategy == "topp":
-        next_ids = layers.top_p_sample(logits, seed=seed, p=sample_p,
-                                       temperature=temperature)
-    elif strategy == "logits":
-        next_ids = None
-    else:
-        raise ValueError("unknown decode strategy %r (greedy | topk | "
-                         "topp | logits)" % (strategy,))
+    next_ids = sample_next(logits, strategy, seed, sample_k, sample_p,
+                           temperature)
     return next_ids, logits, new_caches
 
 

@@ -763,6 +763,19 @@ def _fused_attention(ctx):
     block_k = int(ctx.attr("block_k", 512))
     layout = str(ctx.attr("layout", "bhtd") or "bhtd").lower()
     rng = ctx.rng() if dropout_rate else None
+    head_axis = 2 if layout == "bthd" else 1
+    if k.shape[head_axis] != q.shape[head_axis]:
+        # grouped queries: the kernels take q, k, v of one head count,
+        # so each key/value head is repeated for the query heads that
+        # share it (a cost in compute, none in mathematics)
+        group, rem = divmod(q.shape[head_axis], k.shape[head_axis])
+        if rem:
+            raise ValueError(
+                "fused_attention: %d query heads do not divide over %d "
+                "key/value heads" % (q.shape[head_axis],
+                                     k.shape[head_axis]))
+        k = jnp.repeat(k, group, axis=head_axis)
+        v = jnp.repeat(v, group, axis=head_axis)
 
     if layout == "bthd":
         t, tk, d_head = q.shape[1], k.shape[1], q.shape[-1]

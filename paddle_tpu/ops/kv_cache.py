@@ -61,6 +61,8 @@ _NEG = -1e30
 
 # the decode kernel's stable name in lowered text and device traces
 DECODE_ATTN = "ptpu.decode_attn"
+# the lax path of a slab with fewer heads than the query
+DECODE_ATTN_GROUPED = "ptpu.decode_attn_grouped"
 
 
 # ---------------------------------------------------------------------------
@@ -69,9 +71,14 @@ DECODE_ATTN = "ptpu.decode_attn"
 
 
 def decode_attention_reference(q, k_cache, v_cache, lengths, scale=None):
-    """Pure-lax decode attention: q (B, 1, H, Dh), caches (B, S, H, Dh),
+    """Pure-lax decode attention: q (B, 1, H, Dh), caches (B, S, Hkv, Dh)
+    with H = g * Hkv (g = 1: as many key/value heads as query heads;
+    g > 1: grouped queries, query head h reads K/V head h // g),
     lengths (B,) valid rows per slot -> (B, 1, H, Dh). Exact; the CPU
-    serving path and the numeric reference for the Pallas kernel.
+    serving path, the numeric reference for the Pallas kernel, and the
+    path of a slab with fewer heads than the query on every device: the
+    g query rows of a slot against their one K/V head are a real
+    matmul, and the slab keeps its Hkv heads (never repeated).
 
     Rows with length 0 (empty/inactive slots) produce zeros, not the
     mean of garbage V rows — continuous batching runs every slot of the
@@ -79,21 +86,25 @@ def decode_attention_reference(q, k_cache, v_cache, lengths, scale=None):
     at least stay finite.
     """
     b, one, h, d = q.shape
-    s = k_cache.shape[1]
+    s, hkv = k_cache.shape[1], k_cache.shape[2]
+    if h % hkv:
+        raise ValueError("decode_attention: %d query heads do not divide "
+                         "over %d key/value heads" % (h, hkv))
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    qf = q[:, 0].astype(jnp.float32) * scale                    # (B, H, D)
-    scores = jnp.einsum("bhd,bshd->bhs", qf,
-                        k_cache.astype(jnp.float32))            # (B, H, S)
-    valid = (jnp.arange(s)[None, None, :]
-             < lengths.reshape(-1)[:, None, None])              # (B, 1, S)
+    qf = (q[:, 0].astype(jnp.float32) * scale).reshape(
+        b, hkv, h // hkv, d)                                # (B, Hkv, g, D)
+    scores = jnp.einsum("bkgd,bskd->bkgs", qf,
+                        k_cache.astype(jnp.float32))        # (B, Hkv, g, S)
+    valid = (jnp.arange(s)[None, None, None, :]
+             < lengths.reshape(-1)[:, None, None, None])    # (B, 1, 1, S)
     scores = jnp.where(valid, scores, _NEG)
     m = jnp.max(scores, axis=-1, keepdims=True)
     p = jnp.where(valid, jnp.exp(scores - m), 0.0)
     l = jnp.sum(p, axis=-1, keepdims=True)
-    out = jnp.einsum("bhs,bshd->bhd", p / jnp.maximum(l, 1e-30),
+    out = jnp.einsum("bkgs,bskd->bkgd", p / jnp.maximum(l, 1e-30),
                      v_cache.astype(jnp.float32))
-    return out[:, None].astype(q.dtype)
+    return out.reshape(b, 1, h, d).astype(q.dtype)
 
 
 def _online_softmax_row(q, kb, vb, col0, length, acc, m, l):
@@ -304,6 +315,12 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
     """Dispatch: Pallas kernel when eligible, exact lax fallback
     otherwise (numerics identical — same online softmax)."""
     s, d = k_cache.shape[1], q.shape[-1]
+    if k_cache.shape[2] != q.shape[2]:
+        # fewer key/value heads than query heads: the Pallas kernels
+        # stride over ONE head count and are not entered
+        with jax.named_scope(DECODE_ATTN_GROUPED):
+            return decode_attention_reference(q, k_cache, v_cache, lengths,
+                                              scale=scale)
     if _use_pallas_decode(s, d):
         return pallas_decode_attention(q, k_cache, v_cache, lengths,
                                        scale=scale, block_s=block_s)
@@ -314,7 +331,8 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
 @register_op("decode_attention")
 def _decode_attention_op(ctx):
     """Single-query attention against a KV slab. Inputs Q (B, 1, H, Dh),
-    KCache/VCache (B, S, H, Dh), Lengths (B,) valid rows per slot
+    KCache/VCache (B, S, H, Dh) (or fewer heads that divide H: grouped
+    queries, an exact lax path), Lengths (B,) valid rows per slot
     (INCLUDING the current token's freshly appended row); attr scale.
     The (B, S) slab shapes are static — serving buckets S to powers of
     two so executable count stays bounded."""

@@ -525,8 +525,12 @@ def _split(ctx):
     sections = ctx.attr("sections", None)
     num = ctx.attr("num", 0)
     if sections:
-        idx = list(jnp.cumsum(jnp.array(sections))[:-1])
-        outs = jnp.split(x, [int(i) for i in idx], axis=axis)
+        # host arithmetic: under a jit trace a jnp cumsum is a tracer
+        idx, at = [], 0
+        for sec in sections[:-1]:
+            at += int(sec)
+            idx.append(at)
+        outs = jnp.split(x, idx, axis=axis)
     else:
         outs = jnp.split(x, num, axis=axis)
     return {"Out": list(outs)}

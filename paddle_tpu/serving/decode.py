@@ -40,6 +40,8 @@ everything still queued before exiting).
 """
 from __future__ import annotations
 
+import collections
+import functools
 import json
 import os
 import threading
@@ -59,7 +61,7 @@ from ..ops import kv_cache as _KV
 from ..runtime import recordio as _rio
 
 __all__ = ["DecodeConfig", "save_decode_model", "DecodePredictor",
-           "DecodeServer", "kv_slab_slots"]
+           "DecodeServer", "kv_slab_slots", "cache_spec", "CacheEntry"]
 
 _DECODE_MANIFEST = "__decode__.json"
 _AOT_DIR = "__aot_cache__"
@@ -79,19 +81,14 @@ _KV_ITEMSIZE = {"float32": 4, "bfloat16": 2, "int8": 1}
 
 def kv_slab_slots(budget_bytes: int, config: "DecodeConfig", seq: int,
                   kv_dtype: str = "float32") -> int:
-    """How many cache slots one KV slab byte budget holds at ``seq``
-    positions — the continuous-batching capacity arithmetic behind the
-    int8 slab: per slot, 2*n_layer slabs of seq*n_head*d_head elements
-    (plus the per-position scales when int8). int8 rows cost 1 byte +
-    4/(n_head*d_head) of scale vs bf16's 2 — at realistic head widths
-    one budget holds ~2x the sequences."""
-    if kv_dtype not in _KV_ITEMSIZE:
-        raise ValueError("kv_dtype must be one of %s, got %r"
-                         % (sorted(_KV_ITEMSIZE), kv_dtype))
-    per_pos = config.n_head * config.d_head * _KV_ITEMSIZE[kv_dtype]
-    if kv_dtype == "int8":
-        per_pos += 4  # the (slot, position) float32 scale
-    per_slot = 2 * config.n_layer * int(seq) * per_pos
+    """How many cache slots one cache byte budget holds at ``seq``
+    positions: the continuous-batching capacity arithmetic. A slot
+    costs what ``cache_spec`` says it keeps: per attention layer two
+    slabs of seq * n_kv_head * d_head elements (plus the per-position
+    scales when int8: int8 rows cost 1 byte + 4 / (n_head * d_head) of
+    scale vs bf16's 2, so one budget holds ~2x the sequences), per
+    state-space layer its window and its state, whatever ``seq``."""
+    per_slot = sum(e.nbytes for e in cache_spec(config, 1, seq, kv_dtype))
     return max(int(budget_bytes) // per_slot, 0)
 
 
@@ -123,17 +120,35 @@ def _kv_dtype_from_env() -> str:
 
 
 class DecodeConfig:
-    """Architecture manifest for the decode-side graph builders — the
-    arguments ``models.transformer.transformer_lm`` was trained with.
+    """Architecture manifest for the decode-side graph builders.
     Everything else (batch, slab length, strategy) is a serving-time
-    choice and deliberately NOT part of the manifest."""
+    choice and deliberately NOT part of the manifest.
+
+    The first nine fields are the arguments ``models.transformer.
+    transformer_lm`` was trained with; the rest describe what a block
+    is made of, with OPT's block as the default (a manifest written
+    before they existed loads as what it was): layer ``i`` is an
+    attention layer iff ``i % attn_layer_period == attn_layer_offset``
+    and a Mamba layer otherwise; ``n_kv_head`` key/value heads serve
+    the ``n_head`` query heads; ``norm`` ("layer_norm" | "rms_norm"),
+    ``ffn`` ("relu" | "gated_silu"), ``positions`` (a learned position
+    table is added to the token embedding) and ``biases`` say the rest.
+    ``d_inner`` is the feed-forward width; a Mamba layer's inner width
+    is ``mamba_expand * d_model``."""
 
     FIELDS = ("vocab_size", "n_layer", "n_head", "d_model", "d_inner",
               "max_len", "tie_embeddings", "prefix", "eos_id")
+    # (field, OPT's value): written to a manifest only where they differ
+    BLOCK_FIELDS = (("n_kv_head", None), ("attn_layer_period", 1),
+                    ("attn_layer_offset", 0), ("mamba_d_state", 16),
+                    ("mamba_d_conv", 4), ("mamba_dt_rank", None),
+                    ("mamba_expand", 2), ("norm", "layer_norm"),
+                    ("norm_eps", 1e-5), ("ffn", "relu"),
+                    ("positions", True), ("biases", True))
 
     def __init__(self, vocab_size, n_layer=4, n_head=8, d_model=512,
                  d_inner=2048, max_len=2048, tie_embeddings=False,
-                 prefix="lm", eos_id=None):
+                 prefix="lm", eos_id=None, **block):
         self.vocab_size = int(vocab_size)
         self.n_layer = int(n_layer)
         self.n_head = int(n_head)
@@ -143,23 +158,172 @@ class DecodeConfig:
         self.tie_embeddings = bool(tie_embeddings)
         self.prefix = str(prefix)
         self.eos_id = None if eos_id is None else int(eos_id)
+        for f, default in self.BLOCK_FIELDS:
+            setattr(self, f, block.pop(f, default))
+        if block:
+            raise TypeError("DecodeConfig got unknown fields %s"
+                            % sorted(block))
+        if self.n_kv_head is None:
+            self.n_kv_head = self.n_head
+        if self.mamba_dt_rank is None:
+            self.mamba_dt_rank = -(-self.d_model // 16)
+        if not 0 <= self.attn_layer_offset < self.attn_layer_period:
+            raise ValueError(
+                "attn_layer_offset %r is not a layer of a period of %r"
+                % (self.attn_layer_offset, self.attn_layer_period))
+        if self.n_head % self.n_kv_head:
+            raise ValueError(
+                "%d query heads do not divide over %d key/value heads"
+                % (self.n_head, self.n_kv_head))
 
     @property
     def d_head(self) -> int:
         return self.d_model // self.n_head
 
+    @property
+    def mamba_d_inner(self) -> int:
+        return int(self.mamba_expand) * self.d_model
+
+    def layer_kinds(self) -> List[str]:
+        """"attention" | "mamba" for each layer."""
+        return ["attention" if i % self.attn_layer_period
+                == self.attn_layer_offset else "mamba"
+                for i in range(self.n_layer)]
+
+    @property
+    def has_state(self) -> bool:
+        """Some layer keeps a recurrent state: a cache entry that is
+        not a row per position (no snapshot, no rollback)."""
+        return "mamba" in self.layer_kinds()
+
+    @property
+    def is_opt_block(self) -> bool:
+        """Every block field at OPT's value: the graphs are
+        ``models.transformer.transformer_lm_*``'s."""
+        return (all(getattr(self, f) == d for f, d in self.BLOCK_FIELDS
+                    if f not in ("n_kv_head", "mamba_dt_rank"))
+                and self.n_kv_head == self.n_head)
+
     def to_dict(self) -> Dict:
-        return {f: getattr(self, f) for f in self.FIELDS}
+        d = {f: getattr(self, f) for f in self.FIELDS}
+        if not self.is_opt_block:
+            d.update({f: getattr(self, f) for f, _ in self.BLOCK_FIELDS})
+        return d
 
     @classmethod
     def from_dict(cls, d: Dict) -> "DecodeConfig":
-        return cls(**{f: d[f] for f in cls.FIELDS if f in d})
+        known = cls.FIELDS + tuple(f for f, _ in cls.BLOCK_FIELDS)
+        return cls(**{f: d[f] for f in known if f in d})
+
+
+class CacheEntry(collections.namedtuple(
+        "CacheEntry", "name shape dtype per_position")):
+    """One array of a model's decode cache: its feed name, its shape
+    at (slots, seq), its dtype, and whether it holds a row per position
+    (a K/V slab, or an int8 slab's scales: axis 1 is the sequence, an
+    admission writes ``[:sp]`` and a length masks the rest) or a
+    fixed-size state (replaced whole)."""
+
+    @property
+    def nbytes(self) -> int:
+        itemsize = _KV_ITEMSIZE.get(self.dtype) or np.dtype(
+            self.dtype).itemsize  # numpy has no bfloat16
+        return int(np.prod(self.shape)) * itemsize
+
+
+def cache_spec(config: DecodeConfig, slots: int, seq: int,
+               kv_dtype: str = "float32") -> List[CacheEntry]:
+    """The ordered cache entries of ``config`` at (slots, seq): the ONE
+    description the server's feed names, fresh arrays, admission
+    scatter and capacity arithmetic, and the decode graphs' feeds and
+    fetches, all go by.
+
+    OPT's block: ``kcache_i``, ``vcache_i`` (slots, seq, n_head,
+    d_head) layer by layer (with ``kscale_i``, ``vscale_i`` (slots,
+    seq) after them when int8), the order its decode graph has always
+    fetched them in. Any other block: an attention layer's two slabs
+    (slots, seq, n_kv_head, d_head), a Mamba layer's ``conv_i`` (slots,
+    K - 1, d_inner) window and ``ssm_i`` (slots, d_inner, N) state,
+    SORTED BY NAME: the order a dict of feeds flattens in, so that a
+    donated feed pairs with its own updated output and a step compiles
+    with no pairing copy (PERF.md 7a is what happens otherwise)."""
+    if kv_dtype not in _KV_ITEMSIZE:
+        raise ValueError("kv_dtype must be one of %s, got %r"
+                         % (sorted(_KV_ITEMSIZE), kv_dtype))
+    if kv_dtype != "float32" and not config.is_opt_block:
+        raise ValueError(
+            "%s slabs are built for OPT's block only; this model's "
+            "caches are float32" % kv_dtype)
+    from ..models.jamba import cache_names
+
+    slab = (slots, seq, config.n_kv_head, config.d_head)
+    out = []
+    for i, kind in enumerate(config.layer_kinds()):
+        names = cache_names(kind, i)
+        if kind == "mamba":
+            out.append(CacheEntry(
+                names[0], (slots, config.mamba_d_conv - 1,
+                           config.mamba_d_inner), "float32", False))
+            out.append(CacheEntry(
+                names[1], (slots, config.mamba_d_inner,
+                           config.mamba_d_state), "float32", False))
+            continue
+        out += [CacheEntry(n, slab, kv_dtype, True) for n in names]
+        if kv_dtype == "int8":
+            # the (slot, position) float32 scale of each int8 row
+            out += [CacheEntry("%sscale_%d" % (kv, i), (slots, seq),
+                               "float32", True) for kv in "kv"]
+    return out if config.is_opt_block else sorted(out)
+
+
+@functools.lru_cache(maxsize=None)
+def _scatter_fn(per_position: tuple, donate: bool):
+    """The jitted admission scatter of a cache whose entries are rows
+    per position (True) or fixed-size states (False): (caches, sub,
+    slot_idx, sp) -> caches with ``sub``'s rows at ``slot_idx`` (an
+    index past the last slot drops its row). One call an admission
+    where there was an eager ``.at[].set`` an entry, each some
+    milliseconds of Python for a fraction of that on the device
+    (PERF.md, PR 24 and PR 26). Shared by every server of the process,
+    so a fresh server compiles nothing a warm-up server has. The
+    resident arrays are donated where the backend can reuse them."""
+    def scatter(caches, sub, slot_idx, sp):
+        return [c.at[slot_idx, :sp].set(s, mode="drop") if rows
+                else c.at[slot_idx].set(s, mode="drop")
+                for rows, c, s in zip(per_position, caches, sub)]
+
+    scatter.__name__ = scatter.__qualname__ = "ptpu_admit_scatter"
+    return jax.jit(scatter, static_argnums=(3,),
+                   donate_argnums=(0,) if donate else ())
+
+
+def _prefill_graph(config: DecodeConfig, tokens, lengths, use_ring=False):
+    """The prefill graph ``config`` describes: (last-position logits,
+    the cache entries in ``cache_spec`` order)."""
+    if config.is_opt_block:
+        from ..models import transformer as _T
+
+        logits, caches = _T.transformer_lm_prefill(
+            tokens, lengths, config.vocab_size,
+            n_layer=config.n_layer, n_head=config.n_head,
+            d_model=config.d_model, d_inner=config.d_inner,
+            max_len=config.max_len,
+            tie_embeddings=config.tie_embeddings,
+            prefix=config.prefix, use_ring_attention=use_ring)
+        return logits, [c for pair in caches for c in pair]
+    if use_ring:
+        raise ValueError("ring prefill is built for OPT's block only")
+    from ..models import jamba as _J
+
+    logits, caches = _J.hybrid_lm_prefill(tokens, lengths, config)
+    return logits, [caches[n] for n in sorted(caches)]
 
 
 def save_decode_model(dirname: str, config: DecodeConfig, executor,
                       scope=None, export_batch: int = 1,
                       export_seq: Optional[int] = None) -> None:
-    """Export a trained transformer_lm scope for decode serving.
+    """Export a trained LM scope (transformer_lm, or whatever block
+    ``config`` describes) for decode serving.
 
     Builds the canonical prefill graph (full flash-attention forward,
     last-position logits as the fetch target) and writes it through
@@ -170,7 +334,6 @@ def save_decode_model(dirname: str, config: DecodeConfig, executor,
     builders expect but the scope lacks fails HERE, not at first
     request."""
     from .. import Program, io as fluid_io, program_guard, unique_name
-    from ..models import transformer as _T
 
     export_seq = int(export_seq or min(config.max_len, 128))
     prog, startup = Program(), Program()
@@ -183,13 +346,7 @@ def save_decode_model(dirname: str, config: DecodeConfig, executor,
                                  dtype="int64", append_batch_size=False)
             lengths = layers.data(name="lengths", shape=[export_batch],
                                   dtype="int32", append_batch_size=False)
-            last_logits, _caches = _T.transformer_lm_prefill(
-                tokens, lengths, config.vocab_size,
-                n_layer=config.n_layer, n_head=config.n_head,
-                d_model=config.d_model, d_inner=config.d_inner,
-                max_len=config.max_len,
-                tie_embeddings=config.tie_embeddings,
-                prefix=config.prefix)
+            last_logits, _caches = _prefill_graph(config, tokens, lengths)
     fluid_io.save_inference_model(
         dirname, ["tokens", "lengths"], [last_logits], executor,
         main_program=prog, scope=scope)
@@ -264,13 +421,18 @@ class DecodePredictor:
         _aot.enable_compile_cache()
         state_in, _ = analyze_state(self._program, set(self._feed_names))
         dev = self._device = exe._device
+        # ONE copy of the weights on the device: `_state` holds the
+        # very arrays the load put into the scope (`device_put` of an
+        # array that already lies on `dev` is that array), and the scope
+        # is handed them back where they had to move
         self._state = {}
         for n in state_in:
             val = self._scope.find_var(n)
             if val is None:
                 raise RuntimeError(
                     "decode model is missing persistable %r" % n)
-            self._state[n] = jax.device_put(np.asarray(val), dev)
+            self._state[n] = jax.device_put(val, dev)
+            self._scope.set_var(n, self._state[n])
         self._compiled: Dict = {}
         self._lock = threading.Lock()
         self.traces = 0
@@ -279,6 +441,24 @@ class DecodePredictor:
         """Stable model identity (program content fingerprint of the
         canonical prefill graph) — the fleet's program version."""
         return obs.program_fp(self._program)
+
+    def cache_spec(self, slots: int, seq: int,
+                   kv_dtype: str = "float32") -> List[CacheEntry]:
+        """This model's cache entries at (slots, seq), in the order the
+        decode executables take and return them (``cache_spec``)."""
+        return cache_spec(self.config, slots, seq, kv_dtype)
+
+    def _rows_only(self, what: str):
+        """Levers that snapshot, roll back or reorder a cache work on
+        rows per position, through graphs written for OPT's block."""
+        if self.config.has_state:
+            raise ValueError(
+                "%s needs a cache of rows per position (it rolls back by "
+                "length, or copies rows); this model's state-space layers "
+                "keep a recurrent state, which has no snapshot and no "
+                "rollback yet" % what)
+        if not self.config.is_opt_block:
+            raise ValueError("%s is built for OPT's block only" % what)
 
     # -- graph building ---------------------------------------------------
     def _build(self, kind: str, batch: int, seq: int, strategy: str,
@@ -302,9 +482,14 @@ class DecodePredictor:
         from ..models import transformer as _T
 
         cfg = self.config
+        if kind in ("draft", "verify"):
+            self._rows_only("a %s step" % kind)
         prog, startup = Program(), Program()
         with program_guard(prog, startup):
             with unique_name.guard():
+                if kind == "decode" and not cfg.is_opt_block:
+                    return (prog,) + self._build_described_decode(
+                        batch, seq, strategy, kv_dtype)
                 if kind == "prefill":
                     tokens = layers.data(name="tokens", shape=[batch, seq],
                                          dtype="int64",
@@ -312,17 +497,10 @@ class DecodePredictor:
                     lengths = layers.data(name="lengths", shape=[batch],
                                           dtype="int32",
                                           append_batch_size=False)
-                    logits, caches = _T.transformer_lm_prefill(
-                        tokens, lengths, cfg.vocab_size,
-                        n_layer=cfg.n_layer, n_head=cfg.n_head,
-                        d_model=cfg.d_model, d_inner=cfg.d_inner,
-                        max_len=cfg.max_len,
-                        tie_embeddings=cfg.tie_embeddings,
-                        prefix=cfg.prefix,
-                        use_ring_attention=use_ring)
+                    logits, caches = _prefill_graph(cfg, tokens, lengths,
+                                                    use_ring=use_ring)
                     feeds = ["tokens", "lengths"]
-                    fetches = [logits.name] + [
-                        c.name for pair in caches for c in pair]
+                    fetches = [logits.name] + [c.name for c in caches]
                 elif kind == "verify":
                     tokens = layers.data(name="tokens",
                                          shape=[batch, window],
@@ -420,6 +598,35 @@ class DecodePredictor:
                     if next_ids is not None:
                         fetches = [next_ids.name] + fetches
         return prog, feeds, fetches
+
+    def _build_described_decode(self, batch, seq, strategy, kv_dtype):
+        """The decode step of a block that is not OPT's, inside the
+        caller's program guard: (feed_names, fetch_names). The cache
+        feeds and the fetches of their updates both go in
+        ``cache_spec`` order (sorted names), no ``positions`` feed."""
+        from .. import layers
+        from ..models import jamba as _J
+
+        tokens = layers.data(name="tokens", shape=[batch, 1], dtype="int64",
+                             append_batch_size=False)
+        lengths = layers.data(name="lengths", shape=[batch], dtype="int32",
+                              append_batch_size=False)
+        seed = layers.data(name="seed", shape=[1], dtype="int64",
+                           append_batch_size=False)
+        spec = self.cache_spec(batch, seq, kv_dtype)
+        caches = {e.name: layers.data(name=e.name, shape=list(e.shape),
+                                      dtype=e.dtype,
+                                      append_batch_size=False)
+                  for e in spec}
+        next_ids, logits, new = _J.hybrid_lm_decode(
+            tokens, lengths, caches, self.config, strategy=strategy,
+            seed=seed, sample_k=self.sample_k, sample_p=self.sample_p,
+            temperature=self.temperature)
+        feeds = ["tokens", "lengths", "seed"] + [e.name for e in spec]
+        fetches = [logits.name] + [new[e.name].name for e in spec]
+        if next_ids is not None:
+            fetches = [next_ids.name] + fetches
+        return feeds, fetches
 
     # -- compilation ------------------------------------------------------
     def _feed_structs(self, program, feed_names):
@@ -566,7 +773,8 @@ class DecodePredictor:
     def _prefill(self, tokens, lens, slab_seq):
         """Run prefill at the PROMPTS' own pow2 sequence bucket, then
         zero-pad the returned K/V rows out to the slab length — prompt
-        cost scales with the prompt, not with the decode budget."""
+        cost scales with the prompt, not with the decode budget.
+        Returns (outs, caches in ``cache_spec`` order)."""
         bb = tokens.shape[0]
         sp = min(_pow2_bucket(int(lens.max()), floor=16), slab_seq)
         pexe, _ = self.acquire("prefill", bb, sp)
@@ -577,8 +785,12 @@ class DecodePredictor:
                                    stage="prefill")
         caches = list(outs[1:])
         if sp < slab_seq:
-            pad = [(0, 0), (0, slab_seq - sp), (0, 0), (0, 0)]
-            caches = [jnp.pad(jnp.asarray(c), pad) for c in caches]
+            # rows per position pad out to the slab; a state is whole
+            caches = [
+                jnp.pad(jnp.asarray(c), [(0, 0), (0, slab_seq - sp)]
+                        + [(0, 0)] * (len(e.shape) - 2))
+                if e.per_position else c
+                for e, c in zip(self.cache_spec(bb, slab_seq), caches)]
         return outs, caches
 
     # -- generation (static batch, run to completion) ----------------------
@@ -648,16 +860,16 @@ class DecodePredictor:
         and returns the final caches. A row stops at eos, at its token
         budget, or when its slab row is full."""
         bb = cur.shape[0]
+        names = [e.name for e in self.cache_spec(bb, s)]
         step = 0
         while not finished.all():
             step += 1
             feeds = {"tokens": cur.reshape(bb, 1).astype(np.int64),
-                     "positions": lens.reshape(bb, 1).astype(np.int64),
                      "lengths": lens,
                      "seed": np.array([seed + step], np.int64)}
-            for i in range(self.config.n_layer):
-                feeds["kcache_%d" % i] = caches[2 * i]
-                feeds["vcache_%d" % i] = caches[2 * i + 1]
+            if self.config.positions:
+                feeds["positions"] = lens.reshape(bb, 1).astype(np.int64)
+            feeds.update(zip(names, caches))
             t0 = time.perf_counter()
             outs = dexe(feeds, self._state)
             obs.DECODE_STEP_MS.observe(
@@ -733,6 +945,7 @@ class DecodePredictor:
         accept+1 tokens per row. Token-for-token identical to
         ``generate(strategy="greedy")``; when the window would overrun
         the slab, the tail finishes on plain decode steps."""
+        self._rows_only("speculative decoding")
         if spec_k < 1:
             raise ValueError("spec_k must be >= 1, got %d" % spec_k)
         eos = eos_id if eos_id is not None else self.eos_id
@@ -818,6 +1031,7 @@ class DecodePredictor:
         from ..ops.decode import beam_search_backtrack, beam_search_step
         from ..ops.kv_cache import cache_gather
 
+        self._rows_only("beam search")
         if max_new_tokens < 1:
             raise ValueError("max_new_tokens must be >= 1, got %d"
                              % max_new_tokens)
@@ -948,6 +1162,12 @@ class DecodeServer:
                 "kv_dtype must be 'float32' or 'int8', got %r"
                 % (self.kv_dtype,))
         cfg = predictor.config
+        if speculative:
+            predictor._rows_only("speculative decoding")
+        if prefix_cache or prefix_store is not None:
+            predictor._rows_only("a prefix store")
+        if self.kv_dtype == "int8":
+            predictor._rows_only("an int8 KV slab")
         want = max_seq or cfg.max_len
         self.seq = min(_pow2_bucket(want, floor=16),
                        _pow2_bucket(cfg.max_len))
@@ -1020,22 +1240,26 @@ class DecodeServer:
 
         self.step_active_counts: "collections.deque" = collections.deque(
             maxlen=100_000)
-        # cache feed names in the SAME per-layer order the decode
-        # graph's fetch list flattens its updated tensors: (k, v) per
-        # layer, plus (kscale, vscale) when the slab is int8 — so
-        # zip(self._cache_feed_names, outs[2:]) rethreads each step
-        names = []
-        for i in range(cfg.n_layer):
-            names += ["kcache_%d" % i, "vcache_%d" % i]
-            if self.kv_dtype == "int8":
-                names += ["kscale_%d" % i, "vscale_%d" % i]
-        self._cache_feed_names = names
+        # the model's cache entries, in the SAME order the decode
+        # graph's fetch list flattens its updated tensors — so
+        # zip(self._cache_feed_names, outs[2:]) rethreads each step.
+        # The one source of the feed names, the fresh arrays, the
+        # admission scatter and the step's counts
+        self._spec = predictor.cache_spec(self.slots, self.seq,
+                                          self.kv_dtype)
+        self._cache_feed_names = [e.name for e in self._spec]
         self._cache_per_layer = 4 if self.kv_dtype == "int8" else 2
+        # bytes of fixed-size state (not rows per position) a slot keeps
+        self._state_bytes_per_slot = sum(
+            e.nbytes for e in self._spec
+            if not e.per_position) // self.slots
         # rows a block of the float32 decode kernel brings in, or None
-        # where a step reads whole slabs (int8 slabs and the speculative
-        # verify window are lax paths of their own)
+        # where a step reads whole slabs (int8 slabs, the speculative
+        # verify window and a slab of fewer heads than the query are
+        # lax paths of their own)
         self._stream_rows = None
-        if self.kv_dtype == "float32" and not self.speculative:
+        if (self.kv_dtype == "float32" and not self.speculative
+                and cfg.n_kv_head == cfg.n_head):
             with jax.default_device(predictor._device):  # as acquire()
                 self._stream_rows = _KV.decode_stream_rows(
                     self.seq, cfg.n_head, cfg.d_head, jnp.float32)
@@ -1115,8 +1339,9 @@ class DecodeServer:
             sp = min(16, self.seq)
             self.predictor.acquire("prefill", 1, sp)
             if self.slots > 1:
-                self.predictor.acquire("prefill",
-                                       _pow2_bucket(self.slots), sp)
+                self.predictor.acquire(
+                    "prefill",
+                    _pow2_bucket(self._admit_room(self.slots)), sp)
             if self.speculative:
                 self.predictor.acquire("draft", self.slots, self.seq)
             if self.speculative or self._prefix is not None:
@@ -1225,6 +1450,21 @@ class DecodeServer:
                               kind="prefill")
         return outs, sp
 
+    # prompts one admission prefills at most, while sequences are live
+    _ADMIT_MOST = 8
+
+    def _admit_room(self, free: int) -> int:
+        """How many queued requests the next admission takes. Between
+        two decode steps at most ``_ADMIT_MOST``: an admission stalls
+        every live sequence for its prefill, whose temporaries grow
+        with the prompts it holds (8 prompts of 2048 tokens: 1.9 GB at
+        the widths of a 3 B hybrid model; 64 would not fit a chip), and
+        the executables a server has to have compiled stay the
+        power-of-two batches up to 8. The rest waits one decode step. A
+        gang-scheduled server (``continuous=False``) fills its slots at
+        once, as it always has."""
+        return min(free, self._ADMIT_MOST) if self.continuous else free
+
     def _admit(self, pending, caches, lens, active):
         """Prefill a sub-batch of queued requests into free slots.
         ``pending`` entries are (rid, prompt, max_new, seed); returns
@@ -1235,7 +1475,7 @@ class DecodeServer:
         through the verify window) and identical prompts inside one
         sub-batch dedupe to a single prefill row."""
         free = [i for i in range(self.slots) if active[i] is None]
-        batch = pending[:len(free)]
+        batch = pending[:self._admit_room(len(free))]
         del pending[:len(batch)]
         if self._prefix is not None:
             return self._admit_prefix(batch, free, caches, lens, active)
@@ -1266,7 +1506,8 @@ class DecodeServer:
                 if seed is not None and self.strategy not in ("greedy",):
                     first[i] = self.predictor._sample_host(
                         outs[0][i:i + 1], self.strategy, seed)[0]
-        with _tracing.phase("decode.loop.scatter"):
+        with _tracing.phase("decode.loop.scatter",
+                            **self._scatter_counts(n)):
             caches = self._scatter_prefill(caches, list(outs[1:]),
                                            free[:n], sp)
         for i, (rid, prompt, max_new, seed) in enumerate(batch):
@@ -1288,17 +1529,37 @@ class DecodeServer:
                 lens[slot] = 0
         return caches
 
+    def _scatter_counts(self, n: int) -> dict:
+        """What an admission's ``decode.loop.scatter`` phase carries:
+        ``entries``, the arrays it scatters into, and ``state_slots``,
+        the slots whose fixed-size state it replaces whole (0 for a
+        model of K/V rows alone)."""
+        return {"entries": len(self._spec),
+                "state_slots": n if self._state_bytes_per_slot else 0}
+
     def _scatter_prefill(self, caches, sub, slots, sp):
-        """The slab rebuild of a plain admission: scatter the (n, sp, H,
-        Dh) prefill rows ``sub`` ((k, v) float sub-slabs per layer)
-        into the first ``sp`` positions of ``slots``; rows past sp keep
-        old garbage, masked by length. Out of place: every slab is
-        copied once per admission wave."""
+        """The cache rebuild of a plain admission: scatter the prefill's
+        entries ``sub`` (``cache_spec`` order, ``n`` rows each) into
+        ``slots``. An entry of rows per position takes its first ``sp``
+        positions; rows past sp keep old garbage, masked by length. A
+        fixed-size state is REPLACED WHOLE: no length hides a slot's
+        last occupant. ONE jitted call for all entries, which donates
+        the resident arrays and updates them in place (the int8 path
+        below still rebuilds each array out of place, eagerly)."""
         n = len(slots)
-        slot_idx = jnp.asarray(np.array(slots, np.int32))
         if self.kv_dtype != "int8":
-            return [c.at[slot_idx, :sp].set(jnp.asarray(s)[:n])
-                    for c, s in zip(caches, sub)]
+            # all rows of the prefill's power-of-two batch are scattered,
+            # its pad rows at an index past the last slot, where "drop"
+            # leaves them out: the call's shapes follow the prefill
+            # bucket alone, and an admission of 3 compiles nothing that
+            # an admission of 4 has not
+            idx = np.full((int(sub[0].shape[0]),), self.slots, np.int32)
+            idx[:n] = slots
+            scatter = _scatter_fn(
+                tuple(e.per_position for e in self._spec),
+                self.predictor._device.platform != "cpu")
+            return list(scatter(list(caches), list(sub), idx, sp))
+        slot_idx = jnp.asarray(np.array(slots, np.int32))
         # prefill emits float rows; quantize per (slot, position) at
         # scatter time — the same row-scale scheme the in-graph
         # cache_append_quant applies to decoded rows
@@ -1547,19 +1808,7 @@ class DecodeServer:
 
     def _fresh_slabs(self):
         """Zeroed cache arrays in ``self._cache_feed_names`` order."""
-        cfg = self.predictor.config
-        shape = (self.slots, self.seq, cfg.n_head, cfg.d_head)
-        dt = jnp.int8 if self.kv_dtype == "int8" else jnp.float32
-        arrs = []
-        for _ in range(cfg.n_layer):
-            arrs.append(jnp.zeros(shape, dt))
-            arrs.append(jnp.zeros(shape, dt))
-            if self.kv_dtype == "int8":
-                arrs.append(jnp.zeros((self.slots, self.seq),
-                                      jnp.float32))
-                arrs.append(jnp.zeros((self.slots, self.seq),
-                                      jnp.float32))
-        return arrs
+        return [jnp.zeros(e.shape, e.dtype) for e in self._spec]
 
     def _fail_all_active(self, active, lens, exc):
         """Shared step-failure recovery: a decode/draft/verify call
@@ -1589,13 +1838,16 @@ class DecodeServer:
         rounded up to the decode kernel's block (a free slot still costs
         one block), or the whole slab where the step does not run the
         in-place kernel. ``attended / streamed`` is how much of what is
-        fetched is live."""
+        fetched is live. ``state_bytes``: the bytes of fixed-size state
+        (a state-space layer's window and recurrent state) the step
+        reads and writes, every slot's, live or not."""
         rows = self._stream_rows
         streamed = (self.slots * self.seq if rows is None
                     else int((lens // rows + 1).sum()) * rows)
         return {"active": n_active,
                 "attended": int(lens.sum()) + n_active,
-                "streamed": streamed}
+                "streamed": streamed,
+                "state_bytes": 2 * self.slots * self._state_bytes_per_slot}
 
     def _spec_round(self, drexe, vexe, caches, lens, active, n_active):
         """One speculative round across every active slot: spec_k draft
@@ -1738,7 +1990,8 @@ class DecodeServer:
                             and (self.continuous or n_active == 0))
                 if admit_ok:
                     with _tracing.phase("decode.loop.admit",
-                                        admitted=min(free, len(pending))):
+                                        admitted=min(self._admit_room(free),
+                                                     len(pending))):
                         caches = self._admit(pending, caches, lens, active)
                     n_active = sum(1 for a in active if a is not None)
                 self._set_slot_gauges(n_active)
@@ -1759,10 +2012,11 @@ class DecodeServer:
                         if st is not None:
                             cur[i] = st["cur"]
                     feeds = {"tokens": cur.reshape(self.slots, 1),
-                             "positions": lens.reshape(
-                                 self.slots, 1).astype(np.int64),
                              "lengths": lens.copy(),
                              "seed": np.array([self._seed_ctr], np.int64)}
+                    if self.predictor.config.positions:
+                        feeds["positions"] = lens.reshape(
+                            self.slots, 1).astype(np.int64)
                     self._seed_ctr += 1
                     feeds.update(zip(self._cache_feed_names, caches))
                 try:

@@ -648,6 +648,14 @@ COVERED_ELSEWHERE = {
     "fused_fc",
     # KV-cache decode ops: tests/test_kv_cache_ops.py
     "decode_attention", "cache_append", "cache_gather",
+    # speculative verify window (PR 14): tests/test_speculative.py
+    # (window append, staircase attention, accept against the plain
+    # decode loop); shapes and rules in tests/test_kv_cache_ops.py
+    "cache_append_window", "decode_attention_window", "spec_accept",
+    # state-space layers and RMS norm: tests/test_ssm_ops.py (numpy
+    # recurrence, padded vs unpadded, scan-then-step, infer rules)
+    "rms_norm", "ssm_scan", "ssm_step", "causal_conv1d",
+    "causal_conv1d_step",
     # in-graph sampling: tests/test_sampling_ops.py
     "greedy_sample", "top_k_sample", "top_p_sample",
     # metrics: tests/test_aux.py

@@ -264,6 +264,33 @@ def _whole_slab_ops(text, slab_shape):
     return out
 
 
+def _serving_step(pred, kind, batch, seq, one_chip):
+    """(step function, feed shapes, state shapes) of the program a
+    graph-builder-only DecodePredictor builds for (kind, batch, seq),
+    placed on the described chip: what `DecodePredictor._acquire` jits."""
+    from paddle_tpu.executor import analyze_state
+    from paddle_tpu.framework.trace import RngStream, trace_block
+
+    program, feed_names, fetch_names = pred._build(kind, batch, seq,
+                                                   "greedy")
+    sds = jax.ShapeDtypeStruct
+    feeds = {n: sds(a.shape, a.dtype, sharding=one_chip)
+             for n, a in pred._feed_structs(program, feed_names).items()}
+    gb = program.global_block()
+    state = {}
+    for n in analyze_state(program, set(feed_names))[0]:
+        var = gb._find_var_recursive(n)
+        state[n] = sds(tuple(var.shape), np.float32, sharding=one_chip)
+
+    def step_fn(feeds, state):
+        env = dict(state)
+        env.update(feeds)
+        trace_block(gb, env, RngStream(jax.random.PRNGKey(0)))
+        return tuple(env[n] for n in fetch_names)
+
+    return step_fn, feeds, state
+
+
 _SERVING_CASES = [
     # id, kind, batch, seq, layers, heads, d_model, d_inner, vocab, tied,
     # same-layout copies of donated slabs left in the step
@@ -299,8 +326,6 @@ def test_serving_step_compiles(one_chip, monkeypatch, kind, batch, seq,
     its type and the fetches come (k0, v0, k1, ..) where the feeds
     flatten (kcache_0.., vcache_0..): `pairing_copies`, 8 in the serving
     cell's step, the next PR's to take to 0."""
-    from paddle_tpu.executor import analyze_state
-    from paddle_tpu.framework.trace import RngStream, trace_block
     from paddle_tpu.serving.decode import DecodeConfig, DecodePredictor
 
     monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
@@ -314,23 +339,7 @@ def test_serving_step_compiles(one_chip, monkeypatch, kind, batch, seq,
                                tie_embeddings=tied)
     pred.sample_k, pred.sample_p, pred.temperature = 40, 0.9, 1.0
     pred.draft_n_layer = 1
-    program, feed_names, fetch_names = pred._build(kind, batch, seq,
-                                                   "greedy")
-    sds = jax.ShapeDtypeStruct
-    feeds = {n: sds(a.shape, a.dtype, sharding=one_chip)
-             for n, a in pred._feed_structs(program, feed_names).items()}
-    gb = program.global_block()
-    state = {}
-    for n in analyze_state(program, set(feed_names))[0]:
-        var = gb._find_var_recursive(n)
-        state[n] = sds(tuple(var.shape), np.float32, sharding=one_chip)
-
-    def step_fn(feeds, state):
-        env = dict(state)
-        env.update(feeds)
-        trace_block(gb, env, RngStream(jax.random.PRNGKey(0)))
-        return tuple(env[n] for n in fetch_names)
-
+    step_fn, feeds, state = _serving_step(pred, kind, batch, seq, one_chip)
     compiled = _compiled(step_fn, feeds, state, donate_argnums=(0,))
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= n_layer  # one per layer
@@ -350,3 +359,59 @@ def test_serving_step_compiles(one_chip, monkeypatch, kind, batch, seq,
     # one slab's worth of temporaries for those copies, not two a layer
     temps = compiled.memory_analysis().temp_size_in_bytes
     assert temps < 300 * 2**20, temps
+
+
+_HYBRID_CASES = [
+    # id, kind, batch, seq: one period of 14 layers at the published
+    # widths of the hybrid serving cell (benchmark/configs/jamba2-3b.json)
+    ("decode-64x2048", "decode", 64, 2048),
+    ("prefill-8x512", "prefill", 8, 512),
+]
+
+
+@pytest.mark.parametrize("kind,batch,seq", [c[1:] for c in _HYBRID_CASES],
+                         ids=[c[0] for c in _HYBRID_CASES])
+def test_hybrid_serving_step_compiles(one_chip, monkeypatch, kind, batch,
+                                      seq):
+    """The programs DecodePredictor builds for a hybrid of 13 state-space
+    layers and one attention layer (20 query heads on 1 K/V head of 128,
+    d_inner 5120, state 16): they compile for a v5e and fit it beside
+    nothing else. The decode step donates every cache entry and gets each
+    back in place: its fetches come in the feeds' own (sorted) order, so
+    no recurrent state (64 x 5120 x 16) and no slab is copied to repair a
+    pairing, and the step's temporaries stay small. The prefill holds one
+    `while` a state-space layer (the plain `lax.scan`s) and one Mosaic
+    call (the flash forward of the attention layer)."""
+    from paddle_tpu.serving.decode import DecodeConfig, DecodePredictor
+
+    monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
+    pred = DecodePredictor.__new__(DecodePredictor)  # graph builder only
+    pred.config = DecodeConfig(
+        vocab_size=65536, n_layer=14, n_head=20, d_model=2560, d_inner=8192,
+        max_len=2048, tie_embeddings=True, n_kv_head=1,
+        attn_layer_period=14, attn_layer_offset=7, mamba_d_state=16,
+        mamba_d_conv=4, mamba_dt_rank=160, mamba_expand=2, norm="rms_norm",
+        norm_eps=1e-6, ffn="gated_silu", positions=False, biases=False)
+    pred.sample_k, pred.sample_p, pred.temperature = 40, 0.9, 1.0
+    step_fn, feeds, state = _serving_step(pred, kind, batch, seq, one_chip)
+    compiled = jax.jit(step_fn, donate_argnums=(0,)).lower(
+        feeds, state).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < HBM_BYTES, "does not fit one chip: %r" % (mem,)
+    text = compiled.as_text()
+    if kind == "prefill":
+        assert text.count("tpu_custom_call") == 1   # the flash forward
+        assert text.count(" while(") == 13          # one scan a layer
+        return
+    # the lax path of one shared K/V head: no Mosaic call in the step
+    assert "tpu_custom_call" not in text
+    spec = pred.cache_spec(batch, seq)
+    cache_bytes = sum(e.nbytes for e in spec)
+    assert mem.alias_size_in_bytes >= cache_bytes   # every entry in place
+    for shape in {e.shape for e in spec if e.nbytes > 2**24}:
+        copies = [name for op, name, _ in _whole_slab_ops(text, shape)
+                  if op == "copy"]
+        assert not copies, (shape, copies)
+    assert mem.temp_size_in_bytes < 200 * 2**20, mem.temp_size_in_bytes

@@ -522,8 +522,9 @@ def test_step_counts_streamed_rows(rows, lens, n_active, want):
 
     srv = DecodeServer.__new__(DecodeServer)
     srv.slots, srv.seq, srv._stream_rows = 4, 256, rows
+    srv._state_bytes_per_slot = 0  # K/V rows alone: no fixed-size state
     got = srv._step_counts(np.array(lens, np.int32), n_active)
-    assert got == dict(want, active=n_active)
+    assert got == dict(want, active=n_active, state_bytes=0)
 
 
 def test_phases_land_on_the_profilers_host_plane(decode_dir, tmp_path):
