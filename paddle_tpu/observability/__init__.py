@@ -32,6 +32,7 @@ __all__ = [
     "program_fp", "observe_run", "reset_all", "hlo_cost_stats", "nbytes_of",
     # shared instruments
     "COMPILE_TOTAL", "COMPILE_LATENCY_MS", "CACHE_HITS", "CACHE_MISSES",
+    "CACHE_ENTRIES_FED", "CACHE_ENTRIES_ALIASED",
     "CACHE_EVICTIONS", "STEP_LATENCY_MS", "STEPS_TOTAL", "FEED_BYTES",
     "FETCH_BYTES", "RUN_LOOP_WINDOW_STEPS", "READER_PREFETCH_EVENTS",
     "READER_PREFETCH_DEPTH", "READER_PULL_MS", "LOADER_BATCHES",
@@ -65,6 +66,15 @@ COMPILE_TOTAL = REGISTRY.counter(
 COMPILE_LATENCY_MS = REGISTRY.histogram(
     "paddle_tpu_compile_latency_ms",
     "Wall time of each compilation (first call: trace+compile+run)")
+CACHE_ENTRIES_FED = REGISTRY.counter(
+    "paddle_tpu_decode_cache_entries_fed_total",
+    "Cache entries (KV slabs, scales, states) fed to the donating decode "
+    "programs acquired, by kind")
+CACHE_ENTRIES_ALIASED = REGISTRY.counter(
+    "paddle_tpu_decode_cache_entries_aliased_total",
+    "Of those, the entries whose update the compiled program writes into "
+    "a donated feed's buffer (its input_output_alias), by kind: equal to "
+    "the fed count on a chip, 0 on the CPU where nothing is donated")
 CACHE_HITS = REGISTRY.counter(
     "paddle_tpu_compile_cache_hits_total",
     "Compile-cache hits, by kind, program fingerprint, and "

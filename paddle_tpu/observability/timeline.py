@@ -121,11 +121,13 @@ class StepTimeline:
                        xla_ms: Optional[float] = None,
                        cache: str = "miss",
                        flops: Optional[float] = None,
-                       bytes_accessed: Optional[float] = None):
+                       bytes_accessed: Optional[float] = None,
+                       **described):
         """``trace_ms`` is jax trace + StableHLO lowering (``fn.lower()``);
         ``xla_ms`` is the XLA backend compile (``lowered.compile()``) —
         usually the dominant term, and the one to blame for a slow first
-        step."""
+        step. ``described`` is what the caller read off the executable
+        (a decode step's ``cache_fed`` and ``cache_aliased``)."""
         ev = {"type": "compile", "ts": time.time(), "kind": kind,
               "cache": cache}
         if program is not None:
@@ -138,6 +140,7 @@ class StepTimeline:
             ev["flops"] = flops
         if bytes_accessed is not None:
             ev["bytes_accessed"] = bytes_accessed
+        ev.update(described)
         self._append(ev)
 
     # -- reading ---------------------------------------------------------

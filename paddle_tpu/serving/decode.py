@@ -44,6 +44,7 @@ import collections
 import functools
 import json
 import os
+import re
 import threading
 import time
 from typing import Dict, List, Optional, Sequence
@@ -295,6 +296,75 @@ def _scatter_fn(per_position: tuple, donate: bool):
     scatter.__name__ = scatter.__qualname__ = "ptpu_admit_scatter"
     return jax.jit(scatter, static_argnums=(3,),
                    donate_argnums=(0,) if donate else ())
+
+
+def _pairing_order(feed_names, fetch_names, spec_names):
+    """(traced, take, n): the order a step's outputs are TRACED in, the
+    index into them of each of ``fetch_names`` (None where the two
+    orders are one), and how many cache entries the step is fed: its
+    last ``n`` outputs are their updates.
+
+    jax pairs a donated input with the FIRST output of its shape and
+    dtype, inputs taken in the order the feed dict flattens: sorted by
+    name. A cache entry comes back in its feed's own buffer only if,
+    within every such class, the i-th output is the update of the i-th
+    feed; otherwise XLA honours the crossed aliases and copies whole
+    slabs around them (PERF.md, PR 27: eight 268 MB copies a step). So
+    the cache updates (the tail of ``fetch_names``, which ``cache_spec``
+    ties one to one to the cache feeds) are traced sorted by their
+    FEED's name, which holds in every class at once, and the caller is
+    handed them back in ``fetch_names``' order: an indexing of a tuple
+    of array handles. The outputs before them (ids, logits) keep their
+    places. A spec that sorts already (every block but OPT's) traces as
+    it fetches."""
+    fed = set(feed_names)
+    fed = [n for n in spec_names if n in fed]
+    head = len(fetch_names) - len(fed)
+    order = sorted(range(len(fed)), key=fed.__getitem__)
+    traced = list(fetch_names[:head]) + [fetch_names[head + i]
+                                         for i in order]
+    if traced == list(fetch_names):
+        return traced, None, len(fed)
+    take = list(range(head)) + [head + order.index(i)
+                                for i in range(len(fed))]
+    return traced, take, len(fed)
+
+
+_Step = collections.namedtuple(
+    "_Step", "fn program feed_names fetch_names traced take n_cache")
+
+
+class _InFetchOrder:
+    """A loaded step whose outputs were traced in pairing order
+    (``_pairing_order``), called as the executable is and answering in
+    ``fetch_names``' order. No device work: the outputs are handles.
+    Whatever else is asked of it is the executable's own."""
+
+    def __init__(self, loaded, take):
+        self._loaded, self._take = loaded, take
+
+    def __call__(self, feeds, state):
+        outs = self._loaded(feeds, state)
+        return tuple(outs[i] for i in self._take)
+
+    def __getattr__(self, name):
+        return getattr(self._loaded, name)
+
+
+_ALIAS_MAP = re.compile(r"input_output_alias=\{(.*?)\}, entry_computation")
+
+
+def _aliased_outputs(loaded) -> set:
+    """Indices of the outputs a compiled step writes into a donated
+    input's buffer, from the ``input_output_alias`` of its module line:
+    ``{ {2}: (0, {}, may-alias), .. }``. Empty where nothing was donated
+    (the CPU) or the text cannot be had."""
+    try:
+        found = _ALIAS_MAP.search(loaded.as_text())
+    except Exception:
+        return set()
+    return ({int(i or 0) for i in re.findall(r"\{(\d*)\}:", found.group(1))}
+            if found else set())
 
 
 def _prefill_graph(config: DecodeConfig, tokens, lengths, use_ring=False):
@@ -678,29 +748,19 @@ class DecodePredictor:
                                program=self.fingerprint())
             return hit
         from .engine import Engine
-        from ..framework.trace import RngStream, trace_block
 
-        program, feed_names, fetch_names = self._build(
-            kind, batch, seq, strategy, kv_dtype=kv_dtype,
-            window=window, use_ring=use_ring)
-        engine = Engine(program, disk=self._disk, feed_names=feed_names,
-                        fetch_names=fetch_names)
-        feed_structs = self._feed_structs(program, feed_names)
+        step = self._step(kind, batch, seq, strategy, kv_dtype, window,
+                          use_ring, name=_executable_name(*ck))
+        engine = Engine(step.program, disk=self._disk,
+                        feed_names=step.feed_names,
+                        fetch_names=step.fetch_names)
+        feed_structs = self._feed_structs(step.program, step.feed_names)
         feed_sig = tuple((n, tuple(s.shape), str(np.dtype(s.dtype)))
                          for n, s in sorted(feed_structs.items()))
-        key = engine.key(kind, feed_sig, tuple(fetch_names))
-
-        def step_fn(feeds, state):
-            self.traces += 1
-            env = dict(state)
-            env.update(feeds)
-            rng = RngStream(jax.random.PRNGKey(0))
-            trace_block(program.global_block(), env, rng)
-            return tuple(env[n] for n in fetch_names)
-
-        # jit names the module after the function: a device trace's
-        # module line then tells a prefill from a decode step
-        step_fn.__name__ = step_fn.__qualname__ = _executable_name(*ck)
+        # keyed by the order that was TRACED: an executable of another
+        # order under this key would hand back permuted slabs
+        traced = tuple(step.traced)
+        key = engine.key(kind, feed_sig, traced)
 
         def lower():
             # donate the feeds (the KV slabs dominate them) so XLA
@@ -713,21 +773,64 @@ class DecodePredictor:
             # returned slabs are discarded each round)
             donate = ((0,) if kind != "draft"
                       and current_device().platform != "cpu" else ())
-            fn = jax.jit(step_fn, donate_argnums=donate)
+            fn = jax.jit(step.fn, donate_argnums=donate)
             state_structs = {n: jax.ShapeDtypeStruct(a.shape, a.dtype)
                              for n, a in self._state.items()}
             return fn.lower(feed_structs, state_structs)
 
+        def pairing(loaded):
+            # decided when the program was compiled, so counted from
+            # it: the cache entries fed, and those of them that come
+            # back in a donated feed's buffer (all of them on a chip,
+            # or the spec and the graph disagree on a shape or dtype;
+            # none on the CPU, where nothing is donated)
+            tail = range(len(traced) - step.n_cache, len(traced))
+            aliased = len(_aliased_outputs(loaded).intersection(tail))
+            obs.CACHE_ENTRIES_FED.inc(step.n_cache, kind=kind)
+            obs.CACHE_ENTRIES_ALIASED.inc(aliased, kind=kind)
+            return {"cache_fed": step.n_cache, "cache_aliased": aliased}
+
         loaded, path, timings = engine.acquire(
-            kind, key, lower,
-            meta=engine.meta(kind, feed_sig, tuple(fetch_names)))
+            kind, key, lower, meta=engine.meta(kind, feed_sig, traced),
+            describe=pairing if kind != "draft" else None)
         if path == "cold":
             obs.COMPILE_TOTAL.inc(kind=kind)
             obs.COMPILE_LATENCY_MS.observe(
                 timings["trace_ms"] + timings["xla_ms"], kind=kind)
+        exe = (loaded if step.take is None
+               else _InFetchOrder(loaded, step.take))
         with self._lock:
-            self._compiled[ck] = (loaded, fetch_names)
-        return loaded, fetch_names
+            self._compiled[ck] = (exe, step.fetch_names)
+        return exe, step.fetch_names
+
+    def _step(self, kind, batch, seq, strategy, kv_dtype="float32",
+              window=0, use_ring=False, name="ptpu_step") -> _Step:
+        """The function ``_acquire`` jits for one signature (``fn``) and
+        what is known of it before a trace. ``fn(feeds, state)`` returns
+        the fetches in the order ``traced`` (``_pairing_order``);
+        ``take`` re-indexes them to ``fetch_names``."""
+        from ..framework.trace import RngStream, trace_block
+
+        program, feed_names, fetch_names = self._build(
+            kind, batch, seq, strategy, kv_dtype=kv_dtype,
+            window=window, use_ring=use_ring)
+        traced, take, n_cache = _pairing_order(
+            feed_names, fetch_names,
+            [e.name for e in self.cache_spec(batch, seq, kv_dtype)])
+
+        def step_fn(feeds, state):
+            self.traces += 1
+            env = dict(state)
+            env.update(feeds)
+            rng = RngStream(jax.random.PRNGKey(0))
+            trace_block(program.global_block(), env, rng)
+            return tuple(env[n] for n in traced)
+
+        # jit names the module after the function: a device trace's
+        # module line then tells a prefill from a decode step
+        step_fn.__name__ = step_fn.__qualname__ = name
+        return _Step(step_fn, program, feed_names, fetch_names, traced,
+                     take, n_cache)
 
     # -- host-side sampling (first token, from prefill logits) ------------
     def _sample_host(self, logits, strategy: str, seed: int):

@@ -211,12 +211,16 @@ class Engine:
                 "env": _aot.env_fingerprint(), "created": time.time()}
 
     # -- acquisition ------------------------------------------------------
-    def acquire(self, kind: str, key: str, lower, meta: Optional[Dict] = None):
+    def acquire(self, kind: str, key: str, lower, meta: Optional[Dict] = None,
+                describe=None):
         """THE load-or-compile path: disk hit deserializes (path=warm),
         miss runs ``lower()`` -> ``.compile()`` and stores the result
         (path=cold). Returns ``(compiled, path, timings)`` where path is
         ``"warm" | "cold"`` and timings is ``{"trace_ms", "xla_ms"}`` on
         the cold path (None on warm — a deserialize has no split).
+        ``describe(executable)`` gives fields read off the executable
+        itself; with it the timeline's compile record carries them, on
+        the warm path and (a record of its own) on the cold one.
 
         ``lower`` may raise (program errors propagate exactly as the
         lazy-jit first call would); disk I/O failures are absorbed by
@@ -229,7 +233,9 @@ class Engine:
             obs.CACHE_HITS.inc(kind=kind, tier="disk", program=fp)
             obs.AOT_COMPILE_MS.observe((time.perf_counter() - t0) * 1e3,
                                        path="warm", kind=kind)
-            obs.TIMELINE.record_compile(kind, fp, cache="aot-load")
+            obs.TIMELINE.record_compile(
+                kind, fp, cache="aot-load",
+                **(describe(loaded) if describe else {}))
             return loaded, "warm", None
         if use_disk:  # a disabled tier compiles without tier accounting
             obs.CACHE_MISSES.inc(kind=kind, tier="disk", program=fp)
@@ -240,5 +246,8 @@ class Engine:
         t2 = time.perf_counter()
         obs.AOT_COMPILE_MS.observe((t2 - t0) * 1e3, path="cold", kind=kind)
         self.disk.store(key, compiled, meta=meta)
-        return compiled, "cold", {"trace_ms": (t1 - t0) * 1e3,
-                                  "xla_ms": (t2 - t1) * 1e3}
+        timings = {"trace_ms": (t1 - t0) * 1e3, "xla_ms": (t2 - t1) * 1e3}
+        if describe:
+            obs.TIMELINE.record_compile(kind, fp, **timings,
+                                        **describe(compiled))
+        return compiled, "cold", timings

@@ -157,6 +157,93 @@ def test_warm_start_compiles_nothing(model_dir, pred):
     assert p2.traces == 0
 
 
+@pytest.fixture(scope="module")
+def hybrid_dir(tmp_path_factory):
+    """The tiny state-space / attention hybrid of test_hybrid_decode.py."""
+    import test_hybrid_decode as H
+
+    return H.export_hybrid(str(tmp_path_factory.mktemp("hybrid_model")),
+                           H.seeded_weights())
+
+
+@pytest.mark.parametrize("case,kv_dtype,reordered", [
+    ("opt", "float32", True), ("opt", "int8", True),
+    ("hybrid", "float32", False)],
+    ids=["opt-float32", "opt-int8", "hybrid"])
+def test_decode_step_hands_each_cache_entry_back_in_fetch_order(
+        case, kv_dtype, reordered, request, monkeypatch):
+    """The order `acquire`'s callers see is a contract: `fetch_names` is
+    what the graph builder gave, and `outs[2:]` zipped with
+    `cache_spec`'s names is each entry's OWN update, whatever order the
+    step was traced in (serving/decode.py `_pairing_order`: OPT's
+    updates are traced sorted by their feeds' names, so that on a chip
+    each donated slab comes back in its own buffer; the hybrid's spec
+    sorts already and its executable is handed out bare). Here on the
+    CPU nothing is donated: this pins the re-indexing. The AOT key
+    follows the traced order: a predictor that traces in the fetch
+    order must not load the executable of one that does not."""
+    from paddle_tpu.framework.trace import RngStream, trace_block
+    from paddle_tpu.serving import decode as decode_mod
+
+    mdir = request.getfixturevalue(
+        "model_dir" if case == "opt" else "hybrid_dir")
+    slots, seq = 4, 32
+    p = DecodePredictor(mdir)
+    spec = p.cache_spec(slots, seq, kv_dtype)
+    step = p._step("decode", slots, seq, "greedy", kv_dtype=kv_dtype)
+    exe, fetch_names = p.acquire("decode", slots, seq, kv_dtype=kv_dtype)
+    assert fetch_names == p._build("decode", slots, seq, "greedy",
+                                   kv_dtype=kv_dtype)[2]
+    assert (step.traced != fetch_names) == reordered
+    assert isinstance(exe, decode_mod._InFetchOrder) == reordered
+    assert sorted(step.traced) == sorted(fetch_names)
+    assert "HloModule" in exe.as_text()   # the executable's own surface
+
+    # every entry a constant of its own; one step appends one row
+    lens = np.array([3, 5, 1, 7], np.int32)
+    feeds = {"tokens": np.array([[4], [9], [2], [7]], np.int64),
+             "lengths": lens, "seed": np.zeros((1,), np.int64)}
+    if p.config.positions:
+        feeds["positions"] = lens.reshape(slots, 1).astype(np.int64)
+    for j, e in enumerate(spec):
+        feeds[e.name] = jnp.full(e.shape, j + 2, e.dtype)
+    outs = exe(feeds, p._state)
+    assert len(outs) == 2 + len(spec) == len(fetch_names)
+    # what each fetch IS, read from the traced program by its name
+    env = dict(p._state)
+    env.update(feeds)
+    trace_block(step.program.global_block(), env,
+                RngStream(jax.random.PRNGKey(0)))
+    for name, got in zip(fetch_names, outs):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(env[name]),
+                                   rtol=1e-5, atol=1e-6, err_msg=name)
+    old_rows = np.ones((slots, seq), bool)
+    old_rows[np.arange(slots), lens] = False
+    for j, (e, got) in enumerate(zip(spec, outs[2:])):
+        got = np.asarray(got)
+        assert got.shape == e.shape and str(got.dtype) == e.dtype, e.name
+        if e.per_position:  # its own constant outside the appended row
+            assert (got[old_rows] == j + 2).all(), e.name
+            assert (got[~old_rows] != j + 2).any(), e.name
+
+    # a predictor that traces in the callers' order (the programs of
+    # before PR 27) is another key: it compiles, where a twin loads
+    twin = DecodePredictor(mdir)
+    twin.acquire("decode", slots, seq, kv_dtype=kv_dtype)
+    assert twin.traces == 0
+    real = decode_mod._pairing_order
+    monkeypatch.setattr(
+        decode_mod, "_pairing_order",
+        lambda feeds, fetches, names: (
+            list(fetches), None, real(feeds, fetches, names)[2]))
+    other = DecodePredictor(mdir)
+    oexe, _ = other.acquire("decode", slots, seq, kv_dtype=kv_dtype)
+    assert other.traces == (1 if reordered else 0)
+    for want, got in zip(outs, oexe(feeds, other._state)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-6)
+
+
 def test_signature_count_stays_bucketed(pred):
     """1..4 prompts of assorted lengths share ONE (batch-bucket, slab-
     bucket) signature set — the pow2 discipline that bounds compiles."""

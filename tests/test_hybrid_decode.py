@@ -50,15 +50,19 @@ CFG = dict(model_type="jamba", hidden_act="silu", num_experts=1,
 SLOTS, SEQ = 4, 64
 
 
-@pytest.fixture(scope="module")
-def seeded():
+def seeded_weights():
     specs = jamba_lm.parameter_specs(CFG, "serve")
     return weights.seeded_weights(specs, 2 ** 31 + 11, jamba_lm.init_rule)
 
 
 @pytest.fixture(scope="module")
-def model_dir(tmp_path_factory, seeded):
-    d = str(tmp_path_factory.mktemp("hybrid_model"))
+def seeded():
+    return seeded_weights()
+
+
+def export_hybrid(d, seeded):
+    """The tiny hybrid model, exported for decode serving into ``d``
+    (tests/test_decode_serving.py serves it too)."""
     scope = fluid.Scope()
     for n in seeded:
         scope.set_var(n, seeded[n])
@@ -67,6 +71,12 @@ def model_dir(tmp_path_factory, seeded):
         save_decode_model(d, jamba_lm.decode_config(CFG, "serve"), exe,
                           scope=scope)
     return d
+
+
+@pytest.fixture(scope="module")
+def model_dir(tmp_path_factory, seeded):
+    return export_hybrid(str(tmp_path_factory.mktemp("hybrid_model")),
+                         seeded)
 
 
 @pytest.fixture(scope="module")

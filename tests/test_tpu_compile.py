@@ -67,10 +67,10 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compiled(fn, *avals, **jit_kw):
+def _compiled(fn, *avals, mosaic=True, **jit_kw):
     compiled = jax.jit(fn, **jit_kw).lower(*avals).compile()
-    assert "tpu_custom_call" in compiled.as_text(), (
-        "no Mosaic kernel in the compiled text")
+    assert ("tpu_custom_call" in compiled.as_text()) == mosaic, (
+        "Mosaic kernel in the compiled text? wanted %s" % mosaic)
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
@@ -264,68 +264,73 @@ def _whole_slab_ops(text, slab_shape):
     return out
 
 
-def _serving_step(pred, kind, batch, seq, one_chip):
-    """(step function, feed shapes, state shapes) of the program a
-    graph-builder-only DecodePredictor builds for (kind, batch, seq),
-    placed on the described chip: what `DecodePredictor._acquire` jits."""
+def _serving_step(pred, kind, batch, seq, one_chip, **kw):
+    """(step function, feed shapes, state shapes, how many cache entries
+    it is fed) of the program a graph-builder-only DecodePredictor
+    builds for (kind, batch, seq), placed on the described chip. The
+    function is `DecodePredictor._step`'s: the very one `_acquire` jits,
+    its outputs in the order it traces them."""
     from paddle_tpu.executor import analyze_state
-    from paddle_tpu.framework.trace import RngStream, trace_block
 
-    program, feed_names, fetch_names = pred._build(kind, batch, seq,
-                                                   "greedy")
+    pred.traces = 0
+    step = pred._step(kind, batch, seq, "greedy", **kw)
     sds = jax.ShapeDtypeStruct
     feeds = {n: sds(a.shape, a.dtype, sharding=one_chip)
-             for n, a in pred._feed_structs(program, feed_names).items()}
-    gb = program.global_block()
+             for n, a in pred._feed_structs(step.program,
+                                            step.feed_names).items()}
+    gb = step.program.global_block()
     state = {}
-    for n in analyze_state(program, set(feed_names))[0]:
+    for n in analyze_state(step.program, set(step.feed_names))[0]:
         var = gb._find_var_recursive(n)
         state[n] = sds(tuple(var.shape), np.float32, sharding=one_chip)
-
-    def step_fn(feeds, state):
-        env = dict(state)
-        env.update(feeds)
-        trace_block(gb, env, RngStream(jax.random.PRNGKey(0)))
-        return tuple(env[n] for n in fetch_names)
-
-    return step_fn, feeds, state
+    return step.fn, feeds, state, step.n_cache
 
 
 _SERVING_CASES = [
     # id, kind, batch, seq, layers, heads, d_model, d_inner, vocab, tied,
-    # same-layout copies of donated slabs left in the step
+    # what else `_step` takes
     ("decode-8x1024", "decode", 8, 1024, 2, 8, D_MODEL, D_INNER, VOCAB,
-     False, 2),
+     False, {}),
     ("prefill-8x512", "prefill", 8, 512, 2, 8, D_MODEL, D_INNER, VOCAB,
-     False, 0),
+     False, {}),
     # the serving cell's own decode step (OPT-6.7B widths: 32 heads of
     # 128, 2048 positions, tied table, 4 layers)
     ("decode-8x2048-h32", "decode", 8, 2048, 4, 32, 4096, 16384, 50272,
-     True, 8),
+     True, {}),
+    # the other donating steps, at small depth: a speculative round's
+    # verify window, and the decode step over int8 slabs, whose
+    # (slots, seq) scales are a class of donated feeds of their own
+    ("verify-8x1024-w5", "verify", 8, 1024, 2, 8, D_MODEL, D_INNER, VOCAB,
+     False, {"window": 5}),
+    ("decode-8x1024-int8", "decode", 8, 1024, 2, 8, D_MODEL, D_INNER,
+     VOCAB, False, {"kv_dtype": "int8"}),
 ]
 
 
 @pytest.mark.parametrize(
-    "kind,batch,seq,n_layer,n_head,d_model,d_inner,vocab,tied,"
-    "pairing_copies",
+    "kind,batch,seq,n_layer,n_head,d_model,d_inner,vocab,tied,step_kw",
     [c[1:] for c in _SERVING_CASES], ids=[c[0] for c in _SERVING_CASES])
 def test_serving_step_compiles(one_chip, monkeypatch, kind, batch, seq,
                                n_layer, n_head, d_model, d_inner, vocab,
-                               tied, pairing_copies):
+                               tied, step_kw):
     """The programs DecodePredictor builds for chip_smoke.py's serve
     phase, 2 layers at full width: the decode step at 8 slots x 1024 (the
-    Pallas decode kernel, feeds donated) and the burst prefill; and the
-    decode step at the benchmark's serving widths.
+    Pallas decode kernel, feeds donated) and the burst prefill; the
+    decode step at the benchmark's serving widths; and the verify and
+    int8 decode steps. What is compiled is the function `_acquire` jits.
 
-    A decode step moves no slab: the kernel reads the (slots, seq, heads,
-    d_head) feed where it lies, so the compiled step holds no `reshape`,
-    `transpose` or layout-changing `copy` of a whole slab (each was a
-    268 MB relayout, two a layer a step on the chip: PERF.md, PR 25).
-    What stays is known and counted: same-layout copies of donated
-    slabs, made because jax pairs a donated feed with the first fetch of
-    its type and the fetches come (k0, v0, k1, ..) where the feeds
-    flatten (kcache_0.., vcache_0..): `pairing_copies`, 8 in the serving
-    cell's step, the next PR's to take to 0."""
+    A step that is fed its cache moves no slab. The float32 kernel reads
+    the (slots, seq, heads, d_head) feed where it lies, so the compiled
+    step holds no `reshape`, `transpose` or layout-changing `copy` of a
+    whole slab (each was a 268 MB relayout, two a layer a step on the
+    chip: PERF.md, PR 25). And every entry comes back in its own feed's
+    buffer: jax pairs a donated feed with the first output of its type,
+    the feeds flatten sorted by name (kcache_0.., vcache_0..), and the
+    step traces the updates in that order whatever `cache_spec`'s
+    (`_pairing_order`), so no same-layout `copy` repairs a crossed
+    pairing (there were 8 in the serving cell's step, 46% of its device
+    time: PERF.md, PR 27), the aliased bytes cover the spec's, and the
+    temporaries are a few MiB."""
     from paddle_tpu.serving.decode import DecodeConfig, DecodePredictor
 
     monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
@@ -339,26 +344,38 @@ def test_serving_step_compiles(one_chip, monkeypatch, kind, batch, seq,
                                tie_embeddings=tied)
     pred.sample_k, pred.sample_p, pred.temperature = 40, 0.9, 1.0
     pred.draft_n_layer = 1
-    step_fn, feeds, state = _serving_step(pred, kind, batch, seq, one_chip)
-    compiled = _compiled(step_fn, feeds, state, donate_argnums=(0,))
+    step_fn, feeds, state, n_cache = _serving_step(pred, kind, batch, seq,
+                                                   one_chip, **step_kw)
+    # a verify window attends through the lax path: no Mosaic call
+    mosaic = kind != "verify"
+    compiled = _compiled(step_fn, feeds, state, mosaic=mosaic,
+                         donate_argnums=(0,))
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= n_layer  # one per layer
-    if kind != "decode":
+    if mosaic:
+        assert text.count("tpu_custom_call") >= n_layer  # one per layer
+    if kind == "prefill":
+        assert n_cache == 0
         return
-    assert "input_output_alias" in text
-    slab = (batch, seq, n_head, d_model // n_head)
+    from paddle_tpu.serving.decode import _aliased_outputs
+
+    spec = pred.cache_spec(batch, seq, step_kw.get("kv_dtype", "float32"))
+    assert n_cache == len(spec)
+    n_out = len(jax.tree_util.tree_leaves(compiled.out_info))
+    assert set(range(n_out - n_cache, n_out)) <= _aliased_outputs(compiled)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(e.nbytes for e in spec)
+    slab = spec[0].shape
     ops = _whole_slab_ops(text, slab)
-    moved = [(op, name) for op, name, changed in ops
-             if op in ("reshape", "transpose") or (op == "copy" and changed)]
-    assert not moved, "whole-slab relayouts in the decode step: %r" % moved
-    # the kernel's views of K and V are free
-    assert sum(op == "bitcast" for op, _, _ in ops) >= 2 * n_layer
-    same_layout_copies = [name for op, name, changed in ops
-                          if op == "copy" and not changed]
-    assert len(same_layout_copies) == pairing_copies, same_layout_copies
-    # one slab's worth of temporaries for those copies, not two a layer
-    temps = compiled.memory_analysis().temp_size_in_bytes
-    assert temps < 300 * 2**20, temps
+    copies = [name for op, name, _ in ops if op == "copy"]
+    assert not copies, "whole-slab copies in the step: %r" % copies
+    if spec[0].dtype == "float32":
+        moved = [(op, name) for op, name, _ in ops
+                 if op in ("reshape", "transpose")]
+        assert not moved, "whole-slab relayouts in the step: %r" % moved
+    if kind == "decode" and spec[0].dtype == "float32":
+        # the kernel's views of K and V are free
+        assert sum(op == "bitcast" for op, _, _ in ops) >= 2 * n_layer
+    assert mem.temp_size_in_bytes < 16 * 2**20, mem.temp_size_in_bytes
 
 
 _HYBRID_CASES = [
@@ -393,7 +410,8 @@ def test_hybrid_serving_step_compiles(one_chip, monkeypatch, kind, batch,
         mamba_d_conv=4, mamba_dt_rank=160, mamba_expand=2, norm="rms_norm",
         norm_eps=1e-6, ffn="gated_silu", positions=False, biases=False)
     pred.sample_k, pred.sample_p, pred.temperature = 40, 0.9, 1.0
-    step_fn, feeds, state = _serving_step(pred, kind, batch, seq, one_chip)
+    step_fn, feeds, state, _ = _serving_step(pred, kind, batch, seq,
+                                             one_chip)
     compiled = jax.jit(step_fn, donate_argnums=(0,)).lower(
         feeds, state).compile()
     mem = compiled.memory_analysis()

@@ -647,6 +647,48 @@ def test_lowered_text_names_the_kernels(case, monkeypatch):
         assert all("transpose(jvp(%s))/" % b in text for b in bwd)
 
 
+@pytest.mark.parametrize("kind,kw,fed", [
+    ("decode", {}, 2 * DL), ("decode", {"kv_dtype": "int8"}, 4 * DL),
+    ("verify", {"window": 3}, 2 * DL), ("prefill", {}, 0)],
+    ids=["decode", "decode-int8", "verify", "prefill"])
+def test_acquire_counts_the_cache_entries_fed_and_aliased(decode_dir, kind,
+                                                          kw, fed):
+    """Whether a donated cache entry comes back in a donated buffer is
+    decided when the step is compiled, so it is counted there: beside
+    `paddle_tpu_compile_total`, by `kind`, the entries the program is
+    fed and those of them its `input_output_alias` hands back in place,
+    and both on the timeline's compile record, cold and warm. On the CPU
+    nothing is donated, so `aliased` is 0 here; on a chip it equals
+    `fed` (tests/test_tpu_compile.py reads the v5e program's map through
+    the same `_aliased_outputs`). A draft step never donates and is not
+    counted."""
+    from paddle_tpu.serving.decode import DecodePredictor, _aliased_outputs
+
+    def counts():
+        return (obs.CACHE_ENTRIES_FED.value(kind=kind),
+                obs.CACHE_ENTRIES_ALIASED.value(kind=kind))
+
+    pred = DecodePredictor(decode_dir, draft_n_layer=1)
+    for path, cache in [("cold", "miss"), ("warm", "aot-load")]:
+        fed0, aliased0 = counts()
+        seen = len(obs.TIMELINE.events("compile"))
+        exe, _ = pred.acquire(kind, 4, 64, **kw)   # a shape of this test
+        assert counts() == (fed0 + fed, aliased0), path
+        (ev,) = [e for e in obs.TIMELINE.events("compile")[seen:]
+                 if e["kind"] == kind]
+        assert (ev["cache"], ev["cache_fed"], ev["cache_aliased"]) == (
+            cache, fed, 0), path
+        assert ("xla_ms" in ev) == (path == "cold")
+        assert _aliased_outputs(exe) == set()
+        pred = DecodePredictor(decode_dir, draft_n_layer=1)   # loads it
+    before = counts(), obs.CACHE_ENTRIES_FED.value(kind="draft")
+    pred.acquire("draft", 4, 64)
+    assert (counts(), obs.CACHE_ENTRIES_FED.value(kind="draft")) == before
+    assert {"paddle_tpu_decode_cache_entries_fed_total",
+            "paddle_tpu_decode_cache_entries_aliased_total"} <= {
+                m.name for m in obs.REGISTRY.collect()}
+
+
 def test_decode_executables_carry_distinct_module_names(decode_dir):
     import re
 
