@@ -47,7 +47,8 @@ __all__ = [
     "FLEET_WORKERS", "FLEET_OUTSTANDING", "FLEET_DISPATCHES",
     "FLEET_REQUEUED", "FLEET_MISVERSIONED", "FLEET_BACKPRESSURE_MS",
     "FLEET_SHED", "FLEET_PENDING", "FLEET_AUTOSCALE",
-    "DECODE_TOKENS", "DECODE_SLOTS", "DECODE_STEP_MS", "DECODE_REQUESTS",
+    "DECODE_TOKENS", "DECODE_STEPS", "DECODE_SLOTS", "DECODE_STEP_MS",
+    "DECODE_REQUESTS",
     "DECODE_PREFIX_QUERIES", "DECODE_PREFIX_HITS", "DECODE_PREFIX_BYTES",
     "DECODE_SPEC_PROPOSED", "DECODE_SPEC_ACCEPTED",
     "CKPT_SAVES", "CKPT_BYTES", "CKPT_PENDING", "CKPT_SAVE_MS",
@@ -261,6 +262,13 @@ DECODE_TOKENS = REGISTRY.counter(
     "paddle_tpu_decode_tokens_total",
     "Tokens generated by the KV-cache decode path, by kind=prefill "
     "(prompt tokens absorbed) | decode (sampled tokens)")
+DECODE_STEPS = REGISTRY.counter(
+    "paddle_tpu_decode_steps_total",
+    "Decode steps the serving loop dispatched, by in_flight=1 (the step "
+    "before it was still unread: the device had its next step queued "
+    "before the host saw a token) | 0 (the first step after a park, a "
+    "failure or an emptied batch); the share of 1 is how often the "
+    "per-token round trip is hidden")
 DECODE_SLOTS = REGISTRY.gauge(
     "paddle_tpu_decode_slots",
     "Continuous-batching cache-slot occupancy, state=active|free "
@@ -269,7 +277,9 @@ DECODE_SLOTS = REGISTRY.gauge(
 DECODE_STEP_MS = REGISTRY.histogram(
     "paddle_tpu_decode_step_ms",
     "Wall time per decode iteration, stage=prefill (one admission "
-    "sub-batch) | step (one token across every active slot)")
+    "sub-batch) | step (one token across every active slot: from the "
+    "token before it reaching the host, or from its own dispatch where "
+    "no step was in flight, to its token reaching the host)")
 DECODE_REQUESTS = REGISTRY.counter(
     "paddle_tpu_decode_requests_total",
     "Decode-serving sequences, kind=admitted (entered a cache slot) | "

@@ -298,6 +298,28 @@ def _scatter_fn(per_position: tuple, donate: bool):
                    donate_argnums=(0,) if donate else ())
 
 
+@functools.lru_cache(maxsize=None)
+def _chain_fn(slots: int, device):
+    """The compiled select that builds a decode step's ``tokens`` feed
+    on the device while the step before it is still in flight: (fresh,
+    first, prev) -> (slots, 1) ids, ``first[i]`` where ``fresh[i]`` (the
+    host's token: a slot admitted since, or a free one's 0), else the
+    id that step sampled for the slot, ``prev[i]``: the host never reads
+    it on the way. One shape a server, compiled ahead (a call can
+    neither trace nor compile) and shared by every server of the
+    process with as many slots."""
+    ids = jax.dtypes.canonicalize_dtype(np.int64)
+
+    def chain(fresh, first, prev):
+        return jnp.where(fresh, first, prev).reshape(slots, 1)
+
+    chain.__name__ = chain.__qualname__ = "ptpu_chain_tokens"
+    row = jax.ShapeDtypeStruct((slots,), ids)
+    with jax.default_device(device):
+        return jax.jit(chain).lower(
+            jax.ShapeDtypeStruct((slots,), np.bool_), row, row).compile()
+
+
 def _pairing_order(feed_names, fetch_names, spec_names):
     """(traced, take, n): the order a step's outputs are TRACED in, the
     index into them of each of ``fetch_names`` (None where the two
@@ -332,6 +354,13 @@ def _pairing_order(feed_names, fetch_names, spec_names):
 
 _Step = collections.namedtuple(
     "_Step", "fn program feed_names fetch_names traced take n_cache")
+
+
+# a decode step the serving loop has dispatched and not read: its
+# outputs (device values), the (slot, sequence, last) it ran for, where
+# ``last`` says the host knew at dispatch that this token ends the
+# sequence, and when its dispatch began
+_Flight = collections.namedtuple("_Flight", "outs rows t0")
 
 
 class _InFetchOrder:
@@ -1225,6 +1254,16 @@ class DecodeServer:
     mid-flight instead of waiting for the batch to drain
     (``continuous=False`` restores gang scheduling for A/B runs).
 
+    The loop keeps ONE decode step in flight: step n+1 is dispatched on
+    the device's own next-token ids before the host reads step n's, so
+    the host's share of an iteration runs beside the device's. Lengths
+    and budgets are the host's to know, so a sequence leaves its slot
+    at the dispatch of its last step; only an ``eos_id`` hit is learnt
+    a step late, and that slot's one extra step is delivered to nobody
+    (slots are independent rows, and an admission overwrites what it
+    scatters). Speculative rounds keep the host in the loop: their
+    accept counts decide the next feeds.
+
     Requests ride the same zero-copy channel frames as PredictorServer
     (slot 0: int prompt ids; optional slot 1: [max_new_tokens] or
     [max_new_tokens, seed] int64), and the response is one int64 array
@@ -1439,6 +1478,8 @@ class DecodeServer:
             t0 = time.perf_counter()
             self.predictor.acquire("decode", self.slots, self.seq,
                                    self.strategy, kv_dtype=self.kv_dtype)
+            if not self.speculative:
+                _chain_fn(self.slots, self.predictor._device)
             sp = min(16, self.seq)
             self.predictor.acquire("prefill", 1, sp)
             if self.slots > 1:
@@ -1508,7 +1549,7 @@ class DecodeServer:
         rid = slot_state["rid"]
         # span BEFORE _pop — _pop drops the trace binding
         _tracing.rid_span(rid, "decode.retire",
-                          tokens=int(slot_state["count"]))
+                          tokens=len(slot_state["generated"]))
         fut = self._pop(rid)
         obs.DECODE_REQUESTS.inc(kind="retired")
         if self._prefix is not None:
@@ -1913,7 +1954,7 @@ class DecodeServer:
         """Zeroed cache arrays in ``self._cache_feed_names`` order."""
         return [jnp.zeros(e.shape, e.dtype) for e in self._spec]
 
-    def _fail_all_active(self, active, lens, exc):
+    def _fail_all_active(self, active, lens, exc, flights=()):
         """Shared step-failure recovery: a decode/draft/verify call
         that dies (device OOM, donated-buffer misuse, backend loss)
         must not kill the serving loop and strand every future — fail
@@ -1921,15 +1962,23 @@ class DecodeServer:
         trustworthy), release their prefix refs, free the slots, and
         hand back FRESH slabs (the failed call may have CONSUMED the
         fed ones under donation; lengths are all 0 now, so zeros are
-        correct)."""
-        for i, st in enumerate(active):
-            if st is not None:
-                if self._prefix is not None:
-                    self._prefix.release(st.get("prefix_entry"))
-                self._fail(st["rid"], exc)
-                obs.DECODE_REQUESTS.inc(kind="retired")
-                active[i] = None
-                lens[i] = 0
+        correct). ``flights``: the plain branch's steps lost with the
+        call (None entries skipped); a sequence that had left its slot
+        and waited only for its last token from one of them fails too.
+        A failed sequence is marked ``done``: a step in flight that
+        still lands delivers it nothing."""
+        waiting = [st for f in flights if f is not None
+                   for _i, st, last in f.rows if last]
+        for st in [a for a in active if a is not None] + waiting:
+            if st.get("done"):
+                continue
+            st["done"] = True
+            if self._prefix is not None:
+                self._prefix.release(st.get("prefix_entry"))
+            self._fail(st["rid"], exc)
+            obs.DECODE_REQUESTS.inc(kind="retired")
+        active[:] = [None] * self.slots
+        lens[:] = 0
         return self._fresh_slabs()
 
     def _step_counts(self, lens, n_active):
@@ -2032,6 +2081,103 @@ class DecodeServer:
         obs.DECODE_TOKENS.inc(emitted, kind="decode")
         return caches
 
+    def _dispatch(self, dexe, chain, caches, lens, active, n_active,
+                  flight) -> _Flight:
+        """Dispatch one decode step for every live slot and book what
+        the host knows of it without its ids: lengths advance by one,
+        and a sequence that reaches its budget or the slab's end with
+        this token leaves its slot NOW (free for the next admission),
+        to be resolved when the ids land. ``flight`` is the step before
+        it, still unread: a slot that continues from it takes its token
+        from that step's ids on the device (``_chain_fn``), any other
+        the host's (an admission's first token; 0 for a free slot)."""
+        with _tracing.phase("decode.loop.feeds"):
+            chained = ({i for i, st, _last in flight.rows
+                        if active[i] is st} if flight is not None else ())
+            first = np.zeros((self.slots,), np.int64)
+            for i, st in enumerate(active):
+                if st is not None and i not in chained:
+                    first[i] = st["cur"]
+            if chained:
+                fresh = np.ones((self.slots,), np.bool_)
+                fresh[list(chained)] = False
+                tokens = chain(fresh, first, flight.outs[0])
+            else:
+                tokens = first.reshape(self.slots, 1)
+            feeds = {"tokens": tokens, "lengths": lens.copy(),
+                     "seed": np.array([self._seed_ctr], np.int64)}
+            if self.predictor.config.positions:
+                feeds["positions"] = lens.reshape(
+                    self.slots, 1).astype(np.int64)
+            self._seed_ctr += 1
+            feeds.update(zip(self._cache_feed_names, caches))
+        in_flight = int(flight is not None)
+        with _tracing.phase("decode.loop.dispatch", in_flight=in_flight,
+                            **self._step_counts(lens, n_active)) as ph:
+            t0 = ph.t0 or time.perf_counter()
+            outs = dexe(feeds, self.predictor._state)
+            # the ids start for the host as soon as the step ends, not
+            # when the host comes to ask (a step later)
+            outs[0].copy_to_host_async()
+        obs.DECODE_STEPS.inc(in_flight=str(in_flight))
+        rows = []
+        for i, st in enumerate(active):
+            if st is None:
+                continue
+            lens[i] += 1
+            last = (st["count"] + (i in chained) + 1 >= st["max_new"]
+                    or lens[i] + 1 >= self.seq)
+            rows.append((i, st, last))
+            if last:
+                active[i] = None
+                lens[i] = 0
+        return _Flight(outs, rows, t0)
+
+    def _fetch(self, flight: _Flight, t_token: float):
+        """(ids, now): read a dispatched step's ids; the host waits here
+        for the step, beside whatever was dispatched behind it. The
+        histogram's ``stage="step"`` takes the time this token took
+        once the one before it had arrived (``t_token``), or since the
+        step's own dispatch where nothing was in flight: one
+        observation a step, and their sum the time the loop had a step
+        outstanding (a traced iteration hands over its own clock
+        readings)."""
+        with _tracing.phase("decode.loop.fetch") as ph:
+            ids = np.asarray(flight.outs[0])
+        now = ph.t1 or time.perf_counter()
+        obs.DECODE_STEP_MS.observe(
+            (now - max(flight.t0, t_token)) * 1e3, stage="step")
+        return ids, now
+
+    def _deliver(self, flight: _Flight, ids, lens, active):
+        """Hand a landed step's tokens to their sequences and retire
+        the finished. An ``eos_id`` hit is learnt here, one step late:
+        the slot has run (or is running) one more step, whose token is
+        delivered to nobody and counted nowhere."""
+        with _tracing.phase("decode.loop.retire"):
+            delivered = 0
+            for i, st, last in flight.rows:
+                if st.get("done"):
+                    continue
+                tok = int(ids[i])
+                st["generated"].append(tok)
+                st["count"] += 1
+                delivered += 1
+                if last or (self.eos_id is not None
+                            and tok == self.eos_id):
+                    st["done"] = True
+                    self._retire(st)
+                    if active[i] is st:
+                        active[i] = None
+                        lens[i] = 0
+            self.step_active_counts.append(delivered)
+            obs.DECODE_TOKENS.inc(delivered, kind="decode")
+            # refresh occupancy AFTER retirements: an idle server must
+            # scrape as 0 active, not as its pre-retirement count (the
+            # next iteration may park on the channel before updating)
+            self._set_slot_gauges(
+                sum(1 for a in active if a is not None))
+
     def _loop(self):
         caches = self._fresh_slabs()
         lens = np.zeros((self.slots,), np.int32)
@@ -2045,6 +2191,12 @@ class DecodeServer:
                                               self.seq)
             vexe, _ = self.predictor.acquire("verify", self.slots,
                                              self.seq, window=self._win)
+        else:
+            chain = _chain_fn(self.slots, self.predictor._device)
+        # the plain branch's step in flight (dispatched, its ids not
+        # yet read), and when the last token reached the host
+        flight: Optional[_Flight] = None
+        t_token = 0.0
         closed = False
         while True:
             # one iteration = one record of the flight recorder's
@@ -2057,7 +2209,8 @@ class DecodeServer:
                 batch = []
                 drain = not closed and free > 0 and (
                     self.continuous or n_active == 0)
-                if not closed and n_active == 0 and not pending:
+                if not closed and n_active == 0 and not pending \
+                        and flight is None:
                     # idle: park on the channel until work (or close)
                     with _tracing.phase("decode.loop.park"):
                         batch = self._chan.recv_batch(self.slots, None)
@@ -2098,78 +2251,43 @@ class DecodeServer:
                         caches = self._admit(pending, caches, lens, active)
                     n_active = sum(1 for a in active if a is not None)
                 self._set_slot_gauges(n_active)
-                if n_active == 0:
-                    if closed and not pending:
-                        return
-                    continue
-                if self.speculative:
+                if self.speculative and n_active:
                     caches = self._spec_round(drexe, vexe, caches, lens,
                                               active, n_active)
                     self._set_slot_gauges(
                         sum(1 for a in active if a is not None))
                     continue
-                # one token across every active slot
-                with _tracing.phase("decode.loop.feeds"):
-                    cur = np.zeros((self.slots,), np.int64)
-                    for i, st in enumerate(active):
-                        if st is not None:
-                            cur[i] = st["cur"]
-                    feeds = {"tokens": cur.reshape(self.slots, 1),
-                             "lengths": lens.copy(),
-                             "seed": np.array([self._seed_ctr], np.int64)}
-                    if self.predictor.config.positions:
-                        feeds["positions"] = lens.reshape(
-                            self.slots, 1).astype(np.int64)
-                    self._seed_ctr += 1
-                    feeds.update(zip(self._cache_feed_names, caches))
+                # one token across every active slot, dispatched BEFORE
+                # the host reads the step in flight: all it needs of
+                # that step (the ids, the cache entries) is on the
+                # device, and the host's part of an iteration runs
+                # beside the device's. With nothing live there is no
+                # step to put behind the one in flight, and it is read
+                # before the loop parks or returns
+                step = ids = None
                 try:
-                    # dispatch and fetch are DECODE_STEP_MS's "step"
-                    # stage, split: a traced iteration hands the
-                    # histogram its own clock readings
-                    with _tracing.phase(
-                            "decode.loop.dispatch",
-                            **self._step_counts(lens, n_active)) as ph:
-                        t0 = ph.t0 or time.perf_counter()
-                        outs = dexe(feeds, self.predictor._state)
-                    with _tracing.phase("decode.loop.fetch") as ph:
-                        # the host waits here for the step
-                        nxt = np.asarray(outs[0]).astype(np.int64)
-                    t1 = ph.t1 or time.perf_counter()
+                    if n_active:
+                        step = self._dispatch(dexe, chain, caches, lens,
+                                              active, n_active, flight)
+                        caches = list(step.outs[2:])
+                    if flight is not None:
+                        ids, t_token = self._fetch(flight, t_token)
                 except Exception as e:
                     # a decode step that dies (device OOM, donated-
-                    # buffer misuse, backend loss) must not kill the
-                    # serving loop and strand every future: fail the
-                    # ACTIVE sequences (their cache state is no longer
-                    # trustworthy), free the slots, keep serving the
-                    # queue
-                    caches = self._fail_all_active(active, lens, e)
+                    # buffer misuse, backend loss), at its dispatch or
+                    # when its ids are read, must not kill the serving
+                    # loop and strand every future: the steps in flight
+                    # are lost together. Fail the ACTIVE sequences and
+                    # those waiting for their last token (their cache
+                    # state is no longer trustworthy), free the slots,
+                    # keep serving the queue
+                    caches = self._fail_all_active(active, lens, e,
+                                                   (flight, step))
                     self._set_slot_gauges(0)
+                    flight = None
                     continue
-                obs.DECODE_STEP_MS.observe((t1 - t0) * 1e3, stage="step")
-                with _tracing.phase("decode.loop.retire"):
-                    self.step_active_counts.append(n_active)
-                    caches = list(outs[2:])
-                    emitted = 0
-                    for i, st in enumerate(active):
-                        if st is None:
-                            continue
-                        lens[i] += 1
-                        tok = int(nxt[i])
-                        st["generated"].append(tok)
-                        st["cur"] = tok
-                        st["count"] += 1
-                        emitted += 1
-                        if (self.eos_id is not None
-                                and tok == self.eos_id) \
-                                or st["count"] >= st["max_new"] \
-                                or lens[i] + 1 >= self.seq:
-                            self._retire(st)
-                            active[i] = None
-                            lens[i] = 0
-                    obs.DECODE_TOKENS.inc(emitted, kind="decode")
-                    # refresh occupancy AFTER retirements: an idle
-                    # server must scrape as 0 active, not as its
-                    # pre-retirement count (the next iteration may park
-                    # on the channel before updating)
-                    self._set_slot_gauges(
-                        sum(1 for a in active if a is not None))
+                if flight is not None:
+                    self._deliver(flight, ids, lens, active)
+                flight = step
+                if n_active == 0 and closed and not pending:
+                    return

@@ -9,6 +9,7 @@ import os
 import subprocess
 import sys
 import threading
+import time
 import urllib.request
 
 import numpy as np
@@ -319,27 +320,35 @@ def test_server_stop_is_zero_drop(pred):
         np.testing.assert_array_equal(g, w)
 
 
-def test_server_survives_step_failure(model_dir):
-    """A decode step that raises (device OOM, backend loss) must fail
-    the affected futures and keep the loop alive — not strand every
-    client on a dead daemon thread."""
-    p = DecodePredictor(model_dir)
-    boom = {"armed": True}
-    real_acquire = p.acquire
+def _record_steps(pred, events, fail_on=()):
+    """Wrap the predictor's decode executable: each call is logged as
+    ("call", k, the type of its `tokens` feed), and call ``k`` in
+    ``fail_on`` raises at its dispatch."""
+    real_acquire = pred.acquire
 
-    def flaky_acquire(kind, batch, seq, strategy=None, **kw):
+    def acquire(kind, batch, seq, strategy=None, **kw):
         exe, fetch = real_acquire(kind, batch, seq, strategy, **kw)
         if kind != "decode":
             return exe, fetch
 
         def wrapped(feeds, state):
-            if boom.pop("armed", False):
+            k = 1 + sum(1 for e in events if e[0] == "call")
+            events.append(("call", k, type(feeds["tokens"])))
+            if k in fail_on:
                 raise RuntimeError("injected device failure")
             return exe(feeds, state)
 
         return wrapped, fetch
 
-    p.acquire = flaky_acquire
+    pred.acquire = acquire
+
+
+def test_server_survives_step_failure(model_dir):
+    """A decode step that raises (device OOM, backend loss) must fail
+    the affected futures and keep the loop alive — not strand every
+    client on a dead daemon thread."""
+    p = DecodePredictor(model_dir)
+    _record_steps(p, [], fail_on=(1,))
     srv = DecodeServer(p, slots=2, max_seq=32, max_new_tokens=4,
                        prewarm=False)
     srv.start()
@@ -354,6 +363,275 @@ def test_server_survives_step_failure(model_dir):
     want = DecodePredictor(model_dir).generate([prompts[0]],
                                                max_new_tokens=4)[0]
     np.testing.assert_array_equal(out, want)
+
+
+# -- one step in flight (PR 30) ---------------------------------------------
+
+def _host_in_the_loop(ref, prompt, max_new):
+    """The reference of a server that keeps a step in flight: ONE
+    sequence alone in slot 0 of the same executables, the host reading
+    every token before it feeds the next (the loop's order before PR
+    30). ``ref`` is a DecodeServer that is never started: its admission
+    recipe (prefill at the prompt's bucket, scatter into fresh entries)
+    serves here too."""
+    pred, slots, seq = ref.predictor, ref.slots, ref.seq
+    dexe, _ = pred.acquire("decode", slots, seq, ref.strategy,
+                           kv_dtype=ref.kv_dtype)
+    outs, sp = ref._prefill_prompts([prompt])
+    tok = int(pred._sample_host(outs[0], ref.strategy, 0)[0])
+    caches = ref._scatter_prefill(ref._fresh_slabs(), list(outs[1:]), [0],
+                                  sp)
+    lens = np.zeros((slots,), np.int32)
+    lens[0] = len(prompt)
+    gen = [tok]
+    while len(gen) < max_new and lens[0] + 1 < seq:
+        cur = np.zeros((slots, 1), np.int64)
+        cur[0] = tok
+        feeds = {"tokens": cur, "lengths": lens.copy(),
+                 "seed": np.zeros((1,), np.int64)}
+        if pred.config.positions:
+            feeds["positions"] = lens.reshape(slots, 1).astype(np.int64)
+        feeds.update(zip(ref._cache_feed_names, caches))
+        outs = dexe(feeds, pred._state)
+        tok = int(np.asarray(outs[0])[0])
+        caches = list(outs[2:])
+        lens[0] += 1
+        gen.append(tok)
+    return gen
+
+
+def _case_server(case, request, **kw):
+    mdir = request.getfixturevalue(
+        "hybrid_dir" if case == "hybrid" else "model_dir")
+    kw.setdefault("kv_dtype", "int8" if case == "int8" else "float32")
+    return DecodeServer(DecodePredictor(mdir), **kw)
+
+
+@pytest.mark.parametrize("case", ["opt", "hybrid", "int8"])
+def test_server_with_a_step_in_flight_answers_as_the_host_in_the_loop(
+        case, request):
+    """Token for token, over what the order of the loop could break:
+    eleven requests on three slots, so that admissions land between two
+    steps of live sequences and on slots freed a step before their last
+    token was read; budgets of 1 (the prefill's token alone), 2 and 3
+    (a sequence the host retires at its first or second dispatch);
+    two that end on the slab's last row; four that arrive while the
+    first seven decode."""
+    kw = dict(slots=3, max_seq=32, max_new_tokens=8)
+    srv = _case_server(case, request, **kw)
+    ref = DecodeServer(srv.predictor, kv_dtype=srv.kv_dtype, **kw)
+    r = np.random.RandomState(30)
+    plens = [4, 9, 3, 24, 6, 12, 5, 7, 30, 10, 8]
+    budgets = [1, 2, 3, 8, 5, 8, 1, 2, 2, 3, 8]
+    vocab = srv.predictor.config.vocab_size
+    prompts = [r.randint(1, vocab, n).astype(np.int64) for n in plens]
+    reqs = [(p, np.array([b], np.int64)) for p, b in zip(prompts, budgets)]
+    futs = [srv.submit(q) for q in reqs[:7]]
+    srv.start()
+    futs[2].result(timeout=300)
+    futs += [srv.submit(q) for q in reqs[7:]]
+    got = [np.asarray(f.result(timeout=300)[0]).tolist() for f in futs]
+    srv.stop()
+    want = [_host_in_the_loop(ref, p, b) for p, b in zip(prompts, budgets)]
+    assert got == want
+    assert [len(g) for g in got] == budgets
+    # a step's count is the tokens it DELIVERED: every token but each
+    # request's first (the prefill's) came out of one decode step
+    assert sum(srv.step_active_counts) == sum(budgets) - len(budgets)
+    assert max(srv.step_active_counts) <= 3
+
+
+def test_step_is_dispatched_before_the_one_before_it_is_read(model_dir):
+    """The order itself: step n+1's call happens before the host
+    materialises step n's ids, its `tokens` feed is a device value (the
+    select of `_chain_fn`), and `in_flight` is 1 on every dispatch but
+    the first after a park."""
+    from paddle_tpu import observability as obs
+    from paddle_tpu.observability import tracing
+
+    p = DecodePredictor(model_dir)
+    events = []
+    _record_steps(p, events)
+    srv = DecodeServer(p, slots=2, max_seq=32, max_new_tokens=6)
+    real_dispatch, real_fetch = srv._dispatch, srv._fetch
+    dispatched, read = [], []
+
+    def dispatch(*args):
+        dispatched.append(real_dispatch(*args))
+        return dispatched[-1]
+
+    def fetch(flight, t_token):
+        read.append(flight)
+        events.append(("read", len(dispatched)))
+        return real_fetch(flight, t_token)
+
+    srv._dispatch, srv._fetch = dispatch, fetch
+    before = {k: obs.DECODE_STEPS.value(in_flight=k) for k in "01"}
+    tracing.reset()
+    tracing.set_sample_rate(1.0)
+    try:
+        # three requests queued before the start (two slots: the third
+        # is admitted behind a step in flight), then, when all have
+        # resolved and nothing is live or queued, one more
+        futs = [srv.submit((q,)) for q in _prompts(3, seed=21)]
+        srv.start()
+        for f in futs:
+            assert len(f.result(timeout=300)[0]) == 6
+        late = srv.submit((_prompts(1, seed=22)[0],))
+        assert len(late.result(timeout=300)[0]) == 6
+        srv.stop()
+    finally:
+        tracing.set_sample_rate(0.0)
+    calls = [e for e in events if e[0] == "call"]
+    assert [e[0] for e in events][:4] == ["call", "call", "read", "call"]
+    # every step is read once, in the order dispatched, and when step j
+    # is read step j+1 has been dispatched already, unless step j was
+    # the last before the server emptied
+    assert len(read) == len(calls) == len(dispatched)
+    assert all(a is b for a, b in zip(read, dispatched))
+    behind = [e[1] - j for j, e in enumerate(
+        e for e in events if e[0] == "read")]
+    assert set(behind) == {1, 2} and behind.count(1) == 2
+    steps = [s for s in tracing.get_recorder().spans()
+             if s["name"] == "decode.loop.iter" and "active" in s]
+    in_flight = [s["in_flight"] for s in steps]
+    assert len(in_flight) == len(calls)
+    assert in_flight.count(0) == 2 and in_flight[0] == 0
+    second = in_flight.index(0, 1)
+    assert in_flight == [0] + [1] * (second - 1) + [0] + \
+        [1] * (len(calls) - second - 1)
+    # a slot that continues from the step in flight takes its token on
+    # the device: the `tokens` of such a step never were on the host
+    # (where every slot turned over at once, they are the admission's)
+    for j, (_c, _k, fed) in enumerate(calls):
+        was = {(i, id(st)) for i, st, _l in dispatched[j - 1].rows}
+        chained = in_flight[j] and any(
+            (i, id(st)) in was for i, st, _l in dispatched[j].rows)
+        assert issubclass(fed, jax.Array) == bool(chained), j
+    assert sum(issubclass(c[2], jax.Array) for c in calls) >= 6
+    assert obs.DECODE_STEPS.value(in_flight="0") - before["0"] == 2
+    assert (obs.DECODE_STEPS.value(in_flight="1") - before["1"]
+            == len(calls) - 2)
+    assert sum(srv.step_active_counts) == 4 * 5
+
+
+@pytest.mark.parametrize("case", ["opt", "int8"])
+def test_eos_learnt_a_step_late_reaches_nobody(case, request):
+    """An `eos_id` hit is read one step after the slot has been fed
+    again: the token of that extra step reaches no request and no
+    counter, and the slot's next occupant answers exactly as on a
+    fresh server (an admission overwrites what it scatters). The tiny
+    hybrid model repeats one token, so it has no `eos_id` to hit
+    mid-way: its slots turn over under a step in flight in the
+    token-for-token test above."""
+    from paddle_tpu import observability as obs
+
+    kw = dict(slots=1, max_seq=32, max_new_tokens=8)
+    probe = _case_server(case, request, **kw)
+    vocab = probe.predictor.config.vocab_size
+    r = np.random.RandomState(5)
+    first_p, next_p = (r.randint(1, vocab, n).astype(np.int64)
+                       for n in (7, 5))
+    base = _host_in_the_loop(probe, first_p, 8)
+    # stop the first sequence at a token it emits mid-way for the first
+    # time, after some decode steps and well before its budget
+    cut = next(k + 1 for k in range(2, 6) if base[k] not in base[:k])
+    eos = base[cut - 1]
+    srv = _case_server(case, request, eos_id=eos, **kw)
+    tokens0 = obs.DECODE_TOKENS.value(kind="decode")
+    srv.start()
+    got = np.asarray(srv.submit((first_p,)).result(timeout=300)[0])
+    assert got.tolist() == base[:cut]
+    reused = np.asarray(srv.submit((next_p,)).result(timeout=300)[0])
+    srv.stop()
+    fresh = _case_server(case, request, eos_id=eos, **kw)
+    fresh.start()
+    want = np.asarray(fresh.submit((next_p,)).result(timeout=300)[0])
+    fresh.stop()
+    np.testing.assert_array_equal(reused, want)
+    # delivered tokens alone are counted: the discarded step shows as a
+    # step that delivered nothing
+    assert sum(srv.step_active_counts) == (len(got) - 1) + (len(reused) - 1)
+    assert 0 in srv.step_active_counts
+    assert (obs.DECODE_TOKENS.value(kind="decode") - tokens0
+            == len(got) + len(reused) + len(want))
+
+
+@pytest.mark.parametrize("where", ["dispatch", "fetch"])
+def test_step_failure_with_a_step_in_flight(model_dir, where):
+    """A step that raises at its dispatch, and one whose ids raise when
+    they are read, with another step in flight either way: the steps in
+    flight are lost together, so every live request fails with the
+    error: the one that had left its slot and waited only for its last
+    token, and the one admitted into that slot since; what was queued
+    is served; `stop()` returns."""
+    p = DecodePredictor(model_dir)
+    events = []
+    _record_steps(p, events, fail_on=(3,) if where == "dispatch" else ())
+    srv = DecodeServer(p, slots=2, max_seq=32, max_new_tokens=6)
+    if where == "fetch":
+        real_fetch, reads = srv._fetch, []
+
+        def fetch(flight, t_token):
+            reads.append(flight)
+            if len(reads) == 2:
+                raise RuntimeError("injected device failure")
+            return real_fetch(flight, t_token)
+
+        srv._fetch = fetch
+    prompts = _prompts(5, seed=23)
+    # a budget of 3: its last token is step 2's, so at the failure (the
+    # dispatch of step 3, or the read of step 2 behind it) request 0
+    # has left its slot and waits for that token alone, and request 2
+    # has been admitted into the slot for step 3
+    budgets = [3, 6, 4, 4, 4]
+    futs = [srv.submit((q, np.array([b], np.int64)))
+            for q, b in zip(prompts, budgets)]
+    srv.start()
+    for f in futs[:3]:
+        with pytest.raises(RuntimeError, match="injected device failure"):
+            f.result(timeout=120)
+    got = [f.result(timeout=120)[0] for f in futs[3:]]
+    stopper = threading.Thread(target=srv.stop)
+    stopper.start()
+    stopper.join(timeout=120)
+    assert not stopper.is_alive()
+    want = DecodePredictor(model_dir).generate(prompts[3:],
+                                               max_new_tokens=4)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+
+
+def test_stop_with_work_queued_and_a_step_in_flight_drops_nothing(pred):
+    """`stop()` arrives while a step is in flight and requests wait for
+    a slot: the loop reads the step, admits what is queued and finishes
+    it all before it returns."""
+    srv = DecodeServer(pred, slots=2, max_seq=32, max_new_tokens=5)
+    real_fetch = srv._fetch
+    reads, stopper = [], []
+
+    def fetch(flight, t_token):
+        reads.append(flight)
+        if len(reads) == 1:
+            # from the loop's own thread, between a dispatch and the
+            # read behind it: a step is in flight, six requests queued
+            stopper.append(threading.Thread(target=srv.stop))
+            stopper[0].start()
+            while not srv._chan._py_closed:
+                time.sleep(1e-3)
+        return real_fetch(flight, t_token)
+
+    srv._fetch = fetch
+    prompts = _prompts(8, seed=24)
+    futs = [srv.submit((q,)) for q in prompts]
+    srv.start()
+    got = [f.result(timeout=300)[0] for f in futs]
+    stopper[0].join(timeout=300)
+    assert not stopper[0].is_alive()
+    with pytest.raises(RuntimeError, match="stopped"):
+        srv.submit((prompts[0],))
+    for g, w in zip(got, pred.generate(prompts, max_new_tokens=5)):
+        np.testing.assert_array_equal(g, w)
 
 
 def test_server_rejects_oversized_prompt(pred):

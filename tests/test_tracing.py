@@ -482,10 +482,26 @@ def test_decode_server_gives_one_record_per_loop_iteration(decode_dir):
     assert [s["streamed"] for s in steps] == [4 * 32] * len(steps)
     first = steps[0]
     assert first["admitted"] == 3
+    # an iteration dispatches its step BEFORE it reads the one before
+    # it: the first after the park has a step to dispatch and none to
+    # read, every later one holds ONE dispatch with its counts and ONE
+    # fetch (of the step before), and the last reads the step still in
+    # flight with nothing to put behind it
     for name in ("decode.loop.recv", "decode.loop.admit",
-                 "decode.loop.feeds", "decode.loop.dispatch",
-                 "decode.loop.fetch", "decode.loop.retire"):
+                 "decode.loop.feeds", "decode.loop.dispatch"):
         assert _phase(first, name)["n"] == 1
+    assert not any(p["name"] in ("decode.loop.fetch", "decode.loop.retire")
+                   for p in first["phases"])
+    assert [s["in_flight"] for s in steps] == [0, 1, 1, 1, 1, 1]
+    for s in steps[1:]:
+        for name in ("decode.loop.feeds", "decode.loop.dispatch",
+                     "decode.loop.fetch", "decode.loop.retire"):
+            assert _phase(s, name)["n"] == 1
+        assert (_phase(s, "decode.loop.dispatch")["end_ms"]
+                <= _phase(s, "decode.loop.fetch")["end_ms"])
+    fetches = [s for s in _iters()
+               if any(p["name"] == "decode.loop.fetch" for p in s["phases"])]
+    assert fetches[:-1] == steps[1:] and "active" not in fetches[-1]
     for name in ("decode.loop.prefill", "decode.loop.first_token",
                  "decode.loop.scatter"):
         assert _phase(first, name, "decode.loop.admit")["ms"] > 0
@@ -493,14 +509,23 @@ def test_decode_server_gives_one_record_per_loop_iteration(decode_dir):
     # iteration still parks on the channel (and returns at once)
     assert any(p["name"] == "decode.loop.park" for s in _iters()
                for p in s["phases"])
-    # dispatch + fetch are the histogram's "step" stage, split in two
+    # the histogram's "step" stage: one observation a step, from the
+    # token before it reaching the host (the first: from its own
+    # dispatch) to its own, so that their sum is the time the loop had
+    # a step outstanding: here from the first dispatch to the last
+    # fetch. The gap between two tokens read from the records (the
+    # ends of consecutive `fetch` phases, as the benchmark's
+    # token_gap_ms_p95.serve reads it) is the period of the steps
     after = obs.DECODE_STEP_MS.stats(stage="step")
     assert after["count"] - before["count"] == len(steps)
     hist_ms = after["sum"] - before["sum"]
-    split_ms = sum(_phase(s, "decode.loop.dispatch")["self_ms"]
-                   + _phase(s, "decode.loop.fetch")["self_ms"]
-                   for s in steps)
-    assert split_ms == pytest.approx(hist_ms, rel=0.05)
+    ends = [s["ts"] * 1e3 + _phase(s, "decode.loop.fetch")["end_ms"]
+            for s in fetches]
+    d0 = _phase(first, "decode.loop.dispatch")
+    began = first["ts"] * 1e3 + d0["end_ms"] - d0["ms"]
+    gaps = np.diff([began] + ends)
+    assert len(gaps) == len(steps) and (gaps > 0).all()
+    assert gaps.sum() == pytest.approx(hist_ms, rel=0.05, abs=0.5)
     # request spans and iteration records side by side, nothing dropped
     snap = tracing.snapshot()
     assert snap["dropped"] == 0
