@@ -1,6 +1,7 @@
 """The quickest proof that the system still starts on the chip.
 
-    python chip_smoke.py            # one TPU chip: kernels, train, serve
+    python chip_smoke.py            # one TPU chip: kernels, train, serve,
+                                    # and a small Laguna-family block
     python chip_smoke.py --chips 4  # four chips: the 2x2-mesh trainer only
 
 One process, no children. Drives the two main paths through the entry
@@ -44,6 +45,16 @@ FULL = dict(vocab=32768, n_layer=12, n_head=8, d_model=1024, d_inner=4096,
             # serve: 8 seeded prompts of 128-512 tokens, 32 new tokens each
             slots=8, prompt_lo=128, prompt_hi=512, new_tokens=32,
             interpret=False, require_tpu=True)
+
+# A small Laguna-family block (full and sliding-window layers mixed,
+# rotary positions, a per-head gate, routed experts with a shared one,
+# an untied head) at shapes that enter the kernels the serving cell
+# enters: heads of 128, the published window of 512, a prompt that
+# wraps the ring.
+LAGUNA = dict(vocab=4096, d_model=512, head_dim=128, n_kv_head=2,
+              heads=[4, 6, 6, 6, 4], d_inner=1024, window=512,
+              n_expert=16, top_k=4, d_expert=256, held=[4, 12], seq=2048,
+              slots=8, prompt=700, new_tokens=8, require_tpu=True)
 
 # Tolerances (max abs error over max abs reference, bf16 inputs): one
 # bf16 rounding is 2^-8 = 0.4%; the backward accumulates ~T of them.
@@ -390,6 +401,113 @@ def phase_serve(cfg, place):
     shutil.rmtree(model_dir, ignore_errors=True)
 
 
+def laguna_config(cfg):
+    from paddle_tpu.serving import DecodeConfig
+
+    n = len(cfg["heads"])
+    half = cfg["head_dim"] // 2
+    return DecodeConfig(
+        cfg["vocab"], n_layer=n, n_head=cfg["heads"][0],
+        d_model=cfg["d_model"], d_inner=cfg["d_inner"], max_len=cfg["seq"],
+        tie_embeddings=False, n_kv_head=cfg["n_kv_head"],
+        head_dim=cfg["head_dim"], n_head_by_layer=cfg["heads"],
+        attn_types=["full", "sliding", "sliding", "sliding", "full"][:n],
+        window=cfg["window"], ffn_types=["dense"] + ["experts"] * (n - 1),
+        n_expert=cfg["n_expert"], expert_top_k=cfg["top_k"],
+        d_expert=cfg["d_expert"], d_shared_expert=cfg["d_expert"],
+        experts_held=cfg["held"], router_scale=2.5, attn_gate="per_head",
+        rope={"full": {"rotary_dim": half, "theta": 500000.0,
+                       "attention_factor": 1.4158883083359672,
+                       "yarn": {"factor": 64,
+                                "original_max_position": cfg["seq"],
+                                "beta_fast": 64, "beta_slow": 1}},
+              "sliding": {"rotary_dim": cfg["head_dim"], "theta": 10000.0}},
+        norm="rms_norm", norm_eps=1e-6, ffn="gated_silu", positions=False,
+        biases=False)
+
+
+def phase_laguna(cfg, place):
+    """A Laguna-family block through save_decode_model -> DecodePredictor
+    -> DecodeServer: ONE admission whose prompt wraps the ring of a
+    sliding layer, then eight steps through slabs and rings, against a
+    full-forward rollout (one prefill a token, which knows no cache); and
+    the experts' loads booked. A hang in the expert gather, the ring's
+    slices or the window kernel shows here, in seconds."""
+    import paddle_tpu as fluid
+    from paddle_tpu import layers
+    from paddle_tpu.models import jamba
+    from paddle_tpu.serving import (DecodePredictor, DecodeServer,
+                                    save_decode_model)
+
+    model_dir = os.path.join(OUT_DIR, "laguna_model")
+    shutil.rmtree(model_dir, ignore_errors=True)
+    os.makedirs(model_dir)
+    config = laguna_config(cfg)
+    main_p, startup = fluid.Program(), fluid.Program()
+    main_p.random_seed = startup.random_seed = 7
+    with fluid.program_guard(main_p, startup):
+        with fluid.unique_name.guard():
+            jamba.hybrid_lm_prefill(
+                layers.data(name="tokens", shape=[1, 16], dtype="int64",
+                            append_batch_size=False),
+                layers.data(name="lengths", shape=[1], dtype="int32",
+                            append_batch_size=False), config)
+    scope = fluid.Scope()
+    exe = fluid.Executor(place)
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+        save_decode_model(model_dir, config, exe, scope=scope)
+    exe.close()
+    del scope, exe
+    gc.collect()
+
+    new = cfg["new_tokens"]
+    prompt = np.random.RandomState(3).randint(
+        1, cfg["vocab"], cfg["prompt"]).astype(np.int64)
+    assert cfg["prompt"] > cfg["window"], "the prompt must wrap the ring"
+    pred = DecodePredictor(model_dir, place=place)
+    srv = DecodeServer(pred, slots=cfg["slots"], max_seq=cfg["seq"],
+                       max_new_tokens=new)
+    fut = srv.submit((prompt,))
+    t0 = time.perf_counter()
+    srv.start()
+    try:
+        served = np.asarray(fut.result(timeout=600)).reshape(-1)
+    finally:
+        srv.stop()
+    serve_s = time.perf_counter() - t0
+    assert len(served) == new, served
+    ref_toks, ref_logits = _full_forward_rollout(pred, prompt, new)
+    agree = 0
+    for got, want, row in zip(served, ref_toks, ref_logits):
+        if int(got) != want:
+            gap = float(row[want] - row[int(got)])
+            assert gap <= TOL_GREEDY_TIE, (
+                "decode through slabs and rings left the full-forward "
+                "rollout at token %d: %d vs %d, reference logit gap %.4g > "
+                "%.4g" % (agree, int(got), want, gap, TOL_GREEDY_TIE))
+            break
+        agree += 1
+    pairs = int(srv.moe_load_total.sum())
+    assert pairs > 0, "no pair was booked on a held expert"
+    text = pred.acquire("prefill", 1, 1024)[0].as_text() if cfg[
+        "require_tpu"] else ""
+    if cfg["require_tpu"]:
+        kernels = re.findall(r"%(ptpu\.[a-z_]+)[.\d]* = ", text)
+        assert (kernels.count("ptpu.attn_window") == 3
+                and kernels.count("ptpu.flash_fwd") == 2), (
+            "the prefill does not run one attention kernel a layer: %r"
+            % kernels)
+    _emit("laguna", prompt_len=len(prompt), new_tokens=new,
+          server_s=serve_s, rollout_tokens_agreeing=agree,
+          expert_pairs=pairs,
+          load_max_over_mean=float(
+              (srv.moe_load_total.max(axis=1)
+               / np.maximum(srv.moe_load_total.mean(axis=1), 1e-9)).max()),
+          ok=True)
+    shutil.rmtree(model_dir, ignore_errors=True)
+
+
 # -- --chips 4: the 2x2-mesh trainer ----------------------------------------
 
 def _parallel_step_text(pexe, feed):
@@ -524,6 +642,8 @@ def main(argv=None):
         phase_train(FULL, place)
         gc.collect()
         phase_serve(FULL, place)
+        gc.collect()
+        phase_laguna(LAGUNA, place)
     _emit("done", seconds=time.perf_counter() - t0,
           jax_cache_hits=cache_events["hits"],
           jax_cache_misses=cache_events["misses"])

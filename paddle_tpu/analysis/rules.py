@@ -1119,6 +1119,134 @@ def _infer_cache_append(ctx: InferContext):
     return {"Out": VarInfo(c.shape, c.dtype)}
 
 
+@register_infer("rope")
+def _infer_rope(ctx: InferContext):
+    """Out mirrors X (B, T, H, Dh); rotary_dim is even and fits a
+    head."""
+    x = ctx.in_info("X")
+    r = ctx.attr("rotary_dim", None)
+    if x.shape is not None:
+        if len(x.shape) != 4:
+            raise InferError("X must be rank 4 (B, T, H, Dh), got rank %d"
+                             % len(x.shape))
+        if r is not None and x.shape[3] is not None and (
+                int(r) % 2 or not 0 < int(r) <= x.shape[3]):
+            raise InferError("rotary_dim %d is not an even part of X%s's "
+                             "heads" % (int(r), render_shape(x.shape)))
+    return {"Out": VarInfo(x.shape, x.dtype)}
+
+
+@register_infer("moe_route")
+def _infer_moe_route(ctx: InferContext):
+    """Idx and Weights are X's leading axes with top_k last; top_k
+    cannot pass the router's width."""
+    x, w = ctx.in_shape("X"), ctx.in_shape("W")
+    k = int(ctx.attr("top_k"))
+    if w is not None and len(w) == 2:
+        if (x is not None and x[-1] is not None and w[0] is not None
+                and x[-1] != w[0]):
+            raise InferError("W%s rows do not match X%s's width"
+                             % (render_shape(w), render_shape(x)))
+        if w[1] is not None and k > w[1]:
+            raise InferError("top_k %d passes the router's %d experts"
+                             % (k, w[1]))
+    shape = None if x is None else tuple(x[:-1]) + (k,)
+    return {"Idx": VarInfo(shape, "int32"),
+            "Weights": VarInfo(shape, "float32")}
+
+
+@register_infer("moe_experts")
+def _infer_moe_experts(ctx: InferContext):
+    """Out mirrors X (B, T, D); Load is (Eh,) int32 with WGate (Eh, D,
+    F); WDown is (Eh, F, D)."""
+    x = ctx.in_info("X")
+    g, dn = ctx.in_shape("WGate"), ctx.in_shape("WDown")
+    eh = None
+    if g is not None:
+        if len(g) != 3:
+            raise InferError("WGate must be rank 3 (Eh, D, F), got rank %d"
+                             % len(g))
+        eh = g[0]
+        if (x.shape is not None and x.shape[-1] is not None
+                and g[1] is not None and x.shape[-1] != g[1]):
+            raise InferError("WGate%s does not take X%s's width"
+                             % (render_shape(g), render_shape(x.shape)))
+        if dn is not None and len(dn) == 3 and any(
+                a is not None and b is not None and a != b
+                for a, b in zip(dn, (g[0], g[2], g[1]))):
+            raise InferError("WDown%s is not WGate%s transposed"
+                             % (render_shape(dn), render_shape(g)))
+    return {"Out": VarInfo(x.shape, x.dtype),
+            "Load": VarInfo((eh,), "int32")}
+
+
+@register_infer("moe_shared")
+def _infer_moe_shared(ctx: InferContext):
+    """Out mirrors X."""
+    x = ctx.in_info("X")
+    return {"Out": VarInfo(x.shape, x.dtype)}
+
+
+def _grouped_heads(ctx: InferContext, slots, axes):
+    """Q (B, T, H, Dh) against K/V-like inputs of fewer heads: rank 4,
+    batch and depth equal, heads dividing. ``axes``: (batch, head,
+    depth) of the other inputs."""
+    q = ctx.in_info("Q")
+    qs = q.shape
+    if qs is not None and len(qs) != 4:
+        raise InferError("Q must be rank 4 (B, T, H, Dh), got rank %d"
+                         % len(qs))
+    for slot in slots:
+        c = ctx.in_shape(slot)
+        if c is None or qs is None:
+            continue
+        if len(c) != 4:
+            raise InferError("%s must be rank 4, got rank %d"
+                             % (slot, len(c)))
+        for qi, ci, label in ((0, axes[0], "batch"), (3, axes[2], "depth")):
+            if qs[qi] is not None and c[ci] is not None \
+                    and qs[qi] != c[ci]:
+                raise InferError("%s %s dim %d does not match Q%s"
+                                 % (slot, label, c[ci], render_shape(qs)))
+        if qs[2] is not None and c[axes[1]] is not None \
+                and qs[2] % c[axes[1]]:
+            raise InferError("%s head dim %d does not divide Q%s"
+                             % (slot, c[axes[1]], render_shape(qs)))
+    return VarInfo(qs, q.dtype)
+
+
+@register_infer("attn_window")
+def _infer_attn_window(ctx: InferContext):
+    """Q (B, T, H, Dh) x K/V (B, T, Hkv, Dh) -> Out = Q's shape."""
+    if int(ctx.attr("window", 0)) < 1:
+        raise InferError("window must be >= 1, got %r"
+                         % ctx.attr("window", None))
+    return {"Out": _grouped_heads(ctx, ("K", "V"), (0, 2, 3))}
+
+
+@register_infer("decode_attn_ring")
+def _infer_decode_attn_ring(ctx: InferContext):
+    """Q (B, 1, H, Dh) x rings (B, W, Hkv, Dh) -> Out = Q's shape."""
+    return {"Out": _grouped_heads(ctx, ("KCache", "VCache"), (0, 2, 3))}
+
+
+@register_infer("ring_append")
+def _infer_ring_append(ctx: InferContext):
+    """Out is the ring: Cache's shape and dtype verbatim."""
+    return _infer_cache_append(ctx)
+
+
+@register_infer("ring_pack")
+def _infer_ring_pack(ctx: InferContext):
+    """Out is X with its time axis replaced by the window."""
+    x = ctx.in_info("X")
+    w = int(ctx.attr("window", 0))
+    if w < 1:
+        raise InferError("window must be >= 1, got %d" % w)
+    shape = None if x.shape is None else (x.shape[0], w) + tuple(x.shape[2:])
+    return {"Out": VarInfo(shape, x.dtype)}
+
+
 @register_infer("rms_norm")
 def _infer_rms_norm(ctx: InferContext):
     """Out mirrors X; Scale is X's last axis."""

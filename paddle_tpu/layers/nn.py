@@ -124,6 +124,14 @@ __all__ = [
     "ssm_step",
     "causal_conv1d",
     "causal_conv1d_step",
+    "rope",
+    "moe_route",
+    "moe_experts",
+    "moe_shared",
+    "attn_window",
+    "ring_append",
+    "ring_pack",
+    "decode_attn_ring",
 ]
 
 from .ops import elementwise_add  # re-export for parity
@@ -2525,3 +2533,142 @@ def causal_conv1d_step(x, window, w, bias=None, name=None):
     helper.append_op(type="causal_conv1d_step", inputs=inputs,
                      outputs={"Y": [y], "WindowOut": [new]}, attrs={})
     return y, new
+
+
+# ---------------------------------------------------------------------------
+# rotary positions, routed experts, sliding-window attention and its ring
+# (kernels: ops/rope.py, ops/moe.py, ops/attention.py, ops/kv_cache.py)
+# ---------------------------------------------------------------------------
+
+
+def rope(x, positions=None, rotary_dim=None, theta=10000.0,
+         attention_factor=1.0, yarn=None, name=None):
+    """Rotary position embedding (half-split convention) over the first
+    ``rotary_dim`` channels of each head of x (B, T, H, Dh), at
+    ``positions`` (B, T) (or (B,) for T = 1; None: 0..T-1). ``yarn``:
+    None, or a dict with factor, original_max_position, beta_fast,
+    beta_slow (YaRN's blended frequencies; ``attention_factor``
+    multiplies cos and sin)."""
+    helper = LayerHelper("rope", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype, shape=x.shape)
+    inputs = {"X": [x]}
+    if positions is not None:
+        inputs["Positions"] = [positions]
+    attrs = {"rotary_dim": int(rotary_dim or x.shape[-1]),
+             "theta": float(theta),
+             "attention_factor": float(attention_factor)}
+    if yarn:
+        attrs.update(
+            factor=float(yarn["factor"]),
+            original_max_position=int(yarn["original_max_position"]),
+            beta_fast=float(yarn.get("beta_fast", 32.0)),
+            beta_slow=float(yarn.get("beta_slow", 1.0)))
+    helper.append_op(type="rope", inputs=inputs, outputs={"Out": [out]},
+                     attrs=attrs)
+    return out
+
+
+def moe_route(x, w_router, top_k, scale=1.0, score="sigmoid", name=None):
+    """Router of a routed-expert layer: x (B, T, D), w_router (D, E) ->
+    (idx (B, T, k) int32, weights (B, T, k)): scores over all E experts
+    in float32, the k largest (ties to the lower index), renormalised
+    to sum 1 and multiplied by ``scale``."""
+    helper = LayerHelper("moe_route", name=name)
+    shape = tuple(x.shape[:-1]) + (int(top_k),)
+    idx = helper.create_variable_for_type_inference("int32", shape=shape)
+    w = helper.create_variable_for_type_inference("float32", shape=shape)
+    helper.append_op(
+        type="moe_route", inputs={"X": [x], "W": [w_router]},
+        outputs={"Idx": [idx], "Weights": [w]},
+        attrs={"top_k": int(top_k), "scale": float(scale),
+               "score": str(score)})
+    return idx, w
+
+
+def moe_experts(x, idx, weights, w_gate, w_up, w_down, expert_lo=0,
+                lengths=None, decode=False, name=None):
+    """The routed experts HELD here, ``[expert_lo, expert_lo + Eh)``:
+    every (token, expert) pair that falls on one is computed, whatever
+    the load (no capacity, no drop). x (B, T, D); idx, weights from
+    ``moe_route``; w_gate, w_up (Eh, D, F), w_down (Eh, F, D) -> (out
+    (B, T, D), load (Eh,) int32: pairs each held expert received from
+    real tokens; ``lengths`` (B,) says which tokens are real)."""
+    helper = LayerHelper("moe_experts", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype, shape=x.shape)
+    load = helper.create_variable_for_type_inference(
+        "int32", shape=(w_gate.shape[0],))
+    inputs = {"X": [x], "Idx": [idx], "Weights": [weights],
+              "WGate": [w_gate], "WUp": [w_up], "WDown": [w_down]}
+    if lengths is not None:
+        inputs["Lengths"] = [lengths]
+    helper.append_op(
+        type="moe_experts", inputs=inputs,
+        outputs={"Out": [out], "Load": [load]},
+        attrs={"expert_lo": int(expert_lo), "decode": bool(decode)})
+    return out, load
+
+
+def moe_shared(x, w_gate, w_up, w_down, name=None):
+    """The shared expert every token passes through:
+    (silu(x w_gate) * (x w_up)) w_down."""
+    helper = LayerHelper("moe_shared", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype, shape=x.shape)
+    helper.append_op(
+        type="moe_shared",
+        inputs={"X": [x], "WGate": [w_gate], "WUp": [w_up],
+                "WDown": [w_down]},
+        outputs={"Out": [out]}, attrs={})
+    return out
+
+
+def attn_window(q, k, v, window, scale=None, name=None):
+    """Causal prefill attention of a sliding-window layer: q (B, T, H,
+    Dh), k/v (B, T, Hkv, Dh); a query sees the last ``window`` keys up
+    to its own."""
+    helper = LayerHelper("attn_window", name=name)
+    out = helper.create_variable_for_type_inference(q.dtype, shape=q.shape)
+    helper.append_op(
+        type="attn_window", inputs={"Q": [q], "K": [k], "V": [v]},
+        outputs={"Out": [out]},
+        attrs={"window": int(window), "scale": scale})
+    return out
+
+
+def ring_append(ring, new, pos, name=None):
+    """Write ``new`` (B, 1, ...) at row ``pos mod W`` of a
+    sliding-window layer's ring (B, W, ...)."""
+    helper = LayerHelper("ring_append", name=name)
+    out = helper.create_variable_for_type_inference(
+        ring.dtype, shape=ring.shape)
+    helper.append_op(
+        type="ring_append",
+        inputs={"Cache": [ring], "New": [new], "Pos": [pos]},
+        outputs={"Out": [out]}, attrs={})
+    return out
+
+
+def ring_pack(x, lengths, window, name=None):
+    """A prefill's rows x (B, T, ...) -> the ring (B, window, ...): each
+    row's last ``min(len, window)`` positions at ``position mod
+    window``."""
+    helper = LayerHelper("ring_pack", name=name)
+    out = helper.create_variable_for_type_inference(
+        x.dtype, shape=(x.shape[0], int(window)) + tuple(x.shape[2:]))
+    helper.append_op(
+        type="ring_pack", inputs={"X": [x], "Lengths": [lengths]},
+        outputs={"Out": [out]}, attrs={"window": int(window)})
+    return out
+
+
+def decode_attn_ring(q, k_ring, v_ring, lengths, scale=None, name=None):
+    """Single-query attention against a sliding-window layer's rings
+    (B, W, Hkv, Dh); ``lengths`` (B,) positions held including this
+    step's row."""
+    helper = LayerHelper("decode_attn_ring", name=name)
+    out = helper.create_variable_for_type_inference(q.dtype, shape=q.shape)
+    helper.append_op(
+        type="decode_attn_ring",
+        inputs={"Q": [q], "KCache": [k_ring], "VCache": [v_ring],
+                "Lengths": [lengths]},
+        outputs={"Out": [out]}, attrs={"scale": scale})
+    return out

@@ -1,9 +1,20 @@
-"""Hybrid state-space / attention decoder LM, as AI21's Jamba builds it
-(`model_type: jamba`): Mamba-1 layers with one attention layer every
-``attn_layer_period``, each followed by a gated-SiLU MLP, RMS
-normalization before every mixer and MLP and after the last layer, NO
-position term (the state-space layers carry order), no biases but the
-convolution's and ``dt_proj``'s, logits through the tied token table.
+"""Decoder LMs of a DESCRIBED block: a mixer kind (``mamba`` |
+``attention`` | ``sliding``) times a feed-forward kind (``dense`` |
+``experts``) a layer, RMS normalization before every mixer and
+feed-forward and after the last layer, no biases but the convolution's
+and ``dt_proj``'s. ``serving.decode.DecodeConfig`` says which:
+
+- AI21's Jamba (`model_type: jamba`): Mamba-1 layers with one attention
+  layer every ``attn_layer_period``, each followed by a gated-SiLU MLP,
+  NO position term (the state-space layers carry order), logits through
+  the tied token table;
+- poolside's Laguna (`model_type: laguna`): full and sliding-window
+  attention layers mixed, query heads by layer on shared key/value
+  heads of their own width, rotary positions by layer kind (plain,
+  partial, YaRN), a sigmoid gate a query head on the attention output,
+  a leading dense MLP and then routed experts with a shared one
+  (``ops/moe.py``: no token dropped, the experts held here), an untied
+  output head.
 
 The serving graphs only (serving/decode.py): ``hybrid_lm_prefill``
 walks padded prompts and returns every layer's cache entries AT EACH
@@ -17,7 +28,9 @@ layer ``i`` keeps ``conv_i`` (B, K - 1, d_inner), the convolution's
 window, and ``ssm_i`` (B, d_inner, N), the recurrent state: fixed
 size, no row per position. An attention layer keeps ``kcache_i`` /
 ``vcache_i`` (B, S, n_kv_head, d_head) slabs: the key/value heads as
-they are, never repeated for the query heads that share them.
+they are, never repeated for the query heads that share them. A
+sliding layer keeps ``kring_i`` / ``vring_i`` (B, window, n_kv_head,
+d_head): position p at row p mod window. Keys are stored ROTATED.
 """
 from __future__ import annotations
 
@@ -35,6 +48,8 @@ def cache_names(kind: str, i: int):
     takes and returns them."""
     if kind == "mamba":
         return ["conv_%d" % i, "ssm_%d" % i]
+    if kind == "sliding":
+        return ["kring_%d" % i, "vring_%d" % i]
     return ["kcache_%d" % i, "vcache_%d" % i]
 
 
@@ -90,29 +105,53 @@ def _mamba_mixer(u, cfg, name, lengths, cache):
     return _proj(y, cfg.d_model, name + ".out_proj"), (window, state)
 
 
-def _attention_mixer(u, cfg, name, lengths, cache):
-    """``n_head`` query heads on ``n_kv_head`` key/value heads, no
-    bias, no rotation. ``cache`` is None (prefill: causal flash
-    attention; the slab entries are this prompt's k and v) or
-    (k slab, v slab) (one token: append at ``lengths``, attend
-    lengths + 1 rows). Returns (out, (k, v))."""
+def _attention_mixer(u, cfg, name, lengths, cache, i=0, kind="attention"):
+    """Layer ``i``'s query heads on ``n_kv_head`` key/value heads, no
+    bias; rotary positions where ``cfg.rope`` names this layer kind
+    (a prefill rotates row t at t, a decode step its one row at
+    ``lengths``). ``cache`` is None (prefill: causal flash attention,
+    or ``attn_window`` on a sliding layer; the entries are this
+    prompt's k and v, packed into a ring on a sliding layer) or the
+    layer's two entries (one token: append at ``lengths``, or at
+    ``lengths mod window`` into a ring, and attend). A per-head sigmoid
+    gate from the layer's input scales the attention output where
+    ``cfg.attn_gate`` asks. Returns (out, (k, v))."""
     B, T, _ = u.shape
-    h, hkv, dh = cfg.n_head, cfg.n_kv_head, cfg.d_head
+    h, hkv, dh = cfg.heads(i), cfg.n_kv_head, cfg.d_head
+    sliding = kind == "sliding"
     q = layers.reshape(_proj(u, h * dh, name + ".q"), shape=[B, T, h, dh])
     k = layers.reshape(_proj(u, hkv * dh, name + ".k"),
                        shape=[B, T, hkv, dh])
     v = layers.reshape(_proj(u, hkv * dh, name + ".v"),
                        shape=[B, T, hkv, dh])
+    rot = (cfg.rope or {}).get("sliding" if sliding else "full")
+    if rot:
+        at = None if cache is None else lengths
+        q = layers.rope(q, at, **rot)
+        k = layers.rope(k, at, **rot)
     if cache is None:
-        # the op repeats k and v for the query heads that share them
-        ctx = layers.fused_attention(q, k, v, causal=True, layout="bthd")
+        if sliding:
+            ctx = layers.attn_window(q, k, v, cfg.window)
+            k = layers.ring_pack(k, lengths, cfg.window)
+            v = layers.ring_pack(v, lengths, cfg.window)
+        else:
+            # the op repeats k and v for the query heads that share them
+            ctx = layers.fused_attention(q, k, v, causal=True,
+                                         layout="bthd")
     else:
-        k = layers.cache_append(cache[0], k, lengths)
-        v = layers.cache_append(cache[1], v, lengths)
+        append = layers.ring_append if sliding else layers.cache_append
+        k = append(cache[0], k, lengths)
+        v = append(cache[1], v, lengths)
         kv_lengths = layers.elementwise_add(
             layers.cast(lengths, "int32"),
             layers.fill_constant(shape=[B], dtype="int32", value=1))
-        ctx = layers.decode_attention(q, k, v, kv_lengths)
+        attend = (layers.decode_attn_ring if sliding
+                  else layers.decode_attention)
+        ctx = attend(q, k, v, kv_lengths)
+    if cfg.attn_gate == "per_head":
+        gate = layers.sigmoid(_proj(u, h, name + ".gate"))
+        ctx = layers.elementwise_mul(
+            ctx, layers.reshape(gate, shape=[B, T, h, 1]))
     out = _proj(layers.reshape(ctx, shape=[B, T, h * dh]), cfg.d_model,
                 name + ".o")
     return out, (k, v)
@@ -125,28 +164,81 @@ def _mlp(x, cfg, name):
                  name + ".down")
 
 
-def _layer(x, kind, i, cfg, lengths, cache=None):
+def _experts(x, cfg, name, lengths, decode):
+    """Routed experts with a shared one: x (B, T, D) -> (out, load
+    (experts held,) int32). The router scores all ``n_expert``; the
+    experts ``cfg.held`` are the ones computed."""
+    d, f = cfg.d_model, cfg.d_expert
+    lo, hi = cfg.held
+    w = NormalInitializer(0.0, 0.02)
+    idx, weights = layers.moe_route(
+        x, _param([d, cfg.n_expert], name + ".router.w", w),
+        cfg.expert_top_k, scale=cfg.router_scale, score=cfg.router_score)
+    routed, load = layers.moe_experts(
+        x, idx, weights,
+        _param([hi - lo, d, f], name + ".experts.gate.w", w),
+        _param([hi - lo, d, f], name + ".experts.up.w", w),
+        _param([hi - lo, f, d], name + ".experts.down.w", w),
+        expert_lo=lo, lengths=lengths, decode=decode)
+    fs = cfg.d_shared_expert
+    shared = layers.moe_shared(
+        x, _param([d, fs], name + ".shared.gate.w", w),
+        _param([d, fs], name + ".shared.up.w", w),
+        _param([fs, d], name + ".shared.down.w", w))
+    return layers.elementwise_add(routed, shared), load
+
+
+def _layer(x, kind, i, cfg, lengths, cache=None, loads=None):
     """THE description of layer ``i``: x (B, T, D) -> (x, cache
     entries in ``cache_names(kind, i)`` order). Prefill and decode
-    differ only in ``cache``."""
+    differ only in ``cache``. An expert layer appends its load to
+    ``loads``."""
     name = "%s.l%d" % (cfg.prefix, i)
-    mixer = _mamba_mixer if kind == "mamba" else _attention_mixer
-    mixed, entries = mixer(_rms(x, name + ".norm_in", cfg.norm_eps), cfg,
-                           name + "." + kind, lengths, cache)
+    u = _rms(x, name + ".norm_in", cfg.norm_eps)
+    if kind == "mamba":
+        mixed, entries = _mamba_mixer(u, cfg, name + ".mamba", lengths,
+                                      cache)
+    else:
+        mixed, entries = _attention_mixer(u, cfg, name + ".attention",
+                                          lengths, cache, i, kind)
     x = layers.elementwise_add(x, mixed)
-    ffn = _mlp(_rms(x, name + ".norm_ff", cfg.norm_eps), cfg, name + ".mlp")
+    u = _rms(x, name + ".norm_ff", cfg.norm_eps)
+    if cfg.ffn_kinds()[i] == "experts":
+        ffn, load = _experts(u, cfg, name + ".moe", lengths,
+                             cache is not None)
+        loads.append(load)
+    else:
+        ffn = _mlp(u, cfg, name + ".mlp")
     return layers.elementwise_add(x, ffn), entries
 
 
 def _check(cfg):
+    """Refuse what no graph here computes."""
     if (cfg.norm != "rms_norm" or cfg.ffn != "gated_silu" or cfg.positions
-            or cfg.biases or not cfg.tie_embeddings):
+            or cfg.biases):
         raise ValueError(
-            "the hybrid builders write Jamba's block: RMS norms, a gated-"
-            "SiLU MLP, no positions, no biases, a tied table; got norm=%r "
-            "ffn=%r positions=%r biases=%r tie_embeddings=%r"
-            % (cfg.norm, cfg.ffn, cfg.positions, cfg.biases,
-               cfg.tie_embeddings))
+            "the described-block builders write RMS norms, a gated-SiLU "
+            "MLP, no learned positions and no biases; got norm=%r ffn=%r "
+            "positions=%r biases=%r"
+            % (cfg.norm, cfg.ffn, cfg.positions, cfg.biases))
+    if cfg.attn_gate not in (None, "per_head"):
+        raise ValueError("attn_gate %r: only a sigmoid gate a query head "
+                         "('per_head') is built" % (cfg.attn_gate,))
+    if "experts" in cfg.ffn_kinds() and (
+            cfg.router_score != "sigmoid" or not cfg.d_shared_expert):
+        raise ValueError(
+            "an expert layer is built with sigmoid scores and a shared "
+            "expert; got router_score=%r d_shared_expert=%r"
+            % (cfg.router_score, cfg.d_shared_expert))
+    for kind, rot in (cfg.rope or {}).items():
+        if kind not in ("full", "sliding") or rot.get(
+                "rotary_dim", cfg.d_head) > cfg.d_head:
+            raise ValueError("rope[%r] = %r does not describe a rotation "
+                             "of a head of %d" % (kind, rot, cfg.d_head))
+    if set(cfg.ffn_kinds()) - {"dense", "experts"} or set(
+            cfg.attn_types or ()) - {"full", "sliding"}:
+        raise ValueError("ffn_types %r / attn_types %r name a kind no "
+                         "graph computes" % (cfg.ffn_types, cfg.attn_types))
 
 
 def _embed(tokens, cfg):
@@ -157,24 +249,35 @@ def _embed(tokens, cfg):
 
 
 def _head(last, cfg):
-    """(B, D) -> (B, V) through the tied table, no bias."""
+    """(B, D) -> (B, V), no bias: through the tied table, or through
+    the head's own matrix ``head.w`` (D, V)."""
+    if not cfg.tie_embeddings:
+        return layers.matmul(last, _param(
+            [cfg.d_model, cfg.vocab_size], cfg.prefix + ".head.w",
+            NormalInitializer(0.0, 0.02)))
     emb = default_main_program().global_block().var(cfg.prefix + ".tok_emb")
     return layers.matmul(last, emb, transpose_y=True)
 
 
-def hybrid_lm_prefill(tokens, lengths, cfg):
+def hybrid_lm_prefill(tokens, lengths, cfg, extras=None):
     """Padded prompts ``tokens`` (B, S), ``lengths`` (B,) -> (logits
     (B, V) of each row's last real position, {feed name: cache entry}):
     slab entries hold the prompt's k and v rows (garbage past a row's
     length, masked by length later), state entries the state and the
-    window after each row's LAST REAL token."""
+    window after each row's LAST REAL token, ring entries each row's
+    last ``window`` positions as a decode step will find them.
+    ``extras`` (a dict) receives ``moe_load`` where layers route over
+    experts: the pairs of REAL tokens each held expert received."""
     _check(cfg)
     B, S = tokens.shape
     x = _embed(tokens, cfg)
-    caches = {}
+    caches, loads = {}, []
     for i, kind in enumerate(cfg.layer_kinds()):
-        x, entries = _layer(x, kind, i, cfg, lengths)
+        x, entries = _layer(x, kind, i, cfg, lengths, loads=loads)
         caches.update(zip(cache_names(kind, i), entries))
+    if extras is not None and loads:
+        # (sparse layers, experts held) int32
+        extras["moe_load"] = layers.stack(loads, axis=0)
     x = _rms(x, cfg.prefix + ".norm_f", cfg.norm_eps)
     flat = layers.reshape(x, shape=[B * S, cfg.d_model])
     base = layers.assign((np.arange(B, dtype=np.int32) * S - 1).reshape(B))
@@ -183,7 +286,8 @@ def hybrid_lm_prefill(tokens, lengths, cfg):
 
 
 def hybrid_lm_decode(tokens, lengths, caches, cfg, strategy="greedy",
-                     seed=None, sample_k=40, sample_p=0.9, temperature=1.0):
+                     seed=None, sample_k=40, sample_p=0.9, temperature=1.0,
+                     extras=None):
     """One token per slot: ``tokens`` (B, 1), ``lengths`` (B,) tokens
     each slot holds BEFORE this one, ``caches`` {feed name: entry} ->
     (next_ids (B,) or None, logits (B, V), {feed name: updated
@@ -192,12 +296,16 @@ def hybrid_lm_decode(tokens, lengths, caches, cfg, strategy="greedy",
     B = tokens.shape[0]
     # embedding squeezes the trailing ids dim of 1: restore the time axis
     x = layers.reshape(_embed(tokens, cfg), shape=[B, 1, cfg.d_model])
-    new = {}
+    new, loads = {}, []
     for i, kind in enumerate(cfg.layer_kinds()):
         names = cache_names(kind, i)
         x, entries = _layer(x, kind, i, cfg, lengths,
-                            cache=tuple(caches[n] for n in names))
+                            cache=tuple(caches[n] for n in names),
+                            loads=loads)
         new.update(zip(names, entries))
+    if extras is not None and loads:
+        # (sparse layers, experts held) int32
+        extras["moe_load"] = layers.stack(loads, axis=0)
     x = _rms(x, cfg.prefix + ".norm_f", cfg.norm_eps)
     logits = _head(layers.reshape(x, shape=[B, cfg.d_model]), cfg)
     next_ids = sample_next(logits, strategy, seed, sample_k, sample_p,

@@ -269,6 +269,15 @@ DECODE_STEPS = REGISTRY.counter(
     "before the host saw a token) | 0 (the first step after a park, a "
     "failure or an emptied batch); the share of 1 is how often the "
     "per-token round trip is hidden")
+MOE_EXPERT_PAIRS = REGISTRY.counter(
+    "paddle_tpu_moe_expert_pairs_total",
+    "Token-expert pairs the decode path routed to the experts it holds "
+    "(every one computed: the serving expert layer drops none), by layer")
+MOE_LOAD_MAX_OVER_MEAN = REGISTRY.gauge(
+    "paddle_tpu_moe_load_max_over_mean",
+    "The busiest held expert's pairs over the mean of the held experts', "
+    "over a decode server's life, by layer (1 = even; an expert-parallel "
+    "deployment waits for its busiest chip)")
 DECODE_SLOTS = REGISTRY.gauge(
     "paddle_tpu_decode_slots",
     "Continuous-batching cache-slot occupancy, state=active|free "

@@ -182,8 +182,21 @@ def _causal_mask(s, row0, col0):
     return jnp.where(col <= row, s, _NEG)
 
 
+def _window_mask(s, row0, col0, window):
+    """Keys at or before ``row - window`` get _NEG (with the causal
+    mask: key j is visible to query t iff t - window < j <= t)."""
+    bq, bk = s.shape
+    row = row0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    col = col0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    return jnp.where(col > row - window, s, _NEG)
+
+
 def _mha_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
-                    block_k, seq_k, causal, pid_axis=1):
+                    block_k, seq_k, causal, pid_axis=1, window=0):
+    """``window`` > 0 (causal only): a query at ``t`` sees the keys
+    ``(t - window, t]``; KV blocks wholly before a q-block's window are
+    SKIPPED (the loop starts at the first block that holds a visible
+    key), as blocks above the diagonal are."""
     qi = pl.program_id(pid_axis)
     # keep matmul operands in the input dtype (bf16 under mixed precision:
     # the MXU runs bf16 x bf16 -> f32 at full rate; converting to f32 first
@@ -199,6 +212,8 @@ def _mha_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
         s = jnp.dot(q, kb.T, preferred_element_type=jnp.float32)
         if causal:
             s = _causal_mask(s, qi * block_q, j * block_k)
+        if window:
+            s = _window_mask(s, qi * block_q, j * block_k, window)
         m_new = jnp.maximum(m, jnp.max(s, axis=1))
         p = jnp.exp(s - m_new[:, None])
         corr = jnp.exp(m - m_new)
@@ -217,7 +232,10 @@ def _mha_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
         upper = lax.min(((qi + 1) * block_q + block_k - 1) // block_k, nkv)
     else:
         upper = nkv
-    acc, m, l = lax.fori_loop(0, upper, blk, init)
+    # the first key the q-block's first row sees is row0 - window + 1
+    lower = (lax.max(qi * block_q - window + 1, 0) // block_k
+             if window else 0)
+    acc, m, l = lax.fori_loop(lower, upper, blk, init)
     l = jnp.maximum(l, 1e-30)
     o_ref[0] = (acc / l[:, None]).astype(o_ref.dtype)
     # lse is blocked as a full (1, T) row (TPU block-shape tiling rejects
@@ -536,15 +554,16 @@ def _lse_spec_bthd(h, t):
     return pl.BlockSpec((1, 1, t), lambda bi, hi, qi: (bi * h + hi, 0, 0))
 
 
-def _mha_fwd_call_bthd(qs, k, v, h, causal, block_q, block_k, interpret):
+def _mha_fwd_call_bthd(qs, k, v, h, causal, block_q, block_k, interpret,
+                       window=0, name=None):
     b, t, hd = qs.shape
     tk = k.shape[1]
     d = hd // h
     kernel = functools.partial(
         _mha_fwd_kernel, block_q=block_q, block_k=block_k, seq_k=tk,
-        causal=causal, pid_axis=2)
+        causal=causal, pid_axis=2, window=window)
     return named_pallas_call(
-        FLASH_FWD, kernel,
+        name or FLASH_FWD, kernel,
         grid=(b, h, t // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bi, hi, qi: (bi, qi, hi)),
@@ -880,6 +899,69 @@ def _use_pallas(t, tk, lengths, dropout_rate) -> bool:
     # 128 matches _fit_block's floor so the dispatch gate and the kernel
     # entry can never disagree; tiny sequences stay on the XLA path
     return t % 128 == 0 and tk % 128 == 0 and t >= 256 and tk >= 256
+
+
+ATTN_WINDOW = "ptpu.attn_window"
+
+
+def attn_window_reference(q, k, v, window, scale=None):
+    """Causal attention over a sliding window, exact, pure lax: q (B, T,
+    H, Dh), k/v (B, T, Hkv, Dh) with H = g * Hkv; key j is visible to
+    query t iff t - window < j <= t. Builds the (T, T) scores: the CPU
+    path and the numeric reference of the kernel."""
+    b, t, h, d = q.shape
+    hkv = k.shape[2]
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
+    qf = (q.astype(jnp.float32) * scale).reshape(b, t, hkv, h // hkv, d)
+    s = jnp.einsum("btkgd,bskd->bkgts", qf, k.astype(jnp.float32))
+    row = jnp.arange(t)[:, None]
+    col = jnp.arange(t)[None, :]
+    seen = (col <= row) & (col > row - window)
+    p = jax.nn.softmax(jnp.where(seen, s, _NEG), axis=-1)
+    out = jnp.einsum("bkgts,bskd->btkgd", p, v.astype(jnp.float32))
+    return out.reshape(b, t, h, d).astype(q.dtype)
+
+
+def attn_window(q, k, v, window, scale=None, interpret=False):
+    """Prefill attention of a sliding-window layer over (B, T, H, Dh)
+    queries and (B, T, Hkv, Dh) keys/values. On a TPU with lane-aligned
+    heads and block-aligned sequences the flash forward kernel under
+    the name ``ptpu.attn_window``, which SKIPS the key blocks wholly
+    before a query block's window (and those above the diagonal) and
+    masks inside the two or three blocks left; the exact lax path
+    elsewhere. Forward only: no training graph has a window."""
+    b, t, h, d = q.shape
+    window = int(window)
+    if window >= t:
+        window = 0  # every earlier key is inside it: plain causal
+    if not (interpret or (d % 128 == 0 and _use_pallas(t, t, None, 0.0))):
+        with jax.named_scope(ATTN_WINDOW):
+            return attn_window_reference(q, k, v, window or t, scale)
+    group = h // k.shape[2]
+    if group > 1:
+        # the kernel takes q, k, v of one head count (fused_attention)
+        k = jnp.repeat(k, group, axis=2)
+        v = jnp.repeat(v, group, axis=2)
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
+    block_q = _fit_block(t, _env_block("PADDLE_TPU_FLASH_BQ", 512))
+    block_k = _fit_block(t, _env_block("PADDLE_TPU_FLASH_BK", 512))
+    qs = (q * jnp.asarray(scale, q.dtype)).reshape(b, t, h * d)
+    out, _lse = _mha_fwd_call_bthd(
+        qs, k.reshape(b, t, h * d), v.reshape(b, t, h * d), h, True,
+        block_q, block_k, interpret, window=window, name=ATTN_WINDOW)
+    return out.reshape(b, t, h, d)
+
+
+@register_op("attn_window")
+def _attn_window_op(ctx):
+    """Inputs Q (B, T, H, Dh), K, V (B, T, Hkv, Dh); attrs window,
+    scale -> Out = Q's shape: causal attention in which a query sees
+    the last ``window`` keys up to its own."""
+    return {"Out": attn_window(ctx.input("Q"), ctx.input("K"),
+                               ctx.input("V"), int(ctx.attr("window")),
+                               scale=ctx.attr("scale", None))}
 
 
 @register_op("ring_attention")

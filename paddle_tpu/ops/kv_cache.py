@@ -63,6 +63,10 @@ _NEG = -1e30
 DECODE_ATTN = "ptpu.decode_attn"
 # the lax path of a slab with fewer heads than the query
 DECODE_ATTN_GROUPED = "ptpu.decode_attn_grouped"
+# a sliding-window layer's cache: a ring of `window` rows
+DECODE_ATTN_RING = "ptpu.decode_attn_ring"
+RING_APPEND = "ptpu.ring_append"
+RING_PACK = "ptpu.ring_pack"
 
 
 # ---------------------------------------------------------------------------
@@ -386,3 +390,94 @@ def _cache_gather_op(ctx):
     """Inputs Cache (B, S, ...), Index (N,) int32 slot indices -> Out
     (N, S, ...): slab rows reordered/duplicated by slot."""
     return {"Out": cache_gather(ctx.input("Cache"), ctx.input("Index"))}
+
+
+# ---------------------------------------------------------------------------
+# the ring of a sliding-window layer
+# ---------------------------------------------------------------------------
+#
+# A layer whose queries see the last `window` keys keeps a RING of
+# `window` rows a slot, not a row per position: position p lives at
+# row p mod window. The keys are rotated at their absolute positions
+# before they are stored and a softmax does not care for the order of
+# its keys, so attending the ring is attending its live rows: all of
+# them once `window` positions have been written, rows [0, held) before.
+
+
+def ring_append(ring, new, pos):
+    """ring (B, W, ...) with ``new`` (B, 1, ...) written at row
+    ``pos[b] mod W``: the position's own row, over the one that left
+    the window."""
+    with jax.named_scope(RING_APPEND):
+        w = ring.shape[1]
+        return cache_append(ring, new,
+                            pos.reshape(-1).astype(jnp.int32) % w)
+
+
+def ring_pack(rows, lengths, window):
+    """A prefill's rows (B, T, ...) -> the ring (B, W, ...) an
+    admission stores: each row's last ``min(len, W)`` positions, at
+    ``position mod W``. Rows the prompt did not reach hold whatever the
+    padding computed; ``decode_attn_ring`` masks them by length.
+
+    One contiguous slice a row (the positions ``[start, start + W)``,
+    ``start = max(len - W, 0)``), then a rotation by ``start mod W``: a
+    ``dynamic_slice`` under ``vmap``, never an elementwise gather (a
+    ``take_along_axis`` over a wide array hung the chip: PERF.md 7 h)."""
+    w = int(window)
+    b, t = rows.shape[0], rows.shape[1]
+    with jax.named_scope(RING_PACK):
+        if t < w:
+            rows = jnp.pad(rows, [(0, 0), (0, w - t)]
+                           + [(0, 0)] * (rows.ndim - 2))
+            t = w
+        lens = jnp.clip(lengths.reshape(-1).astype(jnp.int32), 0, t)
+        start = jnp.maximum(lens - w, 0)
+
+        def one(row, at):
+            seg = lax.dynamic_slice_in_dim(row, at, w, axis=0)
+            # seg[j] is position at + j and goes to row (at + j) mod W
+            return lax.dynamic_slice_in_dim(
+                jnp.concatenate([seg, seg], axis=0), (w - at % w) % w, w,
+                axis=0)
+
+        return jax.vmap(one)(rows, start)
+
+
+def decode_attn_ring(q, k_ring, v_ring, lengths, scale=None):
+    """q (B, 1, H, Dh) against rings (B, W, Hkv, Dh); ``lengths`` (B,)
+    the positions held INCLUDING this step's freshly written row. The
+    exact grouped lax path over ``min(lengths, W)`` live rows."""
+    with jax.named_scope(DECODE_ATTN_RING):
+        w = k_ring.shape[1]
+        live = jnp.minimum(lengths.reshape(-1).astype(jnp.int32), w)
+        return decode_attention_reference(q, k_ring, v_ring, live,
+                                          scale=scale)
+
+
+@register_op("ring_append")
+def _ring_append_op(ctx):
+    """Inputs Cache (B, W, ...), New (B, 1, ...), Pos (B,) the
+    position written (the slot's CURRENT length) -> Out: the ring with
+    row ``Pos mod W`` replaced."""
+    return {"Out": ring_append(ctx.input("Cache"), ctx.input("New"),
+                               ctx.input("Pos"))}
+
+
+@register_op("ring_pack")
+def _ring_pack_op(ctx):
+    """Inputs X (B, T, ...), Lengths (B,); attr window -> Out (B, W,
+    ...): each row's last ``min(len, W)`` positions at ``position mod
+    W``."""
+    return {"Out": ring_pack(ctx.input("X"), ctx.input("Lengths"),
+                             int(ctx.attr("window")))}
+
+
+@register_op("decode_attn_ring")
+def _decode_attn_ring_op(ctx):
+    """Inputs Q (B, 1, H, Dh), KCache/VCache (B, W, Hkv, Dh), Lengths
+    (B,) positions held including the current token's -> Out = Q's
+    shape."""
+    return {"Out": decode_attn_ring(
+        ctx.input("Q"), ctx.input("KCache"), ctx.input("VCache"),
+        ctx.input("Lengths"), scale=ctx.attr("scale", None))}

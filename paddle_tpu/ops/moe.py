@@ -1,0 +1,182 @@
+"""Routed-expert feed-forward for serving: NO token is dropped.
+
+``parallel/moe.py`` is the capacity-factor TRAINING layer (a token past
+an expert's capacity gets zero weight); a cached decode graph has to
+equal the model's forward pass, so this file computes every chosen
+(token, expert) pair whatever the load. Three ops, one scope each:
+
+- ``moe_route`` (``ptpu.moe_route``): scores over ALL ``E`` experts in
+  float32 (the router's matmul at ``highest`` precision: it is tiny and
+  its order decides a discontinuous choice), the ``k`` largest (ties to
+  the lower index), renormalised to sum 1, times ``scale``.
+- ``moe_experts`` (``ptpu.moe_experts``): the experts HELD here, ``[lo,
+  lo + Eh)`` of the ``E`` the router chose among ("route over all,
+  compute your own": a chip of an expert-parallel deployment holds a
+  share, and on one chip the layer runs without its exchange). Returns
+  the weighted sum over a token's pairs that fall on held experts, and
+  the pairs each held expert received.
+- ``moe_shared`` (``ptpu.moe_shared``): the shared expert, a gated
+  SiLU MLP every token passes through.
+
+One exact form of the routed product, for a prefill and a decode step
+alike: the pairs are sorted by expert and a grouped product
+(``lax.ragged_dot``; the TPU compiler has kernels of its own for it,
+``ragged-dot-none`` in a trace) runs over blocks of ``_BLOCK_PAIRS``
+sorted pairs in a loop whose trip count is the HELD pairs', so work and
+temporaries follow the pairs routed here and not the worst case, and
+only the experts that received a pair are streamed. (A dense product
+over every held expert with a mask was measured beside it on the chip at
+a decode step's 64 tokens: 2.08 ms a layer against 1.55, PERF.md PR 31;
+at a prefill it does 32x the FLOPs. It is not kept.)
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from .registry import register_op
+
+MOE_ROUTE = "ptpu.moe_route"
+MOE_EXPERTS = "ptpu.moe_experts"
+MOE_SHARED = "ptpu.moe_shared"
+
+# sorted pairs one iteration gathers and multiplies: a block's rows past
+# the held pairs are padding that still costs (0.2 us a row on a v5e), a
+# single prompt of 1-2 k tokens routes 2-4 k pairs here
+_BLOCK_PAIRS = 4096
+
+
+def _silu(x):
+    return x * jax.nn.sigmoid(x)
+
+
+def moe_route(x, w_router, top_k, scale=1.0, score="sigmoid"):
+    """x (..., D), w_router (D, E) -> (idx (..., k) int32, weights
+    (..., k) float32)."""
+    with jax.named_scope(MOE_ROUTE):
+        logits = jnp.matmul(x.astype(jnp.float32),
+                            w_router.astype(jnp.float32),
+                            precision=lax.Precision.HIGHEST)
+        if score != "sigmoid":
+            raise ValueError("moe_route: score function %r is not built"
+                             % score)
+        s = jax.nn.sigmoid(logits)
+        top, idx = lax.top_k(s, int(top_k))
+        top = top / jnp.sum(top, axis=-1, keepdims=True)
+        return idx.astype(jnp.int32), top * jnp.float32(scale)
+
+
+def _held(idx, weights, valid, lo, n_held):
+    """Pairs as flat arrays: local expert id in [0, n_held) or n_held
+    for a pair that is not computed here (another chip's expert, or a
+    token that is padding), its weight, and its token."""
+    n, k = idx.shape
+    local = idx - lo
+    here = (local >= 0) & (local < n_held)
+    if valid is not None:
+        here = here & valid[:, None]
+    local = jnp.where(here, local, n_held).reshape(-1)
+    token = jnp.repeat(jnp.arange(n, dtype=jnp.int32), k)
+    return local, jnp.where(here, weights, 0.0).reshape(-1), token
+
+
+def _experts_grouped(x, local, w, token, w_gate, w_up, w_down, counts):
+    """Pairs sorted by held expert, a grouped product a block of them."""
+    n, d = x.shape
+    m = local.shape[0]
+    blk = min(_BLOCK_PAIRS, m)
+    order = jnp.argsort(local)  # stable: held experts first, by id
+    pad = (-m) % blk
+    tok_s = jnp.pad(token[order], (0, pad))
+    w_s = jnp.pad(w[order], (0, pad))
+    ends = jnp.cumsum(counts)
+    starts = ends - counts
+    n_held = ends[-1]
+
+    def body(i, out):
+        r0 = i * blk
+        rows = lax.dynamic_slice_in_dim(tok_s, r0, blk)
+        wr = lax.dynamic_slice_in_dim(w_s, r0, blk)
+        # this block's share of each group
+        sizes = (jnp.clip(ends, r0, r0 + blk)
+                 - jnp.clip(starts, r0, r0 + blk)).astype(jnp.int32)
+        xs = x[rows]
+        h = _silu(lax.ragged_dot(xs, w_gate, sizes)) * lax.ragged_dot(
+            xs, w_up, sizes)
+        y = lax.ragged_dot(h, w_down, sizes)
+        # rows past the held pairs belong to no group: weight 0, and
+        # whatever the product left there is not read
+        live = (r0 + jnp.arange(blk)) < n_held
+        y = jnp.where(live[:, None], y * wr[:, None], 0.0)
+        return out.at[rows].add(y)
+
+    trips = (n_held + blk - 1) // blk
+    return lax.fori_loop(0, trips, body, jnp.zeros((n, d), jnp.float32))
+
+
+def moe_experts(x, idx, weights, w_gate, w_up, w_down, lo=0, valid=None):
+    """x (N, D); idx, weights (N, k) from ``moe_route``; w_gate, w_up
+    (Eh, D, F), w_down (Eh, F, D): the experts ``[lo, lo + Eh)``;
+    ``valid`` (N,) bool or None marks real tokens. -> (out (N, D): sum
+    over a token's pairs on held experts of weight * expert(x), load
+    (Eh,) int32 pairs a held expert received from real tokens)."""
+    eh = w_gate.shape[0]
+    with jax.named_scope(MOE_EXPERTS):
+        local, w, token = _held(idx, weights, valid, int(lo), eh)
+        counts = jnp.zeros((eh + 1,), jnp.int32).at[local].add(1)[:eh]
+        out = _experts_grouped(x.astype(jnp.float32), local, w, token,
+                               w_gate, w_up, w_down, counts)
+        return out.astype(x.dtype), counts
+
+
+def moe_shared(x, w_gate, w_up, w_down):
+    """The shared expert: (silu(x W_gate) * (x W_up)) W_down."""
+    with jax.named_scope(MOE_SHARED):
+        return jnp.matmul(_silu(jnp.matmul(x, w_gate)) * jnp.matmul(x, w_up),
+                          w_down)
+
+
+@register_op("moe_route")
+def _moe_route_op(ctx):
+    """Inputs X (B, T, D), W (D, E). Attrs top_k, scale, score ->
+    Idx (B, T, k) int32, Weights (B, T, k) float32."""
+    idx, w = moe_route(ctx.input("X"), ctx.input("W"),
+                       int(ctx.attr("top_k")),
+                       float(ctx.attr("scale", 1.0)),
+                       str(ctx.attr("score", "sigmoid")))
+    return {"Idx": idx, "Weights": w}
+
+
+@register_op("moe_experts")
+def _moe_experts_op(ctx):
+    """Inputs X (B, T, D), Idx, Weights (B, T, k), WGate, WUp (Eh, D,
+    F), WDown (Eh, F, D), optional Lengths (B,). Attrs expert_lo (the
+    first expert held), decode (Lengths are tokens held BEFORE this
+    step's one: a slot of length 0 is free; otherwise rows at or past a
+    row's length are padding) -> Out (B, T, D), Load (Eh,) int32."""
+    x = ctx.input("X")
+    b, t, d = x.shape
+    lengths = ctx.input("Lengths")
+    valid = None
+    if lengths is not None:
+        lens = lengths.reshape(-1).astype(jnp.int32)
+        if bool(ctx.attr("decode", False)):
+            valid = jnp.repeat(lens > 0, t)
+        else:
+            valid = (jnp.arange(t, dtype=jnp.int32)[None, :]
+                     < lens[:, None]).reshape(-1)
+    k = ctx.input("Idx").shape[-1]
+    out, load = moe_experts(
+        x.reshape(b * t, d), ctx.input("Idx").reshape(b * t, k),
+        ctx.input("Weights").reshape(b * t, k), ctx.input("WGate"),
+        ctx.input("WUp"), ctx.input("WDown"),
+        lo=int(ctx.attr("expert_lo", 0)), valid=valid)
+    return {"Out": out.reshape(b, t, d), "Load": load}
+
+
+@register_op("moe_shared")
+def _moe_shared_op(ctx):
+    """Inputs X (B, T, D), WGate, WUp (D, F), WDown (F, D) -> Out."""
+    return {"Out": moe_shared(ctx.input("X"), ctx.input("WGate"),
+                              ctx.input("WUp"), ctx.input("WDown"))}

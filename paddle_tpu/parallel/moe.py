@@ -12,6 +12,14 @@ in Switch Transformer; with k=2 the second choice picks up the slack.
 
 Everything is differentiable: grads flow through combine/dispatch and the
 all_to_alls transpose to themselves.
+
+This is the capacity-factor TRAINING layer. A token past an expert's
+capacity is dropped, so its output cannot equal a reference forward
+pass: a cached decode graph does not use it. The SERVING op that drops
+nothing is ``ops/moe.py`` (``layers.moe_route`` / ``moe_experts`` /
+``moe_shared``: every chosen pair computed, the experts held here, a
+shared expert), which the described-block builders (``models/jamba.py``)
+put into the prefill and decode programs.
 """
 from __future__ import annotations
 

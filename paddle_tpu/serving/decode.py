@@ -135,7 +135,25 @@ class DecodeConfig:
     ``ffn`` ("relu" | "gated_silu"), ``positions`` (a learned position
     table is added to the token embedding) and ``biases`` say the rest.
     ``d_inner`` is the feed-forward width; a Mamba layer's inner width
-    is ``mamba_expand * d_model``."""
+    is ``mamba_expand * d_model``.
+
+    ``MORE_FIELDS`` describe what OPT's and Jamba's blocks lack, each
+    written to a manifest only where it is set: ``head_dim`` (a head
+    width of its own: ``d_head`` is ``d_model // n_head`` only where
+    none is given), ``n_head_by_layer`` (query heads layer by layer),
+    ``attn_types`` ("full" | "sliding" for each attention layer: a
+    sliding layer's queries see the last ``window`` keys and its cache
+    is a ring of ``window`` rows), ``ffn_types`` ("dense" | "experts"
+    layer by layer: an expert layer routes over ``n_expert`` experts of
+    width ``d_expert``, ``expert_top_k`` a token, scores by
+    ``router_score``, renormalised, times ``router_scale``, computes
+    those of ``experts_held`` = [lo, hi) and adds a shared expert of
+    width ``d_shared_expert``), ``attn_gate`` ("per_head": a sigmoid
+    gate a query head on the attention output, from the layer's
+    normalised input) and ``rope`` ({"full" | "sliding": {rotary_dim,
+    theta, attention_factor, yarn}}: rotary positions by layer kind).
+    The output head is its own matrix where ``tie_embeddings`` is
+    false."""
 
     FIELDS = ("vocab_size", "n_layer", "n_head", "d_model", "d_inner",
               "max_len", "tie_embeddings", "prefix", "eos_id")
@@ -146,6 +164,14 @@ class DecodeConfig:
                     ("mamba_expand", 2), ("norm", "layer_norm"),
                     ("norm_eps", 1e-5), ("ffn", "relu"),
                     ("positions", True), ("biases", True))
+    # (field, value where the block has none): written only where set
+    MORE_FIELDS = (("head_dim", None), ("n_head_by_layer", None),
+                   ("attn_types", None), ("window", None),
+                   ("ffn_types", None), ("n_expert", 0),
+                   ("expert_top_k", 0), ("d_expert", 0),
+                   ("d_shared_expert", 0), ("experts_held", None),
+                   ("router_score", "sigmoid"), ("router_scale", 1.0),
+                   ("attn_gate", None), ("rope", None))
 
     def __init__(self, vocab_size, n_layer=4, n_head=8, d_model=512,
                  d_inner=2048, max_len=2048, tie_embeddings=False,
@@ -159,7 +185,7 @@ class DecodeConfig:
         self.tie_embeddings = bool(tie_embeddings)
         self.prefix = str(prefix)
         self.eos_id = None if eos_id is None else int(eos_id)
-        for f, default in self.BLOCK_FIELDS:
+        for f, default in self.BLOCK_FIELDS + self.MORE_FIELDS:
             setattr(self, f, block.pop(f, default))
         if block:
             raise TypeError("DecodeConfig got unknown fields %s"
@@ -172,24 +198,65 @@ class DecodeConfig:
             raise ValueError(
                 "attn_layer_offset %r is not a layer of a period of %r"
                 % (self.attn_layer_offset, self.attn_layer_period))
-        if self.n_head % self.n_kv_head:
-            raise ValueError(
-                "%d query heads do not divide over %d key/value heads"
-                % (self.n_head, self.n_kv_head))
+        for f in ("n_head_by_layer", "attn_types", "ffn_types"):
+            per_layer = getattr(self, f)
+            if per_layer is not None:
+                per_layer = list(per_layer)[:self.n_layer]
+                setattr(self, f, per_layer)
+                if len(per_layer) != self.n_layer:
+                    raise ValueError("%s names %d layers of %d"
+                                     % (f, len(per_layer), self.n_layer))
+        for h in set(self.n_head_by_layer or ()) | {self.n_head}:
+            if h % self.n_kv_head:
+                raise ValueError(
+                    "%d query heads do not divide over %d key/value heads"
+                    % (h, self.n_kv_head))
+        if self.experts_held is not None:
+            self.experts_held = [int(e) for e in self.experts_held]
+        if "experts" in (self.ffn_types or ()):
+            lo, hi = self.held
+            if not (0 <= lo < hi <= self.n_expert
+                    and 0 < self.expert_top_k <= self.n_expert
+                    and self.d_expert > 0):
+                raise ValueError(
+                    "an expert layer needs n_expert, expert_top_k <= "
+                    "n_expert, d_expert and experts_held within them; got "
+                    "%r, %r, %r, %r" % (self.n_expert, self.expert_top_k,
+                                        self.d_expert, self.experts_held))
+        if "sliding" in (self.attn_types or ()) and not self.window:
+            raise ValueError("a sliding attention layer needs a window")
 
     @property
     def d_head(self) -> int:
-        return self.d_model // self.n_head
+        return int(self.head_dim or self.d_model // self.n_head)
+
+    def heads(self, i: int) -> int:
+        """Query heads of layer ``i``."""
+        return int(self.n_head_by_layer[i] if self.n_head_by_layer
+                   else self.n_head)
+
+    @property
+    def held(self):
+        """[lo, hi) of the routed experts this program computes."""
+        return tuple(self.experts_held or (0, self.n_expert))
+
+    def ffn_kinds(self) -> List[str]:
+        """"dense" | "experts" for each layer."""
+        return list(self.ffn_types or ["dense"] * self.n_layer)
 
     @property
     def mamba_d_inner(self) -> int:
         return int(self.mamba_expand) * self.d_model
 
     def layer_kinds(self) -> List[str]:
-        """"attention" | "mamba" for each layer."""
-        return ["attention" if i % self.attn_layer_period
-                == self.attn_layer_offset else "mamba"
-                for i in range(self.n_layer)]
+        """"attention" | "sliding" | "mamba" for each layer."""
+        kinds = ["attention" if i % self.attn_layer_period
+                 == self.attn_layer_offset else "mamba"
+                 for i in range(self.n_layer)]
+        if self.attn_types:
+            kinds = ["sliding" if k == "attention" and t == "sliding"
+                     else k for k, t in zip(kinds, self.attn_types)]
+        return kinds
 
     @property
     def has_state(self) -> bool:
@@ -198,10 +265,24 @@ class DecodeConfig:
         return "mamba" in self.layer_kinds()
 
     @property
+    def has_ring(self) -> bool:
+        """Some layer keeps a ring of ``window`` rows: positions that
+        left the window are overwritten (no rows to roll back to)."""
+        return "sliding" in self.layer_kinds()
+
+    @property
+    def extra_fetches(self) -> List[str]:
+        """Names of what a prefill or a decode step returns AFTER its
+        cache entries: ``moe_load`` (sparse layers, experts held) int32
+        where a layer routes over experts."""
+        return ["moe_load"] if "experts" in self.ffn_kinds() else []
+
+    @property
     def is_opt_block(self) -> bool:
         """Every block field at OPT's value: the graphs are
         ``models.transformer.transformer_lm_*``'s."""
-        return (all(getattr(self, f) == d for f, d in self.BLOCK_FIELDS
+        return (all(getattr(self, f) == d
+                    for f, d in self.BLOCK_FIELDS + self.MORE_FIELDS
                     if f not in ("n_kv_head", "mamba_dt_rank"))
                 and self.n_kv_head == self.n_head)
 
@@ -209,21 +290,40 @@ class DecodeConfig:
         d = {f: getattr(self, f) for f in self.FIELDS}
         if not self.is_opt_block:
             d.update({f: getattr(self, f) for f, _ in self.BLOCK_FIELDS})
+            d.update({f: getattr(self, f) for f, dflt in self.MORE_FIELDS
+                      if getattr(self, f) != dflt})
         return d
 
     @classmethod
     def from_dict(cls, d: Dict) -> "DecodeConfig":
-        known = cls.FIELDS + tuple(f for f, _ in cls.BLOCK_FIELDS)
+        known = cls.FIELDS + tuple(
+            f for f, _ in cls.BLOCK_FIELDS + cls.MORE_FIELDS)
         return cls(**{f: d[f] for f in known if f in d})
 
 
 class CacheEntry(collections.namedtuple(
         "CacheEntry", "name shape dtype per_position")):
     """One array of a model's decode cache: its feed name, its shape
-    at (slots, seq), its dtype, and whether it holds a row per position
-    (a K/V slab, or an int8 slab's scales: axis 1 is the sequence, an
-    admission writes ``[:sp]`` and a length masks the rest) or a
-    fixed-size state (replaced whole)."""
+    at (slots, seq), its dtype, whether an admission writes it by rows
+    (``per_position``), and, read from those, its ``kind``:
+
+    - ``"rows"``: a row per position (a K/V slab, or an int8 slab's
+      scales): axis 1 is the sequence, an admission writes ``[:sp]``
+      and a length masks the rest (``per_position`` true);
+    - ``"state"``: a fixed-size recurrent state, replaced whole;
+    - ``"ring"``: the last ``window`` rows of a sliding-window layer at
+      ``position mod window``: a prefill hands the ring over as it is
+      stored, so an admission replaces it whole, as a state
+      (``per_position`` false); a decode step writes one row of it."""
+
+    __slots__ = ()
+
+    @property
+    def kind(self) -> str:
+        if self.per_position:
+            return "rows"
+        # ``cache_names`` calls a sliding layer's entries kring_i, vring_i
+        return "ring" if self.name[1:].startswith("ring") else "state"
 
     @property
     def nbytes(self) -> int:
@@ -243,9 +343,11 @@ def cache_spec(config: DecodeConfig, slots: int, seq: int,
     d_head) layer by layer (with ``kscale_i``, ``vscale_i`` (slots,
     seq) after them when int8), the order its decode graph has always
     fetched them in. Any other block: an attention layer's two slabs
-    (slots, seq, n_kv_head, d_head), a Mamba layer's ``conv_i`` (slots,
-    K - 1, d_inner) window and ``ssm_i`` (slots, d_inner, N) state,
-    SORTED BY NAME: the order a dict of feeds flattens in, so that a
+    (slots, seq, n_kv_head, d_head), a sliding-window layer's two
+    rings ``kring_i``, ``vring_i`` (slots, window, n_kv_head, d_head)
+    whatever ``seq``, a Mamba layer's ``conv_i`` (slots, K - 1,
+    d_inner) window and ``ssm_i`` (slots, d_inner, N) state, SORTED BY
+    NAME: the order a dict of feeds flattens in, so that a
     donated feed pairs with its own updated output and a step compiles
     with no pairing copy (PERF.md 7a is what happens otherwise)."""
     if kv_dtype not in _KV_ITEMSIZE:
@@ -253,8 +355,12 @@ def cache_spec(config: DecodeConfig, slots: int, seq: int,
                          % (sorted(_KV_ITEMSIZE), kv_dtype))
     if kv_dtype != "float32" and not config.is_opt_block:
         raise ValueError(
-            "%s slabs are built for OPT's block only; this model's "
-            "caches are float32" % kv_dtype)
+            "%s slabs are built for OPT's block only (rows per position "
+            "of one head count, quantized a row); this model's caches "
+            "(%s) are float32"
+            % (kv_dtype, ", ".join(sorted(set(
+                {"attention": "rows", "sliding": "ring",
+                 "mamba": "state"}[k] for k in config.layer_kinds())))))
     from ..models.jamba import cache_names
 
     slab = (slots, seq, config.n_kv_head, config.d_head)
@@ -268,6 +374,11 @@ def cache_spec(config: DecodeConfig, slots: int, seq: int,
             out.append(CacheEntry(
                 names[1], (slots, config.mamba_d_inner,
                            config.mamba_d_state), "float32", False))
+            continue
+        if kind == "sliding":
+            ring = (slots, int(config.window), config.n_kv_head,
+                    config.d_head)
+            out += [CacheEntry(n, ring, "float32", False) for n in names]
             continue
         out += [CacheEntry(n, slab, kv_dtype, True) for n in names]
         if kv_dtype == "int8":
@@ -324,7 +435,9 @@ def _pairing_order(feed_names, fetch_names, spec_names):
     """(traced, take, n): the order a step's outputs are TRACED in, the
     index into them of each of ``fetch_names`` (None where the two
     orders are one), and how many cache entries the step is fed: its
-    last ``n`` outputs are their updates.
+    last ``n`` outputs are their updates (what a model returns AFTER
+    its cache entries, ``DecodeConfig.extra_fetches``, is not handed in
+    here: ``DecodePredictor._step`` keeps it in its place).
 
     jax pairs a donated input with the FIRST output of its shape and
     dtype, inputs taken in the order the feed dict flattens: sorted by
@@ -353,7 +466,7 @@ def _pairing_order(feed_names, fetch_names, spec_names):
 
 
 _Step = collections.namedtuple(
-    "_Step", "fn program feed_names fetch_names traced take n_cache")
+    "_Step", "fn program feed_names fetch_names traced take n_cache n_tail")
 
 
 # a decode step the serving loop has dispatched and not read: its
@@ -398,7 +511,8 @@ def _aliased_outputs(loaded) -> set:
 
 def _prefill_graph(config: DecodeConfig, tokens, lengths, use_ring=False):
     """The prefill graph ``config`` describes: (last-position logits,
-    the cache entries in ``cache_spec`` order)."""
+    the cache entries in ``cache_spec`` order, then what
+    ``config.extra_fetches`` names)."""
     if config.is_opt_block:
         from ..models import transformer as _T
 
@@ -414,8 +528,11 @@ def _prefill_graph(config: DecodeConfig, tokens, lengths, use_ring=False):
         raise ValueError("ring prefill is built for OPT's block only")
     from ..models import jamba as _J
 
-    logits, caches = _J.hybrid_lm_prefill(tokens, lengths, config)
-    return logits, [caches[n] for n in sorted(caches)]
+    extras = {}
+    logits, caches = _J.hybrid_lm_prefill(tokens, lengths, config,
+                                          extras=extras)
+    return logits, ([caches[n] for n in sorted(caches)]
+                    + [extras[n] for n in config.extra_fetches])
 
 
 def save_decode_model(dirname: str, config: DecodeConfig, executor,
@@ -554,8 +671,16 @@ class DecodePredictor:
             raise ValueError(
                 "%s needs a cache of rows per position (it rolls back by "
                 "length, or copies rows); this model's state-space layers "
-                "keep a recurrent state, which has no snapshot and no "
-                "rollback yet" % what)
+                "keep a recurrent state (cache entries of kind 'state'), "
+                "which has no snapshot and no rollback yet" % what)
+        if self.config.has_ring:
+            raise ValueError(
+                "%s needs a cache of rows per position (it rolls back by "
+                "length, or copies rows); this model's sliding-window "
+                "layers keep a ring of %d rows (cache entries of kind "
+                "'ring'): a position that left the window is overwritten, "
+                "so there are no rows to roll back to or to share"
+                % (what, self.config.window))
         if not self.config.is_opt_block:
             raise ValueError("%s is built for OPT's block only" % what)
 
@@ -717,12 +842,14 @@ class DecodePredictor:
                                       dtype=e.dtype,
                                       append_batch_size=False)
                   for e in spec}
+        extras = {}
         next_ids, logits, new = _J.hybrid_lm_decode(
             tokens, lengths, caches, self.config, strategy=strategy,
             seed=seed, sample_k=self.sample_k, sample_p=self.sample_p,
-            temperature=self.temperature)
+            temperature=self.temperature, extras=extras)
         feeds = ["tokens", "lengths", "seed"] + [e.name for e in spec]
-        fetches = [logits.name] + [new[e.name].name for e in spec]
+        fetches = ([logits.name] + [new[e.name].name for e in spec]
+                   + [extras[n].name for n in self.config.extra_fetches])
         if next_ids is not None:
             fetches = [next_ids.name] + fetches
         return feeds, fetches
@@ -813,7 +940,8 @@ class DecodePredictor:
             # back in a donated feed's buffer (all of them on a chip,
             # or the spec and the graph disagree on a shape or dtype;
             # none on the CPU, where nothing is donated)
-            tail = range(len(traced) - step.n_cache, len(traced))
+            tail = range(len(traced) - step.n_tail - step.n_cache,
+                         len(traced) - step.n_tail)
             aliased = len(_aliased_outputs(loaded).intersection(tail))
             obs.CACHE_ENTRIES_FED.inc(step.n_cache, kind=kind)
             obs.CACHE_ENTRIES_ALIASED.inc(aliased, kind=kind)
@@ -843,9 +971,15 @@ class DecodePredictor:
         program, feed_names, fetch_names = self._build(
             kind, batch, seq, strategy, kv_dtype=kv_dtype,
             window=window, use_ring=use_ring)
+        # what follows the cache entries keeps its place at the end
+        n_tail = len(self.config.extra_fetches)
+        body = len(fetch_names) - n_tail
         traced, take, n_cache = _pairing_order(
-            feed_names, fetch_names,
+            feed_names, fetch_names[:body],
             [e.name for e in self.cache_spec(batch, seq, kv_dtype)])
+        traced = list(traced) + list(fetch_names[body:])
+        if take is not None:
+            take = list(take) + list(range(body, len(fetch_names)))
 
         def step_fn(feeds, state):
             self.traces += 1
@@ -859,7 +993,7 @@ class DecodePredictor:
         # module line then tells a prefill from a decode step
         step_fn.__name__ = step_fn.__qualname__ = name
         return _Step(step_fn, program, feed_names, fetch_names, traced,
-                     take, n_cache)
+                     take, n_cache, n_tail)
 
     # -- host-side sampling (first token, from prefill logits) ------------
     def _sample_host(self, logits, strategy: str, seed: int):
@@ -915,9 +1049,10 @@ class DecodePredictor:
                     self._state)
         obs.DECODE_STEP_MS.observe((time.perf_counter() - t0) * 1e3,
                                    stage="prefill")
-        caches = list(outs[1:])
+        caches = list(outs[1:1 + len(self.cache_spec(bb, slab_seq))])
         if sp < slab_seq:
-            # rows per position pad out to the slab; a state is whole
+            # rows per position pad out to the slab; a state or a ring
+            # is whole
             caches = [
                 jnp.pad(jnp.asarray(c), [(0, 0), (0, slab_seq - sp)]
                         + [(0, 0)] * (len(e.shape) - 2))
@@ -1007,7 +1142,7 @@ class DecodePredictor:
             obs.DECODE_STEP_MS.observe(
                 (time.perf_counter() - t0) * 1e3, stage="step")
             nxt = np.asarray(outs[0]).astype(np.int64)
-            caches = list(outs[2:])
+            caches = list(outs[2:2 + len(names)])
             emitted = 0
             for i in range(b):
                 if finished[i]:
@@ -1391,10 +1526,21 @@ class DecodeServer:
                                           self.kv_dtype)
         self._cache_feed_names = [e.name for e in self._spec]
         self._cache_per_layer = 4 if self.kv_dtype == "int8" else 2
-        # bytes of fixed-size state (not rows per position) a slot keeps
+        # bytes of fixed-size recurrent state a slot keeps (a step reads
+        # and writes all of it; rows and rings are counted by the row)
         self._state_bytes_per_slot = sum(
             e.nbytes for e in self._spec
-            if not e.per_position) // self.slots
+            if e.kind == "state") // self.slots
+        # a sliding-window layer's ring holds this many rows (0: none)
+        self._ring_window = int(cfg.window) if cfg.has_ring else 0
+        # layers that route over experts: a step and a prefill return
+        # the pairs each held expert received (``moe_load``, last)
+        self._moe_layers = [i for i, k in enumerate(cfg.ffn_kinds())
+                            if k == "experts"]
+        lo, hi = cfg.held
+        self.moe_load_total = np.zeros((len(self._moe_layers), hi - lo),
+                                       np.int64)
+        self._moe_last = {"expert_pairs": 0, "experts_active": 0}
         # rows a block of the float32 decode kernel brings in, or None
         # where a step reads whole slabs (int8 slabs, the speculative
         # verify window and a slab of fewer heads than the query are
@@ -1594,20 +1740,41 @@ class DecodeServer:
                               kind="prefill")
         return outs, sp
 
-    # prompts one admission prefills at most, while sequences are live
-    _ADMIT_MOST = 8
+    # a model without sliding-window or expert layers has neither
+    _ring_window = 0
+    _moe_layers = ()
 
-    def _admit_room(self, free: int) -> int:
+    # prompts one admission prefills at most, while sequences are live,
+    # and the bucketed tokens (power-of-two batch x the prompts' bucket)
+    _ADMIT_MOST = 8
+    _ADMIT_TOKENS = 16384
+
+    def _admit_room(self, free: int, pending=None) -> int:
         """How many queued requests the next admission takes. Between
-        two decode steps at most ``_ADMIT_MOST``: an admission stalls
-        every live sequence for its prefill, whose temporaries grow
-        with the prompts it holds (8 prompts of 2048 tokens: 1.9 GB at
-        the widths of a 3 B hybrid model; 64 would not fit a chip), and
-        the executables a server has to have compiled stay the
-        power-of-two batches up to 8. The rest waits one decode step. A
+        two decode steps at most ``_ADMIT_MOST``, and of ``pending``
+        (the queue, oldest first) no more than keep the prefill's
+        bucketed tokens within ``_ADMIT_TOKENS`` (never fewer than
+        one): an admission stalls every live sequence for its prefill,
+        whose temporaries grow with the tokens it holds (8 prompts of
+        2048 tokens: 1.9 GB at the widths of a 3 B hybrid model; 8 of
+        4096 with their repeated K/V and expert-sorted copies would not
+        fit beside a chip's weights and slabs), and the executables a
+        server has to have compiled stay the power-of-two batches up to
+        8 x 2048 and 4 x 4096. The rest waits one decode step. A
         gang-scheduled server (``continuous=False``) fills its slots at
         once, as it always has."""
-        return min(free, self._ADMIT_MOST) if self.continuous else free
+        if not self.continuous:
+            return free
+        n = min(free, self._ADMIT_MOST)
+        if pending is None:
+            return n  # the most an admission takes, whatever is queued
+        lens = [len(p[1]) for p in pending[:n]]
+        n = len(lens)
+        while n > 1 and _pow2_bucket(n) * min(
+                _pow2_bucket(max(lens[:n]), floor=16),
+                self.seq) > self._ADMIT_TOKENS:
+            n -= 1
+        return n
 
     def _admit(self, pending, caches, lens, active):
         """Prefill a sub-batch of queued requests into free slots.
@@ -1619,7 +1786,7 @@ class DecodeServer:
         through the verify window) and identical prompts inside one
         sub-batch dedupe to a single prefill row."""
         free = [i for i in range(self.slots) if active[i] is None]
-        batch = pending[:self._admit_room(len(free))]
+        batch = pending[:self._admit_room(len(free), pending)]
         del pending[:len(batch)]
         if self._prefix is not None:
             return self._admit_prefix(batch, free, caches, lens, active)
@@ -1650,10 +1817,13 @@ class DecodeServer:
                 if seed is not None and self.strategy not in ("greedy",):
                     first[i] = self.predictor._sample_host(
                         outs[0][i:i + 1], self.strategy, seed)[0]
+        if self._moe_layers:
+            # the prefill has ended (the host has its logits): no wait
+            self._note_load(outs[-1])
         with _tracing.phase("decode.loop.scatter",
-                            **self._scatter_counts(n)):
-            caches = self._scatter_prefill(caches, list(outs[1:]),
-                                           free[:n], sp)
+                            **self._scatter_counts(n, [b[1] for b in batch])):
+            caches = self._scatter_prefill(
+                caches, list(outs[1:1 + len(self._spec)]), free[:n], sp)
         for i, (rid, prompt, max_new, seed) in enumerate(batch):
             slot = free[i]
             tok = int(first[i])
@@ -1673,13 +1843,47 @@ class DecodeServer:
                 lens[slot] = 0
         return caches
 
-    def _scatter_counts(self, n: int) -> dict:
+    def _scatter_counts(self, n: int, prompts=()) -> dict:
         """What an admission's ``decode.loop.scatter`` phase carries:
         ``entries``, the arrays it scatters into, and ``state_slots``,
         the slots whose fixed-size state it replaces whole (0 for a
-        model of K/V rows alone)."""
-        return {"entries": len(self._spec),
-                "state_slots": n if self._state_bytes_per_slot else 0}
+        model of K/V rows alone). Of the admission's prefill, known
+        once it has run (a phase's counts are fixed when it opens, so
+        they ride here and not on ``admit``): ``ring_rows``, the rows
+        it leaves in a sliding-window layer's rings (each prompt's last
+        ``min(len, window)``), and ``expert_pairs``, the token-expert
+        pairs it routed to held experts, all sparse layers."""
+        counts = {"entries": len(self._spec),
+                  "state_slots": n if self._state_bytes_per_slot else 0}
+        if self._ring_window:
+            counts["ring_rows"] = sum(
+                min(len(p), self._ring_window) for p in prompts)
+        if self._moe_layers:
+            counts["expert_pairs"] = self._moe_last["expert_pairs"]
+        return counts
+
+    def _note_load(self, load):
+        """Book one program's ``moe_load`` (sparse layers, experts
+        held): the counter and the gauge a layer, the server's running
+        total, the flight recorder's ``moe.load`` record when it
+        samples, and what the next ``dispatch`` / ``scatter`` phase
+        reports."""
+        load = np.asarray(load, np.int64).reshape(
+            self.moe_load_total.shape)
+        self.moe_load_total += load
+        for j, layer in enumerate(self._moe_layers):
+            obs.MOE_EXPERT_PAIRS.inc(int(load[j].sum()), layer=str(layer))
+            tot = self.moe_load_total[j]
+            obs.MOE_LOAD_MAX_OVER_MEAN.set(
+                float(tot.max()) / max(float(tot.mean()), 1e-9),
+                layer=str(layer))
+        self._moe_last = {"expert_pairs": int(load.sum()),
+                          "experts_active": int((load > 0).sum())}
+        if _tracing.sampled():
+            _tracing.record_process_span(
+                "moe.load", pairs=[int(v) for v in load.sum(axis=1)],
+                busiest=[int(v) for v in load.max(axis=1)],
+                **self._moe_last)
 
     def _scatter_prefill(self, caches, sub, slots, sp):
         """The cache rebuild of a plain admission: scatter the prefill's
@@ -1992,14 +2196,28 @@ class DecodeServer:
         in-place kernel. ``attended / streamed`` is how much of what is
         fetched is live. ``state_bytes``: the bytes of fixed-size state
         (a state-space layer's window and recurrent state) the step
-        reads and writes, every slot's, live or not."""
+        reads and writes, every slot's, live or not. Of a model with
+        sliding-window layers, ``ring_rows``: the rows one such layer's
+        attention reads, each live slot's ``min(length + 1, window)``.
+        Of a model with expert layers, ``expert_pairs`` and
+        ``experts_active``: the token-expert pairs routed to held
+        experts and the (layer, expert) that received any, all sparse
+        layers, of the LAST step whose ``moe_load`` the host has read
+        (with a step in flight, the one dispatched two before this:
+        the loads ride back with the ids and are never waited for)."""
         rows = self._stream_rows
         streamed = (self.slots * self.seq if rows is None
                     else int((lens // rows + 1).sum()) * rows)
-        return {"active": n_active,
-                "attended": int(lens.sum()) + n_active,
-                "streamed": streamed,
-                "state_bytes": 2 * self.slots * self._state_bytes_per_slot}
+        counts = {"active": n_active,
+                  "attended": int(lens.sum()) + n_active,
+                  "streamed": streamed,
+                  "state_bytes": 2 * self.slots * self._state_bytes_per_slot}
+        if self._ring_window:
+            counts["ring_rows"] = int(np.minimum(
+                lens[lens > 0] + 1, self._ring_window).sum())
+        if self._moe_layers:
+            counts.update(self._moe_last)
+        return counts
 
     def _spec_round(self, drexe, vexe, caches, lens, active, n_active):
         """One speculative round across every active slot: spec_k draft
@@ -2117,8 +2335,11 @@ class DecodeServer:
             t0 = ph.t0 or time.perf_counter()
             outs = dexe(feeds, self.predictor._state)
             # the ids start for the host as soon as the step ends, not
-            # when the host comes to ask (a step later)
+            # when the host comes to ask (a step later); the experts'
+            # loads ride with them
             outs[0].copy_to_host_async()
+            if self._moe_layers:
+                outs[-1].copy_to_host_async()
         obs.DECODE_STEPS.inc(in_flight=str(in_flight))
         rows = []
         for i, st in enumerate(active):
@@ -2144,6 +2365,8 @@ class DecodeServer:
         readings)."""
         with _tracing.phase("decode.loop.fetch") as ph:
             ids = np.asarray(flight.outs[0])
+            if self._moe_layers:
+                self._note_load(flight.outs[-1])
         now = ph.t1 or time.perf_counter()
         obs.DECODE_STEP_MS.observe(
             (now - max(flight.t0, t_token)) * 1e3, stage="step")
@@ -2246,8 +2469,8 @@ class DecodeServer:
                             and (self.continuous or n_active == 0))
                 if admit_ok:
                     with _tracing.phase("decode.loop.admit",
-                                        admitted=min(self._admit_room(free),
-                                                     len(pending))):
+                                        admitted=self._admit_room(
+                                            free, pending)):
                         caches = self._admit(pending, caches, lens, active)
                     n_active = sum(1 for a in active if a is not None)
                 self._set_slot_gauges(n_active)
@@ -2269,7 +2492,7 @@ class DecodeServer:
                     if n_active:
                         step = self._dispatch(dexe, chain, caches, lens,
                                               active, n_active, flight)
-                        caches = list(step.outs[2:])
+                        caches = list(step.outs[2:2 + len(self._spec)])
                     if flight is not None:
                         ids, t_token = self._fetch(flight, t_token)
                 except Exception as e:

@@ -656,6 +656,12 @@ COVERED_ELSEWHERE = {
     # recurrence, padded vs unpadded, scan-then-step, infer rules)
     "rms_norm", "ssm_scan", "ssm_step", "causal_conv1d",
     "causal_conv1d_step",
+    # rotary positions, routed experts that drop nothing, window
+    # attention and its ring: tests/test_rope_moe_ops.py (against the
+    # plain reference, closed forms, both sides of the window, the four
+    # shares of an expert-parallel deployment, infer rules)
+    "rope", "moe_route", "moe_experts", "moe_shared", "attn_window",
+    "ring_append", "ring_pack", "decode_attn_ring",
     # in-graph sampling: tests/test_sampling_ops.py
     "greedy_sample", "top_k_sample", "top_p_sample",
     # metrics: tests/test_aux.py

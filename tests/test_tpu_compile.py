@@ -433,3 +433,90 @@ def test_hybrid_serving_step_compiles(one_chip, monkeypatch, kind, batch,
                   if op == "copy"]
         assert not copies, (shape, copies)
     assert mem.temp_size_in_bytes < 200 * 2**20, mem.temp_size_in_bytes
+
+
+_LAGUNA_CASES = [
+    # id, kind, batch, seq: the Laguna serving cell's own programs
+    # (benchmark/configs/laguna-xs.2.json: 5 layers at published widths,
+    # 64 of 256 experts held, 64 slots of 4096 positions)
+    ("decode-64x4096", "decode", 64, 4096),
+    ("prefill-4x4096", "prefill", 4, 4096),
+]
+
+
+@pytest.mark.slow  # two all-core compiles of a minute; `pytest <this file>`
+@pytest.mark.parametrize("kind,batch,seq", [c[1:] for c in _LAGUNA_CASES],
+                         ids=[c[0] for c in _LAGUNA_CASES])
+def test_laguna_serving_step_compiles(one_chip, monkeypatch, kind, batch,
+                                      seq):
+    """The programs DecodePredictor builds for the Laguna cell (full and
+    sliding layers of 48 / 64 query heads on 8 K/V heads of 128, rotary
+    positions, a dense layer and four of 64 held experts of 256 with a
+    shared one, an untied head over 100,352 ids): they compile for a v5e
+    and fit it. The decode step donates every slab and ring and gets
+    each back in place, with no whole-slab copy; the largest admission
+    (4 prompts of 4096: the token bound) holds one attention kernel a
+    layer (three `ptpu.attn_window`, two flash forwards), in every
+    sparse layer the grouped product as the TPU compiler's own ragged
+    dots (three, and their metadata) inside the loop over blocks of
+    sorted pairs, and temporaries that leave room for the weights and
+    64 slots beside it."""
+    import json
+
+    from paddle_tpu.serving.decode import DecodePredictor
+
+    sys_path = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, sys_path)
+    if os.path.join(sys_path, "benchmark") not in list(getattr(
+            sys.modules.get("benchmark"), "__path__", [])):
+        import types
+
+        sys.modules["benchmark"] = types.ModuleType("benchmark")
+        sys.modules["benchmark"].__path__ = [
+            os.path.join(sys_path, "benchmark")]
+    from benchmark.models import laguna_lm
+
+    with open(os.path.join(sys_path, "benchmark", "configs",
+                           "laguna-xs.2.json")) as f:
+        cfg = json.load(f)
+    monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
+    pred = DecodePredictor.__new__(DecodePredictor)  # graph builder only
+    pred.config = laguna_lm.decode_config(cfg, "serve")
+    pred.sample_k, pred.sample_p, pred.temperature = 40, 0.9, 1.0
+    step_fn, feeds, state, n_cache = _serving_step(pred, kind, batch, seq,
+                                                   one_chip)
+    compiled = jax.jit(step_fn, donate_argnums=(0,)).lower(
+        feeds, state).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < HBM_BYTES, "does not fit one chip: %r" % (mem,)
+    weights = sum(int(np.prod(s.shape)) * 4 for s in state.values())
+    assert 5.7e9 < weights < 5.9e9, weights  # 1.454 B parameters
+    text = compiled.as_text()
+    if kind == "prefill":
+        calls = re.findall(r"%([\w.-]+?)(?:\.\d+)? = [^\n]*"
+                           r'custom_call_target="tpu_custom_call"', text)
+        assert sorted(set(calls)) == [
+            "ptpu.attn_window", "ptpu.flash_fwd", "ragged-dot-metadata",
+            "ragged-dot-none"], sorted(set(calls))
+        assert calls.count("ptpu.attn_window") == 3
+        assert calls.count("ptpu.flash_fwd") == 2
+        assert calls.count("ragged-dot-none") == 3 * 4
+        assert text.count(" while(") >= 4           # a loop a sparse layer
+        slabs = sum(e.nbytes for e in pred.cache_spec(64, 4096))
+        assert weights + slabs + mem.temp_size_in_bytes + (
+            mem.output_size_in_bytes) < 15.5 * 2**30, mem
+        return
+    # 8 K/V heads: attention runs the lax paths, no kernel of the repo's;
+    # the only Mosaic calls are the compiler's own ragged dots
+    assert "%ptpu." not in text
+    assert text.count("ragged-dot-none") >= 3 * 4
+    spec = pred.cache_spec(batch, seq)
+    assert n_cache == len(spec) == 10
+    assert mem.alias_size_in_bytes >= sum(e.nbytes for e in spec)
+    for shape in {e.shape for e in spec}:
+        copies = [name for op, name, _ in _whole_slab_ops(text, shape)
+                  if op == "copy"]
+        assert not copies, (shape, copies)
+    assert mem.temp_size_in_bytes < 1.5 * 2**30, mem.temp_size_in_bytes
