@@ -49,10 +49,11 @@ FULL = dict(vocab=32768, n_layer=12, n_head=8, d_model=1024, d_inner=4096,
 # A small Laguna-family block (full and sliding-window layers mixed,
 # rotary positions, a per-head gate, routed experts with a shared one,
 # an untied head) at shapes that enter the kernels the serving cell
-# enters: heads of 128, the published window of 512, a prompt that
-# wraps the ring.
-LAGUNA = dict(vocab=4096, d_model=512, head_dim=128, n_kv_head=2,
-              heads=[4, 6, 6, 6, 4], d_inner=1024, window=512,
+# enters: heads of 128, 2 or 3 query heads on each of 8 key/value heads
+# (slabs with the decode kernel's free view), the published window of
+# 512, a prompt that wraps the ring.
+LAGUNA = dict(vocab=4096, d_model=512, head_dim=128, n_kv_head=8,
+              heads=[16, 24, 24, 24, 16], d_inner=1024, window=512,
               n_expert=16, top_k=4, d_expert=256, held=[4, 12], seq=2048,
               slots=8, prompt=700, new_tokens=8, require_tpu=True)
 
@@ -490,14 +491,20 @@ def phase_laguna(cfg, place):
         agree += 1
     pairs = int(srv.moe_load_total.sum())
     assert pairs > 0, "no pair was booked on a held expert"
-    text = pred.acquire("prefill", 1, 1024)[0].as_text() if cfg[
-        "require_tpu"] else ""
     if cfg["require_tpu"]:
+        text = pred.acquire("prefill", 1, 1024)[0].as_text()
         kernels = re.findall(r"%(ptpu\.[a-z_]+)[.\d]* = ", text)
         assert (kernels.count("ptpu.attn_window") == 3
                 and kernels.count("ptpu.flash_fwd") == 2), (
             "the prefill does not run one attention kernel a layer: %r"
             % kernels)
+        text = pred.acquire("decode", cfg["slots"], cfg["seq"])[0].as_text()
+        kernels = re.findall(r"%(ptpu\.[a-z_]+)[.\d]* = ", text)
+        assert (kernels.count("ptpu.decode_attn_grouped") == 2
+                and srv._stream_rows), (
+            "the decode step does not attend its slabs through the "
+            "in-place kernel: %r, stream rows %r"
+            % (kernels, srv._stream_rows))
     _emit("laguna", prompt_len=len(prompt), new_tokens=new,
           server_s=serve_s, rollout_tokens_agreeing=agree,
           expert_pairs=pairs,

@@ -1542,15 +1542,17 @@ class DecodeServer:
                                        np.int64)
         self._moe_last = {"expert_pairs": 0, "experts_active": 0}
         # rows a block of the float32 decode kernel brings in, or None
-        # where a step reads whole slabs (int8 slabs, the speculative
-        # verify window and a slab of fewer heads than the query are
-        # lax paths of their own)
+        # where a step reads whole slabs (int8 slabs and the speculative
+        # verify window are lax paths of their own; so is a slab of
+        # fewer heads than the query that the kernel has no view of)
         self._stream_rows = None
-        if (self.kv_dtype == "float32" and not self.speculative
-                and cfg.n_kv_head == cfg.n_head):
+        if self.kv_dtype == "float32" and not self.speculative:
+            full = [cfg.heads(i) for i, k in enumerate(cfg.layer_kinds())
+                    if k == "attention"]
             with jax.default_device(predictor._device):  # as acquire()
                 self._stream_rows = _KV.decode_stream_rows(
-                    self.seq, cfg.n_head, cfg.d_head, jnp.float32)
+                    self.seq, cfg.n_kv_head, cfg.d_head, jnp.float32,
+                    q_heads=max(full, default=cfg.n_head))
 
     # -- submission (PredictorServer-compatible surface) -------------------
     def submit(self, sample: Sequence[np.ndarray]):

@@ -122,17 +122,22 @@ _IP_LENGTHS = {
 
 @pytest.mark.parametrize("lengths", list(_IP_LENGTHS.values()),
                          ids=list(_IP_LENGTHS))
-@pytest.mark.parametrize("heads", [8, 32])
-def test_pallas_decode_kernel_in_place_parity(heads, lengths):
-    """The kernel that reads the (B, S, H, D) slab where it lies (grid
+@pytest.mark.parametrize("heads,kv_heads", [
+    (8, 8), (32, 32),
+    # grouped queries: 6, 8 and 2 query rows share a key/value head
+    (48, 8), (64, 8), (16, 8)],
+    ids=["8", "32", "48on8", "64on8", "16on8"])
+def test_pallas_decode_kernel_in_place_parity(heads, kv_heads, lengths):
+    """The kernel that reads the (B, S, Hkv, D) slab where it lies (grid
     over sequence blocks, every head of a block in one copy, dead blocks
-    skipped), interpret mode against the lax reference."""
+    skipped, the query rows of a group against their head's block in one
+    product), interpret mode against the lax reference."""
     b = len(lengths)
-    assert kc.decode_block_rows(_IP_S, heads, _IP_D, np.float32,
+    assert kc.decode_block_rows(_IP_S, kv_heads, _IP_D, np.float32,
                                 _IP_BLOCK) == _IP_BLOCK
     q = jnp.asarray(_rand((b, 1, heads, _IP_D), 0))
-    k = jnp.asarray(_rand((b, _IP_S, heads, _IP_D), 1))
-    v = jnp.asarray(_rand((b, _IP_S, heads, _IP_D), 2))
+    k = jnp.asarray(_rand((b, _IP_S, kv_heads, _IP_D), 1))
+    v = jnp.asarray(_rand((b, _IP_S, kv_heads, _IP_D), 2))
     lens = jnp.asarray(lengths, jnp.int32)
     got = np.asarray(kc.pallas_decode_attention(
         q, k, v, lens, interpret=True, block_s=_IP_BLOCK))
@@ -161,6 +166,91 @@ def test_decode_block_rows_follows_shape_and_dtype(shape, dtype, block_s,
                                                    want):
     s, h, d = shape
     assert kc.decode_block_rows(s, h, d, dtype, block_s) == want
+
+
+# what the dispatch sees decides: (query heads, slab (s, hkv, d), dtype)
+# -> the kernel, or the exact lax path
+_DISPATCH = {
+    "opt-32on32": (32, (2048, 32, 128), "float32", True),
+    "laguna-48on8": (48, (4096, 8, 128), "float32", True),
+    "laguna-64on8": (64, (4096, 8, 128), "float32", True),
+    "32on16": (32, (1024, 16, 128), "float32", True),
+    # ONE key/value head (the hybrid cell's slab): no free view
+    "jamba-20on1": (20, (2048, 1, 128), "float32", False),
+    "12on4": (12, (1024, 4, 128), "float32", False),
+    # packed rows: Mosaic has no strided load of them
+    "48on8-bf16": (48, (4096, 8, 128), "bfloat16", False),
+    # a slab the kernels' blocks do not divide
+    "48on8-s100": (48, (100, 8, 128), "float32", False),
+    "48on8-d64": (48, (4096, 8, 64), "float32", False),
+    # a slot's scores (heads x S float32) would not fit beside the blocks
+    "64on8-s32768": (64, (32768, 8, 128), "float32", False),
+}
+
+
+@pytest.mark.parametrize("heads,slab,dtype,kernel",
+                         list(_DISPATCH.values()), ids=list(_DISPATCH))
+def test_decode_attention_dispatch_follows_shape_and_dtype(
+        monkeypatch, heads, slab, dtype, kernel):
+    """On a TPU the slab's shape and dtype alone choose between the
+    in-place kernel and the lax path: no knob, no model name. A ring
+    stays on the lax path whatever its shape."""
+    import types
+
+    import jax
+
+    monkeypatch.setattr(kc, "current_device",
+                        lambda: types.SimpleNamespace(platform="tpu"))
+    s, hkv, d = slab
+    sds = jax.ShapeDtypeStruct
+    avals = (sds((4, 1, heads, d), dtype), sds((4, s, hkv, d), dtype),
+             sds((4, s, hkv, d), dtype), sds((4,), "int32"))
+    text = str(jax.make_jaxpr(kc.decode_attention)(*avals))
+    assert ("pallas_call" in text) == kernel
+    if kernel:
+        # the grouped call is handed the slab itself, the standing one
+        # its (B, S*H, D) view
+        assert ("f32[4,%d,%d]" % (s * hkv, d) in text) == (heads == hkv)
+    rows = kc.decode_stream_rows(s, hkv, d, dtype, q_heads=heads)
+    assert (rows is not None) == kernel
+    assert "pallas_call" not in str(jax.make_jaxpr(kc.decode_attn_ring)(
+        *avals))
+
+
+_RING_W = 32
+_RING_LENGTHS = {
+    "below": [0, 1, _RING_W - 1, 5],
+    "at": [_RING_W] * 4,
+    "past": [_RING_W + 1, 2 * _RING_W, 1000, _RING_W + 7],
+    "mixed": [7, _RING_W, _RING_W + 1, 0],
+}
+
+
+@pytest.mark.parametrize("lengths", list(_RING_LENGTHS.values()),
+                         ids=list(_RING_LENGTHS))
+@pytest.mark.parametrize("heads", [16, 64])
+def test_grouped_kernel_over_a_ring_matches_decode_attn_ring(heads,
+                                                             lengths):
+    """A ring is a one-block slab of ``min(lengths, W)`` live rows: the
+    grouped kernel in interpret mode against ``decode_attn_ring`` (the
+    lax path, which a ring keeps on the chip too: PERF.md, PR 32)."""
+    b = len(lengths)
+    q = jnp.asarray(_rand((b, 1, heads, _IP_D), 3))
+    kr = jnp.asarray(_rand((b, _RING_W, 8, _IP_D), 4))
+    vr = jnp.asarray(_rand((b, _RING_W, 8, _IP_D), 5))
+    lens = jnp.asarray(lengths, jnp.int32)
+    want = np.asarray(kc.decode_attn_ring(q, kr, vr, lens))
+    got = np.asarray(kc.pallas_decode_attention(
+        q, kr, vr, jnp.minimum(lens, _RING_W), interpret=True))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=2e-6)
+
+
+def test_grouped_slab_without_the_free_view_is_refused_by_the_kernel():
+    q = jnp.zeros((2, 1, 20, 128))
+    kv = jnp.zeros((2, 128, 1, 128))
+    with pytest.raises(ValueError, match="no in-place kernel"):
+        kc.pallas_decode_attention(q, kv, kv, jnp.ones((2,), jnp.int32),
+                                   interpret=True)
 
 
 def test_cache_append():

@@ -289,6 +289,27 @@ def test_server_books_loads_and_ring_rows(pred):
     assert sc["ring_rows"] == 8 + 3 and "expert_pairs" in sc
 
 
+def test_server_asks_stream_rows_with_the_slabs_own_heads(pred, monkeypatch):
+    """`streamed` of a grouped configuration follows the kernel's block
+    over the slab's key/value heads under the full layers' query
+    heads."""
+    from paddle_tpu.serving import decode as D
+
+    asked = []
+
+    def rows(s, h, d, dtype, block_s=512, q_heads=None):
+        asked.append((s, h, d, q_heads))
+        return 16
+
+    monkeypatch.setattr(D._KV, "decode_stream_rows", rows)
+    srv = DecodeServer(pred, slots=SLOTS, max_seq=SEQ, max_new_tokens=4)
+    # the two full layers' 4 query heads, not the sliding layers' 6
+    assert asked == [(SEQ, CFG["num_key_value_heads"], CFG["head_dim"], 4)]
+    counts = srv._step_counts(np.array([3, 0, 30, 0], np.int32), 2)
+    assert counts["streamed"] == 16 * (1 + 1 + 2 + 1) < SLOTS * SEQ
+    assert counts["attended"] == 35
+
+
 # -- an admission is bounded by tokens as well as by prompts ------------------
 
 @_cases("lens,free,want", [

@@ -133,6 +133,36 @@ def test_decode_attention_kernel_compiles(one_chip, dtype):
     _compile(KV.pallas_decode_attention, q, kv, kv, lens)
 
 
+_GROUPED_CASES = [
+    # id, query heads, slab or ring rows: the Laguna serving cell's own
+    # shapes (64 slots, 8 K/V heads of 128, float32)
+    ("full-48on8", 48, 4096),
+    ("full-64on8", 64, 4096),
+    ("ring-64on8", 64, 512),
+]
+
+
+@pytest.mark.parametrize("heads,rows", [c[1:] for c in _GROUPED_CASES],
+                         ids=[c[0] for c in _GROUPED_CASES])
+def test_grouped_decode_attention_kernel_compiles(one_chip, heads, rows):
+    """g query heads on a slab of 8 key/value heads: the in-place kernel
+    is handed the slab itself (its text keeps the slab's shape) and no
+    copy, reshape or transpose of it is made around the call."""
+    sds = jax.ShapeDtypeStruct
+    slab = (64, rows, 8, 128)
+    q = sds((64, 1, heads, 128), jnp.float32, sharding=one_chip)
+    kv = sds(slab, jnp.float32, sharding=one_chip)
+    lens = sds((64,), jnp.int32, sharding=one_chip)
+    text = _compile(KV.pallas_decode_attention, q, kv, kv, lens)
+    line, = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert "%ptpu.decode_attn_grouped" in line.split(" = ")[0], line
+    assert line.count("f32[64,%d,8,128]" % rows) >= 2, line
+    moved = [(op, n) for op, n, _ in _whole_slab_ops(text, slab)
+             if op in ("copy", "reshape", "transpose")]
+    assert not moved, moved
+
+
 def test_lm_head_loss_gradient_compiles(one_chip):
     """(16384 x 1024) . (1024 x 32768): the chunked fused head; it holds
     no Pallas kernel, so only fit and compile are asserted."""
@@ -454,7 +484,8 @@ def test_laguna_serving_step_compiles(one_chip, monkeypatch, kind, batch,
     positions, a dense layer and four of 64 held experts of 256 with a
     shared one, an untied head over 100,352 ids): they compile for a v5e
     and fit it. The decode step donates every slab and ring and gets
-    each back in place, with no whole-slab copy; the largest admission
+    each back in place, with no whole-slab copy, and attends each slab
+    through the in-place kernel (grouped queries); the largest admission
     (4 prompts of 4096: the token bound) holds one attention kernel a
     layer (three `ptpu.attn_window`, two flash forwards), in every
     sparse layer the grouped product as the TPU compiler's own ragged
@@ -480,6 +511,9 @@ def test_laguna_serving_step_compiles(one_chip, monkeypatch, kind, batch,
                            "laguna-xs.2.json")) as f:
         cfg = json.load(f)
     monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
+    monkeypatch.setattr(
+        KV, "_use_pallas_decode",
+        lambda s, d: d % 128 == 0 and s % 128 == 0 and s >= 128)
     pred = DecodePredictor.__new__(DecodePredictor)  # graph builder only
     pred.config = laguna_lm.decode_config(cfg, "serve")
     pred.sample_k, pred.sample_p, pred.temperature = 40, 0.9, 1.0
@@ -508,9 +542,13 @@ def test_laguna_serving_step_compiles(one_chip, monkeypatch, kind, batch,
         assert weights + slabs + mem.temp_size_in_bytes + (
             mem.output_size_in_bytes) < 15.5 * 2**30, mem
         return
-    # 8 K/V heads: attention runs the lax paths, no kernel of the repo's;
-    # the only Mosaic calls are the compiler's own ragged dots
-    assert "%ptpu." not in text
+    # 8 K/V heads of float32: a full layer's slabs have the free view,
+    # so its attention is one call of the in-place kernel on the slabs
+    # themselves (the rings keep the lax path); the other Mosaic calls
+    # are the compiler's own ragged dots
+    calls = re.findall(r"%(ptpu\.[\w.]+?)(?:\.\d+)? = [^\n]*"
+                       r'custom_call_target="tpu_custom_call"', text)
+    assert calls == ["ptpu.decode_attn_grouped"] * 2, calls
     assert text.count("ragged-dot-none") >= 3 * 4
     spec = pred.cache_spec(batch, seq)
     assert n_cache == len(spec) == 10

@@ -541,10 +541,26 @@ def test_decode_server_gives_one_record_per_loop_iteration(decode_dir):
     (64, [63, 64, 127, 255], 4, {"attended": 513,
                                  "streamed": 64 * (1 + 2 + 2 + 4)}),
     (128, [0, 0, 0, 0], 0, {"attended": 0, "streamed": 4 * 128}),
-], ids=["whole-slab", "blocks", "boundaries", "idle"])
-def test_step_counts_streamed_rows(rows, lens, n_active, want):
+    # a slab of 8 key/value heads under more query heads (256 x 8 x 128
+    # float32 on a TPU: one 256-row block): the rounded-up sum, not
+    # slots x seq; ONE key/value head reads whole slabs
+    ({"kv_heads": 8}, [5, 0, 255, 256], 3,
+     {"attended": 519, "streamed": 256 * (1 + 1 + 1 + 2)}),
+    ({"kv_heads": 1}, [5, 0, 255, 256], 3,
+     {"attended": 519, "streamed": 4 * 256}),
+], ids=["whole-slab", "blocks", "boundaries", "idle", "grouped",
+        "grouped-one-head"])
+def test_step_counts_streamed_rows(monkeypatch, rows, lens, n_active, want):
+    import types
+
+    from paddle_tpu.ops import kv_cache as KV
     from paddle_tpu.serving.decode import DecodeServer
 
+    if isinstance(rows, dict):  # what the server asks of a grouped slab
+        monkeypatch.setattr(KV, "current_device",
+                            lambda: types.SimpleNamespace(platform="tpu"))
+        rows = KV.decode_stream_rows(256, rows["kv_heads"], 128, "float32",
+                                     q_heads=48)
     srv = DecodeServer.__new__(DecodeServer)
     srv.slots, srv.seq, srv._stream_rows = 4, 256, rows
     srv._state_bytes_per_slot = 0  # K/V rows alone: no fixed-size state
