@@ -57,6 +57,15 @@ LAGUNA = dict(vocab=4096, d_model=512, head_dim=128, n_kv_head=8,
               n_expert=16, top_k=4, d_expert=256, held=[4, 12], seq=2048,
               slots=8, prompt=700, new_tokens=8, require_tpu=True)
 
+# A Phi-4-mini-flash-family block (SambaY: Mamba and sliding layers, a
+# Mamba layer that hands on its memory, ONE full layer whose K/V the
+# cross layers read, gated memory units; differential attention): 8
+# layers by the rule 2 : 2 : 1 : 1 : 1 : 1, 16 query heads on 8 key/value
+# heads of 64 (flat rows of 512), the published window and state.
+PHI4FLASH = dict(vocab=4096, d_model=1024, n_head=16, n_kv_head=8,
+                 d_inner=2048, window=512, n_layer=8, seq=2048, slots=8,
+                 prompt=700, new_tokens=8, require_tpu=True)
+
 # Tolerances (max abs error over max abs reference, bf16 inputs): one
 # bf16 rounding is 2^-8 = 0.4%; the backward accumulates ~T of them.
 TOL_ATTN_FWD = 2e-2
@@ -427,23 +436,21 @@ def laguna_config(cfg):
         biases=False)
 
 
-def phase_laguna(cfg, place):
-    """A Laguna-family block through save_decode_model -> DecodePredictor
-    -> DecodeServer: ONE admission whose prompt wraps the ring of a
-    sliding layer, then eight steps through slabs and rings, against a
-    full-forward rollout (one prefill a token, which knows no cache); and
-    the experts' loads booked. A hang in the expert gather, the ring's
-    slices or the window kernel shows here, in seconds."""
+def _serve_described(config, cfg, place, model_dir):
+    """A described block with weights from its initializers through
+    save_decode_model -> DecodePredictor -> DecodeServer: ONE admission
+    whose prompt wraps the ring of a sliding layer, then
+    ``new_tokens`` steps, against a full-forward rollout (one prefill a
+    token, which knows no cache). -> (pred, srv, tokens agreeing,
+    seconds, prompt)."""
     import paddle_tpu as fluid
     from paddle_tpu import layers
     from paddle_tpu.models import jamba
     from paddle_tpu.serving import (DecodePredictor, DecodeServer,
                                     save_decode_model)
 
-    model_dir = os.path.join(OUT_DIR, "laguna_model")
     shutil.rmtree(model_dir, ignore_errors=True)
     os.makedirs(model_dir)
-    config = laguna_config(cfg)
     main_p, startup = fluid.Program(), fluid.Program()
     main_p.random_seed = startup.random_seed = 7
     with fluid.program_guard(main_p, startup):
@@ -489,6 +496,18 @@ def phase_laguna(cfg, place):
                 "%.4g" % (agree, int(got), want, gap, TOL_GREEDY_TIE))
             break
         agree += 1
+    return pred, srv, agree, serve_s, prompt
+
+
+def phase_laguna(cfg, place):
+    """A Laguna-family block: the prompt wraps the ring of a sliding
+    layer, then eight steps through slabs and rings, and the experts'
+    loads booked. A hang in the expert gather, the ring's slices or the
+    window kernel shows here, in seconds."""
+    model_dir = os.path.join(OUT_DIR, "laguna_model")
+    new = cfg["new_tokens"]
+    pred, srv, agree, serve_s, prompt = _serve_described(
+        laguna_config(cfg), cfg, place, model_dir)
     pairs = int(srv.moe_load_total.sum())
     assert pairs > 0, "no pair was booked on a held expert"
     if cfg["require_tpu"]:
@@ -512,6 +531,63 @@ def phase_laguna(cfg, place):
               (srv.moe_load_total.max(axis=1)
                / np.maximum(srv.moe_load_total.mean(axis=1), 1e-9)).max()),
           ok=True)
+    shutil.rmtree(model_dir, ignore_errors=True)
+
+
+def phi4flash_config(cfg):
+    from paddle_tpu.serving import DecodeConfig
+
+    n = cfg["n_layer"]
+    half = n // 2
+    kinds = [("mamba" if i % 2 == 0 else "sliding") if i < half
+             else "mamba" if i == half else "attention" if i == half + 1
+             else "gmu" if i % 2 == 0 else "cross" for i in range(n)]
+    return DecodeConfig(
+        cfg["vocab"], n_layer=n, n_head=cfg["n_head"],
+        d_model=cfg["d_model"], d_inner=cfg["d_inner"], max_len=cfg["seq"],
+        tie_embeddings=True, n_kv_head=cfg["n_kv_head"], layer_types=kinds,
+        window=cfg["window"], diff_attn=True, attn_biases=True,
+        mamba_norms=False, norm="layer_norm", norm_eps=1e-5,
+        ffn="gated_silu", positions=False, biases=False)
+
+
+def phase_phi4flash(cfg, place):
+    """A Phi-4-mini-flash-family block: a prefill with the one-row
+    shortcut whose prompt wraps the rings, then eight steps through the
+    ONE slab (read by the full layer and the cross layers), rings,
+    states and the memory. A slab that is copied for its append, or a
+    hang in the flat rows' slices, shows here, in seconds."""
+    model_dir = os.path.join(OUT_DIR, "phi4flash_model")
+    config = phi4flash_config(cfg)
+    pred, srv, agree, serve_s, prompt = _serve_described(
+        config, cfg, place, model_dir)
+    readers = srv._step_counts(np.zeros((cfg["slots"],), np.int32), 0)[
+        "slab_readers"]
+    assert readers == 1 + config.layer_kinds().count("cross")
+    if cfg["require_tpu"]:
+        text = pred.acquire("prefill", 1, 1024)[0].as_text()
+        kernels = re.findall(r"%(ptpu\.[a-z_]+)[.\d]* = ", text)
+        sliding = config.layer_kinds().count("sliding")
+        assert (kernels.count("ptpu.attn_window") == sliding
+                and kernels.count("ptpu.flash_fwd") == 1), (
+            "the prefill does not run one attention kernel a layer that "
+            "owns keys: %r" % kernels)
+        text = pred.acquire("decode", cfg["slots"], cfg["seq"])[0].as_text()
+        entry = text[text.index("ENTRY"):]
+        slab = "f32[%d,%d,%d]" % (cfg["slots"], cfg["seq"],
+                                  config.kv_row[0])
+        copies = [ln for ln in entry.splitlines()
+                  if " copy(" in ln and "= " + slab in ln]
+        assert not copies, ("the decode step copies its slab: %s"
+                            % copies[0][:200])
+        kernels = re.findall(r"%(ptpu\.[a-z_]+)[.\d]* = ", text)
+        assert (kernels.count("ptpu.diff_attn_rows") == readers
+                and srv._stream_rows), (
+            "the slab's readers do not attend it through the kernel over "
+            "flat rows: %r, stream rows %r" % (kernels, srv._stream_rows))
+    _emit("phi4flash", prompt_len=len(prompt), new_tokens=cfg["new_tokens"],
+          server_s=serve_s, rollout_tokens_agreeing=agree,
+          slab_readers=readers, ok=True)
     shutil.rmtree(model_dir, ignore_errors=True)
 
 
@@ -651,6 +727,8 @@ def main(argv=None):
         phase_serve(FULL, place)
         gc.collect()
         phase_laguna(LAGUNA, place)
+        gc.collect()
+        phase_phi4flash(PHI4FLASH, place)
     _emit("done", seconds=time.perf_counter() - t0,
           jax_cache_hits=cache_events["hits"],
           jax_cache_misses=cache_events["misses"])

@@ -1247,6 +1247,75 @@ def _infer_ring_pack(ctx: InferContext):
     return {"Out": VarInfo(shape, x.dtype)}
 
 
+def _infer_diff(ctx: InferContext, slots):
+    """Q (B, T, H, dh) against flat key/value rows (B, ., Hkv dh): Out
+    is (B, T, H / 2, 2 dh); Hkv is even and its pairs divide H's."""
+    q = ctx.in_info("Q")
+    qs = q.shape
+    if qs is None:
+        return {"Out": VarInfo(None, q.dtype)}
+    if len(qs) != 4:
+        raise InferError("Q must be rank 4 (B, T, H, dh), got rank %d"
+                         % len(qs))
+    for slot in slots:
+        c = ctx.in_shape(slot)
+        if c is None:
+            continue
+        if len(c) != 3:
+            raise InferError("%s must be rank 3 (B, S, Hkv dh: flat rows),"
+                             " got rank %d" % (slot, len(c)))
+        if qs[3] is not None and c[2] is not None and (
+                c[2] % (2 * qs[3]) or not c[2]):
+            raise InferError(
+                "%s%s does not hold key/value PAIRS of Q%s's heads (a "
+                "row of a multiple of 2 x %d)"
+                % (slot, render_shape(c), render_shape(qs), qs[3]))
+        if None not in (qs[2], qs[3], c[2]) and qs[2] % (c[2] // qs[3]):
+            raise InferError("%s's %d key/value heads do not divide Q%s's"
+                             % (slot, c[2] // qs[3], render_shape(qs)))
+        if qs[0] is not None and c[0] is not None and qs[0] != c[0]:
+            raise InferError("%s batch dim %d does not match Q%s"
+                             % (slot, c[0], render_shape(qs)))
+    h = None if qs[2] is None else qs[2] // 2
+    w = None if qs[3] is None else 2 * qs[3]
+    return {"Out": VarInfo((qs[0], qs[1], h, w), q.dtype)}
+
+
+@register_infer("diff_attention")
+def _infer_diff_attention(ctx: InferContext):
+    if int(ctx.attr("window", 0) or 0) < 0:
+        raise InferError("window must be >= 0, got %r"
+                         % ctx.attr("window", None))
+    return _infer_diff(ctx, ("K", "V"))
+
+
+@register_infer("diff_decode_attention")
+def _infer_diff_decode_attention(ctx: InferContext):
+    return _infer_diff(ctx, ("KCache", "VCache"))
+
+
+@register_infer("attn_cross")
+def _infer_attn_cross(ctx: InferContext):
+    return _infer_diff(ctx, ("KCache", "VCache"))
+
+
+@register_infer("gmu")
+def _infer_gmu(ctx: InferContext):
+    """Out mirrors X; Memory is X's rows at WIn's width."""
+    x = ctx.in_info("X")
+    m, w = ctx.in_shape("Memory"), ctx.in_shape("WIn")
+    if (m is not None and w is not None and len(w) == 2
+            and m[-1] is not None and w[1] is not None and m[-1] != w[1]):
+        raise InferError("Memory%s is not as wide as WIn%s's columns"
+                         % (render_shape(m), render_shape(w)))
+    if (x.shape is not None and m is not None and len(m) == len(x.shape)
+            and any(a is not None and b is not None and a != b
+                    for a, b in zip(x.shape[:-1], m[:-1]))):
+        raise InferError("Memory%s does not hold a row for each of X%s's"
+                         % (render_shape(m), render_shape(x.shape)))
+    return {"Out": VarInfo(x.shape, x.dtype)}
+
+
 @register_infer("rms_norm")
 def _infer_rms_norm(ctx: InferContext):
     """Out mirrors X; Scale is X's last axis."""

@@ -132,6 +132,10 @@ __all__ = [
     "ring_append",
     "ring_pack",
     "decode_attn_ring",
+    "diff_attention",
+    "diff_decode_attention",
+    "attn_cross",
+    "gmu",
 ]
 
 from .ops import elementwise_add  # re-export for parity
@@ -2671,4 +2675,80 @@ def decode_attn_ring(q, k_ring, v_ring, lengths, scale=None, name=None):
         inputs={"Q": [q], "KCache": [k_ring], "VCache": [v_ring],
                 "Lengths": [lengths]},
         outputs={"Out": [out]}, attrs={"scale": scale})
+    return out
+
+
+def _diff_out(helper, q):
+    """(B, T, H / 2, 2 dh) for differential queries (B, T, H, dh)."""
+    b, t, h, dh = q.shape
+    return helper.create_variable_for_type_inference(
+        q.dtype, shape=(b, t, h // 2, 2 * dh))
+
+
+def _diff_inputs(lambdas, gain):
+    lq1, lk1, lq2, lk2 = lambdas
+    return {"LQ1": [lq1], "LK1": [lk1], "LQ2": [lq2], "LK2": [lk2],
+            "Gain": [gain]}
+
+
+def diff_attention(q, k, v, lambdas, gain, lam_init, window=0,
+                   epsilon=1e-5, name=None):
+    """Differential attention of a prefill (ops/diff_attn.py): q (B, T,
+    H, dh), k/v (B, T, Hkv dh) flat key/value rows, ``lambdas`` the
+    layer's four vectors (lq1, lk1, lq2, lk2) of dh, ``gain`` (2 dh,);
+    causal, within ``window`` where given. -> (B, T, H / 2, 2 dh)."""
+    helper = LayerHelper("diff_attention", name=name)
+    out = _diff_out(helper, q)
+    inputs = {"Q": [q], "K": [k], "V": [v]}
+    inputs.update(_diff_inputs(lambdas, gain))
+    helper.append_op(
+        type="diff_attention", inputs=inputs, outputs={"Out": [out]},
+        attrs={"lam_init": float(lam_init), "window": int(window or 0),
+               "epsilon": float(epsilon)})
+    return out
+
+
+def diff_decode_attention(q, k_cache, v_cache, lengths, lambdas, gain,
+                          lam_init, ring=False, epsilon=1e-5, name=None):
+    """One token of differential attention against the layer's slab (B,
+    S, Hkv dh), or its ring (B, W, Hkv dh) with ``ring``, of flat rows;
+    ``lengths`` (B,) positions held including this step's row."""
+    helper = LayerHelper("diff_decode_attention", name=name)
+    out = _diff_out(helper, q)
+    inputs = {"Q": [q], "KCache": [k_cache], "VCache": [v_cache],
+              "Lengths": [lengths]}
+    inputs.update(_diff_inputs(lambdas, gain))
+    helper.append_op(
+        type="diff_decode_attention", inputs=inputs,
+        outputs={"Out": [out]},
+        attrs={"lam_init": float(lam_init), "ring": bool(ring),
+               "epsilon": float(epsilon)})
+    return out
+
+
+def attn_cross(q, k, v, lengths, lambdas, gain, lam_init, epsilon=1e-5,
+               name=None):
+    """One query row of differential attention against keys and values
+    ANOTHER layer owns (its slab in a step, its prompt rows in a
+    prefill): rows ``[0, lengths)`` are seen, nothing is appended."""
+    helper = LayerHelper("attn_cross", name=name)
+    out = _diff_out(helper, q)
+    inputs = {"Q": [q], "KCache": [k], "VCache": [v], "Lengths": [lengths]}
+    inputs.update(_diff_inputs(lambdas, gain))
+    helper.append_op(
+        type="attn_cross", inputs=inputs, outputs={"Out": [out]},
+        attrs={"lam_init": float(lam_init), "epsilon": float(epsilon)})
+    return out
+
+
+def gmu(x, memory, w_in, w_out, name=None):
+    """Gated memory unit: ``(memory * silu(x w_in)) w_out``; ``memory``
+    (B, T, Di) is a state-space layer's scan output, row for row."""
+    helper = LayerHelper("gmu", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype, shape=x.shape)
+    helper.append_op(
+        type="gmu",
+        inputs={"X": [x], "Memory": [memory], "WIn": [w_in],
+                "WOut": [w_out]},
+        outputs={"Out": [out]}, attrs={})
     return out

@@ -1,8 +1,10 @@
 """Decoder LMs of a DESCRIBED block: a mixer kind (``mamba`` |
-``attention`` | ``sliding``) times a feed-forward kind (``dense`` |
-``experts``) a layer, RMS normalization before every mixer and
-feed-forward and after the last layer, no biases but the convolution's
-and ``dt_proj``'s. ``serving.decode.DecodeConfig`` says which:
+``attention`` | ``sliding`` | ``gmu`` | ``cross``) times a feed-forward
+kind (``dense`` | ``experts``) a layer, one normalization (RMS, or
+LayerNorm with bias) before every mixer and feed-forward and after the
+last layer, no biases but the convolution's and ``dt_proj``'s and,
+where asked, the attention projections'.
+``serving.decode.DecodeConfig`` says which:
 
 - AI21's Jamba (`model_type: jamba`): Mamba-1 layers with one attention
   layer every ``attn_layer_period``, each followed by a gated-SiLU MLP,
@@ -14,14 +16,29 @@ and ``dt_proj``'s. ``serving.decode.DecodeConfig`` says which:
   partial, YaRN), a sigmoid gate a query head on the attention output,
   a leading dense MLP and then routed experts with a shared one
   (``ops/moe.py``: no token dropped, the experts held here), an untied
-  output head.
+  output head;
+- Microsoft's Phi-4-mini-flash (`model_type: phi4flash`, SambaY): a
+  first half of Mamba-1 and sliding-window layers, one Mamba layer
+  that also hands on its MEMORY (the scan's output before the gate),
+  one full-attention layer whose K and V are the model's only
+  full-length cache, then gated memory units (``gmu``: the memory
+  times a gate from the layer's input) and cross layers (``cross``:
+  queries of their own on that one layer's K and V) that keep NOTHING;
+  every attention differential (``ops/diff_attn.py``), LayerNorm, no
+  positions.
 
 The serving graphs only (serving/decode.py): ``hybrid_lm_prefill``
 walks padded prompts and returns every layer's cache entries AT EACH
 ROW'S LENGTH; ``hybrid_lm_decode`` advances them by one token. Both
-are derived from ONE description of a layer, ``_layer``: its kind
-(``mamba`` | ``attention``) and whether it is handed a cache entry
-decide what it builds, and the parameter set is written once.
+are derived from ONE description of a layer, ``_layer``: its kind and
+whether it is handed a cache entry decide what it builds, and the
+parameter set is written once. A layer may own no cache entry and read
+another's: what a layer hands on to the layers after it (the memory,
+the keys and values) travels in ``shared``. The layers from
+``cfg.tail_start`` on own nothing, so a prefill runs them on each
+prompt's LAST row alone: the rows of the memory and of K and V they
+need exist already, and nobody reads their other rows. Exact, and the
+architecture's prefill saving.
 
 Cache entries, by feed name (``serving.decode.cache_spec``): a Mamba
 layer ``i`` keeps ``conv_i`` (B, K - 1, d_inner), the convolution's
@@ -31,6 +48,9 @@ size, no row per position. An attention layer keeps ``kcache_i`` /
 they are, never repeated for the query heads that share them. A
 sliding layer keeps ``kring_i`` / ``vring_i`` (B, window, n_kv_head,
 d_head): position p at row p mod window. Keys are stored ROTATED.
+Under differential attention a slab or ring row is FLAT, (B, S | window,
+n_kv_head * d_head) (``ops/diff_attn.py`` says why); a ``gmu`` or
+``cross`` layer keeps nothing.
 """
 from __future__ import annotations
 
@@ -39,6 +59,7 @@ import numpy as np
 from .. import layers
 from ..framework import default_main_program
 from ..initializer import ConstantInitializer, NormalInitializer
+from ..ops import diff_attn as _D
 from ..param_attr import ParamAttr
 from .transformer import sample_next
 
@@ -46,6 +67,8 @@ from .transformer import sample_next
 def cache_names(kind: str, i: int):
     """Feed names of layer ``i``'s cache entries, in the order ``_layer``
     takes and returns them."""
+    if kind in ("gmu", "cross"):
+        return []  # reads what another layer keeps
     if kind == "mamba":
         return ["conv_%d" % i, "ssm_%d" % i]
     if kind == "sliding":
@@ -67,6 +90,16 @@ def _rms(x, name, eps):
                            param_attr=ParamAttr(name=name + ".w"))
 
 
+def _norm(x, name, cfg):
+    """The block's normalization over the last axis."""
+    if cfg.norm == "layer_norm":
+        return layers.layer_norm(
+            x, begin_norm_axis=len(x.shape) - 1, epsilon=cfg.norm_eps,
+            param_attr=ParamAttr(name=name + ".w"),
+            bias_attr=ParamAttr(name=name + ".b"))
+    return _rms(x, name, cfg.norm_eps)
+
+
 def _param(shape, name, init, is_bias=False):
     return layers.create_parameter(
         shape=shape, dtype="float32", is_bias=is_bias,
@@ -74,9 +107,11 @@ def _param(shape, name, init, is_bias=False):
 
 
 def _mamba_mixer(u, cfg, name, lengths, cache):
-    """Mamba-1 with Jamba's RMS norms on delta, B and C. ``cache`` is
-    None (prefill: scan from zero, state and window at ``lengths``) or
-    (window, state) (one token). Returns (out, (window, state))."""
+    """Mamba-1, with Jamba's RMS norms on delta, B and C where
+    ``cfg.mamba_norms``. ``cache`` is None (prefill: scan from zero,
+    state and window at ``lengths``) or (window, state) (one token).
+    Returns (out, (window, state), memory): the memory is the scan's
+    output with the ``D`` skip, BEFORE the ``silu(z)`` gate."""
     di, n, r, k = (cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_dt_rank,
                    cfg.mamba_d_conv)
     x, z = layers.split(_proj(u, 2 * di, name + ".in_proj"), 2, dim=-1)
@@ -90,9 +125,10 @@ def _mamba_mixer(u, cfg, name, lengths, cache):
     x = layers.swish(x, beta=1.0)  # SiLU
     dt, b, c = layers.split(_proj(x, r + 2 * n, name + ".x_proj"),
                             [r, n, n], dim=-1)
-    dt = _rms(dt, name + ".dt_norm", cfg.norm_eps)
-    b = _rms(b, name + ".b_norm", cfg.norm_eps)
-    c = _rms(c, name + ".c_norm", cfg.norm_eps)
+    if cfg.mamba_norms:
+        dt = _rms(dt, name + ".dt_norm", cfg.norm_eps)
+        b = _rms(b, name + ".b_norm", cfg.norm_eps)
+        c = _rms(c, name + ".c_norm", cfg.norm_eps)
     delta = layers.softplus(_proj(dt, di, name + ".dt_proj", bias=True))
     a_log = _param([di, n], name + ".A_log", ConstantInitializer(0.0))
     a = layers.scale(layers.exp(a_log), scale=-1.0)
@@ -101,8 +137,19 @@ def _mamba_mixer(u, cfg, name, lengths, cache):
         y, state = layers.ssm_scan(x, delta, a, b, c, d, lengths)
     else:
         y, state = layers.ssm_step(x, delta, a, b, c, d, cache[1])
-    y = layers.elementwise_mul(y, layers.swish(z, beta=1.0))
-    return _proj(y, cfg.d_model, name + ".out_proj"), (window, state)
+    gated = layers.elementwise_mul(y, layers.swish(z, beta=1.0))
+    return (_proj(gated, cfg.d_model, name + ".out_proj"), (window, state),
+            y)
+
+
+def _diff_params(cfg, name):
+    """A differential layer's four lambda vectors and the gain its
+    heads share: ((lq1, lk1, lq2, lk2), gain)."""
+    lam = NormalInitializer(0.0, 0.1)
+    return (tuple(_param([cfg.d_head], "%s.lambda_%s" % (name, n), lam)
+                  for n in ("q1", "k1", "q2", "k2")),
+            _param([2 * cfg.d_head], name + ".subln.w",
+                   ConstantInitializer(1.0)))
 
 
 def _attention_mixer(u, cfg, name, lengths, cache, i=0, kind="attention"):
@@ -115,29 +162,41 @@ def _attention_mixer(u, cfg, name, lengths, cache, i=0, kind="attention"):
     layer's two entries (one token: append at ``lengths``, or at
     ``lengths mod window`` into a ring, and attend). A per-head sigmoid
     gate from the layer's input scales the attention output where
-    ``cfg.attn_gate`` asks. Returns (out, (k, v))."""
+    ``cfg.attn_gate`` asks. Under ``cfg.diff_attn`` the attention is
+    differential and k, v and the cache entries keep FLAT rows
+    (``cfg.kv_row``: the projection's own output). Returns (out, (k,
+    v))."""
     B, T, _ = u.shape
     h, hkv, dh = cfg.heads(i), cfg.n_kv_head, cfg.d_head
+    bias = cfg.attn_biases
     sliding = kind == "sliding"
-    q = layers.reshape(_proj(u, h * dh, name + ".q"), shape=[B, T, h, dh])
-    k = layers.reshape(_proj(u, hkv * dh, name + ".k"),
-                       shape=[B, T, hkv, dh])
-    v = layers.reshape(_proj(u, hkv * dh, name + ".v"),
-                       shape=[B, T, hkv, dh])
+    q = layers.reshape(_proj(u, h * dh, name + ".q", bias),
+                       shape=[B, T, h, dh])
+    k = layers.reshape(_proj(u, hkv * dh, name + ".k", bias),
+                       shape=[B, T] + list(cfg.kv_row))
+    v = layers.reshape(_proj(u, hkv * dh, name + ".v", bias),
+                       shape=[B, T] + list(cfg.kv_row))
+    diff = _diff_params(cfg, name) if cfg.diff_attn else None
+    lam0 = _D.lambda_init(i)
     rot = (cfg.rope or {}).get("sliding" if sliding else "full")
     if rot:
         at = None if cache is None else lengths
         q = layers.rope(q, at, **rot)
         k = layers.rope(k, at, **rot)
     if cache is None:
-        if sliding:
+        if diff:
+            ctx = layers.diff_attention(
+                q, k, v, *diff, lam_init=lam0,
+                window=cfg.window if sliding else 0, epsilon=cfg.norm_eps)
+        elif sliding:
             ctx = layers.attn_window(q, k, v, cfg.window)
-            k = layers.ring_pack(k, lengths, cfg.window)
-            v = layers.ring_pack(v, lengths, cfg.window)
         else:
             # the op repeats k and v for the query heads that share them
             ctx = layers.fused_attention(q, k, v, causal=True,
                                          layout="bthd")
+        if sliding:
+            k = layers.ring_pack(k, lengths, cfg.window)
+            v = layers.ring_pack(v, lengths, cfg.window)
     else:
         append = layers.ring_append if sliding else layers.cache_append
         k = append(cache[0], k, lengths)
@@ -145,16 +204,56 @@ def _attention_mixer(u, cfg, name, lengths, cache, i=0, kind="attention"):
         kv_lengths = layers.elementwise_add(
             layers.cast(lengths, "int32"),
             layers.fill_constant(shape=[B], dtype="int32", value=1))
-        attend = (layers.decode_attn_ring if sliding
-                  else layers.decode_attention)
-        ctx = attend(q, k, v, kv_lengths)
+        if diff:
+            ctx = layers.diff_decode_attention(
+                q, k, v, kv_lengths, *diff, lam_init=lam0, ring=sliding,
+                epsilon=cfg.norm_eps)
+        else:
+            attend = (layers.decode_attn_ring if sliding
+                      else layers.decode_attention)
+            ctx = attend(q, k, v, kv_lengths)
     if cfg.attn_gate == "per_head":
         gate = layers.sigmoid(_proj(u, h, name + ".gate"))
         ctx = layers.elementwise_mul(
             ctx, layers.reshape(gate, shape=[B, T, h, 1]))
     out = _proj(layers.reshape(ctx, shape=[B, T, h * dh]), cfg.d_model,
-                name + ".o")
+                name + ".o", bias)
     return out, (k, v)
+
+
+def _cross_mixer(u, cfg, name, i, kv):
+    """Queries of this layer's own on the keys and values ANOTHER layer
+    keeps: ``kv`` = (k, v, rows seen (B,)): that layer's slab after this
+    step's append, or its prompt rows in a prefill. u is (B, 1, D): one
+    query row a sequence, at its last position (a prefill that runs
+    every layer on every row hands all T rows in, each of which sees
+    the keys up to its own: the causal op, for the test of the
+    shortcut)."""
+    B, T, _ = u.shape
+    h, dh, bias = cfg.heads(i), cfg.d_head, cfg.attn_biases
+    q = layers.reshape(_proj(u, h * dh, name + ".q", bias),
+                       shape=[B, T, h, dh])
+    k, v, seen = kv
+    diff = _diff_params(cfg, name)
+    if T == 1:
+        ctx = layers.attn_cross(q, k, v, seen, *diff,
+                                lam_init=_D.lambda_init(i),
+                                epsilon=cfg.norm_eps)
+    else:
+        ctx = layers.diff_attention(q, k, v, *diff,
+                                    lam_init=_D.lambda_init(i),
+                                    epsilon=cfg.norm_eps)
+    return _proj(layers.reshape(ctx, shape=[B, T, h * dh]), cfg.d_model,
+                 name + ".o", bias)
+
+
+def _gmu_mixer(u, cfg, name, memory):
+    """``W_out (memory * silu(W_in u))``: the memory row for row."""
+    w = NormalInitializer(0.0, 0.02)
+    di = cfg.mamba_d_inner
+    return layers.gmu(u, memory,
+                      _param([cfg.d_model, di], name + ".in_proj.w", w),
+                      _param([di, cfg.d_model], name + ".out_proj.w", w))
 
 
 def _mlp(x, cfg, name):
@@ -188,21 +287,38 @@ def _experts(x, cfg, name, lengths, decode):
     return layers.elementwise_add(routed, shared), load
 
 
-def _layer(x, kind, i, cfg, lengths, cache=None, loads=None):
+def _layer(x, kind, i, cfg, lengths, cache=None, loads=None, shared=None):
     """THE description of layer ``i``: x (B, T, D) -> (x, cache
     entries in ``cache_names(kind, i)`` order). Prefill and decode
     differ only in ``cache``. An expert layer appends its load to
-    ``loads``."""
+    ``loads``. ``shared`` (a dict) carries what a layer hands on to the
+    layers after it: a Mamba layer its ``memory`` (B, T, Di), a full
+    attention layer its ``kv`` = (k, v, rows seen): a ``gmu`` and a
+    ``cross`` layer read the nearest before them and keep nothing."""
     name = "%s.l%d" % (cfg.prefix, i)
-    u = _rms(x, name + ".norm_in", cfg.norm_eps)
+    shared = {} if shared is None else shared
+    u = _norm(x, name + ".norm_in", cfg)
+    entries = ()
     if kind == "mamba":
-        mixed, entries = _mamba_mixer(u, cfg, name + ".mamba", lengths,
-                                      cache)
+        mixed, entries, shared["memory"] = _mamba_mixer(
+            u, cfg, name + ".mamba", lengths, cache)
+    elif kind == "gmu":
+        mixed = _gmu_mixer(u, cfg, name + ".gmu", shared["memory"])
+    elif kind == "cross":
+        mixed = _cross_mixer(u, cfg, name + ".cross", i, shared["kv"])
     else:
         mixed, entries = _attention_mixer(u, cfg, name + ".attention",
                                           lengths, cache, i, kind)
+        if kind == "attention" and "cross" in cfg.layer_kinds()[i:]:
+            # a prefill's rows are seen up to each length; a step's
+            # slab with the row it has just appended
+            seen = layers.cast(lengths, "int32")
+            if cache is not None:
+                seen = layers.elementwise_add(seen, layers.fill_constant(
+                    shape=[x.shape[0]], dtype="int32", value=1))
+            shared["kv"] = entries + (seen,)
     x = layers.elementwise_add(x, mixed)
-    u = _rms(x, name + ".norm_ff", cfg.norm_eps)
+    u = _norm(x, name + ".norm_ff", cfg)
     if cfg.ffn_kinds()[i] == "experts":
         ffn, load = _experts(u, cfg, name + ".moe", lengths,
                              cache is not None)
@@ -214,13 +330,19 @@ def _layer(x, kind, i, cfg, lengths, cache=None, loads=None):
 
 def _check(cfg):
     """Refuse what no graph here computes."""
-    if (cfg.norm != "rms_norm" or cfg.ffn != "gated_silu" or cfg.positions
-            or cfg.biases):
+    if (cfg.norm not in ("rms_norm", "layer_norm")
+            or cfg.ffn != "gated_silu" or cfg.positions or cfg.biases):
         raise ValueError(
-            "the described-block builders write RMS norms, a gated-SiLU "
-            "MLP, no learned positions and no biases; got norm=%r ffn=%r "
-            "positions=%r biases=%r"
+            "the described-block builders write RMS norms or LayerNorm, "
+            "a gated-SiLU MLP, no learned positions and no biases but "
+            "`attn_biases`; got norm=%r ffn=%r positions=%r biases=%r"
             % (cfg.norm, cfg.ffn, cfg.positions, cfg.biases))
+    if "cross" in cfg.layer_kinds() and not cfg.diff_attn:
+        raise ValueError("a cross layer is built with differential "
+                         "attention alone (diff_attn)")
+    if cfg.diff_attn and (cfg.rope or cfg.attn_gate):
+        raise ValueError("differential attention is built without rotary "
+                         "positions and without an output gate")
     if cfg.attn_gate not in (None, "per_head"):
         raise ValueError("attn_gate %r: only a sigmoid gate a query head "
                          "('per_head') is built" % (cfg.attn_gate,))
@@ -259,7 +381,8 @@ def _head(last, cfg):
     return layers.matmul(last, emb, transpose_y=True)
 
 
-def hybrid_lm_prefill(tokens, lengths, cfg, extras=None):
+def hybrid_lm_prefill(tokens, lengths, cfg, extras=None,
+                      one_row_tail=True):
     """Padded prompts ``tokens`` (B, S), ``lengths`` (B,) -> (logits
     (B, V) of each row's last real position, {feed name: cache entry}):
     slab entries hold the prompt's k and v rows (garbage past a row's
@@ -267,22 +390,44 @@ def hybrid_lm_prefill(tokens, lengths, cfg, extras=None):
     window after each row's LAST REAL token, ring entries each row's
     last ``window`` positions as a decode step will find them.
     ``extras`` (a dict) receives ``moe_load`` where layers route over
-    experts: the pairs of REAL tokens each held expert received."""
+    experts: the pairs of REAL tokens each held expert received. The
+    layers from ``cfg.tail_start`` on run on each row's last real
+    position alone; ``one_row_tail=False`` runs them on every row, for
+    the test that shows the two equal."""
     _check(cfg)
     B, S = tokens.shape
     x = _embed(tokens, cfg)
-    caches, loads = {}, []
+    caches, loads, shared, at = {}, [], {}, []
+
+    def last_rows(a):
+        """(B, S, W) -> (B, W): each row's last real position."""
+        flat = layers.reshape(a, shape=[B * S, a.shape[-1]])
+        if not at:
+            base = layers.assign(
+                (np.arange(B, dtype=np.int32) * S - 1).reshape(B))
+            at.append(layers.elementwise_add(layers.cast(lengths, "int32"),
+                                             base))
+        return layers.gather(flat, at[0])
+
+    tail = cfg.tail_start if one_row_tail else cfg.n_layer
     for i, kind in enumerate(cfg.layer_kinds()):
-        x, entries = _layer(x, kind, i, cfg, lengths, loads=loads)
+        if i == tail:
+            # no layer from here on owns a cache entry: one row a prompt
+            x = layers.reshape(last_rows(x), shape=[B, 1, cfg.d_model])
+            if "memory" in shared:
+                shared["memory"] = layers.reshape(
+                    last_rows(shared["memory"]),
+                    shape=[B, 1, cfg.mamba_d_inner])
+        x, entries = _layer(x, kind, i, cfg, lengths, loads=loads,
+                            shared=shared)
         caches.update(zip(cache_names(kind, i), entries))
     if extras is not None and loads:
         # (sparse layers, experts held) int32
         extras["moe_load"] = layers.stack(loads, axis=0)
-    x = _rms(x, cfg.prefix + ".norm_f", cfg.norm_eps)
-    flat = layers.reshape(x, shape=[B * S, cfg.d_model])
-    base = layers.assign((np.arange(B, dtype=np.int32) * S - 1).reshape(B))
-    idx = layers.elementwise_add(layers.cast(lengths, "int32"), base)
-    return _head(layers.gather(flat, idx), cfg), caches
+    x = _norm(x, cfg.prefix + ".norm_f", cfg)
+    if tail < cfg.n_layer:
+        return _head(layers.reshape(x, shape=[B, cfg.d_model]), cfg), caches
+    return _head(last_rows(x), cfg), caches
 
 
 def hybrid_lm_decode(tokens, lengths, caches, cfg, strategy="greedy",
@@ -296,17 +441,17 @@ def hybrid_lm_decode(tokens, lengths, caches, cfg, strategy="greedy",
     B = tokens.shape[0]
     # embedding squeezes the trailing ids dim of 1: restore the time axis
     x = layers.reshape(_embed(tokens, cfg), shape=[B, 1, cfg.d_model])
-    new, loads = {}, []
+    new, loads, shared = {}, [], {}
     for i, kind in enumerate(cfg.layer_kinds()):
         names = cache_names(kind, i)
         x, entries = _layer(x, kind, i, cfg, lengths,
                             cache=tuple(caches[n] for n in names),
-                            loads=loads)
+                            loads=loads, shared=shared)
         new.update(zip(names, entries))
     if extras is not None and loads:
         # (sparse layers, experts held) int32
         extras["moe_load"] = layers.stack(loads, axis=0)
-    x = _rms(x, cfg.prefix + ".norm_f", cfg.norm_eps)
+    x = _norm(x, cfg.prefix + ".norm_f", cfg)
     logits = _head(layers.reshape(x, shape=[B, cfg.d_model]), cfg)
     next_ids = sample_next(logits, strategy, seed, sample_k, sample_p,
                            temperature)

@@ -782,36 +782,54 @@ def _fused_attention(ctx):
     block_k = int(ctx.attr("block_k", 512))
     layout = str(ctx.attr("layout", "bhtd") or "bhtd").lower()
     rng = ctx.rng() if dropout_rate else None
-    head_axis = 2 if layout == "bthd" else 1
-    if k.shape[head_axis] != q.shape[head_axis]:
-        # grouped queries: the kernels take q, k, v of one head count,
-        # so each key/value head is repeated for the query heads that
-        # share it (a cost in compute, none in mathematics)
-        group, rem = divmod(q.shape[head_axis], k.shape[head_axis])
-        if rem:
-            raise ValueError(
-                "fused_attention: %d query heads do not divide over %d "
-                "key/value heads" % (q.shape[head_axis],
-                                     k.shape[head_axis]))
-        k = jnp.repeat(k, group, axis=head_axis)
-        v = jnp.repeat(v, group, axis=head_axis)
-
     if layout == "bthd":
-        t, tk, d_head = q.shape[1], k.shape[1], q.shape[-1]
-        if d_head % 128 == 0 and _use_pallas(t, tk, lengths, dropout_rate):
-            kern = functools.partial(
-                pallas_flash_attention_bthd, causal=causal, scale=scale,
-                block_q=_env_block("PADDLE_TPU_FLASH_BQ", 512),
-                block_k=_env_block("PADDLE_TPU_FLASH_BK", block_k))
-            return {"Out": _per_shard(kern, q, k, v, head_dim=2)}
-        out = _attention_bhtd(
-            jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
-            jnp.swapaxes(v, 1, 2), lengths, causal, scale, dropout_rate,
-            block_k, rng)
-        return {"Out": jnp.swapaxes(out, 1, 2)}
-
+        return {"Out": _attention_bthd(q, k, v, lengths, causal, scale,
+                                       dropout_rate, block_k, rng)}
+    k, v = _repeat_kv(q, k, v, 1)
     return {"Out": _attention_bhtd(q, k, v, lengths, causal, scale,
                                    dropout_rate, block_k, rng)}
+
+
+def _repeat_kv(q, k, v, head_axis):
+    """Grouped queries: the kernels take q, k, v of one head count, so
+    each key/value head is repeated for the query heads that share it
+    (a cost in compute, none in mathematics)."""
+    if k.shape[head_axis] == q.shape[head_axis]:
+        return k, v
+    group, rem = divmod(q.shape[head_axis], k.shape[head_axis])
+    if rem:
+        raise ValueError(
+            "fused_attention: %d query heads do not divide over %d "
+            "key/value heads" % (q.shape[head_axis], k.shape[head_axis]))
+    return (jnp.repeat(k, group, axis=head_axis),
+            jnp.repeat(v, group, axis=head_axis))
+
+
+def _attention_bthd(q, k, v, lengths, causal, scale, dropout_rate, block_k,
+                    rng):
+    """The (B, T, H, Dh) dispatch: the zero-transpose Pallas kernels at
+    a lane-aligned head, ``_attention_bhtd`` around two transposes
+    otherwise."""
+    k, v = _repeat_kv(q, k, v, 2)
+    t, tk, d_head = q.shape[1], k.shape[1], q.shape[-1]
+    if d_head % 128 == 0 and _use_pallas(t, tk, lengths, dropout_rate):
+        kern = functools.partial(
+            pallas_flash_attention_bthd, causal=causal, scale=scale,
+            block_q=_env_block("PADDLE_TPU_FLASH_BQ", 512),
+            block_k=_env_block("PADDLE_TPU_FLASH_BK", block_k))
+        return _per_shard(kern, q, k, v, head_dim=2)
+    out = _attention_bhtd(
+        jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
+        jnp.swapaxes(v, 1, 2), lengths, causal, scale, dropout_rate,
+        block_k, rng)
+    return jnp.swapaxes(out, 1, 2)
+
+
+def causal_attention_bthd(q, k, v, scale=None, block_k=512):
+    """Causal attention of a prefill over (B, T, H, Dh) queries and (B,
+    T, Hkv, Dh) keys and values, as ``fused_attention`` dispatches it
+    at ``layout="bthd"``: for the ops that build on it."""
+    return _attention_bthd(q, k, v, None, True, scale, 0.0, block_k, None)
 
 
 def _attention_bhtd(q, k, v, lengths, causal, scale, dropout_rate, block_k,

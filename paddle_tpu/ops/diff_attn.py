@@ -1,0 +1,404 @@
+"""Differential attention (arXiv:2410.05258) for serving, and the gated
+memory unit of a decoder-hybrid-decoder (arXiv:2507.06607).
+
+A differential layer splits its ``H`` query heads of ``dh`` into pairs
+``(2j, 2j + 1)`` and its ``Hkv`` key/value heads into pairs ``(2p, 2p +
+1)``; query pair ``j`` reads key/value pair ``p = j // g`` (``g = H /
+Hkv``). With ``A1 = softmax(q_2j . k_2p / sqrt(dh))`` and ``A2 =
+softmax(q_2j+1 . k_2p+1 / sqrt(dh))`` under the layer's mask,
+
+    O_j = (A1 - lam * A2) [v_2p | v_2p+1]                    (2 dh wide)
+    O_j <- rms_norm(O_j; gain, eps) * (1 - lam_init)
+    lam = exp(lq1 . lk1) - exp(lq2 . lk2) + lam_init
+
+So query head ``2j`` and ``2j + 1`` do NOT share a key head, and both
+read both value heads: no grouped-query kernel computes it as it
+stands. THE PAIR LAYOUT makes it one: a key/value row is read as ``P =
+Hkv / 2`` pair-heads of ``2 dh`` (the same ``Hkv * dh`` floats in the
+same order: a view, never a copy of a slab), and a query head's ``dh``
+values are laid into the half of a ``2 dh`` row that its key lies in,
+zeros in the other half (``pair_queries``). Then ``q'_h . K_p = q_h .
+k_(2p + h % 2)`` exactly (the zeros add nothing), the value of
+pair-head ``p`` IS ``[v_2p | v_2p+1]``, and query head ``h`` reads
+pair-head ``h // (2 g)``: grouped queries at ``2 dh``. In a prefill
+the kernels the repo has compute that (the causal flash kernel and the
+window kernel, lane-aligned at ``dh`` = 64). The only cost is the zero
+half of the score product, 1.33x an attention's FLOPs and no byte of a
+slab. What is left is a subtraction and a norm (``diff_combine``).
+
+Slabs and rings keep a position's row FLAT: ``(B, S, Hkv * dh)``, the
+projection's own output, heads in order, so pair-head ``p`` is the
+columns ``[2 dh p, 2 dh (p + 1))``. On a TPU a 4-D slab ``(B, S, P, 2
+dh)`` whose heads do not fill a sublane tile (``P`` = 10) is laid out
+heads-major by the compiler, and the one-row append then costs two
+relayout copies of the WHOLE slab a step (compiled for a described
+v5e: four 1.25 GiB copies, 2 GiB of temporaries); a flat row is a
+multiple of 128 lanes wide, the append is a row scatter in place, and a
+pair-head's columns are a lane-aligned slice that the score and value
+products read where they lie. Two paths attend such rows, chosen by
+shape, dtype and device (``rows_block_rows``), numerics one: the
+Pallas kernel ``ptpu.diff_attn_rows`` over a slab (float32 on a TPU:
+one grid cell a (slot, sequence block), the block's pair-heads picked
+by lane slices, the lengths scalar-prefetched so that dead blocks are
+neither fetched nor computed, two passes so that the products round
+what the lax path's round, as ``kv_cache._decode_attn_grouped_kernel``);
+and ``_attend_rows_lax``, exact and pure lax, one product pair a
+pair-head, which reads the whole slab whatever the lengths: every
+other device, and a ring (one block a slot and nearly all of it live:
+the kernel has no dead rows to skip there; PERF.md, PR 32).
+
+Four ops, one scope each:
+
+- ``diff_attention`` (``ptpu.diff_attn``): a prefill's whole sequence,
+  causal or causal within a window.
+- ``diff_decode_attention`` (``ptpu.diff_attn_slab`` |
+  ``ptpu.diff_attn_ring``): one token against the layer's own slab or
+  ring of flat rows, after this step's row was written.
+- ``attn_cross`` (``ptpu.attn_cross``): one query row against ANOTHER
+  layer's keys and values (a slab in a step, a prompt's rows in a
+  prefill), to which it appends nothing.
+- ``gmu`` (``ptpu.gmu``): ``(M * silu(u W_in)) W_out``, ``M`` the
+  memory a state-space layer handed on, row for row.
+"""
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import attention as _A
+from . import kv_cache as _KV
+from .registry import register_op
+from .ssm import rms_norm
+
+DIFF_ATTN = "ptpu.diff_attn"
+DIFF_ATTN_SLAB = "ptpu.diff_attn_slab"
+DIFF_ATTN_RING = "ptpu.diff_attn_ring"
+ATTN_CROSS = "ptpu.attn_cross"
+GMU = "ptpu.gmu"
+# the decode kernel over a slab of flat rows: its call's name in lowered
+# text and device traces
+DIFF_ATTN_ROWS = "ptpu.diff_attn_rows"
+
+_NEG = -1e30
+
+
+def lambda_init(depth: int) -> float:
+    """``lam_init`` of the layer at ``depth`` (0-based)."""
+    return 0.8 - 0.6 * math.exp(-0.3 * depth)
+
+
+def diff_lambda(lq1, lk1, lq2, lk2, lam_init):
+    """The layer's one ``lam``, float32."""
+    f32 = jnp.float32
+    return (jnp.exp(jnp.sum(lq1.astype(f32) * lk1.astype(f32)))
+            - jnp.exp(jnp.sum(lq2.astype(f32) * lk2.astype(f32)))
+            + lam_init)
+
+
+def pair_queries(q):
+    """q (B, T, H, dh) -> (B, T, H, 2 dh): head ``h``'s values in half
+    ``h % 2`` of its row, zeros in the other."""
+    zero = jnp.zeros_like(q)
+    even = (jnp.arange(q.shape[2]) % 2 == 0)[None, None, :, None]
+    return jnp.where(even, jnp.concatenate([q, zero], axis=-1),
+                     jnp.concatenate([zero, q], axis=-1))
+
+
+def diff_combine(ctx, lam, gain, lam_init, eps):
+    """ctx (B, T, H, 2 dh), head ``h``'s softmax over its pair's values
+    -> (B, T, H / 2, 2 dh): ``ctx[2j] - lam * ctx[2j + 1]``, normalised
+    by its own RMS times ``gain``, times ``1 - lam_init``."""
+    b, t, h, w = ctx.shape
+    both = ctx.reshape(b, t, h // 2, 2, w)
+    out = both[:, :, :, 0] - lam.astype(ctx.dtype) * both[:, :, :, 1]
+    return rms_norm(out, gain, eps) * jnp.asarray(1.0 - lam_init, ctx.dtype)
+
+
+def _check(q, k, v):
+    """(pair-heads P, 1 / sqrt(dh)) of queries (B, T, H, dh) on flat
+    key/value rows (B, S, Hkv dh)."""
+    h, dh = q.shape[2], q.shape[3]
+    pairs, rem = divmod(k.shape[-1], 2 * dh)
+    if (k.ndim != 3 or k.shape != v.shape or rem or not pairs
+            or h % (2 * pairs)):
+        raise ValueError(
+            "differential attention: %d query heads of %d need flat "
+            "key/value rows (B, S, Hkv x %d) of an even Hkv whose pairs "
+            "divide the query pairs; got K %s V %s"
+            % (h, dh, dh, k.shape, v.shape))
+    return pairs, 1.0 / math.sqrt(dh)
+
+
+def rows_block_rows(s, h, row, dtype, block_s=512):
+    """Sequence rows per block of the kernel over (B, s, row) flat rows
+    of ``dtype`` under ``h`` paired query heads, or None where the lax
+    path attends them: a type that is not 32 bits wide, scores that do
+    not fit beside the blocks, no block that divides ``s``."""
+    if (jnp.dtype(dtype).itemsize != 4 or row % 128
+            or h * s * 4 > _KV._GROUPED_SCORE_BYTES):
+        return None
+    return _KV.fit_block_rows(
+        s, min(block_s, _KV._INPLACE_BLOCK_BYTES // (row * 4)))
+
+
+def decode_stream_rows(s, h, row, dtype, block_s=512):
+    """Rows a block of the slab's attention brings in on the device a
+    step traced now is bound for, or None where it reads whole slabs
+    (the lax path): what ``kv_cache.decode_stream_rows`` answers for a
+    slab of heads."""
+    if not _KV._use_pallas_decode(s, row):
+        return None
+    return rows_block_rows(s, h, row, dtype, block_s)
+
+
+def _rows_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, s_ref, m_ref, l_ref,
+                 acc_ref, *, block_s, n_pair, group, width, n_blk):
+    """One (slot, step) grid cell, 2 * n_blk steps a slot, over blocks
+    (1, BS, P w) of the slab itself: pair-head p's keys and values are
+    the lanes [p w, (p + 1) w) of a block, and the ``group`` paired
+    query rows that read it (rows [p g, (p + 1) g)) meet them in one (g,
+    w) x (w, BS) product. Two passes, as
+    ``kv_cache._decode_attn_grouped_kernel`` and for its reason (the
+    NORMALISED weights are what the MXU rounds): steps [0, n_blk) stream
+    K into the scores ``s_ref`` (H, S) and the running maximum, step
+    n_blk sums the weights, steps [n_blk, 2 n_blk) stream V. K's index
+    stops at the slot's last live block and V's waits at block 0
+    meanwhile: a live block is copied once, a dead one never."""
+    j = pl.program_id(1)
+    length = len_ref[pl.program_id(0)]
+    live_blocks = (length + block_s - 1) // block_s
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when(j < live_blocks)
+    def _():
+        col0 = pl.multiple_of(j * block_s, block_s)
+        for p in range(n_pair):
+            hh = slice(p * group, (p + 1) * group)
+            s = jnp.dot(q_ref[0, 0, hh, :],
+                        k_ref[0, :, p * width:(p + 1) * width].T,
+                        preferred_element_type=jnp.float32)   # (g, BS)
+            live = col0 + lax.broadcasted_iota(jnp.int32, s.shape, 1) < length
+            s = jnp.where(live, s, _NEG)
+            s_ref[hh, pl.ds(col0, block_s)] = s
+            m_ref[hh, :] = jnp.maximum(m_ref[hh, :],
+                                       jnp.max(s, axis=1, keepdims=True))
+
+    @pl.when(j == n_blk)
+    def _():
+        def add(i, l):
+            s = s_ref[:, pl.ds(pl.multiple_of(i * block_s, block_s), block_s)]
+            return l + jnp.sum(jnp.exp(s - m_ref[...]), axis=1, keepdims=True)
+
+        l_ref[...] = lax.fori_loop(0, live_blocks, add,
+                                   jnp.zeros(l_ref.shape, jnp.float32))
+
+    @pl.when((j >= n_blk) & (j - n_blk < live_blocks))
+    def _():
+        col0 = pl.multiple_of((j - n_blk) * block_s, block_s)
+        for p in range(n_pair):
+            hh = slice(p * group, (p + 1) * group)
+            w = (jnp.exp(s_ref[hh, pl.ds(col0, block_s)] - m_ref[hh, :])
+                 / jnp.maximum(l_ref[hh, :], 1e-30))
+            acc_ref[hh, :] += jnp.dot(
+                w, v_ref[0, :, p * width:(p + 1) * width],
+                preferred_element_type=jnp.float32)
+
+    @pl.when(j == 2 * n_blk - 1)
+    def _():
+        o_ref[0, 0] = acc_ref[...].astype(o_ref.dtype)
+
+
+def pallas_attend_rows(qp, k, v, lengths, scale, block_s=512,
+                       interpret=False):
+    """``_attend_rows_lax``'s contract through the kernel: the slab is
+    handed over as it lies, a (1, rows, P w) block a step."""
+    b, _, h, w = qp.shape
+    s, row = k.shape[1], k.shape[2]
+    rows = rows_block_rows(s, h, row, k.dtype, block_s)
+    if rows is None:
+        raise ValueError(
+            "no kernel for %d paired query heads on (%d, %d) %s rows; the "
+            "lax path attends them" % (h, s, row, jnp.dtype(k.dtype).name))
+    n_blk, pairs = s // rows, row // w
+    lens = lengths.reshape(-1).astype(jnp.int32)
+
+    def last(bi, lens_ref):
+        return jnp.maximum(lens_ref[bi] + rows - 1, rows) // rows - 1
+
+    def k_block(bi, j, lens_ref):
+        # past the slot's last live block: the same block again
+        return bi, jnp.minimum(j, last(bi, lens_ref)), 0
+
+    def v_block(bi, j, lens_ref):
+        # block 0 while K streams, then as K's
+        return bi, jnp.clip(j - n_blk, 0, last(bi, lens_ref)), 0
+
+    def qo_block(bi, j, lens_ref):
+        return bi, 0, 0, 0
+
+    kernel = functools.partial(_rows_kernel, block_s=rows, n_pair=pairs,
+                               group=h // pairs, width=w, n_blk=n_blk)
+    return _A.named_pallas_call(
+        DIFF_ATTN_ROWS, kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, 2 * n_blk),
+            in_specs=[
+                pl.BlockSpec((1, 1, h, w), qo_block),
+                pl.BlockSpec((1, rows, row), k_block),
+                pl.BlockSpec((1, rows, row), v_block),
+            ],
+            out_specs=pl.BlockSpec((1, 1, h, w), qo_block),
+            scratch_shapes=[pltpu.VMEM((h, s), jnp.float32),
+                            pltpu.VMEM((h, 1), jnp.float32),
+                            pltpu.VMEM((h, 1), jnp.float32),
+                            pltpu.VMEM((h, w), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, 1, h, w), qp.dtype),
+        interpret=interpret,
+        **_A._tpu_params("parallel", "arbitrary"),
+    )(lens, qp * jnp.asarray(scale, qp.dtype), k, v)
+
+
+def _attend_rows(qp, k, v, lengths, scale, ring=False):
+    """Paired queries qp (B, 1, H, w) on flat rows k, v (B, S, P w), rows
+    ``[0, lengths)`` seen -> (B, 1, H, w): the kernel where the rows'
+    shape, type and the device allow it and the rows are a slab's, the
+    lax path otherwise."""
+    s, row = k.shape[1], k.shape[2]
+    if not ring and decode_stream_rows(s, qp.shape[2], row,
+                                       k.dtype) is not None:
+        return pallas_attend_rows(qp, k, v, lengths, scale)
+    return _attend_rows_lax(qp, k, v, lengths, scale)
+
+
+def _attend_rows_lax(qp, k, v, lengths, scale):
+    """``_attend_rows``, exact and pure lax, the arithmetic of
+    ``kv_cache.decode_attention_reference``: a pair-head at a time over
+    its lane-aligned columns. A slot of length 0 gives zeros."""
+    b, _, h, w = qp.shape
+    s, pairs = k.shape[1], k.shape[2] // w
+    f32 = jnp.float32
+    qf = (qp[:, 0].astype(f32) * scale).reshape(b, pairs, h // pairs, w)
+    valid = (jnp.arange(s)[None, None, :]
+             < lengths.reshape(-1).astype(jnp.int32)[:, None, None])
+    out = []
+    for p in range(pairs):
+        kp = lax.slice_in_dim(k, p * w, (p + 1) * w, axis=2).astype(f32)
+        vp = lax.slice_in_dim(v, p * w, (p + 1) * w, axis=2).astype(f32)
+        sc = jnp.where(valid, jnp.einsum("bgd,bsd->bgs", qf[:, p], kp),
+                       _NEG)
+        m = jnp.max(sc, axis=-1, keepdims=True)
+        pr = jnp.where(valid, jnp.exp(sc - m), 0.0)
+        l = jnp.sum(pr, axis=-1, keepdims=True)
+        out.append(jnp.einsum("bgs,bsd->bgd", pr / jnp.maximum(l, 1e-30),
+                              vp))
+    return jnp.stack(out, axis=1).reshape(b, 1, h, w).astype(qp.dtype)
+
+
+def diff_attention(q, k, v, lam, gain, lam_init, window=0, eps=1e-5):
+    """A whole sequence: q (B, T, H, dh), k/v (B, T, Hkv dh) flat rows
+    -> (B, T, H / 2, 2 dh). Key j is visible to query t iff j <= t, and
+    with ``window`` also j > t - window."""
+    pairs, scale = _check(q, k, v)
+    with jax.named_scope(DIFF_ATTN):
+        qp = pair_queries(q)
+        # a prefill's rows are activations: their pair view costs what
+        # a reshape of a few megabytes costs
+        k = k.reshape(k.shape[:2] + (pairs, -1))
+        v = v.reshape(v.shape[:2] + (pairs, -1))
+        if window:
+            ctx = _A.attn_window(qp, k, v, int(window), scale=scale)
+        else:
+            ctx = _A.causal_attention_bthd(qp, k, v, scale=scale)
+        return diff_combine(ctx, lam, gain, lam_init, eps)
+
+
+def diff_decode_attention(q, k_cache, v_cache, lengths, lam, gain, lam_init,
+                          ring=False, eps=1e-5, scope=None):
+    """One token: q (B, 1, H, dh) against a slab (B, S, Hkv dh), or a
+    ring (B, W, Hkv dh) with ``ring``, of flat rows; ``lengths`` (B,)
+    the positions held INCLUDING the row this query may see last (a
+    ring that has wrapped shows all its rows: a softmax does not care
+    for the order of its keys). -> (B, 1, H / 2, 2 dh)."""
+    _, scale = _check(q, k_cache, v_cache)
+    with jax.named_scope(scope or (DIFF_ATTN_RING if ring
+                                   else DIFF_ATTN_SLAB)):
+        seen = lengths.reshape(-1).astype(jnp.int32)
+        if ring:
+            seen = jnp.minimum(seen, k_cache.shape[1])
+        ctx = _attend_rows(pair_queries(q), k_cache, v_cache, seen, scale,
+                           ring=ring)
+        return diff_combine(ctx, lam, gain, lam_init, eps)
+
+
+def attn_cross(q, k, v, lengths, lam, gain, lam_init, eps=1e-5):
+    """``diff_decode_attention`` over keys and values another layer
+    owns: a slab (B, S, Hkv dh) in a step, a prompt's rows (B, T, Hkv
+    dh) in a prefill; rows ``[0, lengths)`` are seen."""
+    return diff_decode_attention(q, k, v, lengths, lam, gain, lam_init,
+                                 eps=eps, scope=ATTN_CROSS)
+
+
+def gmu(u, memory, w_in, w_out):
+    """u (B, T, D), memory (B, T, Di), w_in (D, Di), w_out (Di, D) ->
+    (B, T, D): ``(memory * silu(u w_in)) w_out``."""
+    with jax.named_scope(GMU):
+        g = jnp.matmul(u, w_in)
+        return jnp.matmul(memory * (g * jax.nn.sigmoid(g)), w_out)
+
+
+def _lam(ctx):
+    return diff_lambda(ctx.input("LQ1"), ctx.input("LK1"), ctx.input("LQ2"),
+                       ctx.input("LK2"), float(ctx.attr("lam_init")))
+
+
+@register_op("diff_attention")
+def _diff_attention_op(ctx):
+    """Inputs Q (B, T, H, dh), K, V (B, T, Hkv dh), LQ1, LK1, LQ2, LK2
+    (dh,), Gain (2 dh,); attrs lam_init, window (0: causal), epsilon ->
+    Out (B, T, H / 2, 2 dh)."""
+    return {"Out": diff_attention(
+        ctx.input("Q"), ctx.input("K"), ctx.input("V"), _lam(ctx),
+        ctx.input("Gain"), float(ctx.attr("lam_init")),
+        window=int(ctx.attr("window", 0) or 0),
+        eps=float(ctx.attr("epsilon", 1e-5)))}
+
+
+@register_op("diff_decode_attention")
+def _diff_decode_attention_op(ctx):
+    """Inputs Q (B, 1, H, dh), KCache, VCache (B, S | W, Hkv dh),
+    Lengths (B,), the four lambda vectors, Gain; attrs lam_init, ring,
+    epsilon -> Out (B, 1, H / 2, 2 dh)."""
+    return {"Out": diff_decode_attention(
+        ctx.input("Q"), ctx.input("KCache"), ctx.input("VCache"),
+        ctx.input("Lengths"), _lam(ctx), ctx.input("Gain"),
+        float(ctx.attr("lam_init")), ring=bool(ctx.attr("ring", False)),
+        eps=float(ctx.attr("epsilon", 1e-5)))}
+
+
+@register_op("attn_cross")
+def _attn_cross_op(ctx):
+    """As ``diff_decode_attention`` without ``ring``; K and V are
+    another layer's."""
+    return {"Out": attn_cross(
+        ctx.input("Q"), ctx.input("KCache"), ctx.input("VCache"),
+        ctx.input("Lengths"), _lam(ctx), ctx.input("Gain"),
+        float(ctx.attr("lam_init")), eps=float(ctx.attr("epsilon", 1e-5)))}
+
+
+@register_op("gmu")
+def _gmu_op(ctx):
+    """Inputs X (B, T, D), Memory (B, T, Di), WIn (D, Di), WOut (Di, D)
+    -> Out = X's shape."""
+    return {"Out": gmu(ctx.input("X"), ctx.input("Memory"),
+                       ctx.input("WIn"), ctx.input("WOut"))}

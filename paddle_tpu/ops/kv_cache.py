@@ -296,7 +296,13 @@ def decode_block_rows(s, h, d, dtype, block_s=512):
     data"), so they keep the per-head kernel."""
     if jnp.dtype(dtype).itemsize != 4 or h % 8:
         return None
-    want = min(block_s, s, _INPLACE_BLOCK_BYTES // (h * d * 4))
+    return fit_block_rows(s, min(block_s, _INPLACE_BLOCK_BYTES // (h * d * 4)))
+
+
+def fit_block_rows(s, want):
+    """The largest power of two of rows, at least 8 and at most ``want``
+    (and ``s``), that divides ``s``; None where none does."""
+    want = min(want, s)
     rows = 8
     while rows * 2 <= want:
         rows *= 2

@@ -58,6 +58,7 @@ from .. import observability as obs
 from ..observability import tracing as _tracing
 from ..runtime import aot_cache as _aot
 from ..framework.scope import current_device
+from ..ops import diff_attn as _DA
 from ..ops import kv_cache as _KV
 from ..runtime import recordio as _rio
 
@@ -153,7 +154,17 @@ class DecodeConfig:
     normalised input) and ``rope`` ({"full" | "sliding": {rotary_dim,
     theta, attention_factor, yarn}}: rotary positions by layer kind).
     The output head is its own matrix where ``tie_embeddings`` is
-    false."""
+    false. ``layer_types`` names every layer's mixer outright where the
+    kinds are no period and offset ("mamba" | "attention" | "sliding" |
+    "gmu" | "cross"): a ``gmu`` layer gates the MEMORY (the scan's
+    output before its gate) of the nearest state-space layer before it,
+    a ``cross`` layer attends the keys and values of the nearest
+    full-attention layer before it, and neither owns a cache entry;
+    ``diff_attn`` makes every attention differential (``ops/
+    diff_attn.py``: slabs and rings then keep a position's row FLAT,
+    ``n_kv_head * d_head`` floats); ``attn_biases`` puts a bias
+    on the attention projections alone; ``mamba_norms`` (Jamba's RMS
+    norms on delta, B and C) is true unless a manifest says otherwise."""
 
     FIELDS = ("vocab_size", "n_layer", "n_head", "d_model", "d_inner",
               "max_len", "tie_embeddings", "prefix", "eos_id")
@@ -171,7 +182,10 @@ class DecodeConfig:
                    ("expert_top_k", 0), ("d_expert", 0),
                    ("d_shared_expert", 0), ("experts_held", None),
                    ("router_score", "sigmoid"), ("router_scale", 1.0),
-                   ("attn_gate", None), ("rope", None))
+                   ("attn_gate", None), ("rope", None),
+                   ("layer_types", None), ("diff_attn", False),
+                   ("attn_biases", False), ("mamba_norms", True))
+    MIXERS = ("mamba", "attention", "sliding", "gmu", "cross")
 
     def __init__(self, vocab_size, n_layer=4, n_head=8, d_model=512,
                  d_inner=2048, max_len=2048, tie_embeddings=False,
@@ -198,7 +212,8 @@ class DecodeConfig:
             raise ValueError(
                 "attn_layer_offset %r is not a layer of a period of %r"
                 % (self.attn_layer_offset, self.attn_layer_period))
-        for f in ("n_head_by_layer", "attn_types", "ffn_types"):
+        for f in ("n_head_by_layer", "attn_types", "ffn_types",
+                  "layer_types"):
             per_layer = getattr(self, f)
             if per_layer is not None:
                 per_layer = list(per_layer)[:self.n_layer]
@@ -223,8 +238,22 @@ class DecodeConfig:
                     "n_expert, d_expert and experts_held within them; got "
                     "%r, %r, %r, %r" % (self.n_expert, self.expert_top_k,
                                         self.d_expert, self.experts_held))
-        if "sliding" in (self.attn_types or ()) and not self.window:
+        kinds = self.layer_kinds()
+        if "sliding" in kinds and not self.window:
             raise ValueError("a sliding attention layer needs a window")
+        for i, kind in enumerate(kinds):
+            if kind not in self.MIXERS:
+                raise ValueError("layer_types[%d] = %r is none of %s"
+                                 % (i, kind, ", ".join(self.MIXERS)))
+            need = {"gmu": "mamba", "cross": "attention"}.get(kind)
+            if need and need not in kinds[:i]:
+                raise ValueError(
+                    "layer %d (%s) reads what a %s layer before it hands "
+                    "on, and none is" % (i, kind, need))
+        if self.diff_attn and self.n_kv_head % 2:
+            raise ValueError(
+                "differential attention pairs its heads: %d key/value "
+                "heads do not pair" % self.n_kv_head)
 
     @property
     def d_head(self) -> int:
@@ -248,8 +277,31 @@ class DecodeConfig:
     def mamba_d_inner(self) -> int:
         return int(self.mamba_expand) * self.d_model
 
+    @property
+    def kv_row(self):
+        """Shape of one position's row of a slab or a ring: (heads,
+        width), or FLAT under differential attention (the same floats
+        in the same order; ``ops/diff_attn.py`` says why)."""
+        if self.diff_attn:
+            return (self.n_kv_head * self.d_head,)
+        return self.n_kv_head, self.d_head
+
+    @property
+    def tail_start(self) -> int:
+        """The first layer from which on no layer owns a cache entry
+        (``n_layer`` where the last layer owns one): a prefill runs the
+        layers from here on each prompt's LAST row alone."""
+        kinds = self.layer_kinds()
+        i = len(kinds)
+        while i and kinds[i - 1] in ("gmu", "cross"):
+            i -= 1
+        return i
+
     def layer_kinds(self) -> List[str]:
-        """"attention" | "sliding" | "mamba" for each layer."""
+        """The mixer of each layer: "attention" | "sliding" | "mamba",
+        and where ``layer_types`` names them "gmu" | "cross" too."""
+        if self.layer_types:
+            return list(self.layer_types)
         kinds = ["attention" if i % self.attn_layer_period
                  == self.attn_layer_offset else "mamba"
                  for i in range(self.n_layer)]
@@ -346,7 +398,10 @@ def cache_spec(config: DecodeConfig, slots: int, seq: int,
     (slots, seq, n_kv_head, d_head), a sliding-window layer's two
     rings ``kring_i``, ``vring_i`` (slots, window, n_kv_head, d_head)
     whatever ``seq``, a Mamba layer's ``conv_i`` (slots, K - 1,
-    d_inner) window and ``ssm_i`` (slots, d_inner, N) state, SORTED BY
+    d_inner) window and ``ssm_i`` (slots, d_inner, N) state, NOTHING for
+    a ``gmu`` or a ``cross`` layer (it reads what another layer keeps;
+    a slab may so have several readers a step); a slab's or a ring's
+    row is ``config.kv_row``, flat under differential attention; SORTED BY
     NAME: the order a dict of feeds flattens in, so that a
     donated feed pairs with its own updated output and a step compiles
     with no pairing copy (PERF.md 7a is what happens otherwise)."""
@@ -360,10 +415,11 @@ def cache_spec(config: DecodeConfig, slots: int, seq: int,
             "(%s) are float32"
             % (kv_dtype, ", ".join(sorted(set(
                 {"attention": "rows", "sliding": "ring",
-                 "mamba": "state"}[k] for k in config.layer_kinds())))))
+                 "mamba": "state"}.get(k, "none")
+                for k in config.layer_kinds())))))
     from ..models.jamba import cache_names
 
-    slab = (slots, seq, config.n_kv_head, config.d_head)
+    slab = (slots, seq) + config.kv_row
     out = []
     for i, kind in enumerate(config.layer_kinds()):
         names = cache_names(kind, i)
@@ -376,9 +432,10 @@ def cache_spec(config: DecodeConfig, slots: int, seq: int,
                            config.mamba_d_state), "float32", False))
             continue
         if kind == "sliding":
-            ring = (slots, int(config.window), config.n_kv_head,
-                    config.d_head)
+            ring = (slots, int(config.window)) + config.kv_row
             out += [CacheEntry(n, ring, "float32", False) for n in names]
+            continue
+        if not names:  # reads another layer's entries, owns none
             continue
         out += [CacheEntry(n, slab, kv_dtype, True) for n in names]
         if kv_dtype == "int8":
@@ -1549,10 +1606,22 @@ class DecodeServer:
         if self.kv_dtype == "float32" and not self.speculative:
             full = [cfg.heads(i) for i, k in enumerate(cfg.layer_kinds())
                     if k == "attention"]
+            heads = max(full, default=cfg.n_head)
             with jax.default_device(predictor._device):  # as acquire()
-                self._stream_rows = _KV.decode_stream_rows(
-                    self.seq, cfg.n_kv_head, cfg.d_head, jnp.float32,
-                    q_heads=max(full, default=cfg.n_head))
+                if cfg.diff_attn:  # a slab of flat rows has its own path
+                    self._stream_rows = _DA.decode_stream_rows(
+                        self.seq, heads, cfg.kv_row[0], jnp.float32)
+                else:
+                    self._stream_rows = _KV.decode_stream_rows(
+                        self.seq, cfg.n_kv_head, cfg.d_head, jnp.float32,
+                        q_heads=heads)
+        # layers that attend ONE shared slab in a step: its owner and
+        # the cross layers after it (0: every slab has one reader)
+        kinds = cfg.layer_kinds()
+        self._slab_readers = (1 + kinds.count("cross")
+                              if "cross" in kinds else 0)
+        # a prefill runs the layers from here on one row a prompt
+        self._has_tail = cfg.tail_start < cfg.n_layer
 
     # -- submission (PredictorServer-compatible surface) -------------------
     def submit(self, sample: Sequence[np.ndarray]):
@@ -1742,9 +1811,13 @@ class DecodeServer:
                               kind="prefill")
         return outs, sp
 
-    # a model without sliding-window or expert layers has neither
+    # a model without sliding-window or expert layers has neither, and
+    # one whose every layer owns its cache entries no shared slab's
+    # readers and no one-row tail of a prefill
     _ring_window = 0
     _moe_layers = ()
+    _slab_readers = 0
+    _has_tail = False
 
     # prompts one admission prefills at most, while sequences are live,
     # and the bucketed tokens (power-of-two batch x the prompts' bucket)
@@ -1854,7 +1927,11 @@ class DecodeServer:
         they ride here and not on ``admit``): ``ring_rows``, the rows
         it leaves in a sliding-window layer's rings (each prompt's last
         ``min(len, window)``), and ``expert_pairs``, the token-expert
-        pairs it routed to held experts, all sparse layers."""
+        pairs it routed to held experts, all sparse layers. Of a model
+        whose last layers own no cache entry, ``prompt_rows``, the real
+        prompt rows the prefill walked, and ``tail_rows``, the rows
+        those last layers ran on: one a prompt
+        (``DecodeConfig.tail_start``)."""
         counts = {"entries": len(self._spec),
                   "state_slots": n if self._state_bytes_per_slot else 0}
         if self._ring_window:
@@ -1862,6 +1939,9 @@ class DecodeServer:
                 min(len(p), self._ring_window) for p in prompts)
         if self._moe_layers:
             counts["expert_pairs"] = self._moe_last["expert_pairs"]
+        if self._has_tail:
+            counts["prompt_rows"] = sum(len(p) for p in prompts)
+            counts["tail_rows"] = len(prompts)
         return counts
 
     def _note_load(self, load):
@@ -2206,7 +2286,11 @@ class DecodeServer:
         experts and the (layer, expert) that received any, all sparse
         layers, of the LAST step whose ``moe_load`` the host has read
         (with a step in flight, the one dispatched two before this:
-        the loads ride back with the ids and are never waited for)."""
+        the loads ride back with the ids and are never waited for).
+        Of a model with cross layers, ``slab_readers``: the layers that
+        attend the ONE shared slab in this step (``attended`` and
+        ``streamed`` count its rows once; its bytes are rows x
+        readers)."""
         rows = self._stream_rows
         streamed = (self.slots * self.seq if rows is None
                     else int((lens // rows + 1).sum()) * rows)
@@ -2219,6 +2303,8 @@ class DecodeServer:
                 lens[lens > 0] + 1, self._ring_window).sum())
         if self._moe_layers:
             counts.update(self._moe_last)
+        if self._slab_readers:
+            counts["slab_readers"] = self._slab_readers
         return counts
 
     def _spec_round(self, drexe, vexe, caches, lens, active, n_active):

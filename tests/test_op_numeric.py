@@ -662,6 +662,10 @@ COVERED_ELSEWHERE = {
     # shares of an expert-parallel deployment, infer rules)
     "rope", "moe_route", "moe_experts", "moe_shared", "attn_window",
     "ring_append", "ring_pack", "decode_attn_ring",
+    # differential attention (full, windowed, one token over a slab and
+    # over a wrapped ring, cross) and the gated memory unit against
+    # plain statements of them: tests/test_diff_attn_ops.py
+    "diff_attention", "diff_decode_attention", "attn_cross", "gmu",
     # in-graph sampling: tests/test_sampling_ops.py
     "greedy_sample", "top_k_sample", "top_p_sample",
     # metrics: tests/test_aux.py

@@ -558,3 +558,116 @@ def test_laguna_serving_step_compiles(one_chip, monkeypatch, kind, batch,
                   if op == "copy"]
         assert not copies, (shape, copies)
     assert mem.temp_size_in_bytes < 1.5 * 2**30, mem.temp_size_in_bytes
+
+
+_PHI4FLASH_CASES = [
+    # id, kind, batch, seq: the Phi-4-mini-flash serving cell's own
+    # programs (benchmark/configs/phi4-mini-flash.json: 16 layers at
+    # published widths, 64 slots of 4096 positions; the largest
+    # admission is 8 prompts of the 1024 bucket)
+    ("decode-64x4096", "decode", 64, 4096),
+    ("prefill-8x1024", "prefill", 8, 1024),
+]
+
+
+@pytest.mark.parametrize("kind,batch,seq",
+                         [c[1:] for c in _PHI4FLASH_CASES],
+                         ids=[c[0] for c in _PHI4FLASH_CASES])
+def test_phi4flash_serving_step_compiles(one_chip, monkeypatch, kind, batch,
+                                         seq):
+    """The programs DecodePredictor builds for the Phi-4-mini-flash cell
+    (Mamba and sliding layers, the memory's Mamba, ONE full layer, gated
+    memory units and cross layers; differential attention over 40 query
+    heads on 20 key/value heads of 64): they compile for a v5e and fit
+    it beside each other. The decode step donates the one slab, the
+    four rings and the five states and gets each back in place: flat
+    rows, so NO whole-slab copy or relayout for the one-row append (a
+    4-D slab of 10 pair-heads cost four 1.25 GiB copies a step), and a
+    few tens of MB of temporaries; no Mosaic call (the lax paths). The
+    largest admission holds one attention kernel a layer that owns keys
+    (four `ptpu.attn_window`, one flash forward: the cross layers run
+    one query row a prompt) and five scans."""
+    import json
+
+    from paddle_tpu.serving.decode import DecodePredictor
+
+    sys_path = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, sys_path)
+    if os.path.join(sys_path, "benchmark") not in list(getattr(
+            sys.modules.get("benchmark"), "__path__", [])):
+        import types
+
+        sys.modules["benchmark"] = types.ModuleType("benchmark")
+        sys.modules["benchmark"].__path__ = [
+            os.path.join(sys_path, "benchmark")]
+    from benchmark.models import phi4flash_lm
+
+    with open(os.path.join(sys_path, "benchmark", "configs",
+                           "phi4-mini-flash.json")) as f:
+        cfg = json.load(f)
+    monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
+    monkeypatch.setattr(
+        KV, "_use_pallas_decode",
+        lambda s, d: d % 128 == 0 and s % 128 == 0 and s >= 128)
+    pred = DecodePredictor.__new__(DecodePredictor)  # graph builder only
+    pred.config = phi4flash_lm.decode_config(cfg, "serve")
+    pred.sample_k, pred.sample_p, pred.temperature = 40, 0.9, 1.0
+    step_fn, feeds, state, n_cache = _serving_step(pred, kind, batch, seq,
+                                                   one_chip)
+    compiled = jax.jit(step_fn, donate_argnums=(0,)).lower(
+        feeds, state).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < HBM_BYTES, "does not fit one chip: %r" % (mem,)
+    weights = sum(int(np.prod(s.shape)) * 4 for s in state.values())
+    assert 8.7e9 < weights < 8.85e9, weights  # 2.193 B parameters
+    text = compiled.as_text()
+    slabs = sum(e.nbytes for e in pred.cache_spec(64, 4096))
+    if kind == "prefill":
+        calls = re.findall(r"%([\w.-]+?)(?:\.\d+)? = [^\n]*"
+                           r'custom_call_target="tpu_custom_call"', text)
+        assert calls.count("ptpu.attn_window") == 4, calls
+        assert calls.count("ptpu.flash_fwd") == 1, calls
+        # a prefill's cross layers: one query row a prompt on its rows
+        assert calls.count("ptpu.diff_attn_rows") == 3, calls
+        assert text.count(" while(") >= 5           # a scan a Mamba layer
+        assert weights + slabs + mem.temp_size_in_bytes + (
+            mem.output_size_in_bytes) < 15.5 * 2**30, mem
+        return
+    # the full layer and the three cross layers attend the ONE slab
+    # through the kernel over flat rows; the rings keep the lax path
+    calls = re.findall(r"%(ptpu\.[\w.]+?)(?:\.\d+)? = [^\n]*"
+                       r'custom_call_target="tpu_custom_call"', text)
+    assert calls == ["ptpu.diff_attn_rows"] * 4, calls
+    spec = pred.cache_spec(batch, seq)
+    assert n_cache == len(spec) == 20
+    assert mem.alias_size_in_bytes >= sum(e.nbytes for e in spec)
+    big = {e.shape for e in spec if e.nbytes > 2**27}
+    assert big == {(64, 4096, 1280), (64, 512, 1280)}  # the slab, the rings
+    for shape in big:
+        moved = [name for op, name, changed in _whole_slab_ops(text, shape)
+                 if op == "copy" or changed]
+        assert not moved, (shape, moved)
+    assert mem.temp_size_in_bytes < 200 * 2**20, mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("b,s,h,row", [(64, 4096, 40, 1280),
+                                       (8, 1024, 40, 1280),
+                                       (8, 2048, 16, 512)])
+def test_diff_attn_rows_kernel_compiles(one_chip, b, s, h, row):
+    """The kernel over a slab of flat rows (`ops/diff_attn.py`) at the
+    Phi-4-mini-flash cell's slab, at its largest prefill's rows (a cross
+    layer's one query row a prompt) and at chip_smoke's block: Mosaic
+    takes the lane slices of a (rows, P x 128) block and the (heads, S)
+    score scratch, with no temporaries outside the call."""
+    from paddle_tpu.ops import diff_attn as D
+
+    compiled = _compiled(
+        lambda qp, k, v, n: D.pallas_attend_rows(qp, k, v, n, 0.125),
+        jax.ShapeDtypeStruct((b, 1, h, 128), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((b, s, row), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((b, s, row), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one_chip))
+    assert "ptpu.diff_attn_rows" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
