@@ -282,9 +282,11 @@ def transformer_lm(
     Off by default: the reference benchmark model keeps
     the matrices separate (reference
     benchmark/fluid/models/machine_translation.py:1). Under a
-    tensor-parallel mesh pass megatron_transformer_plan(tied=True) —
-    the default plan's hidden-sharded emb rule would split the head
-    matmul's contracted axis (see that plan's docstring)."""
+    tensor-parallel mesh pass megatron_transformer_plan(tied=True): the
+    table is then split by vocabulary rows over mp, the fused head runs
+    per rank over its own rows (ops/fused_loss.py) and vocab_size must
+    divide by mp. The default plan's hidden-sharded emb rule would split
+    the head matmul's contracted axis (see that plan's docstring)."""
     x = _embed(ids, vocab_size, d_model, max_len, "lm")
     for i in range(n_layer):
         x = decoder_layer(x, None, n_head, d_model, d_inner, dropout_rate,

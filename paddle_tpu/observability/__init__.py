@@ -57,6 +57,7 @@ __all__ = [
     "REQUEST_PHASE_MS", "TRACE_SPANS", "tracing",
     "TRANSPILE_OPS_REMOVED", "TRANSPILE_OPS_FUSED", "TRANSPILE_PASS_MS",
     "QUANT_CALIB_BATCHES", "QUANT_OPS", "QUANT_PARITY",
+    "FUSED_HEAD_TRACES",
 ]
 
 # -- the shared instrument set (registered once, process-wide) -----------
@@ -76,6 +77,12 @@ CACHE_ENTRIES_ALIASED = REGISTRY.counter(
     "Of those, the entries whose update the compiled program writes into "
     "a donated feed's buffer (its input_output_alias), by kind: equal to "
     "the fed count on a chip, 0 on the CPU where nothing is donated")
+FUSED_HEAD_TRACES = REGISTRY.counter(
+    "paddle_tpu_fused_head_traces_total",
+    "Traces of the fused LM head (ops/fused_loss.py), by "
+    "path=vocab_parallel|local and the ways the vocabulary is split: "
+    "which path a step was compiled with. Counted when the op is traced, "
+    "so a step loaded from the executable cache adds nothing")
 CACHE_HITS = REGISTRY.counter(
     "paddle_tpu_compile_cache_hits_total",
     "Compile-cache hits, by kind, program fingerprint, and "

@@ -17,15 +17,37 @@ than one (N, block_v) tile live at a time. Chunks are read from W in
 place via dynamic slices (no transposed copy of the weight). All matmuls
 run on the MXU with fp32 accumulation (`preferred_element_type`), so bf16
 inputs under mixed precision keep full-precision loss/grads.
+
+Vocabulary-parallel under a tensor-parallel mesh. Where the step is traced
+under a mesh whose plan names a tensor axis of more than one device
+(`framework.trace.current_trace_mesh` / `current_trace_plan`, as
+`ops/attention._per_shard` reads them), the SAME chunk loop runs inside a
+`shard_map`: every rank of the tensor axis owns a contiguous slice of
+V / ways vocabulary rows (dim 0 of a (V, D) table, dim 1 of a (D, V)
+weight), contracts the whole D locally, and numbers its columns from
+`axis_index * V / ways`. No collective runs inside either loop. After the
+forward loop the three (N,) row carries cross the tensor axis once (the
+max by `pmax`, the sum rescaled to it and the picked logit by `psum`);
+after the backward loop `dx` is summed over the tensor axis once, and
+`dW` / `db` over the batch axes once (`_grad_vma_like`: a cotangent is
+summed over the axes its primal does not vary on). Left to GSPMD the same
+loop all-reduces a chunk of partial logits per iteration, forward and
+backward (it splits the contracted D), or gathers the table around it.
+With no mesh, a tensor axis of one device, or inside an enclosing
+`shard_map`, the op lowers to the one-device program unchanged.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
+from ..framework.trace import current_trace_mesh, current_trace_plan
+from ..observability import FUSED_HEAD_TRACES
 from .registry import register_op
 
 _NEG = -1e30
@@ -117,10 +139,38 @@ def _chunk_logits(x, wb, transpose_w):
     return jnp.dot(x, wb, preferred_element_type=jnp.float32)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _lm_head_loss(block_v, transpose_w, x, w, b, labels):
-    loss, _ = _lm_head_fwd(block_v, transpose_w, x, w, b, labels)
+def _chunk_cols(j, block_v, axis, v):
+    """Vocabulary ids of chunk j's columns. ``axis`` None: the whole
+    vocabulary is here. Else this rank's slice of ``v`` rows starts at
+    axis_index * v, and a column of its padded tail gets the id -1, which
+    no label has: the tail's ids would be the next rank's first rows."""
+    col = j * block_v + jnp.arange(block_v)
+    if axis is None:
+        return col
+    return jnp.where(col < v, col + lax.axis_index(axis) * v, -1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _lm_head_loss(block_v, transpose_w, axis, x, w, b, labels):
+    loss, _ = _lm_head_fwd(block_v, transpose_w, axis, x, w, b, labels)
     return loss
+
+
+def _vocab_mesh():
+    """(mesh, tensor axis, batch axes) where the step is being traced under
+    a mesh whose plan splits tensors over an axis of more than one device;
+    (None, None, ()) with no mesh, a tensor axis of one device, or inside
+    an enclosing shard_map (its axes are manual already: a pipeline
+    stage, a dp-mapped step). `ops/attention._per_shard` reads the same
+    two; it is left as it is because a Mosaic kernel's lowered body embeds
+    its file's line numbers, and the one-chip step's text with them."""
+    mesh, plan = current_trace_mesh(), current_trace_plan()
+    axis = getattr(plan, "tensor_axis", None)
+    if (mesh is None or axis is None or mesh.shape[axis] == 1
+            or jax.sharding.get_abstract_mesh().manual_axes):
+        return None, None, ()
+    return mesh, axis, tuple(a for a in plan.batch_axes
+                             if mesh.shape[a] > 1)
 
 
 def lm_head_loss(block_v, x, w, b, labels, transpose_w=False):
@@ -129,14 +179,41 @@ def lm_head_loss(block_v, x, w, b, labels, transpose_w=False):
     place; b: (V,); labels: (N,) int -> loss (N, 1) fp32.
 
     loss_i = logsumexp_v(x_i @ w + b) - (x_i @ w + b)[labels_i]
-    """
-    return _lm_head_loss(block_v, bool(transpose_w), x, w, b, labels)
+
+    Under a trace mesh with a tensor axis of more than one device the
+    vocabulary is split over that axis and the rows over the plan's batch
+    axes (module docstring). A V or N the axes do not divide is an error
+    naming the shape, never a quiet switch to another path."""
+    transpose_w = bool(transpose_w)
+    mesh, axis, batch_axes = _vocab_mesh()
+    if axis is None:
+        FUSED_HEAD_TRACES.inc(path="local", ways=1)
+        return _lm_head_loss(block_v, transpose_w, None, x, w, b, labels)
+    ways = mesh.shape[axis]
+    b_ways = math.prod(mesh.shape[a] for a in batch_axes)
+    n = x.shape[0]
+    if w.shape[0 if transpose_w else 1] % ways or n % b_ways:
+        raise ValueError(
+            "fused_lm_head_loss: w %s (transpose_w=%s) and x %s do not "
+            "divide over mesh %s with tensor axis %r (vocabulary) and "
+            "batch axes %s (rows)" % (w.shape, transpose_w, x.shape,
+                                      dict(mesh.shape), axis, batch_axes))
+    FUSED_HEAD_TRACES.inc(path="vocab_parallel", ways=ways)
+    rows = batch_axes or None
+    return jax.shard_map(
+        functools.partial(_lm_head_loss, block_v, transpose_w, axis),
+        mesh=mesh,
+        in_specs=(P(rows, None),
+                  P(axis, None) if transpose_w else P(None, axis),
+                  P(axis), P(rows)),
+        out_specs=P(rows, None))(x, w, b, labels.reshape(n))
 
 
-def _lm_head_fwd(block_v, transpose_w, x, w, b, labels):
+def _lm_head_fwd(block_v, transpose_w, axis, x, w, b, labels):
     n = x.shape[0]
     labels = labels.reshape(n).astype(jnp.int32)
     wp, bp, nblk = _pad_wb(w, b, block_v, transpose_w)
+    v = b.shape[0]
     xdt = x.dtype
 
     def body(j, carry):
@@ -144,7 +221,7 @@ def _lm_head_fwd(block_v, transpose_w, x, w, b, labels):
         wb = _w_chunk(wp, j, block_v, transpose_w).astype(xdt)
         bb = lax.dynamic_slice_in_dim(bp, j * block_v, block_v, 0)
         logits = _chunk_logits(x, wb, transpose_w) + bb
-        col = j * block_v + jnp.arange(block_v)
+        col = _chunk_cols(j, block_v, axis, v)
         m_new = jnp.maximum(m, jnp.max(logits, axis=-1))
         s = s * jnp.exp(m - m_new) + jnp.sum(
             jnp.exp(logits - m_new[:, None]), axis=-1)
@@ -165,12 +242,18 @@ def _lm_head_fwd(block_v, transpose_w, x, w, b, labels):
         m, s, picked = carry
     else:
         m, s, picked = lax.fori_loop(0, nblk, body, init)
+    if axis is not None:
+        # the ranks' row statistics meet once, after the loop
+        m_all = lax.pmax(m, axis)
+        s = lax.psum(s * jnp.exp(m - m_all), axis)
+        picked = lax.psum(picked, axis)
+        m = m_all
     lse = m + jnp.log(s)
     loss = (lse - picked)[:, None]
     return loss, (x, w, b, labels, lse)
 
 
-def _lm_head_bwd(block_v, transpose_w, res, g):
+def _lm_head_bwd(block_v, transpose_w, axis, res, g):
     x, w, b, labels, lse = res
     n, d = x.shape
     v = w.shape[0 if transpose_w else 1]
@@ -186,7 +269,7 @@ def _lm_head_bwd(block_v, transpose_w, res, g):
         wbx = wb.astype(xdt)
         logits = _chunk_logits(x, wbx, transpose_w) + bb
         p = jnp.exp(logits - lse[:, None])  # padded cols: exp(-1e30-lse)=0
-        col = j * block_v + jnp.arange(block_v)
+        col = _chunk_cols(j, block_v, axis, v)
         hit = labels[:, None] == col[None, :]
         gch = (p - hit.astype(jnp.float32)) * gl  # (N, BV) fp32
         gchx = gch.astype(xdt)
@@ -218,7 +301,9 @@ def _lm_head_bwd(block_v, transpose_w, res, g):
     else:
         dx, dw, db = lax.fori_loop(0, nblk, body, init)
     dw = dw[:v] if transpose_w else dw[:, :v]
-    return (_grad_vma_like(dx.astype(x.dtype), x),
+    # as written dx is summed over the tensor axis in float32 and rounded
+    # after (the TPU compiler moves the rounding first: PERF.md §6, PR 34)
+    return (_grad_vma_like(dx, x).astype(x.dtype),
             _grad_vma_like(dw.astype(w.dtype), w),
             _grad_vma_like(db[:v].astype(b.dtype), b), None)
 

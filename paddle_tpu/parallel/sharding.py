@@ -49,21 +49,25 @@ class ShardingPlan:
         # (read by kernels that shard_map themselves, ops/attention.py)
         self.tensor_axis: Optional[str] = None
         self._exact: Dict[str, P] = {}
-        self._regex: list = []
+        self._regex: list = []  # (compiled pattern, spec, strict)
 
     # -- construction ----------------------------------------------------
     def set(self, name: str, spec: P) -> "ShardingPlan":
         self._exact[name] = spec
         return self
 
-    def set_regex(self, pattern: str, spec: P) -> "ShardingPlan":
-        self._regex.append((re.compile(pattern), spec))
+    def set_regex(self, pattern: str, spec: P,
+                  strict: bool = False) -> "ShardingPlan":
+        """strict: a dim of more than one element that the spec's axes do
+        not divide is an error naming the variable and its shape, where
+        other rules quietly leave such a dim whole (``spec``)."""
+        self._regex.append((re.compile(pattern), spec, strict))
         return self
 
     # -- resolution ------------------------------------------------------
     def spec(self, name: str, ndim: Optional[int] = None,
              shape: Optional[Sequence[int]] = None) -> P:
-        s = self._lookup(name)
+        s, strict = self._lookup(name)
         if shape is not None:
             ndim = len(shape)
         if ndim is not None and len(s) > ndim:
@@ -81,23 +85,30 @@ class ShardingPlan:
                     continue
                 axes = ax if isinstance(ax, tuple) else (ax,)
                 ways = int(np.prod([self.mesh.shape[a] for a in axes]))
+                if strict and shape[i] > 1 and shape[i] % ways:
+                    raise ValueError(
+                        "%r of shape %s: dim %d does not divide over the %d "
+                        "devices of mesh axis %r, and this plan's rule for "
+                        "it cannot leave it whole" % (
+                            name, tuple(shape), i, ways, ax))
                 fixed.append(ax if shape[i] % ways == 0 else None)
             s = P(*fixed)
         return s
 
-    def _lookup(self, name: str) -> P:
+    def _lookup(self, name: str):
+        """(spec, whether the rule that gave it is strict)."""
         if name in self._exact:
-            return self._exact[name]
-        for rx, spec in self._regex:
+            return self._exact[name], False
+        for rx, spec, strict in self._regex:
             if rx.search(name):
-                return spec
+                return spec, strict
         best, best_len = None, -1
         for key, spec in self._exact.items():
             if name.startswith(key) and len(key) > best_len:
                 best, best_len = spec, len(key)
         if best is not None:
-            return best
-        return self.default
+            return best, False
+        return self.default, False
 
     def sharding(self, name: str, ndim: Optional[int] = None,
                  shape: Optional[Sequence[int]] = None) -> NamedSharding:
@@ -160,14 +171,20 @@ def megatron_transformer_plan(
     pattern, derived by the compiler instead of hand-written NCCL calls.
 
     tied=True is for ``transformer_lm(tie_embeddings=True)``: the token
-    table doubles as the vocab projection, so neither of this plan's
-    embedding rules fits it — hidden-sharding (the default emb rule)
-    would split the head matmul's CONTRACTED axis (an all-reduce of
-    partial logits per vocab chunk), and the head's vocab-column split
-    would shard the axis the fused kernel dynamic-slices in place.
-    The tied table and head bias are pinned replicated instead: the
-    whole head stays comm-free, and dp/ZeRO still shards its optimizer
-    state where that plan composes.
+    table (V, D) doubles as the vocab projection, so it is split where the
+    head needs it split: by vocabulary ROWS, ``P(mp_axis, None)``, with the
+    head bias ``P(mp_axis)`` and the optimizer moments the same. Each mp
+    rank then owns V / mp rows of the table: the fused head
+    (ops/fused_loss.py) runs its chunk loop over that slice inside a
+    shard_map, contracting the whole D locally, and only the (N,) row
+    statistics and one dx cross mp; the embedding read of the same table
+    is a local masked take and one all-reduce of the activations. The
+    default emb rule (hidden-sharding) would split the head matmul's
+    CONTRACTED axis, and a table pinned replicated gets it split all the
+    same (GSPMD then all-reduces a chunk of partial logits per loop
+    iteration, and gathers the updated table every step). A V that mp
+    does not divide is an error naming the shape, never a quiet fall back
+    to a whole table.
     """
     plan = ShardingPlan(mesh, batch_axes=batch_axes)
     if mp_axis in mesh.axis_names:
@@ -175,21 +192,22 @@ def megatron_transformer_plan(
     col_w = P(None, mp_axis)  # (in, out) split on out
     row_w = P(mp_axis, None)  # (in, out) split on in
     col_b = P(mp_axis)
-    for pat, spec in [
+    for pat, spec, strict in [
         # .qkv: the fused projection's columns are grouped per head
         # [h0:q,k,v | h1:q,k,v | ...], so a contiguous column split over
         # mp keeps whole head groups local — same comm pattern as
         # separate q/k/v columns
-        (r"\.(q|k|v|qkv|fc1)\.w", col_w),
-        (r"\.(q|k|v|qkv|fc1)\.b", col_b),
-        (r"\.(out|fc2)\.w", row_w),
-        (r"\.(out|fc2)\.b", P()),
-        (r"pos_emb", P(None, mp_axis)),
-        (r"tok_emb", P() if tied else P(None, mp_axis)),
-        (r"\.head\.w", col_w),  # vocab-parallel output projection
-        (r"\.head\.b", P() if tied else col_b),
+        (r"\.(q|k|v|qkv|fc1)\.w", col_w, False),
+        (r"\.(q|k|v|qkv|fc1)\.b", col_b, False),
+        (r"\.(out|fc2)\.w", row_w, False),
+        (r"\.(out|fc2)\.b", P(), False),
+        (r"pos_emb", P(None, mp_axis), False),
+        # tied: vocabulary rows, as the head reads them (docstring)
+        (r"tok_emb", row_w if tied else P(None, mp_axis), tied),
+        (r"\.head\.w", col_w, False),  # vocab-parallel output projection
+        (r"\.head\.b", col_b, tied),
     ]:
-        plan.set_regex(pat, spec)
+        plan.set_regex(pat, spec, strict=strict)
     return plan
 
 
@@ -222,7 +240,7 @@ def infer_tp_plan(mesh: Mesh, program, mp_axis: str = "mp") -> ShardingPlan:
     try:
         for var in program.global_block().vars.values():
             if getattr(var, "persistable", False) and any(
-                    rx.search(var.name) for rx, _ in probe._regex):
+                    rx.search(var.name) for rx, _, _ in probe._regex):
                 matched = True
                 break
     except Exception:

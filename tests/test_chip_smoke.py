@@ -91,6 +91,12 @@ def test_parallel_phase_on_four_virtual_devices(monkeypatch, capsys):
         seen.append(kw["in_specs"][0]), shard_map(f, **kw))[1])
     chip_smoke.phase_parallel(TINY, fluid.CPUPlace())
     assert '"phase": "parallel"' in capsys.readouterr().out
-    # batch over dp, heads (dim 2 of B,T,H,Dh) over mp
+    # the flash kernels: batch over dp, heads (dim 2 of B,T,H,Dh) over mp
     want = jax.sharding.PartitionSpec("dp", None, "mp", None)
-    assert seen and all(s == want for s in seen), seen
+    attn = [s for s in seen if len(s) == 4]
+    assert attn and all(s == want for s in attn), seen
+    # the fused head: rows over dp (its weight's vocabulary over mp)
+    head = [s for s in seen if len(s) == 2]
+    assert head and all(
+        s == jax.sharding.PartitionSpec("dp", None) for s in head), seen
+    assert len(attn) + len(head) == len(seen), seen

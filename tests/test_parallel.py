@@ -261,7 +261,10 @@ def test_transformer_lm_dp_x_mp_parity(fused_qkv, tied):
     shardings change the partitioning, never the math. Covers the
     separate q/k/v projections, the fused head-grouped .qkv layout the
     plan's column split was extended for, and the tied embed/head table
-    under the plan's tied=True rules (replicated table, comm-free head)."""
+    under the plan's tied=True rules (the table split by vocabulary rows
+    over mp; the fused head runs a rank over its own 32 rows under a
+    shard_map and only row statistics and one dx cross mp). The untied
+    head's (D, V) weight takes the same path on its column split."""
     from paddle_tpu import models
     from paddle_tpu.parallel import make_mesh, megatron_transformer_plan
 

@@ -231,18 +231,23 @@ def test_training_step_compiles(one_chip, monkeypatch, tie):
     assert text.count("tpu_custom_call") >= 2 * 2
 
 
-def test_training_step_compiles_on_2x2_mesh(topo, monkeypatch):
+@pytest.mark.parametrize("tie", [False, True], ids=["untied", "tied"])
+def test_training_step_compiles_on_2x2_mesh(topo, monkeypatch, tie):
     """The same step as one program over four chips: batch over dp, heads
     over mp (megatron plan). Mosaic kernels cannot be partitioned by
-    GSPMD; `fused_attention` shard_maps them under the trace mesh."""
+    GSPMD; `fused_attention` shard_maps them under the trace mesh, and
+    the fused head its chunk loops, a vocabulary slice an mp rank: no
+    collective lies inside a loop body and nothing gathers the head's
+    (tied: the token table's) whole weight."""
+    from hlo_text import collectives, while_bodies
     from paddle_tpu.framework import trace as trace_mod
     from paddle_tpu.parallel import megatron_transformer_plan
 
     monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
     monkeypatch.setenv("PADDLE_TPU_FLASH_FUSED_BWD", "1")
     mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("dp", "mp"))
-    plan = megatron_transformer_plan(mesh)
-    main_p, startup, loss = _lm_programs(2)
+    plan = megatron_transformer_plan(mesh, tied=tie)
+    main_p, startup, loss = _lm_programs(2, tie=tie)
     sds = jax.ShapeDtypeStruct
 
     def place_state(name, aval):
@@ -260,6 +265,12 @@ def test_training_step_compiles_on_2x2_mesh(topo, monkeypatch):
         text = _compile(stepfn, *avals, donate_argnums=(1,))
     assert text.count("tpu_custom_call") >= 2 * 2
     assert "all-reduce" in text
+    assert len(while_bodies(text)) >= 2  # the head's two chunk loops
+    found = collectives(text)
+    assert not [c for c in found if c[3] is not None]
+    whole = ("[%d,%d]" % (VOCAB, D_MODEL), "[%d,%d]" % (D_MODEL, VOCAB))
+    assert not [c for c in found if c[0] == "all-gather"
+                and any(w in c[1] for w in whole)]
     # an mp-split weight is half per device: fc1.w is (1024, 4096) f32
     fc1 = next(n for n in avals[1] if n.endswith(".fc1.w"))
     assert avals[1][fc1].sharding.shard_shape(avals[1][fc1].shape) == (
