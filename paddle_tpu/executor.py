@@ -74,10 +74,10 @@ _EXE_IDS = itertools.count()
 
 class _Compiled:
     __slots__ = ("fn", "state_in_names", "state_out_names", "fetch_names",
-                 "program", "fp", "hlo")
+                 "program", "fp", "hlo", "lazy")
 
     def __init__(self, fn, state_in_names, state_out_names, fetch_names,
-                 program, fp=None, hlo=None):
+                 program, fp=None, hlo=None, lazy=True):
         self.fn = fn
         self.state_in_names = state_in_names
         self.state_out_names = state_out_names
@@ -87,6 +87,10 @@ class _Compiled:
         self.program = program
         self.fp = fp          # short program fingerprint (observability)
         self.hlo = hlo        # opt-in trace/lower timings + cost estimates
+        # a bare jax.jit that traces and compiles inside its first call
+        # (False: an executable Engine.acquire loaded or compiled, and
+        # recorded)
+        self.lazy = lazy
 
 
 class _CompileCache:
@@ -362,17 +366,19 @@ class Executor:
 
     def _compile(self, program: Program, feed_sig, fetch_names, scope: Scope,
                  user_feed_names=None) -> _Compiled:
+        began = time.perf_counter()
         state_in, state_out = self._verify_and_analyze(
             program, feed_sig, scope, user_feed_names,
             fetch_names=fetch_names)
 
         stepfn = build_step_fn(program, fetch_names, state_in, state_out)
         fn = jax.jit(stepfn, donate_argnums=(1,))
-        fn, hlo = self._aot_compile(
+        exe, hlo = self._aot_compile(
             fn, program, feed_sig, fetch_names, state_in, state_out, scope,
-            loop=False, kind="run")
-        return _Compiled(fn, state_in, state_out, fetch_names, program,
-                         fp=obs.program_fp(program), hlo=hlo)
+            loop=False, kind="run", began=began)
+        return _Compiled(exe, state_in, state_out, fetch_names, program,
+                         fp=obs.program_fp(program), hlo=hlo,
+                         lazy=exe is fn)
 
     def _compile_loop(self, program: Program, feed_sig, fetch_names,
                       scope: Scope, per_step_names: frozenset,
@@ -389,6 +395,7 @@ class Executor:
         matter how many steps run (the reference
         gets the same effect from double_buffer readers + multi-iteration
         C++ executor loops, e.g. ParallelExecutor::Run batches)."""
+        began = time.perf_counter()
         state_in, state_out = self._verify_and_analyze(
             program,
             # per-step feeds are validated against their per-iteration shape
@@ -406,11 +413,13 @@ class Executor:
             }
 
         fn = jax.jit(make_loop_fn(stepfn, slice_feeds), donate_argnums=(1,))
-        fn, hlo = self._aot_compile(
+        exe, hlo = self._aot_compile(
             fn, program, feed_sig, fetch_names, state_in, state_out, scope,
-            loop=True, per_step_names=per_step_names, kind="loop")
-        return _Compiled(fn, state_in, state_out, fetch_names, program,
-                         fp=obs.program_fp(program), hlo=hlo)
+            loop=True, per_step_names=per_step_names, kind="loop",
+            began=began)
+        return _Compiled(exe, state_in, state_out, fetch_names, program,
+                         fp=obs.program_fp(program), hlo=hlo,
+                         lazy=exe is fn)
 
     @staticmethod
     def _avals_for(feed_sig, state_in, scope, loop=False):
@@ -437,13 +446,18 @@ class Executor:
 
     def _aot_compile(self, fn, program: Program, feed_sig, fetch_names,
                      state_in, state_out, scope, *, loop: bool, kind: str,
+                     began: float,
                      per_step_names: frozenset = frozenset()):
         """Acquire the executable through the persistent disk tier:
         explicit ``lower → compile`` AOT (donation set on `fn` is
         preserved through lowering AND serialization), with the compiled
         executable stored under a key that covers everything that shapes
-        it (see aot_cache.env_fingerprint). Returns ``(callable, hlo)``
-        where hlo feeds timeline.record_compile.
+        it (see aot_cache.env_fingerprint). Returns ``(callable, hlo)``:
+        an executable ``Engine.acquire`` loaded or compiled and recorded
+        (``began``, the caller's clock when it started on the program,
+        gives the record its ``build_ms``) and None, or the lazy `fn`
+        itself, whose first call is its acquisition, and the `hlo` that
+        ``observe_run`` adds to that record.
 
         Failure contract: a disabled cache or an un-abstractable
         signature falls back to the lazy ``jax.jit`` path unchanged;
@@ -483,18 +497,15 @@ class Executor:
                     program, e, feed_names=tuple(n for n, _, _ in feed_sig),
                     fetch_names=tuple(fetch_names))
 
-        compiled, path, hlo = eng.acquire(
+        # the trace/XLA split comes free on the explicit AOT path, and
+        # the cost estimates for the asking (the lazy path needs opt-in
+        # _hlo_compile_stats to pay for either)
+        compiled, _path, _timings = eng.acquire(
             kind, key, lower,
-            meta=eng.meta("loop" if loop else "step", feed_sig, fetch_names))
-        if path == "warm":
-            return compiled, None
-        # the trace/XLA split comes free on the explicit AOT path (the
-        # lazy path needs opt-in _hlo_compile_stats to pay for it)
-        if obs.TIMELINE.hlo_cost_enabled():
-            cost = obs.hlo_cost_stats(compiled)
-            if cost:
-                hlo.update(cost)
-        return compiled, hlo
+            meta=eng.meta("loop" if loop else "step", feed_sig, fetch_names),
+            cost=obs.TIMELINE.hlo_cost_enabled(), counts_compile=False,
+            build_ms=(time.perf_counter() - began) * 1e3)
+        return compiled, None
 
     def _hlo_compile_stats(self, fn, feed_sig, state_in, scope, loop=False):
         """Opt-in (``observability.TIMELINE.set_hlo_cost(True)``): lower +
@@ -939,7 +950,7 @@ class Executor:
                 "%s/program_%x" % (label, id(program) & 0xFFFF), wall)
         obs.observe_run(
             "run", wall, steps=1, program=compiled.fp, compiled=first_run,
-            hlo=compiled.hlo if first_run else None,
+            lazy=compiled.lazy, hlo=compiled.hlo if first_run else None,
             feed_bytes=obs.nbytes_of(feed_arrays.values()),
             fetch_bytes=obs.nbytes_of(fetches),
             device_ms=wall * 1e3 if fence else None)
@@ -1128,7 +1139,8 @@ class Executor:
                 "%s/program_%x" % (label, id(program) & 0xFFFF), wall)
         obs.observe_run(
             "loop", wall, steps=effective_steps, program=compiled.fp,
-            compiled=first_run, hlo=compiled.hlo if first_run else None,
+            compiled=first_run, lazy=compiled.lazy,
+            hlo=compiled.hlo if first_run else None,
             feed_bytes=obs.nbytes_of(feed_arrays.values()),
             fetch_bytes=obs.nbytes_of(fetches),
             device_ms=wall * 1e3 if fence else None)

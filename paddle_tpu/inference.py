@@ -192,6 +192,7 @@ class Predictor:
                 self._disk.touch(self._key(feed_sig))
             return self._compiled[feed_sig]
         obs.CACHE_MISSES.inc(kind="predict", tier="memory", program=fp)
+        t_build = time.perf_counter()
         from .executor import Executor
 
         # fail fast with the variable name on an impossible feed shape
@@ -215,28 +216,21 @@ class Predictor:
                     self._program, e, feed_names=tuple(self._feed_names),
                     fetch_names=tuple(self._fetch_names))
 
-        # acquisition (disk-load-or-compile + the tier metrics contract)
-        # goes through the shared Engine — the same code path the
-        # training Executor's _aot_compile runs
-        loaded, path, timings = self._engine.acquire(
-            "predict", key, lower, meta=self._meta(feed_sig))
-        if path == "warm":
-            if self._disk.read_meta(key) is None:
-                # missing OR unreadable sidecar next to a valid blob
-                # (pre-sidecar cache, or a torn/corrupt .sig write):
-                # rewrite it now so the NEXT process's preload finds
-                # this executable instead of paying the lazy
-                # first-call deserialization forever
-                self._disk.write_meta(key, self._meta(feed_sig))
-        else:
-            # the predictor compiles AOT anyway, so the trace/XLA split
-            # and cost-analysis estimates come for free here
-            cost = obs.hlo_cost_stats(loaded) or {}
-            wall_ms = timings["trace_ms"] + timings["xla_ms"]
-            obs.COMPILE_TOTAL.inc(kind="predict")
-            obs.COMPILE_LATENCY_MS.observe(wall_ms, kind="predict")
-            obs.TIMELINE.record_compile(
-                "predict", fp, wall_ms=wall_ms, **dict(timings, **cost))
+        # acquisition (disk-load-or-compile + the tier metrics contract
+        # and the timeline's record) goes through the shared Engine — the
+        # same code path the training Executor's _aot_compile runs. The
+        # predictor compiles AOT anyway, so the cost-analysis estimates
+        # come for free on the cold path
+        loaded, path, _timings = self._engine.acquire(
+            "predict", key, lower, meta=self._meta(feed_sig), cost=True,
+            build_ms=(time.perf_counter() - t_build) * 1e3)
+        if path == "warm" and self._disk.read_meta(key) is None:
+            # missing OR unreadable sidecar next to a valid blob
+            # (pre-sidecar cache, or a torn/corrupt .sig write):
+            # rewrite it now so the NEXT process's preload finds
+            # this executable instead of paying the lazy
+            # first-call deserialization forever
+            self._disk.write_meta(key, self._meta(feed_sig))
         self._compiled[feed_sig] = loaded
         return loaded
 

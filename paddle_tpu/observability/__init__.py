@@ -7,7 +7,8 @@ ONE fused XLA computation: compile events and compile-cache behavior
 batch-size distribution (Predictor / PredictorServer), and bench phase
 accounting (bench.py). Everything records into one process-wide
 ``MetricRegistry`` (metrics.py) and one bounded ``StepTimeline``
-(timeline.py); export.py renders Prometheus text / JSON, and
+(timeline.py: a ring of steps and a ring of executable acquisitions);
+export.py renders Prometheus text / JSON, and
 ``PredictorServer.start_http()`` serves it at ``GET /metrics``.
 
 The legacy ``paddle_tpu.profiler`` module is a compatibility shim over
@@ -16,6 +17,7 @@ this registry (its event table lives in the
 """
 from __future__ import annotations
 
+import time
 import weakref
 from typing import Optional
 
@@ -29,7 +31,8 @@ from .timeline import TIMELINE, StepTimeline, get_timeline, hlo_cost_stats  # no
 __all__ = [
     "REGISTRY", "TIMELINE", "get_registry", "get_timeline",
     "MetricRegistry", "StepTimeline", "metrics", "timeline", "export",
-    "program_fp", "observe_run", "reset_all", "hlo_cost_stats", "nbytes_of",
+    "program_fp", "observe_run", "observe_acquire", "reset_all",
+    "hlo_cost_stats", "nbytes_of",
     # shared instruments
     "COMPILE_TOTAL", "COMPILE_LATENCY_MS", "CACHE_HITS", "CACHE_MISSES",
     "CACHE_ENTRIES_FED", "CACHE_ENTRIES_ALIASED",
@@ -423,14 +426,18 @@ def program_fp(program) -> str:
 
 def observe_run(kind: str, wall_s: float, *, steps: int = 1,
                 program: Optional[str] = None, compiled: bool = False,
-                hlo: Optional[dict] = None,
+                lazy: bool = True, hlo: Optional[dict] = None,
                 feed_bytes: int = 0, fetch_bytes: int = 0,
                 device_ms: Optional[float] = None):
     """One executor dispatch -> registry + timeline, in one call (keeps
     the executor hot path to a single function call). ``compiled=True``
-    marks a first call (the lazy jit's trace+compile happened inside it);
-    ``hlo`` carries the opt-in trace/lower split and cost estimates from
-    ``Executor._hlo_compile_stats``."""
+    marks a first call. With ``lazy`` the executable was a bare
+    ``jax.jit`` and its trace+compile happened inside this call: the call
+    IS the acquisition, and is recorded as one (``path="lazy"``; ``hlo``
+    carries the opt-in trace/lower split and cost estimates from
+    ``Executor._hlo_compile_stats``). Without it the executable came
+    through ``Engine.acquire``, which wrote the record; the first
+    dispatch still counts as a compilation, as it always has."""
     wall_ms = wall_s * 1e3
     STEP_LATENCY_MS.observe(wall_ms, kind=kind)
     STEPS_TOTAL.inc(steps, kind=kind)
@@ -438,14 +445,71 @@ def observe_run(kind: str, wall_s: float, *, steps: int = 1,
         FEED_BYTES.inc(feed_bytes, kind=kind)
     if fetch_bytes:
         FETCH_BYTES.inc(fetch_bytes, kind=kind)
-    if compiled:
-        COMPILE_TOTAL.inc(kind=kind)
-        COMPILE_LATENCY_MS.observe(wall_ms, kind=kind)
-        TIMELINE.record_compile(kind, program, wall_ms=wall_ms,
-                                **(hlo or {}))
+    if compiled and lazy:
+        observe_acquire(kind, "lazy", wall_ms, program=program,
+                        ts=time.time() - wall_s,
+                        phase=tracing.current_phase(), compile_ms=wall_ms,
+                        **(hlo or {}))
+    elif compiled:
+        _count_compile(kind, wall_ms)
     TIMELINE.record_step(kind, wall_ms, steps=steps, program=program,
                          device_ms=device_ms, feed_bytes=feed_bytes,
                          fetch_bytes=fetch_bytes)
+
+
+def _count_compile(kind: str, ms: float):
+    COMPILE_TOTAL.inc(kind=kind)
+    COMPILE_LATENCY_MS.observe(ms, kind=kind)
+
+
+# the timeline's `cache` field, older than `path` and kept beside it
+_CACHE_OF_PATH = {"warm": "aot-load", "cold": "miss", "lazy": "miss"}
+
+
+def observe_acquire(kind: str, path: str, wall_ms: float, *,
+                    program: Optional[str] = None,
+                    name: Optional[str] = None, ts: Optional[float] = None,
+                    phase: Optional[str] = None,
+                    aot_ms: Optional[float] = None, disk: bool = False,
+                    compile_ms: Optional[float] = None, **fields):
+    """One executable acquisition -> registry + timeline, in one call:
+    the one place that feeds the compile instruments and writes the
+    timeline's compile record. A hit in a memory cache is no acquisition
+    and never comes here.
+
+    The record: ``name`` (the executable's own, ``ptpu_<kind>_b<batch>_
+    s<seq>`` as the device trace prints it, else ``<kind>/<program>``),
+    ``kind``, ``program`` (8-hex fingerprint), ``path`` = ``"warm"`` (a
+    hit in the AOT disk tier) | ``"cold"`` (lower + XLA compile) |
+    ``"lazy"`` (a first call through ``jax.jit``: trace + compile + run,
+    not split), ``cache`` (``"aot-load"`` | ``"miss"``), ``ts`` (the
+    START, ``time.time()``: the flight recorder's clock) and ``wall_ms``
+    (the whole acquisition as its caller saw it), ``phase`` (the
+    innermost ``tracing.phase`` open on the thread when it began; absent
+    when none was, as at trace rate 0), and in ``fields`` the parts that
+    path has, each in ms and summing to no more than ``wall_ms``:
+    ``build_ms`` (the caller's program construction, feed structs and
+    key), ``load_ms`` (read + deserialize; with ``blob_bytes``),
+    ``trace_ms``, ``xla_ms``, ``store_ms`` (serialize + write),
+    ``describe_ms``; whatever else the caller read off the executable
+    (``flops``, ``cache_fed``) rides along.
+
+    The registry, each instrument as it always moved: ``aot_ms`` is the
+    sample of ``paddle_tpu_aot_compile_ms{path, kind}`` (the AOT path
+    only: a load's time, or trace + XLA); ``disk`` counts a hit (warm)
+    or a miss in the disk tier; ``compile_ms`` counts a compilation and
+    is its ``paddle_tpu_compile_latency_ms`` sample."""
+    if aot_ms is not None:
+        AOT_COMPILE_MS.observe(aot_ms, path=path, kind=kind)
+    if disk:
+        (CACHE_HITS if path == "warm" else CACHE_MISSES).inc(
+            kind=kind, tier="disk", program=program)
+    if compile_ms is not None:
+        _count_compile(kind, compile_ms)
+    TIMELINE.record_compile(
+        kind, program, ts=ts, cache=_CACHE_OF_PATH[path],
+        name=name or "%s/%s" % (kind, program), path=path,
+        wall_ms=wall_ms, phase=phase, **fields)
 
 
 def nbytes_of(values) -> int:

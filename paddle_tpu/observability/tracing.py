@@ -71,7 +71,7 @@ from .metrics import process_labels
 __all__ = [
     "TraceRecorder", "RECORDER", "get_recorder", "new_trace_id",
     "sample_rate", "set_sample_rate", "sampled", "maybe_start",
-    "record_span", "record_process_span", "phase",
+    "record_span", "record_process_span", "phase", "current_phase",
     "bind_rid", "rid_trace", "pop_rid", "rid_span", "bound",
     "process_trace_id", "snapshot", "merge_snapshots", "reset",
 ]
@@ -381,6 +381,14 @@ def phase(name: str, **counts):
     if cur is _UNSAMPLED:
         return _NO_PHASE
     return _Phase(name, counts, cur)
+
+
+def current_phase() -> Optional[str]:
+    """Name of the innermost phase open on this thread, or None: at rate
+    0, outside every phase, and inside an outermost phase that lost its
+    sampling draw. What an acquisition record gives as the ``phase`` it
+    began under."""
+    return getattr(getattr(_tls, "cur", None), "name", None)
 
 
 # -- rid binding (multi-stage servers) -----------------------------------

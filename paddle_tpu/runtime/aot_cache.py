@@ -239,6 +239,14 @@ class AotDiskCache:
     def meta_path(self, key: str) -> str:
         return os.path.join(self.dir, key + META_SUFFIX)
 
+    def blob_bytes(self, key: str) -> Optional[int]:
+        """Size of the stored executable under ``key``, None where there
+        is none (what an acquisition record gives as ``blob_bytes``)."""
+        try:
+            return os.path.getsize(self.blob_path(key))
+        except OSError:
+            return None
+
     # -- load/store -------------------------------------------------------
     def load(self, key: str):
         """Deserialized executable, or None (miss / disabled / corrupt —

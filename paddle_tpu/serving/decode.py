@@ -962,8 +962,10 @@ class DecodePredictor:
             return hit
         from .engine import Engine
 
+        t_build = time.perf_counter()
+        name = _executable_name(*ck)
         step = self._step(kind, batch, seq, strategy, kv_dtype, window,
-                          use_ring, name=_executable_name(*ck))
+                          use_ring, name=name)
         engine = Engine(step.program, disk=self._disk,
                         feed_names=step.feed_names,
                         fetch_names=step.fetch_names)
@@ -1004,13 +1006,10 @@ class DecodePredictor:
             obs.CACHE_ENTRIES_ALIASED.inc(aliased, kind=kind)
             return {"cache_fed": step.n_cache, "cache_aliased": aliased}
 
-        loaded, path, timings = engine.acquire(
+        loaded, _path, _timings = engine.acquire(
             kind, key, lower, meta=engine.meta(kind, feed_sig, traced),
-            describe=pairing if kind != "draft" else None)
-        if path == "cold":
-            obs.COMPILE_TOTAL.inc(kind=kind)
-            obs.COMPILE_LATENCY_MS.observe(
-                timings["trace_ms"] + timings["xla_ms"], kind=kind)
+            describe=pairing if kind != "draft" else None, name=name,
+            build_ms=(time.perf_counter() - t_build) * 1e3)
         exe = (loaded if step.take is None
                else _InFetchOrder(loaded, step.take))
         with self._lock:

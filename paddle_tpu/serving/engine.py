@@ -38,6 +38,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from .. import observability as obs
+from ..observability import tracing
 from ..runtime import aot_cache as _aot
 
 __all__ = ["Engine"]
@@ -212,42 +213,81 @@ class Engine:
 
     # -- acquisition ------------------------------------------------------
     def acquire(self, kind: str, key: str, lower, meta: Optional[Dict] = None,
-                describe=None):
+                describe=None, *, name: Optional[str] = None,
+                build_ms: float = 0.0, cost: bool = False,
+                counts_compile: bool = True):
         """THE load-or-compile path: disk hit deserializes (path=warm),
         miss runs ``lower()`` -> ``.compile()`` and stores the result
         (path=cold). Returns ``(compiled, path, timings)`` where path is
         ``"warm" | "cold"`` and timings is ``{"trace_ms", "xla_ms"}`` on
         the cold path (None on warm — a deserialize has no split).
-        ``describe(executable)`` gives fields read off the executable
-        itself; with it the timeline's compile record carries them, on
-        the warm path and (a record of its own) on the cold one.
+
+        Every acquisition is ONE ``observability.observe_acquire`` call
+        (the compile instruments and the timeline's record, whose fields
+        it documents): the parts timed here, ``name`` (the executable's
+        own; ``<kind>/<fingerprint>`` without one) and ``build_ms`` (what
+        the caller spent on the program, its feed structs and the key
+        before it called) from the caller, the ``tracing.phase`` it
+        began under. ``describe(executable)`` gives fields read off the
+        executable itself, on both paths; ``cost`` adds XLA's
+        cost-analysis estimates on the cold one. ``counts_compile=False``
+        leaves ``paddle_tpu_compile_total`` to the caller's first
+        dispatch (the Executor: ``observe_run`` counts a first run
+        whichever path its executable came by). While tracing samples,
+        the acquisition is also a ``tracing.phase("acquire")`` with
+        children ``acquire.load | .trace | .xla | .store``: a record of
+        the flight recorder's process ring, and under a profiler session
+        a host span on the device events' clock.
 
         ``lower`` may raise (program errors propagate exactly as the
         lazy-jit first call would); disk I/O failures are absorbed by
         AotDiskCache per its never-a-crash contract."""
         fp = self.fingerprint()
         use_disk = self.disk.enabled
-        t0 = time.perf_counter()
-        loaded = self.disk.load(key) if use_disk else None
-        if loaded is not None:
-            obs.CACHE_HITS.inc(kind=kind, tier="disk", program=fp)
-            obs.AOT_COMPILE_MS.observe((time.perf_counter() - t0) * 1e3,
-                                       path="warm", kind=kind)
-            obs.TIMELINE.record_compile(
-                kind, fp, cache="aot-load",
-                **(describe(loaded) if describe else {}))
-            return loaded, "warm", None
-        if use_disk:  # a disabled tier compiles without tier accounting
-            obs.CACHE_MISSES.inc(kind=kind, tier="disk", program=fp)
-        t0 = time.perf_counter()
-        lowered = lower()
-        t1 = time.perf_counter()
-        compiled = lowered.compile()
-        t2 = time.perf_counter()
-        obs.AOT_COMPILE_MS.observe((t2 - t0) * 1e3, path="cold", kind=kind)
-        self.disk.store(key, compiled, meta=meta)
-        timings = {"trace_ms": (t1 - t0) * 1e3, "xla_ms": (t2 - t1) * 1e3}
-        if describe:
-            obs.TIMELINE.record_compile(kind, fp, **timings,
-                                        **describe(compiled))
-        return compiled, "cold", timings
+        began_under = tracing.current_phase()
+        ts = time.time() - build_ms / 1e3
+        clock = time.perf_counter
+        parts = {"build_ms": build_ms or None}
+        compiled = timings = None
+        t0 = clock()
+        with tracing.phase("acquire", executable=name or kind):
+            blob = self.disk.blob_bytes(key) if use_disk else None
+            if blob is not None:
+                with tracing.phase("acquire.load", blob_bytes=blob):
+                    t = clock()
+                    compiled = self.disk.load(key)
+                    load_ms = (clock() - t) * 1e3
+            path = "warm" if compiled is not None else "cold"
+            if path == "warm":
+                parts.update(load_ms=load_ms, blob_bytes=blob)
+            else:
+                t1 = clock()
+                with tracing.phase("acquire.trace"):
+                    lowered = lower()
+                t2 = clock()
+                with tracing.phase("acquire.xla"):
+                    compiled = lowered.compile()
+                t3 = clock()
+                with tracing.phase("acquire.store"):
+                    stored = self.disk.store(key, compiled, meta=meta)
+                timings = {"trace_ms": (t2 - t1) * 1e3,
+                           "xla_ms": (t3 - t2) * 1e3}
+                parts.update(timings, store_ms=(clock() - t3) * 1e3,
+                             blob_bytes=(self.disk.blob_bytes(key)
+                                         if stored else None))
+            cost = cost and path == "cold"
+            if describe or cost:
+                t = clock()
+                if cost:
+                    parts.update(obs.hlo_cost_stats(compiled) or {})
+                if describe:
+                    parts.update(describe(compiled))
+                parts["describe_ms"] = (clock() - t) * 1e3
+        compile_ms = (t3 - t1) * 1e3 if timings else None
+        obs.observe_acquire(
+            kind, path, build_ms + (clock() - t0) * 1e3, program=fp,
+            name=name, ts=ts, phase=began_under,
+            aot_ms=load_ms if path == "warm" else compile_ms,
+            disk=use_disk,
+            compile_ms=compile_ms if counts_compile else None, **parts)
+        return compiled, path, timings

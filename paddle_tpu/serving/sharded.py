@@ -132,6 +132,8 @@ class ShardedPredictor:
             return self._compiled[feed_sig]
         obs.CACHE_MISSES.inc(kind="predict_sharded", tier="memory",
                              program=fp)
+        ts, began_under = time.time(), obs.tracing.current_phase()
+        t0 = time.perf_counter()
         from ..executor import Executor
 
         Executor._check_feed_shapes(self._program, feed_sig)
@@ -146,18 +148,22 @@ class ShardedPredictor:
         out_shardings = tuple(rep for _ in self._fetch_names)
         fn = jax.jit(self._step_fn(), in_shardings=in_shardings,
                      out_shardings=out_shardings)
-        t0 = time.perf_counter()
+        t1 = time.perf_counter()
         with trace_mod.mesh_context(self.mesh, self._plan):
             lowered = fn.lower(
                 {n: jax.ShapeDtypeStruct(s, np.dtype(d))
                  for n, s, d in feed_sig},
                 {n: jax.ShapeDtypeStruct(a.shape, a.dtype)
                  for n, a in self._state.items()})
+            t2 = time.perf_counter()
             compiled = lowered.compile()
-        wall_ms = (time.perf_counter() - t0) * 1e3
-        obs.COMPILE_TOTAL.inc(kind="predict_sharded")
-        obs.COMPILE_LATENCY_MS.observe(wall_ms, kind="predict_sharded")
-        obs.TIMELINE.record_compile("predict_sharded", fp, wall_ms=wall_ms)
+        t3 = time.perf_counter()
+        # no disk tier here: a mesh executable is compiled every process
+        obs.observe_acquire(
+            "predict_sharded", "cold", (t3 - t0) * 1e3, program=fp, ts=ts,
+            phase=began_under, compile_ms=(t3 - t1) * 1e3,
+            build_ms=(t1 - t0) * 1e3, trace_ms=(t2 - t1) * 1e3,
+            xla_ms=(t3 - t2) * 1e3)
         self._compiled[feed_sig] = compiled
         return compiled
 
