@@ -76,6 +76,42 @@ def no_leaked_workers():
                 % ([p.name for p in procs], [t.name for t in threads]))
 
 
+# The slowest honest case takes 95 s on a contended box (ROADMAP D1); a
+# test still running at this many seconds is hung, not slow.
+TEST_LIMIT_S = 300
+
+
+@pytest.fixture(autouse=True)
+def per_test_limit(request):
+    """A hang fails ONE test, not the run: a real-time timer around each
+    test whose handler writes every thread's stack and fails the test by
+    name, so the worker goes on to the next one instead of sitting on the
+    hang until the run's own clock cuts it (exit 124, every later test
+    unrun). Signals reach the main thread only, which is where pytest
+    runs a test; a test that waits on other processes keeps its own
+    shorter limits (`fut.result(timeout=...)`)."""
+    import faulthandler
+    import signal
+
+    def on_alarm(signum, frame):
+        with tempfile.TemporaryFile() as f:  # faulthandler wants a real fd
+            faulthandler.dump_traceback(file=f, all_threads=True)
+            f.seek(0)
+            stacks = f.read().decode("utf-8", "replace")
+        pytest.fail("%s: still running after %g s (the per-test limit of "
+                    "tests/conftest.py). Every thread's stack:\n%s"
+                    % (request.node.nodeid, TEST_LIMIT_S, stacks),
+                    pytrace=False)
+
+    prev = signal.signal(signal.SIGALRM, on_alarm)
+    signal.setitimer(signal.ITIMER_REAL, TEST_LIMIT_S)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, prev)
+
+
 @pytest.fixture(autouse=True)
 def fresh_programs():
     """Each test gets fresh default programs / scope / name counters."""

@@ -2,89 +2,38 @@
 ordered/unordered epochs, worker failure propagation, and the read-op /
 run_loop integration (epoch + EOF parity with py_reader).
 
-Sources and mappers are MODULE-LEVEL (class instances) because the
-default forkserver start method pickles them across the process
-boundary — the same contract real users live under.
+Sources and mappers are module-level classes of `dataloader_sources.py`
+(beside this file), which says why they live there.
 """
 from __future__ import annotations
-
-import os
 
 import numpy as np
 import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu import layers
-from paddle_tpu.io.dataloader import DataLoader
+from paddle_tpu.io.dataloader import _SLOT_WAIT_S, DataLoader
+
+from dataloader_sources import (DyingSrc, ObjectSrc, PaddleBatchSrc,
+                                RaisingSrc, RawImageSrc, RegressionSrc,
+                                SampleSrc, SlowFirstMapper, TensorSrc)
 
 
-class SampleSrc:
-    """Yields (feature, label) samples with deterministic contents."""
+@pytest.fixture(scope="module", autouse=True)
+def forkserver_of_its_own():
+    """The loader warms the fork server with its own module (numpy, the
+    frame codec, and through the package jax) so that a worker is one
+    fork; `set_forkserver_preload` is silently too late where the
+    process's fork server already runs, and an xdist worker that ran a
+    Router test before this file has one, cold: every worker of every
+    epoch here would then import jax (seconds a worker; ROADMAP D15).
+    These tests are of the loader as it is designed to run, so the file
+    starts from no fork server, and leaves none to the files after it."""
+    from multiprocessing import forkserver
 
-    def __init__(self, n, d=3):
-        self.n, self.d = n, d
-
-    def __call__(self):
-        for i in range(self.n):
-            yield (np.full((self.d,), i, np.float32), np.int64(i))
-
-
-class TensorSrc:
-    def __init__(self, n, shape=(2, 3)):
-        self.n, self.shape = n, shape
-
-    def __call__(self):
-        for i in range(self.n):
-            yield (np.full(self.shape, i, np.float32),)
-
-
-class PaddleBatchSrc:
-    """paddle.batch convention: yields lists of per-sample tuples."""
-
-    def __init__(self, n_batches, bs=4):
-        self.n_batches, self.bs = n_batches, bs
-
-    def __call__(self):
-        for b in range(self.n_batches):
-            yield [(np.full((2,), b * self.bs + i, np.float64), int(i))
-                   for i in range(self.bs)]
-
-
-class ObjectSrc:
-    def __call__(self):
-        for i in range(3):
-            yield (np.array(["s%d" % i, None], dtype=object),)
-
-
-class RaisingSrc:
-    """Yields a few good samples, then raises."""
-
-    def __init__(self, good=4):
-        self.good = good
-
-    def __call__(self):
-        for i in range(self.good):
-            yield (np.full((3,), i, np.float32),)
-        raise ValueError("decode exploded mid-epoch")
-
-
-class DyingSrc:
-    """Simulates a segfaulting worker: hard process death, no message."""
-
-    def __call__(self):
-        yield (np.ones(3, np.float32),)
-        os._exit(23)
-
-
-class SlowFirstMapper:
-    """Delays the FIRST batch's samples so ordered mode must reorder."""
-
-    def __call__(self, s):
-        import time
-
-        if float(s[0][0]) < 4:  # first batch of 4
-            time.sleep(0.05)
-        return s
+    forkserver._forkserver._stop()
+    yield
+    forkserver._forkserver._stop()
 
 
 def _drain(dl):
@@ -96,14 +45,30 @@ def _drain(dl):
             return out
 
 
+def _rides_shm(arr):
+    """Is `arr` a view over a shared-memory slot (not a pickled copy)?"""
+    base = arr
+    while isinstance(getattr(base, "base", None), np.ndarray):
+        base = base.base
+    return isinstance(base.base, memoryview)
+
+
 def test_ordered_matches_serial_across_epochs():
+    """A consumer that does not hoard views rides shared memory. What
+    that promises on any box: a batch whose worker certainly had a free
+    slot is a view over it (each worker's first of an epoch: the ring
+    came back whole, but for the one tail batch `b` still names), and no
+    batch went by pickle unless its worker first waited out the whole
+    slot wait (`_SLOT_WAIT_S`: "slot starvation costs a copy, never
+    liveness"), which a consumer starved of the CPU by five other test
+    workers does cause and a quiet box never does."""
     dl = DataLoader(["x", "y"], [[-1, 3], [-1]], ["float32", "int64"],
                     num_workers=2, capacity=4)
     dl.decorate_sample_reader(SampleSrc(23), batch_size=4, drop_last=False)
     try:
         for _epoch in range(3):
             dl.start()
-            heads, shapes, dtypes = [], [], []
+            heads, shapes, dtypes, shm = [], [], [], []
             while True:  # consume WITHOUT hoarding views (fast path)
                 try:
                     b = dl.next()
@@ -112,10 +77,16 @@ def test_ordered_matches_serial_across_epochs():
                 heads.append(float(b["x"][0, 0]))
                 shapes.append(b["x"].shape)
                 dtypes.append(b["y"].dtype)
+                shm.append(_rides_shm(b["x"]))
             assert heads == [0.0, 4.0, 8.0, 12.0, 16.0, 20.0]
             assert shapes[-1] == (3, 3)  # drop_last=False tail
             assert dtypes[0] == np.int64
-        assert dl.stats()["pickle_batches"] == 0  # stayed zero-copy
+            assert shm[:2] == [True, True], shm  # slots came back
+        stats = dl.stats()
+        assert stats["shm_batches"] + stats["pickle_batches"] == 18
+        # stayed zero-copy wherever a slot came free within the wait
+        assert (stats["worker_stall_s"]
+                >= 0.99 * _SLOT_WAIT_S * stats["pickle_batches"]), stats
     finally:
         dl.close()
 
@@ -166,11 +137,7 @@ def test_zero_copy_and_pickle_fallbacks():
         dl.start()
         got = _drain(dl)
         assert dl.stats()["shm_batches"] == 4
-        base = got[0]["x"]
-        while getattr(base, "base", None) is not None and \
-                isinstance(base.base, np.ndarray):
-            base = base.base
-        assert isinstance(base.base, memoryview)  # view over the slot
+        assert _rides_shm(got[0]["x"])  # a view over the slot
     finally:
         dl.close()
     # ... object dtypes fall back to pickle ...
@@ -281,20 +248,6 @@ def _loss_program(reader_factory):
     return mp_, sp, reader, loss
 
 
-class RegressionSrc:
-    """Deterministic linear-regression samples shared by both readers."""
-
-    def __init__(self, n=24, seed=0):
-        r = np.random.RandomState(seed)
-        self.x = r.randn(n, 4).astype(np.float32)
-        self.y = (self.x @ np.arange(1, 5, dtype=np.float32)
-                  ).reshape(n, 1).astype(np.float32)
-
-    def __call__(self):
-        for xi, yi in zip(self.x, self.y):
-            yield (xi, yi)
-
-
 def test_read_op_run_loop_epochs_match_py_reader():
     """Acceptance: the DataLoader drives Executor.run_loop through a
     `read` op with epoch-restart + EOF semantics identical to PyReader —
@@ -379,19 +332,6 @@ def test_read_op_plain_run_epoch_loop():
             assert steps == 4  # 24 / 6
         assert losses[-1] < losses[0] * 0.5
         reader.close()
-
-
-class RawImageSrc:
-    """(HWC uint8 image, label) samples for the vision-mapper test."""
-
-    def __init__(self, n):
-        self.n = n
-
-    def __call__(self):
-        r = np.random.RandomState(3)
-        for i in range(self.n):
-            yield (r.randint(0, 256, (40, 48, 3)).astype(np.uint8),
-                   np.int64(i % 10))
 
 
 def test_image_simple_transform_mapper_in_workers():

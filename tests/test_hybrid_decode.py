@@ -102,6 +102,16 @@ def _rollout(pred, prompts, steps, forced):
     return [np.stack(r) for r in rows]
 
 
+def _reference(seeded, text, rows):
+    """`rows` of the plain reference's logits over `text`, padded to SEQ
+    positions: see `tests/test_laguna_decode.py::_reference`."""
+    padded = np.zeros((SEQ,), np.int64)
+    padded[:len(text)] = text
+    return np.asarray(ref.serve_logits(
+        seeded, jnp.asarray(padded), CFG, CFG["num_hidden_layers"],
+        rows=np.asarray(rows)))
+
+
 def test_prefill_then_decode_matches_the_reference(pred, seeded):
     """Prompts of 5, 21 and 40 tokens in buckets of 16, 32 and 64 (none
     fills its bucket: padding that advanced a state, or a window taken
@@ -115,10 +125,8 @@ def test_prefill_then_decode_matches_the_reference(pred, seeded):
     forced = _prompts([k + 1] * 3, seed=4)
     got = _rollout(pred, prompts, k, forced)
     for p, f, g in zip(prompts, forced, got):
-        full = np.concatenate([p, f[:k]])
-        want = np.asarray(ref.serve_logits(
-            seeded, jnp.asarray(full), CFG, CFG["num_hidden_layers"],
-            rows=np.arange(len(p) - 1, len(p) + k)))
+        want = _reference(seeded, np.concatenate([p, f[:k]]),
+                          np.arange(len(p) - 1, len(p) + k))
         err = (np.linalg.norm(g - want) / np.linalg.norm(want))
         assert err < 2e-4, (len(p), err)
 
@@ -127,9 +135,7 @@ def _greedy_reference(seeded, prompt, n):
     seq = list(prompt)
     out = []
     for _ in range(n):
-        lg = np.asarray(ref.serve_logits(
-            seeded, jnp.asarray(np.array(seq)), CFG,
-            CFG["num_hidden_layers"], rows=np.array([len(seq) - 1])))
+        lg = _reference(seeded, seq, [len(seq) - 1])
         out.append(int(lg[0].argmax()))
         seq.append(out[-1])
     return out

@@ -150,11 +150,21 @@ def probes(pred):
     return prompts, forced, _rollout(pred, prompts, K, forced)
 
 
-def _want(seeded, p, f, variant=""):
-    full = np.concatenate([p, f[:K]])
+def _reference(seeded, text, rows, variant=""):
+    """`rows` of the plain reference's logits over `text`, in one full
+    forward pass. The reference is causal (a row sees nothing after it),
+    so the text is padded to SEQ positions: its per-layer programs then
+    compile once a file, not once a new length (seconds each)."""
+    padded = np.zeros((SEQ,), np.int64)
+    padded[:len(text)] = text
     return np.asarray(ref.serve_logits(
-        seeded, jnp.asarray(full), CFG, N_LAYER,
-        rows=np.arange(len(p) - 1, len(p) + K), variant=variant))
+        seeded, jnp.asarray(padded), CFG, N_LAYER, rows=np.asarray(rows),
+        variant=variant))
+
+
+def _want(seeded, p, f, variant=""):
+    return _reference(seeded, np.concatenate([p, f[:K]]),
+                      np.arange(len(p) - 1, len(p) + K), variant)
 
 
 @_cases("which", range(len(PROBE_LENS)),
@@ -187,9 +197,7 @@ def _is_greedy(seeded, prompt, generated):
     """Each generated token is the argmax of the reference's logits
     over what came before it (one full forward over the whole text)."""
     full = np.concatenate([prompt, generated])
-    lg = np.asarray(ref.serve_logits(
-        seeded, jnp.asarray(full), CFG, N_LAYER,
-        rows=np.arange(len(prompt) - 1, len(full) - 1)))
+    lg = _reference(seeded, full, np.arange(len(prompt) - 1, len(full) - 1))
     return lg.argmax(-1).tolist() == list(generated)
 
 

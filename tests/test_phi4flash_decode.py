@@ -96,11 +96,19 @@ def probes(pred):
     return prompts, forced, [np.stack(r) for r in rows]
 
 
-def _want(seeded, p, f, variant=""):
-    full = np.concatenate([p, f[:K]])
+def _reference(seeded, text, rows, variant=""):
+    """`rows` of the plain reference's logits over `text`, padded to SEQ
+    positions: see `tests/test_laguna_decode.py::_reference`."""
+    padded = np.zeros((SEQ,), np.int64)
+    padded[:len(text)] = text
     return np.asarray(ref.serve_logits(
-        seeded, jnp.asarray(full), CFG, N_LAYER,
-        rows=np.arange(len(p) - 1, len(p) + K), variant=variant))
+        seeded, jnp.asarray(padded), CFG, N_LAYER, rows=np.asarray(rows),
+        variant=variant))
+
+
+def _want(seeded, p, f, variant=""):
+    return _reference(seeded, np.concatenate([p, f[:K]]),
+                      np.arange(len(p) - 1, len(p) + K), variant)
 
 
 @pytest.mark.parametrize("which", range(len(PROBE_LENS)),
@@ -171,8 +179,7 @@ def test_one_row_shortcut_equals_every_layer_on_every_row(seeded):
         np.testing.assert_array_equal(short[1][name], whole[1][name])
     # and it is a shortcut: the tail's MLPs see (4, 1, D), not (4, 32, D)
     assert short[2].count("gmu") == whole[2].count("gmu") == 1
-    want = np.asarray(ref.serve_logits(
-        seeded, jnp.asarray(prompts[1]), CFG, N_LAYER, rows=np.array([29])))
+    want = _reference(seeded, prompts[1], [29])
     assert _rel(short[0][1], want[0]) < 2e-4
 
 
@@ -262,9 +269,7 @@ def test_server_counts_one_slab_and_its_readers(pred):
 
 def _is_greedy(seeded, prompt, generated):
     full = np.concatenate([prompt, generated])
-    lg = np.asarray(ref.serve_logits(
-        seeded, jnp.asarray(full), CFG, N_LAYER,
-        rows=np.arange(len(prompt) - 1, len(full) - 1)))
+    lg = _reference(seeded, full, np.arange(len(prompt) - 1, len(full) - 1))
     return lg.argmax(-1).tolist() == list(generated)
 
 

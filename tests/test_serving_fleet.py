@@ -113,7 +113,8 @@ def test_concurrent_clients_all_rows_correct(fleet, model):
     for t in threads:
         t.start()
     for t in threads:
-        t.join()
+        t.join(timeout=240)  # 20 results, a second each in practice
+    assert not [t for t in threads if t.is_alive()], "a client never ended"
     assert not errs, errs
 
 
@@ -121,15 +122,27 @@ def test_fleet_metrics_merge_with_replica_labels(fleet, model):
     """Every worker's registry rides back over the control pipe labeled
     by replica; the merged snapshot keeps the series collision-free."""
     _model_dir, feed, _want = model
-    # enough parallel traffic that least-outstanding touches BOTH
-    # replicas (a lone request legitimately lands on one)
-    for fut in [fleet.submit((feed[i % 5],)) for i in range(12)]:
-        fut.result(timeout=120)
-    merged = fleet.fleet_metrics()
+
+    def served():
+        # the merge also carries THIS process's own registry, unlabeled:
+        # whatever server an earlier test file ran in this worker
+        merged = fleet.fleet_metrics()
+        series = merged["metrics"].get(
+            "paddle_tpu_predict_requests_total", {"series": []})["series"]
+        return merged, {s["labels"]["replica"] for s in series
+                        if s["labels"].get("path") == "server"
+                        and "replica" in s["labels"]}
+
+    # parallel traffic until least-outstanding has touched BOTH replicas
+    # (a lone request legitimately lands on one, and so may a dozen where
+    # one replica answers before the next request is routed)
+    deadline = time.monotonic() + 120
+    merged, by_replica = served()
+    while len(by_replica) < 2 and time.monotonic() < deadline:
+        for fut in [fleet.submit((feed[i % 5],)) for i in range(12)]:
+            fut.result(timeout=120)
+        merged, by_replica = served()
     assert sorted(merged["replicas"]) == ["replica0", "replica1"]
-    series = merged["metrics"]["paddle_tpu_predict_requests_total"]["series"]
-    by_replica = {s["labels"].get("replica") for s in series
-                  if s["labels"].get("path") == "server"}
     assert by_replica == {"replica0", "replica1"}
 
 

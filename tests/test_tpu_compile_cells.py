@@ -1,0 +1,185 @@
+"""Compile the serving steps of the Laguna and Phi-4-mini-flash cells for
+a DESCRIBED TPU v5e (`tpu_compile_lib.py`): the cells' own programs, from
+their own configuration files. See `test_tpu_compile.py` for what such a
+compile can and cannot say.
+"""
+from __future__ import annotations
+
+import importlib
+import json
+import os
+import re
+import sys
+import types
+
+import numpy as np
+import pytest
+
+import jax
+
+from paddle_tpu.ops import kv_cache as KV
+
+from tpu_compile_lib import (HBM_BYTES, REPO, _serving_step,
+                             _whole_slab_ops)
+from tpu_compile_lib import one_chip, topo  # noqa: F401  (fixtures)
+
+
+def _cell_predictor(model, config, monkeypatch):
+    """A graph-builder-only DecodePredictor of `benchmark/models/<model>`
+    under `benchmark/configs/<config>`, steered to the Pallas paths."""
+    from paddle_tpu.serving.decode import DecodePredictor
+
+    if os.path.join(REPO, "benchmark") not in list(getattr(
+            sys.modules.get("benchmark"), "__path__", [])):
+        sys.modules["benchmark"] = types.ModuleType("benchmark")
+        sys.modules["benchmark"].__path__ = [os.path.join(REPO, "benchmark")]
+    models = importlib.import_module("benchmark.models." + model)
+    with open(os.path.join(REPO, "benchmark", "configs", config)) as f:
+        cfg = json.load(f)
+    monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
+    monkeypatch.setattr(
+        KV, "_use_pallas_decode",
+        lambda s, d: d % 128 == 0 and s % 128 == 0 and s >= 128)
+    pred = DecodePredictor.__new__(DecodePredictor)  # graph builder only
+    pred.config = models.decode_config(cfg, "serve")
+    pred.sample_k, pred.sample_p, pred.temperature = 40, 0.9, 1.0
+    return pred
+
+
+_LAGUNA_CASES = [
+    # id, kind, batch, seq: the Laguna serving cell's own programs
+    # (benchmark/configs/laguna-xs.2.json: 5 layers at published widths,
+    # 64 of 256 experts held, 64 slots of 4096 positions)
+    ("decode-64x4096", "decode", 64, 4096),
+    ("prefill-4x4096", "prefill", 4, 4096),
+]
+
+
+@pytest.mark.slow  # two all-core compiles of a minute; `pytest <this file>`
+@pytest.mark.parametrize("kind,batch,seq", [c[1:] for c in _LAGUNA_CASES],
+                         ids=[c[0] for c in _LAGUNA_CASES])
+def test_laguna_serving_step_compiles(one_chip, monkeypatch, kind, batch,
+                                      seq):
+    """The programs DecodePredictor builds for the Laguna cell (full and
+    sliding layers of 48 / 64 query heads on 8 K/V heads of 128, rotary
+    positions, a dense layer and four of 64 held experts of 256 with a
+    shared one, an untied head over 100,352 ids): they compile for a v5e
+    and fit it. The decode step donates every slab and ring and gets
+    each back in place, with no whole-slab copy, and attends each slab
+    through the in-place kernel (grouped queries); the largest admission
+    (4 prompts of 4096: the token bound) holds one attention kernel a
+    layer (three `ptpu.attn_window`, two flash forwards), in every
+    sparse layer the grouped product as the TPU compiler's own ragged
+    dots (three, and their metadata) inside the loop over blocks of
+    sorted pairs, and temporaries that leave room for the weights and
+    64 slots beside it."""
+    pred = _cell_predictor("laguna_lm", "laguna-xs.2.json", monkeypatch)
+    step_fn, feeds, state, n_cache = _serving_step(pred, kind, batch, seq,
+                                                   one_chip)
+    compiled = jax.jit(step_fn, donate_argnums=(0,)).lower(
+        feeds, state).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < HBM_BYTES, "does not fit one chip: %r" % (mem,)
+    weights = sum(int(np.prod(s.shape)) * 4 for s in state.values())
+    assert 5.7e9 < weights < 5.9e9, weights  # 1.454 B parameters
+    text = compiled.as_text()
+    if kind == "prefill":
+        calls = re.findall(r"%([\w.-]+?)(?:\.\d+)? = [^\n]*"
+                           r'custom_call_target="tpu_custom_call"', text)
+        assert sorted(set(calls)) == [
+            "ptpu.attn_window", "ptpu.flash_fwd", "ragged-dot-metadata",
+            "ragged-dot-none"], sorted(set(calls))
+        assert calls.count("ptpu.attn_window") == 3
+        assert calls.count("ptpu.flash_fwd") == 2
+        assert calls.count("ragged-dot-none") == 3 * 4
+        assert text.count(" while(") >= 4           # a loop a sparse layer
+        slabs = sum(e.nbytes for e in pred.cache_spec(64, 4096))
+        assert weights + slabs + mem.temp_size_in_bytes + (
+            mem.output_size_in_bytes) < 15.5 * 2**30, mem
+        return
+    # 8 K/V heads of float32: a full layer's slabs have the free view,
+    # so its attention is one call of the in-place kernel on the slabs
+    # themselves (the rings keep the lax path); the other Mosaic calls
+    # are the compiler's own ragged dots
+    calls = re.findall(r"%(ptpu\.[\w.]+?)(?:\.\d+)? = [^\n]*"
+                       r'custom_call_target="tpu_custom_call"', text)
+    assert calls == ["ptpu.decode_attn_grouped"] * 2, calls
+    assert text.count("ragged-dot-none") >= 3 * 4
+    spec = pred.cache_spec(batch, seq)
+    assert n_cache == len(spec) == 10
+    assert mem.alias_size_in_bytes >= sum(e.nbytes for e in spec)
+    for shape in {e.shape for e in spec}:
+        copies = [name for op, name, _ in _whole_slab_ops(text, shape)
+                  if op == "copy"]
+        assert not copies, (shape, copies)
+    assert mem.temp_size_in_bytes < 1.5 * 2**30, mem.temp_size_in_bytes
+
+
+_PHI4FLASH_CASES = [
+    # id, kind, batch, seq: the Phi-4-mini-flash serving cell's own
+    # programs (benchmark/configs/phi4-mini-flash.json: 16 layers at
+    # published widths, 64 slots of 4096 positions; the largest
+    # admission is 8 prompts of the 1024 bucket)
+    ("decode-64x4096", "decode", 64, 4096),
+    ("prefill-8x1024", "prefill", 8, 1024),
+]
+
+
+@pytest.mark.parametrize("kind,batch,seq",
+                         [c[1:] for c in _PHI4FLASH_CASES],
+                         ids=[c[0] for c in _PHI4FLASH_CASES])
+def test_phi4flash_serving_step_compiles(one_chip, monkeypatch, kind, batch,
+                                         seq):
+    """The programs DecodePredictor builds for the Phi-4-mini-flash cell
+    (Mamba and sliding layers, the memory's Mamba, ONE full layer, gated
+    memory units and cross layers; differential attention over 40 query
+    heads on 20 key/value heads of 64): they compile for a v5e and fit
+    it beside each other. The decode step donates the one slab, the
+    four rings and the five states and gets each back in place: flat
+    rows, so NO whole-slab copy or relayout for the one-row append (a
+    4-D slab of 10 pair-heads cost four 1.25 GiB copies a step), and a
+    few tens of MB of temporaries; no Mosaic call (the lax paths). The
+    largest admission holds one attention kernel a layer that owns keys
+    (four `ptpu.attn_window`, one flash forward: the cross layers run
+    one query row a prompt) and five scans."""
+    pred = _cell_predictor("phi4flash_lm", "phi4-mini-flash.json", monkeypatch)
+    step_fn, feeds, state, n_cache = _serving_step(pred, kind, batch, seq,
+                                                   one_chip)
+    compiled = jax.jit(step_fn, donate_argnums=(0,)).lower(
+        feeds, state).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < HBM_BYTES, "does not fit one chip: %r" % (mem,)
+    weights = sum(int(np.prod(s.shape)) * 4 for s in state.values())
+    assert 8.7e9 < weights < 8.85e9, weights  # 2.193 B parameters
+    text = compiled.as_text()
+    slabs = sum(e.nbytes for e in pred.cache_spec(64, 4096))
+    if kind == "prefill":
+        calls = re.findall(r"%([\w.-]+?)(?:\.\d+)? = [^\n]*"
+                           r'custom_call_target="tpu_custom_call"', text)
+        assert calls.count("ptpu.attn_window") == 4, calls
+        assert calls.count("ptpu.flash_fwd") == 1, calls
+        # a prefill's cross layers: one query row a prompt on its rows
+        assert calls.count("ptpu.diff_attn_rows") == 3, calls
+        assert text.count(" while(") >= 5           # a scan a Mamba layer
+        assert weights + slabs + mem.temp_size_in_bytes + (
+            mem.output_size_in_bytes) < 15.5 * 2**30, mem
+        return
+    # the full layer and the three cross layers attend the ONE slab
+    # through the kernel over flat rows; the rings keep the lax path
+    calls = re.findall(r"%(ptpu\.[\w.]+?)(?:\.\d+)? = [^\n]*"
+                       r'custom_call_target="tpu_custom_call"', text)
+    assert calls == ["ptpu.diff_attn_rows"] * 4, calls
+    spec = pred.cache_spec(batch, seq)
+    assert n_cache == len(spec) == 20
+    assert mem.alias_size_in_bytes >= sum(e.nbytes for e in spec)
+    big = {e.shape for e in spec if e.nbytes > 2**27}
+    assert big == {(64, 4096, 1280), (64, 512, 1280)}  # the slab, the rings
+    for shape in big:
+        moved = [name for op, name, changed in _whole_slab_ops(text, shape)
+                 if op == "copy" or changed]
+        assert not moved, (shape, moved)
+    assert mem.temp_size_in_bytes < 200 * 2**20, mem.temp_size_in_bytes

@@ -1,0 +1,141 @@
+"""Compile the main path's kernels, alone, for a DESCRIBED TPU v5e
+(`tpu_compile_lib.py`) at the shapes the chip runs them at: the flash
+attention kernels (both layouts, split and fused backward), the decode
+kernels (one K/V head a query head, and grouped queries on a slab of 8),
+the fused LM-head loss gradient, and the kernel over a slab of flat
+rows. See `test_tpu_compile.py` for what such a compile can and cannot
+say.
+"""
+from __future__ import annotations
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.ops import attention as A
+from paddle_tpu.ops import kv_cache as KV
+
+from tpu_compile_lib import (B, D_MODEL, HBM_BYTES, T, VOCAB, _compile,
+                             _compiled, _whole_slab_ops)
+from tpu_compile_lib import one_chip, topo  # noqa: F401  (fixtures)
+
+
+def _attn_loss(kern):
+    def loss(q, k, v):
+        return jnp.sum(jnp.sin(kern(q, k, v, causal=True)
+                               .astype(jnp.float32)))
+    return loss
+
+
+_BTHD = (B, T, 8, 128)
+_ATTN_CASES = [
+    # id, kernel, shape, grads?, fused backward?
+    ("bthd-fwd", "pallas_flash_attention_bthd", _BTHD, False, False),
+    ("bthd-bwd-split", "pallas_flash_attention_bthd", _BTHD, True, False),
+    ("bthd-bwd-fused", "pallas_flash_attention_bthd", _BTHD, True, True),
+    ("bhtd-h8d128-bwd-split", "pallas_flash_attention", (B, 8, T, 128),
+     True, False),
+    ("bhtd-h8d128-bwd-fused", "pallas_flash_attention", (B, 8, T, 128),
+     True, True),
+    ("bhtd-h16d64-bwd-split", "pallas_flash_attention", (B, 16, T, 64),
+     True, False),
+    ("bhtd-h16d64-bwd-fused", "pallas_flash_attention", (B, 16, T, 64),
+     True, True),
+]
+
+
+@pytest.mark.parametrize("kernel,shape,grads,fused",
+                         [c[1:] for c in _ATTN_CASES],
+                         ids=[c[0] for c in _ATTN_CASES])
+def test_flash_attention_kernel_compiles(one_chip, monkeypatch, kernel,
+                                         shape, grads, fused):
+    monkeypatch.setenv("PADDLE_TPU_FLASH_FUSED_BWD", "1" if fused else "0")
+    fn = _attn_loss(getattr(A, kernel))
+    if grads:
+        fn = jax.value_and_grad(fn, argnums=(0, 1, 2))
+    av = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    text = _compile(fn, av, av, av)
+    # fwd alone is one kernel; split backward adds dq + dkv, fused adds one
+    want = 1 if not grads else (2 if fused else 3)
+    assert text.count("tpu_custom_call") >= want
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_decode_attention_kernel_compiles(one_chip, dtype):
+    sds = jax.ShapeDtypeStruct
+    q = sds((8, 1, 8, 128), dtype, sharding=one_chip)
+    kv = sds((8, 1024, 8, 128), dtype, sharding=one_chip)
+    lens = sds((8,), jnp.int32, sharding=one_chip)
+    _compile(KV.pallas_decode_attention, q, kv, kv, lens)
+
+
+_GROUPED_CASES = [
+    # id, query heads, slab or ring rows: the Laguna serving cell's own
+    # shapes (64 slots, 8 K/V heads of 128, float32)
+    ("full-48on8", 48, 4096),
+    ("full-64on8", 64, 4096),
+    ("ring-64on8", 64, 512),
+]
+
+
+@pytest.mark.parametrize("heads,rows", [c[1:] for c in _GROUPED_CASES],
+                         ids=[c[0] for c in _GROUPED_CASES])
+def test_grouped_decode_attention_kernel_compiles(one_chip, heads, rows):
+    """g query heads on a slab of 8 key/value heads: the in-place kernel
+    is handed the slab itself (its text keeps the slab's shape) and no
+    copy, reshape or transpose of it is made around the call."""
+    sds = jax.ShapeDtypeStruct
+    slab = (64, rows, 8, 128)
+    q = sds((64, 1, heads, 128), jnp.float32, sharding=one_chip)
+    kv = sds(slab, jnp.float32, sharding=one_chip)
+    lens = sds((64,), jnp.int32, sharding=one_chip)
+    text = _compile(KV.pallas_decode_attention, q, kv, kv, lens)
+    line, = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert "%ptpu.decode_attn_grouped" in line.split(" = ")[0], line
+    assert line.count("f32[64,%d,8,128]" % rows) >= 2, line
+    moved = [(op, n) for op, n, _ in _whole_slab_ops(text, slab)
+             if op in ("copy", "reshape", "transpose")]
+    assert not moved, moved
+
+
+def test_lm_head_loss_gradient_compiles(one_chip):
+    """(16384 x 1024) . (1024 x 32768): the chunked fused head; it holds
+    no Pallas kernel, so only fit and compile are asserted."""
+    from paddle_tpu.ops.fused_loss import lm_head_loss
+
+    sds = jax.ShapeDtypeStruct
+    x = sds((B * T, D_MODEL), jnp.bfloat16, sharding=one_chip)
+    w = sds((D_MODEL, VOCAB), jnp.float32, sharding=one_chip)
+    b = sds((VOCAB,), jnp.float32, sharding=one_chip)
+    y = sds((B * T,), jnp.int32, sharding=one_chip)
+
+    def loss(x, w, b, y):
+        return jnp.mean(lm_head_loss(4096, x, w, b, y))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, w, b, y).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
+@pytest.mark.parametrize("b,s,h,row", [(64, 4096, 40, 1280),
+                                       (8, 1024, 40, 1280),
+                                       (8, 2048, 16, 512)])
+def test_diff_attn_rows_kernel_compiles(one_chip, b, s, h, row):
+    """The kernel over a slab of flat rows (`ops/diff_attn.py`) at the
+    Phi-4-mini-flash cell's slab, at its largest prefill's rows (a cross
+    layer's one query row a prompt) and at chip_smoke's block: Mosaic
+    takes the lane slices of a (rows, P x 128) block and the (heads, S)
+    score scratch, with no temporaries outside the call."""
+    from paddle_tpu.ops import diff_attn as D
+
+    compiled = _compiled(
+        lambda qp, k, v, n: D.pallas_attend_rows(qp, k, v, n, 0.125),
+        jax.ShapeDtypeStruct((b, 1, h, 128), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((b, s, row), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((b, s, row), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one_chip))
+    assert "ptpu.diff_attn_rows" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20

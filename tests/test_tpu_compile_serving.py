@@ -1,0 +1,165 @@
+"""Compile the serving steps of the OPT-family model and of the hybrid
+for a DESCRIBED TPU v5e (`tpu_compile_lib.py`): the programs
+`DecodePredictor` builds, at the widths the chip and the benchmark's
+cells run them at. See `test_tpu_compile.py` for what such a compile can
+and cannot say.
+"""
+from __future__ import annotations
+
+import jax
+import pytest
+
+from paddle_tpu.ops import kv_cache as KV
+
+from tpu_compile_lib import (D_INNER, D_MODEL, HBM_BYTES, T, VOCAB,
+                             _compiled, _serving_step, _whole_slab_ops)
+from tpu_compile_lib import one_chip, topo  # noqa: F401  (fixtures)
+
+
+_SERVING_CASES = [
+    # id, kind, batch, seq, layers, heads, d_model, d_inner, vocab, tied,
+    # what else `_step` takes
+    ("decode-8x1024", "decode", 8, 1024, 2, 8, D_MODEL, D_INNER, VOCAB,
+     False, {}),
+    ("prefill-8x512", "prefill", 8, 512, 2, 8, D_MODEL, D_INNER, VOCAB,
+     False, {}),
+    # the serving cell's own decode step (OPT-6.7B widths: 32 heads of
+    # 128, 2048 positions, tied table, 4 layers)
+    ("decode-8x2048-h32", "decode", 8, 2048, 4, 32, 4096, 16384, 50272,
+     True, {}),
+    # the other donating steps, at small depth: a speculative round's
+    # verify window, and the decode step over int8 slabs, whose
+    # (slots, seq) scales are a class of donated feeds of their own
+    ("verify-8x1024-w5", "verify", 8, 1024, 2, 8, D_MODEL, D_INNER, VOCAB,
+     False, {"window": 5}),
+    ("decode-8x1024-int8", "decode", 8, 1024, 2, 8, D_MODEL, D_INNER,
+     VOCAB, False, {"kv_dtype": "int8"}),
+]
+
+
+@pytest.mark.parametrize(
+    "kind,batch,seq,n_layer,n_head,d_model,d_inner,vocab,tied,step_kw",
+    [c[1:] for c in _SERVING_CASES], ids=[c[0] for c in _SERVING_CASES])
+def test_serving_step_compiles(one_chip, monkeypatch, kind, batch, seq,
+                               n_layer, n_head, d_model, d_inner, vocab,
+                               tied, step_kw):
+    """The programs DecodePredictor builds for chip_smoke.py's serve
+    phase, 2 layers at full width: the decode step at 8 slots x 1024 (the
+    Pallas decode kernel, feeds donated) and the burst prefill; the
+    decode step at the benchmark's serving widths; and the verify and
+    int8 decode steps. What is compiled is the function `_acquire` jits.
+
+    A step that is fed its cache moves no slab. The float32 kernel reads
+    the (slots, seq, heads, d_head) feed where it lies, so the compiled
+    step holds no `reshape`, `transpose` or layout-changing `copy` of a
+    whole slab (each was a 268 MB relayout, two a layer a step on the
+    chip: PERF.md, PR 25). And every entry comes back in its own feed's
+    buffer: jax pairs a donated feed with the first output of its type,
+    the feeds flatten sorted by name (kcache_0.., vcache_0..), and the
+    step traces the updates in that order whatever `cache_spec`'s
+    (`_pairing_order`), so no same-layout `copy` repairs a crossed
+    pairing (there were 8 in the serving cell's step, 46% of its device
+    time: PERF.md, PR 27), the aliased bytes cover the spec's, and the
+    temporaries are a few MiB."""
+    from paddle_tpu.serving.decode import DecodeConfig, DecodePredictor
+
+    monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
+    monkeypatch.setattr(
+        KV, "_use_pallas_decode",
+        lambda s, d: d % 128 == 0 and s % 128 == 0 and s >= 128)
+    pred = DecodePredictor.__new__(DecodePredictor)  # graph builder only
+    pred.config = DecodeConfig(vocab_size=vocab, n_layer=n_layer,
+                               n_head=n_head, d_model=d_model,
+                               d_inner=d_inner, max_len=max(T, seq),
+                               tie_embeddings=tied)
+    pred.sample_k, pred.sample_p, pred.temperature = 40, 0.9, 1.0
+    pred.draft_n_layer = 1
+    step_fn, feeds, state, n_cache = _serving_step(pred, kind, batch, seq,
+                                                   one_chip, **step_kw)
+    # a verify window attends through the lax path: no Mosaic call
+    mosaic = kind != "verify"
+    compiled = _compiled(step_fn, feeds, state, mosaic=mosaic,
+                         donate_argnums=(0,))
+    text = compiled.as_text()
+    if mosaic:
+        assert text.count("tpu_custom_call") >= n_layer  # one per layer
+    if kind == "prefill":
+        assert n_cache == 0
+        return
+    from paddle_tpu.serving.decode import _aliased_outputs
+
+    spec = pred.cache_spec(batch, seq, step_kw.get("kv_dtype", "float32"))
+    assert n_cache == len(spec)
+    n_out = len(jax.tree_util.tree_leaves(compiled.out_info))
+    assert set(range(n_out - n_cache, n_out)) <= _aliased_outputs(compiled)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(e.nbytes for e in spec)
+    slab = spec[0].shape
+    ops = _whole_slab_ops(text, slab)
+    copies = [name for op, name, _ in ops if op == "copy"]
+    assert not copies, "whole-slab copies in the step: %r" % copies
+    if spec[0].dtype == "float32":
+        moved = [(op, name) for op, name, _ in ops
+                 if op in ("reshape", "transpose")]
+        assert not moved, "whole-slab relayouts in the step: %r" % moved
+    if kind == "decode" and spec[0].dtype == "float32":
+        # the kernel's views of K and V are free
+        assert sum(op == "bitcast" for op, _, _ in ops) >= 2 * n_layer
+    assert mem.temp_size_in_bytes < 16 * 2**20, mem.temp_size_in_bytes
+
+
+_HYBRID_CASES = [
+    # id, kind, batch, seq: one period of 14 layers at the published
+    # widths of the hybrid serving cell (benchmark/configs/jamba2-3b.json)
+    ("decode-64x2048", "decode", 64, 2048),
+    ("prefill-8x512", "prefill", 8, 512),
+]
+
+
+@pytest.mark.parametrize("kind,batch,seq", [c[1:] for c in _HYBRID_CASES],
+                         ids=[c[0] for c in _HYBRID_CASES])
+def test_hybrid_serving_step_compiles(one_chip, monkeypatch, kind, batch,
+                                      seq):
+    """The programs DecodePredictor builds for a hybrid of 13 state-space
+    layers and one attention layer (20 query heads on 1 K/V head of 128,
+    d_inner 5120, state 16): they compile for a v5e and fit it beside
+    nothing else. The decode step donates every cache entry and gets each
+    back in place: its fetches come in the feeds' own (sorted) order, so
+    no recurrent state (64 x 5120 x 16) and no slab is copied to repair a
+    pairing, and the step's temporaries stay small. The prefill holds one
+    `while` a state-space layer (the plain `lax.scan`s) and one Mosaic
+    call (the flash forward of the attention layer)."""
+    from paddle_tpu.serving.decode import DecodeConfig, DecodePredictor
+
+    monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
+    pred = DecodePredictor.__new__(DecodePredictor)  # graph builder only
+    pred.config = DecodeConfig(
+        vocab_size=65536, n_layer=14, n_head=20, d_model=2560, d_inner=8192,
+        max_len=2048, tie_embeddings=True, n_kv_head=1,
+        attn_layer_period=14, attn_layer_offset=7, mamba_d_state=16,
+        mamba_d_conv=4, mamba_dt_rank=160, mamba_expand=2, norm="rms_norm",
+        norm_eps=1e-6, ffn="gated_silu", positions=False, biases=False)
+    pred.sample_k, pred.sample_p, pred.temperature = 40, 0.9, 1.0
+    step_fn, feeds, state, _ = _serving_step(pred, kind, batch, seq,
+                                             one_chip)
+    compiled = jax.jit(step_fn, donate_argnums=(0,)).lower(
+        feeds, state).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < HBM_BYTES, "does not fit one chip: %r" % (mem,)
+    text = compiled.as_text()
+    if kind == "prefill":
+        assert text.count("tpu_custom_call") == 1   # the flash forward
+        assert text.count(" while(") == 13          # one scan a layer
+        return
+    # the lax path of one shared K/V head: no Mosaic call in the step
+    assert "tpu_custom_call" not in text
+    spec = pred.cache_spec(batch, seq)
+    cache_bytes = sum(e.nbytes for e in spec)
+    assert mem.alias_size_in_bytes >= cache_bytes   # every entry in place
+    for shape in {e.shape for e in spec if e.nbytes > 2**24}:
+        copies = [name for op, name, _ in _whole_slab_ops(text, shape)
+                  if op == "copy"]
+        assert not copies, (shape, copies)
+    assert mem.temp_size_in_bytes < 200 * 2**20, mem.temp_size_in_bytes

@@ -700,7 +700,7 @@ def test_acquire_counts_the_cache_entries_fed_and_aliased(decode_dir, kind,
     fed and those of them its `input_output_alias` hands back in place,
     and both on the timeline's compile record, cold and warm. On the CPU
     nothing is donated, so `aliased` is 0 here; on a chip it equals
-    `fed` (tests/test_tpu_compile.py reads the v5e program's map through
+    `fed` (tests/test_tpu_compile_serving.py reads the v5e program's map through
     the same `_aliased_outputs`). A draft step never donates and is not
     counted."""
     from paddle_tpu.serving.decode import DecodePredictor, _aliased_outputs

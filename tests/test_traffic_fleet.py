@@ -232,7 +232,9 @@ def test_scripted_trace_scale_up_burst_kill_drain_shrink(model):
                                        "max": 8}}]), next_sample))
         timer.cancel()
         assert killed, "chaos kill never fired"
-        assert _wait(lambda: router.stats()["dead"] == 0, 30), \
+        # (the scaler thread reaps between its actions, and a scale-up it
+        # is in the middle of is a worker spawn: see step 5's waits)
+        assert _wait(lambda: router.stats()["dead"] == 0, 90), \
             "autoscaler should reap the crashed replica"
         # 4) sustained pressure: the fleet grows back to 2
         reports.append(run_trace(router, trace(
