@@ -1,7 +1,8 @@
 """The quickest proof that the system still starts on the chip.
 
     python chip_smoke.py            # one TPU chip: kernels, train, serve,
-                                    # and a small Laguna-family block
+                                    # and small Laguna-, Phi-4-mini-flash-
+                                    # and Mistral-Small-4-family blocks
     python chip_smoke.py --chips 4  # four chips: the 2x2-mesh trainer only
 
 One process, no children. Drives the two main paths through the entry
@@ -65,6 +66,18 @@ LAGUNA = dict(vocab=4096, d_model=512, head_dim=128, n_kv_head=8,
 PHI4FLASH = dict(vocab=4096, d_model=1024, n_head=16, n_kv_head=8,
                  d_inner=2048, window=512, n_layer=8, seq=2048, slots=8,
                  prompt=700, new_tokens=8, require_tpu=True)
+
+# A Mistral-Small-4-family block (every layer multi-head latent attention
+# over a latent row of 320 floats, then routed experts under a softmax
+# router with a shared one) at the published head widths (64 + 64
+# query/key, 128 value channels, ranks 256 and 64: the flash kernel at a
+# head of 128, a slab row that is no multiple of 128 lanes), YaRN's
+# original context SHORTER than the prompt, so the query scale turns.
+MISTRAL4 = dict(vocab=4096, d_model=1024, n_head=8, q_rank=256, kv_rank=256,
+                nope=64, rope=64, v=128, n_layer=2, n_expert=16, top_k=4,
+                d_expert=256, held=[0, 8], original=512, window=0,
+                seq=2048, slots=8, prompt=700, new_tokens=8,
+                require_tpu=True)
 
 # Tolerances (max abs error over max abs reference, bf16 inputs): one
 # bf16 rounding is 2^-8 = 0.4%; the backward accumulates ~T of them.
@@ -473,6 +486,8 @@ def _serve_described(config, cfg, place, model_dir):
     prompt = np.random.RandomState(3).randint(
         1, cfg["vocab"], cfg["prompt"]).astype(np.int64)
     assert cfg["prompt"] > cfg["window"], "the prompt must wrap the ring"
+    assert cfg["prompt"] > cfg.get("original", 0), (
+        "the prompt must pass YaRN's original context")
     pred = DecodePredictor(model_dir, place=place)
     srv = DecodeServer(pred, slots=cfg["slots"], max_seq=cfg["seq"],
                        max_new_tokens=new)
@@ -588,6 +603,69 @@ def phase_phi4flash(cfg, place):
     _emit("phi4flash", prompt_len=len(prompt), new_tokens=cfg["new_tokens"],
           server_s=serve_s, rollout_tokens_agreeing=agree,
           slab_readers=readers, ok=True)
+    shutil.rmtree(model_dir, ignore_errors=True)
+
+
+def mistral4_config(cfg):
+    from paddle_tpu.ops.mla import softmax_scale
+    from paddle_tpu.serving import DecodeConfig
+
+    n = cfg["n_layer"]
+    return DecodeConfig(
+        cfg["vocab"], n_layer=n, n_head=cfg["n_head"],
+        d_model=cfg["d_model"], d_inner=cfg["d_model"], max_len=cfg["seq"],
+        tie_embeddings=False, layer_types=["latent"] * n,
+        ffn_types=["experts"] * n, q_lora_rank=cfg["q_rank"],
+        kv_lora_rank=cfg["kv_rank"], qk_nope_dim=cfg["nope"],
+        qk_rope_dim=cfg["rope"], v_head_dim=cfg["v"],
+        softmax_scale=softmax_scale(cfg["nope"] + cfg["rope"], 128.0, 1.0),
+        rope={"latent": {"theta": 10000.0, "interleave": True,
+                         "scale_beta": 0.1,
+                         "yarn": {"factor": 128,
+                                  "original_max_position": cfg["original"],
+                                  "beta_fast": 32, "beta_slow": 1}}},
+        n_expert=cfg["n_expert"], expert_top_k=cfg["top_k"],
+        d_expert=cfg["d_expert"], d_shared_expert=cfg["d_expert"],
+        experts_held=cfg["held"], router_score="softmax", router_scale=1.0,
+        norm="rms_norm", norm_eps=1e-6, ffn="gated_silu", positions=False,
+        biases=False)
+
+
+def phase_mistral4(cfg, place):
+    """A Mistral-Small-4-family block: ONE prefill by the expanded path
+    (the flash kernel at a head of 64 + 64), whose prompt passes YaRN's
+    original context, then eight steps by the absorbed path through the
+    latent slab, against the full-forward rollout (a prefill a token,
+    which knows no cache and no absorption). A slab that is copied or
+    relaid for its append shows here, in seconds."""
+    model_dir = os.path.join(OUT_DIR, "mistral4_model")
+    config = mistral4_config(cfg)
+    pred, srv, agree, serve_s, prompt = _serve_described(
+        config, cfg, place, model_dir)
+    counts = srv._step_counts(np.zeros((cfg["slots"],), np.int32), 0)
+    assert counts["latent_row_bytes"] == 4 * config.latent_row
+    pairs = int(srv.moe_load_total.sum())
+    assert pairs > 0, "no pair was booked on a held expert"
+    if cfg["require_tpu"]:
+        text = pred.acquire("prefill", 1, 1024)[0].as_text()
+        kernels = re.findall(r"%(ptpu\.[a-z_]+)[.\d]* = ", text)
+        assert kernels.count("ptpu.flash_fwd") == cfg["n_layer"], (
+            "the prefill does not run one flash forward a layer: %r"
+            % kernels)
+        text = pred.acquire("decode", cfg["slots"], cfg["seq"])[0].as_text()
+        entry = text[text.index("ENTRY"):]
+        slab = "f32[%d,%d,%d]" % (cfg["slots"], cfg["seq"],
+                                  config.latent_row)
+        copies = [ln for ln in entry.splitlines()
+                  if " copy(" in ln and "= " + slab in ln]
+        assert not copies, ("the decode step copies its latent slab: %s"
+                            % copies[0][:200])
+        heads = "f32[%d,%d,%d," % (cfg["slots"], cfg["seq"], cfg["n_head"])
+        assert heads not in text, (
+            "the decode step builds keys or values of every head")
+    _emit("mistral4", prompt_len=len(prompt), new_tokens=cfg["new_tokens"],
+          server_s=serve_s, rollout_tokens_agreeing=agree,
+          expert_pairs=pairs, latent_row=config.latent_row, ok=True)
     shutil.rmtree(model_dir, ignore_errors=True)
 
 
@@ -729,6 +807,8 @@ def main(argv=None):
         phase_laguna(LAGUNA, place)
         gc.collect()
         phase_phi4flash(PHI4FLASH, place)
+        gc.collect()
+        phase_mistral4(MISTRAL4, place)
     _emit("done", seconds=time.perf_counter() - t0,
           jax_cache_hits=cache_events["hits"],
           jax_cache_misses=cache_events["misses"])

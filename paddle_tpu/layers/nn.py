@@ -136,6 +136,11 @@ __all__ = [
     "diff_decode_attention",
     "attn_cross",
     "gmu",
+    "mla_q",
+    "mla_kv",
+    "mla_expand",
+    "mla_decode",
+    "mla_append",
 ]
 
 from .ops import elementwise_add  # re-export for parity
@@ -2545,28 +2550,37 @@ def causal_conv1d_step(x, window, w, bias=None, name=None):
 # ---------------------------------------------------------------------------
 
 
-def rope(x, positions=None, rotary_dim=None, theta=10000.0,
-         attention_factor=1.0, yarn=None, name=None):
-    """Rotary position embedding (half-split convention) over the first
-    ``rotary_dim`` channels of each head of x (B, T, H, Dh), at
-    ``positions`` (B, T) (or (B,) for T = 1; None: 0..T-1). ``yarn``:
-    None, or a dict with factor, original_max_position, beta_fast,
-    beta_slow (YaRN's blended frequencies; ``attention_factor``
-    multiplies cos and sin)."""
-    helper = LayerHelper("rope", name=name)
-    out = helper.create_variable_for_type_inference(x.dtype, shape=x.shape)
-    inputs = {"X": [x]}
-    if positions is not None:
-        inputs["Positions"] = [positions]
-    attrs = {"rotary_dim": int(rotary_dim or x.shape[-1]),
-             "theta": float(theta),
+def _rope_attrs(theta, attention_factor, yarn, interleave):
+    """The rotation's attributes as the ``rope`` and ``mla_*`` ops read
+    them (``ops/rope.py``: ``yarn_of_attrs``)."""
+    attrs = {"theta": float(theta),
              "attention_factor": float(attention_factor)}
+    if interleave:  # written only where set: a program without it is
+        attrs["interleave"] = True  # the program it was (its AOT key)
     if yarn:
         attrs.update(
             factor=float(yarn["factor"]),
             original_max_position=int(yarn["original_max_position"]),
             beta_fast=float(yarn.get("beta_fast", 32.0)),
             beta_slow=float(yarn.get("beta_slow", 1.0)))
+    return attrs
+
+
+def rope(x, positions=None, rotary_dim=None, theta=10000.0,
+         attention_factor=1.0, yarn=None, interleave=False, name=None):
+    """Rotary position embedding over the first ``rotary_dim`` channels
+    of each head of x (B, T, H, Dh), at ``positions`` (B, T) (or (B,)
+    for T = 1; None: 0..T-1): the half-split pairs, or the pairs (2i,
+    2i+1) under ``interleave``. ``yarn``: None, or a dict with factor,
+    original_max_position, beta_fast, beta_slow (YaRN's blended
+    frequencies; ``attention_factor`` multiplies cos and sin)."""
+    helper = LayerHelper("rope", name=name)
+    out = helper.create_variable_for_type_inference(x.dtype, shape=x.shape)
+    inputs = {"X": [x]}
+    if positions is not None:
+        inputs["Positions"] = [positions]
+    attrs = {"rotary_dim": int(rotary_dim or x.shape[-1])}
+    attrs.update(_rope_attrs(theta, attention_factor, yarn, interleave))
     helper.append_op(type="rope", inputs=inputs, outputs={"Out": [out]},
                      attrs=attrs)
     return out
@@ -2738,6 +2752,109 @@ def attn_cross(q, k, v, lengths, lambdas, gain, lam_init, epsilon=1e-5,
     helper.append_op(
         type="attn_cross", inputs=inputs, outputs={"Out": [out]},
         attrs={"lam_init": float(lam_init), "epsilon": float(epsilon)})
+    return out
+
+
+# ---------------------------------------------------------------------------
+# latent attention (kernels: ops/mla.py)
+# ---------------------------------------------------------------------------
+
+
+def _mla_rot(rot):
+    """Attributes of a latent layer's rotation from ``DecodeConfig.
+    rope["latent"]``: theta, yarn, attention_factor, interleave, and the
+    query scale's beta."""
+    attrs = _rope_attrs(rot.get("theta", 10000.0),
+                        rot.get("attention_factor", 1.0), rot.get("yarn"),
+                        rot.get("interleave", False))
+    attrs["scale_beta"] = float(rot.get("scale_beta", 0.0) or 0.0)
+    return attrs
+
+
+def mla_q(x, w_a, gain, w_b, n_head, rope_dim, rot, positions=None,
+          epsilon=1e-6, name=None):
+    """A latent layer's queries: x (B, T, D) -> (B, T, H, nope + rope):
+    ``rms(x w_a) w_b`` by head, each head's last ``rope_dim`` channels
+    rotated at ``positions`` ((B,) at T = 1; None: 0..T-1), the row
+    times the position-dependent query scale where ``rot`` has a
+    ``scale_beta``."""
+    helper = LayerHelper("mla_q", name=name)
+    out = helper.create_variable_for_type_inference(
+        x.dtype, shape=tuple(x.shape[:2]) + (
+            int(n_head), int(w_b.shape[1]) // int(n_head)))
+    inputs = {"X": [x], "WA": [w_a], "Gain": [gain], "WB": [w_b]}
+    if positions is not None:
+        inputs["Positions"] = [positions]
+    attrs = _mla_rot(rot)
+    attrs.update(n_head=int(n_head), rope_dim=int(rope_dim),
+                 epsilon=float(epsilon))
+    helper.append_op(type="mla_q", inputs=inputs, outputs={"Out": [out]},
+                     attrs=attrs)
+    return out
+
+
+def mla_kv(x, w_a, gain, rope_dim, rot, positions=None, epsilon=1e-6,
+           name=None):
+    """The latent row a position keeps: x (B, T, D) -> (B, T, rank +
+    rope) = ``[rms(c_kv) ; rope(k_r)]`` of ``[c_kv ; k_r] = x w_a``."""
+    helper = LayerHelper("mla_kv", name=name)
+    out = helper.create_variable_for_type_inference(
+        x.dtype, shape=tuple(x.shape[:2]) + (int(w_a.shape[1]),))
+    inputs = {"X": [x], "WA": [w_a], "Gain": [gain]}
+    if positions is not None:
+        inputs["Positions"] = [positions]
+    attrs = _mla_rot(rot)
+    attrs.update(rope_dim=int(rope_dim), epsilon=float(epsilon))
+    helper.append_op(type="mla_kv", inputs=inputs, outputs={"Out": [out]},
+                     attrs=attrs)
+    return out
+
+
+def mla_expand(rows, w_b, n_head, nope_dim, name=None):
+    """The expanded path's keys and values from latent rows (B, T, rank
+    + rope): (k (B, T, H, nope + rope), v (B, T, H, v))."""
+    helper = LayerHelper("mla_expand", name=name)
+    b, t, w = rows.shape
+    per_head = int(w_b.shape[1]) // int(n_head)
+    rope_dim = int(w) - int(w_b.shape[0])
+    k = helper.create_variable_for_type_inference(
+        rows.dtype, shape=(b, t, int(n_head), int(nope_dim) + rope_dim))
+    v = helper.create_variable_for_type_inference(
+        rows.dtype, shape=(b, t, int(n_head), per_head - int(nope_dim)))
+    helper.append_op(
+        type="mla_expand", inputs={"Rows": [rows], "WB": [w_b]},
+        outputs={"K": [k], "V": [v]},
+        attrs={"n_head": int(n_head), "nope_dim": int(nope_dim)})
+    return k, v
+
+
+def mla_decode(q, slab, lengths, w_b, scale, name=None):
+    """The absorbed path: q (B, 1, H, nope + rope) attends the latent
+    slab (B, S, rank + rope) up to ``lengths`` (B,) rows -> (B, 1, H,
+    v); no key or value of any head is built."""
+    helper = LayerHelper("mla_decode", name=name)
+    b, _, h, dq = q.shape
+    nope = int(dq) - (int(slab.shape[-1]) - int(w_b.shape[0]))
+    out = helper.create_variable_for_type_inference(
+        q.dtype, shape=(b, 1, h, int(w_b.shape[1]) // int(h) - nope))
+    helper.append_op(
+        type="mla_decode",
+        inputs={"Q": [q], "Cache": [slab], "Lengths": [lengths],
+                "WB": [w_b]},
+        outputs={"Out": [out]}, attrs={"scale": float(scale)})
+    return out
+
+
+def mla_append(slab, row, pos, name=None):
+    """One latent row a slot: ``row`` (B, 1, W) at row ``pos[b]`` of
+    ``slab`` (B, S, W)."""
+    helper = LayerHelper("mla_append", name=name)
+    out = helper.create_variable_for_type_inference(
+        slab.dtype, shape=slab.shape)
+    helper.append_op(
+        type="mla_append",
+        inputs={"Cache": [slab], "New": [row], "Pos": [pos]},
+        outputs={"Out": [out]}, attrs={})
     return out
 
 

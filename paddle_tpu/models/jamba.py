@@ -1,5 +1,5 @@
 """Decoder LMs of a DESCRIBED block: a mixer kind (``mamba`` |
-``attention`` | ``sliding`` | ``gmu`` | ``cross``) times a feed-forward
+``attention`` | ``sliding`` | ``gmu`` | ``cross`` | ``latent``) times a feed-forward
 kind (``dense`` | ``experts``) a layer, one normalization (RMS, or
 LayerNorm with bias) before every mixer and feed-forward and after the
 last layer, no biases but the convolution's and ``dt_proj``'s and,
@@ -25,7 +25,14 @@ where asked, the attention projections'.
   times a gate from the layer's input) and cross layers (``cross``:
   queries of their own on that one layer's K and V) that keep NOTHING;
   every attention differential (``ops/diff_attn.py``), LayerNorm, no
-  positions.
+  positions;
+- Mistral's Mistral-Small-4 (`model_type: mistral4`; DeepSeek-V2's
+  layer): every layer multi-head latent attention (``latent``,
+  ``ops/mla.py``: a prefill EXPANDS the latent rows to K and V of
+  every head and runs the flash kernel, a decode step attends the
+  latent slab itself, ABSORBED), rotary pairs (2i, 2i+1) under YaRN
+  with a position-dependent query scale, then routed experts under a
+  softmax router with a shared one, an untied head.
 
 The serving graphs only (serving/decode.py): ``hybrid_lm_prefill``
 walks padded prompts and returns every layer's cache entries AT EACH
@@ -50,7 +57,9 @@ sliding layer keeps ``kring_i`` / ``vring_i`` (B, window, n_kv_head,
 d_head): position p at row p mod window. Keys are stored ROTATED.
 Under differential attention a slab or ring row is FLAT, (B, S | window,
 n_kv_head * d_head) (``ops/diff_attn.py`` says why); a ``gmu`` or
-``cross`` layer keeps nothing.
+``cross`` layer keeps nothing. A latent layer keeps ONE ``latent_i`` (B,
+S, kv_lora_rank + qk_rope_dim): a position's ``[c_kv ; k_r]``, the
+latent normalised and the shared key row rotated, neither K nor V.
 """
 from __future__ import annotations
 
@@ -60,6 +69,7 @@ from .. import layers
 from ..framework import default_main_program
 from ..initializer import ConstantInitializer, NormalInitializer
 from ..ops import diff_attn as _D
+from ..ops.moe import ROUTER_SCORES
 from ..param_attr import ParamAttr
 from .transformer import sample_next
 
@@ -73,6 +83,8 @@ def cache_names(kind: str, i: int):
         return ["conv_%d" % i, "ssm_%d" % i]
     if kind == "sliding":
         return ["kring_%d" % i, "vring_%d" % i]
+    if kind == "latent":
+        return ["latent_%d" % i]
     return ["kcache_%d" % i, "vcache_%d" % i]
 
 
@@ -221,6 +233,48 @@ def _attention_mixer(u, cfg, name, lengths, cache, i=0, kind="attention"):
     return out, (k, v)
 
 
+def _latent_mixer(u, cfg, name, lengths, cache):
+    """Multi-head latent attention (``ops/mla.py``). ``cache`` is None
+    (prefill: the EXPANDED path, K and V of every head from the latent
+    rows, the causal flash kernel at ``cfg.softmax_scale``; the entry
+    is the prompt's latent rows) or the layer's one entry (one token:
+    append its row at ``lengths``, then the ABSORBED path over the
+    slab). ``W_kvb`` is one parameter for both. Returns (out, (latent
+    rows or slab,))."""
+    B, T, _ = u.shape
+    h, d = cfg.n_head, cfg.d_model
+    nope, rdim, vdim = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    w = NormalInitializer(0.0, 0.02)
+    one = ConstantInitializer(1.0)
+    rot = (cfg.rope or {}).get("latent") or {}
+    scale = (cfg.softmax_scale if cfg.softmax_scale is not None
+             else float(nope + rdim) ** -0.5)
+    at = None if cache is None else lengths
+    q = layers.mla_q(
+        u, _param([d, cfg.q_lora_rank], name + ".q_a.w", w),
+        _param([cfg.q_lora_rank], name + ".q_norm.w", one),
+        _param([cfg.q_lora_rank, h * (nope + rdim)], name + ".q_b.w", w),
+        h, rdim, rot, positions=at, epsilon=cfg.norm_eps)
+    rows = layers.mla_kv(
+        u, _param([d, cfg.latent_row], name + ".kv_a.w", w),
+        _param([cfg.kv_lora_rank], name + ".kv_norm.w", one),
+        rdim, rot, positions=at, epsilon=cfg.norm_eps)
+    w_kvb = _param([cfg.kv_lora_rank, h * (nope + vdim)], name + ".kv_b.w",
+                   w)
+    if cache is None:
+        k, v = layers.mla_expand(rows, w_kvb, h, nope)
+        ctx = layers.fused_attention(q, k, v, causal=True, scale=scale,
+                                     layout="bthd")
+    else:
+        rows = layers.mla_append(cache[0], rows, lengths)
+        kv_lengths = layers.elementwise_add(
+            layers.cast(lengths, "int32"),
+            layers.fill_constant(shape=[B], dtype="int32", value=1))
+        ctx = layers.mla_decode(q, rows, kv_lengths, w_kvb, scale)
+    out = _proj(layers.reshape(ctx, shape=[B, T, h * vdim]), d, name + ".o")
+    return out, (rows,)
+
+
 def _cross_mixer(u, cfg, name, i, kv):
     """Queries of this layer's own on the keys and values ANOTHER layer
     keeps: ``kv`` = (k, v, rows seen (B,)): that layer's slab after this
@@ -306,6 +360,9 @@ def _layer(x, kind, i, cfg, lengths, cache=None, loads=None, shared=None):
         mixed = _gmu_mixer(u, cfg, name + ".gmu", shared["memory"])
     elif kind == "cross":
         mixed = _cross_mixer(u, cfg, name + ".cross", i, shared["kv"])
+    elif kind == "latent":
+        mixed, entries = _latent_mixer(u, cfg, name + ".attention",
+                                       lengths, cache)
     else:
         mixed, entries = _attention_mixer(u, cfg, name + ".attention",
                                           lengths, cache, i, kind)
@@ -347,16 +404,34 @@ def _check(cfg):
         raise ValueError("attn_gate %r: only a sigmoid gate a query head "
                          "('per_head') is built" % (cfg.attn_gate,))
     if "experts" in cfg.ffn_kinds() and (
-            cfg.router_score != "sigmoid" or not cfg.d_shared_expert):
+            cfg.router_score not in ROUTER_SCORES
+            or not cfg.d_shared_expert):
         raise ValueError(
-            "an expert layer is built with sigmoid scores and a shared "
+            "an expert layer is built with %s scores and a shared "
             "expert; got router_score=%r d_shared_expert=%r"
-            % (cfg.router_score, cfg.d_shared_expert))
+            % (" or ".join(ROUTER_SCORES), cfg.router_score,
+               cfg.d_shared_expert))
     for kind, rot in (cfg.rope or {}).items():
+        if kind == "latent":
+            # the whole rope part of a latent head turns; only YaRN
+            # carries the original context the query scale counts in
+            unknown = set(rot) - {"theta", "yarn", "attention_factor",
+                                  "interleave", "scale_beta"}
+            if unknown or (rot.get("scale_beta") and not rot.get("yarn")):
+                raise ValueError(
+                    "rope['latent'] = %r: a latent layer's rotation is "
+                    "theta, yarn, attention_factor, interleave and "
+                    "scale_beta (with yarn's original_max_position)"
+                    % (rot,))
+            continue
         if kind not in ("full", "sliding") or rot.get(
                 "rotary_dim", cfg.d_head) > cfg.d_head:
             raise ValueError("rope[%r] = %r does not describe a rotation "
                              "of a head of %d" % (kind, rot, cfg.d_head))
+    if cfg.has_latent and (cfg.diff_attn or cfg.attn_gate
+                           or cfg.attn_biases):
+        raise ValueError("a latent layer is built without differential "
+                         "attention, an output gate or biases")
     if set(cfg.ffn_kinds()) - {"dense", "experts"} or set(
             cfg.attn_types or ()) - {"full", "sliding"}:
         raise ValueError("ffn_types %r / attn_types %r name a kind no "

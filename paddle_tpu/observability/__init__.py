@@ -60,7 +60,7 @@ __all__ = [
     "REQUEST_PHASE_MS", "TRACE_SPANS", "tracing",
     "TRANSPILE_OPS_REMOVED", "TRANSPILE_OPS_FUSED", "TRANSPILE_PASS_MS",
     "QUANT_CALIB_BATCHES", "QUANT_OPS", "QUANT_PARITY",
-    "FUSED_HEAD_TRACES",
+    "FUSED_HEAD_TRACES", "MLA_TRACES",
 ]
 
 # -- the shared instrument set (registered once, process-wide) -----------
@@ -86,6 +86,13 @@ FUSED_HEAD_TRACES = REGISTRY.counter(
     "path=vocab_parallel|local and the ways the vocabulary is split: "
     "which path a step was compiled with. Counted when the op is traced, "
     "so a step loaded from the executable cache adds nothing")
+MLA_TRACES = REGISTRY.counter(
+    "paddle_tpu_mla_traces_total",
+    "Traces of latent attention (ops/mla.py), by path=expanded (a "
+    "prefill: K and V of every head built from the latent rows) | "
+    "absorbed (a decode step: attention on the latent rows themselves): "
+    "which path a program was traced with. Counted when the op is "
+    "traced, so a program loaded from the executable cache adds nothing")
 CACHE_HITS = REGISTRY.counter(
     "paddle_tpu_compile_cache_hits_total",
     "Compile-cache hits, by kind, program fingerprint, and "

@@ -144,15 +144,37 @@ def flash_attention(q, k, v, causal=False, scale=None, lengths=None,
 # ---------------------------------------------------------------------------
 
 
-def _tpu_params(*dimension_semantics):
+def _tpu_params(*dimension_semantics, vmem_limit_bytes=None):
     """compiler_params kwargs marking grid axes "parallel" (Mosaic may
     split them across megacore on v4/v5p) or "arbitrary" (sequential —
     REQUIRED for axes whose output blocks are revisited/accumulated:
-    the lse row in the fwd kernel, dk/dv in the fused backward)."""
+    the lse row in the fwd kernel, dk/dv in the fused backward).
+    ``vmem_limit_bytes`` raises the kernel's scoped VMEM above the
+    compiler's default where a caller knows its blocks need it."""
     if os.environ.get("PADDLE_TPU_DIM_SEMANTICS", "1") == "0":
         return {}  # kill-switch: restores the pre-semantics kernels
+    more = ({} if vmem_limit_bytes is None
+            else {"vmem_limit_bytes": int(vmem_limit_bytes)})
     return {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=tuple(dimension_semantics))}
+        dimension_semantics=tuple(dimension_semantics), **more)}
+
+
+# what the compiler's default scoped VMEM (16 MiB on a v5e) leaves a
+# kernel's resident K and V, double-buffered, beside its other blocks
+_KV_VMEM_DEFAULT = 12 * 2**20
+
+
+def _kv_vmem_limit(tk: int, d: int, itemsize: int):
+    """Scoped VMEM for a forward kernel that keeps one head's whole K
+    and V resident (``_mha_fwd_call*``: a (1, tk, d) block each, double
+    buffered): None while the default holds them (every sequence up to
+    4,096 float32 rows of 128: the compiled text is what it was), else
+    their bytes and 16 MiB for the other blocks and the score tiles (a
+    prompt bucket of 8,192 or 16,384 rows; a v5e core has 128 MiB)."""
+    resident = 2 * 2 * tk * d * itemsize
+    if resident <= _KV_VMEM_DEFAULT:
+        return None
+    return resident + 16 * 2**20
 
 
 def named_pallas_call(name, kernel, **kw):
@@ -579,7 +601,9 @@ def _mha_fwd_call_bthd(qs, k, v, h, causal, block_q, block_k, interpret,
             jax.ShapeDtypeStruct((b * h, 1, t), jnp.float32),
         ],
         interpret=interpret,
-        **_tpu_params("parallel", "parallel", "arbitrary"),
+        **_tpu_params("parallel", "parallel", "arbitrary",
+                      vmem_limit_bytes=_kv_vmem_limit(
+                          tk, d, jnp.dtype(k.dtype).itemsize)),
     )(qs, k, v)
 
 

@@ -7,8 +7,9 @@ equal the model's forward pass, so this file computes every chosen
 
 - ``moe_route`` (``ptpu.moe_route``): scores over ALL ``E`` experts in
   float32 (the router's matmul at ``highest`` precision: it is tiny and
-  its order decides a discontinuous choice), the ``k`` largest (ties to
-  the lower index), renormalised to sum 1, times ``scale``.
+  its order decides a discontinuous choice), a sigmoid a logit or a
+  softmax over the ``E`` (``ROUTER_SCORES``), the ``k`` largest (ties
+  to the lower index), renormalised to sum 1, times ``scale``.
 - ``moe_experts`` (``ptpu.moe_experts``): the experts HELD here, ``[lo,
   lo + Eh)`` of the ``E`` the router chose among ("route over all,
   compute your own": a chip of an expert-parallel deployment holds a
@@ -41,6 +42,9 @@ MOE_ROUTE = "ptpu.moe_route"
 MOE_EXPERTS = "ptpu.moe_experts"
 MOE_SHARED = "ptpu.moe_shared"
 
+# the score functions ``moe_route`` builds
+ROUTER_SCORES = ("sigmoid", "softmax")
+
 # sorted pairs one iteration gathers and multiplies: a block's rows past
 # the held pairs are padding that still costs (0.2 us a row on a v5e), a
 # single prompt of 1-2 k tokens routes 2-4 k pairs here
@@ -53,15 +57,19 @@ def _silu(x):
 
 def moe_route(x, w_router, top_k, scale=1.0, score="sigmoid"):
     """x (..., D), w_router (D, E) -> (idx (..., k) int32, weights
-    (..., k) float32)."""
+    (..., k) float32). ``score`` "sigmoid": a sigmoid a logit
+    (DeepSeek-V3's router); "softmax": a softmax over all E (Mixtral's:
+    renormalised over the chosen k it equals a softmax over the chosen
+    logits)."""
+    if score not in ROUTER_SCORES:
+        raise ValueError("moe_route: score function %r is not built (%s "
+                         "are)" % (score, ", ".join(ROUTER_SCORES)))
     with jax.named_scope(MOE_ROUTE):
         logits = jnp.matmul(x.astype(jnp.float32),
                             w_router.astype(jnp.float32),
                             precision=lax.Precision.HIGHEST)
-        if score != "sigmoid":
-            raise ValueError("moe_route: score function %r is not built"
-                             % score)
-        s = jax.nn.sigmoid(logits)
+        s = (jax.nn.sigmoid(logits) if score == "sigmoid"
+             else jax.nn.softmax(logits, axis=-1))
         top, idx = lax.top_k(s, int(top_k))
         top = top / jnp.sum(top, axis=-1, keepdims=True)
         return idx.astype(jnp.int32), top * jnp.float32(scale)

@@ -19,6 +19,17 @@ slot's length. Three kinds, by attribute:
   ``beta_slow`` times within the original context, and ``cos``/``sin``
   multiplied by ``attention_factor``.
 
+``interleave`` rotates the pairs ``(2i, 2i+1)`` of those channels in
+place of the half-split pairs ``(i, i + r/2)`` (the published MLA
+checkpoints' ``rope_interleave``): channel ``2i`` is the real and
+``2i+1`` the imaginary part of a complex number turned by ``p *
+inv_freq_i``, and the row keeps its layout.
+
+``query_scale`` is the position-dependent scale of a query row that
+the Llama-4 / Ministral-3 rule adds for long contexts
+(``llama_4_scaling_beta``): ``1 + beta * ln(1 + floor(p / original))``,
+1 for every position below the original context.
+
 The frequencies are host arithmetic in float64 from the attributes (a
 constant of the program); the angles, ``cos`` and ``sin`` are float32.
 """
@@ -67,23 +78,53 @@ def rope_inv_freq(rotary_dim: int, theta: float, yarn=None) -> np.ndarray:
     return inter * ramp + extra * (1.0 - ramp)
 
 
-def rope(x, positions, inv_freq, attention_factor=1.0):
+def _positions(positions, t):
+    """(B | 1, T) float32 from (B, T), (T,), (B,) at T = 1, or None."""
+    if positions is None:
+        return jnp.arange(t, dtype=jnp.float32)[None, :]
+    return positions.reshape(-1, t).astype(jnp.float32)
+
+
+def query_scale(positions, t, beta, original_max_position):
+    """(B | 1, T) float32: ``1 + beta ln(1 + floor(p / original))``."""
+    pos = _positions(positions, t)
+    return 1.0 + float(beta) * jnp.log1p(
+        jnp.floor(pos / float(original_max_position)))
+
+
+def yarn_of_attrs(attr):
+    """The ``yarn`` dict of ``rope_inv_freq`` from an op's attributes
+    (``attr(name, default)``); None where ``factor`` is 0 or absent."""
+    factor = float(attr("factor", 0.0) or 0.0)
+    if not factor:
+        return None
+    return {"factor": factor,
+            "original_max_position": attr("original_max_position", None),
+            "beta_fast": attr("beta_fast", 32.0),
+            "beta_slow": attr("beta_slow", 1.0)}
+
+
+def rope(x, positions, inv_freq, attention_factor=1.0, interleave=False):
     """x (B, T, H, Dh) rotated over its first ``2 * len(inv_freq)``
-    channels at ``positions`` (B, T) (or (T,), or None: 0..T-1)."""
+    channels at ``positions`` (B, T) (or (T,), or None: 0..T-1): the
+    half-split pairs, or the pairs (2i, 2i+1) under ``interleave``."""
     b, t, _, dh = x.shape
     half = len(inv_freq)
     r = 2 * half
     with jax.named_scope(ROPE):
-        if positions is None:
-            pos = jnp.arange(t, dtype=jnp.float32)[None, :]
-        else:
-            pos = positions.reshape(-1, t).astype(jnp.float32)
+        pos = _positions(positions, t)
         ang = pos[:, :, None] * jnp.asarray(inv_freq, jnp.float32)
         cos = (jnp.cos(ang) * attention_factor)[:, :, None, :]
         sin = (jnp.sin(ang) * attention_factor)[:, :, None, :]
         xf = x.astype(jnp.float32)
-        x1, x2 = xf[..., :half], xf[..., half:r]
-        parts = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+        if interleave:
+            pairs = xf[..., :r].reshape(xf.shape[:-1] + (half, 2))
+            x1, x2 = pairs[..., 0], pairs[..., 1]
+            parts = [jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                               axis=-1).reshape(xf.shape[:-1] + (r,))]
+        else:
+            x1, x2 = xf[..., :half], xf[..., half:r]
+            parts = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
         if r < dh:
             parts.append(xf[..., r:])
         return jnp.concatenate(parts, axis=-1).astype(x.dtype)
@@ -93,17 +134,12 @@ def rope(x, positions, inv_freq, attention_factor=1.0):
 def _rope_op(ctx):
     """Inputs X (B, T, H, Dh), optional Positions (B, T) or (B,) for
     T = 1 (absent: 0..T-1). Attrs: rotary_dim, theta, attention_factor,
-    and for YaRN factor, original_max_position, beta_fast, beta_slow
-    (factor 0 or absent: plain). -> Out = X's shape."""
+    interleave, and for YaRN factor, original_max_position, beta_fast,
+    beta_slow (factor 0 or absent: plain). -> Out = X's shape."""
     x = ctx.input("X")
-    factor = float(ctx.attr("factor", 0.0) or 0.0)
-    yarn = None
-    if factor:
-        yarn = {"factor": factor,
-                "original_max_position": ctx.attr("original_max_position"),
-                "beta_fast": ctx.attr("beta_fast", 32.0),
-                "beta_slow": ctx.attr("beta_slow", 1.0)}
     inv = rope_inv_freq(int(ctx.attr("rotary_dim", x.shape[-1])),
-                        float(ctx.attr("theta", 10000.0)), yarn)
+                        float(ctx.attr("theta", 10000.0)),
+                        yarn_of_attrs(ctx.attr))
     return {"Out": rope(x, ctx.input("Positions"), inv,
-                        float(ctx.attr("attention_factor", 1.0) or 1.0))}
+                        float(ctx.attr("attention_factor", 1.0) or 1.0),
+                        bool(ctx.attr("interleave", False)))}

@@ -85,7 +85,8 @@ def kv_slab_slots(budget_bytes: int, config: "DecodeConfig", seq: int,
                   kv_dtype: str = "float32") -> int:
     """How many cache slots one cache byte budget holds at ``seq``
     positions: the continuous-batching capacity arithmetic. A slot
-    costs what ``cache_spec`` says it keeps: per attention layer two
+    costs what ``cache_spec`` says it keeps: per latent layer one slab of
+    seq * (kv_lora_rank + qk_rope_dim) elements, per attention layer two
     slabs of seq * n_kv_head * d_head elements (plus the per-position
     scales when int8: int8 rows cost 1 byte + 4 / (n_head * d_head) of
     scale vs bf16's 2, so one budget holds ~2x the sequences), per
@@ -164,7 +165,18 @@ class DecodeConfig:
     diff_attn.py``: slabs and rings then keep a position's row FLAT,
     ``n_kv_head * d_head`` floats); ``attn_biases`` puts a bias
     on the attention projections alone; ``mamba_norms`` (Jamba's RMS
-    norms on delta, B and C) is true unless a manifest says otherwise."""
+    norms on delta, B and C) is true unless a manifest says otherwise.
+    A ``latent`` layer is multi-head latent attention (``ops/mla.py``):
+    queries through a bottleneck of ``q_lora_rank``, heads of
+    ``qk_nope_dim + qk_rope_dim`` query/key channels (the last
+    ``qk_rope_dim`` rotated by ``rope["latent"]``: theta, yarn,
+    attention_factor, ``interleave``, and ``scale_beta``, the
+    position-dependent query scale) and ``v_head_dim`` value channels,
+    scores times ``softmax_scale`` (None: ``(qk_nope_dim +
+    qk_rope_dim)^-0.5``); its cache entry keeps, for each position, ONE
+    latent row of ``kv_lora_rank + qk_rope_dim`` floats that is neither
+    K nor V (``latent_row``). ``router_score`` names the router's score
+    function ("sigmoid" | "softmax")."""
 
     FIELDS = ("vocab_size", "n_layer", "n_head", "d_model", "d_inner",
               "max_len", "tie_embeddings", "prefix", "eos_id")
@@ -184,8 +196,13 @@ class DecodeConfig:
                    ("router_score", "sigmoid"), ("router_scale", 1.0),
                    ("attn_gate", None), ("rope", None),
                    ("layer_types", None), ("diff_attn", False),
-                   ("attn_biases", False), ("mamba_norms", True))
-    MIXERS = ("mamba", "attention", "sliding", "gmu", "cross")
+                   ("attn_biases", False), ("mamba_norms", True),
+                   ("q_lora_rank", 0), ("kv_lora_rank", 0),
+                   ("qk_nope_dim", 0), ("qk_rope_dim", 0),
+                   ("v_head_dim", 0), ("softmax_scale", None))
+    MIXERS = ("mamba", "attention", "sliding", "gmu", "cross", "latent")
+    LATENT_WIDTHS = ("q_lora_rank", "kv_lora_rank", "qk_nope_dim",
+                     "qk_rope_dim", "v_head_dim")
 
     def __init__(self, vocab_size, n_layer=4, n_head=8, d_model=512,
                  d_inner=2048, max_len=2048, tie_embeddings=False,
@@ -250,6 +267,12 @@ class DecodeConfig:
                 raise ValueError(
                     "layer %d (%s) reads what a %s layer before it hands "
                     "on, and none is" % (i, kind, need))
+        if "latent" in kinds and not all(
+                int(getattr(self, f) or 0) > 0 for f in self.LATENT_WIDTHS):
+            raise ValueError(
+                "a latent layer needs %s; got %s" % (
+                    ", ".join(self.LATENT_WIDTHS),
+                    [getattr(self, f) for f in self.LATENT_WIDTHS]))
         if self.diff_attn and self.n_kv_head % 2:
             raise ValueError(
                 "differential attention pairs its heads: %d key/value "
@@ -287,6 +310,12 @@ class DecodeConfig:
         return self.n_kv_head, self.d_head
 
     @property
+    def latent_row(self) -> int:
+        """Floats of the one row a latent layer keeps a position:
+        ``[c_kv ; k_r]``."""
+        return int(self.kv_lora_rank) + int(self.qk_rope_dim)
+
+    @property
     def tail_start(self) -> int:
         """The first layer from which on no layer owns a cache entry
         (``n_layer`` where the last layer owns one): a prefill runs the
@@ -299,7 +328,8 @@ class DecodeConfig:
 
     def layer_kinds(self) -> List[str]:
         """The mixer of each layer: "attention" | "sliding" | "mamba",
-        and where ``layer_types`` names them "gmu" | "cross" too."""
+        and where ``layer_types`` names them "gmu" | "cross" | "latent"
+        too."""
         if self.layer_types:
             return list(self.layer_types)
         kinds = ["attention" if i % self.attn_layer_period
@@ -321,6 +351,12 @@ class DecodeConfig:
         """Some layer keeps a ring of ``window`` rows: positions that
         left the window are overwritten (no rows to roll back to)."""
         return "sliding" in self.layer_kinds()
+
+    @property
+    def has_latent(self) -> bool:
+        """Some layer keeps latent rows: a row per position that is
+        neither K nor V (no head axis, one array a layer)."""
+        return "latent" in self.layer_kinds()
 
     @property
     def extra_fetches(self) -> List[str]:
@@ -362,6 +398,11 @@ class CacheEntry(collections.namedtuple(
     - ``"rows"``: a row per position (a K/V slab, or an int8 slab's
       scales): axis 1 is the sequence, an admission writes ``[:sp]``
       and a length masks the rest (``per_position`` true);
+    - ``"latent"``: a row per position too, written and masked the same
+      way, but ONE array a layer of ``(slots, seq, kv_lora_rank +
+      qk_rope_dim)``: the normalised latent ``c_kv`` and the rotated key
+      row ``k_r`` all heads share, neither K nor V and with no head
+      axis (``ops/mla.py`` reads it by two paths);
     - ``"state"``: a fixed-size recurrent state, replaced whole;
     - ``"ring"``: the last ``window`` rows of a sliding-window layer at
       ``position mod window``: a prefill hands the ring over as it is
@@ -373,7 +414,8 @@ class CacheEntry(collections.namedtuple(
     @property
     def kind(self) -> str:
         if self.per_position:
-            return "rows"
+            # ``cache_names`` calls a latent layer's entry latent_i
+            return "latent" if self.name.startswith("latent_") else "rows"
         # ``cache_names`` calls a sliding layer's entries kring_i, vring_i
         return "ring" if self.name[1:].startswith("ring") else "state"
 
@@ -397,7 +439,10 @@ def cache_spec(config: DecodeConfig, slots: int, seq: int,
     fetched them in. Any other block: an attention layer's two slabs
     (slots, seq, n_kv_head, d_head), a sliding-window layer's two
     rings ``kring_i``, ``vring_i`` (slots, window, n_kv_head, d_head)
-    whatever ``seq``, a Mamba layer's ``conv_i`` (slots, K - 1,
+    whatever ``seq``, a latent layer's ONE ``latent_i`` (slots, seq,
+    ``config.latent_row``): a row per position that is neither K nor V
+    (``[c_kv ; k_r]``, normalised and rotated: what both of MLA's
+    attention paths read), a Mamba layer's ``conv_i`` (slots, K - 1,
     d_inner) window and ``ssm_i`` (slots, d_inner, N) state, NOTHING for
     a ``gmu`` or a ``cross`` layer (it reads what another layer keeps;
     a slab may so have several readers a step); a slab's or a ring's
@@ -415,7 +460,7 @@ def cache_spec(config: DecodeConfig, slots: int, seq: int,
             "(%s) are float32"
             % (kv_dtype, ", ".join(sorted(set(
                 {"attention": "rows", "sliding": "ring",
-                 "mamba": "state"}.get(k, "none")
+                 "mamba": "state", "latent": "latent"}.get(k, "none")
                 for k in config.layer_kinds())))))
     from ..models.jamba import cache_names
 
@@ -436,6 +481,10 @@ def cache_spec(config: DecodeConfig, slots: int, seq: int,
             out += [CacheEntry(n, ring, "float32", False) for n in names]
             continue
         if not names:  # reads another layer's entries, owns none
+            continue
+        if kind == "latent":
+            out.append(CacheEntry(
+                names[0], (slots, seq, config.latent_row), "float32", True))
             continue
         out += [CacheEntry(n, slab, kv_dtype, True) for n in names]
         if kv_dtype == "int8":
@@ -738,6 +787,16 @@ class DecodePredictor:
                 "'ring'): a position that left the window is overwritten, "
                 "so there are no rows to roll back to or to share"
                 % (what, self.config.window))
+        if self.config.has_latent:
+            raise ValueError(
+                "%s is built for OPT's block only, through graphs that "
+                "read K and V rows; this model's latent layers keep one "
+                "row of %d floats a position (cache entries of kind "
+                "'latent'), which IS a row per position (it could be "
+                "rolled back by length or shared by prefix) but is "
+                "neither K nor V: no verify window, row copy or int8 "
+                "quantisation is built over it"
+                % (what, self.config.latent_row))
         if not self.config.is_opt_block:
             raise ValueError("%s is built for OPT's block only" % what)
 
@@ -1602,7 +1661,8 @@ class DecodeServer:
         # verify window are lax paths of their own; so is a slab of
         # fewer heads than the query that the kernel has no view of)
         self._stream_rows = None
-        if self.kv_dtype == "float32" and not self.speculative:
+        if (self.kv_dtype == "float32" and not self.speculative
+                and not cfg.has_latent):  # absorbed attention: a lax path
             full = [cfg.heads(i) for i, k in enumerate(cfg.layer_kinds())
                     if k == "attention"]
             heads = max(full, default=cfg.n_head)
@@ -1621,6 +1681,10 @@ class DecodeServer:
                               if "cross" in kinds else 0)
         # a prefill runs the layers from here on one row a prompt
         self._has_tail = cfg.tail_start < cfg.n_layer
+        # bytes of one latent row (0: no latent layer); a step's
+        # absorbed attention reads every latent layer's live rows
+        self._latent_row_bytes = (4 * cfg.latent_row if cfg.has_latent
+                                  else 0)
 
     # -- submission (PredictorServer-compatible surface) -------------------
     def submit(self, sample: Sequence[np.ndarray]):
@@ -1817,6 +1881,7 @@ class DecodeServer:
     _moe_layers = ()
     _slab_readers = 0
     _has_tail = False
+    _latent_row_bytes = 0
 
     # prompts one admission prefills at most, while sequences are live,
     # and the bucketed tokens (power-of-two batch x the prompts' bucket)
@@ -1834,7 +1899,8 @@ class DecodeServer:
         4096 with their repeated K/V and expert-sorted copies would not
         fit beside a chip's weights and slabs), and the executables a
         server has to have compiled stay the power-of-two batches up to
-        8 x 2048 and 4 x 4096. The rest waits one decode step. A
+        8 x 2048, 4 x 4096, 2 x 8192 and 1 x 16384 (the last two where
+        a slab is that long). The rest waits one decode step. A
         gang-scheduled server (``continuous=False``) fills its slots at
         once, as it always has."""
         if not self.continuous:
@@ -1895,7 +1961,9 @@ class DecodeServer:
             # the prefill has ended (the host has its logits): no wait
             self._note_load(outs[-1])
         with _tracing.phase("decode.loop.scatter",
-                            **self._scatter_counts(n, [b[1] for b in batch])):
+                            **self._scatter_counts(
+                                n, [b[1] for b in batch],
+                                int(outs[0].shape[0]) * sp)):
             caches = self._scatter_prefill(
                 caches, list(outs[1:1 + len(self._spec)]), free[:n], sp)
         for i, (rid, prompt, max_new, seed) in enumerate(batch):
@@ -1917,7 +1985,7 @@ class DecodeServer:
                 lens[slot] = 0
         return caches
 
-    def _scatter_counts(self, n: int, prompts=()) -> dict:
+    def _scatter_counts(self, n: int, prompts=(), bucket_rows=0) -> dict:
         """What an admission's ``decode.loop.scatter`` phase carries:
         ``entries``, the arrays it scatters into, and ``state_slots``,
         the slots whose fixed-size state it replaces whole (0 for a
@@ -1930,7 +1998,14 @@ class DecodeServer:
         whose last layers own no cache entry, ``prompt_rows``, the real
         prompt rows the prefill walked, and ``tail_rows``, the rows
         those last layers ran on: one a prompt
-        (``DecodeConfig.tail_start``)."""
+        (``DecodeConfig.tail_start``). Of a model with latent layers,
+        ``prompt_rows`` too and ``bucket_rows``, the rows of the
+        prefill's bucket (power-of-two batch x sequence bucket): what
+        its expanded attention and projections ran on, padding
+        included; ``prompts``, how many it held, and ``attn_pairs``, the
+        (query, key) pairs under the causal mask of their live rows
+        (each prompt's ``len (len + 1) / 2``): what model FLOPs of a
+        prefill are counted from."""
         counts = {"entries": len(self._spec),
                   "state_slots": n if self._state_bytes_per_slot else 0}
         if self._ring_window:
@@ -1941,6 +2016,12 @@ class DecodeServer:
         if self._has_tail:
             counts["prompt_rows"] = sum(len(p) for p in prompts)
             counts["tail_rows"] = len(prompts)
+        if self._latent_row_bytes:
+            counts["prompt_rows"] = sum(len(p) for p in prompts)
+            counts["bucket_rows"] = int(bucket_rows)
+            counts["prompts"] = len(prompts)
+            counts["attn_pairs"] = sum(len(p) * (len(p) + 1) // 2
+                                       for p in prompts)
         return counts
 
     def _note_load(self, load):
@@ -2289,7 +2370,10 @@ class DecodeServer:
         Of a model with cross layers, ``slab_readers``: the layers that
         attend the ONE shared slab in this step (``attended`` and
         ``streamed`` count its rows once; its bytes are rows x
-        readers)."""
+        readers). Of a model with latent layers, ``latent_rows``: the
+        live latent rows the step's absorbed attention reads, one
+        latent layer's (= ``attended``; every latent layer reads as
+        many), and ``latent_row_bytes``, the bytes of one such row."""
         rows = self._stream_rows
         streamed = (self.slots * self.seq if rows is None
                     else int((lens // rows + 1).sum()) * rows)
@@ -2304,6 +2388,9 @@ class DecodeServer:
             counts.update(self._moe_last)
         if self._slab_readers:
             counts["slab_readers"] = self._slab_readers
+        if self._latent_row_bytes:
+            counts["latent_rows"] = counts["attended"]
+            counts["latent_row_bytes"] = self._latent_row_bytes
         return counts
 
     def _spec_round(self, drexe, vexe, caches, lens, active, n_active):

@@ -74,6 +74,20 @@ def test_serve_phase_tiny(capsys):
     assert '"second_predictor_traces": 0' in out
 
 
+def test_mistral4_phase_tiny(capsys):
+    """One prefill by the expanded path and eight steps by the absorbed
+    one, against the full-forward rollout; the prompt passes YaRN's
+    original context, so the query scale turns."""
+    tiny = dict(chip_smoke.MISTRAL4, vocab=256, d_model=64, n_head=4,
+                q_rank=32, kv_rank=24, nope=8, rope=8, v=16, n_expert=8,
+                d_expert=24, held=[0, 4], original=16, seq=64, slots=2,
+                prompt=40, require_tpu=False)
+    chip_smoke.phase_mistral4(tiny, fluid.CPUPlace())
+    out = capsys.readouterr().out
+    assert '"phase": "mistral4"' in out and '"latent_row": 32' in out
+    assert '"rollout_tokens_agreeing": 8' in out
+
+
 def test_parallel_phase_on_four_virtual_devices(monkeypatch, capsys):
     """The --chips 4 phase on the CPU mesh, with the attention dispatch
     steered onto the Pallas kernels (interpret mode) so the shard_map

@@ -445,7 +445,8 @@ def test_builders_refuse_what_no_graph_computes():
     for bad, match in [
             (dict(base, biases=True), "no biases"),
             (dict(base, attn_gate="elementwise"), "per_head"),
-            (dict(experts, router_score="softmax"), "sigmoid scores"),
+            (dict(experts, router_score="tanh"),
+             "sigmoid or softmax scores"),
             (dict(experts, d_shared_expert=0), "a shared expert"),
             (dict(base, rope={"full": {"rotary_dim": 64}}), "rotation"),
             (dict(base, ffn_types=["glu"]), "no graph computes")]:

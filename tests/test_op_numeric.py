@@ -666,6 +666,10 @@ COVERED_ELSEWHERE = {
     # over a wrapped ring, cross) and the gated memory unit against
     # plain statements of them: tests/test_diff_attn_ops.py
     "diff_attention", "diff_decode_attention", "attn_cross", "gmu",
+    # latent attention (MLA): tests/test_mla_ops.py (absorbed against
+    # expanded on the same latent rows, the rotation of pairs against
+    # complex numbers, the query scale, the scale a, the append)
+    "mla_q", "mla_kv", "mla_expand", "mla_decode", "mla_append",
     # in-graph sampling: tests/test_sampling_ops.py
     "greedy_sample", "top_k_sample", "top_p_sample",
     # metrics: tests/test_aux.py

@@ -135,15 +135,40 @@ def test_yarn_ramp_of_the_published_numbers():
 
 # -- the router ---------------------------------------------------------------
 
-def test_route_renormalises_scales_and_breaks_ties_low():
+@pytest.mark.parametrize("score", moe.ROUTER_SCORES)
+def test_route_renormalises_scales_and_breaks_ties_low(score):
     x = jnp.eye(3, 4, dtype=jnp.float32)
     w = jnp.asarray([[2.0, 0.0, 2.0, -1.0, 2.0]] * 4, jnp.float32)
-    idx, wt = moe.moe_route(x, w, 2, scale=2.5)
+    idx, wt = moe.moe_route(x, w, 2, scale=2.5, score=score)
     # experts 0, 2 and 4 tie: the two of lower index
     assert idx.tolist() == [[0, 2]] * 3 and idx.dtype == jnp.int32
     np.testing.assert_allclose(wt, np.full((3, 2), 1.25), rtol=1e-6)
-    with pytest.raises(ValueError, match="score function"):
-        moe.moe_route(x, w, 2, score="softmax")
+    with pytest.raises(ValueError, match="score function 'tanh' is not "
+                                         "built .sigmoid, softmax are."):
+        moe.moe_route(x, w, 2, score="tanh")
+
+
+@pytest.mark.parametrize("score", moe.ROUTER_SCORES)
+def test_route_weights_are_the_scores_renormalised_over_the_chosen(score):
+    """The k largest of the scores over ALL experts, renormalised: under
+    "softmax" that is a softmax over the chosen LOGITS alone (the
+    denominator over all experts cancels), under "sigmoid" the chosen
+    sigmoids over their sum; the same experts either way (both scores
+    rise with the logit)."""
+    r = np.random.default_rng(11)
+    x = jnp.asarray(r.normal(size=(7, 16)), jnp.float32)
+    w = jnp.asarray(r.normal(size=(16, 12)), jnp.float32)
+    idx, wt = moe.moe_route(x, w, 3, scale=1.0, score=score)
+    logits = np.asarray(x, np.float64) @ np.asarray(w, np.float64)
+    want_idx = np.argsort(-logits, axis=-1, kind="stable")[:, :3]
+    assert np.asarray(idx).tolist() == want_idx.tolist()
+    chosen = np.take_along_axis(logits, want_idx, axis=-1)
+    if score == "softmax":
+        e = np.exp(chosen - chosen.max(-1, keepdims=True))
+    else:
+        e = 1.0 / (1.0 + np.exp(-chosen))
+    np.testing.assert_allclose(wt, e / e.sum(-1, keepdims=True), rtol=2e-5)
+    np.testing.assert_allclose(np.asarray(wt).sum(-1), 1.0, rtol=1e-6)
 
 
 # -- the expert layer ----------------------------------------------------------

@@ -1,5 +1,5 @@
-"""Compile the serving steps of the Laguna and Phi-4-mini-flash cells for
-a DESCRIBED TPU v5e (`tpu_compile_lib.py`): the cells' own programs, from
+"""Compile the serving steps of the Laguna, Phi-4-mini-flash and
+Mistral-Small-4 cells for a DESCRIBED TPU v5e (`tpu_compile_lib.py`): the cells' own programs, from
 their own configuration files. See `test_tpu_compile.py` for what such a
 compile can and cannot say.
 """
@@ -183,3 +183,72 @@ def test_phi4flash_serving_step_compiles(one_chip, monkeypatch, kind, batch,
                  if op == "copy" or changed]
         assert not moved, (shape, moved)
     assert mem.temp_size_in_bytes < 200 * 2**20, mem.temp_size_in_bytes
+
+
+_MISTRAL4_CASES = [
+    # id, kind, batch, seq: the Mistral-Small-4 serving cell's own
+    # programs (benchmark/configs/mistral-small-4.json: 4 layers at
+    # published widths, 16 of 128 experts held, 32 slots of 16,384
+    # positions; the largest admission is one prompt of the 16,384 bucket)
+    ("decode-32x16384", "decode", 32, 16384),
+    ("prefill-1x16384", "prefill", 1, 16384),
+]
+
+
+@pytest.mark.parametrize("kind,batch,seq",
+                         [c[1:] for c in _MISTRAL4_CASES],
+                         ids=[c[0] for c in _MISTRAL4_CASES])
+def test_mistral4_serving_step_compiles(one_chip, monkeypatch, kind, batch,
+                                        seq):
+    """The programs DecodePredictor builds for the Mistral-Small-4 cell
+    (latent attention: 32 heads of 64 + 64 query/key and 128 value
+    channels over a latent row of 320 floats; a softmax router over 128
+    experts of width 2048, 16 held, and a shared one; an untied head
+    over 16,384 ids): they compile for a v5e and fit it beside each
+    other. The compiler lays the 320-wide row out with the SEQUENCE
+    minor ({1,2,0}: 320 is no multiple of 128 lanes, so no padding to
+    384), and the decode step donates the four latent slabs and gets
+    each back in place in that one layout: no whole-slab copy or
+    relayout for the one-row append (a scatter cost two a layer a
+    step), the absorbed attention as plain fusions (no Mosaic call but
+    the compiler's ragged dots), no K or V of 32 heads anywhere (no
+    array of slots x 16,384 x 32 heads). The largest admission holds one
+    flash forward a layer, whose resident K and V of 16,384 rows need
+    the raised scoped VMEM."""
+    pred = _cell_predictor("mistral4_lm", "mistral-small-4.json",
+                           monkeypatch)
+    step_fn, feeds, state, n_cache = _serving_step(pred, kind, batch, seq,
+                                                   one_chip)
+    compiled = jax.jit(step_fn, donate_argnums=(0,)).lower(
+        feeds, state).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < HBM_BYTES, "does not fit one chip: %r" % (mem,)
+    weights = sum(int(np.prod(s.shape)) * 4 for s in state.values())
+    assert 7.8e9 < weights < 7.9e9, weights  # 1.960 B parameters
+    text = compiled.as_text()
+    slabs = sum(e.nbytes for e in pred.cache_spec(32, 16384))
+    assert round(slabs / 1e9, 2) == 2.68
+    calls = re.findall(r"%([\w.-]+?)(?:\.\d+)? = [^\n]*"
+                       r'custom_call_target="tpu_custom_call"', text)
+    if kind == "prefill":
+        assert calls.count("ptpu.flash_fwd") == 4, calls
+        assert calls.count("ragged-dot-none") == 3 * 4
+        assert weights + slabs + mem.temp_size_in_bytes + (
+            mem.output_size_in_bytes) < 15.5 * 2**30, mem
+        return
+    assert not [c for c in calls if c.startswith("ptpu.")], calls
+    spec = pred.cache_spec(batch, seq)
+    assert n_cache == len(spec) == 4
+    assert mem.alias_size_in_bytes >= sum(e.nbytes for e in spec)
+    (shape,) = {e.shape for e in spec}
+    assert shape == (32, 16384, 320)
+    moved = [name for op, name, changed in _whole_slab_ops(text, shape)
+             if op == "copy" or changed]
+    assert not moved, moved
+    layouts = set(re.findall(r"f32\[32,16384,320\]\{([\d,]+)", text))
+    assert layouts == {"1,2,0"}, layouts
+    # no expanded K or V: nothing of slots x positions x heads
+    assert "f32[32,16384,32," not in text
+    assert mem.temp_size_in_bytes < 300 * 2**20, mem.temp_size_in_bytes
