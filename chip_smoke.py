@@ -634,7 +634,8 @@ def mistral4_config(cfg):
 def phase_mistral4(cfg, place):
     """A Mistral-Small-4-family block: ONE prefill by the expanded path
     (the flash kernel at a head of 64 + 64), whose prompt passes YaRN's
-    original context, then eight steps by the absorbed path through the
+    original context, then eight steps by the absorbed path (the kernel
+    over the slab's transposed view, one call a layer) through the
     latent slab, against the full-forward rollout (a prefill a token,
     which knows no cache and no absorption). A slab that is copied or
     relaid for its append shows here, in seconds."""
@@ -656,10 +657,16 @@ def phase_mistral4(cfg, place):
         entry = text[text.index("ENTRY"):]
         slab = "f32[%d,%d,%d]" % (cfg["slots"], cfg["seq"],
                                   config.latent_row)
-        copies = [ln for ln in entry.splitlines()
-                  if " copy(" in ln and "= " + slab in ln]
+        view = "f32[%d,%d,%d]" % (cfg["slots"], config.latent_row,
+                                  cfg["seq"])  # what the kernel is handed
+        copies = [ln for ln in entry.splitlines() if " copy(" in ln
+                  and ("= " + slab in ln or "= " + view in ln)]
         assert not copies, ("the decode step copies its latent slab: %s"
                             % copies[0][:200])
+        kernels = re.findall(r"%(ptpu\.[a-z_]+)[.\d]* = ", entry)
+        assert kernels.count("ptpu.mla_latent_attn") == cfg["n_layer"], (
+            "the decode step does not run the absorbed attention's kernel "
+            "once a layer: %r" % kernels)
         heads = "f32[%d,%d,%d," % (cfg["slots"], cfg["seq"], cfg["n_head"])
         assert heads not in text, (
             "the decode step builds keys or values of every head")

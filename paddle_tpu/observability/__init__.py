@@ -90,9 +90,9 @@ MLA_TRACES = REGISTRY.counter(
     "paddle_tpu_mla_traces_total",
     "Traces of latent attention (ops/mla.py), by path=expanded (a "
     "prefill: K and V of every head built from the latent rows) | "
-    "absorbed (a decode step: attention on the latent rows themselves): "
-    "which path a program was traced with. Counted when the op is "
-    "traced, so a program loaded from the executable cache adds nothing")
+    "absorbed_kernel | absorbed (a step: attention ON the latent rows, "
+    "by the kernel over live blocks | the lax form over whole slabs). "
+    "Counted when the op is traced: a program loaded from a cache adds 0")
 CACHE_HITS = REGISTRY.counter(
     "paddle_tpu_compile_cache_hits_total",
     "Compile-cache hits, by kind, program fingerprint, and "

@@ -16,8 +16,28 @@ attention paths read it, and they compute the same numbers:
   = ``a [q~_h ; q_rope_h] . row(j)``, ``o~_h = sum_j p_h(j) c_kv(j)``,
   ``o_h = o~_h W^V_h``: attention ON the latent rows, no K or V of any
   head is built, and ``W^K_h``, ``W^V_h`` are views of the one stored
-  ``W_kvb``. An exact lax path: the slab is read as it lies, (B, S,
-  rank + rope), by two products whose contraction is the row.
+  ``W_kvb``. Between ``q~`` and ``o~ W^V`` one of two paths attends
+  the rows, numerics one, chosen from the slab's shape and type and
+  the device (``latent_block_rows``, ``decode_stream_rows``):
+
+  - the Pallas kernel ``ptpu.mla_latent_attn`` (a TPU; a float32 slab
+    whose row and rank fill whole sublane tiles, whose sequence divides
+    into blocks of at least 128 lanes and whose (H, S) scores fit
+    beside them): one call a layer over the slab WHERE IT LIES. The
+    TPU compiler lays a (B, S, 320) slab out with the sequence minor
+    ({1,2,0}: 320 sublane rows of S lanes a slot, no padding to 384
+    lanes) and a Mosaic call wants row-major operands, so the kernel is
+    handed the TRANSPOSED view (B, 320, S), whose row-major form is
+    those very bytes: a bitcast, where a call on (B, S, 320) would be
+    handed a padded copy of the whole slab. A block is (320, lanes) of
+    positions; the lengths are scalar-prefetched and a slot's block
+    index stops at its last live block, so dead rows are neither
+    fetched nor computed; two passes, so that the products round what
+    the lax form's round (the NORMALISED weights).
+  - the exact lax form (``_latent_attend_lax``; every other device,
+    type and shape, and the kernel's reference): the slab is read as
+    it lies, (B, S, rank + rope), by two products whose contraction is
+    the row, every row of every slot whatever its length.
 
 Five ops, one scope each: ``mla_q`` (``ptpu.mla_q``: down projection,
 RMS norm, up projection, the rotation of each head's rope part, the
@@ -27,17 +47,23 @@ position keeps), ``mla_expand`` (``ptpu.mla_expand``), ``mla_decode``
 (``ptpu.mla_decode``) and ``mla_append`` (``ptpu.mla_append``: one row
 a slot at its length, in place under donation).
 ``paddle_tpu_mla_traces_total{path}`` counts which path a program was
-traced with.
+traced with: ``expanded``, ``absorbed_kernel`` or ``absorbed`` (the
+lax form).
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..observability import MLA_TRACES
+from . import attention as _A
+from . import kv_cache as _KV
 from . import rope as _R
 from .ssm import rms_norm as _rms
 from .registry import register_op
@@ -47,6 +73,12 @@ MLA_KV = "ptpu.mla_kv"
 MLA_EXPAND = "ptpu.mla_expand"
 MLA_DECODE = "ptpu.mla_decode"
 MLA_APPEND = "ptpu.mla_append"
+# the absorbed attention's kernel: its call's name in lowered text and
+# device traces. It carries ``latent_`` because a trace's reader tells an
+# event that reads a latent slab by that piece of its text (the feed's
+# name ``latent_i``) or by the slab's shape, and a Mosaic call's text
+# holds neither: its operand is the transposed view's bitcast.
+MLA_LATENT_ATTN = "ptpu.mla_latent_attn"
 
 _NEG = -1e30
 
@@ -124,29 +156,210 @@ def mla_expand(rows, w_kvb, n_head, nope):
                 kv[..., nope:])
 
 
+def _latent_attend_lax(q_row, slab, lens, rank):
+    """The exact lax form of the absorbed attention: scaled query rows
+    q_row (B, H, W) on the slab (B, S, W) as it lies, rows [0, lens)
+    of a slot seen -> (B, H, rank). Both products contract the slab's
+    row; the second also sums the ``rope`` columns, which are dropped
+    (a slice of the slab would be a copy of it). The reference, and the
+    path of every shape and device ``latent_block_rows`` refuses."""
+    s = slab.shape[1]
+    scores = jnp.einsum("bhw,bsw->bhs", q_row, slab)
+    live = jnp.arange(s)[None, None, :] < lens[:, None, None]
+    scores = jnp.where(live, scores, _NEG)
+    m = jnp.max(scores, axis=-1, keepdims=True)
+    p = jnp.where(live, jnp.exp(scores - m), 0.0)
+    p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+    return jnp.einsum("bhs,bsw->bhw", p, slab)[..., :rank]
+
+
+# positions (lanes) a block of the kernel: (320, 1024) float32 is 1.3 MB,
+# both passes' blocks double-buffered 4.6 MB beside 2 MB of scores. On
+# the chip, a call over 32 slots of 16,384 with ~121,000 rows live takes
+# 0.60 ms at 1,024 and at 2,048 lanes (which streams 15% more rows and
+# runs half the grid cells: a cell costs ~0.16 us, live or dead), 0.75
+# at 512 and 1.19 at 256; the lax form takes 1.67 whatever is live
+# (PERF.md, PR 39).
+_LATENT_BLOCK_LANES = 1024
+
+
+def latent_block_rows(s, h, row, rank, dtype, block_s=_LATENT_BLOCK_LANES):
+    """Positions (lanes) per block of the kernel over a (B, s, row)
+    latent slab of ``dtype`` under ``h`` heads, ``rank`` of the row the
+    summed part, or None where the lax path attends it: a type that is
+    not 32 bits wide (a 16-bit slab tiles (16, 128) and is laid out
+    otherwise), a row or a rank that does not fill whole 8-row sublane
+    tiles of the transposed view, a slot's scores (h, s) that do not
+    fit beside the blocks, no block of at least 128 lanes that divides
+    ``s``."""
+    if (jnp.dtype(dtype).itemsize != 4 or row % 8 or rank % 8
+            or h * s * 4 > _KV._GROUPED_SCORE_BYTES):
+        return None
+    lanes = _KV.fit_block_rows(s, block_s)
+    return lanes if lanes is not None and lanes >= 128 else None
+
+
+def decode_stream_rows(s, h, row, rank, dtype):
+    """Rows a block of the absorbed attention brings in on the device a
+    step traced now is bound for, or None where it reads whole slabs
+    (the lax path): what ``kv_cache.decode_stream_rows`` answers for a
+    slab of heads. The kernel's minor dimension is the block of
+    positions, so that is what has to be lane-aligned."""
+    lanes = latent_block_rows(s, h, row, rank, dtype)
+    if lanes is None or not _KV._use_pallas_decode(s, lanes):
+        return None
+    return lanes
+
+
+def _latent_attn_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, s_ref, m_ref,
+                        l_ref, acc_ref, *, block_s, n_blk):
+    """One (slot, step) grid cell, 2 * n_blk steps a slot, over blocks
+    of the slab's TRANSPOSED view (B, W, S): k_ref (1, W, BS) is every
+    float of BS positions, the position on the lanes, and v_ref (1,
+    rank, BS) the ``c_kv`` sublane rows of the same positions (the
+    ``k_r`` rows take no part in the weighted sum). q_ref (1, H, W) is
+    pre-scaled; o_ref (1, H, rank).
+
+    Two passes, as ``kv_cache._decode_attn_grouped_kernel`` and for its
+    reason (the products round what the lax path's round: the scaled
+    query, the rows and the NORMALISED weights; PERF.md, PR 32): steps
+    [0, n_blk) stream the blocks into the scores ``s_ref`` (H, S) and
+    the running maximum, step n_blk sums the weights, steps [n_blk, 2
+    n_blk) stream the same positions again against the normalised
+    weights. Pass one's index stops at the slot's last live block and
+    pass two's waits at block 0 meanwhile: a live block is copied once
+    a pass, a dead one never."""
+    j = pl.program_id(1)
+    length = len_ref[pl.program_id(0)]
+    live_blocks = (length + block_s - 1) // block_s
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when(j < live_blocks)
+    def _():
+        col0 = pl.multiple_of(j * block_s, block_s)
+        s = jnp.dot(q_ref[0], k_ref[0],
+                    preferred_element_type=jnp.float32)       # (H, BS)
+        live = col0 + lax.broadcasted_iota(jnp.int32, s.shape, 1) < length
+        s = jnp.where(live, s, _NEG)
+        s_ref[:, pl.ds(col0, block_s)] = s
+        m_ref[...] = jnp.maximum(m_ref[...],
+                                 jnp.max(s, axis=1, keepdims=True))
+
+    @pl.when(j == n_blk)
+    def _():
+        def add(i, l):
+            s = s_ref[:, pl.ds(pl.multiple_of(i * block_s, block_s), block_s)]
+            return l + jnp.sum(jnp.exp(s - m_ref[...]), axis=1, keepdims=True)
+
+        l_ref[...] = lax.fori_loop(0, live_blocks, add,
+                                   jnp.zeros(l_ref.shape, jnp.float32))
+
+    @pl.when((j >= n_blk) & (j - n_blk < live_blocks))
+    def _():
+        col0 = pl.multiple_of((j - n_blk) * block_s, block_s)
+        p = (jnp.exp(s_ref[:, pl.ds(col0, block_s)] - m_ref[...])
+             / jnp.maximum(l_ref[...], 1e-30))
+        # (H, BS) x (rank, BS): the positions, on both operands' lanes
+        acc_ref[...] += lax.dot_general(
+            p, v_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(j == 2 * n_blk - 1)
+    def _():
+        o_ref[0] = acc_ref[...].astype(o_ref.dtype)
+
+
+def pallas_latent_attend(q_row, slab, lens, rank, block_s=_LATENT_BLOCK_LANES,
+                         interpret=False):
+    """``_latent_attend_lax``'s contract through the kernel, over the
+    slab WHERE IT LIES. On the chip the compiler lays a (B, S, W) slab
+    whose row is no multiple of 128 lanes out with the sequence minor
+    ({1,2,0}: W sublane rows of S lanes a slot), and a Mosaic call wants
+    its operands row-major: handed the (B, S, W) array it would be
+    handed a padded copy of the whole slab, every layer every step. The
+    transposed view (B, W, S), row-major, IS the slab's bytes, so the
+    ``swapaxes`` below is a bitcast in the compiled step (compiled for
+    a described v5e: tests/test_tpu_compile_cells.py)."""
+    b, h, w = q_row.shape
+    s = slab.shape[1]
+    lanes = latent_block_rows(s, h, w, rank, slab.dtype, block_s)
+    if lanes is None:
+        raise ValueError(
+            "no kernel for %d heads on a (%d, %d) %s latent slab of rank "
+            "%d; the lax path attends it"
+            % (h, s, w, jnp.dtype(slab.dtype).name, rank))
+    n_blk = s // lanes
+
+    def last(bi, lens_ref):
+        return jnp.maximum(lens_ref[bi] + lanes - 1, lanes) // lanes - 1
+
+    def k_block(bi, j, lens_ref):
+        # past the slot's last live block: the same block again
+        return bi, 0, jnp.minimum(j, last(bi, lens_ref))
+
+    def v_block(bi, j, lens_ref):
+        # block 0 while pass one streams, then as pass one's
+        return bi, 0, jnp.clip(j - n_blk, 0, last(bi, lens_ref))
+
+    def qo_block(bi, j, lens_ref):
+        return bi, 0, 0
+
+    # a length past the slab would index past the score scratch; the
+    # lax form reads it as "every row"
+    lens = jnp.clip(lens, 0, s)
+    slab_t = jnp.swapaxes(slab, 1, 2)
+    kernel = functools.partial(_latent_attn_kernel, block_s=lanes,
+                               n_blk=n_blk)
+    return _A.named_pallas_call(
+        MLA_LATENT_ATTN, kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, 2 * n_blk),
+            in_specs=[
+                pl.BlockSpec((1, h, w), qo_block),
+                pl.BlockSpec((1, w, lanes), k_block),
+                pl.BlockSpec((1, rank, lanes), v_block),
+            ],
+            out_specs=pl.BlockSpec((1, h, rank), qo_block),
+            scratch_shapes=[pltpu.VMEM((h, s), jnp.float32),
+                            pltpu.VMEM((h, 1), jnp.float32),
+                            pltpu.VMEM((h, 1), jnp.float32),
+                            pltpu.VMEM((h, rank), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, h, rank), jnp.float32),
+        interpret=interpret,
+        **_A._tpu_params("parallel", "arbitrary"),
+    )(lens, q_row, slab_t, slab_t)
+
+
 def mla_decode(q, slab, lengths, w_kvb, scale):
     """The ABSORBED path: q (B, 1, H, nope + rope), the latent slab (B,
     S, rank + rope) with ``lengths`` (B,) live rows a slot -> (B, 1, H,
-    v). Both products contract the slab's row as it lies; the second
-    also sums the ``rope`` columns, which are dropped (a slice of the
-    slab would be a copy of it)."""
+    v). ``q~ = q_nope W^K`` before and ``o~ W^V`` after are plain
+    products; between them the attention on the latent rows runs the
+    kernel over a slot's live blocks where the slab's shape, type and
+    the device allow it (``decode_stream_rows``) and the exact lax
+    form, which reads every row of every slot, elsewhere. A slot of
+    length 0 gives zeros."""
     b, _, h, _ = q.shape
     s, rank = slab.shape[1], w_kvb.shape[0]
     nope = q.shape[-1] - (slab.shape[-1] - rank)
-    MLA_TRACES.inc(path="absorbed")
+    kernel = decode_stream_rows(s, h, slab.shape[-1], rank,
+                                slab.dtype) is not None
+    MLA_TRACES.inc(path="absorbed_kernel" if kernel else "absorbed")
     with jax.named_scope(MLA_DECODE):
         w_k, w_v = _split_kvb(w_kvb, h, nope)
         qf = q[:, 0].astype(jnp.float32)
         q_lat = jnp.einsum("bhd,rhd->bhr", qf[..., :nope], w_k)
         q_row = jnp.concatenate([q_lat, qf[..., nope:]], axis=-1) * scale
-        scores = jnp.einsum("bhw,bsw->bhs", q_row, slab)
-        live = (jnp.arange(s)[None, None, :]
-                < lengths.reshape(-1).astype(jnp.int32)[:, None, None])
-        scores = jnp.where(live, scores, _NEG)
-        m = jnp.max(scores, axis=-1, keepdims=True)
-        p = jnp.where(live, jnp.exp(scores - m), 0.0)
-        p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
-        o_lat = jnp.einsum("bhs,bsw->bhw", p, slab)[..., :rank]
+        lens = lengths.reshape(-1).astype(jnp.int32)
+        if kernel:
+            o_lat = pallas_latent_attend(q_row, slab, lens, rank)
+        else:
+            o_lat = _latent_attend_lax(q_row, slab, lens, rank)
         out = jnp.einsum("bhr,rhd->bhd", o_lat, w_v)
         return out[:, None].astype(q.dtype)
 

@@ -210,9 +210,12 @@ def test_mistral4_serving_step_compiles(one_chip, monkeypatch, kind, batch,
     384), and the decode step donates the four latent slabs and gets
     each back in place in that one layout: no whole-slab copy or
     relayout for the one-row append (a scatter cost two a layer a
-    step), the absorbed attention as plain fusions (no Mosaic call but
-    the compiler's ragged dots), no K or V of 32 heads anywhere (no
-    array of slots x 16,384 x 32 heads). The largest admission holds one
+    step) nor for the absorbed attention, whose kernel
+    (`ptpu.mla_latent_attn`, one call a layer) is handed the slab's
+    TRANSPOSED view, a bitcast of it, and is found by the benchmark's
+    reader of "an event that reads a latent slab"; no K or V of 32
+    heads anywhere (no array of slots x 16,384 x 32 heads). The largest
+    admission holds one
     flash forward a layer, whose resident K and V of 16,384 rows need
     the raised scoped VMEM."""
     pred = _cell_predictor("mistral4_lm", "mistral-small-4.json",
@@ -238,14 +241,32 @@ def test_mistral4_serving_step_compiles(one_chip, monkeypatch, kind, batch,
         assert weights + slabs + mem.temp_size_in_bytes + (
             mem.output_size_in_bytes) < 15.5 * 2**30, mem
         return
-    assert not [c for c in calls if c.startswith("ptpu.")], calls
+    assert [c for c in calls if c.startswith("ptpu.")] == [
+        "ptpu.mla_latent_attn"] * 4, calls
+    mla_cost = importlib.import_module("benchmark.lib.mla_cost")
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "mistral-small-4.json")) as f:
+        pats = mla_cost.patterns(json.load(f))["slab"]
+    kernel_lines = [ln for ln in text.splitlines()
+                    if 'custom_call_target="tpu_custom_call"' in ln
+                    and "%ptpu." in ln.split(" = ")[0]]
+    assert len(kernel_lines) == 4
+    for ln in kernel_lines:
+        assert any(p in ln for p in pats), ln[:300]
+        # the slab's transposed view, row-major: the same bytes
+        assert ln.count("f32[32,320,16384]{2,1,0") >= 2, ln[:600]
     spec = pred.cache_spec(batch, seq)
     assert n_cache == len(spec) == 4
     assert mem.alias_size_in_bytes >= sum(e.nbytes for e in spec)
     (shape,) = {e.shape for e in spec}
     assert shape == (32, 16384, 320)
-    moved = [name for op, name, changed in _whole_slab_ops(text, shape)
-             if op == "copy" or changed]
+    ops = _whole_slab_ops(text, shape)
+    # the kernel's operand is a BITCAST of the slab (other dimensions
+    # and order, the same bytes: no instruction runs for it); anything
+    # else that changes a slab's layout, and any copy, moves 671 MB
+    assert [op for op, _, _ in ops].count("bitcast") == 4
+    moved = [name for op, name, changed in ops
+             if op == "copy" or (changed and op != "bitcast")]
     assert not moved, moved
     layouts = set(re.findall(r"f32\[32,16384,320\]\{([\d,]+)", text))
     assert layouts == {"1,2,0"}, layouts

@@ -2,8 +2,9 @@
 (`tpu_compile_lib.py`) at the shapes the chip runs them at: the flash
 attention kernels (both layouts, split and fused backward), the decode
 kernels (one K/V head a query head, and grouped queries on a slab of 8),
-the fused LM-head loss gradient, and the kernel over a slab of flat
-rows. See `test_tpu_compile.py` for what such a compile can and cannot
+the fused LM-head loss gradient, the kernel over a slab of flat rows,
+and the absorbed latent attention's kernel over its slab's transposed
+view. See `test_tpu_compile.py` for what such a compile can and cannot
 say.
 """
 from __future__ import annotations
@@ -138,4 +139,38 @@ def test_diff_attn_rows_kernel_compiles(one_chip, b, s, h, row):
         jax.ShapeDtypeStruct((b, s, row), jnp.float32, sharding=one_chip),
         jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one_chip))
     assert "ptpu.diff_attn_rows" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+@pytest.mark.parametrize("b,s,h", [(32, 16384, 32), (8, 2048, 8)],
+                         ids=["cell-32x16384", "smoke-8x2048"])
+def test_latent_attention_kernel_compiles(one_chip, b, s, h):
+    """The absorbed attention's kernel (`ops/mla.py`) at the
+    Mistral-Small-4 cell's slab (the transposed view is (32, 320,
+    16384)) and at chip_smoke's: Mosaic takes a (320, lanes) block whose
+    contraction is no multiple of 128, the (rank, lanes) block of the
+    same operand and the (heads, S) score scratch inside the compiler's
+    default scoped VMEM (the call asks for no more); the
+    compiler keeps the slab's parameter in its sequence-minor layout
+    and hands the kernel a BITCAST of it: no copy, no transpose, no
+    temporaries outside the call."""
+    from paddle_tpu.ops import mla
+
+    row, rank = 320, 256
+    slab = (b, s, row)
+    assert mla.latent_block_rows(s, h, row, rank, jnp.float32) == min(
+        s, mla._LATENT_BLOCK_LANES)
+    compiled = _compiled(
+        lambda q, c, n: mla.pallas_latent_attend(q, c, n, rank),
+        jax.ShapeDtypeStruct((b, h, row), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct(slab, jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one_chip))
+    text = compiled.as_text()
+    line, = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert "%ptpu.mla_latent_attn" in line.split(" = ")[0], line
+    assert line.count("f32[%d,%d,%d]{2,1,0" % (b, row, s)) >= 2, line
+    assert "f32[%d,%d,%d]{1,2,0" % slab in text
+    ops = [op for op, _, _ in _whole_slab_ops(text, slab)]
+    assert ops[0] == "parameter" and set(ops[1:]) == {"bitcast"}, ops
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
