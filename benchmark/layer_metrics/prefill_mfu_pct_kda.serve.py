@@ -1,0 +1,44 @@
+"""The prefill's share of the chip's bf16 peak in the traced admissions
+of a configuration with delta-rule (KDA) layers (`prefill_mfu_pct_mla.
+serve`, an accepted file, counts every layer latent): the model FLOPs of
+their LIVE prompt rows (`lib/ling_cost.prefill_flops`: every row through
+the mixers' projections, the dense MLPs, the routers and the shared
+experts; the held pairs the program counted; the chunked delta rule;
+causal attention of the one latent layer over the live (query, key)
+pairs counted once; the head on one row a prompt; not the bucket's
+padding nor the zero channels the flash kernel is handed) over the peak
+x the time inside the `jit_ptpu_prefill_*` module events (first chip).
+The counts are those of the admission's `decode.loop.scatter` phase,
+the first that opens after the program has started. Model FLOPs over
+the peak cannot pass 100%. Nothing where the phases carry no
+`kda_tokens`."""
+from benchmark.lib import ling_cost, program_spans
+from benchmark.lib.trace_reduce import union
+
+LAYER = "model step"
+UNIT = "%"
+MOVES = "serve_tokens_per_s"
+SOURCE = "device_trace"
+
+
+def read(run):
+    spans = program_spans.of_run(run)
+    cfg = run["cfg"]
+    if not spans or "kda_lower_bound" not in cfg or "serve" not in cfg:
+        return None
+    ops = program_spans.first_device(spans["ops"])
+    modules = program_spans.first_device(spans["modules"])
+    busy = union((s, s + d) for _, s, d, _ in ops)
+    admits = ling_cost.admissions(spans, modules, busy, program_spans)
+    spent = sum(t for t, _ in admits)
+    if not admits or spent <= 0:
+        return None
+    flops = sum(ling_cost.prefill_flops(
+        cfg, float(c["prompt_rows"]), float(c["expert_pairs"]),
+        float(c["attn_pairs"]), float(c["prompts"])) for _, c in admits)
+    print("prefill_mfu_pct_kda: %d admissions, %.0f live rows of %.0f "
+          "bucket rows, %.3f TFLOP of the model in %.6f s busy"
+          % (len(admits), sum(float(c["prompt_rows"]) for _, c in admits),
+             sum(float(c["bucket_rows"]) for _, c in admits),
+             flops / 1e12, spent), flush=True)
+    return 100.0 * flops / (run["peaks"]["flops"] * spent)

@@ -79,6 +79,13 @@ MISTRAL4 = dict(vocab=4096, d_model=1024, n_head=8, q_rank=256, kv_rank=256,
                 seq=2048, slots=8, prompt=700, new_tokens=8,
                 require_tpu=True)
 
+# Kimi Delta Attention at Ling-3.0-flash's published head sizes: 32 heads
+# of a 128 x 128 state. Two prompts of 700 and 450 tokens in a bucket of
+# 1,024 (padding inside a chunk), then 8 steps; every fourth channel
+# decays at the gate's bound.
+KDA = dict(heads=32, head_dim=128, batch=2, seq=1024, lengths=[700, 450],
+           steps=8, require_tpu=True)
+
 # Tolerances (max abs error over max abs reference, bf16 inputs): one
 # bf16 rounding is 2^-8 = 0.4%; the backward accumulates ~T of them.
 TOL_ATTN_FWD = 2e-2
@@ -89,6 +96,9 @@ TOL_DECODE_ATTN = 2e-2
 TOL_GREEDY_TIE = 2e-2
 # 2x2-mesh vs single-device loss under AMP O2 (bf16 activations)
 TOL_PARALLEL_LOSS = 2e-2
+# the chunked delta rule (products on the MXU: operands rounded to
+# bfloat16) against one exact float32 step after another
+TOL_KDA = 2e-2
 
 
 def _emit(phase, **fields):
@@ -179,6 +189,49 @@ def phase_kernels(cfg):
             "pallas_decode_attention %s vs reference: %.3g > %.3g"
             % (jnp.dtype(dt).name, e, TOL_DECODE_ATTN))
     _emit("kernels", shape=[b, t, h, d], rel_err=errs, ok=True)
+
+
+def phase_kda(cfg):
+    """`ptpu.kda_scan` (the chunked delta rule of a prefill) and
+    `ptpu.kda_step` (one update) compiled and run on the device, against
+    each other: the scan over a whole text == the scan over its first
+    rows handed to the step, then one step after another (outputs and
+    the last state); a row's padding leaves its state alone."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import kda
+
+    h, d, b, t = cfg["heads"], cfg["head_dim"], cfg["batch"], cfg["seq"]
+    steps = cfg["steps"]
+    lens = np.asarray(cfg["lengths"], np.int32)
+    r = np.random.RandomState(0)
+    q, k, v = (jnp.asarray(r.randn(b, t, h, d), jnp.float32)
+               for _ in range(3))
+    g = -5.0 * r.uniform(size=(b, t, h, d)) ** 3
+    g[..., ::4] = -4.999  # whole chunks at the bound on these channels
+    g = jnp.asarray(g, jnp.float32)
+    beta = jnp.asarray(r.uniform(0.05, 0.95, size=(b, t, h)), jnp.float32)
+    scan = jax.jit(lambda *a: kda.kda_scan(*a, lower_bound=-5.0))
+    step = jax.jit(kda.kda_step)
+    o_all, s_all = scan(q, k, v, g, beta, jnp.asarray(lens))
+    _, state = scan(q, k, v, g, beta, jnp.asarray(lens - steps))
+    outs = []
+    for j in range(steps):
+        at = lens - steps + j
+        row = lambda a: jnp.stack([a[i, at[i]] for i in range(b)])[:, None]  # noqa
+        o, state = step(row(q), row(k), row(v), row(g), row(beta), state)
+        outs.append(np.asarray(o)[:, 0])
+    want = np.stack([np.stack([np.asarray(o_all)[i, lens[i] - steps + j]
+                               for i in range(b)]) for j in range(steps)])
+    errs = {"outputs": _rel_err(np.stack(outs), want),
+            "state": _rel_err(state, s_all)}
+    assert np.isfinite(np.asarray(o_all)).all()
+    for name, e in errs.items():
+        assert e <= TOL_KDA, ("kda_scan vs kda_step, %s: %.3g > %.3g"
+                              % (name, e, TOL_KDA))
+    _emit("kda", shape=[b, t, h, d], lengths=lens.tolist(), steps=steps,
+          rel_err=errs, ok=True)
 
 
 # -- phase 2: train ---------------------------------------------------------
@@ -807,6 +860,7 @@ def main(argv=None):
         phase_parallel(FULL, place)
     else:
         phase_kernels(FULL)
+        phase_kda(KDA)
         phase_train(FULL, place)
         gc.collect()
         phase_serve(FULL, place)

@@ -1158,7 +1158,8 @@ def _infer_moe_route(ctx: InferContext):
 @register_infer("moe_experts")
 def _infer_moe_experts(ctx: InferContext):
     """Out mirrors X (B, T, D); Load is (Eh,) int32 with WGate (Eh, D,
-    F); WDown is (Eh, F, D)."""
+    F), one entry more under ``count_elsewhere``; WDown is (Eh, F,
+    D)."""
     x = ctx.in_info("X")
     g, dn = ctx.in_shape("WGate"), ctx.in_shape("WDown")
     eh = None
@@ -1176,6 +1177,8 @@ def _infer_moe_experts(ctx: InferContext):
                 for a, b in zip(dn, (g[0], g[2], g[1]))):
             raise InferError("WDown%s is not WGate%s transposed"
                              % (render_shape(dn), render_shape(g)))
+    if eh is not None and ctx.attr("count_elsewhere", False):
+        eh += 1
     return {"Out": VarInfo(x.shape, x.dtype),
             "Load": VarInfo((eh,), "int32")}
 

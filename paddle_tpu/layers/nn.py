@@ -141,6 +141,10 @@ __all__ = [
     "mla_expand",
     "mla_decode",
     "mla_append",
+    "mla_attend",
+    "kda_gate",
+    "kda_scan",
+    "kda_step",
 ]
 
 from .ops import elementwise_add  # re-export for parity
@@ -2586,43 +2590,55 @@ def rope(x, positions=None, rotary_dim=None, theta=10000.0,
     return out
 
 
-def moe_route(x, w_router, top_k, scale=1.0, score="sigmoid", name=None):
+def moe_route(x, w_router, top_k, scale=1.0, score="sigmoid", bias=None,
+              n_group=1, topk_group=1, name=None):
     """Router of a routed-expert layer: x (B, T, D), w_router (D, E) ->
     (idx (B, T, k) int32, weights (B, T, k)): scores over all E experts
     in float32, the k largest (ties to the lower index), renormalised
-    to sum 1 and multiplied by ``scale``."""
+    to sum 1 and multiplied by ``scale``. ``bias`` (E,): a selection
+    bias (chosen by score + bias, weighted by score); ``n_group`` > 1:
+    the choice is limited to the ``topk_group`` best groups."""
     helper = LayerHelper("moe_route", name=name)
     shape = tuple(x.shape[:-1]) + (int(top_k),)
     idx = helper.create_variable_for_type_inference("int32", shape=shape)
     w = helper.create_variable_for_type_inference("float32", shape=shape)
-    helper.append_op(
-        type="moe_route", inputs={"X": [x], "W": [w_router]},
-        outputs={"Idx": [idx], "Weights": [w]},
-        attrs={"top_k": int(top_k), "scale": float(scale),
-               "score": str(score)})
+    inputs = {"X": [x], "W": [w_router]}
+    attrs = {"top_k": int(top_k), "scale": float(scale),
+             "score": str(score)}
+    if bias is not None:  # written only where set: a program without
+        inputs["Bias"] = [bias]  # them is the program it was
+    if int(n_group) > 1:
+        attrs.update(n_group=int(n_group), topk_group=int(topk_group))
+    helper.append_op(type="moe_route", inputs=inputs,
+                     outputs={"Idx": [idx], "Weights": [w]}, attrs=attrs)
     return idx, w
 
 
 def moe_experts(x, idx, weights, w_gate, w_up, w_down, expert_lo=0,
-                lengths=None, decode=False, name=None):
+                lengths=None, decode=False, count_elsewhere=False,
+                name=None):
     """The routed experts HELD here, ``[expert_lo, expert_lo + Eh)``:
     every (token, expert) pair that falls on one is computed, whatever
     the load (no capacity, no drop). x (B, T, D); idx, weights from
     ``moe_route``; w_gate, w_up (Eh, D, F), w_down (Eh, F, D) -> (out
     (B, T, D), load (Eh,) int32: pairs each held expert received from
-    real tokens; ``lengths`` (B,) says which tokens are real)."""
+    real tokens; ``lengths`` (B,) says which tokens are real;
+    ``count_elsewhere`` adds a last entry: the real tokens that sent no
+    pair here)."""
     helper = LayerHelper("moe_experts", name=name)
     out = helper.create_variable_for_type_inference(x.dtype, shape=x.shape)
     load = helper.create_variable_for_type_inference(
-        "int32", shape=(w_gate.shape[0],))
+        "int32", shape=(int(w_gate.shape[0]) + bool(count_elsewhere),))
     inputs = {"X": [x], "Idx": [idx], "Weights": [weights],
               "WGate": [w_gate], "WUp": [w_up], "WDown": [w_down]}
     if lengths is not None:
         inputs["Lengths"] = [lengths]
+    attrs = {"expert_lo": int(expert_lo), "decode": bool(decode)}
+    if count_elsewhere:  # written only where set
+        attrs["count_elsewhere"] = True
     helper.append_op(
         type="moe_experts", inputs=inputs,
-        outputs={"Out": [out], "Load": [load]},
-        attrs={"expert_lo": int(expert_lo), "decode": bool(decode)})
+        outputs={"Out": [out], "Load": [load]}, attrs=attrs)
     return out, load
 
 
@@ -2774,7 +2790,8 @@ def _mla_rot(rot):
 def mla_q(x, w_a, gain, w_b, n_head, rope_dim, rot, positions=None,
           epsilon=1e-6, name=None):
     """A latent layer's queries: x (B, T, D) -> (B, T, H, nope + rope):
-    ``rms(x w_a) w_b`` by head, each head's last ``rope_dim`` channels
+    ``rms(x w_a) w_b`` by head (``x w_b`` where ``w_a`` and ``gain``
+    are None: no bottleneck), each head's last ``rope_dim`` channels
     rotated at ``positions`` ((B,) at T = 1; None: 0..T-1), the row
     times the position-dependent query scale where ``rot`` has a
     ``scale_beta``."""
@@ -2782,7 +2799,9 @@ def mla_q(x, w_a, gain, w_b, n_head, rope_dim, rot, positions=None,
     out = helper.create_variable_for_type_inference(
         x.dtype, shape=tuple(x.shape[:2]) + (
             int(n_head), int(w_b.shape[1]) // int(n_head)))
-    inputs = {"X": [x], "WA": [w_a], "Gain": [gain], "WB": [w_b]}
+    inputs = {"X": [x], "WB": [w_b]}
+    if w_a is not None:
+        inputs.update(WA=[w_a], Gain=[gain])
     if positions is not None:
         inputs["Positions"] = [positions]
     attrs = _mla_rot(rot)
@@ -2826,6 +2845,70 @@ def mla_expand(rows, w_b, n_head, nope_dim, name=None):
         outputs={"K": [k], "V": [v]},
         attrs={"n_head": int(n_head), "nope_dim": int(nope_dim)})
     return k, v
+
+
+def mla_attend(q, k, v, scale, name=None):
+    """The expanded path's causal attention where the query/key head
+    width is not the value head's: q, k (B, T, H, dq), v (B, T, H, dv)
+    -> (B, T, H, dv) (``ops/mla.py: mla_attend``)."""
+    helper = LayerHelper("mla_attend", name=name)
+    out = helper.create_variable_for_type_inference(v.dtype, shape=v.shape)
+    helper.append_op(type="mla_attend",
+                     inputs={"Q": [q], "K": [k], "V": [v]},
+                     outputs={"Out": [out]}, attrs={"scale": float(scale)})
+    return out
+
+
+def kda_gate(f, b, a_log, dt_bias, kind, bound, name=None):
+    """A KDA layer's log-decay and write strength: f (B, T, H * dk), b
+    (B, T, H), a_log (H,), dt_bias (H * dk,) -> (g (B, T, H, dk), beta
+    (B, T, H)) (``ops/kda.py: kda_gate``)."""
+    helper = LayerHelper("kda_gate", name=name)
+    bsz, t, h = b.shape
+    g = helper.create_variable_for_type_inference(
+        "float32", shape=(bsz, t, h, int(f.shape[-1]) // int(h)))
+    beta = helper.create_variable_for_type_inference("float32",
+                                                     shape=b.shape)
+    helper.append_op(
+        type="kda_gate",
+        inputs={"F": [f], "B": [b], "ALog": [a_log], "DtBias": [dt_bias]},
+        outputs={"G": [g], "Beta": [beta]},
+        attrs={"kind": str(kind), "bound": float(bound)})
+    return g, beta
+
+
+def kda_scan(q, k, v, g, beta, lengths=None, lower_bound=None, name=None):
+    """The chunked delta rule of a prefill from a zero state: q, k, g
+    (B, T, H, dk), v (B, T, H, dv), beta (B, T, H) -> (o (B, T, H, dv),
+    state (B, H, dk, dv) at each row's length). ``lower_bound``: the
+    gate's least log-decay a token, None where it has none."""
+    helper = LayerHelper("kda_scan", name=name)
+    out = helper.create_variable_for_type_inference(v.dtype, shape=v.shape)
+    state = helper.create_variable_for_type_inference(
+        "float32", shape=(v.shape[0], v.shape[2], q.shape[3], v.shape[3]))
+    inputs = {"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta]}
+    if lengths is not None:
+        inputs["Lengths"] = [lengths]
+    attrs = {} if lower_bound is None else {
+        "lower_bound": float(lower_bound)}
+    helper.append_op(type="kda_scan", inputs=inputs,
+                     outputs={"Out": [out], "State": [state]}, attrs=attrs)
+    return out, state
+
+
+def kda_step(q, k, v, g, beta, state, name=None):
+    """One token of ``kda_scan``: q, k, g (B, 1, H, dk), v (B, 1, H,
+    dv), beta (B, 1, H), state (B, H, dk, dv) -> (o, state out)."""
+    helper = LayerHelper("kda_step", name=name)
+    out = helper.create_variable_for_type_inference(v.dtype, shape=v.shape)
+    new = helper.create_variable_for_type_inference(
+        state.dtype, shape=state.shape)
+    helper.append_op(
+        type="kda_step",
+        inputs={"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta],
+                "State": [state]},
+        outputs={"Out": [out], "StateOut": [new]}, attrs={})
+    return out, new
 
 
 def mla_decode(q, slab, lengths, w_b, scale, name=None):

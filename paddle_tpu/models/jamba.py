@@ -1,5 +1,5 @@
 """Decoder LMs of a DESCRIBED block: a mixer kind (``mamba`` |
-``attention`` | ``sliding`` | ``gmu`` | ``cross`` | ``latent``) times a feed-forward
+``attention`` | ``sliding`` | ``gmu`` | ``cross`` | ``latent`` | ``kda``) times a feed-forward
 kind (``dense`` | ``experts``) a layer, one normalization (RMS, or
 LayerNorm with bias) before every mixer and feed-forward and after the
 last layer, no biases but the convolution's and ``dt_proj``'s and,
@@ -32,7 +32,15 @@ where asked, the attention projections'.
   every head and runs the flash kernel, a decode step attends the
   latent slab itself, ABSORBED), rotary pairs (2i, 2i+1) under YaRN
   with a position-dependent query scale, then routed experts under a
-  softmax router with a shared one, an untied head.
+  softmax router with a shared one, an untied head;
+- inclusionAI's Ling-3.0-flash (`model_type: bailing_hybrid`): five
+  Kimi-Delta-Attention layers (``kda``, ``ops/kda.py``: a matrix state
+  a head written by a delta rule under a decay a channel; a prefill
+  runs the CHUNKED form, a step one update) to one latent layer whose
+  query has no bottleneck and whose query/key head (192) is wider than
+  its value head (128), a sigmoid gate a head on both mixers' output,
+  two leading dense MLPs and then routed experts under a sigmoid
+  router with a selection bias and group-limited choice.
 
 The serving graphs only (serving/decode.py): ``hybrid_lm_prefill``
 walks padded prompts and returns every layer's cache entries AT EACH
@@ -59,7 +67,11 @@ Under differential attention a slab or ring row is FLAT, (B, S | window,
 n_kv_head * d_head) (``ops/diff_attn.py`` says why); a ``gmu`` or
 ``cross`` layer keeps nothing. A latent layer keeps ONE ``latent_i`` (B,
 S, kv_lora_rank + qk_rope_dim): a position's ``[c_kv ; k_r]``, the
-latent normalised and the shared key row rotated, neither K nor V.
+latent normalised and the shared key row rotated, neither K nor V. A
+KDA layer keeps ``convq_i``, ``convk_i``, ``convv_i`` (B, K - 1, H *
+dk), the three convolutions' windows, and ``kda_i`` (B, H, dk, dv), the
+delta rule's state: fixed size, replaced whole by an admission and
+rewritten whole by every step.
 """
 from __future__ import annotations
 
@@ -69,6 +81,7 @@ from .. import layers
 from ..framework import default_main_program
 from ..initializer import ConstantInitializer, NormalInitializer
 from ..ops import diff_attn as _D
+from ..ops.kda import KDA_GATES
 from ..ops.moe import ROUTER_SCORES
 from ..param_attr import ParamAttr
 from .transformer import sample_next
@@ -85,6 +98,9 @@ def cache_names(kind: str, i: int):
         return ["kring_%d" % i, "vring_%d" % i]
     if kind == "latent":
         return ["latent_%d" % i]
+    if kind == "kda":
+        return ["convq_%d" % i, "convk_%d" % i, "convv_%d" % i,
+                "kda_%d" % i]
     return ["kcache_%d" % i, "vcache_%d" % i]
 
 
@@ -224,10 +240,7 @@ def _attention_mixer(u, cfg, name, lengths, cache, i=0, kind="attention"):
             attend = (layers.decode_attn_ring if sliding
                       else layers.decode_attention)
             ctx = attend(q, k, v, kv_lengths)
-    if cfg.attn_gate == "per_head":
-        gate = layers.sigmoid(_proj(u, h, name + ".gate"))
-        ctx = layers.elementwise_mul(
-            ctx, layers.reshape(gate, shape=[B, T, h, 1]))
+    ctx = _head_gate(ctx, u, cfg, name, h)
     out = _proj(layers.reshape(ctx, shape=[B, T, h * dh]), cfg.d_model,
                 name + ".o", bias)
     return out, (k, v)
@@ -250,11 +263,17 @@ def _latent_mixer(u, cfg, name, lengths, cache):
     scale = (cfg.softmax_scale if cfg.softmax_scale is not None
              else float(nope + rdim) ** -0.5)
     at = None if cache is None else lengths
-    q = layers.mla_q(
-        u, _param([d, cfg.q_lora_rank], name + ".q_a.w", w),
-        _param([cfg.q_lora_rank], name + ".q_norm.w", one),
-        _param([cfg.q_lora_rank, h * (nope + rdim)], name + ".q_b.w", w),
-        h, rdim, rot, positions=at, epsilon=cfg.norm_eps)
+    if cfg.q_lora_rank:
+        q = layers.mla_q(
+            u, _param([d, cfg.q_lora_rank], name + ".q_a.w", w),
+            _param([cfg.q_lora_rank], name + ".q_norm.w", one),
+            _param([cfg.q_lora_rank, h * (nope + rdim)], name + ".q_b.w",
+                   w),
+            h, rdim, rot, positions=at, epsilon=cfg.norm_eps)
+    else:  # no bottleneck: one projection from the layer's input
+        q = layers.mla_q(
+            u, None, None, _param([d, h * (nope + rdim)], name + ".q.w", w),
+            h, rdim, rot, positions=at, epsilon=cfg.norm_eps)
     rows = layers.mla_kv(
         u, _param([d, cfg.latent_row], name + ".kv_a.w", w),
         _param([cfg.kv_lora_rank], name + ".kv_norm.w", one),
@@ -263,16 +282,74 @@ def _latent_mixer(u, cfg, name, lengths, cache):
                    w)
     if cache is None:
         k, v = layers.mla_expand(rows, w_kvb, h, nope)
-        ctx = layers.fused_attention(q, k, v, causal=True, scale=scale,
-                                     layout="bthd")
+        if nope + rdim == vdim:
+            ctx = layers.fused_attention(q, k, v, causal=True, scale=scale,
+                                         layout="bthd")
+        else:  # a query/key head wider than the value head
+            ctx = layers.mla_attend(q, k, v, scale)
     else:
         rows = layers.mla_append(cache[0], rows, lengths)
         kv_lengths = layers.elementwise_add(
             layers.cast(lengths, "int32"),
             layers.fill_constant(shape=[B], dtype="int32", value=1))
         ctx = layers.mla_decode(q, rows, kv_lengths, w_kvb, scale)
+    ctx = _head_gate(ctx, u, cfg, name, h)
     out = _proj(layers.reshape(ctx, shape=[B, T, h * vdim]), d, name + ".o")
     return out, (rows,)
+
+
+def _head_gate(ctx, u, cfg, name, h):
+    """ctx (B, T, h, dv) times a sigmoid gate a head from the layer's
+    input where ``cfg.attn_gate`` asks (``name.gate.w``)."""
+    if cfg.attn_gate != "per_head":
+        return ctx
+    B, T = ctx.shape[0], ctx.shape[1]
+    gate = layers.sigmoid(_proj(u, h, name + ".gate"))
+    return layers.elementwise_mul(
+        ctx, layers.reshape(gate, shape=[B, T, h, 1]))
+
+
+def _kda_mixer(u, cfg, name, lengths, cache):
+    """Kimi Delta Attention (``ops/kda.py``): q, k and v each through a
+    causal depthwise convolution and a SiLU, q and k L2-normalised a
+    head, a log-decay a key channel and a write strength a head from
+    the layer's input (``kda_gate``), the delta rule, an RMS norm a
+    head with one gain of ``kda_head_dim`` and the per-head output
+    gate. ``cache`` is None (prefill: the CHUNKED scan from a zero
+    state; the entries are the three windows and the state at
+    ``lengths``) or (window q, window k, window v, state) (one token).
+    No positions: the recurrence carries order. Returns (out,
+    entries)."""
+    B, T, _ = u.shape
+    h, dk, kc = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_conv
+    w = NormalInitializer(0.0, 0.02)
+    mixed, windows = [], []
+    for j, part in enumerate("qkv"):
+        x = _proj(u, h * dk, "%s.%s" % (name, part))
+        conv_w = _param([h * dk, kc], "%s.conv_%s.w" % (name, part), w)
+        if cache is None:
+            x, window = layers.causal_conv1d(x, conv_w, None, lengths)
+        else:
+            x, window = layers.causal_conv1d_step(x, cache[j], conv_w)
+        mixed.append(layers.reshape(layers.swish(x, beta=1.0),
+                                    shape=[B, T, h, dk]))
+        windows.append(window)
+    g, beta = layers.kda_gate(
+        _proj(u, h * dk, name + ".f"), _proj(u, h, name + ".beta"),
+        _param([h], name + ".A_log", ConstantInitializer(0.0)),
+        _param([h * dk], name + ".dt_bias", ConstantInitializer(0.0)),
+        cfg.kda_gate, cfg.kda_gate_bound)
+    if cache is None:
+        o, state = layers.kda_scan(
+            *mixed, g, beta, lengths,
+            lower_bound=(cfg.kda_gate_bound
+                         if cfg.kda_gate == "lower_bound_sigmoid" else None))
+    else:
+        o, state = layers.kda_step(*mixed, g, beta, cache[3])
+    o = _head_gate(_rms(o, name + ".o_norm", cfg.norm_eps), u, cfg, name, h)
+    out = _proj(layers.reshape(o, shape=[B, T, h * dk]), cfg.d_model,
+                name + ".o")
+    return out, tuple(windows) + (state,)
 
 
 def _cross_mixer(u, cfg, name, i, kv):
@@ -326,13 +403,18 @@ def _experts(x, cfg, name, lengths, decode):
     w = NormalInitializer(0.0, 0.02)
     idx, weights = layers.moe_route(
         x, _param([d, cfg.n_expert], name + ".router.w", w),
-        cfg.expert_top_k, scale=cfg.router_scale, score=cfg.router_score)
+        cfg.expert_top_k, scale=cfg.router_scale, score=cfg.router_score,
+        bias=(_param([cfg.n_expert], name + ".router.bias",
+                     NormalInitializer(0.0, 0.01))
+              if cfg.router_bias else None),
+        n_group=cfg.router_groups, topk_group=cfg.router_topk_groups)
     routed, load = layers.moe_experts(
         x, idx, weights,
         _param([hi - lo, d, f], name + ".experts.gate.w", w),
         _param([hi - lo, d, f], name + ".experts.up.w", w),
         _param([hi - lo, f, d], name + ".experts.down.w", w),
-        expert_lo=lo, lengths=lengths, decode=decode)
+        expert_lo=lo, lengths=lengths, decode=decode,
+        count_elsewhere=cfg.router_groups > 1)
     fs = cfg.d_shared_expert
     shared = layers.moe_shared(
         x, _param([d, fs], name + ".shared.gate.w", w),
@@ -363,6 +445,8 @@ def _layer(x, kind, i, cfg, lengths, cache=None, loads=None, shared=None):
     elif kind == "latent":
         mixed, entries = _latent_mixer(u, cfg, name + ".attention",
                                        lengths, cache)
+    elif kind == "kda":
+        mixed, entries = _kda_mixer(u, cfg, name + ".kda", lengths, cache)
     else:
         mixed, entries = _attention_mixer(u, cfg, name + ".attention",
                                           lengths, cache, i, kind)
@@ -428,10 +512,13 @@ def _check(cfg):
                 "rotary_dim", cfg.d_head) > cfg.d_head:
             raise ValueError("rope[%r] = %r does not describe a rotation "
                              "of a head of %d" % (kind, rot, cfg.d_head))
-    if cfg.has_latent and (cfg.diff_attn or cfg.attn_gate
-                           or cfg.attn_biases):
-        raise ValueError("a latent layer is built without differential "
-                         "attention, an output gate or biases")
+    if (cfg.has_latent or "kda" in cfg.layer_kinds()) and (
+            cfg.diff_attn or cfg.attn_biases):
+        raise ValueError("a latent or a KDA layer is built without "
+                         "differential attention and without biases")
+    if "kda" in cfg.layer_kinds() and cfg.kda_gate not in KDA_GATES:
+        raise ValueError("kda_gate %r: a KDA layer's decay gate is %s"
+                         % (cfg.kda_gate, " or ".join(KDA_GATES)))
     if set(cfg.ffn_kinds()) - {"dense", "experts"} or set(
             cfg.attn_types or ()) - {"full", "sliding"}:
         raise ValueError("ffn_types %r / attn_types %r name a kind no "

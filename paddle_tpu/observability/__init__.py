@@ -60,7 +60,7 @@ __all__ = [
     "REQUEST_PHASE_MS", "TRACE_SPANS", "tracing",
     "TRANSPILE_OPS_REMOVED", "TRANSPILE_OPS_FUSED", "TRANSPILE_PASS_MS",
     "QUANT_CALIB_BATCHES", "QUANT_OPS", "QUANT_PARITY",
-    "FUSED_HEAD_TRACES", "MLA_TRACES",
+    "FUSED_HEAD_TRACES", "MLA_TRACES", "MOE_TOKENS_ELSEWHERE",
 ]
 
 # -- the shared instrument set (registered once, process-wide) -----------
@@ -290,6 +290,12 @@ MOE_EXPERT_PAIRS = REGISTRY.counter(
     "paddle_tpu_moe_expert_pairs_total",
     "Token-expert pairs the decode path routed to the experts it holds "
     "(every one computed: the serving expert layer drops none), by layer")
+MOE_TOKENS_ELSEWHERE = REGISTRY.counter(
+    "paddle_tpu_moe_tokens_elsewhere_total",
+    "Real tokens that sent the experts held here NO pair, by layer: "
+    "under group-limited routing with a group a chip, a token whose "
+    "kept groups leave this chip's out (half of all tokens where 4 of 8 "
+    "groups are kept and the groups are even)")
 MOE_LOAD_MAX_OVER_MEAN = REGISTRY.gauge(
     "paddle_tpu_moe_load_max_over_mean",
     "The busiest held expert's pairs over the mean of the held experts', "

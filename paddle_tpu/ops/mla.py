@@ -39,7 +39,10 @@ attention paths read it, and they compute the same numbers:
     it lies, (B, S, rank + rope), by two products whose contraction is
     the row, every row of every slot whatever its length.
 
-Five ops, one scope each: ``mla_q`` (``ptpu.mla_q``: down projection,
+Six ops, one scope each: ``mla_attend`` (``ptpu.mla_attend``: the
+expanded path's causal attention where the query/key head is wider
+than the value head, through the flash kernel at a padded common
+width), ``mla_q`` (``ptpu.mla_q``: down projection,
 RMS norm, up projection, the rotation of each head's rope part, the
 position-dependent query scale), ``mla_kv`` (``ptpu.mla_kv``: down
 projection, RMS norm of ``c_kv``, rotation of ``k_r``: the row a
@@ -71,6 +74,7 @@ from .registry import register_op
 MLA_Q = "ptpu.mla_q"
 MLA_KV = "ptpu.mla_kv"
 MLA_EXPAND = "ptpu.mla_expand"
+MLA_ATTEND = "ptpu.mla_attend"
 MLA_DECODE = "ptpu.mla_decode"
 MLA_APPEND = "ptpu.mla_append"
 # the absorbed attention's kernel: its call's name in lowered text and
@@ -105,13 +109,15 @@ def _rotate(x, positions, rot):
 
 
 def mla_q(u, w_qa, g_q, w_qb, positions, n_head, rope_dim, eps, rot):
-    """u (B, T, D) -> q (B, T, H, nope + rope): ``c_q = rms(u W_qa)``,
-    ``q = c_q W_qb`` by head, each head's LAST ``rope_dim`` channels
-    rotated at ``positions`` (None: 0..T-1), the whole row times the
-    query scale where ``rot["scale_beta"]`` is set."""
+    """u (B, T, D) -> q (B, T, H, nope + rope): ``c_q = rms(u W_qa)``
+    (``c_q = u`` where ``w_qa`` is None: a query with no bottleneck,
+    ``q_lora_rank`` null), ``q = c_q W_qb`` by head, each head's LAST
+    ``rope_dim`` channels rotated at ``positions`` (None: 0..T-1), the
+    whole row times the query scale where ``rot["scale_beta"]`` is
+    set."""
     b, t, _ = u.shape
     with jax.named_scope(MLA_Q):
-        c_q = _rms(jnp.matmul(u, w_qa), g_q, eps)
+        c_q = u if w_qa is None else _rms(jnp.matmul(u, w_qa), g_q, eps)
         q = jnp.matmul(c_q, w_qb).reshape(b, t, n_head, -1)
         nope = q.shape[-1] - rope_dim
         q = jnp.concatenate(
@@ -154,6 +160,28 @@ def mla_expand(rows, w_kvb, n_head, nope):
                                (b, t, n_head, rows.shape[-1] - rank))
         return (jnp.concatenate([kv[..., :nope], k_r], axis=-1),
                 kv[..., nope:])
+
+
+def mla_attend(q, k, v, scale):
+    """The EXPANDED path's causal attention where a head's query/key
+    width is not its value width (192 / 128): q, k (B, T, H, dq), v (B,
+    T, H, dv) -> (B, T, H, dv). The flash kernels take ONE head width,
+    a multiple of the 128 lanes, so q, k and v are padded with zero
+    channels to the next such width (256) and the output's first ``dv``
+    channels are kept: exact (a zero channel adds nothing to a score,
+    and a zero value channel is a zero output channel), at 512 / 320 of
+    the products' FLOPs. A flash forward with a value width of its own
+    would save that; the kernels of the plain models stay as they
+    are."""
+    dq, dv = q.shape[-1], v.shape[-1]
+    with jax.named_scope(MLA_ATTEND):
+        width = -(-max(dq, dv) // 128) * 128
+
+        def pad(x):
+            return jnp.pad(x, ((0, 0),) * 3 + ((0, width - x.shape[-1]),))
+
+        return _A.causal_attention_bthd(pad(q), pad(k), pad(v),
+                                        scale)[..., :dv]
 
 
 def _latent_attend_lax(q_row, slab, lens, rank):
@@ -401,9 +429,9 @@ def _rot_of(ctx):
 
 @register_op("mla_q")
 def _mla_q_op(ctx):
-    """Inputs X (B, T, D), WA (D, q_rank), Gain (q_rank,), WB (q_rank,
-    H * (nope + rope)), optional Positions (B,) at T = 1 (absent:
-    0..T-1). Attrs n_head, rope_dim, epsilon, and the rotation's: theta,
+    """Inputs X (B, T, D), optional WA (D, q_rank) and Gain (q_rank,)
+    (both absent: no bottleneck, WB is (D, ...)), WB (q_rank, H * (nope
+    + rope)), optional Positions (B,) at T = 1 (absent: 0..T-1). Attrs n_head, rope_dim, epsilon, and the rotation's: theta,
     interleave, attention_factor, factor / original_max_position /
     beta_fast / beta_slow (YaRN), scale_beta -> Out (B, T, H, nope +
     rope)."""
@@ -433,6 +461,14 @@ def _mla_expand_op(ctx):
     k, v = mla_expand(ctx.input("Rows"), ctx.input("WB"),
                       int(ctx.attr("n_head")), int(ctx.attr("nope_dim")))
     return {"K": k, "V": v}
+
+
+@register_op("mla_attend")
+def _mla_attend_op(ctx):
+    """Inputs Q, K (B, T, H, dq), V (B, T, H, dv), dq != dv. Attr scale
+    -> Out (B, T, H, dv): causal attention of a prefill."""
+    return {"Out": mla_attend(ctx.input("Q"), ctx.input("K"),
+                              ctx.input("V"), float(ctx.attr("scale")))}
 
 
 @register_op("mla_decode")

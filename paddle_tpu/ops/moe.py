@@ -9,7 +9,11 @@ equal the model's forward pass, so this file computes every chosen
   float32 (the router's matmul at ``highest`` precision: it is tiny and
   its order decides a discontinuous choice), a sigmoid a logit or a
   softmax over the ``E`` (``ROUTER_SCORES``), the ``k`` largest (ties
-  to the lower index), renormalised to sum 1, times ``scale``.
+  to the lower index), renormalised to sum 1, times ``scale``. With a
+  selection ``bias`` the k are chosen by ``s + b`` and weighted by
+  ``s``; with ``n_group`` groups the choice is limited to the
+  ``topk_group`` whose two best biased scores sum highest
+  (DeepSeek-V3's ``noaux_tc``, arXiv:2412.19437 section 2.1.2).
 - ``moe_experts`` (``ptpu.moe_experts``): the experts HELD here, ``[lo,
   lo + Eh)`` of the ``E`` the router chose among ("route over all,
   compute your own": a chip of an expert-parallel deployment holds a
@@ -55,12 +59,29 @@ def _silu(x):
     return x * jax.nn.sigmoid(x)
 
 
-def moe_route(x, w_router, top_k, scale=1.0, score="sigmoid"):
+def _group_limited(c, n_group, topk_group):
+    """Selection scores with every group but the ``topk_group`` best of
+    ``n_group`` masked out: c (..., E), the experts in ``n_group`` runs
+    of ``E / n_group``; a group's score is the sum of its two largest
+    (DeepSeek-V3's ``noaux_tc``); ties to the lower group."""
+    e = c.shape[-1]
+    grouped = c.reshape(c.shape[:-1] + (n_group, e // n_group))
+    best2, _ = lax.top_k(grouped, 2)
+    _, keep = lax.top_k(jnp.sum(best2, axis=-1), int(topk_group))
+    kept = jnp.any(keep[..., None] == jnp.arange(n_group), axis=-2)
+    return jnp.where(jnp.repeat(kept, e // n_group, axis=-1), c, -jnp.inf)
+
+
+def moe_route(x, w_router, top_k, scale=1.0, score="sigmoid", bias=None,
+              n_group=1, topk_group=1):
     """x (..., D), w_router (D, E) -> (idx (..., k) int32, weights
     (..., k) float32). ``score`` "sigmoid": a sigmoid a logit
     (DeepSeek-V3's router); "softmax": a softmax over all E (Mixtral's:
     renormalised over the chosen k it equals a softmax over the chosen
-    logits)."""
+    logits). ``bias`` (E,) or None: a selection bias, the k are CHOSEN
+    by ``s + bias`` and weighted by ``s``. ``n_group`` > 1: the choice
+    is limited to the ``topk_group`` best groups (``_group_limited``).
+    No bias and one group trace to the graph they always have."""
     if score not in ROUTER_SCORES:
         raise ValueError("moe_route: score function %r is not built (%s "
                          "are)" % (score, ", ".join(ROUTER_SCORES)))
@@ -70,7 +91,14 @@ def moe_route(x, w_router, top_k, scale=1.0, score="sigmoid"):
                             precision=lax.Precision.HIGHEST)
         s = (jax.nn.sigmoid(logits) if score == "sigmoid"
              else jax.nn.softmax(logits, axis=-1))
-        top, idx = lax.top_k(s, int(top_k))
+        if bias is None and int(n_group) == 1:
+            top, idx = lax.top_k(s, int(top_k))
+        else:
+            c = s if bias is None else s + bias.astype(jnp.float32)
+            if int(n_group) > 1:
+                c = _group_limited(c, int(n_group), int(topk_group))
+            _, idx = lax.top_k(c, int(top_k))
+            top = jnp.take_along_axis(s, idx, axis=-1)
         top = top / jnp.sum(top, axis=-1, keepdims=True)
         return idx.astype(jnp.int32), top * jnp.float32(scale)
 
@@ -123,18 +151,27 @@ def _experts_grouped(x, local, w, token, w_gate, w_up, w_down, counts):
     return lax.fori_loop(0, trips, body, jnp.zeros((n, d), jnp.float32))
 
 
-def moe_experts(x, idx, weights, w_gate, w_up, w_down, lo=0, valid=None):
+def moe_experts(x, idx, weights, w_gate, w_up, w_down, lo=0, valid=None,
+                count_elsewhere=False):
     """x (N, D); idx, weights (N, k) from ``moe_route``; w_gate, w_up
     (Eh, D, F), w_down (Eh, F, D): the experts ``[lo, lo + Eh)``;
     ``valid`` (N,) bool or None marks real tokens. -> (out (N, D): sum
     over a token's pairs on held experts of weight * expert(x), load
-    (Eh,) int32 pairs a held expert received from real tokens)."""
+    (Eh,) int32 pairs a held expert received from real tokens; with
+    ``count_elsewhere`` (Eh + 1,), the last the real tokens NONE of
+    whose pairs fell on a held expert)."""
     eh = w_gate.shape[0]
     with jax.named_scope(MOE_EXPERTS):
         local, w, token = _held(idx, weights, valid, int(lo), eh)
         counts = jnp.zeros((eh + 1,), jnp.int32).at[local].add(1)[:eh]
         out = _experts_grouped(x.astype(jnp.float32), local, w, token,
                                w_gate, w_up, w_down, counts)
+        if count_elsewhere:
+            none = jnp.all(local.reshape(idx.shape) == eh, axis=-1)
+            if valid is not None:
+                none = none & valid
+            counts = jnp.concatenate(
+                [counts, jnp.sum(none, dtype=jnp.int32)[None]])
         return out.astype(x.dtype), counts
 
 
@@ -147,12 +184,15 @@ def moe_shared(x, w_gate, w_up, w_down):
 
 @register_op("moe_route")
 def _moe_route_op(ctx):
-    """Inputs X (B, T, D), W (D, E). Attrs top_k, scale, score ->
-    Idx (B, T, k) int32, Weights (B, T, k) float32."""
+    """Inputs X (B, T, D), W (D, E), optional Bias (E,). Attrs top_k,
+    scale, score, n_group, topk_group -> Idx (B, T, k) int32, Weights
+    (B, T, k) float32."""
     idx, w = moe_route(ctx.input("X"), ctx.input("W"),
                        int(ctx.attr("top_k")),
                        float(ctx.attr("scale", 1.0)),
-                       str(ctx.attr("score", "sigmoid")))
+                       str(ctx.attr("score", "sigmoid")),
+                       ctx.input("Bias"), int(ctx.attr("n_group", 1)),
+                       int(ctx.attr("topk_group", 1)))
     return {"Idx": idx, "Weights": w}
 
 
@@ -162,7 +202,9 @@ def _moe_experts_op(ctx):
     F), WDown (Eh, F, D), optional Lengths (B,). Attrs expert_lo (the
     first expert held), decode (Lengths are tokens held BEFORE this
     step's one: a slot of length 0 is free; otherwise rows at or past a
-    row's length are padding) -> Out (B, T, D), Load (Eh,) int32."""
+    row's length are padding), count_elsewhere (Load gets a last entry:
+    real tokens that sent no pair here) -> Out (B, T, D), Load (Eh,)
+    int32."""
     x = ctx.input("X")
     b, t, d = x.shape
     lengths = ctx.input("Lengths")
@@ -179,7 +221,8 @@ def _moe_experts_op(ctx):
         x.reshape(b * t, d), ctx.input("Idx").reshape(b * t, k),
         ctx.input("Weights").reshape(b * t, k), ctx.input("WGate"),
         ctx.input("WUp"), ctx.input("WDown"),
-        lo=int(ctx.attr("expert_lo", 0)), valid=valid)
+        lo=int(ctx.attr("expert_lo", 0)), valid=valid,
+        count_elsewhere=bool(ctx.attr("count_elsewhere", False)))
     return {"Out": out.reshape(b, t, d), "Load": load}
 
 

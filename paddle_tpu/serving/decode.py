@@ -176,7 +176,17 @@ class DecodeConfig:
     qk_rope_dim)^-0.5``); its cache entry keeps, for each position, ONE
     latent row of ``kv_lora_rank + qk_rope_dim`` floats that is neither
     K nor V (``latent_row``). ``router_score`` names the router's score
-    function ("sigmoid" | "softmax")."""
+    function ("sigmoid" | "softmax"); ``router_bias`` adds a selection
+    bias an expert (chosen by score + bias, weighted by score) and
+    ``router_groups`` / ``router_topk_groups`` limit the choice to the
+    best groups (``ops/moe.py``). ``q_lora_rank`` 0 is a latent query
+    with no bottleneck; ``attn_gate`` reaches a latent and a KDA layer
+    too. A ``kda`` layer is Kimi Delta Attention (``ops/kda.py``):
+    ``kda_heads`` heads of ``kda_head_dim`` key and value channels,
+    convolutions of ``kda_conv`` taps on q, k and v, a decay gate
+    ``kda_gate`` ("lower_bound_sigmoid", log-decay in (``kda_gate_bound``,
+    0) | "softplus", unbounded); it keeps three windows and ONE matrix
+    state a head, all fixed-size."""
 
     FIELDS = ("vocab_size", "n_layer", "n_head", "d_model", "d_inner",
               "max_len", "tie_embeddings", "prefix", "eos_id")
@@ -199,10 +209,16 @@ class DecodeConfig:
                    ("attn_biases", False), ("mamba_norms", True),
                    ("q_lora_rank", 0), ("kv_lora_rank", 0),
                    ("qk_nope_dim", 0), ("qk_rope_dim", 0),
-                   ("v_head_dim", 0), ("softmax_scale", None))
-    MIXERS = ("mamba", "attention", "sliding", "gmu", "cross", "latent")
-    LATENT_WIDTHS = ("q_lora_rank", "kv_lora_rank", "qk_nope_dim",
-                     "qk_rope_dim", "v_head_dim")
+                   ("v_head_dim", 0), ("softmax_scale", None),
+                   ("kda_heads", 0), ("kda_head_dim", 0), ("kda_conv", 4),
+                   ("kda_gate", "lower_bound_sigmoid"),
+                   ("kda_gate_bound", -5.0), ("router_groups", 1),
+                   ("router_topk_groups", 1), ("router_bias", False))
+    MIXERS = ("mamba", "attention", "sliding", "gmu", "cross", "latent",
+              "kda")
+    # ``q_lora_rank`` 0 is a query with no bottleneck
+    LATENT_WIDTHS = ("kv_lora_rank", "qk_nope_dim", "qk_rope_dim",
+                     "v_head_dim")
 
     def __init__(self, vocab_size, n_layer=4, n_head=8, d_model=512,
                  d_inner=2048, max_len=2048, tie_embeddings=False,
@@ -273,6 +289,23 @@ class DecodeConfig:
                 "a latent layer needs %s; got %s" % (
                     ", ".join(self.LATENT_WIDTHS),
                     [getattr(self, f) for f in self.LATENT_WIDTHS]))
+        if "kda" in kinds and not (int(self.kda_heads or 0) > 0
+                                   and int(self.kda_head_dim or 0) > 0
+                                   and int(self.kda_conv or 0) > 1):
+            raise ValueError(
+                "a kda layer needs kda_heads, kda_head_dim and a kda_conv "
+                "of at least 2; got %r, %r, %r"
+                % (self.kda_heads, self.kda_head_dim, self.kda_conv))
+        if "experts" in (self.ffn_types or ()) and (
+                self.n_expert % int(self.router_groups)
+                or not 0 < int(self.router_topk_groups)
+                <= int(self.router_groups)
+                or self.expert_top_k > int(self.router_topk_groups)
+                * (self.n_expert // int(self.router_groups))):
+            raise ValueError(
+                "%d experts in %r groups of which %r are kept do not hold "
+                "a top-%d" % (self.n_expert, self.router_groups,
+                              self.router_topk_groups, self.expert_top_k))
         if self.diff_attn and self.n_kv_head % 2:
             raise ValueError(
                 "differential attention pairs its heads: %d key/value "
@@ -344,7 +377,7 @@ class DecodeConfig:
     def has_state(self) -> bool:
         """Some layer keeps a recurrent state: a cache entry that is
         not a row per position (no snapshot, no rollback)."""
-        return "mamba" in self.layer_kinds()
+        return bool({"mamba", "kda"} & set(self.layer_kinds()))
 
     @property
     def has_ring(self) -> bool:
@@ -445,7 +478,9 @@ def cache_spec(config: DecodeConfig, slots: int, seq: int,
     attention paths read), a Mamba layer's ``conv_i`` (slots, K - 1,
     d_inner) window and ``ssm_i`` (slots, d_inner, N) state, NOTHING for
     a ``gmu`` or a ``cross`` layer (it reads what another layer keeps;
-    a slab may so have several readers a step); a slab's or a ring's
+    a slab may so have several readers a step), a KDA layer's three
+    windows ``convq_i``, ``convk_i``, ``convv_i`` (slots, K - 1, H *
+    dk) and its ``kda_i`` (slots, H, dk, dv) matrix state; a slab's or a ring's
     row is ``config.kv_row``, flat under differential attention; SORTED BY
     NAME: the order a dict of feeds flattens in, so that a
     donated feed pairs with its own updated output and a step compiles
@@ -459,8 +494,8 @@ def cache_spec(config: DecodeConfig, slots: int, seq: int,
             "of one head count, quantized a row); this model's caches "
             "(%s) are float32"
             % (kv_dtype, ", ".join(sorted(set(
-                {"attention": "rows", "sliding": "ring",
-                 "mamba": "state", "latent": "latent"}.get(k, "none")
+                {"attention": "rows", "sliding": "ring", "mamba": "state",
+                 "kda": "state", "latent": "latent"}.get(k, "none")
                 for k in config.layer_kinds())))))
     from ..models.jamba import cache_names
 
@@ -475,6 +510,14 @@ def cache_spec(config: DecodeConfig, slots: int, seq: int,
             out.append(CacheEntry(
                 names[1], (slots, config.mamba_d_inner,
                            config.mamba_d_state), "float32", False))
+            continue
+        if kind == "kda":
+            width = config.kda_heads * config.kda_head_dim
+            out += [CacheEntry(n, (slots, config.kda_conv - 1, width),
+                               "float32", False) for n in names[:3]]
+            out.append(CacheEntry(
+                names[3], (slots, config.kda_heads, config.kda_head_dim,
+                           config.kda_head_dim), "float32", False))
             continue
         if kind == "sliding":
             ring = (slots, int(config.window)) + config.kv_row
@@ -774,11 +817,16 @@ class DecodePredictor:
         """Levers that snapshot, roll back or reorder a cache work on
         rows per position, through graphs written for OPT's block."""
         if self.config.has_state:
+            kinds = self.config.layer_kinds()
             raise ValueError(
                 "%s needs a cache of rows per position (it rolls back by "
-                "length, or copies rows); this model's state-space layers "
+                "length, or copies rows); this model's %s layers "
                 "keep a recurrent state (cache entries of kind 'state'), "
-                "which has no snapshot and no rollback yet" % what)
+                "which has no snapshot and no rollback yet"
+                % (what, " and ".join(
+                    n for k, n in (("mamba", "state-space"),
+                                   ("kda", "delta-rule (KDA)"))
+                    if k in kinds)))
         if self.config.has_ring:
             raise ValueError(
                 "%s needs a cache of rows per position (it rolls back by "
@@ -1646,6 +1694,10 @@ class DecodeServer:
         self._state_bytes_per_slot = sum(
             e.nbytes for e in self._spec
             if e.kind == "state") // self.slots
+        # of those, a slot's delta-rule (KDA) matrix states, all layers
+        self._kda_state_bytes_per_slot = sum(
+            e.nbytes for e in self._spec
+            if e.name.startswith("kda_")) // self.slots
         # a sliding-window layer's ring holds this many rows (0: none)
         self._ring_window = int(cfg.window) if cfg.has_ring else 0
         # layers that route over experts: a step and a prefill return
@@ -1656,6 +1708,9 @@ class DecodeServer:
         self.moe_load_total = np.zeros((len(self._moe_layers), hi - lo),
                                        np.int64)
         self._moe_last = {"expert_pairs": 0, "experts_active": 0}
+        # under group-limited routing a token may send this chip nothing:
+        # ``moe_load`` then carries that count in a last column
+        self._moe_elsewhere = int(cfg.router_groups) > 1
         # rows a block of the float32 decode kernel brings in, or None
         # where a step reads whole slabs (int8 slabs and the speculative
         # verify window are lax paths of their own; so is a slab of
@@ -1885,6 +1940,7 @@ class DecodeServer:
     _slab_readers = 0
     _has_tail = False
     _latent_row_bytes = 0
+    _kda_state_bytes_per_slot = 0
 
     # prompts one admission prefills at most, while sequences are live,
     # and the bucketed tokens (power-of-two batch x the prompts' bucket)
@@ -2008,7 +2064,10 @@ class DecodeServer:
         included; ``prompts``, how many it held, and ``attn_pairs``, the
         (query, key) pairs under the causal mask of their live rows
         (each prompt's ``len (len + 1) / 2``): what model FLOPs of a
-        prefill are counted from."""
+        prefill are counted from. Of a model with KDA layers,
+        ``kda_tokens`` and ``kda_pad_tokens``: the real rows each such
+        layer's chunked scan walked, and the rows of the bucket beyond
+        them (scanned too, and leaving every state alone)."""
         counts = {"entries": len(self._spec),
                   "state_slots": n if self._state_bytes_per_slot else 0}
         if self._ring_window:
@@ -2025,6 +2084,10 @@ class DecodeServer:
             counts["prompts"] = len(prompts)
             counts["attn_pairs"] = sum(len(p) * (len(p) + 1) // 2
                                        for p in prompts)
+        if self._kda_state_bytes_per_slot:
+            counts["kda_tokens"] = sum(len(p) for p in prompts)
+            counts["kda_pad_tokens"] = (int(bucket_rows)
+                                        - counts["kda_tokens"])
         return counts
 
     def _note_load(self, load):
@@ -2034,7 +2097,12 @@ class DecodeServer:
         samples, and what the next ``dispatch`` / ``scatter`` phase
         reports."""
         load = np.asarray(load, np.int64).reshape(
-            self.moe_load_total.shape)
+            len(self._moe_layers), -1)
+        if self._moe_elsewhere:
+            for j, layer in enumerate(self._moe_layers):
+                obs.MOE_TOKENS_ELSEWHERE.inc(int(load[j, -1]),
+                                             layer=str(layer))
+            load = load[:, :-1]
         self.moe_load_total += load
         for j, layer in enumerate(self._moe_layers):
             obs.MOE_EXPERT_PAIRS.inc(int(load[j].sum()), layer=str(layer))
@@ -2376,7 +2444,10 @@ class DecodeServer:
         readers). Of a model with latent layers, ``latent_rows``: the
         live latent rows the step's absorbed attention reads, one
         latent layer's (= ``attended``; every latent layer reads as
-        many), and ``latent_row_bytes``, the bytes of one such row."""
+        many), and ``latent_row_bytes``, the bytes of one such row. Of a
+        model with KDA layers, ``kda_state_bytes``: the bytes of the
+        LIVE slots' matrix states, all such layers (a step reads and
+        writes each once: ``2 x`` this is its state traffic)."""
         rows = self._stream_rows
         streamed = (self.slots * self.seq if rows is None
                     else int((lens // rows + 1).sum()) * rows)
@@ -2394,6 +2465,9 @@ class DecodeServer:
         if self._latent_row_bytes:
             counts["latent_rows"] = counts["attended"]
             counts["latent_row_bytes"] = self._latent_row_bytes
+        if self._kda_state_bytes_per_slot:
+            counts["kda_state_bytes"] = (
+                n_active * self._kda_state_bytes_per_slot)
         return counts
 
     def _spec_round(self, drexe, vexe, caches, lens, active, n_active):

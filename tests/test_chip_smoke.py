@@ -52,6 +52,7 @@ def test_a_raising_phase_ends_the_run(monkeypatch, capsys):
 
     monkeypatch.setattr(jax, "devices", lambda *a: [FakeTpu()])
     monkeypatch.setattr(chip_smoke, "phase_kernels", lambda cfg: None)
+    monkeypatch.setattr(chip_smoke, "phase_kda", lambda cfg: None)
     monkeypatch.setattr(chip_smoke, "phase_train", boom)
     with pytest.raises(RuntimeError, match="train phase died"):
         chip_smoke.main([])
@@ -61,6 +62,16 @@ def test_a_raising_phase_ends_the_run(monkeypatch, capsys):
 def test_kernels_phase_tiny(capsys):
     chip_smoke.phase_kernels(TINY)
     assert '"phase": "kernels"' in capsys.readouterr().out
+
+
+def test_kda_phase_tiny(capsys):
+    """The chunked scan and the step against each other, at a tiny head
+    and texts that end inside a chunk."""
+    tiny = dict(chip_smoke.KDA, heads=2, head_dim=16, seq=128,
+                lengths=[100, 70], steps=4, require_tpu=False)
+    chip_smoke.phase_kda(tiny)
+    out = capsys.readouterr().out
+    assert '"phase": "kda"' in out and '"ok": true' in out
 
 
 def test_train_phase_tiny(capsys):

@@ -380,7 +380,7 @@ def test_manifest_round_trip_and_one_w_kvb(pred, seeded):
 
 
 @pytest.mark.parametrize("bad,match", [
-    (dict(layer_types=["latent"]), "a latent layer needs q_lora_rank"),
+    (dict(layer_types=["latent"]), "a latent layer needs kv_lora_rank"),
     (dict(layer_types=["latent"], q_lora_rank=8, kv_lora_rank=8,
           qk_nope_dim=4, qk_rope_dim=4), "a latent layer needs"),
 ])
@@ -406,7 +406,7 @@ def test_builders_refuse_what_no_latent_graph_computes():
              "a latent layer's rotation"),
             (dict(base, rope={"latent": {"scale_beta": 0.1}}),
              "a latent layer's rotation"),
-            (dict(base, attn_gate="per_head"), "without differential"),
+            (dict(base, attn_gate="per_channel"), "only a sigmoid gate"),
             (dict(base, attn_biases=True), "without differential")]:
         with pytest.raises(ValueError, match=match):
             jamba._check(DecodeConfig(97, **bad))

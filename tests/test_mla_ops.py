@@ -223,6 +223,89 @@ def test_ops_through_the_layers_api_and_the_traces_counter():
         assert after[path] - before.get(path, 0) >= 1
 
 
+# -- a query with no bottleneck, a query/key head wider than the value head -----
+
+def test_query_without_a_bottleneck_is_one_projection():
+    w = _weights(3)
+    r = np.random.default_rng(4)
+    w_q = jnp.asarray(r.normal(size=(D, H * (DN + DR))) * 0.3, jnp.float32)
+    rot = {"theta": 6e6, "interleave": True}
+    q = mla.mla_q(w["u"], None, None, w_q, None, H, DR, 1e-6, rot)
+    assert q.shape == (B, T, H, DN + DR)
+    plain = jnp.matmul(w["u"], w_q).reshape(B, T, H, DN + DR)
+    np.testing.assert_allclose(q[..., :DN], plain[..., :DN], rtol=1e-6)
+    # position 0 is not turned; every later one is, pair by pair
+    np.testing.assert_allclose(q[:, 0], plain[:, 0], rtol=1e-6, atol=1e-7)
+    want = rope.rope(plain[..., DN:], None, rope.rope_inv_freq(DR, 6e6),
+                     1.0, True)
+    np.testing.assert_allclose(q[..., DN:], want, rtol=1e-6, atol=1e-7)
+
+
+@pytest.mark.parametrize("dq,dv", [(24, 16), (192, 128), (16, 24)],
+                         ids=["24/16", "192/128", "16/24"])
+def test_unlike_width_expanded_attention_is_plain_softmax_attention(dq, dv):
+    """`mla_attend` (zero channels up to one lane-aligned width, the
+    flash dispatch, the first `dv` channels kept) against the softmax
+    written out."""
+    r = np.random.default_rng(dq)
+    q, k = (jnp.asarray(r.normal(size=(2, 20, 3, dq)), jnp.float32)
+            for _ in range(2))
+    v = jnp.asarray(r.normal(size=(2, 20, 3, dv)), jnp.float32)
+    a = float(dq) ** -0.5
+    got = mla.mla_attend(q, k, v, a)
+    assert got.shape == (2, 20, 3, dv)
+    with jax.default_matmul_precision("highest"):
+        want = _causal(q, k, v, a)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+
+
+def test_absorbed_equals_expanded_at_unlike_widths():
+    """A head of 8 + 4 query/key channels over 6 value channels, no
+    query bottleneck: a decode step over the slab == the row of the
+    expanded attention."""
+    r = np.random.default_rng(8)
+    dn, dr, dv, rk = 8, 4, 6, 8
+
+    def m(*shape):
+        return jnp.asarray(r.normal(size=shape) * 0.3, jnp.float32)
+
+    u, w_q, w_kva, w_kvb = (m(B, T, D), m(D, H * (dn + dr)), m(D, rk + dr),
+                            m(rk, H * (dn + dv)))
+    rot = {"theta": 6e6, "interleave": True}
+    q = mla.mla_q(u, None, None, w_q, None, H, dr, 1e-6, rot)
+    rows = mla.mla_kv(u, w_kva, jnp.ones((rk,)), None, dr, 1e-6, rot)
+    k, v = mla.mla_expand(rows, w_kvb, H, dn)
+    assert k.shape == (B, T, H, dn + dr) and v.shape == (B, T, H, dv)
+    a = float(dn + dr) ** -0.5
+    want = mla.mla_attend(q, k, v, a)
+    slab = jnp.full((B, 16, rk + dr), 1e6, jnp.float32).at[:, :T].set(rows)
+    for t in (0, 5, T - 1):
+        got = mla.mla_decode(q[:, t:t + 1], slab, jnp.asarray([t + 1] * B),
+                             w_kvb, a)
+        np.testing.assert_allclose(got[:, 0], want[:, t], rtol=2e-5,
+                                   atol=2e-6)
+
+
+def test_latent_kernel_at_a_row_of_576_and_a_rank_of_512():
+    """The absorbed kernel at the Ling cell's row (512 + 64), 32 heads,
+    interpret mode, against the lax form; and the rule takes that shape
+    at the cell's slab."""
+    assert mla.latent_block_rows(16384, 32, 576, 512, "float32") \
+        == mla._LATENT_BLOCK_LANES
+    r = np.random.default_rng(6)
+    lens = jnp.asarray([0, 1, 129, 256], jnp.int32)
+    slab = r.normal(size=(4, 256, 576)).astype(np.float32)
+    for i, n in enumerate(np.asarray(lens)):
+        slab[i, n:] = 1e6
+    q_row = jnp.asarray(r.normal(size=(4, 32, 576)) * 0.05, jnp.float32)
+    want = mla._latent_attend_lax(q_row, jnp.asarray(slab), lens, 512)
+    got = mla.pallas_latent_attend(q_row, jnp.asarray(slab), lens, 512,
+                                   block_s=128, interpret=True)
+    assert got.shape == (4, 32, 512) and np.isfinite(np.asarray(got)).all()
+    err = float(jnp.linalg.norm(got - want))
+    assert err <= 1e-5 * float(jnp.linalg.norm(want)), err
+
+
 # -- the absorbed attention's kernel (`ptpu.mla_latent_attn`) ------------------
 
 KS, KH, KRANK, KROPE = 512, 8, 32, 8

@@ -670,6 +670,11 @@ COVERED_ELSEWHERE = {
     # expanded on the same latent rows, the rotation of pairs against
     # complex numbers, the query scale, the scale a, the append)
     "mla_q", "mla_kv", "mla_expand", "mla_decode", "mla_append",
+    "mla_attend",
+    # Kimi Delta Attention: tests/test_kda_ops.py (the chunked scan
+    # against the recurrence a token at a time and one step after
+    # another, at the gate's bound, with padding; both gates)
+    "kda_gate", "kda_scan", "kda_step",
     # in-graph sampling: tests/test_sampling_ops.py
     "greedy_sample", "top_k_sample", "top_p_sample",
     # metrics: tests/test_aux.py

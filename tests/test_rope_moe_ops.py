@@ -171,6 +171,87 @@ def test_route_weights_are_the_scores_renormalised_over_the_chosen(score):
     np.testing.assert_allclose(np.asarray(wt).sum(-1), 1.0, rtol=1e-6)
 
 
+def _route_written_out(x, w, b, k, n_group, topk_group, scale):
+    """DeepSeek-V3's `noaux_tc` by hand, in float64: sigmoid scores,
+    chosen by score + bias inside the best groups (a group's score the
+    sum of its two largest), weighted by score; ties to the lower
+    index."""
+    s = 1.0 / (1.0 + np.exp(-(np.asarray(x, np.float64)
+                               @ np.asarray(w, np.float64))))
+    c = s if b is None else s + np.asarray(b, np.float64)
+    n, e = c.shape
+    idx = np.zeros((n, k), np.int64)
+    for t in range(n):
+        per = c[t].reshape(n_group, e // n_group)
+        score = np.sort(per, axis=-1)[:, -2:].sum(-1) if n_group > 1 \
+            else np.zeros(1)
+        keep = np.argsort(-score, kind="stable")[:topk_group]
+        masked = np.full(e, -np.inf)
+        for g in keep:
+            lo = g * (e // n_group)
+            masked[lo:lo + e // n_group] = c[t, lo:lo + e // n_group]
+        idx[t] = np.argsort(-masked, kind="stable")[:k]
+    chosen = np.take_along_axis(s, idx, axis=-1)
+    return idx, scale * chosen / chosen.sum(-1, keepdims=True)
+
+
+@pytest.mark.parametrize("bias,n_group,topk_group", [
+    (True, 1, 1), (False, 8, 4), (True, 8, 4), (True, 4, 1), (True, 8, 8)],
+    ids=["bias", "groups", "bias+groups", "one-group-kept", "all-kept"])
+def test_route_with_a_selection_bias_and_groups(bias, n_group, topk_group):
+    """Chosen by `s + b`, weighted by `s`; the choice limited to the
+    `topk_group` groups whose two best biased scores sum highest: against
+    the rule written out by hand."""
+    r = np.random.default_rng(3)
+    x = jnp.asarray(r.normal(size=(40, 24)), jnp.float32)
+    w = jnp.asarray(r.normal(size=(24, 64)) * 0.4, jnp.float32)
+    b = jnp.asarray(r.normal(size=(64,)) * 0.2, jnp.float32) if bias else None
+    idx, wt = moe.moe_route(x, w, 4, scale=2.5, bias=b, n_group=n_group,
+                            topk_group=topk_group)
+    want_idx, want_w = _route_written_out(x, w, b, 4, n_group, topk_group,
+                                          2.5)
+    assert np.asarray(idx).tolist() == want_idx.tolist()
+    np.testing.assert_allclose(wt, want_w, rtol=2e-5)
+    np.testing.assert_allclose(np.asarray(wt).sum(-1), 2.5, rtol=1e-6)
+    groups = np.asarray(idx) // (64 // n_group)
+    assert max(len(set(g)) for g in groups.tolist()) <= topk_group
+    if bias:  # the bias changes choices, and never a weight's formula
+        plain, _ = moe.moe_route(x, w, 4, scale=2.5, n_group=n_group,
+                                 topk_group=topk_group)
+        assert (np.asarray(plain) != np.asarray(idx)).any()
+
+
+def test_route_breaks_ties_low_among_experts_and_groups():
+    """Equal scores everywhere: the lower groups stay, and within them
+    the lower experts."""
+    x = jnp.ones((2, 4), jnp.float32)
+    w = jnp.zeros((4, 16), jnp.float32)
+    idx, wt = moe.moe_route(x, w, 4, bias=jnp.zeros((16,)), n_group=4,
+                            topk_group=2)
+    assert idx.tolist() == [[0, 1, 2, 3]] * 2
+    np.testing.assert_allclose(wt, 0.25)
+    # a bias lifts expert 9 of group 2: that group stays with group 0,
+    # and expert 9 comes first
+    b = jnp.zeros((16,)).at[9].set(0.1)
+    idx, wt = moe.moe_route(x, w, 3, bias=b, n_group=4, topk_group=2)
+    assert idx.tolist() == [[9, 0, 1]] * 2
+    np.testing.assert_allclose(wt, 1 / 3, rtol=1e-6)  # weighted by s alone
+
+
+def test_route_without_bias_and_groups_is_the_graph_it_was():
+    """`n_group` 1 and no bias lower to the text they always have: the
+    Laguna and Mistral cells' executables do not change."""
+    x, w = jnp.zeros((6, 8)), jnp.zeros((8, 16))
+    old = jax.jit(lambda a, b: moe.moe_route(a, b, 4, 2.5, "sigmoid")
+                  ).lower(x, w).as_text()
+    new = jax.jit(lambda a, b: moe.moe_route(
+        a, b, 4, 2.5, "sigmoid", None, 1, 1)).lower(x, w).as_text()
+    assert old == new and "top_k" in old or "TopK" in old or "sort" in old
+    grouped = jax.jit(lambda a, b: moe.moe_route(
+        a, b, 4, 2.5, "sigmoid", None, 4, 2)).lower(x, w).as_text()
+    assert grouped != old
+
+
 # -- the expert layer ----------------------------------------------------------
 
 D, F, E, K = 32, 16, 16, 4

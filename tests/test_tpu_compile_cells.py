@@ -1,5 +1,5 @@
-"""Compile the serving steps of the Laguna, Phi-4-mini-flash and
-Mistral-Small-4 cells for a DESCRIBED TPU v5e (`tpu_compile_lib.py`): the cells' own programs, from
+"""Compile the serving steps of the Laguna, Phi-4-mini-flash,
+Mistral-Small-4 and Ling-3.0-flash cells for a DESCRIBED TPU v5e (`tpu_compile_lib.py`): the cells' own programs, from
 their own configuration files. See `test_tpu_compile.py` for what such a
 compile can and cannot say.
 """
@@ -272,4 +272,87 @@ def test_mistral4_serving_step_compiles(one_chip, monkeypatch, kind, batch,
     assert layouts == {"1,2,0"}, layouts
     # no expanded K or V: nothing of slots x positions x heads
     assert "f32[32,16384,32," not in text
+    assert mem.temp_size_in_bytes < 300 * 2**20, mem.temp_size_in_bytes
+
+
+_LING3_CASES = [
+    # id, kind, batch, seq: the Ling-3.0-flash serving cell's own
+    # programs (benchmark/configs/ling-3.0-flash.json: 6 layers at
+    # published widths, 64 of 512 experts held, 64 slots of 16,384
+    # positions; the largest admission is one prompt of the 16,384 bucket)
+    ("decode-64x16384", "decode", 64, 16384),
+    ("prefill-1x16384", "prefill", 1, 16384),
+]
+
+
+@pytest.mark.parametrize("kind,batch,seq", [c[1:] for c in _LING3_CASES],
+                         ids=[c[0] for c in _LING3_CASES])
+def test_ling3_serving_step_compiles(one_chip, monkeypatch, kind, batch,
+                                     seq):
+    """The programs DecodePredictor builds for the Ling-3.0-flash cell
+    (five KDA layers: 32 heads of a 128 x 128 state, three windows; one
+    latent layer: 32 heads of 128 + 64 query/key and 128 value channels
+    over a latent row of 576 floats, no query bottleneck; a sigmoid
+    router with a bias over 512 experts of width 768 in 8 groups, 64
+    held; an untied head over 19,648 ids): they compile for a v5e and
+    fit it beside each other. What the compiler chooses for the 576-wide
+    row is READ here: the sequence minor ({1,2,0}), as for Mistral's 320
+    (576 is no multiple of 128 lanes either), so the absorbed kernel's
+    transposed view is a bitcast and the step holds no copy of the
+    slab; the five matrix states are donated and each comes back from
+    ONE fusion, in the layout it came in. The largest admission holds
+    one flash forward (the latent layer's, at heads padded to 256) and
+    the chunked scans' loops."""
+    pred = _cell_predictor("ling3_lm", "ling-3.0-flash.json", monkeypatch)
+    step_fn, feeds, state, n_cache = _serving_step(pred, kind, batch, seq,
+                                                   one_chip)
+    compiled = jax.jit(step_fn, donate_argnums=(0,)).lower(
+        feeds, state).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < HBM_BYTES, "does not fit one chip: %r" % (mem,)
+    weights = sum(int(np.prod(s.shape)) * 4 for s in state.values())
+    assert 8.1e9 < weights < 8.13e9, weights  # 2.029 B parameters
+    text = compiled.as_text()
+    spec = pred.cache_spec(64, 16384)
+    slabs = sum(e.nbytes for e in spec)
+    assert round(slabs / 1e9, 2) == 3.13
+    calls = re.findall(r"%([\w.-]+?)(?:\.\d+)? = [^\n]*"
+                       r'custom_call_target="tpu_custom_call"', text)
+    if kind == "prefill":
+        assert calls.count("ptpu.flash_fwd") == 1, calls
+        assert calls.count("ragged-dot-none") == 3 * 4
+        # the padded heads: one array of 16,384 x 32 x 256 a q, k, v
+        assert "f32[1,16384,8192]" in text
+        # beside the weights, the slabs and states and the step
+        assert weights + slabs + mem.temp_size_in_bytes + (
+            mem.output_size_in_bytes) < 15.5 * 2**30, mem
+        assert mem.temp_size_in_bytes < 3.5 * 2**30, mem.temp_size_in_bytes
+        return
+    assert [c for c in calls if c.startswith("ptpu.")] == [
+        "ptpu.mla_latent_attn"], calls
+    (kernel,) = [ln for ln in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in ln
+                 and "%ptpu." in ln.split(" = ")[0]]
+    # the slab's transposed view, row-major: the same bytes
+    assert kernel.count("f32[64,576,16384]{2,1,0}") >= 2, kernel[:600]
+    assert n_cache == len(spec) == 21
+    assert mem.alias_size_in_bytes >= slabs
+    ops = _whole_slab_ops(text, (64, 16384, 576))
+    assert [op for op, _, _ in ops].count("bitcast") == 1
+    moved = [name for op, name, changed in ops
+             if op == "copy" or (changed and op != "bitcast")]
+    assert not moved, moved
+    assert set(re.findall(r"f32\[64,16384,576\]\{([\d,]+)", text)) == {
+        "1,2,0"}
+    # a matrix state is written by one fusion a layer, never copied
+    ops = _whole_slab_ops(text, (64, 32, 128, 128))
+    assert sorted(op for op, _, _ in ops) == ["fusion"] * 5 + [
+        "parameter"] * 5, ops
+    assert not [name for _, name, changed in ops if changed]
+    assert set(re.findall(r"f32\[64,32,128,128\]\{([\d,]+)", text)) == {
+        "3,2,1,0"}
+    # no expanded K or V: nothing of slots x positions x heads
+    assert "f32[64,16384,32," not in text
     assert mem.temp_size_in_bytes < 300 * 2**20, mem.temp_size_in_bytes
