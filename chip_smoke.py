@@ -624,7 +624,10 @@ def phase_phi4flash(cfg, place):
     shortcut whose prompt wraps the rings, then eight steps through the
     ONE slab (read by the full layer and the cross layers), rings,
     states and the memory. A slab that is copied for its append, or a
-    hang in the flat rows' slices, shows here, in seconds."""
+    hang in the flat rows' slices, shows here, in seconds. The hybrid
+    phase: its prefill program holds ONE call of the selective scan's
+    kernel a state-space layer (13 in the Jamba cell's period of 14,
+    as many as this block has here) and no `while`."""
     model_dir = os.path.join(OUT_DIR, "phi4flash_model")
     config = phi4flash_config(cfg)
     pred, srv, agree, serve_s, prompt = _serve_described(
@@ -640,6 +643,11 @@ def phase_phi4flash(cfg, place):
                 and kernels.count("ptpu.flash_fwd") == 1), (
             "the prefill does not run one attention kernel a layer that "
             "owns keys: %r" % kernels)
+        scans = config.layer_kinds().count("mamba")
+        assert (kernels.count("ptpu.ssm_scan") == scans
+                and " while(" not in text), (
+            "the prefill does not run one selective-scan kernel a "
+            "state-space layer (%d): %r" % (scans, kernels))
         text = pred.acquire("decode", cfg["slots"], cfg["seq"])[0].as_text()
         entry = text[text.index("ENTRY"):]
         slab = "f32[%d,%d,%d]" % (cfg["slots"], cfg["seq"],

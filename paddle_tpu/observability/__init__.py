@@ -60,7 +60,8 @@ __all__ = [
     "REQUEST_PHASE_MS", "TRACE_SPANS", "tracing",
     "TRANSPILE_OPS_REMOVED", "TRANSPILE_OPS_FUSED", "TRANSPILE_PASS_MS",
     "QUANT_CALIB_BATCHES", "QUANT_OPS", "QUANT_PARITY",
-    "FUSED_HEAD_TRACES", "MLA_TRACES", "MOE_TOKENS_ELSEWHERE",
+    "FUSED_HEAD_TRACES", "MLA_TRACES", "SSM_SCAN_TRACES",
+    "MOE_TOKENS_ELSEWHERE",
 ]
 
 # -- the shared instrument set (registered once, process-wide) -----------
@@ -92,6 +93,13 @@ MLA_TRACES = REGISTRY.counter(
     "prefill: K and V of every head built from the latent rows) | "
     "absorbed_kernel | absorbed (a step: attention ON the latent rows, "
     "by the kernel over live blocks | the lax form over whole slabs). "
+    "Counted when the op is traced: a program loaded from a cache adds 0")
+SSM_SCAN_TRACES = REGISTRY.counter(
+    "paddle_tpu_ssm_scan_traces_total",
+    "Traces of the selective scan (ops/ssm.py), by path=kernel (one "
+    "Pallas call whose state tile stays in vector memory and whose grid "
+    "stops at a row's length) | lax (a lax.scan over every position of "
+    "the bucket: the CPU, a bucket under one block of positions). "
     "Counted when the op is traced: a program loaded from a cache adds 0")
 CACHE_HITS = REGISTRY.counter(
     "paddle_tpu_compile_cache_hits_total",

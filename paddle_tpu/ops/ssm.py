@@ -14,11 +14,17 @@ LENGTH (padding must not advance a state: there is no length mask to
 hide it afterwards, as there is for K/V rows); a decode step advances
 both by one token.
 
-Pure ``lax`` under ``jax.named_scope`` (the names a device trace
-shows): ``ptpu.ssm_scan`` is a plain ``lax.scan`` over the sequence
-that carries only the (batch, d_state, d_inner) state, never a
-(seq, d_inner, d_state) tensor. No Pallas kernel yet: the serving
-cell's trace says what one is worth (PERF.md).
+Under ``jax.named_scope`` (the names a device trace shows).
+``ptpu.ssm_scan`` carries only the (batch, d_state, d_inner) state,
+never a (seq, d_inner, d_state) tensor, and has two forms of ONE
+recurrence, a position after a position in float32: a plain
+``lax.scan`` over the sequence (the CPU's path, a bucket under one
+block of positions, and the numeric reference), and on a TPU one
+Pallas call a layer (``_ssm_scan_kernel``, since PR 41) whose state
+tile stays in vector memory across a row's blocks of positions and
+whose grid does no work past a row's length. ``_use_kernel`` chooses
+by shape and device; ``paddle_tpu_ssm_scan_traces_total{path}`` says
+which a program was traced with. The one-token step is lax.
 
 The state's interface shape is (batch, d_inner, d_state). Inside, the
 wide axis is kept minor, (batch, d_state, d_inner): on a TPU the
@@ -28,10 +34,17 @@ works on full 128-lane vectors.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from ..observability import SSM_SCAN_TRACES
+from . import attention as _A
+from . import kv_cache as _KV
 from .registry import register_op
 
 SSM_SCAN = "ptpu.ssm_scan"
@@ -42,6 +55,16 @@ RMS_NORM = "ptpu.rms_norm"
 # steps of the scan that one loop iteration holds: the loop's own
 # overhead is paid once for them
 _SCAN_UNROLL = 8
+
+# the kernel's blocks: positions a grid cell walks, and the lanes of
+# d_inner whose (d_state, lanes) state tile it keeps. On the chip a
+# live position of a row costs 0.34-0.47 us at 1,024 lanes, 0.46-0.61
+# at 512 and 0.8-0.9 at 256 (a position's transposed B and C columns
+# are broadcast once a tile, whatever its width), and 64, 128 or 256
+# positions a block read the same within 5% (PERF.md, PR 41: the
+# probe's table; the lax form walks a BUCKET position in 0.93-1.70)
+_KERNEL_BLOCK_T = 128
+_KERNEL_BLOCK_D = 1024
 
 
 def rms_norm(x, scale, epsilon=1e-6):
@@ -69,38 +92,220 @@ def _ssm_update(state, x, delta, a_t, b, c, d):
     return y, new
 
 
-def ssm_scan(x, delta, a, b, c, d, lengths=None):
+def _ssm_scan_lax(x, delta, a, b, c, d, lens):
+    """The scan as a ``lax.scan`` over time-major copies: x, delta (B,
+    T, Di), a (Di, N), b, c (B, T, N), d (Di,), lens (B,) int32 -> (y
+    (B, T, Di) float32, state (B, N, Di))."""
+    bsz, t, di = x.shape
+    f32 = jnp.float32
+    a_t = a.astype(f32).T
+    dd = d.astype(f32)
+
+    def body(state, inp):
+        i, x_t, dt_t, b_t, c_t = inp
+        y, new = _ssm_update(state, x_t, dt_t, a_t, b_t, c_t, dd)
+        live = (i < lens)[:, None, None]
+        return jnp.where(live, new, state), y
+
+    xs = (jnp.arange(t, dtype=jnp.int32),
+          jnp.swapaxes(x.astype(f32), 0, 1),
+          jnp.swapaxes(delta.astype(f32), 0, 1),
+          jnp.swapaxes(b.astype(f32), 0, 1),
+          jnp.swapaxes(c.astype(f32), 0, 1))
+    state, ys = lax.scan(body, jnp.zeros((bsz, a.shape[1], di), f32), xs,
+                         unroll=min(_SCAN_UNROLL, t))
+    return jnp.swapaxes(ys, 0, 1), state
+
+
+def _ssm_scan_kernel(len_ref, x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref,
+                     y_ref, st_ref, s_ref, *, block_t, n_t):
+    """One (row, d_inner block, block of positions) grid cell, the last
+    axis sequential. x_ref, dt_ref, y_ref (1, Tb, Dl): the positions'
+    rows where they lie in (B, T, Di); a_ref (N, Dl) is ``A^T``; b_ref,
+    c_ref (1, Tb, N); d_ref (1, Dl); st_ref (1, N, Dl) the row's state,
+    written at the last cell; s_ref (N, Dl) the state tile, which lives
+    in vector memory from the row's first block to its last.
+
+    Eight positions a loop iteration, unrolled: their (8, N) rows of
+    B and C are transposed once, so that a position's N numbers lie
+    along the sublanes as the state's do, and each position is
+    ``_ssm_update`` on the tile. The loop ends with the group of eight
+    that holds the row's last live position of the block. A dead
+    position of that group gets delta, x, B and C of zero, and so
+    leaves the state as it was to the last bit (``exp(0 * A) = 1``, for
+    a finite A, and ``1 * s + 0 * 0 = s``): four selects on eight rows
+    where a select on the state tile would be paid by every position,
+    and ONE body, so that Mosaic compiles half the code a call. The y
+    of a block's dead groups is zeros, that of a dead position beside a
+    live one ``0`` too."""
+    length = len_ref[pl.program_id(0)]
+    ti = pl.program_id(2)
+    n_live = jnp.clip(length - ti * block_t, 0, block_t)
+
+    @pl.when(ti == 0)
+    def _():
+        s_ref[...] = jnp.zeros(s_ref.shape, jnp.float32)
+
+    @pl.when(n_live < block_t)
+    def _():
+        y_ref[...] = jnp.zeros(y_ref.shape, y_ref.dtype)
+
+    def group(g, state):
+        base = pl.multiple_of(g * 8, 8)
+        live = base + lax.broadcasted_iota(jnp.int32, (8, 1), 0) < n_live
+        a_t = a_ref[...]
+        dd = d_ref[...]
+        x8 = jnp.where(live, x_ref[0, pl.ds(base, 8), :], 0.0)
+        dt8 = jnp.where(live, dt_ref[0, pl.ds(base, 8), :], 0.0)
+        b8 = jnp.where(live, b_ref[0, pl.ds(base, 8), :], 0.0).T
+        c8 = jnp.where(live, c_ref[0, pl.ds(base, 8), :], 0.0).T
+        ys = []
+        for k in range(8):
+            x, dt = x8[k:k + 1], dt8[k:k + 1]
+            decay = jnp.exp(dt * a_t)
+            state = decay * state + (dt * x) * b8[:, k:k + 1]
+            ys.append(jnp.sum(state * c8[:, k:k + 1], axis=0, keepdims=True)
+                      + dd * x)
+        y_ref[0, pl.ds(base, 8), :] = jnp.concatenate(ys, axis=0)
+        return state
+
+    @pl.when(n_live > 0)
+    def _():
+        s_ref[...] = lax.fori_loop(0, (n_live + 7) // 8, group, s_ref[...])
+
+    @pl.when(ti == n_t - 1)
+    def _():
+        st_ref[0] = s_ref[...]
+
+
+def _kernel_blocks(t, di, n, block_t=_KERNEL_BLOCK_T,
+                   block_d=_KERNEL_BLOCK_D):
+    """(positions, lanes) a block of the kernel for a (B, t, di) scan
+    over a state of ``n``, or None where the lax form runs: a sequence
+    that is not whole blocks of positions (a bucket under one block
+    among them), a ``d_inner`` that does not fill whole 128-lane
+    vectors, a ``d_state`` that does not fill whole 8-row sublane
+    tiles."""
+    if t < block_t or t % block_t or di % 128 or n % 8:
+        return None
+    return block_t, _A._fit_block(di, block_d)
+
+
+def _use_kernel(t, di, n) -> bool:
+    """A step bound for a TPU and a shape the kernel takes
+    (``_kernel_blocks``); PADDLE_TPU_NO_PALLAS opts out, as it does for
+    every kernel (``kv_cache._use_pallas_decode``)."""
+    return (_kernel_blocks(t, di, n) is not None
+            and _KV._use_pallas_decode(t, di))
+
+
+def pallas_ssm_scan(x, delta, a, b, c, d, lens, block_t=_KERNEL_BLOCK_T,
+                    block_d=_KERNEL_BLOCK_D, interpret=False):
+    """``_ssm_scan_lax``'s contract through the kernel: ONE call, the
+    operands where they lie. ``lens`` is a scalar-prefetch operand: a
+    block of positions wholly past a row's length is neither fetched
+    (its index waits at the row's last live block) nor computed."""
+    bsz, t, di = x.shape
+    n = a.shape[1]
+    blocks = _kernel_blocks(t, di, n, block_t, block_d)
+    if blocks is None:
+        raise ValueError(
+            "no kernel for a (%d, %d, %d) scan over a state of %d in "
+            "blocks of %d positions; the lax form runs it"
+            % (bsz, t, di, n, block_t))
+    block_t, block_d = blocks
+    n_t = t // block_t
+    f32 = jnp.float32
+
+    def last(bi, lens_ref):
+        return jnp.maximum(lens_ref[bi] + block_t - 1, block_t) // block_t - 1
+
+    def xd_block(bi, dj, ti, lens_ref):
+        # past the row's last live block: the same block again
+        return bi, jnp.minimum(ti, last(bi, lens_ref)), dj
+
+    def bc_block(bi, dj, ti, lens_ref):
+        return bi, jnp.minimum(ti, last(bi, lens_ref)), 0
+
+    def lanes(bi, dj, ti, lens_ref):
+        return 0, dj
+
+    kernel = functools.partial(_ssm_scan_kernel, block_t=block_t, n_t=n_t)
+    y, state = _A.named_pallas_call(
+        SSM_SCAN, kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bsz, di // block_d, n_t),
+            in_specs=[
+                pl.BlockSpec((1, block_t, block_d), xd_block),
+                pl.BlockSpec((1, block_t, block_d), xd_block),
+                pl.BlockSpec((n, block_d), lanes),
+                pl.BlockSpec((1, block_t, n), bc_block),
+                pl.BlockSpec((1, block_t, n), bc_block),
+                pl.BlockSpec((1, block_d), lanes),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_t, block_d),
+                             lambda bi, dj, ti, lens_ref: (bi, ti, dj)),
+                pl.BlockSpec((1, n, block_d),
+                             lambda bi, dj, ti, lens_ref: (bi, 0, dj)),
+            ],
+            scratch_shapes=[pltpu.VMEM((n, block_d), f32)]),
+        out_shape=[jax.ShapeDtypeStruct((bsz, t, di), f32),
+                   jax.ShapeDtypeStruct((bsz, n, di), f32)],
+        interpret=interpret,
+        **_A._tpu_params("parallel", "parallel", "arbitrary"),
+    )(jnp.clip(lens, 0, t), x.astype(f32), delta.astype(f32),
+      a.astype(f32).T, b.astype(f32), c.astype(f32),
+      d.astype(f32).reshape(1, di))
+    return y, state
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def _ssm_scan_kernel_path(x, delta, a, b, c, d, lens, interpret):
+    return pallas_ssm_scan(x, delta, a, b, c, d, lens, interpret=interpret)
+
+
+def _kernel_path_fwd(x, delta, a, b, c, d, lens, interpret):
+    out = pallas_ssm_scan(x, delta, a, b, c, d, lens, interpret=interpret)
+    return out, (x, delta, a, b, c, d, lens)
+
+
+def _kernel_path_bwd(interpret, res, cts):
+    # no cell trains through a scan: the backward is the lax form's
+    *operands, lens = res
+    _, vjp = jax.vjp(
+        lambda *ops: _ssm_scan_lax(*ops, lens), *operands)
+    return (*vjp(cts), None)
+
+
+_ssm_scan_kernel_path.defvjp(_kernel_path_fwd, _kernel_path_bwd)
+
+
+def ssm_scan(x, delta, a, b, c, d, lengths=None, interpret=False):
     """Selective scan from a zero state over padded sequences.
 
     x, delta (B, T, Di); a (Di, N); b, c (B, T, N); d (Di,); lengths
     (B,) real tokens per row (None: all T). Returns (y (B, T, Di),
     state (B, Di, N)): the state after each row's LAST REAL token:
     positions at or past a row's length leave its state untouched
-    (their y is finite and meaningless)."""
+    (their y is finite and meaningless). The kernel where
+    ``_use_kernel`` says so (``interpret``: the kernel in interpret
+    mode, whatever the device: the tests' way in), else the lax
+    form."""
     bsz, t, di = x.shape
     n = a.shape[1]
-    f32 = jnp.float32
     lens = (jnp.full((bsz,), t, jnp.int32) if lengths is None
             else lengths.reshape(-1).astype(jnp.int32))
+    kernel = interpret or _use_kernel(t, di, n)
+    SSM_SCAN_TRACES.inc(path="kernel" if kernel else "lax")
     with jax.named_scope(SSM_SCAN):
-        a_t = a.astype(f32).T
-        dd = d.astype(f32)
-
-        def body(state, inp):
-            i, x_t, dt_t, b_t, c_t = inp
-            y, new = _ssm_update(state, x_t, dt_t, a_t, b_t, c_t, dd)
-            live = (i < lens)[:, None, None]
-            return jnp.where(live, new, state), y
-
-        xs = (jnp.arange(t, dtype=jnp.int32),
-              jnp.swapaxes(x.astype(f32), 0, 1),
-              jnp.swapaxes(delta.astype(f32), 0, 1),
-              jnp.swapaxes(b.astype(f32), 0, 1),
-              jnp.swapaxes(c.astype(f32), 0, 1))
-        state, ys = lax.scan(body, jnp.zeros((bsz, n, di), f32), xs,
-                             unroll=min(_SCAN_UNROLL, t))
-        return (jnp.swapaxes(ys, 0, 1).astype(x.dtype),
-                jnp.swapaxes(state, 1, 2))
+        if kernel:
+            y, state = _ssm_scan_kernel_path(x, delta, a, b, c, d, lens,
+                                             interpret)
+        else:
+            y, state = _ssm_scan_lax(x, delta, a, b, c, d, lens)
+        return y.astype(x.dtype), jnp.swapaxes(state, 1, 2)
 
 
 def ssm_step(x, delta, a, b, c, d, state):

@@ -1698,6 +1698,8 @@ class DecodeServer:
         self._kda_state_bytes_per_slot = sum(
             e.nbytes for e in self._spec
             if e.name.startswith("kda_")) // self.slots
+        # layers whose prefill is a selective scan (``ptpu.ssm_scan``)
+        self._ssm_layers = cfg.layer_kinds().count("mamba")
         # a sliding-window layer's ring holds this many rows (0: none)
         self._ring_window = int(cfg.window) if cfg.has_ring else 0
         # layers that route over experts: a step and a prefill return
@@ -1941,6 +1943,7 @@ class DecodeServer:
     _has_tail = False
     _latent_row_bytes = 0
     _kda_state_bytes_per_slot = 0
+    _ssm_layers = 0
 
     # prompts one admission prefills at most, while sequences are live,
     # and the bucketed tokens (power-of-two batch x the prompts' bucket)
@@ -2067,7 +2070,11 @@ class DecodeServer:
         prefill are counted from. Of a model with KDA layers,
         ``kda_tokens`` and ``kda_pad_tokens``: the real rows each such
         layer's chunked scan walked, and the rows of the bucket beyond
-        them (scanned too, and leaving every state alone)."""
+        them (scanned too, and leaving every state alone). Of a model
+        with state-space layers, ``ssm_tokens`` and ``ssm_pad_tokens``:
+        the same two of each selective scan (the kernel skips the
+        blocks of positions wholly past a row's length, the lax form
+        walks them all)."""
         counts = {"entries": len(self._spec),
                   "state_slots": n if self._state_bytes_per_slot else 0}
         if self._ring_window:
@@ -2088,6 +2095,10 @@ class DecodeServer:
             counts["kda_tokens"] = sum(len(p) for p in prompts)
             counts["kda_pad_tokens"] = (int(bucket_rows)
                                         - counts["kda_tokens"])
+        if self._ssm_layers:
+            counts["ssm_tokens"] = sum(len(p) for p in prompts)
+            counts["ssm_pad_tokens"] = (int(bucket_rows)
+                                        - counts["ssm_tokens"])
         return counts
 
     def _note_load(self, load):

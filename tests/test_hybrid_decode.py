@@ -193,7 +193,12 @@ def test_scatter_and_step_counts_carry_the_state(pred):
     assert counts == {"active": 2, "attended": 14,
                       "streamed": SLOTS * SEQ,  # the lax path: whole slab
                       "state_bytes": 2 * SLOTS * per_slot}
-    assert srv._scatter_counts(3) == {"entries": 8, "state_slots": 3}
+    assert srv._scatter_counts(3) == {"entries": 8, "state_slots": 3,
+                                      "ssm_tokens": 0, "ssm_pad_tokens": 0}
+    # the real rows each selective scan walks, and the bucket's beyond
+    prompts = [np.arange(n, dtype=np.int64) % 7 for n in (20, 3, 9)]
+    sc = srv._scatter_counts(3, prompts, bucket_rows=4 * 32)
+    assert (sc["ssm_tokens"], sc["ssm_pad_tokens"]) == (32, 96)
 
 
 # -- the cache manager's one description -------------------------------------

@@ -262,9 +262,10 @@ def test_server_counts_one_slab_and_its_readers(pred):
                       "state_bytes": 2 * SLOTS * state,
                       "ring_rows": 4 + 8, "slab_readers": 2}
     prompts = _prompts([20, 3], seed=11)
-    sc = srv._scatter_counts(2, prompts)
+    sc = srv._scatter_counts(2, prompts, bucket_rows=2 * 32)
     assert sc == {"entries": 12, "state_slots": 2, "ring_rows": 8 + 3,
-                  "prompt_rows": 23, "tail_rows": 2}
+                  "prompt_rows": 23, "tail_rows": 2,
+                  "ssm_tokens": 23, "ssm_pad_tokens": 41}
 
 
 def _is_greedy(seeded, prompt, generated):

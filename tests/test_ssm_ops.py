@@ -1,14 +1,23 @@
 """State-space op battery (ops/ssm.py): the selective scan against a
 step-by-step numpy recurrence, padded against unpadded (state and
 window taken at each row's length), scan-then-step against a scan one
-token longer, the causal convolution and its step, RMS normalization,
+token longer, the scan's Pallas kernel in interpret mode against the
+lax form and the gate that chooses between them, the causal
+convolution and its step, RMS normalization,
 and decode attention with fewer key/value heads than query heads. Each
 op's infer rule is cross-checked against the traced shapes."""
 import math
+import types
 
 import numpy as np
 import pytest
 
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu import observability as obs
+from paddle_tpu.ops import kv_cache as KV
+from paddle_tpu.ops import ssm as S
 from tests.op_test import check_infer, run_op
 
 B, T, DI, N, K = 3, 21, 12, 4, 4
@@ -80,19 +89,154 @@ def test_ssm_scan_padding_does_not_advance_the_state():
     np.testing.assert_allclose(padded, alone, rtol=1e-6, atol=1e-6)
 
 
-def test_ssm_scan_then_step_is_a_scan_one_token_longer():
-    inp = _ssm_inputs(t=T + 1)
-    y_all, state_all = _scan(inp)
-    head = {k: (v[:, :T] if v.ndim == 3 else v) for k, v in inp.items()}
-    _, state = _scan(head)
-    feeds = {k: (v[:, T:] if v.ndim == 3 else v) for k, v in inp.items()}
+def _kernel_inputs(bsz, t, di=128, n=8, seed=0):
+    """Operands of a shape the kernel takes (``S._kernel_blocks``)."""
+    r = np.random.RandomState(seed)
+    return (r.randn(bsz, t, di).astype(np.float32),
+            (np.abs(r.randn(bsz, t, di)) * 0.3 + 0.01).astype(np.float32),
+            -np.exp(r.randn(di, n) * 0.3).astype(np.float32),
+            r.randn(bsz, t, n).astype(np.float32),
+            r.randn(bsz, t, n).astype(np.float32),
+            r.randn(di).astype(np.float32))
+
+
+def _traces():
+    got = {k["path"]: v for k, v in obs.SSM_SCAN_TRACES.samples()}
+    return got.get("kernel", 0), got.get("lax", 0)
+
+
+@pytest.mark.parametrize("path", ["lax", "kernel"])
+def test_ssm_scan_then_step_is_a_scan_one_token_longer(path):
+    """The state a scan hands to ``ssm_step`` is the state of a scan
+    one token longer, whichever form scanned: the lax form over 21 and
+    22 positions, the kernel over a bucket of 256 stopped at 128 (a
+    block's last row) and at 129."""
+    if path == "lax":
+        inp = _ssm_inputs(t=T + 1)
+        y_all, state_all = _scan(inp)
+        head = {k: (v[:, :T] if v.ndim == 3 else v) for k, v in inp.items()}
+        _, state = _scan(head)
+        at, di = T, DI
+    else:
+        at, di = S._KERNEL_BLOCK_T, 128
+        ops = _kernel_inputs(B, 2 * at, di)
+        inp = dict(zip(("X", "Delta", "A", "B", "C", "D"), ops))
+        y_all, state_all = S.ssm_scan(
+            *ops, jnp.full((B,), at + 1, jnp.int32), interpret=True)
+        _, state = S.ssm_scan(*ops, jnp.full((B,), at, jnp.int32),
+                              interpret=True)
+        y_all, state_all = np.asarray(y_all), np.asarray(state_all)
+    feeds = {k: (v[:, at:at + 1] if v.ndim == 3 else v)
+             for k, v in inp.items()}
     feeds["State"] = state
     out = run_op("ssm_step", feeds, outs=("Y", "StateOut"))
-    assert np.asarray(out["Y"]).shape == (B, 1, DI)
-    np.testing.assert_allclose(np.asarray(out["Y"])[:, 0], y_all[:, T],
+    assert np.asarray(out["Y"]).shape == (B, 1, di)
+    np.testing.assert_allclose(np.asarray(out["Y"])[:, 0], y_all[:, at],
                                rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(np.asarray(out["StateOut"]), state_all,
                                rtol=2e-5, atol=2e-5)
+
+
+_BT = S._KERNEL_BLOCK_T
+_KERNEL_CASES = [
+    # id, bucket positions, lengths (None: every position)
+    ("every_position", 2 * _BT, None),
+    ("ragged", 2 * _BT, [2 * _BT, _BT + 3, 57]),
+    ("block_boundary", 2 * _BT, [_BT, _BT - 1, _BT + 1]),
+    ("zero_beside_full", 2 * _BT, [0, 2 * _BT]),
+    ("one_block", _BT, [_BT, 5]),
+    ("many_blocks", 4 * _BT, [4 * _BT, 2 * _BT + 44, 1]),
+    ("past_the_bucket", _BT, [_BT + 9, 8]),
+]
+
+
+@pytest.mark.parametrize("t,lens", [c[1:] for c in _KERNEL_CASES],
+                         ids=[c[0] for c in _KERNEL_CASES])
+def test_ssm_scan_kernel_matches_the_lax_form(t, lens):
+    """The Pallas kernel (interpret mode) against the lax form: the
+    state at each row's length, y at every live position, y finite
+    everywhere (zeros past a row's length, where the lax form's is
+    finite and meaningless); the counter says which form was traced."""
+    bsz = 2 if lens is None else len(lens)
+    ops = _kernel_inputs(bsz, t)
+    ln = None if lens is None else jnp.asarray(lens, jnp.int32)
+    k0, l0 = _traces()
+    want_y, want_state = S.ssm_scan(*ops, ln)
+    assert _traces() == (k0, l0 + 1)
+    got_y, got_state = S.ssm_scan(*ops, ln, interpret=True)
+    assert _traces() == (k0 + 1, l0 + 1)
+    assert got_y.shape == want_y.shape == (bsz, t, 128)
+    assert got_state.shape == want_state.shape == (bsz, 128, 8)
+    np.testing.assert_allclose(got_state, want_state, rtol=1e-5, atol=1e-6)
+    got_y = np.asarray(got_y)
+    assert np.isfinite(got_y).all()
+    for bi, n in enumerate([t] * bsz if lens is None else lens):
+        n = min(n, t)
+        np.testing.assert_allclose(got_y[bi, :n], np.asarray(want_y)[bi, :n],
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_array_equal(got_y[bi, n:], 0.0)
+
+
+def test_ssm_scan_kernel_path_differentiates_as_the_lax_form():
+    """No cell trains through a scan, and the op must not start to
+    raise: the kernel path's backward is the lax form's."""
+    ops = _kernel_inputs(2, 2 * _BT, seed=3)
+    ln = jnp.asarray([2 * _BT, _BT + 3], jnp.int32)
+    w = np.random.RandomState(4).randn(2, 2 * _BT, 128).astype(np.float32)
+    w[1, _BT + 3:] = 0.0  # a padded position's y means nothing
+
+    def loss(interpret, x, delta, a, b, c, d):
+        y, state = S.ssm_scan(x, delta, a, b, c, d, ln, interpret=interpret)
+        return jnp.sum(y * w) + jnp.sum(state * state)
+
+    args = tuple(range(1, 7))
+    want = jax.grad(loss, argnums=args)(False, *ops)
+    got = jax.grad(loss, argnums=args)(True, *ops)
+    for g, wnt in zip(got, want):
+        np.testing.assert_allclose(g, wnt, rtol=2e-4, atol=2e-4)
+
+
+_GATE_CASES = [
+    # id, (t, d_inner, d_state), blocks or None
+    ("the-cells-2048", (2048, 5120, 16), (_BT, S._KERNEL_BLOCK_D)),
+    ("one-block", (_BT, 5120, 16), (_BT, S._KERNEL_BLOCK_D)),
+    ("d-inner-of-twelve-lane-tiles", (256, 1536, 16), (_BT, 512)),
+    ("under-one-block", (64, 5120, 16), None),
+    ("not-whole-blocks", (_BT + 8, 5120, 16), None),
+    ("d-inner-no-lane-tiles", (256, 5000, 16), None),
+    ("d-state-no-sublane-tiles", (256, 5120, 4), None),
+]
+
+
+@pytest.mark.parametrize("shape,blocks", [c[1:] for c in _GATE_CASES],
+                         ids=[c[0] for c in _GATE_CASES])
+def test_ssm_scan_gate_answers_from_shape_and_device(shape, blocks,
+                                                     monkeypatch):
+    """``_kernel_blocks`` answers from the shape; ``_use_kernel`` adds
+    the device a step is bound for: never the CPU."""
+    assert S._kernel_blocks(*shape) == blocks
+    assert not S._use_kernel(*shape)
+    monkeypatch.setattr(KV, "current_device",
+                        lambda: types.SimpleNamespace(platform="tpu"))
+    assert S._use_kernel(*shape) == (blocks is not None)
+    monkeypatch.setenv("PADDLE_TPU_NO_PALLAS", "1")
+    assert not S._use_kernel(*shape)
+
+
+def test_ssm_scan_a_refused_shape_takes_the_lax_path(monkeypatch):
+    """A bucket under one block of positions, on a device the kernel
+    runs on: the lax form, and the counter says so; the kernel's own
+    entry refuses it by name."""
+    monkeypatch.setattr(KV, "current_device",
+                        lambda: types.SimpleNamespace(platform="tpu"))
+    ops = _kernel_inputs(2, _BT // 2)
+    k0, l0 = _traces()
+    y, state = S.ssm_scan(*ops, jnp.asarray([_BT // 2, 3], jnp.int32))
+    assert _traces() == (k0, l0 + 1)
+    assert y.shape == (2, _BT // 2, 128) and state.shape == (2, 128, 8)
+    with pytest.raises(ValueError, match="the lax form runs it"):
+        S.pallas_ssm_scan(*ops, jnp.asarray([1, 1], jnp.int32),
+                          interpret=True)
 
 
 def _np_conv(x, w, bias):

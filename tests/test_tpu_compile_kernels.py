@@ -174,3 +174,47 @@ def test_latent_attention_kernel_compiles(one_chip, b, s, h):
     ops = [op for op, _, _ in _whole_slab_ops(text, slab)]
     assert ops[0] == "parameter" and set(ops[1:]) == {"bitcast"}, ops
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+@pytest.mark.parametrize("b,t", [(1, 2048), (8, 256)],
+                         ids=["cell-1x2048", "cell-8x256"])
+def test_ssm_scan_kernel_compiles(one_chip, b, t):
+    """The selective scan's kernel (`ops/ssm.py`) at the hybrid cells'
+    widths (`d_inner` 5,120, `d_state` 16), at their longest bucket and
+    at their widest admission: Mosaic takes the (8, 16) transposes of a
+    group's B and C rows, the lane broadcast of a position's column and
+    the 16-sublane sum on a (16, lanes) state tile, inside the default
+    scoped VMEM; x, delta and y are the (B, T, Di) arrays where they
+    lie (no transposed copy of them outside the call, which the lax
+    form's `while` needs five of), and the only temporaries are B and C
+    padded to whole lanes."""
+    from paddle_tpu.ops import ssm as S
+
+    di, n = 5120, 16
+    block_t, block_d = S._kernel_blocks(t, di, n)
+    # double-buffered blocks of x, delta, y, A^T, B, C (N lanes padded
+    # to 128), D (8 sublanes), the state's block and the state's scratch
+    vmem = 4 * (2 * (3 * block_t * block_d + n * block_d
+                     + 2 * block_t * 128 + 8 * block_d + n * block_d)
+                + n * block_d)
+    print("ptpu.ssm_scan (%d, %d, %d): blocks of (%d positions, %d lanes), "
+          "%.2f MiB of VMEM in blocks and scratch"
+          % (b, t, di, block_t, block_d, vmem / 2**20))
+    assert vmem < 12 * 2**20
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = _compiled(
+        S.pallas_ssm_scan, sd((b, t, di)), sd((b, t, di)), sd((di, n)),
+        sd((b, t, n)), sd((b, t, n)), sd((di,)), sd((b,), jnp.int32))
+    text = compiled.as_text()
+    line, = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert "%ptpu.ssm_scan" in line.split(" = ")[0], line
+    assert " while(" not in text
+    wide = [(op, name) for op, name, _ in _whole_slab_ops(text, (b, t, di))
+            if op not in ("parameter", "get-tuple-element")]
+    assert not wide, "x, delta or y copied outside the call: %r" % wide
+    assert compiled.memory_analysis().temp_size_in_bytes <= (
+        2 * b * t * 128 * 4 + 2**16)

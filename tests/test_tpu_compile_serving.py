@@ -126,12 +126,24 @@ def test_hybrid_serving_step_compiles(one_chip, monkeypatch, kind, batch,
     nothing else. The decode step donates every cache entry and gets each
     back in place: its fetches come in the feeds' own (sorted) order, so
     no recurrent state (64 x 5120 x 16) and no slab is copied to repair a
-    pairing, and the step's temporaries stay small. The prefill holds one
-    `while` a state-space layer (the plain `lax.scan`s) and one Mosaic
-    call (the flash forward of the attention layer)."""
+    pairing, and the step's temporaries stay small. The prefill, bound
+    for a TPU, holds one Mosaic call a state-space layer
+    (`ptpu.ssm_scan`, the selective scan's kernel: 13) beside the flash
+    forward of the attention layer, and no `while`: no scan takes the
+    lax form in a bucket of whole blocks of positions."""
+    import types
+
+    from paddle_tpu import observability as obs
+    from paddle_tpu.ops import kv_cache as KV
     from paddle_tpu.serving.decode import DecodeConfig, DecodePredictor
 
     monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
+    if kind == "prefill":
+        # the gate asks what device a step is bound for, and here that
+        # is the CPU: the test steers it, as FORCE_PALLAS steers the
+        # flash kernel's
+        monkeypatch.setattr(KV, "current_device",
+                            lambda: types.SimpleNamespace(platform="tpu"))
     pred = DecodePredictor.__new__(DecodePredictor)  # graph builder only
     pred.config = DecodeConfig(
         vocab_size=65536, n_layer=14, n_head=20, d_model=2560, d_inner=8192,
@@ -142,6 +154,11 @@ def test_hybrid_serving_step_compiles(one_chip, monkeypatch, kind, batch,
     pred.sample_k, pred.sample_p, pred.temperature = 40, 0.9, 1.0
     step_fn, feeds, state, _ = _serving_step(pred, kind, batch, seq,
                                              one_chip)
+
+    def scans_traced():
+        return {k["path"]: v for k, v in obs.SSM_SCAN_TRACES.samples()}
+
+    before = scans_traced()
     compiled = jax.jit(step_fn, donate_argnums=(0,)).lower(
         feeds, state).compile()
     mem = compiled.memory_analysis()
@@ -150,8 +167,14 @@ def test_hybrid_serving_step_compiles(one_chip, monkeypatch, kind, batch,
     assert total < HBM_BYTES, "does not fit one chip: %r" % (mem,)
     text = compiled.as_text()
     if kind == "prefill":
-        assert text.count("tpu_custom_call") == 1   # the flash forward
-        assert text.count(" while(") == 13          # one scan a layer
+        after = scans_traced()
+        assert after["kernel"] - before.get("kernel", 0) == 13
+        assert after.get("lax", 0) == before.get("lax", 0)
+        calls = [ln.split(" = ")[0] for ln in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in ln]
+        assert len(calls) == 14, calls              # 13 scans, one flash
+        assert sum("ptpu.ssm_scan" in c for c in calls) == 13, calls
+        assert " while(" not in text
         return
     # the lax path of one shared K/V head: no Mosaic call in the step
     assert "tpu_custom_call" not in text
