@@ -196,7 +196,9 @@ def phase_kda(cfg):
     `ptpu.kda_step` (one update) compiled and run on the device, against
     each other: the scan over a whole text == the scan over its first
     rows handed to the step, then one step after another (outputs and
-    the last state); a row's padding leaves its state alone."""
+    the last state); a row's padding leaves its state alone. On a TPU
+    the scan is the KERNEL (one `ptpu.kda_scan` call in the compiled
+    text, asserted); elsewhere the lax form."""
     import jax
     import jax.numpy as jnp
 
@@ -214,6 +216,14 @@ def phase_kda(cfg):
     beta = jnp.asarray(r.uniform(0.05, 0.95, size=(b, t, h)), jnp.float32)
     scan = jax.jit(lambda *a: kda.kda_scan(*a, lower_bound=-5.0))
     step = jax.jit(kda.kda_step)
+    path = "kernel" if kda._use_kernel(t, d, d, -5.0) else "lax"
+    text = scan.lower(q, k, v, g, beta, jnp.asarray(lens)).compile().as_text()
+    calls = sum("%ptpu.kda_scan" in ln.split(" = ")[0]
+                and "tpu_custom_call" in ln for ln in text.splitlines())
+    if jax.devices()[0].platform == "tpu":
+        assert (path, calls) == ("kernel", 1), (
+            "the scan at %r: path %s, %d kernel calls" % ((b, t, h, d),
+                                                         path, calls))
     o_all, s_all = scan(q, k, v, g, beta, jnp.asarray(lens))
     _, state = scan(q, k, v, g, beta, jnp.asarray(lens - steps))
     outs = []
@@ -231,7 +241,7 @@ def phase_kda(cfg):
         assert e <= TOL_KDA, ("kda_scan vs kda_step, %s: %.3g > %.3g"
                               % (name, e, TOL_KDA))
     _emit("kda", shape=[b, t, h, d], lengths=lens.tolist(), steps=steps,
-          rel_err=errs, ok=True)
+          path=path, kernel_calls=calls, rel_err=errs, ok=True)
 
 
 # -- phase 2: train ---------------------------------------------------------

@@ -60,7 +60,7 @@ __all__ = [
     "REQUEST_PHASE_MS", "TRACE_SPANS", "tracing",
     "TRANSPILE_OPS_REMOVED", "TRANSPILE_OPS_FUSED", "TRANSPILE_PASS_MS",
     "QUANT_CALIB_BATCHES", "QUANT_OPS", "QUANT_PARITY",
-    "FUSED_HEAD_TRACES", "MLA_TRACES", "SSM_SCAN_TRACES",
+    "FUSED_HEAD_TRACES", "MLA_TRACES", "SSM_SCAN_TRACES", "KDA_SCAN_TRACES",
     "MOE_TOKENS_ELSEWHERE",
 ]
 
@@ -101,6 +101,14 @@ SSM_SCAN_TRACES = REGISTRY.counter(
     "stops at a row's length) | lax (a lax.scan over every position of "
     "the bucket: the CPU, a bucket under one block of positions). "
     "Counted when the op is traced: a program loaded from a cache adds 0")
+KDA_SCAN_TRACES = REGISTRY.counter(
+    "paddle_tpu_kda_scan_traces_total",
+    "Traces of the chunked delta rule (ops/kda.py), by path=kernel (one "
+    "Pallas call whose matrix state and chunk factors stay in vector "
+    "memory and whose grid stops at a row's length) | lax (composed lax "
+    "over every chunk of the bucket: the CPU, a gate with no lower "
+    "bound, a shape the kernel does not take). Counted when the op is "
+    "traced: a program loaded from a cache adds 0")
 CACHE_HITS = REGISTRY.counter(
     "paddle_tpu_compile_cache_hits_total",
     "Compile-cache hits, by kind, program fingerprint, and "

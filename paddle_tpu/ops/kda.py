@@ -39,8 +39,19 @@ THREE forms that compute the same numbers:
   with q for k_t and the diagonal kept) and ``S_C = Diag(e^{G_C}) S_0 +
   K^^T U`` with ``K^_i = e^{G_C - G_i} k_i``. Exact algebra: no term is
   dropped. A, T, ``T Diag(beta) V`` and ``T Diag(beta) K~`` need no
-  state, so they are built for ``_BLOCK_CHUNKS`` chunks at once; the
-  state is touched once a chunk, by three matrix products.
+  state; the state is touched once a chunk, by three matrix products.
+  The chunked form has TWO PATHS of that one algebra
+  (``paddle_tpu_kda_scan_traces_total{path}`` says which a program was
+  traced with; ``_use_kernel`` chooses by shape, gate and device):
+  composed lax (``_kda_scan_lax``: the state-free parts built for
+  ``_BLOCK_CHUNKS`` chunks at once in HBM, a ``lax.scan`` a chunk: the
+  CPU's path, the guarded gate's, the kernel's reference and its
+  backward), and on a TPU one Pallas call a layer (``pallas_kda_scan``,
+  since PR 42): a head's (dk, dv) state and a block's factors, Grams
+  and inverses stay in vector memory, the operands come in once as
+  blocks of the (B, T, H * d) arrays where they lie, the L2 norms and
+  the masking of dead positions are inside, and a block of positions
+  wholly past a row's length is neither fetched nor computed.
 
 The per-channel decay is what makes the chunked form hard: ``e^{G_t -
 G_i}`` is a product ``e^{G_t} e^{-G_i}`` only while ``e^{-G_i}`` fits a
@@ -67,10 +78,17 @@ part of ``kda_scan`` / ``kda_step``.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from ..observability import KDA_SCAN_TRACES
+from . import attention as _A
+from . import kv_cache as _KV
 from .registry import register_op
 
 KDA_GATE = "ptpu.kda_gate"
@@ -86,6 +104,11 @@ _BLOCK_CHUNKS = 16  # chunks whose state-free parts are built at once,
 _BLOCK_TOKENS = 2048  # ... as long as the batch's rows hold no more tokens
 _SAFE_EXP = 80.0    # e^80 < float32's 3.4e38, with a factor of 6e3 spare
 _L2_EPS = 1e-6
+
+# positions a grid cell of the kernel walks (whole pairs of chunks), and
+# the pairs that one iteration of its state-free loops holds
+_KERNEL_BLOCK_T = 256
+_KERNEL_GROUP = 2
 
 # the products inside a chunk (A, its inverse, T Diag(beta) [V, K~]): what
 # the solve amplifies. Three bfloat16 passes (float32 to ~2^-17): six
@@ -276,7 +299,434 @@ def _chunks(state, q, k, v, g, beta, guarded):
     return jnp.transpose(o, (1, 0, 3, 2, 4)), state
 
 
-def kda_scan(q, k, v, g, beta, lengths=None, lower_bound=None, qk_norm=True):
+def _guarded(lower_bound) -> bool:
+    """Whether a gate with this bound takes the guarded form: no bound,
+    or one under which ``_SUB`` tokens leave float32."""
+    return (lower_bound is None
+            or _SUB * abs(float(lower_bound)) > _SAFE_EXP)
+
+
+def _kda_scan_lax(q, k, v, g, beta, lens, guarded, qk_norm):
+    """The chunked form as composed lax: the CPU's path, the guarded
+    gate's, the kernel's reference and its backward. Shapes as
+    ``kda_scan``; lens (B,) int32 -> (o (B, T, H, dv) float32, state)."""
+    bsz, t, h, dk = q.shape
+    dv = v.shape[-1]
+    q, k = _prepare(q, k, qk_norm)
+    live = jnp.arange(t, dtype=jnp.int32)[None, :] < lens[:, None]
+    g = jnp.where(live[:, :, None, None], g.astype(jnp.float32), 0.0)
+    beta = jnp.where(live[:, :, None], beta.astype(jnp.float32), 0.0)
+    # the factored form holds 4 (C, H, dk) factors a chunk (0.5 MB a
+    # token at the published widths), the guarded form _SUB
+    per = _CHUNK * (1 if guarded else max(1, min(
+        _BLOCK_CHUNKS, _BLOCK_TOKENS // (_CHUNK * bsz))))
+    per = min(per, -(-t // _CHUNK) * _CHUNK)
+    pad = (-t) % per
+    nblk, n = (t + pad) // per, per // _CHUNK
+
+    def blocks(a):
+        a = jnp.pad(a.astype(jnp.float32),
+                    ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+        a = a.reshape((bsz, nblk, n, _CHUNK) + a.shape[2:])
+        return jnp.swapaxes(a, 0, 1)                     # block first
+
+    xs = tuple(blocks(a) for a in (q, k, v, g, beta))
+    state = jnp.zeros((bsz, h, dk, dv), jnp.float32)
+
+    def body(s, x):
+        o, s = _chunks(s, *x, guarded=guarded)
+        return s, o
+
+    if nblk == 1:
+        state, o = body(state, tuple(a[0] for a in xs))
+        o = o[None]
+    else:
+        state, o = lax.scan(body, state, xs)
+    o = jnp.swapaxes(o, 0, 1).reshape(bsz, t + pad, h, dv)[:, :t]
+    return o, state
+
+
+def _split(x):
+    """float32 -> its two leading bfloat16 parts."""
+    hi = x.astype(jnp.bfloat16)
+    return hi, (x - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+
+
+def _mm(a, b, dims, passes):
+    """float32 ``a`` times float32 ``b`` contracting ``dims`` (one axis
+    of each), accumulated in float32, in ``passes`` bfloat16 passes of
+    the matrix unit: 1 (operands rounded to bfloat16: what XLA gives a
+    float32 product on a TPU by default), 3 (``Precision.HIGH``'s: both
+    operands in two parts, the low x low product dropped; written out,
+    since Mosaic lowers only DEFAULT and HIGHEST: the two parts of ``a``
+    stacked against the high part of ``b`` in one product, its high
+    part against the low part of ``b`` in another) or 6 (``HIGHEST``)."""
+    dn = (dims, ((), ()))
+    f32 = jnp.float32
+    if passes == 6:
+        return lax.dot_general(a, b, dn, precision=lax.Precision.HIGHEST,
+                               preferred_element_type=f32)
+    if passes == 1:
+        return lax.dot_general(a.astype(jnp.bfloat16), b.astype(jnp.bfloat16),
+                               dn, preferred_element_type=f32)
+    (a_hi, a_lo), (b_hi, b_lo) = _split(a), _split(b)
+    free = 1 - dims[0][0]               # the axis of ``a`` that stays
+    both = lax.dot_general(jnp.concatenate([a_hi, a_lo], axis=free), b_hi,
+                           dn, preferred_element_type=f32)
+    n = a.shape[free]
+    return (both[:n] + both[n:]) + lax.dot_general(
+        a_hi, b_lo, dn, preferred_element_type=f32)
+
+
+def _cumsum_rows(x, period):
+    """Running sum down the rows of (n, lanes), started again every
+    ``period`` rows (a power of two), in float32 adds: log2(period)
+    shifted adds."""
+    row = lax.broadcasted_iota(jnp.int32, (x.shape[0], 1), 0) % period
+    s = 1
+    while s < period:
+        x = x + jnp.where(row >= s, pltpu.roll(x, s, 0), 0.0)
+        s *= 2
+    return x
+
+
+def _kda_scan_kernel(len_ref, q_ref, k_ref, v_ref, g_ref, b_ref, o_ref,
+                     st_ref, s_ref, qd_ref, kd_ref, ke_ref, vb_ref, aqk_ref,
+                     am_ref, mb_ref, xb_ref, de_ref, *, block_t, n_t, qk_norm,
+                     intra, state, group):
+    """One (row, head, block of positions) grid cell, the last axis
+    sequential. q_ref, k_ref, g_ref (1, Tb, dk), v_ref, o_ref (1, Tb,
+    dv): a head's lanes of the (B, T, H * d) arrays where they lie;
+    b_ref (1, Tb, H) every head's beta; st_ref (1, 1, dk, dv) the
+    state's block, written at the row's last cell; s_ref (dk, dv) the
+    state, which lives in vector memory from the row's first block to
+    its last. The other scratches hold a block's state-free parts
+    between the passes below; nothing of them goes to HBM.
+
+    The module doc's algebra in four passes over the block's LIVE
+    chunks, two chunks (a PAIR) at a time and ``group`` pairs a loop
+    iteration. A chunk's 64 x 64 matrices fill half a register's lanes
+    and a quarter of the matrix unit, so a pair's lie SIDE BY SIDE,
+    ``[X_0 | X_1]`` (64, 128), and a product with both is one product
+    with the second operand block-diagonal: ``[X_0 | X_1] diag(Y_0,
+    Y_1) = [X_0 Y_0 | X_1 Y_1]``, the same numbers (a zero contributes
+    nothing) in half the operations. A chunk of the last pair past the
+    row's length, like a position past it inside a live chunk, gets g =
+    0 and beta = 0: it decays nothing and writes nothing.
+
+    1, state-free: the L2 norms, the running sum of g, the sub-chunk
+       factors, the two Grams (the 2 x 32 rows of q and k of a pair's
+       a-th sub-chunks against the keys of the sub-chunks up to their
+       own, four products a pair), ``Diag(beta) A`` and its diagonal
+       blocks;
+    2, ONCE for the block: forward substitution on all its ``Tb / 16``
+       diagonal blocks together, row i of every block a strided read:
+       fifteen dependent steps on a few full registers where a block at
+       a time would be fifteen steps on a sixteenth of one;
+    3, state-free: the inverse by halves as products of the whole 64 x
+       64 with the other blocks masked to zero, then ``T Diag(beta) [V,
+       K~]``;
+    4, the three products that touch the state, a chunk after a
+       chunk."""
+    f32 = jnp.float32
+    c_, s_ = _CHUNK, _SUB
+    ns = c_ // s_
+    dk, dv = q_ref.shape[-1], v_ref.shape[-1]
+    head = pl.program_id(1)
+    ti = pl.program_id(2)
+    n_live = jnp.clip(len_ref[pl.program_id(0)] - ti * block_t, 0, block_t)
+    n_groups = (n_live + 2 * group * c_ - 1) // (2 * group * c_)
+
+    @pl.when(ti == 0)
+    def _():
+        s_ref[...] = jnp.zeros(s_ref.shape, f32)
+
+    @pl.when(n_live < block_t)
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    # a pair's (64, 128): the row, the column inside its own chunk, and
+    # which chunk of the two
+    tr = lax.broadcasted_iota(jnp.int32, (c_, 2 * c_), 0)
+    lane = lax.broadcasted_iota(jnp.int32, (c_, 2 * c_), 1)
+    tc, left = lane % c_, lane < c_
+    is_head = lax.broadcasted_iota(
+        jnp.int32, (2 * c_, b_ref.shape[-1]), 1) == head
+
+    def norm(x, scale):
+        if not qk_norm:
+            return x * scale if scale != 1.0 else x
+        return x * (lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True)
+                              + _L2_EPS) * scale)
+
+    def side_by_side(x_0, x_1):
+        # a mask of its own shape: Mosaic does not slice a mask
+        shape = (x_0.shape[0], 2 * c_)
+        return jnp.where(lax.broadcasted_iota(jnp.int32, shape, 1) < c_,
+                         x_0, x_1)
+
+    def block_diagonal(x):
+        """[X_0 | X_1] (64, 128) -> diag(X_0, X_1) (128, 128)."""
+        return jnp.concatenate([jnp.where(left, x, 0.0),
+                                jnp.where(left, 0.0, x)], axis=0)
+
+    def live_rows(base, n):
+        return base + lax.broadcasted_iota(jnp.int32, (n, 1), 0) < n_live
+
+    def pair_rows(p):
+        """A pair's rows in the (Tb, .) arrays and in the side-by-side
+        ones."""
+        return (pl.ds(pl.multiple_of(p * 2 * c_, 2 * c_), 2 * c_),
+                pl.ds(pl.multiple_of(p * c_, c_), c_))
+
+    def state_free(p):
+        rows, half = pair_rows(p)
+        live = live_rows(p * 2 * c_, 2 * c_)
+        q = norm(q_ref[0, rows, :], float(dk) ** -0.5)
+        k = norm(k_ref[0, rows, :], 1.0)
+        g = jnp.where(live, g_ref[0, rows, :], 0.0)
+        beta = jnp.where(live, jnp.sum(
+            jnp.where(is_head, b_ref[0, rows, :], 0.0), axis=-1,
+            keepdims=True), 0.0)                              # (2 C, 1)
+        g_cum = _cumsum_rows(g, c_)
+        g_end = jnp.concatenate(
+            [jnp.broadcast_to(g_cum[(j + 1) * c_ - 1:(j + 1) * c_], (c_, dk))
+             for j in range(2)], axis=0)
+        # G at each sub-chunk's middle token (module doc)
+        mid = [g_cum[a * s_ + s_ // 2 - 1:a * s_ + s_ // 2]
+               for a in range(2 * ns)]
+        e_row = jnp.exp(g_cum - jnp.concatenate(
+            [jnp.broadcast_to(m, (s_, dk)) for m in mid], axis=0))
+        qe, ke = q * e_row, k * e_row
+        a_qk, a_kk = [], []
+        for a in range(ns):
+            n = (a + 1) * s_
+            x, k_col = [], []
+            for j in range(2):          # the pair's two chunks
+                at = j * c_
+                x += [qe[at + a * s_:at + n], ke[at + a * s_:at + n]]
+                k_col.append(k[at:at + n] * jnp.exp(
+                    mid[j * ns + a] - g_cum[at:at + n]))
+                if n < c_:
+                    k_col.append(jnp.zeros((c_ - n, dk), f32))
+            gram = _mm(jnp.concatenate(x, axis=0),
+                       jnp.concatenate(k_col, axis=0), ((1,), (1,)), intra)
+            a_qk.append(side_by_side(gram[:s_], gram[2 * s_:3 * s_]))
+            a_kk.append(side_by_side(gram[s_:2 * s_], gram[3 * s_:]))
+        m = (side_by_side(beta[:c_], beta[c_:])
+             * jnp.where(tr > tc, jnp.concatenate(a_kk, axis=0), 0.0))
+        aqk_ref[half, :] = jnp.where(tr >= tc, jnp.concatenate(a_qk, axis=0),
+                                     0.0)
+        am_ref[half, :] = m                               # Diag(beta) A
+        mb_ref[rows, :] = jnp.concatenate(
+            [m[a * s_:(a + 1) * s_, j * c_ + a * s_:j * c_ + (a + 1) * s_]
+             for j in range(2) for a in range(ns)], axis=0)
+        e_cum = jnp.exp(g_cum)
+        qd_ref[rows, :] = q * e_cum
+        kd_ref[rows, :] = beta * (k * e_cum)
+        ke_ref[rows, :] = k * jnp.exp(g_end - g_cum)
+        vb_ref[rows, :] = beta * v_ref[0, rows, :]
+        # e^(G_C) a key channel, as the state's rows want it: down the
+        # sublanes, the same along the lanes
+        for j in range(2):
+            at = pl.multiple_of((2 * p + j) * dk, dk)
+            de_ref[pl.ds(at, dk), :] = jnp.exp(jnp.broadcast_to(
+                g_cum[(j + 1) * c_ - 1:(j + 1) * c_], (dk, dk))).T
+
+    def substitute():
+        # X[i] = e_i - sum_{j<i} M[i, j] X[j], every diagonal block of
+        # the grid cell at once: a block a sublane, the row's 16
+        # numbers along the lanes
+        nb = block_t // s_
+        col = lax.broadcasted_iota(jnp.int32, (nb, s_), 1)
+        solved = []
+        for i in range(s_):
+            m_row = mb_ref[pl.ds(i, nb, stride=s_), :]
+            x = (col == i).astype(f32)
+            for j in range(i):
+                x = x - m_row[:, j:j + 1] * solved[j]
+            solved.append(x)
+            xb_ref[pl.ds(i, nb, stride=s_), :] = x
+
+    def invert(p):
+        rows, half = pair_rows(p)
+        m = am_ref[half, :]
+        xb = xb_ref[rows, :]
+        t_inv = jnp.where(tr // s_ == tc // s_, jnp.concatenate(
+            [xb[:c_]] * ns + [xb[c_:]] * ns, axis=1), 0.0)
+        size = s_
+        while size < c_:
+            # [[A, 0], [C, D]]^-1 = [[A^-1, 0], [-D^-1 C A^-1, D^-1]] for
+            # every pair of diagonal blocks of ``size`` at once
+            pair_r, pair_c = tr // size, tc // size
+            low = jnp.where((pair_r == pair_c + 1) & (pair_r % 2 == 1), m, 0.0)
+            t_inv = t_inv - _mm(
+                _mm(t_inv, block_diagonal(low), ((1,), (0,)), intra),
+                block_diagonal(t_inv), ((1,), (0,)), intra)
+            size *= 2
+        uw = _mm(block_diagonal(t_inv), jnp.concatenate(
+            [vb_ref[rows, :], kd_ref[rows, :]], axis=1), ((1,), (0,)), intra)
+        vb_ref[rows, :] = uw[:, :dv]     # T Diag(beta) V
+        kd_ref[rows, :] = uw[:, dv:]     # T Diag(beta) K~
+
+    def advance(p):
+        half = pair_rows(p)[1]
+        for j in range(2):
+            rows = pl.ds(pl.multiple_of((2 * p + j) * c_, c_), c_)
+            s = s_ref[...]
+            wq = _mm(jnp.concatenate([kd_ref[rows, :], qd_ref[rows, :]],
+                                     axis=0), s, ((1,), (0,)), state)
+            u = vb_ref[rows, :] - wq[:c_]
+            o = wq[c_:] + _mm(aqk_ref[half, j * c_:(j + 1) * c_], u,
+                              ((1,), (0,)), state)
+            o_ref[0, rows, :] = jnp.where(
+                live_rows((2 * p + j) * c_, c_), o, 0.0).astype(o_ref.dtype)
+            d_end = de_ref[pl.ds(pl.multiple_of((2 * p + j) * dk, dk), dk), :]
+            if dv != dk:
+                d_end = d_end[:, :1]
+            s_ref[...] = d_end * s + _mm(ke_ref[rows, :], u, ((0,), (0,)),
+                                         state)
+
+    def grouped(fn):
+        def body(i, carry):
+            for j in range(group):
+                fn(i * group + j)
+            return carry
+        lax.fori_loop(0, n_groups, body, 0)
+
+    @pl.when(n_live > 0)
+    def _():
+        grouped(state_free)
+        substitute()
+        grouped(invert)
+        lax.fori_loop(0, (n_live + 2 * c_ - 1) // (2 * c_),
+                      lambda p, carry: advance(p) or carry, 0)
+
+    @pl.when(ti == n_t - 1)
+    def _():
+        st_ref[0, 0] = s_ref[...]
+
+
+def _kernel_block(t, dk, dv, block_t=_KERNEL_BLOCK_T):
+    """Positions a block of the kernel for a (B, t, H, dk) scan into
+    (dk, dv) states, or None where the lax form runs: a sequence that is
+    not whole blocks, a head that does not fill whole 128-lane vectors."""
+    if t < block_t or t % block_t or dk % 128 or dv % 128:
+        return None
+    return block_t
+
+
+def _use_kernel(t, dk, dv, lower_bound) -> bool:
+    """A step bound for a TPU (PADDLE_TPU_NO_PALLAS opts out, as for
+    every kernel: ``kv_cache._use_pallas_decode``), the factored form (a
+    gate whose bound keeps ``_SUB`` tokens inside float32) and a shape
+    the kernel takes (``_kernel_block``)."""
+    return (not _guarded(lower_bound)
+            and _kernel_block(t, dk, dv) is not None
+            and _KV._use_pallas_decode(t, dk))
+
+
+def pallas_kda_scan(q, k, v, g, beta, lens, qk_norm=True,
+                    block_t=_KERNEL_BLOCK_T, intra=3, state=1,
+                    group=_KERNEL_GROUP, interpret=False):
+    """``_kda_scan_lax``'s contract (the factored form) through the
+    kernel: ONE call, the operands where they lie, a head a block of
+    lanes of the (B, T, H * d) views. ``lens`` is a scalar-prefetch
+    operand: a block of positions wholly past a row's length is neither
+    fetched (its index waits at the row's last live block) nor computed.
+    ``intra`` / ``state``: bfloat16 passes of the products inside a
+    chunk (``_INTRA``'s three) and of the three that touch the state
+    (XLA's default for float32: one); 6 is float32 all through, the
+    tests' way to hold the kernel to the recurrence."""
+    bsz, t, h, dk = q.shape
+    dv = v.shape[-1]
+    block_t = _kernel_block(t, dk, dv, block_t)
+    if block_t is None:
+        raise ValueError(
+            "no kernel for a (%d, %d, %d, %d) scan into (%d, %d) states; "
+            "the lax form runs it" % (bsz, t, h, dk, dk, dv))
+    n_t = t // block_t
+    f32 = jnp.float32
+
+    def last(bi, lens_ref):
+        return jnp.maximum(lens_ref[bi] + block_t - 1, block_t) // block_t - 1
+
+    def head_block(bi, hi, ti, lens_ref):
+        # past the row's last live block: the same block again
+        return bi, jnp.minimum(ti, last(bi, lens_ref)), hi
+
+    def beta_block(bi, hi, ti, lens_ref):
+        return bi, jnp.minimum(ti, last(bi, lens_ref)), 0
+
+    kernel = functools.partial(
+        _kda_scan_kernel, block_t=block_t, n_t=n_t, qk_norm=bool(qk_norm),
+        intra=intra, state=state, group=group)
+    o, st = _A.named_pallas_call(
+        KDA_SCAN, kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bsz, h, n_t),
+            in_specs=[
+                pl.BlockSpec((1, block_t, dk), head_block),
+                pl.BlockSpec((1, block_t, dk), head_block),
+                pl.BlockSpec((1, block_t, dv), head_block),
+                pl.BlockSpec((1, block_t, dk), head_block),
+                pl.BlockSpec((1, block_t, h), beta_block),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_t, dv),
+                             lambda bi, hi, ti, lens_ref: (bi, ti, hi)),
+                pl.BlockSpec((1, 1, dk, dv),
+                             lambda bi, hi, ti, lens_ref: (bi, hi, 0, 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((dk, dv), f32),           # the state
+                pltpu.VMEM((block_t, dk), f32),      # q e^G
+                pltpu.VMEM((block_t, dk), f32),      # beta k e^G
+                pltpu.VMEM((block_t, dk), f32),      # k e^(G_C - G)
+                pltpu.VMEM((block_t, dv), f32),      # beta v
+                pltpu.VMEM((block_t // 2, 2 * _CHUNK), f32),  # A_qk, pairs
+                pltpu.VMEM((block_t // 2, 2 * _CHUNK), f32),  # Diag(beta) A
+                pltpu.VMEM((block_t, _SUB), f32),    # its diagonal blocks
+                pltpu.VMEM((block_t, _SUB), f32),    # ... inverted
+                pltpu.VMEM((block_t // _CHUNK * dk, dk), f32),  # e^(G_C)
+            ]),
+        out_shape=[jax.ShapeDtypeStruct((bsz, t, h * dv), f32),
+                   jax.ShapeDtypeStruct((bsz, h, dk, dv), f32)],
+        interpret=interpret,
+        **_A._tpu_params("parallel", "parallel", "arbitrary"),
+    )(jnp.clip(lens, 0, t), q.astype(f32).reshape(bsz, t, h * dk),
+      k.astype(f32).reshape(bsz, t, h * dk),
+      v.astype(f32).reshape(bsz, t, h * dv),
+      g.astype(f32).reshape(bsz, t, h * dk), beta.astype(f32))
+    return o.reshape(bsz, t, h, dv), st
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _kda_scan_kernel_path(q, k, v, g, beta, lens, qk_norm, interpret):
+    return pallas_kda_scan(q, k, v, g, beta, lens, qk_norm,
+                           interpret=interpret)
+
+
+def _kernel_path_fwd(q, k, v, g, beta, lens, qk_norm, interpret):
+    out = pallas_kda_scan(q, k, v, g, beta, lens, qk_norm,
+                          interpret=interpret)
+    return out, (q, k, v, g, beta, lens)
+
+
+def _kernel_path_bwd(qk_norm, interpret, res, cts):
+    # no cell trains through a scan: the backward is the lax form's
+    *operands, lens = res
+    _, vjp = jax.vjp(
+        lambda *ops: _kda_scan_lax(*ops, lens, False, qk_norm), *operands)
+    return (*vjp(cts), None)
+
+
+_kda_scan_kernel_path.defvjp(_kernel_path_fwd, _kernel_path_bwd)
+
+
+def kda_scan(q, k, v, g, beta, lengths=None, lower_bound=None, qk_norm=True,
+             interpret=False):
     """The CHUNKED delta rule from a zero state over padded sequences:
     q, k, g (B, T, H, dk), v (B, T, H, dv), beta (B, T, H), lengths (B,)
     real tokens a row (None: all T) -> (o (B, T, H, dv), state (B, H,
@@ -285,47 +735,24 @@ def kda_scan(q, k, v, g, beta, lengths=None, lower_bound=None, qk_norm=True):
     meaningless). ``lower_bound``: the least log-decay a token's ``g``
     can hold (the gate's bound), or None where it has none: the
     factored form runs only where ``_SUB`` tokens at the bound stay
-    inside float32, the guarded form otherwise (module doc)."""
+    inside float32, the guarded form otherwise (module doc). The kernel
+    where ``_use_kernel`` says so (``interpret``: the kernel in
+    interpret mode, whatever the device: the tests' way in), else the
+    lax form."""
     bsz, t, h, dk = q.shape
     dv = v.shape[-1]
-    guarded = (lower_bound is None
-               or _SUB * abs(float(lower_bound)) > _SAFE_EXP)
     lens = (jnp.full((bsz,), t, jnp.int32) if lengths is None
             else lengths.reshape(-1).astype(jnp.int32))
+    kernel = interpret or _use_kernel(t, dk, dv, lower_bound)
+    KDA_SCAN_TRACES.inc(path="kernel" if kernel else "lax")
     with jax.named_scope(KDA_SCAN):
-        q, k = _prepare(q, k, qk_norm)
-        live = jnp.arange(t, dtype=jnp.int32)[None, :] < lens[:, None]
-        g = jnp.where(live[:, :, None, None], g.astype(jnp.float32), 0.0)
-        beta = jnp.where(live[:, :, None], beta.astype(jnp.float32), 0.0)
-        # the factored form holds 4 (C, H, dk) factors a chunk (0.5 MB a
-        # token at the published widths), the guarded form _SUB
-        per = _CHUNK * (1 if guarded else max(1, min(
-            _BLOCK_CHUNKS, _BLOCK_TOKENS // (_CHUNK * bsz))))
-        per = min(per, -(-t // _CHUNK) * _CHUNK)
-        pad = (-t) % per
-        nblk, n = (t + pad) // per, per // _CHUNK
-
-        def blocks(a):
-            a = jnp.pad(a.astype(jnp.float32),
-                        ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
-            a = a.reshape((bsz, nblk, n, _CHUNK) + a.shape[2:])
-            return jnp.swapaxes(a, 0, 1)                     # block first
-
-        xs = tuple(blocks(a) for a in (q, k, v, g, beta))
-        state = jnp.zeros((bsz, h, dk, dv), jnp.float32)
-
-        def body(s, x):
-            o, s = _chunks(s, *x, guarded=guarded)
-            return s, o
-
-        if nblk == 1:
-            state, o = body(state, tuple(a[0] for a in xs))
-            o = o[None]
+        if kernel:
+            o, state = _kda_scan_kernel_path(q, k, v, g, beta, lens,
+                                             bool(qk_norm), interpret)
         else:
-            state, o = lax.scan(body, state, xs)
-        o = jnp.swapaxes(o, 0, 1).reshape(bsz, t + pad, h, dv)[:, :t]
+            o, state = _kda_scan_lax(q, k, v, g, beta, lens,
+                                     _guarded(lower_bound), qk_norm)
         return o.astype(v.dtype), state
-
 
 def kda_step(q, k, v, g, beta, state, qk_norm=True):
     """One token: q, k, g (B, 1, H, dk) or (B, H, dk), v (B, 1, H, dv),

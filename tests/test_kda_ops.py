@@ -4,7 +4,8 @@ step after another, at lengths that are no multiple of the chunk, with
 padding, with a log-decay AT the gate's bound for whole chunks (where a
 chunk's decay cannot be factored from its start), and by the guarded
 path of a gate with no bound; the gate's two kinds; the triangular
-inverse; the ops through a Program."""
+inverse; the ops through a Program. The chunked form's kernel:
+`test_kda_kernel.py`."""
 import numpy as np
 import pytest
 

@@ -3,8 +3,8 @@
 attention kernels (both layouts, split and fused backward), the decode
 kernels (one K/V head a query head, and grouped queries on a slab of 8),
 the fused LM-head loss gradient, the kernel over a slab of flat rows,
-and the absorbed latent attention's kernel over its slab's transposed
-view. See `test_tpu_compile.py` for what such a compile can and cannot
+the absorbed latent attention's kernel over its slab's transposed
+view, the selective scan's kernel and the chunked delta rule's. See `test_tpu_compile.py` for what such a compile can and cannot
 say.
 """
 from __future__ import annotations
@@ -218,3 +218,60 @@ def test_ssm_scan_kernel_compiles(one_chip, b, t):
     assert not wide, "x, delta or y copied outside the call: %r" % wide
     assert compiled.memory_analysis().temp_size_in_bytes <= (
         2 * b * t * 128 * 4 + 2**16)
+
+
+@pytest.mark.parametrize("b,t", [(8, 2048), (2, 8192), (1, 16384)],
+                         ids=["cell-8x2048", "cell-2x8192", "cell-1x16384"])
+def test_kda_scan_kernel_compiles(one_chip, b, t):
+    """The chunked delta rule's kernel (`ops/kda.py`) at Ling-3.0-flash's
+    head sizes (32 heads of a 128 x 128 state), at the cell's widest,
+    its middle and its longest admission: Mosaic takes the strided
+    reads and writes of the forward substitution, the lane slices of the
+    diagonal blocks, the stacked bfloat16 parts of the three-pass
+    products and the dynamic loops over live chunks, inside the default
+    scoped VMEM. ONE call, whose result tuple holds the final state at
+    its interface shape (what the benchmark's reader of matrix states
+    looks for, `,32,128,128]`, in the call's own line); q, k, v, g are
+    the projections' (B, T, H * d) arrays where they lie; no `while`,
+    and no temporary of a chunk's or a sub-chunk's shape outside the
+    call: the only ones are o before its reshape and beta's copy."""
+    from paddle_tpu.ops import kda as K
+
+    h, d = 32, 128
+    block_t = K._kernel_block(t, d, d)
+    assert block_t == K._KERNEL_BLOCK_T
+    # double-buffered blocks of q, k, v, g, o, beta (H lanes padded to
+    # 128), the state's block; the scratches: the state, four (Tb, d),
+    # two (Tb, 64) and two (Tb, 16) padded to 128 lanes, e^(G_C)
+    vmem = 4 * (2 * (5 * block_t * d + block_t * 128 + d * d)
+                + d * d + 4 * block_t * d + 4 * block_t * 128
+                + block_t // 64 * d * d)
+    print("ptpu.kda_scan (%d, %d, %d, %d): blocks of %d positions, "
+          "%.2f MiB of VMEM in blocks and scratch"
+          % (b, t, h, d, block_t, vmem / 2**20))
+    assert vmem < 12 * 2**20
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def scan(q, k, v, g, beta, lens):
+        q, k, v, g = (a.reshape(b, t, h, d) for a in (q, k, v, g))
+        o, state = K.pallas_kda_scan(q, k, v, g, beta, lens)
+        return o.reshape(b, t, h * d), state
+
+    flat = sd((b, t, h * d))
+    compiled = _compiled(scan, flat, flat, flat, flat, sd((b, t, h)),
+                         sd((b,), jnp.int32))
+    text = compiled.as_text()
+    line, = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert "%ptpu.kda_scan" in line.split(" = ")[0], line
+    assert ",32,128,128]" in line.split(" custom-call(")[0], line[:400]
+    assert " while(" not in text
+    wide = [(op, name) for op, name, _ in _whole_slab_ops(text, (b, t, h * d))
+            if op not in ("parameter", "get-tuple-element", "bitcast")]
+    assert not wide, "q, k, v, g or o copied outside the call: %r" % wide
+    for shape in (",64,64]", ",16,16]", ",4,4,16,", ",64,32,128]"):
+        assert shape not in text, shape
+    assert compiled.memory_analysis().temp_size_in_bytes <= (
+        b * t * 128 * 4 + 2**16)

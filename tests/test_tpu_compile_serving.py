@@ -186,3 +186,43 @@ def test_hybrid_serving_step_compiles(one_chip, monkeypatch, kind, batch,
                   if op == "copy"]
         assert not copies, (shape, copies)
     assert mem.temp_size_in_bytes < 200 * 2**20, mem.temp_size_in_bytes
+
+
+def test_ling3_prefill_holds_one_scan_kernel_a_kda_layer(one_chip,
+                                                         monkeypatch):
+    """The Ling-3.0-flash cell's widest admission (8 prompts of the
+    2,048 bucket; `benchmark/configs/ling-3.0-flash.json`), bound for a
+    TPU: one `ptpu.kda_scan` call a KDA layer (five), each with the
+    final state at its interface shape in its own line, beside the
+    latent layer's one flash forward; the counter says `kernel` five
+    times and `lax` never. (The program's temporaries are what they were,
+    3.14 GiB: the experts' sorted pairs and the padded heads hold them,
+    not the scans' factors, which lived a block of chunks at a time.)"""
+    from paddle_tpu import observability as obs
+    from test_tpu_compile_cells import _cell_predictor
+
+    pred = _cell_predictor("ling3_lm", "ling-3.0-flash.json", monkeypatch)
+    step_fn, feeds, state, _ = _serving_step(pred, "prefill", 8, 2048,
+                                             one_chip)
+
+    def scans_traced():
+        got = {k["path"]: v for k, v in obs.KDA_SCAN_TRACES.samples()}
+        return got.get("kernel", 0), got.get("lax", 0)
+
+    k0, l0 = scans_traced()
+    compiled = jax.jit(step_fn, donate_argnums=(0,)).lower(
+        feeds, state).compile()
+    assert scans_traced() == (k0 + 5, l0)
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < HBM_BYTES, "does not fit one chip: %r" % (mem,)
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln
+             and "%ptpu." in ln.split(" = ")[0]]
+    scans = [ln for ln in calls if "%ptpu.kda_scan" in ln.split(" = ")[0]]
+    assert len(scans) == 5, [ln.split(" = ")[0] for ln in calls]
+    assert len(calls) == 6, [ln.split(" = ")[0] for ln in calls]  # + flash
+    for ln in scans:
+        assert "f32[8,32,128,128]" in ln.split(" custom-call(")[0], ln[:400]
+    assert mem.temp_size_in_bytes < 3.3 * 2**30, mem.temp_size_in_bytes
