@@ -81,6 +81,8 @@ from .. import layers
 from ..framework import default_main_program
 from ..initializer import ConstantInitializer, NormalInitializer
 from ..ops import diff_attn as _D
+from ..ops import kv_cache as _KV
+from ..ops import mla as _MLA
 from ..ops.kda import KDA_GATES
 from ..ops.moe import ROUTER_SCORES
 from ..param_attr import ParamAttr
@@ -178,6 +180,27 @@ def _diff_params(cfg, name):
                   for n in ("q1", "k1", "q2", "k2")),
             _param([2 * cfg.d_head], name + ".subln.w",
                    ConstantInitializer(1.0)))
+
+
+def stream_view(cfg, seq, dtype="float32"):
+    """The view (``ops/decode_stream.py``) of the op that reads
+    ``cfg``'s full-length cache of ``seq`` positions in a decode step,
+    by the layer kind that keeps it, as ``_layer`` picks the op: a
+    latent layer's ``mla_decode``; under differential attention the
+    full layer's ``diff_decode_attention`` (and the cross layers'
+    ``attn_cross``) over flat rows; else ``decode_attention`` over
+    slabs of heads (OPT's block too), under the full layers' query
+    heads, not the sliding layers'."""
+    kinds = cfg.layer_kinds()
+    if "latent" in kinds:
+        return _MLA.latent_view(seq, cfg.n_head, cfg.latent_row,
+                                cfg.kv_lora_rank, dtype)
+    heads = max((cfg.heads(i) for i, k in enumerate(kinds)
+                 if k == "attention"), default=cfg.n_head)
+    if cfg.diff_attn:
+        return _D.rows_view(seq, heads, cfg.kv_row[0], 2 * cfg.d_head,
+                            dtype)
+    return _KV.decode_view(seq, heads, cfg.n_kv_head, cfg.d_head, dtype)
 
 
 def _attention_mixer(u, cfg, name, lengths, cache, i=0, kind="attention"):

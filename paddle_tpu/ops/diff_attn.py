@@ -36,16 +36,15 @@ v5e: four 1.25 GiB copies, 2 GiB of temporaries); a flat row is a
 multiple of 128 lanes wide, the append is a row scatter in place, and a
 pair-head's columns are a lane-aligned slice that the score and value
 products read where they lie. Two paths attend such rows, chosen by
-shape, dtype and device (``rows_block_rows``), numerics one: the
-Pallas kernel ``ptpu.diff_attn_rows`` over a slab (float32 on a TPU:
-one grid cell a (slot, sequence block), the block's pair-heads picked
-by lane slices, the lengths scalar-prefetched so that dead blocks are
-neither fetched nor computed, two passes so that the products round
-what the lax path's round, as ``kv_cache._decode_attn_grouped_kernel``);
-and ``_attend_rows_lax``, exact and pure lax, one product pair a
-pair-head, which reads the whole slab whatever the lengths: every
-other device, and a ring (one block a slot and nearly all of it live:
-the kernel has no dead rows to skip there; PERF.md, PR 32).
+shape, dtype and device (``kv_cache.decode_stream_rows`` of
+``rows_view``), numerics one: the Pallas kernel ``ptpu.diff_attn_rows``
+over a slab (float32 on a TPU), which is the streamed two-pass body of
+``ops/decode_stream.py`` under this file's VIEW (``rows_view``: a (1,
+rows, P w) block of the slab itself, a pair-head's keys and values its
+lane slice); and ``_attend_rows_lax``, exact and pure lax, one product
+pair a pair-head, which reads the whole slab whatever the lengths:
+every other device, and a ring (one block a slot and nearly all of it
+live: the kernel has no dead rows to skip there; PERF.md, PR 32).
 
 Four ops, one scope each:
 
@@ -62,16 +61,14 @@ Four ops, one scope each:
 """
 from __future__ import annotations
 
-import functools
 import math
 
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from . import attention as _A
+from . import decode_stream as _DS
 from . import kv_cache as _KV
 from .registry import register_op
 from .ssm import rms_norm
@@ -135,138 +132,41 @@ def _check(q, k, v):
     return pairs, 1.0 / math.sqrt(dh)
 
 
-def rows_block_rows(s, h, row, dtype, block_s=512):
-    """Sequence rows per block of the kernel over (B, s, row) flat rows
-    of ``dtype`` under ``h`` paired query heads, or None where the lax
-    path attends them: a type that is not 32 bits wide, scores that do
-    not fit beside the blocks, no block that divides ``s``."""
-    if (jnp.dtype(dtype).itemsize != 4 or row % 128
-            or h * s * 4 > _KV._GROUPED_SCORE_BYTES):
-        return None
-    return _KV.fit_block_rows(
-        s, min(block_s, _KV._INPLACE_BLOCK_BYTES // (row * 4)))
+def rows_view(s, h, row, w, dtype, block_s=512):
+    """The view (``ops/decode_stream.py``) of (B, s, row) flat rows of
+    ``dtype`` under ``h`` paired query heads of ``w``: a (1, rows, P w)
+    block of the slab itself a step, at most ``block_s`` rows of it;
+    pair-head p's keys and values are the lanes [p w, (p + 1) w) of a
+    block (a lane-aligned slice where the row is a multiple of 128
+    lanes), and the g paired query rows that read it meet them in one
+    (g, w) x (w, BS) product."""
+    f32 = jnp.float32
 
+    def lanes(ref, p):
+        return ref[0, :, p * w:(p + 1) * w]
 
-def decode_stream_rows(s, h, row, dtype, block_s=512):
-    """Rows a block of the slab's attention brings in on the device a
-    step traced now is bound for, or None where it reads whole slabs
-    (the lax path): what ``kv_cache.decode_stream_rows`` answers for a
-    slab of heads."""
-    if not _KV._use_pallas_decode(s, row):
-        return None
-    return rows_block_rows(s, h, row, dtype, block_s)
-
-
-def _rows_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, s_ref, m_ref, l_ref,
-                 acc_ref, *, block_s, n_pair, group, width, n_blk):
-    """One (slot, step) grid cell, 2 * n_blk steps a slot, over blocks
-    (1, BS, P w) of the slab itself: pair-head p's keys and values are
-    the lanes [p w, (p + 1) w) of a block, and the ``group`` paired
-    query rows that read it (rows [p g, (p + 1) g)) meet them in one (g,
-    w) x (w, BS) product. Two passes, as
-    ``kv_cache._decode_attn_grouped_kernel`` and for its reason (the
-    NORMALISED weights are what the MXU rounds): steps [0, n_blk) stream
-    K into the scores ``s_ref`` (H, S) and the running maximum, step
-    n_blk sums the weights, steps [n_blk, 2 n_blk) stream V. K's index
-    stops at the slot's last live block and V's waits at block 0
-    meanwhile: a live block is copied once, a dead one never."""
-    j = pl.program_id(1)
-    length = len_ref[pl.program_id(0)]
-    live_blocks = (length + block_s - 1) // block_s
-
-    @pl.when(j == 0)
-    def _():
-        m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
-        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
-
-    @pl.when(j < live_blocks)
-    def _():
-        col0 = pl.multiple_of(j * block_s, block_s)
-        for p in range(n_pair):
-            hh = slice(p * group, (p + 1) * group)
-            s = jnp.dot(q_ref[0, 0, hh, :],
-                        k_ref[0, :, p * width:(p + 1) * width].T,
-                        preferred_element_type=jnp.float32)   # (g, BS)
-            live = col0 + lax.broadcasted_iota(jnp.int32, s.shape, 1) < length
-            s = jnp.where(live, s, _NEG)
-            s_ref[hh, pl.ds(col0, block_s)] = s
-            m_ref[hh, :] = jnp.maximum(m_ref[hh, :],
-                                       jnp.max(s, axis=1, keepdims=True))
-
-    @pl.when(j == n_blk)
-    def _():
-        def add(i, l):
-            s = s_ref[:, pl.ds(pl.multiple_of(i * block_s, block_s), block_s)]
-            return l + jnp.sum(jnp.exp(s - m_ref[...]), axis=1, keepdims=True)
-
-        l_ref[...] = lax.fori_loop(0, live_blocks, add,
-                                   jnp.zeros(l_ref.shape, jnp.float32))
-
-    @pl.when((j >= n_blk) & (j - n_blk < live_blocks))
-    def _():
-        col0 = pl.multiple_of((j - n_blk) * block_s, block_s)
-        for p in range(n_pair):
-            hh = slice(p * group, (p + 1) * group)
-            w = (jnp.exp(s_ref[hh, pl.ds(col0, block_s)] - m_ref[hh, :])
-                 / jnp.maximum(l_ref[hh, :], 1e-30))
-            acc_ref[hh, :] += jnp.dot(
-                w, v_ref[0, :, p * width:(p + 1) * width],
-                preferred_element_type=jnp.float32)
-
-    @pl.when(j == 2 * n_blk - 1)
-    def _():
-        o_ref[0, 0] = acc_ref[...].astype(o_ref.dtype)
+    return _DS.StreamView(
+        DIFF_ATTN_ROWS, seq=s, dtype=dtype,
+        most=_DS.rows_within(row * 4, block_s), score_rows=h,
+        whole_tiles=row % 128 == 0, lanes=row, q_block=(1, 1, h, w),
+        k_block=(1, 1, row), v_block=(1, 1, row), o_block=(1, 1, h, w),
+        groups=row // w,
+        scores=lambda p, hh, q_ref, k_ref: jnp.dot(
+            q_ref[0, 0, hh, :], lanes(k_ref, p).T,
+            preferred_element_type=f32),
+        values=lambda p, wts, v_ref: jnp.dot(
+            wts, lanes(v_ref, p), preferred_element_type=f32))
 
 
 def pallas_attend_rows(qp, k, v, lengths, scale, block_s=512,
                        interpret=False):
     """``_attend_rows_lax``'s contract through the kernel: the slab is
     handed over as it lies, a (1, rows, P w) block a step."""
-    b, _, h, w = qp.shape
-    s, row = k.shape[1], k.shape[2]
-    rows = rows_block_rows(s, h, row, k.dtype, block_s)
-    if rows is None:
-        raise ValueError(
-            "no kernel for %d paired query heads on (%d, %d) %s rows; the "
-            "lax path attends them" % (h, s, row, jnp.dtype(k.dtype).name))
-    n_blk, pairs = s // rows, row // w
+    _, _, h, w = qp.shape
+    view = rows_view(k.shape[1], h, k.shape[2], w, k.dtype, block_s)
     lens = lengths.reshape(-1).astype(jnp.int32)
-
-    def last(bi, lens_ref):
-        return jnp.maximum(lens_ref[bi] + rows - 1, rows) // rows - 1
-
-    def k_block(bi, j, lens_ref):
-        # past the slot's last live block: the same block again
-        return bi, jnp.minimum(j, last(bi, lens_ref)), 0
-
-    def v_block(bi, j, lens_ref):
-        # block 0 while K streams, then as K's
-        return bi, jnp.clip(j - n_blk, 0, last(bi, lens_ref)), 0
-
-    def qo_block(bi, j, lens_ref):
-        return bi, 0, 0, 0
-
-    kernel = functools.partial(_rows_kernel, block_s=rows, n_pair=pairs,
-                               group=h // pairs, width=w, n_blk=n_blk)
-    return _A.named_pallas_call(
-        DIFF_ATTN_ROWS, kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b, 2 * n_blk),
-            in_specs=[
-                pl.BlockSpec((1, 1, h, w), qo_block),
-                pl.BlockSpec((1, rows, row), k_block),
-                pl.BlockSpec((1, rows, row), v_block),
-            ],
-            out_specs=pl.BlockSpec((1, 1, h, w), qo_block),
-            scratch_shapes=[pltpu.VMEM((h, s), jnp.float32),
-                            pltpu.VMEM((h, 1), jnp.float32),
-                            pltpu.VMEM((h, 1), jnp.float32),
-                            pltpu.VMEM((h, w), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((b, 1, h, w), qp.dtype),
-        interpret=interpret,
-        **_A._tpu_params("parallel", "arbitrary"),
-    )(lens, qp * jnp.asarray(scale, qp.dtype), k, v)
+    return _DS.stream_attend(view, lens, qp * jnp.asarray(scale, qp.dtype),
+                             k, v, interpret)
 
 
 def _attend_rows(qp, k, v, lengths, scale, ring=False):
@@ -274,9 +174,9 @@ def _attend_rows(qp, k, v, lengths, scale, ring=False):
     ``[0, lengths)`` seen -> (B, 1, H, w): the kernel where the rows'
     shape, type and the device allow it and the rows are a slab's, the
     lax path otherwise."""
-    s, row = k.shape[1], k.shape[2]
-    if not ring and decode_stream_rows(s, qp.shape[2], row,
-                                       k.dtype) is not None:
+    _, _, h, w = qp.shape
+    if not ring and _KV.decode_stream_rows(rows_view(
+            k.shape[1], h, k.shape[2], w, k.dtype)) is not None:
         return pallas_attend_rows(qp, k, v, lengths, scale)
     return _attend_rows_lax(qp, k, v, lengths, scale)
 

@@ -20,27 +20,29 @@ Three ops:
 
 - ``decode_attention``: Q (B, 1, H, Dh) x cache K/V (B, S, Hkv, Dh)
   with Lengths (B,) -> (B, 1, H, Dh), H = g * Hkv. A Pallas TPU kernel
-  that reads the slab WHERE IT LIES: the (B, S*Hkv, Dh) view of the
-  feed (a bitcast on the chip when the heads fill whole 8-row sublane
-  tiles of a 32-bit type), one grid cell per (slot, sequence block),
-  every head of the block in one contiguous copy, a head's rows picked
-  by a strided load, the softmax over the slot's blocks in VMEM
-  scratch. The lengths are scalar-prefetched, so the block index stops
-  at a slot's last live block: dead rows are neither fetched nor
-  computed. At g = 1 (a key/value head a query head) the softmax is
-  online, K and V blocks side by side; at g > 1 (grouped queries) the
-  g query rows that share a head meet its block in one product, K's
-  blocks first and V's after, so that the weights are normalised
-  before they are rounded, as the lax path rounds them. Slabs without
+  that reads the slab WHERE IT LIES, in blocks of sequence rows with
+  every head of a block in one contiguous copy, a head's rows picked by
+  a strided load (``_head_rows``: the (B, S*Hkv, Dh) view of the slab
+  is a bitcast on the chip when the heads fill whole 8-row sublane
+  tiles of a 32-bit type). The lengths are scalar-prefetched, so the
+  block index stops at a slot's last live block: dead rows are neither
+  fetched nor computed. At g = 1 (a key/value head a query head) the
+  body is this file's: an online softmax, K and V blocks side by side,
+  one grid cell a (slot, sequence block). At g > 1 (grouped queries)
+  this file holds the VIEW (``decode_view``: the (1, rows, Hkv, Dh)
+  block of the slab itself, head i's strided rows against the g query
+  rows that share it) and ``ops/decode_stream.py`` the body, two passes
+  so that the weights are normalised before they are rounded, as the
+  lax path rounds them, and the rule for a block's rows. Slabs without
   that free view (heads not a multiple of 8; 16- and 8-bit types,
   whose packed rows Mosaic cannot load strided) run the older kernel
   at g = 1, one grid cell per (batch, head) over the (B, S, H*Dh)
   view, which costs a physical copy of the slab a call, and the exact
   lax path at g > 1 (ONE key/value head under many query heads). Shape
-  and dtype alone choose (``decode_block_rows``,
-  ``grouped_block_rows``). The exact pure-``lax`` path serves CPU/GPU
-  and non-aligned shapes; the kernels also run under
-  ``interpret=True`` so parity is testable off TPU.
+  and dtype alone choose (``decode_stream.block_positions`` of the
+  view). The exact pure-``lax`` path serves CPU/GPU and non-aligned
+  shapes; the kernels also run under ``interpret=True`` so parity is
+  testable off TPU.
 - ``cache_append``: scatter one new K or V row per sequence at its
   current length (functional update — callers thread the slab through
   the step function; XLA aliases it in place under donation).
@@ -61,6 +63,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..framework.scope import current_device
+from . import decode_stream as _DS
 from .attention import _fit_block, _tpu_params, named_pallas_call
 from .registry import register_op
 
@@ -89,7 +92,7 @@ def decode_attention_reference(q, k_cache, v_cache, lengths, scale=None):
     lengths (B,) valid rows per slot -> (B, 1, H, Dh). Exact; the CPU
     serving path, the numeric reference for the Pallas kernel, and on
     every device the path of a ring and of a grouped slab the in-place
-    kernel has no free view of (``decode_block_rows``: one key/value
+    kernel has no free view of (``decode_view``: one key/value
     head, a 16-bit type): the g query rows of a slot against their one K/V head are a
     real matmul, and the slab keeps its Hkv heads (never repeated).
 
@@ -198,181 +201,50 @@ def _decode_attn_inplace_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
                        ).astype(o_ref.dtype)
 
 
-def _decode_attn_grouped_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, s_ref,
-                                m_ref, l_ref, acc_ref, *, block_s, n_head,
-                                group, n_blk):
-    """The in-place kernel for a slab of fewer heads than the query:
-    one (slot, step) grid cell, 2 * n_blk steps a slot. k/v blocks
-    (1, BS, Hkv, D) of the slab itself, viewed (1, BS * Hkv, D) here
-    (one (8, 128) tile a row: the same memory), a head's rows picked by
-    the strided load; the g = ``group`` query rows that share key/value
-    head h (rows [h*g, (h+1)*g), as ``decode_attention_reference`` pairs
-    them) meet its block in one (g, D) x (D, BS) product.
-
-    Two passes, so that the products round what the lax path's round
-    (operands to bfloat16 at the TPU's default precision: the scaled
-    query, K, the NORMALISED weights, V) and a step's logits do not move
-    with the path (an online softmax rounds unnormalised weights: 0.0024
-    of the output's norm apart; PERF.md, PR 32). Steps [0, n_blk) stream
-    K: block j's scores go to ``s_ref`` (H, S) and the running maximum
-    to ``m_ref``. Step n_blk sums the weights into ``l_ref``. Steps
-    [n_blk, 2 n_blk) stream V: block j - n_blk's weights, normalised, are
-    multiplied against it into ``acc_ref`` (H, D), written out at the
-    last step. K's index stops at the slot's last live block and V's
-    waits at block 0 meanwhile, so each live block is copied once and
-    dead blocks are neither fetched nor computed."""
-    j = pl.program_id(1)
-    length = len_ref[pl.program_id(0)]
-    live_blocks = (length + block_s - 1) // block_s
-
-    def interleaved(ref):
-        return ref.reshape(1, block_s * n_head, ref.shape[-1])
-
-    @pl.when(j == 0)
-    def _():
-        m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
-        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
-
-    @pl.when(j < live_blocks)
-    def _():
-        k = interleaved(k_ref)
-        col0 = pl.multiple_of(j * block_s, block_s)
-        for h in range(n_head):
-            hh = slice(h * group, (h + 1) * group)
-            s = jnp.dot(q_ref[0, 0, hh, :],
-                        k[0, pl.ds(h, block_s, stride=n_head), :].T,
-                        preferred_element_type=jnp.float32)   # (g, BS)
-            live = col0 + lax.broadcasted_iota(jnp.int32, s.shape, 1) < length
-            s = jnp.where(live, s, _NEG)
-            s_ref[hh, pl.ds(col0, block_s)] = s
-            m_ref[hh, :] = jnp.maximum(m_ref[hh, :],
-                                       jnp.max(s, axis=1, keepdims=True))
-
-    @pl.when(j == n_blk)
-    def _():
-        def add(i, l):
-            s = s_ref[:, pl.ds(pl.multiple_of(i * block_s, block_s), block_s)]
-            return l + jnp.sum(jnp.exp(s - m_ref[...]), axis=1, keepdims=True)
-
-        l_ref[...] = lax.fori_loop(0, live_blocks, add,
-                                   jnp.zeros(l_ref.shape, jnp.float32))
-
-    @pl.when((j >= n_blk) & (j - n_blk < live_blocks))
-    def _():
-        v = interleaved(v_ref)
-        col0 = pl.multiple_of((j - n_blk) * block_s, block_s)
-        for h in range(n_head):
-            hh = slice(h * group, (h + 1) * group)
-            p = (jnp.exp(s_ref[hh, pl.ds(col0, block_s)] - m_ref[hh, :])
-                 / jnp.maximum(l_ref[hh, :], 1e-30))
-            acc_ref[hh, :] += jnp.dot(
-                p, v[0, pl.ds(h, block_s, stride=n_head), :],
-                preferred_element_type=jnp.float32)
-
-    @pl.when(j == 2 * n_blk - 1)
-    def _():
-        o_ref[0, 0] = acc_ref[...].astype(o_ref.dtype)
+def _head_rows(ref, i):
+    """Key/value head ``i``'s rows of a (1, BS, Hkv, D) block of the
+    slab itself, viewed (1, BS * Hkv, D) (one (8, 128) tile a row: the
+    same memory): row r of head i lies at sublane r * Hkv + i, a
+    strided load."""
+    _, rows, hkv, d = ref.shape
+    return ref.reshape(1, rows * hkv, d)[0, pl.ds(i, rows, stride=hkv), :]
 
 
-# a K or V block of the in-place kernel: 128 rows of the 32 x 128 float32
-# slab, a whole MXU tile a head (at 64 rows a call takes 0.30 ms where it
-# takes 0.24; PERF.md, PR 25), 512 rows of an 8 x 128 one (1.37 ms a call
-# over 64 slots of ~1,450 live rows, 1.34 at 256 rows and 1.38 at 128: one
-# rule serves both; PERF.md, PR 32). Both slabs' blocks, double-buffered,
-# are 8 MiB of the 16 MiB of VMEM a v5e kernel may use.
-_INPLACE_BLOCK_BYTES = 2 * 2**20
-# the grouped kernel keeps a slot's scores, (query heads, S) float32, in
-# VMEM beside the blocks: 1 MiB for 64 heads on 4096 rows
-_GROUPED_SCORE_BYTES = 4 * 2**20
+def _grouped_scores(i, hh, q_ref, k_ref):
+    """The g query rows that share key/value head i (rows ``hh``, as
+    ``decode_attention_reference`` pairs them) meet its block in one
+    (g, D) x (D, BS) product."""
+    return jnp.dot(q_ref[0, 0, hh, :], _head_rows(k_ref, i).T,
+                   preferred_element_type=jnp.float32)
 
 
-def decode_block_rows(s, h, d, dtype, block_s=512):
-    """Sequence rows per block of the in-place kernel for (B, s, h, d)
-    slabs of ``dtype``, or None where the slab has no free (B, s*h, d)
-    view and the per-head kernel runs instead. The view is a bitcast on
-    the chip when the heads fill whole sublane tiles (8 rows of 32 bits:
-    8 heads of f32). Narrower types pack two or four rows a sublane, and
-    Mosaic has no strided load of them ("Strided load with non 32-bit
-    data"), so they keep the per-head kernel."""
-    if jnp.dtype(dtype).itemsize != 4 or h % 8:
-        return None
-    return fit_block_rows(s, min(block_s, _INPLACE_BLOCK_BYTES // (h * d * 4)))
+def _grouped_values(i, p, v_ref):
+    return jnp.dot(p, _head_rows(v_ref, i),
+                   preferred_element_type=jnp.float32)
 
 
-def fit_block_rows(s, want):
-    """The largest power of two of rows, at least 8 and at most ``want``
-    (and ``s``), that divides ``s``; None where none does."""
-    want = min(want, s)
-    rows = 8
-    while rows * 2 <= want:
-        rows *= 2
-    while rows > 8 and s % rows:
-        rows //= 2
-    return None if s % rows else rows
-
-
-def grouped_block_rows(s, h, hkv, d, dtype, block_s=512):
-    """Sequence rows per block of the grouped in-place kernel for ``h``
-    query heads on (B, s, hkv, d) slabs, or None where it cannot run:
-    the slab has no free view (``decode_block_rows``: ONE key/value
-    head, a 16-bit type) or a slot's scores do not fit beside the
-    blocks."""
-    if h % hkv or h * s * 4 > _GROUPED_SCORE_BYTES:
-        return None
-    return decode_block_rows(s, hkv, d, dtype, block_s)
-
-
-def _grouped_decode_attention(qs, k_cache, v_cache, lens, block_s,
-                              interpret):
-    """``pallas_decode_attention`` over slabs of fewer heads than the
-    (pre-scaled) query: the kernel is handed the slabs themselves, a
-    (1, rows, Hkv, D) block a step, so its call's text keeps the slab's
-    shape (a trace's reader tells a decode step's attention by it)."""
-    b, one, h, d = qs.shape
-    s, hkv = k_cache.shape[1], k_cache.shape[2]
-    rows = grouped_block_rows(s, h, hkv, d, k_cache.dtype, block_s)
-    if rows is None:
-        raise ValueError(
-            "decode_attention: no in-place kernel for %d query heads on a "
-            "(%d, %d, %d) %s slab; the lax path attends it"
-            % (h, s, hkv, d, jnp.dtype(k_cache.dtype).name))
-    n_blk = s // rows
-
-    def last(bi, lens_ref):
-        return jnp.maximum(lens_ref[bi] + rows - 1, rows) // rows - 1
-
-    def k_block(bi, j, lens_ref):
-        # past the slot's last live block: the same block again
-        return bi, jnp.minimum(j, last(bi, lens_ref)), 0, 0
-
-    def v_block(bi, j, lens_ref):
-        # block 0 while K streams, then as K's
-        return bi, jnp.clip(j - n_blk, 0, last(bi, lens_ref)), 0, 0
-
-    def qo_block(bi, j, lens_ref):
-        return bi, 0, 0, 0
-
-    kernel = functools.partial(_decode_attn_grouped_kernel, block_s=rows,
-                               n_head=hkv, group=h // hkv, n_blk=n_blk)
-    return named_pallas_call(
-        DECODE_ATTN_GROUPED, kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b, 2 * n_blk),
-            in_specs=[
-                pl.BlockSpec((1, 1, h, d), qo_block),
-                pl.BlockSpec((1, rows, hkv, d), k_block),
-                pl.BlockSpec((1, rows, hkv, d), v_block),
-            ],
-            out_specs=pl.BlockSpec((1, 1, h, d), qo_block),
-            scratch_shapes=[pltpu.VMEM((h, s), jnp.float32),
-                            pltpu.VMEM((h, 1), jnp.float32),
-                            pltpu.VMEM((h, 1), jnp.float32),
-                            pltpu.VMEM((h, d), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((b, 1, h, d), qs.dtype),
-        interpret=interpret,
-        **_tpu_params("parallel", "arbitrary"),
-    )(lens, qs, k_cache, v_cache)
+def decode_view(s, h, hkv, d, dtype, block_s=512):
+    """The view (``ops/decode_stream.py``) of ``decode_attention``'s
+    in-place kernel over (B, s, hkv, d) slabs of ``dtype`` under ``h``
+    query heads: blocks of at most ``block_s`` rows of the slab where it
+    lies, which costs no copy when the heads fill whole sublane tiles (8
+    rows of 32 bits: 8 heads of f32; the (B, s*hkv, d) view is then a
+    bitcast on the chip). At h == hkv the body is this file's one-pass
+    online softmax, which keeps no scores, and the view is the rule's
+    numbers alone; at h > hkv it is the two-pass body, handed the slabs
+    themselves, a (1, rows, hkv, d) block a step, so that its call's
+    text keeps the slab's shape (a trace's reader tells a decode step's
+    attention by it)."""
+    blocks = dict(seq=s, dtype=dtype, most=_DS.rows_within(hkv * d * 4,
+                                                           block_s),
+                  whole_tiles=hkv % 8 == 0 and h % hkv == 0, lanes=d)
+    if h == hkv:
+        return _DS.StreamView(DECODE_ATTN, **blocks)
+    return _DS.StreamView(
+        DECODE_ATTN_GROUPED, score_rows=h, q_block=(1, 1, h, d),
+        k_block=(1, 1, hkv, d), v_block=(1, 1, hkv, d),
+        o_block=(1, 1, h, d), groups=hkv, scores=_grouped_scores,
+        values=_grouped_values, **blocks)
 
 
 def pallas_decode_attention(q, k_cache, v_cache, lengths, scale=None,
@@ -380,13 +252,14 @@ def pallas_decode_attention(q, k_cache, v_cache, lengths, scale=None,
     """Pallas decode attention over BTHD slabs; same contract as
     ``decode_attention_reference``. A softmax over KV blocks in VMEM, no
     (B, H, S) score tensor in HBM, in one of two shapes chosen from the
-    slab's own shape and dtype (``decode_block_rows``):
+    slab's own shape and dtype (``decode_view``):
 
     - in place: the slab viewed (B, S*H, D), grid (B, S // block_s),
       every head of a sequence block in one contiguous copy, dead
       blocks skipped. No relayout of the slab anywhere in the step. A
-      slab of fewer heads than the query (grouped queries) runs the body
-      of its own (``_decode_attn_grouped_kernel``) over the same blocks.
+      slab of fewer heads than the query (grouped queries) runs the
+      two-pass body (``decode_stream.stream_attend``) over the same
+      blocks.
     - per head: the slab viewed (B, S, H*D), grid (B, H), each cell its
       head's whole column. On the chip that view is a physical copy of
       the slab: the path of shapes the free view does not exist for.
@@ -398,15 +271,14 @@ def pallas_decode_attention(q, k_cache, v_cache, lengths, scale=None,
         scale = 1.0 / math.sqrt(d)
     qs = q * jnp.asarray(scale, q.dtype)
     lens = lengths.reshape(-1).astype(jnp.int32)
+    view = decode_view(s, h, k_cache.shape[2], d, k_cache.dtype, block_s)
     if k_cache.shape[2] != h:
-        return _grouped_decode_attention(qs, k_cache, v_cache, lens,
-                                         block_s, interpret)
-    rows = decode_block_rows(s, h, d, k_cache.dtype, block_s)
+        return _DS.stream_attend(view, lens, qs, k_cache, v_cache,
+                                 interpret)
+    rows = _DS.block_positions(view)
     if rows is not None:
         def kv_block(bi, j, lens_ref):
-            # past the slot's last live block: the same block again
-            last = jnp.maximum(lens_ref[bi] + rows - 1, rows) // rows - 1
-            return bi, jnp.minimum(j, last), 0
+            return bi, _DS.live_block(j, lens_ref, bi, rows), 0
 
         def qo_block(bi, j, lens_ref):
             return bi, 0, 0, 0
@@ -470,16 +342,15 @@ def _use_pallas_decode(s: int, d: int) -> bool:
     return d % 128 == 0 and s % 128 == 0 and s >= 128
 
 
-def decode_stream_rows(s, h, d, dtype, block_s=512, q_heads=None):
-    """Rows a block of ``decode_attention`` brings in for (B, s, h, d)
-    slabs under ``q_heads`` query heads (None: as many as the slab's) on
-    the device a step traced now is bound for, or None where it reads
-    whole slabs (the lax path; the per-head kernel)."""
-    if not _use_pallas_decode(s, d):
+def decode_stream_rows(view):
+    """Positions a block of ``view``'s kernel brings in on the device a
+    step traced now is bound for, or None where the cache is read whole
+    (the lax path; the per-head kernel): ``block_positions`` and the
+    device gate."""
+    rows = _DS.block_positions(view)
+    if rows is None or not _use_pallas_decode(view.seq, view.lanes or rows):
         return None
-    if q_heads in (None, h):
-        return decode_block_rows(s, h, d, dtype, block_s)
-    return grouped_block_rows(s, q_heads, h, d, dtype, block_s)
+    return rows
 
 
 def decode_attention(q, k_cache, v_cache, lengths, scale=None,
@@ -487,14 +358,14 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
     """Dispatch: Pallas kernel when eligible, exact lax fallback
     otherwise (numerics identical — same softmax). A slab with fewer
     key/value heads than the query has heads enters the in-place kernel
-    where it can (``grouped_block_rows``: a 32-bit type, a multiple of 8
-    heads) and stays on the lax path otherwise (ONE key/value head; a
-    16-bit slab): the slab's shape and dtype choose."""
+    where it can (``decode_view``: a 32-bit type, a multiple of 8 heads)
+    and stays on the lax path otherwise (ONE key/value head; a 16-bit
+    slab): the slab's shape and dtype choose."""
     s, d = k_cache.shape[1], q.shape[-1]
     h, hkv = q.shape[2], k_cache.shape[2]
     if h != hkv:
-        if decode_stream_rows(s, hkv, d, k_cache.dtype, block_s,
-                              q_heads=h) is not None:
+        if decode_stream_rows(decode_view(s, h, hkv, d, k_cache.dtype,
+                                          block_s)) is not None:
             return pallas_decode_attention(q, k_cache, v_cache, lengths,
                                            scale=scale, block_s=block_s)
         with jax.named_scope(DECODE_ATTN_GROUPED):

@@ -18,22 +18,21 @@ attention paths read it, and they compute the same numbers:
   head is built, and ``W^K_h``, ``W^V_h`` are views of the one stored
   ``W_kvb``. Between ``q~`` and ``o~ W^V`` one of two paths attends
   the rows, numerics one, chosen from the slab's shape and type and
-  the device (``latent_block_rows``, ``decode_stream_rows``):
+  the device (``kv_cache.decode_stream_rows`` of ``latent_view``):
 
   - the Pallas kernel ``ptpu.mla_latent_attn`` (a TPU; a float32 slab
     whose row and rank fill whole sublane tiles, whose sequence divides
     into blocks of at least 128 lanes and whose (H, S) scores fit
-    beside them): one call a layer over the slab WHERE IT LIES. The
-    TPU compiler lays a (B, S, 320) slab out with the sequence minor
-    ({1,2,0}: 320 sublane rows of S lanes a slot, no padding to 384
-    lanes) and a Mosaic call wants row-major operands, so the kernel is
-    handed the TRANSPOSED view (B, 320, S), whose row-major form is
-    those very bytes: a bitcast, where a call on (B, S, 320) would be
-    handed a padded copy of the whole slab. A block is (320, lanes) of
-    positions; the lengths are scalar-prefetched and a slot's block
-    index stops at its last live block, so dead rows are neither
-    fetched nor computed; two passes, so that the products round what
-    the lax form's round (the NORMALISED weights).
+    beside them): one call a layer of the streamed two-pass body of
+    ``ops/decode_stream.py`` under this file's VIEW (``latent_view``),
+    over the slab WHERE IT LIES. The TPU compiler lays a (B, S, 320)
+    slab out with the sequence minor ({1,2,0}: 320 sublane rows of S
+    lanes a slot, no padding to 384 lanes) and a Mosaic call wants
+    row-major operands, so the view is of the TRANSPOSED slab (B, 320,
+    S), whose row-major form is those very bytes: a bitcast, where a
+    call on (B, S, 320) would be handed a padded copy of the whole
+    slab. A block is (320, lanes) of positions, and V's the first
+    ``rank`` sublane rows of the same array.
   - the exact lax form (``_latent_attend_lax``; every other device,
     type and shape, and the kernel's reference): the slab is read as
     it lies, (B, S, rank + rope), by two products whose contraction is
@@ -55,17 +54,15 @@ lax form).
 """
 from __future__ import annotations
 
-import functools
 import math
 
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from ..observability import MLA_TRACES
 from . import attention as _A
+from . import decode_stream as _DS
 from . import kv_cache as _KV
 from . import rope as _R
 from .ssm import rms_norm as _rms
@@ -190,7 +187,7 @@ def _latent_attend_lax(q_row, slab, lens, rank):
     of a slot seen -> (B, H, rank). Both products contract the slab's
     row; the second also sums the ``rope`` columns, which are dropped
     (a slice of the slab would be a copy of it). The reference, and the
-    path of every shape and device ``latent_block_rows`` refuses."""
+    path of every shape and device ``latent_view`` has no block for."""
     s = slab.shape[1]
     scores = jnp.einsum("bhw,bsw->bhs", q_row, slab)
     live = jnp.arange(s)[None, None, :] < lens[:, None, None]
@@ -211,94 +208,28 @@ def _latent_attend_lax(q_row, slab, lens, rank):
 _LATENT_BLOCK_LANES = 1024
 
 
-def latent_block_rows(s, h, row, rank, dtype, block_s=_LATENT_BLOCK_LANES):
-    """Positions (lanes) per block of the kernel over a (B, s, row)
-    latent slab of ``dtype`` under ``h`` heads, ``rank`` of the row the
-    summed part, or None where the lax path attends it: a type that is
-    not 32 bits wide (a 16-bit slab tiles (16, 128) and is laid out
-    otherwise), a row or a rank that does not fill whole 8-row sublane
-    tiles of the transposed view, a slot's scores (h, s) that do not
-    fit beside the blocks, no block of at least 128 lanes that divides
-    ``s``."""
-    if (jnp.dtype(dtype).itemsize != 4 or row % 8 or rank % 8
-            or h * s * 4 > _KV._GROUPED_SCORE_BYTES):
-        return None
-    lanes = _KV.fit_block_rows(s, block_s)
-    return lanes if lanes is not None and lanes >= 128 else None
-
-
-def decode_stream_rows(s, h, row, rank, dtype):
-    """Rows a block of the absorbed attention brings in on the device a
-    step traced now is bound for, or None where it reads whole slabs
-    (the lax path): what ``kv_cache.decode_stream_rows`` answers for a
-    slab of heads. The kernel's minor dimension is the block of
-    positions, so that is what has to be lane-aligned."""
-    lanes = latent_block_rows(s, h, row, rank, dtype)
-    if lanes is None or not _KV._use_pallas_decode(s, lanes):
-        return None
-    return lanes
-
-
-def _latent_attn_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, s_ref, m_ref,
-                        l_ref, acc_ref, *, block_s, n_blk):
-    """One (slot, step) grid cell, 2 * n_blk steps a slot, over blocks
-    of the slab's TRANSPOSED view (B, W, S): k_ref (1, W, BS) is every
-    float of BS positions, the position on the lanes, and v_ref (1,
-    rank, BS) the ``c_kv`` sublane rows of the same positions (the
-    ``k_r`` rows take no part in the weighted sum). q_ref (1, H, W) is
-    pre-scaled; o_ref (1, H, rank).
-
-    Two passes, as ``kv_cache._decode_attn_grouped_kernel`` and for its
-    reason (the products round what the lax path's round: the scaled
-    query, the rows and the NORMALISED weights; PERF.md, PR 32): steps
-    [0, n_blk) stream the blocks into the scores ``s_ref`` (H, S) and
-    the running maximum, step n_blk sums the weights, steps [n_blk, 2
-    n_blk) stream the same positions again against the normalised
-    weights. Pass one's index stops at the slot's last live block and
-    pass two's waits at block 0 meanwhile: a live block is copied once
-    a pass, a dead one never."""
-    j = pl.program_id(1)
-    length = len_ref[pl.program_id(0)]
-    live_blocks = (length + block_s - 1) // block_s
-
-    @pl.when(j == 0)
-    def _():
-        m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
-        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
-
-    @pl.when(j < live_blocks)
-    def _():
-        col0 = pl.multiple_of(j * block_s, block_s)
-        s = jnp.dot(q_ref[0], k_ref[0],
-                    preferred_element_type=jnp.float32)       # (H, BS)
-        live = col0 + lax.broadcasted_iota(jnp.int32, s.shape, 1) < length
-        s = jnp.where(live, s, _NEG)
-        s_ref[:, pl.ds(col0, block_s)] = s
-        m_ref[...] = jnp.maximum(m_ref[...],
-                                 jnp.max(s, axis=1, keepdims=True))
-
-    @pl.when(j == n_blk)
-    def _():
-        def add(i, l):
-            s = s_ref[:, pl.ds(pl.multiple_of(i * block_s, block_s), block_s)]
-            return l + jnp.sum(jnp.exp(s - m_ref[...]), axis=1, keepdims=True)
-
-        l_ref[...] = lax.fori_loop(0, live_blocks, add,
-                                   jnp.zeros(l_ref.shape, jnp.float32))
-
-    @pl.when((j >= n_blk) & (j - n_blk < live_blocks))
-    def _():
-        col0 = pl.multiple_of((j - n_blk) * block_s, block_s)
-        p = (jnp.exp(s_ref[:, pl.ds(col0, block_s)] - m_ref[...])
-             / jnp.maximum(l_ref[...], 1e-30))
-        # (H, BS) x (rank, BS): the positions, on both operands' lanes
-        acc_ref[...] += lax.dot_general(
+def latent_view(s, h, row, rank, dtype, block_s=_LATENT_BLOCK_LANES):
+    """The view (``ops/decode_stream.py``) of a (B, s, row) latent slab
+    of ``dtype`` under ``h`` heads, ``rank`` of the row the summed part:
+    blocks of the slab's TRANSPOSED view (B, row, s), at most
+    ``block_s`` positions on the lanes (so at least 128 of them). K's
+    block (1, row, BS) is every float of BS positions and the scores
+    one (h, row) x (row, BS) product of all heads; V's block (1, rank,
+    BS) is the ``c_kv`` sublane rows of the same positions of the same
+    array (the ``k_r`` rows take no part in the weighted sum), the
+    contraction on both operands' lanes. Row and rank have to fill
+    whole 8-row sublane tiles of that view."""
+    f32 = jnp.float32
+    return _DS.StreamView(
+        MLA_LATENT_ATTN, seq=s, dtype=dtype, most=block_s, score_rows=h,
+        least=128, whole_tiles=row % 8 == 0 and rank % 8 == 0,
+        q_block=(1, h, row), k_block=(1, row, 1), v_block=(1, rank, 1),
+        o_block=(1, h, rank), seq_axis=2,
+        scores=lambda i, hh, q_ref, k_ref: jnp.dot(
+            q_ref[0], k_ref[0], preferred_element_type=f32),
+        values=lambda i, p, v_ref: lax.dot_general(
             p, v_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(j == 2 * n_blk - 1)
-    def _():
-        o_ref[0] = acc_ref[...].astype(o_ref.dtype)
+            preferred_element_type=f32))
 
 
 def pallas_latent_attend(q_row, slab, lens, rank, block_s=_LATENT_BLOCK_LANES,
@@ -310,57 +241,12 @@ def pallas_latent_attend(q_row, slab, lens, rank, block_s=_LATENT_BLOCK_LANES,
     its operands row-major: handed the (B, S, W) array it would be
     handed a padded copy of the whole slab, every layer every step. The
     transposed view (B, W, S), row-major, IS the slab's bytes, so the
-    ``swapaxes`` below is a bitcast in the compiled step (compiled for
-    a described v5e: tests/test_tpu_compile_cells.py)."""
-    b, h, w = q_row.shape
-    s = slab.shape[1]
-    lanes = latent_block_rows(s, h, w, rank, slab.dtype, block_s)
-    if lanes is None:
-        raise ValueError(
-            "no kernel for %d heads on a (%d, %d) %s latent slab of rank "
-            "%d; the lax path attends it"
-            % (h, s, w, jnp.dtype(slab.dtype).name, rank))
-    n_blk = s // lanes
-
-    def last(bi, lens_ref):
-        return jnp.maximum(lens_ref[bi] + lanes - 1, lanes) // lanes - 1
-
-    def k_block(bi, j, lens_ref):
-        # past the slot's last live block: the same block again
-        return bi, 0, jnp.minimum(j, last(bi, lens_ref))
-
-    def v_block(bi, j, lens_ref):
-        # block 0 while pass one streams, then as pass one's
-        return bi, 0, jnp.clip(j - n_blk, 0, last(bi, lens_ref))
-
-    def qo_block(bi, j, lens_ref):
-        return bi, 0, 0
-
-    # a length past the slab would index past the score scratch; the
-    # lax form reads it as "every row"
-    lens = jnp.clip(lens, 0, s)
-    slab_t = jnp.swapaxes(slab, 1, 2)
-    kernel = functools.partial(_latent_attn_kernel, block_s=lanes,
-                               n_blk=n_blk)
-    return _A.named_pallas_call(
-        MLA_LATENT_ATTN, kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b, 2 * n_blk),
-            in_specs=[
-                pl.BlockSpec((1, h, w), qo_block),
-                pl.BlockSpec((1, w, lanes), k_block),
-                pl.BlockSpec((1, rank, lanes), v_block),
-            ],
-            out_specs=pl.BlockSpec((1, h, rank), qo_block),
-            scratch_shapes=[pltpu.VMEM((h, s), jnp.float32),
-                            pltpu.VMEM((h, 1), jnp.float32),
-                            pltpu.VMEM((h, 1), jnp.float32),
-                            pltpu.VMEM((h, rank), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((b, h, rank), jnp.float32),
-        interpret=interpret,
-        **_A._tpu_params("parallel", "arbitrary"),
-    )(lens, q_row, slab_t, slab_t)
+    ``swapaxes`` ``stream_attend`` makes of it (the view's sequence is
+    index 2) is a bitcast in the compiled step (compiled for a
+    described v5e: tests/test_tpu_compile_cells.py)."""
+    _, h, w = q_row.shape
+    view = latent_view(slab.shape[1], h, w, rank, slab.dtype, block_s)
+    return _DS.stream_attend(view, lens, q_row, slab, slab, interpret)
 
 
 def mla_decode(q, slab, lengths, w_kvb, scale):
@@ -369,14 +255,14 @@ def mla_decode(q, slab, lengths, w_kvb, scale):
     v). ``q~ = q_nope W^K`` before and ``o~ W^V`` after are plain
     products; between them the attention on the latent rows runs the
     kernel over a slot's live blocks where the slab's shape, type and
-    the device allow it (``decode_stream_rows``) and the exact lax
+    the device allow it (``kv_cache.decode_stream_rows``) and the exact lax
     form, which reads every row of every slot, elsewhere. A slot of
     length 0 gives zeros."""
     b, _, h, _ = q.shape
     s, rank = slab.shape[1], w_kvb.shape[0]
     nope = q.shape[-1] - (slab.shape[-1] - rank)
-    kernel = decode_stream_rows(s, h, slab.shape[-1], rank,
-                                slab.dtype) is not None
+    kernel = _KV.decode_stream_rows(latent_view(
+        s, h, slab.shape[-1], rank, slab.dtype)) is not None
     MLA_TRACES.inc(path="absorbed_kernel" if kernel else "absorbed")
     with jax.named_scope(MLA_DECODE):
         w_k, w_v = _split_kvb(w_kvb, h, nope)

@@ -58,8 +58,7 @@ from .. import observability as obs
 from ..observability import tracing as _tracing
 from ..runtime import aot_cache as _aot
 from ..framework.scope import current_device
-from ..ops import diff_attn as _DA
-from ..ops import kv_cache as _KV, mla as _MLA
+from ..ops import kv_cache as _KV
 from ..runtime import recordio as _rio
 
 __all__ = ["DecodeConfig", "save_decode_model", "DecodePredictor",
@@ -1719,21 +1718,11 @@ class DecodeServer:
         # fewer heads than the query that the kernel has no view of)
         self._stream_rows = None
         if self.kv_dtype == "float32" and not self.speculative:
-            full = [cfg.heads(i) for i, k in enumerate(cfg.layer_kinds())
-                    if k == "attention"]
-            heads = max(full, default=cfg.n_head)
+            from ..models import jamba as _J
+
             with jax.default_device(predictor._device):  # as acquire()
-                if cfg.has_latent:  # each of the kernel's two passes
-                    self._stream_rows = _MLA.decode_stream_rows(
-                        self.seq, cfg.n_head, cfg.latent_row,
-                        cfg.kv_lora_rank, jnp.float32)
-                elif cfg.diff_attn:  # a slab of flat rows has its own path
-                    self._stream_rows = _DA.decode_stream_rows(
-                        self.seq, heads, cfg.kv_row[0], jnp.float32)
-                else:
-                    self._stream_rows = _KV.decode_stream_rows(
-                        self.seq, cfg.n_kv_head, cfg.d_head, jnp.float32,
-                        q_heads=heads)
+                self._stream_rows = _KV.decode_stream_rows(
+                    _J.stream_view(cfg, self.seq))
         # layers that attend ONE shared slab in a step: its owner and
         # the cross layers after it (0: every slab has one reader)
         kinds = cfg.layer_kinds()

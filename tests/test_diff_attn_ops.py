@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 import paddle_tpu as fluid
 from paddle_tpu import layers
+from paddle_tpu.ops import decode_stream as DS
 from paddle_tpu.ops import diff_attn as D
 from paddle_tpu.ops import kv_cache as KV
 
@@ -260,46 +261,29 @@ def test_ops_carry_their_scopes(parts):
 
 
 # -- the kernel over a slab of flat rows ---------------------------------------
-
-@pytest.mark.parametrize("block_s", [64, 128, 256])
-@pytest.mark.parametrize("lens", [(0, 100, 256), (1, 64, 65)])
-def test_rows_kernel_matches_the_lax_path(block_s, lens):
-    """`ptpu.diff_attn_rows` in interpret mode against the exact lax
-    path: 16 paired query heads of 128 on slabs of 256 flat rows of 4
-    pair-heads; a free slot (zeros), a slot that ends inside a block,
-    at a block's edge and one row past it, a full one."""
-    r = np.random.default_rng(7)
-    b, s, h, pairs, w = 3, 256, 16, 4, 128
-    qp = D.pair_queries(jnp.asarray(
-        r.normal(size=(b, 1, h, w // 2)).astype(np.float32)))
-    k = jnp.asarray(r.normal(size=(b, s, pairs * w)).astype(np.float32))
-    v = jnp.asarray(r.normal(size=(b, s, pairs * w)).astype(np.float32))
-    lens = jnp.asarray(lens, jnp.int32)
-    with jax.default_matmul_precision("highest"):
-        want = D._attend_rows_lax(qp, k, v, lens, 0.125)
-        got = D.pallas_attend_rows(qp, k, v, lens, 0.125, block_s=block_s,
-                                   interpret=True)
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
-    assert not np.asarray(got)[np.asarray(lens) == 0].any()
-
+# (its parity with the lax path: `test_decode_stream.py`, the `rows-` cases)
 
 def test_shape_and_dtype_choose_the_rows_path(monkeypatch, parts):
     """Float32 rows a multiple of 128 lanes wide whose scores fit beside
     the blocks take the kernel, in blocks of at most 2 MiB; a 16-bit
     type, a narrow row or scores past the budget take the lax path; so
     does every ring, and every device but a TPU."""
-    assert D.rows_block_rows(4096, 40, 1280, jnp.float32) == 256
-    assert D.rows_block_rows(2048, 16, 512, jnp.float32) == 512
-    assert D.rows_block_rows(512, 40, 1280, jnp.float32) == 256
-    assert D.rows_block_rows(4096, 40, 1280, jnp.bfloat16) is None
-    assert D.rows_block_rows(4096, 8, 64, jnp.float32) is None
-    assert D.rows_block_rows(32768, 40, 1280, jnp.float32) is None
-    assert D.decode_stream_rows(4096, 40, 1280, jnp.float32) is None  # CPU
+    def block(*shape):
+        return DS.block_positions(D.rows_view(*shape))
+
+    assert block(4096, 40, 1280, 128, jnp.float32) == 256
+    assert block(2048, 16, 512, 128, jnp.float32) == 512
+    assert block(512, 40, 1280, 128, jnp.float32) == 256
+    assert block(4096, 40, 1280, 128, jnp.bfloat16) is None
+    assert block(4096, 8, 64, 64, jnp.float32) is None
+    assert block(32768, 40, 1280, 128, jnp.float32) is None
+    cell = D.rows_view(4096, 40, 1280, 128, jnp.float32)
+    assert KV.decode_stream_rows(cell) is None  # CPU
     calls = []
     monkeypatch.setattr(D._KV, "_use_pallas_decode", lambda s, d: True)
     monkeypatch.setattr(D, "pallas_attend_rows",
                         lambda *a, **kw: calls.append(a) or D._attend_rows_lax(*a))
-    assert D.decode_stream_rows(4096, 40, 1280, jnp.float32) == 256
+    assert KV.decode_stream_rows(cell) == 256
     q, k, v, lams, gain = parts
     big = lambda a: jnp.tile(a, (1, 1, 8))[:, :16]          # rows of 256
     qq = jnp.tile(q[:, :1], (1, 1, 1, 8))                    # heads of 64

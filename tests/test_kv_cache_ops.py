@@ -8,6 +8,7 @@ import pytest
 
 import jax.numpy as jnp
 
+from paddle_tpu.ops import decode_stream as DS
 from paddle_tpu.ops import kv_cache as kc
 from tests.op_test import check_infer, run_op
 
@@ -122,19 +123,18 @@ _IP_LENGTHS = {
 
 @pytest.mark.parametrize("lengths", list(_IP_LENGTHS.values()),
                          ids=list(_IP_LENGTHS))
-@pytest.mark.parametrize("heads,kv_heads", [
-    (8, 8), (32, 32),
-    # grouped queries: 6, 8 and 2 query rows share a key/value head
-    (48, 8), (64, 8), (16, 8)],
-    ids=["8", "32", "48on8", "64on8", "16on8"])
+@pytest.mark.parametrize("heads,kv_heads", [(8, 8), (32, 32)],
+                         ids=["8", "32"])
 def test_pallas_decode_kernel_in_place_parity(heads, kv_heads, lengths):
-    """The kernel that reads the (B, S, Hkv, D) slab where it lies (grid
+    """The kernel that reads the (B, S, H, D) slab where it lies (grid
     over sequence blocks, every head of a block in one copy, dead blocks
-    skipped, the query rows of a group against their head's block in one
-    product), interpret mode against the lax reference."""
+    skipped), interpret mode against the lax reference. A slab of fewer
+    heads than the query runs the streamed two-pass body: its cases
+    (48, 64 and 16 heads on 8, and a ring) are
+    `test_decode_stream.py::test_view_kernel_matches_its_lax_path`'s."""
     b = len(lengths)
-    assert kc.decode_block_rows(_IP_S, kv_heads, _IP_D, np.float32,
-                                _IP_BLOCK) == _IP_BLOCK
+    assert DS.block_positions(kc.decode_view(
+        _IP_S, heads, kv_heads, _IP_D, np.float32, _IP_BLOCK)) == _IP_BLOCK
     q = jnp.asarray(_rand((b, 1, heads, _IP_D), 0))
     k = jnp.asarray(_rand((b, _IP_S, kv_heads, _IP_D), 1))
     v = jnp.asarray(_rand((b, _IP_S, kv_heads, _IP_D), 2))
@@ -165,7 +165,8 @@ def test_pallas_decode_kernel_in_place_parity(heads, kv_heads, lengths):
 def test_decode_block_rows_follows_shape_and_dtype(shape, dtype, block_s,
                                                    want):
     s, h, d = shape
-    assert kc.decode_block_rows(s, h, d, dtype, block_s) == want
+    assert DS.block_positions(
+        kc.decode_view(s, h, h, d, dtype, block_s)) == want
 
 
 # what the dispatch sees decides: (query heads, slab (s, hkv, d), dtype)
@@ -211,38 +212,10 @@ def test_decode_attention_dispatch_follows_shape_and_dtype(
         # the grouped call is handed the slab itself, the standing one
         # its (B, S*H, D) view
         assert ("f32[4,%d,%d]" % (s * hkv, d) in text) == (heads == hkv)
-    rows = kc.decode_stream_rows(s, hkv, d, dtype, q_heads=heads)
+    rows = kc.decode_stream_rows(kc.decode_view(s, heads, hkv, d, dtype))
     assert (rows is not None) == kernel
     assert "pallas_call" not in str(jax.make_jaxpr(kc.decode_attn_ring)(
         *avals))
-
-
-_RING_W = 32
-_RING_LENGTHS = {
-    "below": [0, 1, _RING_W - 1, 5],
-    "at": [_RING_W] * 4,
-    "past": [_RING_W + 1, 2 * _RING_W, 1000, _RING_W + 7],
-    "mixed": [7, _RING_W, _RING_W + 1, 0],
-}
-
-
-@pytest.mark.parametrize("lengths", list(_RING_LENGTHS.values()),
-                         ids=list(_RING_LENGTHS))
-@pytest.mark.parametrize("heads", [16, 64])
-def test_grouped_kernel_over_a_ring_matches_decode_attn_ring(heads,
-                                                             lengths):
-    """A ring is a one-block slab of ``min(lengths, W)`` live rows: the
-    grouped kernel in interpret mode against ``decode_attn_ring`` (the
-    lax path, which a ring keeps on the chip too: PERF.md, PR 32)."""
-    b = len(lengths)
-    q = jnp.asarray(_rand((b, 1, heads, _IP_D), 3))
-    kr = jnp.asarray(_rand((b, _RING_W, 8, _IP_D), 4))
-    vr = jnp.asarray(_rand((b, _RING_W, 8, _IP_D), 5))
-    lens = jnp.asarray(lengths, jnp.int32)
-    want = np.asarray(kc.decode_attn_ring(q, kr, vr, lens))
-    got = np.asarray(kc.pallas_decode_attention(
-        q, kr, vr, jnp.minimum(lens, _RING_W), interpret=True))
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=2e-6)
 
 
 def test_grouped_slab_without_the_free_view_is_refused_by_the_kernel():

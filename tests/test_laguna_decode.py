@@ -305,14 +305,15 @@ def test_server_asks_stream_rows_with_the_slabs_own_heads(pred, monkeypatch):
 
     asked = []
 
-    def rows(s, h, d, dtype, block_s=512, q_heads=None):
-        asked.append((s, h, d, q_heads))
+    def rows(view):
+        asked.append((view.seq, view.k_block, view.score_rows))
         return 16
 
     monkeypatch.setattr(D._KV, "decode_stream_rows", rows)
     srv = DecodeServer(pred, slots=SLOTS, max_seq=SEQ, max_new_tokens=4)
     # the two full layers' 4 query heads, not the sliding layers' 6
-    assert asked == [(SEQ, CFG["num_key_value_heads"], CFG["head_dim"], 4)]
+    assert asked == [(SEQ, (1, 1, CFG["num_key_value_heads"],
+                            CFG["head_dim"]), 4)]
     counts = srv._step_counts(np.array([3, 0, 30, 0], np.int32), 2)
     assert counts["streamed"] == 16 * (1 + 1 + 2 + 1) < SLOTS * SEQ
     assert counts["attended"] == 35

@@ -17,6 +17,8 @@ import jax.numpy as jnp
 import paddle_tpu as fluid
 from paddle_tpu import layers
 from paddle_tpu import observability as obs
+from paddle_tpu.ops import decode_stream as DS
+from paddle_tpu.ops import kv_cache as KV
 from paddle_tpu.ops import mla, rope
 
 B, T, D, H = 2, 12, 32, 4
@@ -286,91 +288,9 @@ def test_absorbed_equals_expanded_at_unlike_widths():
                                    atol=2e-6)
 
 
-def test_latent_kernel_at_a_row_of_576_and_a_rank_of_512():
-    """The absorbed kernel at the Ling cell's row (512 + 64), 32 heads,
-    interpret mode, against the lax form; and the rule takes that shape
-    at the cell's slab."""
-    assert mla.latent_block_rows(16384, 32, 576, 512, "float32") \
-        == mla._LATENT_BLOCK_LANES
-    r = np.random.default_rng(6)
-    lens = jnp.asarray([0, 1, 129, 256], jnp.int32)
-    slab = r.normal(size=(4, 256, 576)).astype(np.float32)
-    for i, n in enumerate(np.asarray(lens)):
-        slab[i, n:] = 1e6
-    q_row = jnp.asarray(r.normal(size=(4, 32, 576)) * 0.05, jnp.float32)
-    want = mla._latent_attend_lax(q_row, jnp.asarray(slab), lens, 512)
-    got = mla.pallas_latent_attend(q_row, jnp.asarray(slab), lens, 512,
-                                   block_s=128, interpret=True)
-    assert got.shape == (4, 32, 512) and np.isfinite(np.asarray(got)).all()
-    err = float(jnp.linalg.norm(got - want))
-    assert err <= 1e-5 * float(jnp.linalg.norm(want)), err
-
-
 # -- the absorbed attention's kernel (`ptpu.mla_latent_attn`) ------------------
-
-KS, KH, KRANK, KROPE = 512, 8, 32, 8
-_UNEQUAL = [int(n) for n in np.random.default_rng(11).integers(0, KS + 1, 32)]
-_KERNEL_CASES = [
-    # id, live rows a slot (`{b}` stands for the block)
-    ("empty", lambda b: [0, 0]),
-    ("one-row", lambda b: [1, 0]),
-    ("edge-1", lambda b: [b - 1, 1]),
-    ("edge", lambda b: [b, 2 * b]),
-    ("edge+1", lambda b: [b + 1, KS - 1]),
-    ("full-slab", lambda b: [KS, KS]),
-    ("past-the-slab", lambda b: [KS + 5, KS]),  # read as every row
-    ("unequal-32", lambda b: _UNEQUAL),
-]
-
-
-@pytest.mark.parametrize("block", [128, 256])
-@pytest.mark.parametrize("lengths", [c[1] for c in _KERNEL_CASES],
-                         ids=[c[0] for c in _KERNEL_CASES])
-def test_latent_kernel_matches_the_lax_path(monkeypatch, lengths, block):
-    """`mla_decode` through the kernel (interpret mode, steered past the
-    device rule) against `mla_decode` by the lax path, which the CPU
-    takes: to 1e-5 of the output's norm (what two passes gave the
-    grouped kernel; PERF.md, PR 32), zeros and finite at length 0, no
-    row past a slot's length read (garbage there), and the `k_r`
-    columns (large here) not summed into the output. The counter names
-    each path."""
-    lens = np.asarray(lengths(block), np.int32)
-    b = len(lens)
-    r = np.random.default_rng(5)
-    slab = r.normal(size=(b, KS, KRANK + KROPE)).astype(np.float32)
-    slab[..., KRANK:] *= 10.0
-    for i, n in enumerate(lens):
-        slab[i, n:] = 1e6
-    q = jnp.asarray(r.normal(size=(b, 1, KH, DN + KROPE)) * 0.3, jnp.float32)
-    w_kvb = jnp.asarray(r.normal(size=(KRANK, KH * (DN + DV))) * 0.3,
-                        jnp.float32)
-    slab, lens = jnp.asarray(slab), jnp.asarray(lens)
-
-    def counts():
-        return {k["path"]: v for k, v in obs.MLA_TRACES.samples()}
-
-    c0 = counts()
-    want = mla.mla_decode(q, slab, lens, w_kvb, 0.37)
-    c1 = counts()
-    assert c1["absorbed"] - c0.get("absorbed", 0) == 1
-    assert c1.get("absorbed_kernel", 0) == c0.get("absorbed_kernel", 0)
-    monkeypatch.setattr(mla, "decode_stream_rows", lambda *a: block)
-    kernel = mla.pallas_latent_attend
-    monkeypatch.setattr(
-        mla, "pallas_latent_attend",
-        lambda *a: kernel(*a, block_s=block, interpret=True))
-    got = mla.mla_decode(q, slab, lens, w_kvb, 0.37)
-    c2 = counts()
-    assert c2["absorbed_kernel"] - c1.get("absorbed_kernel", 0) == 1
-    assert c2["absorbed"] == c1["absorbed"]
-    assert got.shape == want.shape == (b, 1, KH, DV)
-    assert np.all(np.isfinite(got))
-    dead = np.asarray(lens) == 0
-    np.testing.assert_array_equal(np.asarray(got)[dead], 0.0)
-    np.testing.assert_array_equal(np.asarray(want)[dead], 0.0)
-    err = float(jnp.linalg.norm(got - want))
-    assert err <= 1e-5 * max(float(jnp.linalg.norm(want)), 1e-30), err
-
+# (its parity with the lax form, through `mla_decode` and at the Ling
+# cell's row of 576: `test_decode_stream.py`, the `latent-` cases)
 
 _RULE_CASES = [
     # id, (s, h, row, rank, dtype), lanes a block or None
@@ -388,18 +308,17 @@ _RULE_CASES = [
                          ids=[c[0] for c in _RULE_CASES])
 def test_latent_kernel_rule_is_the_slabs_shape_type_and_device(
         monkeypatch, shape, lanes):
-    """`latent_block_rows` answers from shape and type alone;
-    `decode_stream_rows` adds the device: the CPU takes the lax path at
+    """`block_positions` of `latent_view` answers from shape and type
+    alone; `decode_stream_rows` adds the device: the CPU takes the lax path at
     every shape, a TPU (stood in for by the decode kernels' own device
     rule, lifted) the kernel wherever the shape allows one."""
-    from paddle_tpu.ops import kv_cache as KV
-
-    assert mla.latent_block_rows(*shape) == lanes
-    assert mla.decode_stream_rows(*shape) is None  # the CPU
+    view = mla.latent_view(*shape)
+    assert DS.block_positions(view) == lanes
+    assert KV.decode_stream_rows(view) is None  # the CPU
     monkeypatch.setattr(
         KV, "_use_pallas_decode",
         lambda s, d: d % 128 == 0 and s % 128 == 0 and s >= 128)
-    assert mla.decode_stream_rows(*shape) == lanes
+    assert KV.decode_stream_rows(view) == lanes
     if lanes is None:
         with pytest.raises(ValueError, match="the lax path attends it"):
             mla.pallas_latent_attend(

@@ -155,11 +155,12 @@ def test_latent_attention_kernel_compiles(one_chip, b, s, h):
     and hands the kernel a BITCAST of it: no copy, no transpose, no
     temporaries outside the call."""
     from paddle_tpu.ops import mla
+    from paddle_tpu.ops.decode_stream import block_positions
 
     row, rank = 320, 256
     slab = (b, s, row)
-    assert mla.latent_block_rows(s, h, row, rank, jnp.float32) == min(
-        s, mla._LATENT_BLOCK_LANES)
+    assert block_positions(mla.latent_view(
+        s, h, row, rank, jnp.float32)) == min(s, mla._LATENT_BLOCK_LANES)
     compiled = _compiled(
         lambda q, c, n: mla.pallas_latent_attend(q, c, n, rank),
         jax.ShapeDtypeStruct((b, h, row), jnp.float32, sharding=one_chip),

@@ -559,8 +559,8 @@ def test_step_counts_streamed_rows(monkeypatch, rows, lens, n_active, want):
     if isinstance(rows, dict):  # what the server asks of a grouped slab
         monkeypatch.setattr(KV, "current_device",
                             lambda: types.SimpleNamespace(platform="tpu"))
-        rows = KV.decode_stream_rows(256, rows["kv_heads"], 128, "float32",
-                                     q_heads=48)
+        rows = KV.decode_stream_rows(KV.decode_view(
+            256, 48, rows["kv_heads"], 128, "float32"))
     srv = DecodeServer.__new__(DecodeServer)
     srv.slots, srv.seq, srv._stream_rows = 4, 256, rows
     srv._state_bytes_per_slot = 0  # K/V rows alone: no fixed-size state
