@@ -145,6 +145,9 @@ __all__ = [
     "kda_gate",
     "kda_scan",
     "kda_step",
+    "latent_prefill",
+    "dsa_index_keys",
+    "dsa_mask",
 ]
 
 from .ops import elementwise_add  # re-export for parity
@@ -2813,9 +2816,10 @@ def mla_q(x, w_a, gain, w_b, n_head, rope_dim, rot, positions=None,
 
 
 def mla_kv(x, w_a, gain, rope_dim, rot, positions=None, epsilon=1e-6,
-           name=None):
+           rescale=1.0, name=None):
     """The latent row a position keeps: x (B, T, D) -> (B, T, rank +
-    rope) = ``[rms(c_kv) ; rope(k_r)]`` of ``[c_kv ; k_r] = x w_a``."""
+    rope) = ``[rescale rms(c_kv) ; rope(k_r)]`` of ``[c_kv ; k_r] = x
+    w_a``."""
     helper = LayerHelper("mla_kv", name=name)
     out = helper.create_variable_for_type_inference(
         x.dtype, shape=tuple(x.shape[:2]) + (int(w_a.shape[1]),))
@@ -2824,6 +2828,8 @@ def mla_kv(x, w_a, gain, rope_dim, rot, positions=None, epsilon=1e-6,
         inputs["Positions"] = [positions]
     attrs = _mla_rot(rot)
     attrs.update(rope_dim=int(rope_dim), epsilon=float(epsilon))
+    if float(rescale) != 1.0:
+        attrs["rescale"] = float(rescale)
     helper.append_op(type="mla_kv", inputs=inputs, outputs={"Out": [out]},
                      attrs=attrs)
     return out
@@ -2911,33 +2917,118 @@ def kda_step(q, k, v, g, beta, state, name=None):
     return out, new
 
 
-def mla_decode(q, slab, lengths, w_b, scale, name=None):
+def mla_decode(q, slab, lengths, w_b, scale, chosen=None, scope=None,
+               name=None):
     """The absorbed path: q (B, 1, H, nope + rope) attends the latent
     slab (B, S, rank + rope) up to ``lengths`` (B,) rows -> (B, 1, H,
-    v); no key or value of any head is built."""
+    v); no key or value of any head is built. ``chosen`` (B, S) bool:
+    of a slot's live rows only those (``dsa_mask``); ``scope``: the
+    named scope where it is not ``ptpu.mla_decode``."""
     helper = LayerHelper("mla_decode", name=name)
     b, _, h, dq = q.shape
     nope = int(dq) - (int(slab.shape[-1]) - int(w_b.shape[0]))
     out = helper.create_variable_for_type_inference(
         q.dtype, shape=(b, 1, h, int(w_b.shape[1]) // int(h) - nope))
-    helper.append_op(
-        type="mla_decode",
-        inputs={"Q": [q], "Cache": [slab], "Lengths": [lengths],
-                "WB": [w_b]},
-        outputs={"Out": [out]}, attrs={"scale": float(scale)})
+    inputs = {"Q": [q], "Cache": [slab], "Lengths": [lengths], "WB": [w_b]}
+    attrs = {"scale": float(scale)}
+    if chosen is not None:
+        inputs["Chosen"] = [chosen]
+    if scope:
+        attrs["scope"] = str(scope)
+    helper.append_op(type="mla_decode", inputs=inputs,
+                     outputs={"Out": [out]}, attrs=attrs)
     return out
 
 
-def mla_append(slab, row, pos, name=None):
+def latent_prefill(c_q, rows, w_qb, w_kvb, w_o, n_head, nope_dim, scale,
+                   rot, gate=None, window=0, mask=None, lengths=None,
+                   scope=None, name=None):
+    """A prefill's expanded attention of a latent layer of many heads,
+    a group of heads at a time, from the query latent c_q (B, T, q_rank)
+    and the latent rows (B, T, rank + rope) to the output projection
+    (B, T, D) (``ops/mla.py: latent_prefill``): causal, over the last
+    ``window`` keys where set, under ``mask`` (B, T, T) int8 where
+    given, each head times ``gate`` (B, T, H) where given; rows past
+    ``lengths`` (B,) are no one's to read and may come out as zeros."""
+    helper = LayerHelper("latent_prefill", name=name)
+    out = helper.create_variable_for_type_inference(
+        c_q.dtype, shape=tuple(c_q.shape[:2]) + (int(w_o.shape[1]),))
+    inputs = {"CQ": [c_q], "Rows": [rows], "WQB": [w_qb], "WKVB": [w_kvb],
+              "WO": [w_o]}
+    if gate is not None:
+        inputs["Gate"] = [gate]
+    if mask is not None:
+        inputs["Mask"] = [mask]
+    if lengths is not None:
+        inputs["Lengths"] = [lengths]
+    attrs = _mla_rot(rot)
+    attrs.update(n_head=int(n_head), nope_dim=int(nope_dim),
+                 scale=float(scale), window=int(window or 0))
+    if scope:
+        attrs["scope"] = str(scope)
+    helper.append_op(type="latent_prefill", inputs=inputs,
+                     outputs={"Out": [out]}, attrs=attrs)
+    return out
+
+
+def dsa_index_keys(x, w, gain, bias, rot, positions=None, epsilon=1e-5,
+                   name=None):
+    """The index key a position keeps under a learned indexer: x (B, T,
+    D) -> (B, T, d) = LayerNorm(x w), its first ``rot["rotary_dim"]``
+    channels rotated (``ops/dsa.py: index_keys``)."""
+    helper = LayerHelper("dsa_index_keys", name=name)
+    out = helper.create_variable_for_type_inference(
+        x.dtype, shape=tuple(x.shape[:2]) + (int(w.shape[1]),))
+    inputs = {"X": [x], "W": [w], "Gain": [gain], "Bias": [bias]}
+    if positions is not None:
+        inputs["Positions"] = [positions]
+    helper.append_op(
+        type="dsa_index_keys", inputs=inputs, outputs={"Out": [out]},
+        attrs={"epsilon": float(epsilon), "theta": float(rot["theta"]),
+               "rotary_dim": int(rot["rotary_dim"])})
+    return out
+
+
+def dsa_mask(c_q, x, w_q, w_w, keys, n_heads, topk, rot, positions=None,
+             lengths=None, name=None):
+    """The indexer's choice (``ops/dsa.py``): from the query latent c_q
+    (B, T, q_rank), the layer's input x and the index keys, a prefill's
+    (B, T, T) int8 mask (``keys`` (B, T, d); with ``lengths`` (B,) the
+    prompts' live tokens, the query rows past them are left unchosen),
+    or with ``positions`` and ``lengths`` (B,) a step's (B, S) bool over
+    the slab of keys (B, S, d): 1 where the query attends the
+    position."""
+    helper = LayerHelper("dsa_mask", name=name)
+    b, t = c_q.shape[:2]
+    inputs = {"CQ": [c_q], "X": [x], "WQ": [w_q], "WW": [w_w],
+              "Keys": [keys]}
+    if lengths is not None:
+        inputs["Lengths"] = [lengths]
+    if positions is None:
+        out = helper.create_variable_for_type_inference(
+            "int8", shape=(b, t, t))
+    else:
+        out = helper.create_variable_for_type_inference(
+            "bool", shape=(b, int(keys.shape[1])))
+        inputs["Positions"] = [positions]
+    helper.append_op(
+        type="dsa_mask", inputs=inputs, outputs={"Out": [out]},
+        attrs={"n_heads": int(n_heads), "topk": int(topk),
+               "theta": float(rot["theta"]),
+               "rotary_dim": int(rot["rotary_dim"])})
+    return out
+
+
+def mla_append(slab, row, pos, ring=False, name=None):
     """One latent row a slot: ``row`` (B, 1, W) at row ``pos[b]`` of
-    ``slab`` (B, S, W)."""
+    ``slab`` (B, S, W), at ``pos[b] mod S`` of a ``ring``."""
     helper = LayerHelper("mla_append", name=name)
     out = helper.create_variable_for_type_inference(
         slab.dtype, shape=slab.shape)
     helper.append_op(
         type="mla_append",
         inputs={"Cache": [slab], "New": [row], "Pos": [pos]},
-        outputs={"Out": [out]}, attrs={})
+        outputs={"Out": [out]}, attrs={"ring": True} if ring else {})
     return out
 
 

@@ -1,5 +1,6 @@
 """Decoder LMs of a DESCRIBED block: a mixer kind (``mamba`` |
-``attention`` | ``sliding`` | ``gmu`` | ``cross`` | ``latent`` | ``kda``) times a feed-forward
+``attention`` | ``sliding`` | ``gmu`` | ``cross`` | ``latent`` | ``kda`` |
+``latent_dsa`` | ``latent_ring``) times a feed-forward
 kind (``dense`` | ``experts``) a layer, one normalization (RMS, or
 LayerNorm with bias) before every mixer and feed-forward and after the
 last layer, no biases but the convolution's and ``dt_proj``'s and,
@@ -40,7 +41,16 @@ where asked, the attention projections'.
   query has no bottleneck and whose query/key head (192) is wider than
   its value head (128), a sigmoid gate a head on both mixers' output,
   two leading dense MLPs and then routed experts under a sigmoid
-  router with a selection bias and group-limited choice.
+  router with a selection bias and group-limited choice;
+- dots-studio's dots3-note-prev (`model_type: dots3_note`): full layers
+  of latent attention UNDER A LEARNED INDEXER (``latent_dsa``,
+  ``ops/dsa.py``: a query attends the ``index_topk`` earlier positions
+  the indexer scores highest), three sliding layers to one of latent
+  attention OF ANOTHER GEOMETRY over a window (``latent_ring``), both
+  with rescaled latents and a gate a head, of so many heads that a
+  prefill expands a group of them at a time (``ops/mla.py:
+  latent_prefill``); a leading dense MLP, then routed experts under a
+  sigmoid router with a selection bias.
 
 The serving graphs only (serving/decode.py): ``hybrid_lm_prefill``
 walks padded prompts and returns every layer's cache entries AT EACH
@@ -71,7 +81,10 @@ latent normalised and the shared key row rotated, neither K nor V. A
 KDA layer keeps ``convq_i``, ``convk_i``, ``convv_i`` (B, K - 1, H *
 dk), the three convolutions' windows, and ``kda_i`` (B, H, dk, dv), the
 delta rule's state: fixed size, replaced whole by an admission and
-rewritten whole by every step.
+rewritten whole by every step. A latent layer under an indexer keeps its
+``latent_i`` and ``index_i`` (B, S, index_head_dim), a position's index
+key; a latent layer over a window ONE ``lring_i`` (B, window, its own
+kv_lora_rank + qk_rope_dim): position p at row p mod window.
 """
 from __future__ import annotations
 
@@ -83,6 +96,7 @@ from ..initializer import ConstantInitializer, NormalInitializer
 from ..ops import diff_attn as _D
 from ..ops import kv_cache as _KV
 from ..ops import mla as _MLA
+from ..ops.dsa import DSA_ATTEND
 from ..ops.kda import KDA_GATES
 from ..ops.moe import ROUTER_SCORES
 from ..param_attr import ParamAttr
@@ -100,6 +114,10 @@ def cache_names(kind: str, i: int):
         return ["kring_%d" % i, "vring_%d" % i]
     if kind == "latent":
         return ["latent_%d" % i]
+    if kind == "latent_dsa":
+        return ["index_%d" % i, "latent_%d" % i]
+    if kind == "latent_ring":
+        return ["lring_%d" % i]
     if kind == "kda":
         return ["convq_%d" % i, "convk_%d" % i, "convv_%d" % i,
                 "kda_%d" % i]
@@ -192,9 +210,12 @@ def stream_view(cfg, seq, dtype="float32"):
     slabs of heads (OPT's block too), under the full layers' query
     heads, not the sliding layers'."""
     kinds = cfg.layer_kinds()
-    if "latent" in kinds:
-        return _MLA.latent_view(seq, cfg.n_head, cfg.latent_row,
-                                cfg.kv_lora_rank, dtype)
+    if {"latent", "latent_dsa"} & set(kinds):
+        # under an indexer the one-pass kernel over the chosen rows
+        view = (_MLA.chosen_view if "latent_dsa" in kinds
+                else _MLA.latent_view)
+        return view(seq, cfg.n_head, cfg.latent_row, cfg.kv_lora_rank,
+                    dtype)
     heads = max((cfg.heads(i) for i, k in enumerate(kinds)
                  if k == "attention"), default=cfg.n_head)
     if cfg.diff_attn:
@@ -319,6 +340,88 @@ def _latent_mixer(u, cfg, name, lengths, cache):
     ctx = _head_gate(ctx, u, cfg, name, h)
     out = _proj(layers.reshape(ctx, shape=[B, T, h * vdim]), d, name + ".o")
     return out, (rows,)
+
+
+def _wide_latent_mixer(u, cfg, name, lengths, cache, kind):
+    """A latent layer of MANY heads: under a learned indexer
+    (``latent_dsa``, ``ops/dsa.py``: a query attends the ``index_topk``
+    earlier positions the indexer scores highest) or of a geometry of
+    its own over the last ``window`` positions (``latent_ring``). The
+    normalised latents are rescaled where ``cfg.latent_rescale``.
+    ``cache`` is None (prefill: the expanded attention a group of heads
+    at a time, ``ops/mla.py: latent_prefill``, under the indexer's (B,
+    T, T) choice or over the window; the entries are the prompt's index
+    keys and latent rows, or its last ``window`` rows packed into a
+    ring) or the layer's entries (one token: append, then the absorbed
+    path over the chosen rows of the slab, or over the ring). Returns
+    (out, entries in ``cache_names`` order)."""
+    B, T, _ = u.shape
+    geo, d, eps = cfg.latent_geometry(kind), cfg.d_model, cfg.norm_eps
+    h, nope, rdim, vdim = geo.n_head, geo.nope, geo.rope, geo.v
+    ring = kind == "latent_ring"
+    w = NormalInitializer(0.0, 0.02)
+    one = ConstantInitializer(1.0)
+    rot = (cfg.rope or {}).get("latent_ring" if ring else "latent") or {}
+    at = None if cache is None else lengths
+    c_q = _rms(_proj(u, geo.q_rank, name + ".q_a"), name + ".q_norm", eps)
+    if geo.rho_q != 1.0:
+        c_q = layers.scale(c_q, scale=geo.rho_q)
+    w_qb = _param([geo.q_rank, h * (nope + rdim)], name + ".q_b.w", w)
+    rows = layers.mla_kv(
+        u, _param([d, geo.row], name + ".kv_a.w", w),
+        _param([geo.rank], name + ".kv_norm.w", one),
+        rdim, rot, positions=at, epsilon=eps, rescale=geo.rho_kv)
+    w_kvb = _param([geo.rank, h * (nope + vdim)], name + ".kv_b.w", w)
+    w_o = _param([h * vdim, d], name + ".o.w", w)
+    gate = (layers.sigmoid(_proj(u, h, name + ".gate"))
+            if cfg.attn_gate == "per_head" else None)
+    if not ring:
+        j, di = int(cfg.index_heads), int(cfg.index_head_dim)
+        irot = cfg.rope["index"]
+        keys = layers.dsa_index_keys(
+            u, _param([d, di], name + ".index.k.w", w),
+            _param([di], name + ".index.k_norm.w", one),
+            _param([di], name + ".index.k_norm.b", ConstantInitializer(0.0),
+                   is_bias=True),
+            irot, positions=at, epsilon=eps)
+        index = (_param([geo.q_rank, j * di], name + ".index.q.w", w),
+                 _param([d, j], name + ".index.weights.w", w))
+    scope = _MLA.LATENT_RING_ATTEND if ring else DSA_ATTEND
+    if cache is None:
+        mask = None if ring else layers.dsa_mask(
+            c_q, u, index[0], index[1], keys, j, cfg.index_topk, irot,
+            lengths=lengths)
+        out = layers.latent_prefill(
+            c_q, rows, w_qb, w_kvb, w_o, h, nope, geo.scale, rot, gate=gate,
+            window=cfg.window if ring else 0, mask=mask, lengths=lengths,
+            scope=scope)
+        if ring:
+            return out, (layers.ring_pack(rows, lengths, cfg.window),)
+        return out, (keys, rows)
+    q = layers.mla_q(c_q, None, None, w_qb, h, rdim, rot, positions=at,
+                     epsilon=eps)
+    kv_lengths = layers.elementwise_add(
+        layers.cast(lengths, "int32"),
+        layers.fill_constant(shape=[B], dtype="int32", value=1))
+    if ring:
+        # a ring's live rows are min(positions held, window): a length
+        # past its rows reads as "every row"
+        entries = (layers.mla_append(cache[0], rows, lengths, ring=True),)
+        ctx = layers.mla_decode(q, entries[0], kv_lengths, w_kvb, geo.scale,
+                                scope=scope)
+    else:
+        entries = (layers.mla_append(cache[0], keys, lengths),
+                   layers.mla_append(cache[1], rows, lengths))
+        chosen = layers.dsa_mask(
+            c_q, u, index[0], index[1], entries[0], j, cfg.index_topk, irot,
+            positions=lengths, lengths=kv_lengths)
+        ctx = layers.mla_decode(q, entries[1], kv_lengths, w_kvb, geo.scale,
+                                chosen=chosen, scope=scope)
+    if gate is not None:
+        ctx = layers.elementwise_mul(
+            ctx, layers.reshape(gate, shape=[B, T, h, 1]))
+    return layers.matmul(layers.reshape(ctx, shape=[B, T, h * vdim]),
+                         w_o), entries
 
 
 def _head_gate(ctx, u, cfg, name, h):
@@ -468,6 +571,9 @@ def _layer(x, kind, i, cfg, lengths, cache=None, loads=None, shared=None):
     elif kind == "latent":
         mixed, entries = _latent_mixer(u, cfg, name + ".attention",
                                        lengths, cache)
+    elif kind in ("latent_dsa", "latent_ring"):
+        mixed, entries = _wide_latent_mixer(u, cfg, name + ".attention",
+                                            lengths, cache, kind)
     elif kind == "kda":
         mixed, entries = _kda_mixer(u, cfg, name + ".kda", lengths, cache)
     else:
@@ -519,17 +625,25 @@ def _check(cfg):
             % (" or ".join(ROUTER_SCORES), cfg.router_score,
                cfg.d_shared_expert))
     for kind, rot in (cfg.rope or {}).items():
-        if kind == "latent":
+        if kind == "index":
+            # an indexer's queries and keys: their first channels, plain
+            if set(rot) != {"theta", "rotary_dim"} or not (
+                    0 < int(rot["rotary_dim"]) <= int(cfg.index_head_dim)):
+                raise ValueError(
+                    "rope['index'] = %r: an indexer's rotation is theta "
+                    "and a rotary_dim within index_head_dim" % (rot,))
+            continue
+        if kind in ("latent", "latent_ring"):
             # the whole rope part of a latent head turns; only YaRN
             # carries the original context the query scale counts in
             unknown = set(rot) - {"theta", "yarn", "attention_factor",
                                   "interleave", "scale_beta"}
             if unknown or (rot.get("scale_beta") and not rot.get("yarn")):
                 raise ValueError(
-                    "rope['latent'] = %r: a latent layer's rotation is "
+                    "rope[%r] = %r: a latent layer's rotation is "
                     "theta, yarn, attention_factor, interleave and "
                     "scale_beta (with yarn's original_max_position)"
-                    % (rot,))
+                    % (kind, rot))
             continue
         if kind not in ("full", "sliding") or rot.get(
                 "rotary_dim", cfg.d_head) > cfg.d_head:
@@ -539,6 +653,10 @@ def _check(cfg):
             cfg.diff_attn or cfg.attn_biases):
         raise ValueError("a latent or a KDA layer is built without "
                          "differential attention and without biases")
+    if "latent_dsa" in cfg.layer_kinds() and "index" not in (cfg.rope
+                                                             or {}):
+        raise ValueError("a latent layer under an indexer needs "
+                         "rope['index'] = {theta, rotary_dim}")
     if "kda" in cfg.layer_kinds() and cfg.kda_gate not in KDA_GATES:
         raise ValueError("kda_gate %r: a KDA layer's decay gate is %s"
                          % (cfg.kda_gate, " or ".join(KDA_GATES)))

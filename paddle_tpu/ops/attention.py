@@ -214,12 +214,40 @@ def _window_mask(s, row0, col0, window):
 
 
 def _mha_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
-                    block_k, seq_k, causal, pid_axis=1, window=0):
+                    block_k, seq_k, causal, pid_axis=1, window=0,
+                    mask_ref=None, len_ref=None):
     """``window`` > 0 (causal only): a query at ``t`` sees the keys
     ``(t - window, t]``; KV blocks wholly before a q-block's window are
     SKIPPED (the loop starts at the first block that holds a visible
-    key), as blocks above the diagonal are."""
+    key), as blocks above the diagonal are. ``mask_ref`` (1, seq_k /
+    block_k, block_q, block_k) int8: a mask that differs by (query,
+    key), this q-block's rows against every key block; a key whose
+    entry is 0 gets _NEG (``_mha_fwd_masked_kernel``). ``len_ref`` (B,)
+    int32, scalar-prefetched (``_mha_fwd_lens_kernel``; the BTHD grid,
+    whose first index is the batch row): a q-block wholly past its
+    row's length computes nothing and writes zeros. V's width may be
+    another than q's and K's; the output has V's."""
     qi = pl.program_id(pid_axis)
+    if len_ref is not None:
+        live = qi * block_q < len_ref[pl.program_id(0)]
+
+        @pl.when(jnp.logical_not(live))
+        def _():
+            o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+            lse_ref[0, 0, pl.ds(qi * block_q, block_q)] = jnp.zeros(
+                (block_q,), jnp.float32)
+
+        pl.when(live)(functools.partial(
+            _mha_fwd_block, q_ref, k_ref, v_ref, o_ref, lse_ref, mask_ref,
+            qi, block_q, block_k, seq_k, causal, window))
+        return
+    _mha_fwd_block(q_ref, k_ref, v_ref, o_ref, lse_ref, mask_ref, qi,
+                   block_q, block_k, seq_k, causal, window)
+
+
+def _mha_fwd_block(q_ref, k_ref, v_ref, o_ref, lse_ref, mask_ref, qi,
+                   block_q, block_k, seq_k, causal, window):
+    """``_mha_fwd_kernel``'s work on q-block ``qi``."""
     # keep matmul operands in the input dtype (bf16 under mixed precision:
     # the MXU runs bf16 x bf16 -> f32 at full rate; converting to f32 first
     # would halve MXU throughput AND double VMEM traffic); only the softmax
@@ -236,6 +264,8 @@ def _mha_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
             s = _causal_mask(s, qi * block_q, j * block_k)
         if window:
             s = _window_mask(s, qi * block_q, j * block_k, window)
+        if mask_ref is not None:
+            s = jnp.where(mask_ref[0, j].astype(jnp.int32) != 0, s, _NEG)
         m_new = jnp.maximum(m, jnp.max(s, axis=1))
         p = jnp.exp(s - m_new[:, None])
         corr = jnp.exp(m - m_new)
@@ -244,8 +274,7 @@ def _mha_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
             p.astype(vb.dtype), vb, preferred_element_type=jnp.float32)
         return acc, m_new, l
 
-    d = q.shape[-1]
-    init = (jnp.zeros((block_q, d), jnp.float32),
+    init = (jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32),
             jnp.full((block_q,), _NEG, jnp.float32),
             jnp.zeros((block_q,), jnp.float32))
     # with causal masking, KV blocks strictly above the diagonal contribute
@@ -264,6 +293,21 @@ def _mha_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
     # (1, BQ) blocks); consecutive grid steps over j revisit the same row
     # block, so each writes its own BQ slice
     lse_ref[0, 0, pl.ds(qi * block_q, block_q)] = m + jnp.log(l)
+
+
+def _mha_fwd_masked_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
+                           **kw):
+    """``_mha_fwd_kernel`` with the mask among its operands."""
+    _mha_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, mask_ref=mask_ref,
+                    **kw)
+
+
+def _mha_fwd_lens_kernel(len_ref, q_ref, k_ref, v_ref, *rest, **kw):
+    """``_mha_fwd_kernel`` with the rows' lengths scalar-prefetched, and
+    the mask among its operands where there is one."""
+    _mha_fwd_kernel(q_ref, k_ref, v_ref, rest[-2], rest[-1],
+                    mask_ref=rest[0] if len(rest) == 3 else None,
+                    len_ref=len_ref, **kw)
 
 
 def _mha_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
@@ -573,38 +617,79 @@ def _lse_spec_bthd(h, t):
     Flattening (B, H) into the major dim makes the singleton blocks
     cover full dims, which is exactly how the proven BHTD path lays out
     its stats."""
-    return pl.BlockSpec((1, 1, t), lambda bi, hi, qi: (bi * h + hi, 0, 0))
+    return pl.BlockSpec((1, 1, t),
+                        lambda bi, hi, qi, *_: (bi * h + hi, 0, 0))
 
 
 def _mha_fwd_call_bthd(qs, k, v, h, causal, block_q, block_k, interpret,
-                       window=0, name=None):
+                       window=0, name=None, mask=None, lengths=None):
+    """``mask`` (B, T, tk) int8, the same for every head: 1 where the
+    query attends the key (with ``causal``, which still bounds the key
+    blocks a q-block walks: a mask here only ever takes keys away). The
+    kernel is handed it as (B, tk / block_k, T, block_k), a key block an
+    index of a major axis, so that it reads a (block_q, block_k) tile
+    by a plain index. ``lengths`` (B,) int32, scalar-prefetched: the
+    q-blocks wholly past a row's length compute nothing, give zeros and
+    fetch no tile of the mask (a prefill's bucket is a power of two and
+    the prompt in it on average two thirds of that: half the causal
+    pairs). V (B, tk, h * dv) may be of another width than q and K; the
+    output is (B, T, h * dv)."""
     b, t, hd = qs.shape
     tk = k.shape[1]
-    d = hd // h
+    d, dv = hd // h, v.shape[2] // h
     kernel = functools.partial(
-        _mha_fwd_kernel, block_q=block_q, block_k=block_k, seq_k=tk,
+        _mha_fwd_lens_kernel if lengths is not None
+        else _mha_fwd_kernel if mask is None else _mha_fwd_masked_kernel,
+        block_q=block_q, block_k=block_k, seq_k=tk,
         causal=causal, pid_axis=2, window=window)
-    return named_pallas_call(
-        name or FLASH_FWD, kernel,
+    operands, mask_specs, mask_bytes = (qs, k, v), [], 0
+
+    def live_q(bi, qi, lens):
+        """q-block ``qi``, or the row's last live one past it: a block
+        whose index did not change is not copied again."""
+        if not lens:
+            return qi
+        return jnp.minimum(qi, jnp.maximum(lens[0][bi] - 1, 0) // block_q)
+
+    if mask is not None:
+        nk = tk // block_k
+        operands += (jnp.swapaxes(
+            mask.astype(jnp.int8).reshape(b, t, nk, block_k), 1, 2),)
+        mask_specs = [pl.BlockSpec(
+            (1, nk, block_q, block_k),
+            lambda bi, hi, qi, *lens: (bi, 0, live_q(bi, qi, lens), 0))]
+        mask_bytes = 2 * block_q * tk
+    limit = _kv_vmem_limit(tk, d, jnp.dtype(k.dtype).itemsize)
+    if mask_bytes and limit is not None:
+        limit += mask_bytes
+    specs = dict(
         grid=(b, h, t // block_q),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bi, hi, qi: (bi, qi, hi)),
-            pl.BlockSpec((1, tk, d), lambda bi, hi, qi: (bi, 0, hi)),
-            pl.BlockSpec((1, tk, d), lambda bi, hi, qi: (bi, 0, hi)),
-        ],
+            pl.BlockSpec((1, block_q, d), lambda bi, hi, qi, *lens: (
+                bi, live_q(bi, qi, lens), hi)),
+            pl.BlockSpec((1, tk, d), lambda bi, hi, qi, *lens: (bi, 0, hi)),
+            pl.BlockSpec((1, tk, dv), lambda bi, hi, qi, *lens: (bi, 0, hi)),
+        ] + mask_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bi, hi, qi: (bi, qi, hi)),
+            pl.BlockSpec((1, block_q, dv),
+                         lambda bi, hi, qi, *lens: (bi, qi, hi)),
             _lse_spec_bthd(h, t),
-        ],
+        ])
+    if lengths is not None:
+        operands = (lengths.reshape(-1).astype(jnp.int32),) + operands
+        specs = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, **specs))
+    return named_pallas_call(
+        name or FLASH_FWD, kernel,
         out_shape=[
-            jax.ShapeDtypeStruct((b, t, hd), qs.dtype),
+            jax.ShapeDtypeStruct((b, t, h * dv), qs.dtype),
             jax.ShapeDtypeStruct((b * h, 1, t), jnp.float32),
         ],
         interpret=interpret,
         **_tpu_params("parallel", "parallel", "arbitrary",
-                      vmem_limit_bytes=_kv_vmem_limit(
-                          tk, d, jnp.dtype(k.dtype).itemsize)),
-    )(qs, k, v)
+                      vmem_limit_bytes=limit),
+        **specs,
+    )(*operands)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))

@@ -54,11 +54,15 @@ lax form).
 """
 from __future__ import annotations
 
+import dataclasses
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..observability import MLA_TRACES
 from . import attention as _A
@@ -80,6 +84,9 @@ MLA_APPEND = "ptpu.mla_append"
 # name ``latent_i``) or by the slab's shape, and a Mosaic call's text
 # holds neither: its operand is the transposed view's bitcast.
 MLA_LATENT_ATTN = "ptpu.mla_latent_attn"
+# a latent layer over a window: its prefill's flash calls and its step's
+# reads of the ring
+LATENT_RING_ATTEND = "ptpu.latent_ring_attend"
 
 _NEG = -1e30
 
@@ -126,15 +133,19 @@ def mla_q(u, w_qa, g_q, w_qb, positions, n_head, rope_dim, eps, rot):
         return q.astype(u.dtype)
 
 
-def mla_kv(u, w_kva, g_kv, positions, rope_dim, eps, rot):
+def mla_kv(u, w_kva, g_kv, positions, rope_dim, eps, rot, rescale=1.0):
     """u (B, T, D) -> the latent rows (B, T, rank + rope): ``[rms(c_kv) ;
-    rope(k_r)]`` of ``[c_kv ; k_r] = u W_kva``."""
+    rope(k_r)]`` of ``[c_kv ; k_r] = u W_kva``, the normalised latent
+    times ``rescale`` where a model rescales it (``(d_model /
+    kv_lora_rank)^1/2``: ``DecodeConfig.latent_rescale``)."""
     with jax.named_scope(MLA_KV):
         row = jnp.matmul(u, w_kva)
         rank = row.shape[-1] - rope_dim
         k_r = _rotate(row[:, :, None, rank:], positions, rot)[:, :, 0]
-        return jnp.concatenate([_rms(row[..., :rank], g_kv, eps), k_r],
-                               axis=-1).astype(u.dtype)
+        c_kv = _rms(row[..., :rank], g_kv, eps)
+        if rescale != 1.0:
+            c_kv = c_kv * rescale
+        return jnp.concatenate([c_kv, k_r], axis=-1).astype(u.dtype)
 
 
 def _split_kvb(w_kvb, n_head, nope):
@@ -181,16 +192,124 @@ def mla_attend(q, k, v, scale):
                                         scale)[..., :dv]
 
 
-def _latent_attend_lax(q_row, slab, lens, rank):
+# heads a pass of ``latent_prefill``: 16 heads of 16,384 rows padded to
+# 256 channels are 268 MB each of q, k, v and the output, where 128
+# heads' would be 2.1 GB each
+_PREFILL_HEADS = 16
+
+
+def _masked_attend_lax(q, k, v, scale, window, mask):
+    """Exact lax attention of a head group: q, k (B, T, g, dq), v (B, T,
+    g, dv); causal, the last ``window`` keys where it is set, and under
+    ``mask`` (B, T, T) where one is given. Builds the (T, T) scores:
+    every device but a TPU, and the kernel's reference."""
+    t = q.shape[1]
+    s = jnp.einsum("btgd,bsgd->bgts", q.astype(jnp.float32) * scale,
+                   k.astype(jnp.float32))
+    row, col = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
+    seen = col <= row
+    if window:
+        seen &= col > row - window
+    seen = seen[None, None]
+    if mask is not None:
+        seen = seen & (mask[:, None] != 0)
+    p = jax.nn.softmax(jnp.where(seen, s, _NEG), axis=-1)
+    return jnp.einsum("bgts,bsgd->btgd", p, v.astype(jnp.float32))
+
+
+def latent_prefill(c_q, rows, w_qb, w_kvb, gate, w_o, n_head, nope, scale,
+                   rot, window=0, mask=None, lengths=None, name=MLA_ATTEND,
+                   heads=_PREFILL_HEADS, interpret=False):
+    """A prefill's EXPANDED attention of a latent layer of MANY heads,
+    from the query latent to the output projection, ``heads`` heads at a
+    time: c_q (B, T, q_rank), the latent rows (B, T, rank + rope), W_qb
+    (q_rank, H (nope + rope)), W_kvb (rank, H (nope + v)), gate (B, T,
+    H) or None, W_o (H v, D) -> (B, T, D). For each group of heads:
+    ``q = c_q W_qb`` (its rope part rotated), ``[k_nope ; v] = c_kv
+    W_kvb``, causal attention at ``scale`` (over the last ``window``
+    keys where set; under ``mask`` (B, T, T) int8 where given: the keys
+    an indexer chose, ``ops/dsa.py``), the gate, and the group's rows
+    of ``W_o`` added to the output. The q, k and v of all heads never
+    exist at once (128 heads of 16,384 tokens: 1.6, 1.6 and 1.1 GB, 2.1
+    GB each padded to the flash kernel's 256 channels), and the same
+    numbers as ``mla_expand`` + ``mla_attend`` + the projection give: a
+    sum over heads taken in groups. The flash kernel (a TPU, block-
+    aligned sequences; its q, k and v in bfloat16, float32 sums; q and k
+    padded with zero channels to 256, v at its own 128) under ``name``
+    in a device trace; the exact lax form elsewhere. ``lengths`` (B,):
+    the rows' live tokens; the kernel then leaves the q-blocks wholly
+    past them alone (zeros: rows no one reads)."""
+    b, t, _ = c_q.shape
+    rank = w_kvb.shape[0]
+    rope = rows.shape[-1] - rank
+    dq, per = nope + rope, w_kvb.shape[1] // n_head
+    dv = per - nope
+    g = min(int(heads), n_head)
+    while n_head % g:
+        g -= 1
+    kernel = interpret or _A._use_pallas(t, t, None, 0.0)
+    MLA_TRACES.inc(path="expanded")
+    c_kv = rows[..., :rank]
+    k_r = jnp.broadcast_to(rows[:, :, None, rank:], (b, t, g, rope))
+
+    def attend(q, k, v):
+        if not kernel:
+            return _masked_attend_lax(q, k, v, scale, window, mask)
+
+        def pad(x):
+            # the kernel's operands in bfloat16: what the MXU would round
+            # float32 operands to at the default precision anyway (the
+            # arithmetic the lax paths compute in), where a Mosaic dot of
+            # float32 operands runs several passes at ~13% of the peak;
+            # a head's channels a whole number of 128-lane tiles
+            width = -(-x.shape[-1] // 128) * 128
+            return jnp.pad(x, ((0, 0),) * 3 + ((0, width - x.shape[-1]),)
+                           ).reshape(b, t, g * width).astype(jnp.bfloat16)
+
+        block_q = _A._fit_block(t, 256 if mask is not None else 512)
+        block_k = _A._fit_block(t, 512)
+        out, _ = _A._mha_fwd_call_bthd(
+            pad(q * jnp.asarray(scale, q.dtype)), pad(k), pad(v), g, True,
+            block_q, block_k, interpret,
+            window=0 if window >= t else int(window), name=name, mask=mask,
+            lengths=lengths)
+        return out.reshape(b, t, g, -1)[..., :dv].astype(jnp.float32)
+
+    def group(i, y):
+        q = jnp.matmul(c_q, lax.dynamic_slice_in_dim(
+            w_qb, i * g * dq, g * dq, axis=1)).reshape(b, t, g, dq)
+        q = jnp.concatenate(
+            [q[..., :nope], _rotate(q[..., nope:], None, rot)], axis=-1)
+        kv = jnp.matmul(c_kv, lax.dynamic_slice_in_dim(
+            w_kvb, i * g * per, g * per, axis=1)).reshape(b, t, g, per)
+        k = jnp.concatenate([kv[..., :nope], k_r], axis=-1)
+        ctx = attend(q.astype(c_q.dtype), k, kv[..., nope:])
+        if gate is not None:
+            ctx = ctx * lax.dynamic_slice_in_dim(gate, i * g, g,
+                                                 axis=2)[..., None]
+        return y + jnp.matmul(
+            ctx.reshape(b, t, g * dv).astype(c_q.dtype),
+            lax.dynamic_slice_in_dim(w_o, i * g * dv, g * dv, axis=0))
+
+    with jax.named_scope(name):
+        return lax.fori_loop(
+            0, n_head // g, group,
+            jnp.zeros((b, t, w_o.shape[1]), c_q.dtype))
+
+
+def _latent_attend_lax(q_row, slab, lens, rank, chosen=None):
     """The exact lax form of the absorbed attention: scaled query rows
     q_row (B, H, W) on the slab (B, S, W) as it lies, rows [0, lens)
-    of a slot seen -> (B, H, rank). Both products contract the slab's
+    of a slot seen (of them those ``chosen`` (B, S) bool, where given)
+    -> (B, H, rank). Both products contract the slab's
     row; the second also sums the ``rope`` columns, which are dropped
     (a slice of the slab would be a copy of it). The reference, and the
     path of every shape and device ``latent_view`` has no block for."""
     s = slab.shape[1]
     scores = jnp.einsum("bhw,bsw->bhs", q_row, slab)
     live = jnp.arange(s)[None, None, :] < lens[:, None, None]
+    if chosen is not None:
+        live = live & chosen[:, None, :]
     scores = jnp.where(live, scores, _NEG)
     m = jnp.max(scores, axis=-1, keepdims=True)
     p = jnp.where(live, jnp.exp(scores - m), 0.0)
@@ -249,7 +368,104 @@ def pallas_latent_attend(q_row, slab, lens, rank, block_s=_LATENT_BLOCK_LANES,
     return _DS.stream_attend(view, lens, q_row, slab, slab, interpret)
 
 
-def mla_decode(q, slab, lengths, w_kvb, scale):
+def chosen_view(s, h, row, rank, dtype, block_s=_LATENT_BLOCK_LANES):
+    """``latent_view`` for the one-pass kernel under a choice of rows,
+    which keeps no slot's scores between passes: any number of heads."""
+    return dataclasses.replace(latent_view(s, h, row, rank, dtype, block_s),
+                               score_rows=0)
+
+
+def _chosen_attend_kernel(len_ref, q_ref, k_ref, c_ref, o_ref, m_ref, l_ref,
+                          acc_ref, *, block_s, n_blk, rank):
+    """One (slot, block) grid cell: q_ref (1, H, row) pre-scaled, k_ref
+    (1, row, BS) a block of the slab's transposed view, c_ref (1, 1, BS)
+    1.0 where the position is chosen. An online softmax over the slot's
+    live blocks: ``m_ref``, ``l_ref`` (H, 1) and ``acc_ref`` (H, rank)
+    live across them. Both products on bfloat16 operands, what the lax
+    form's round to at the TPU's default precision; sums in float32."""
+    j = pl.program_id(1)
+    live_blocks = (len_ref[pl.program_id(0)] + block_s - 1) // block_s
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when(j < live_blocks)
+    def _():
+        k = k_ref[0].astype(jnp.bfloat16)                      # (row, BS)
+        keep = c_ref[0] > 0.5                                  # (1, BS)
+        sc = jnp.where(keep, jnp.dot(
+            q_ref[0].astype(jnp.bfloat16), k,
+            preferred_element_type=jnp.float32), _NEG)         # (H, BS)
+        m = jnp.maximum(m_ref[...], jnp.max(sc, axis=1, keepdims=True))
+        p = jnp.where(keep, jnp.exp(sc - m), 0.0)
+        corr = jnp.exp(m_ref[...] - m)
+        l_ref[...] = corr * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = corr * acc_ref[...] + lax.dot_general(
+            p.astype(jnp.bfloat16), k[:rank], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m
+
+    @pl.when(j == n_blk - 1)
+    def _():
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(
+            o_ref.dtype)
+
+
+def pallas_chosen_attend(q_row, slab, lens, chosen, rank, name,
+                         block_s=_LATENT_BLOCK_LANES, interpret=False):
+    """``_latent_attend_lax``'s contract under ``chosen`` (B, S) bool
+    through a kernel, ``name`` in a device trace, over the slab WHERE IT
+    LIES (its transposed view, as ``pallas_latent_attend`` reads it): a
+    slot's live blocks are streamed ONCE, the rows not chosen masked
+    inside, a dead block never fetched; no (B, H, S) array of scores
+    goes to memory. One pass and an online softmax where the streamed
+    kernels of ``ops/decode_stream.py`` make two: the scores of 128
+    heads on 16,384 positions, 8 MiB, do not wait in vector memory, and
+    what an online softmax rounds otherwise (unnormalised weights to
+    bfloat16: 0.002 of the output's norm, PERF.md, PR 32) the lax form
+    under a mask has no claim to either."""
+    b, h, w = q_row.shape
+    s = slab.shape[1]
+    rows = _DS.block_positions(chosen_view(s, h, w, rank, slab.dtype,
+                                            block_s))
+    if rows is None:
+        raise ValueError("%s: no kernel for a slab of %d positions of %d %s"
+                         % (name, s, w, jnp.dtype(slab.dtype).name))
+    n_blk = s // rows
+    lens = jnp.clip(lens, 0, s)
+    keep = chosen & (jnp.arange(s, dtype=jnp.int32)[None, :] < lens[:, None])
+
+    def block(bi, j, lens_ref):
+        return (bi, 0, _DS.live_block(j, lens_ref, bi, rows))
+
+    return _A.named_pallas_call(
+        name, functools.partial(_chosen_attend_kernel, block_s=rows,
+                                n_blk=n_blk, rank=rank),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, n_blk),
+            in_specs=[
+                pl.BlockSpec((1, h, w), lambda bi, j, lens_ref: (bi, 0, 0)),
+                pl.BlockSpec((1, w, rows), block),
+                pl.BlockSpec((1, 1, rows), block),
+            ],
+            out_specs=pl.BlockSpec((1, h, rank),
+                                   lambda bi, j, lens_ref: (bi, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((h, 1), jnp.float32),
+                            pltpu.VMEM((h, 1), jnp.float32),
+                            pltpu.VMEM((h, rank), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, h, rank), q_row.dtype),
+        interpret=interpret,
+        **_A._tpu_params("parallel", "arbitrary"),
+    )(lens, q_row, jnp.swapaxes(slab, 1, 2),
+      keep.astype(jnp.float32)[:, None, :])
+
+
+def mla_decode(q, slab, lengths, w_kvb, scale, chosen=None,
+               name=MLA_DECODE):
     """The ABSORBED path: q (B, 1, H, nope + rope), the latent slab (B,
     S, rank + rope) with ``lengths`` (B,) live rows a slot -> (B, 1, H,
     v). ``q~ = q_nope W^K`` before and ``o~ W^V`` after are plain
@@ -257,30 +473,42 @@ def mla_decode(q, slab, lengths, w_kvb, scale):
     kernel over a slot's live blocks where the slab's shape, type and
     the device allow it (``kv_cache.decode_stream_rows``) and the exact lax
     form, which reads every row of every slot, elsewhere. A slot of
-    length 0 gives zeros."""
+    length 0 gives zeros. Under ``chosen`` (B, S) bool (an indexer's
+    choice, ``ops/dsa.py``) a slot attends its chosen live rows alone:
+    the kernel ``<name>_step`` streams a slot's live blocks once and
+    masks the rows not chosen (``pallas_chosen_attend``; the lax form
+    streams every row of every slot and writes the scores out). A
+    RING of latent rows (``latent_ring``) is a slab of ``window`` rows
+    whose order the softmax does not see: ``lengths`` is then
+    ``min(positions held, window)``."""
     b, _, h, _ = q.shape
     s, rank = slab.shape[1], w_kvb.shape[0]
     nope = q.shape[-1] - (slab.shape[-1] - rank)
-    kernel = _KV.decode_stream_rows(latent_view(
+    view = latent_view if chosen is None else chosen_view
+    kernel = _KV.decode_stream_rows(view(
         s, h, slab.shape[-1], rank, slab.dtype)) is not None
     MLA_TRACES.inc(path="absorbed_kernel" if kernel else "absorbed")
-    with jax.named_scope(MLA_DECODE):
+    with jax.named_scope(name):
         w_k, w_v = _split_kvb(w_kvb, h, nope)
         qf = q[:, 0].astype(jnp.float32)
         q_lat = jnp.einsum("bhd,rhd->bhr", qf[..., :nope], w_k)
         q_row = jnp.concatenate([q_lat, qf[..., nope:]], axis=-1) * scale
         lens = lengths.reshape(-1).astype(jnp.int32)
-        if kernel:
+        if kernel and chosen is not None:
+            o_lat = pallas_chosen_attend(q_row, slab, lens, chosen, rank,
+                                         name + "_step")
+        elif kernel:
             o_lat = pallas_latent_attend(q_row, slab, lens, rank)
         else:
-            o_lat = _latent_attend_lax(q_row, slab, lens, rank)
+            o_lat = _latent_attend_lax(q_row, slab, lens, rank, chosen)
         out = jnp.einsum("bhr,rhd->bhd", o_lat, w_v)
         return out[:, None].astype(q.dtype)
 
 
-def mla_append(slab, row, pos):
+def mla_append(slab, row, pos, ring=False):
     """One latent row a slot: row (B, 1, W) at ``pos`` (B,) of slab (B,
-    S, W). One dynamic-update-slice a slot and not ``kv_cache.
+    S, W), at ``pos mod S`` where the slab is a ``ring`` of the last S
+    positions. One dynamic-update-slice a slot and not ``kv_cache.
     cache_append``'s scatter: on the chip a row of 320 floats is no
     multiple of the 128 lanes, so the compiler lays the slab out with
     the SEQUENCE minor ({1,2,0}: 320 sublane rows of S lanes, no
@@ -293,7 +521,8 @@ def mla_append(slab, row, pos):
             raise ValueError("mla_append appends ONE row per sequence; "
                              "New has time dim %d" % row.shape[1])
         row = row[:, 0]
-    pos = jnp.clip(pos.reshape(-1).astype(jnp.int32), 0, s - 1)
+    pos = pos.reshape(-1).astype(jnp.int32)
+    pos = pos % s if ring else jnp.clip(pos, 0, s - 1)
     zero = jnp.zeros((), jnp.int32)
     with jax.named_scope(MLA_APPEND):
         for i in range(b):
@@ -331,12 +560,13 @@ def _mla_q_op(ctx):
 @register_op("mla_kv")
 def _mla_kv_op(ctx):
     """Inputs X (B, T, D), WA (D, rank + rope), Gain (rank,), optional
-    Positions. Attrs rope_dim, epsilon and the rotation's (``mla_q``)
-    -> Out (B, T, rank + rope)."""
+    Positions. Attrs rope_dim, epsilon, rescale and the rotation's
+    (``mla_q``) -> Out (B, T, rank + rope)."""
     return {"Out": mla_kv(
         ctx.input("X"), ctx.input("WA"), ctx.input("Gain"),
         ctx.input("Positions"), int(ctx.attr("rope_dim")),
-        float(ctx.attr("epsilon", 1e-6)), _rot_of(ctx))}
+        float(ctx.attr("epsilon", 1e-6)), _rot_of(ctx),
+        float(ctx.attr("rescale", 1.0)))}
 
 
 @register_op("mla_expand")
@@ -360,16 +590,38 @@ def _mla_attend_op(ctx):
 @register_op("mla_decode")
 def _mla_decode_op(ctx):
     """Inputs Q (B, 1, H, nope + rope), Cache (B, S, rank + rope),
-    Lengths (B,) live rows, WB (rank, H * (nope + v)). Attr scale ->
-    Out (B, 1, H, v)."""
+    Lengths (B,) live rows, WB (rank, H * (nope + v)), optional Chosen
+    (B, S) bool. Attrs scale, scope (the named scope: ``ptpu.
+    mla_decode`` unless given) -> Out (B, 1, H, v)."""
     return {"Out": mla_decode(ctx.input("Q"), ctx.input("Cache"),
                               ctx.input("Lengths"), ctx.input("WB"),
-                              float(ctx.attr("scale")))}
+                              float(ctx.attr("scale")),
+                              ctx.input("Chosen"),
+                              ctx.attr("scope", None) or MLA_DECODE)}
+
+
+@register_op("latent_prefill")
+def _latent_prefill_op(ctx):
+    """Inputs CQ (B, T, q_rank), Rows (B, T, rank + rope), WQB, WKVB, WO
+    (H v, D), optional Gate (B, T, H), Mask (B, T, T) int8 and Lengths
+    (B,) live tokens a row. Attrs
+    n_head, nope_dim, scale, window, scope and the rotation's
+    (``mla_q``) -> Out (B, T, D): ``latent_prefill``."""
+    return {"Out": latent_prefill(
+        ctx.input("CQ"), ctx.input("Rows"), ctx.input("WQB"),
+        ctx.input("WKVB"), ctx.input("Gate"), ctx.input("WO"),
+        int(ctx.attr("n_head")), int(ctx.attr("nope_dim")),
+        float(ctx.attr("scale")), _rot_of(ctx),
+        window=int(ctx.attr("window", 0) or 0), mask=ctx.input("Mask"),
+        lengths=ctx.input("Lengths"),
+        name=ctx.attr("scope", None) or MLA_ATTEND)}
 
 
 @register_op("mla_append")
 def _mla_append_op(ctx):
-    """Inputs Cache (B, S, W), New (B, 1, W), Pos (B,) -> Out: the slab
-    with each slot's new row at its position."""
+    """Inputs Cache (B, S, W), New (B, 1, W), Pos (B,). Attr ring (the
+    position mod S) -> Out: the slab with each slot's new row at its
+    position."""
     return {"Out": mla_append(ctx.input("Cache"), ctx.input("New"),
-                              ctx.input("Pos"))}
+                              ctx.input("Pos"),
+                              bool(ctx.attr("ring", False)))}

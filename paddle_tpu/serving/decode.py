@@ -185,7 +185,19 @@ class DecodeConfig:
     convolutions of ``kda_conv`` taps on q, k and v, a decay gate
     ``kda_gate`` ("lower_bound_sigmoid", log-decay in (``kda_gate_bound``,
     0) | "softplus", unbounded); it keeps three windows and ONE matrix
-    state a head, all fixed-size."""
+    state a head, all fixed-size. A ``latent_dsa`` layer is a latent
+    layer UNDER A LEARNED INDEXER (``ops/dsa.py``): ``index_heads`` index
+    queries of ``index_head_dim`` from the query latent (so
+    ``q_lora_rank`` > 0), one index key a position, and a query attends
+    the ``index_topk`` earlier positions the indexer scores highest; it
+    keeps its latent slab and, beside it, a slab of index keys. A
+    ``latent_ring`` layer is latent attention OF A GEOMETRY OF ITS OWN
+    over the last ``window`` positions: ``latent_ring`` = {n_head,
+    q_lora_rank, kv_lora_rank, qk_nope_dim, qk_rope_dim, v_head_dim},
+    rotated by ``rope["latent_ring"]``; it keeps a RING of ``window``
+    latent rows. ``latent_rescale`` multiplies the normalised latents by
+    ``(d_model / rank)^1/2``, each kind by its own ranks.
+    ``latent_geometry(kind)`` is a latent kind's sizes."""
 
     FIELDS = ("vocab_size", "n_layer", "n_head", "d_model", "d_inner",
               "max_len", "tie_embeddings", "prefix", "eos_id")
@@ -212,9 +224,16 @@ class DecodeConfig:
                    ("kda_heads", 0), ("kda_head_dim", 0), ("kda_conv", 4),
                    ("kda_gate", "lower_bound_sigmoid"),
                    ("kda_gate_bound", -5.0), ("router_groups", 1),
-                   ("router_topk_groups", 1), ("router_bias", False))
+                   ("router_topk_groups", 1), ("router_bias", False),
+                   ("latent_ring", None), ("latent_rescale", False),
+                   ("index_heads", 0), ("index_head_dim", 0),
+                   ("index_topk", 0))
     MIXERS = ("mamba", "attention", "sliding", "gmu", "cross", "latent",
-              "kda")
+              "kda", "latent_dsa", "latent_ring")
+    # the kinds that keep latent rows
+    LATENT_KINDS = ("latent", "latent_dsa", "latent_ring")
+    RING_WIDTHS = ("n_head", "q_lora_rank", "kv_lora_rank", "qk_nope_dim",
+                   "qk_rope_dim", "v_head_dim")
     # ``q_lora_rank`` 0 is a query with no bottleneck
     LATENT_WIDTHS = ("kv_lora_rank", "qk_nope_dim", "qk_rope_dim",
                      "v_head_dim")
@@ -271,7 +290,7 @@ class DecodeConfig:
                     "%r, %r, %r, %r" % (self.n_expert, self.expert_top_k,
                                         self.d_expert, self.experts_held))
         kinds = self.layer_kinds()
-        if "sliding" in kinds and not self.window:
+        if {"sliding", "latent_ring"} & set(kinds) and not self.window:
             raise ValueError("a sliding attention layer needs a window")
         for i, kind in enumerate(kinds):
             if kind not in self.MIXERS:
@@ -282,12 +301,31 @@ class DecodeConfig:
                 raise ValueError(
                     "layer %d (%s) reads what a %s layer before it hands "
                     "on, and none is" % (i, kind, need))
-        if "latent" in kinds and not all(
+        if {"latent", "latent_dsa"} & set(kinds) and not all(
                 int(getattr(self, f) or 0) > 0 for f in self.LATENT_WIDTHS):
             raise ValueError(
                 "a latent layer needs %s; got %s" % (
                     ", ".join(self.LATENT_WIDTHS),
                     [getattr(self, f) for f in self.LATENT_WIDTHS]))
+        if "latent_dsa" in kinds and not all(
+                int(getattr(self, f) or 0) > 0 for f in (
+                    "q_lora_rank", "index_heads", "index_head_dim",
+                    "index_topk")):
+            raise ValueError(
+                "a latent layer under an indexer needs q_lora_rank (the "
+                "index queries come from the query latent), index_heads, "
+                "index_head_dim and index_topk; got %r, %r, %r, %r"
+                % (self.q_lora_rank, self.index_heads, self.index_head_dim,
+                   self.index_topk))
+        if "latent_ring" in kinds:
+            ring = dict(self.latent_ring or {})
+            if (set(ring) != set(self.RING_WIDTHS)
+                    or not all(int(v or 0) > 0 for v in ring.values())):
+                raise ValueError(
+                    "a latent layer over a window needs latent_ring = {%s}, "
+                    "all positive; got %r"
+                    % (", ".join(self.RING_WIDTHS), self.latent_ring))
+            self.latent_ring = {f: int(ring[f]) for f in self.RING_WIDTHS}
         if "kda" in kinds and not (int(self.kda_heads or 0) > 0
                                    and int(self.kda_head_dim or 0) > 0
                                    and int(self.kda_conv or 0) > 1):
@@ -347,6 +385,26 @@ class DecodeConfig:
         ``[c_kv ; k_r]``."""
         return int(self.kv_lora_rank) + int(self.qk_rope_dim)
 
+    def latent_geometry(self, kind: str = "latent") -> "LatentGeometry":
+        """The sizes of latent kind ``kind``: a ``latent_ring`` layer's
+        own (``latent_ring``), else the model's one set."""
+        g = (self.latent_ring if kind == "latent_ring" else
+             {f: int(getattr(self, f) or 0) for f in self.RING_WIDTHS})
+        scale = (self.softmax_scale
+                 if kind != "latent_ring" and self.softmax_scale is not None
+                 else float(g["qk_nope_dim"] + g["qk_rope_dim"]) ** -0.5)
+
+        def rho(rank):
+            if not (self.latent_rescale and rank):
+                return 1.0
+            return (float(self.d_model) / rank) ** 0.5
+
+        return LatentGeometry(
+            g["n_head"], g["q_lora_rank"], g["kv_lora_rank"],
+            g["qk_nope_dim"], g["qk_rope_dim"], g["v_head_dim"],
+            g["kv_lora_rank"] + g["qk_rope_dim"], float(scale),
+            rho(g["q_lora_rank"]), rho(g["kv_lora_rank"]))
+
     @property
     def tail_start(self) -> int:
         """The first layer from which on no layer owns a cache entry
@@ -382,13 +440,13 @@ class DecodeConfig:
     def has_ring(self) -> bool:
         """Some layer keeps a ring of ``window`` rows: positions that
         left the window are overwritten (no rows to roll back to)."""
-        return "sliding" in self.layer_kinds()
+        return bool({"sliding", "latent_ring"} & set(self.layer_kinds()))
 
     @property
     def has_latent(self) -> bool:
         """Some layer keeps latent rows: a row per position that is
         neither K nor V (no head axis, one array a layer)."""
-        return "latent" in self.layer_kinds()
+        return bool(set(self.LATENT_KINDS) & set(self.layer_kinds()))
 
     @property
     def extra_fetches(self) -> List[str]:
@@ -421,6 +479,14 @@ class DecodeConfig:
         return cls(**{f: d[f] for f in known if f in d})
 
 
+# a latent kind's sizes (``DecodeConfig.latent_geometry``): heads, the
+# two ranks (``q_rank`` 0: no bottleneck), the three head widths, the
+# floats of the row a position keeps, the softmax scale, and what the
+# normalised query and key/value latents are multiplied by
+LatentGeometry = collections.namedtuple(
+    "LatentGeometry", "n_head q_rank rank nope rope v row scale rho_q rho_kv")
+
+
 class CacheEntry(collections.namedtuple(
         "CacheEntry", "name shape dtype per_position")):
     """One array of a model's decode cache: its feed name, its shape
@@ -439,15 +505,24 @@ class CacheEntry(collections.namedtuple(
     - ``"ring"``: the last ``window`` rows of a sliding-window layer at
       ``position mod window``: a prefill hands the ring over as it is
       stored, so an admission replaces it whole, as a state
-      (``per_position`` false); a decode step writes one row of it."""
+      (``per_position`` false); a decode step writes one row of it;
+    - ``"latent_ring"``: such a ring of LATENT rows, ``(slots, window,
+      kv_lora_rank + qk_rope_dim)`` of the layer kind's own widths;
+    - ``"index"``: an indexer's keys, ``(slots, seq, index_head_dim)``:
+      a row per position beside the latent slab of the same layer,
+      written and masked as that is (``ops/dsa.py``)."""
 
     __slots__ = ()
 
     @property
     def kind(self) -> str:
         if self.per_position:
-            # ``cache_names`` calls a latent layer's entry latent_i
-            return "latent" if self.name.startswith("latent_") else "rows"
+            # ``cache_names`` calls a latent layer's entry latent_i and
+            # an indexer's keys index_i
+            return {"latent": "latent", "index": "index"}.get(
+                self.name.split("_")[0], "rows")
+        if self.name.startswith("lring_"):
+            return "latent_ring"
         # ``cache_names`` calls a sliding layer's entries kring_i, vring_i
         return "ring" if self.name[1:].startswith("ring") else "state"
 
@@ -474,7 +549,10 @@ def cache_spec(config: DecodeConfig, slots: int, seq: int,
     whatever ``seq``, a latent layer's ONE ``latent_i`` (slots, seq,
     ``config.latent_row``): a row per position that is neither K nor V
     (``[c_kv ; k_r]``, normalised and rotated: what both of MLA's
-    attention paths read), a Mamba layer's ``conv_i`` (slots, K - 1,
+    attention paths read), a latent layer under an indexer that and
+    ``index_i`` (slots, seq, ``index_head_dim``), its index keys, a
+    latent layer over a window ONE ring ``lring_i`` (slots, window,
+    its own row) whatever ``seq``, a Mamba layer's ``conv_i`` (slots, K - 1,
     d_inner) window and ``ssm_i`` (slots, d_inner, N) state, NOTHING for
     a ``gmu`` or a ``cross`` layer (it reads what another layer keeps;
     a slab may so have several readers a step), a KDA layer's three
@@ -494,7 +572,9 @@ def cache_spec(config: DecodeConfig, slots: int, seq: int,
             "(%s) are float32"
             % (kv_dtype, ", ".join(sorted(set(
                 {"attention": "rows", "sliding": "ring", "mamba": "state",
-                 "kda": "state", "latent": "latent"}.get(k, "none")
+                 "kda": "state", "latent": "latent",
+                 "latent_dsa": "latent", "latent_ring": "ring"}.get(
+                     k, "none")
                 for k in config.layer_kinds())))))
     from ..models.jamba import cache_names
 
@@ -528,12 +608,33 @@ def cache_spec(config: DecodeConfig, slots: int, seq: int,
             out.append(CacheEntry(
                 names[0], (slots, seq, config.latent_row), "float32", True))
             continue
+        if kind == "latent_dsa":
+            out.append(CacheEntry(
+                names[0], (slots, seq, int(config.index_head_dim)),
+                "float32", True))
+            out.append(CacheEntry(
+                names[1], (slots, seq, config.latent_row), "float32", True))
+            continue
+        if kind == "latent_ring":
+            out.append(CacheEntry(
+                names[0], (slots, int(config.window),
+                           config.latent_geometry(kind).row),
+                "float32", False))
+            continue
         out += [CacheEntry(n, slab, kv_dtype, True) for n in names]
         if kv_dtype == "int8":
             # the (slot, position) float32 scale of each int8 row
             out += [CacheEntry("%sscale_%d" % (kv, i), (slots, seq),
                                "float32", True) for kv in "kv"]
     return out if config.is_opt_block else sorted(out)
+
+
+def _kept_pairs(prompts, k: int) -> int:
+    """(query, key) pairs of ``prompts`` where a query at position t
+    keeps ``min(t + 1, k)`` keys: a window of ``k``, or an indexer's
+    choice of ``k``."""
+    return sum(n * (n + 1) // 2 if n <= k else k * (k + 1) // 2 + (n - k) * k
+               for n in map(len, prompts))
 
 
 @functools.lru_cache(maxsize=None)
@@ -1734,6 +1835,13 @@ class DecodeServer:
         # absorbed attention reads every latent layer's live rows
         self._latent_row_bytes = (4 * cfg.latent_row if cfg.has_latent
                                   else 0)
+        # a query of a layer under an indexer attends this many rows at
+        # most (0: no such layer); its step scores and streams a slot's
+        # live blocks and masks the rows not chosen (``ops/dsa.py``; the
+        # lax forms, where ``_stream_rows`` is None, every row of every
+        # slot)
+        self._index_topk = (int(cfg.index_topk)
+                            if "latent_dsa" in kinds else 0)
 
     # -- submission (PredictorServer-compatible surface) -------------------
     def submit(self, sample: Sequence[np.ndarray]):
@@ -1931,6 +2039,7 @@ class DecodeServer:
     _slab_readers = 0
     _has_tail = False
     _latent_row_bytes = 0
+    _index_topk = 0
     _kda_state_bytes_per_slot = 0
     _ssm_layers = 0
 
@@ -2063,7 +2172,13 @@ class DecodeServer:
         with state-space layers, ``ssm_tokens`` and ``ssm_pad_tokens``:
         the same two of each selective scan (the kernel skips the
         blocks of positions wholly past a row's length, the lax form
-        walks them all)."""
+        walks them all). Of a model with layers under an indexer,
+        ``index_pairs`` and ``chosen_pairs`` beside ``attn_pairs``: the
+        (query, key) pairs one such layer's indexer scored under the
+        causal mask, and those its attention kept (a query at position
+        t keeps ``min(t + 1, index_topk)``). Of a model with latent
+        layers over a window, ``window_pairs``: the pairs one such
+        layer attends (``min(t + 1, window)`` a query)."""
         counts = {"entries": len(self._spec),
                   "state_slots": n if self._state_bytes_per_slot else 0}
         if self._ring_window:
@@ -2080,6 +2195,11 @@ class DecodeServer:
             counts["prompts"] = len(prompts)
             counts["attn_pairs"] = sum(len(p) * (len(p) + 1) // 2
                                        for p in prompts)
+        if self._index_topk:
+            counts["index_pairs"] = counts["attn_pairs"]
+            counts["chosen_pairs"] = _kept_pairs(prompts, self._index_topk)
+        if self._ring_window and self._latent_row_bytes:
+            counts["window_pairs"] = _kept_pairs(prompts, self._ring_window)
         if self._kda_state_bytes_per_slot:
             counts["kda_tokens"] = sum(len(p) for p in prompts)
             counts["kda_pad_tokens"] = (int(bucket_rows)
@@ -2447,7 +2567,14 @@ class DecodeServer:
         many), and ``latent_row_bytes``, the bytes of one such row. Of a
         model with KDA layers, ``kda_state_bytes``: the bytes of the
         LIVE slots' matrix states, all such layers (a step reads and
-        writes each once: ``2 x`` this is its state traffic)."""
+        writes each once: ``2 x`` this is its state traffic). Of a
+        model with layers under an indexer, one such layer's
+        ``rows_live`` (= ``attended``), ``rows_scored``, the rows its
+        indexer's product ran over and its attention streamed (=
+        ``streamed``: the kernels read a slot's live blocks, the lax
+        forms every row of every slot), and ``rows_chosen``, the rows its
+        attention kept: each live slot's ``min(length + 1,
+        index_topk)``."""
         rows = self._stream_rows
         streamed = (self.slots * self.seq if rows is None
                     else int((lens // rows + 1).sum()) * rows)
@@ -2465,6 +2592,11 @@ class DecodeServer:
         if self._latent_row_bytes:
             counts["latent_rows"] = counts["attended"]
             counts["latent_row_bytes"] = self._latent_row_bytes
+        if self._index_topk:
+            counts["rows_live"] = counts["attended"]
+            counts["rows_scored"] = streamed
+            counts["rows_chosen"] = int(np.minimum(
+                lens[lens > 0] + 1, self._index_topk).sum())
         if self._kda_state_bytes_per_slot:
             counts["kda_state_bytes"] = (
                 n_active * self._kda_state_bytes_per_slot)

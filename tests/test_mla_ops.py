@@ -325,3 +325,298 @@ def test_latent_kernel_rule_is_the_slabs_shape_type_and_device(
                 jnp.zeros((1, shape[1], shape[2]), jnp.float32),
                 jnp.zeros((1, shape[0], shape[2]), shape[4]),
                 jnp.zeros((1,), jnp.int32), shape[3])
+
+
+# -- many heads, a group at a time; under a mask; over a window; a ring ---------
+# (a latent layer under an indexer and a latent layer over a window:
+# `tests/test_dots3_decode.py` has the model)
+
+def _grouped_inputs(seed, t=24, h=H):
+    r = np.random.default_rng(seed)
+
+    def m(*shape):
+        return jnp.asarray(r.normal(size=shape) * 0.3, jnp.float32)
+
+    return {"c_q": m(B, t, RQ), "rows": m(B, t, RK + DR),
+            "q_b": m(RQ, h * (DN + DR)), "kv_b": m(RK, h * (DN + DV)),
+            "gate": jax.nn.sigmoid(m(B, t, h)), "o": m(h * DV, D),
+            "mask": jnp.asarray(np.tril(r.random((B, t, t)) < 0.5)
+                                | np.eye(t, dtype=bool)[None], jnp.int8)}
+
+
+def _expanded(w, rot, a, seen, h=H):
+    """`mla_q`'s up-projection, `mla_expand`, softmax attention over the
+    keys `seen` (B | 1, T, T), the gate and the projection, all heads at
+    once."""
+    q = mla.mla_q(w["c_q"], None, None, w["q_b"], None, h, DR, 1e-6, rot)
+    k, v = mla.mla_expand(w["rows"], w["kv_b"], h, DN)
+    s = jnp.einsum("bthd,bshd->bhts", q, k) * a
+    p = jax.nn.softmax(jnp.where(seen[:, None], s, -jnp.inf), axis=-1)
+    ctx = jnp.einsum("bhts,bshd->bthd", p, v) * w["gate"][..., None]
+    return jnp.matmul(ctx.reshape(ctx.shape[:2] + (-1,)), w["o"])
+
+
+_GROUPED = [
+    # id, heads a pass, window, mask, through the kernel (interpreted)
+    ("causal-4-heads-at-once", 4, 0, False, False),
+    ("causal-2-heads-a-pass", 2, 0, False, False),
+    ("causal-3-does-not-divide-4", 3, 0, False, False),
+    ("window-5", 2, 5, False, False),
+    ("mask", 2, 0, True, False),
+    ("kernel-causal", 2, 0, False, True),
+    ("kernel-window-130", 2, 130, False, True),
+    ("kernel-mask", 2, 0, True, True),
+    ("kernel-mask-lengths", 2, 0, True, True, (300, 1024)),
+    ("kernel-window-130-lengths", 2, 130, False, True, (1, 700)),
+    ("lax-mask-lengths", 2, 0, True, False, (5, 24)),
+]
+
+
+@pytest.mark.parametrize("heads,window,masked,kernel,lengths",
+                         [(c[1:] + (None,))[:5] for c in _GROUPED],
+                         ids=[c[0] for c in _GROUPED])
+def test_latent_prefill_is_the_expanded_attention_a_group_at_a_time(
+        heads, window, masked, kernel, lengths):
+    """`latent_prefill` (q, k and v of `heads` heads at a time, the
+    group's rows of W_o added up) against all heads at once: causal,
+    over a window, under a (query, key) mask; by the lax form and
+    through the flash kernel (interpreted), its mask operand at 1,024
+    positions: q-blocks of 256 against two key blocks of 512. Given
+    the rows' `lengths`, every live row is what it was and the kernel
+    leaves the q-blocks wholly past a row's length zeros."""
+    t = (1024 if masked or lengths else 256) if kernel else 24
+    w = _grouped_inputs(heads + window, t)
+    rot = {"theta": 5e4, "interleave": True}
+    a = float(DN + DR) ** -0.5
+    at = np.arange(t)
+    seen = (at[None, :] <= at[:, None])[None]
+    if window:
+        seen = seen & (at[None, :] > at[:, None] - window)[None]
+    if masked:
+        seen = seen & (np.asarray(w["mask"]) != 0)
+    got = mla.latent_prefill(
+        w["c_q"], w["rows"], w["q_b"], w["kv_b"], w["gate"], w["o"], H, DN,
+        a, rot, window=window, mask=w["mask"] if masked else None,
+        lengths=None if lengths is None else jnp.asarray(lengths, jnp.int32),
+        heads=heads, interpret=kernel)
+    assert got.shape == (B, t, D)
+    with jax.default_matmul_precision("highest"):
+        want = _expanded(w, rot, a, jnp.asarray(seen))
+    if lengths is not None:
+        live = (at[None, :] < np.asarray(lengths)[:, None])[..., None]
+        if kernel:
+            q_rows = 256 if masked else 512  # a q-block of the kernel
+            dead = -(-np.asarray(lengths) // q_rows) * q_rows
+            for b_, d0 in enumerate(dead):
+                assert not np.asarray(got[b_, d0:]).any()
+                assert np.asarray(got[b_, :d0]).all()
+        got, want = got * live, want * live
+    if kernel:  # bfloat16 operands: a key wrongly seen or hidden is 1e-1
+        err = np.linalg.norm(got - want) / np.linalg.norm(want)
+        assert err < 1e-2, err
+        return
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+
+
+_CHOSEN_KERNEL = [
+    # id, slots' lengths, positions a slot, lanes a block, share chosen
+    ("one-block", (0, 1, 100, 128), 128, 128, 0.5),
+    ("blocks-of-128", (0, 130, 512, 257), 512, 128, 0.4),
+    ("blocks-of-256-few-chosen", (512, 3, 256, 300), 512, 256, 0.02),
+    ("all-chosen", (512, 511, 129, 0), 512, 128, 1.0),
+]
+
+
+@pytest.mark.parametrize("lens,s,lanes,share",
+                         [c[1:] for c in _CHOSEN_KERNEL],
+                         ids=[c[0] for c in _CHOSEN_KERNEL])
+def test_chosen_rows_kernel_is_the_lax_form_under_the_mask(lens, s, lanes,
+                                                           share):
+    """`pallas_chosen_attend` (interpreted: one pass over a slot's live
+    blocks, an online softmax, the rows not chosen masked inside)
+    against `_latent_attend_lax` under the same choice: slots of no live
+    row, of part of a block, of every block; a chosen row past a slot's
+    length is not seen; a slot that chose nothing gives zeros."""
+    r = np.random.default_rng(s + lanes)
+    h, row, rank = 16, 80, 64
+    q = jnp.asarray(r.normal(size=(len(lens), h, row)) * 0.3, jnp.float32)
+    slab = jnp.asarray(r.normal(size=(len(lens), s, row)), jnp.float32)
+    chosen = r.random((len(lens), s)) < share
+    chosen[-1, :] = share == 1.0  # the last slot chose nothing, or all
+    lens = jnp.asarray(lens, jnp.int32)
+    want = mla._latent_attend_lax(q, slab, lens, rank, jnp.asarray(chosen))
+    got = mla.pallas_chosen_attend(q, slab, lens, jnp.asarray(chosen), rank,
+                                   "ptpu.test_step", block_s=lanes,
+                                   interpret=True)
+    assert got.shape == want.shape == (len(lens), h, rank)
+    for i in range(len(lens)):
+        if not (chosen[i, :int(lens[i])]).any():
+            assert not np.asarray(got[i]).any()
+    # bfloat16 operands, as the TPU's default precision rounds the lax form's
+    err = np.linalg.norm(got - want) / np.linalg.norm(want)
+    assert err < 1e-2, err
+
+
+def test_chosen_rows_kernel_rule(monkeypatch):
+    """Under a choice the absorbed attention of ANY number of heads has
+    a kernel wherever the slab's layout has one (no scores wait between
+    passes: the two-pass rule refuses 128 heads on 16,384 positions),
+    and `mla_decode` takes it on the device that has it."""
+    shape = (16384, 128, 576, 512, "float32")
+    assert DS.block_positions(mla.latent_view(*shape)) is None
+    assert DS.block_positions(mla.chosen_view(*shape)) == 1024
+    assert DS.block_positions(mla.chosen_view(513, 64, 1088, 1024,
+                                               "float32")) is None
+    assert KV.decode_stream_rows(mla.chosen_view(*shape)) is None  # the CPU
+    with pytest.raises(ValueError, match="no kernel for a slab"):
+        mla.pallas_chosen_attend(
+            jnp.zeros((1, 4, 80)), jnp.zeros((1, 192, 80)),
+            jnp.zeros((1,), jnp.int32), jnp.zeros((1, 192), bool), 64, "x")
+
+
+@pytest.mark.parametrize("lens,lanes", [((0, 130, 512), 128),
+                                        ((512, 1, 256), 256),
+                                        ((3, 300, 0), 512)])
+def test_step_scores_kernel_is_the_lax_products(lens, lanes):
+    """`pallas_step_scores` (interpreted: a slot's live blocks of index
+    keys read once, all heads' products of a block at once) against
+    `index_scores` of one query row a slot, on the live blocks; zeros
+    past them."""
+    from paddle_tpu.ops import dsa
+
+    r = np.random.default_rng(lanes)
+    b, s, j, d = len(lens), 512, 8, 128
+    q_i = jnp.asarray(r.normal(size=(b, 1, j, d)), jnp.float32)
+    w = jnp.asarray(r.normal(size=(b, 1, j)), jnp.float32)
+    keys = jnp.asarray(r.normal(size=(b, s, d)), jnp.float32)
+    want = np.asarray(dsa.index_scores(q_i, w, keys)[:, 0])
+    got = np.asarray(dsa.pallas_step_scores(
+        q_i[:, 0], w[:, 0], keys, jnp.asarray(lens, jnp.int32),
+        block_s=lanes, interpret=True))
+    assert got.shape == (b, s)
+    for i, n in enumerate(lens):
+        live = -(-n // lanes) * lanes
+        assert not got[i, live:].any()
+        if live:  # bfloat16 operands, float32 sums
+            np.testing.assert_allclose(got[i, :live], want[i, :live],
+                                       atol=0.01 * np.abs(want[i]).max())
+    assert dsa.step_block(16384, 128, "float32") is None  # the CPU
+
+
+@pytest.mark.parametrize("lengths", [(40,), (64,), (1,), (17, 33)])
+def test_prefill_mask_leaves_the_rows_past_the_longest_prompt(lengths):
+    """`prefill_mask` given the prompts' lengths: the blocks of query
+    rows that hold a live row of any prompt are what they are without
+    the lengths, the blocks past the longest prompt stay 0."""
+    from paddle_tpu.ops import dsa
+
+    r = np.random.default_rng(sum(lengths))
+    b, t, j, d, rows, k = len(lengths), 64, 4, 8, 16, 12
+    q_i = jnp.asarray(r.normal(size=(b, t, j, d)), jnp.float32)
+    w = jnp.asarray(r.normal(size=(b, t, j)), jnp.float32)
+    k_i = jnp.asarray(r.normal(size=(b, t, d)), jnp.float32)
+    whole = np.asarray(dsa.prefill_mask(q_i, w, k_i, k, rows=rows))
+    got = np.asarray(dsa.prefill_mask(
+        q_i, w, k_i, k, jnp.asarray(lengths, jnp.int32), rows=rows))
+    live = -(-max(lengths) // rows) * rows
+    np.testing.assert_array_equal(got[:, :live], whole[:, :live])
+    assert not got[:, live:].any()
+    assert whole[:, -1].sum() == b * k
+
+
+@pytest.mark.parametrize("case", ["chosen", "ring-not-full", "ring-wrapped"])
+def test_absorbed_attention_under_a_choice_and_over_a_ring(case):
+    """`mla_decode` over the CHOSEN rows of a slab == the expanded
+    attention over those rows; over a RING (the last rows at position
+    mod window, `mla_append(ring=True)`) == over the last `window`
+    rows, whatever their order."""
+    r = np.random.default_rng(len(case))
+    w = _grouped_inputs(3, T)
+    rot = {"theta": 5e4, "interleave": True}
+    a = float(DN + DR) ** -0.5
+    q = mla.mla_q(w["c_q"], None, None, w["q_b"], None, H, DR, 1e-6, rot)
+    k, v = mla.mla_expand(w["rows"], w["kv_b"], H, DN)
+    at = np.arange(T)
+    if case == "chosen":
+        t = T - 2
+        chosen = r.random((B, 16)) < 0.5
+        chosen[:, t] = True
+        slab = jnp.zeros((B, 16, RK + DR)).at[:, :T].set(w["rows"])
+        got = mla.mla_decode(q[:, t:t + 1], slab, jnp.asarray([t + 1] * B),
+                             w["kv_b"], a, chosen=jnp.asarray(chosen))
+        seen = chosen[:, :T] & (at <= t)[None]
+    else:
+        window, t = 5, (3 if case == "ring-not-full" else T - 1)
+        ring = jnp.full((B, window, RK + DR), 1e6, jnp.float32)
+        for p in range(t + 1):
+            ring = mla.mla_append(ring, w["rows"][:, p:p + 1],
+                                  jnp.asarray([p] * B), ring=True)
+        for p in range(max(t + 1 - window, 0), t + 1):
+            np.testing.assert_array_equal(ring[:, p % window],
+                                          w["rows"][:, p])
+        got = mla.mla_decode(q[:, t:t + 1], ring, jnp.asarray([t + 1] * B),
+                             w["kv_b"], a)
+        seen = np.broadcast_to((at <= t) & (at > t - window), (B, T))
+    s = jnp.einsum("bhd,bshd->bhs", q[:, t], k) * a
+    p = jax.nn.softmax(jnp.where(jnp.asarray(seen)[:, None], s, -jnp.inf),
+                       axis=-1)
+    want = jnp.einsum("bhs,bshd->bhd", p, v)
+    np.testing.assert_allclose(got[:, 0], want, rtol=2e-5, atol=2e-6)
+
+
+def test_rescaled_latent_rows_and_the_new_ops_through_the_layers_api():
+    """`mla_kv(rescale=)` multiplies the normalised latent alone; and
+    `latent_prefill`, `dsa_index_keys`, `dsa_mask` in a Program against
+    the functions."""
+    from paddle_tpu.ops import dsa
+
+    w = _weights(5)
+    rot = {"theta": 5e4, "interleave": True}
+    plain = mla.mla_kv(w["u"], w["kv_a"], w["g_kv"], None, DR, 1e-6, rot)
+    scaled = mla.mla_kv(w["u"], w["kv_a"], w["g_kv"], None, DR, 1e-6, rot,
+                        rescale=2.0)
+    np.testing.assert_allclose(scaled[..., :RK], 2.0 * plain[..., :RK],
+                               rtol=1e-6)
+    np.testing.assert_array_equal(scaled[..., RK:], plain[..., RK:])
+    g = _grouped_inputs(9, T)
+    r = np.random.default_rng(1)
+    j, di, topk = 2, 8, 4
+    irot = {"theta": 1e4, "rotary_dim": 4}
+    feed = {"u": np.asarray(w["u"]), "c_q": np.asarray(g["c_q"]),
+            "rows": np.asarray(g["rows"]), "q_b": np.asarray(g["q_b"]),
+            "kv_b": np.asarray(g["kv_b"]), "gate": np.asarray(g["gate"]),
+            "o": np.asarray(g["o"]),
+            "w_ik": r.normal(size=(D, di)).astype(np.float32),
+            "gain": np.ones((di,), np.float32),
+            "bias": np.zeros((di,), np.float32),
+            "w_iq": r.normal(size=(RQ, j * di)).astype(np.float32),
+            "w_iw": r.normal(size=(D, j)).astype(np.float32)}
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        v = {n: layers.data(name=n, shape=list(a.shape), dtype=str(a.dtype),
+                            append_batch_size=False)
+             for n, a in feed.items()}
+        keys = layers.dsa_index_keys(v["u"], v["w_ik"], v["gain"], v["bias"],
+                                     irot)
+        mask = layers.dsa_mask(v["c_q"], v["u"], v["w_iq"], v["w_iw"], keys,
+                               j, topk, irot)
+        out = layers.latent_prefill(
+            v["c_q"], v["rows"], v["q_b"], v["kv_b"], v["o"], H, DN, 0.3, rot,
+            gate=v["gate"], mask=mask, scope="ptpu.dsa_attend")
+        assert tuple(keys.shape) == (B, T, di)
+        assert tuple(mask.shape) == (B, T, T)
+        assert tuple(out.shape) == (B, T, D)
+    got = fluid.Executor(fluid.CPUPlace()).run(
+        main, feed=feed, fetch_list=[keys, mask, out])
+    want_keys = dsa.index_keys(w["u"], feed["w_ik"], feed["gain"],
+                               feed["bias"], None, 1e-5, irot)
+    q_i, iw = dsa.index_queries(g["c_q"], w["u"], feed["w_iq"], feed["w_iw"],
+                                None, j, irot)
+    want_mask = dsa.prefill_mask(q_i, iw, want_keys, topk)
+    np.testing.assert_allclose(got[0], want_keys, rtol=1e-5, atol=1e-6)
+    np.testing.assert_array_equal(got[1], want_mask)
+    assert (np.asarray(got[1]).sum(-1)
+            == np.minimum(np.arange(T) + 1, topk)[None]).all()
+    np.testing.assert_allclose(got[2], mla.latent_prefill(
+        g["c_q"], g["rows"], g["q_b"], g["kv_b"], g["gate"], g["o"], H, DN,
+        0.3, rot, mask=want_mask), rtol=1e-5, atol=1e-6)

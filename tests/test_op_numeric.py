@@ -675,6 +675,10 @@ COVERED_ELSEWHERE = {
     # against the recurrence a token at a time and one step after
     # another, at the gate's bound, with padding; both gates)
     "kda_gate", "kda_scan", "kda_step",
+    # many heads a group at a time, the learned indexer's keys and
+    # choice: tests/test_mla_ops.py (the grouped cases) and
+    # tests/test_dots3_decode.py (select against lax.top_k)
+    "latent_prefill", "dsa_index_keys", "dsa_mask",
     # in-graph sampling: tests/test_sampling_ops.py
     "greedy_sample", "top_k_sample", "top_p_sample",
     # metrics: tests/test_aux.py

@@ -355,6 +355,33 @@ def test_padding_and_free_slots_route_nowhere(layer):
     assert float(jnp.abs(out[3:5]).max()) == 0.0
 
 
+@pytest.mark.parametrize("n,blk,most,dead", [
+    (64, 32, 4, 0), (64, 32, 4, 9), (8, 16, 8, 3), (40, 8, 1, 2)])
+def test_add_by_token_sums_a_tokens_rows_without_a_scatter(n, blk, most,
+                                                           dead):
+    """``_add_by_token`` against a scatter-add: a token's rows (up to
+    ``most``, side by side or apart) summed, rows of no token (index N,
+    with whatever a grouped product left there) not read."""
+    r = np.random.default_rng(n + blk)
+    live = blk - dead
+    if most == 1:
+        rows = r.choice(n, live, replace=False)
+    else:
+        # every token at most ``most`` times, token 0 exactly that often
+        pool = np.repeat(np.arange(1, n), most)
+        rows = r.permutation(np.concatenate(
+            [np.zeros(most, np.int64), r.permutation(pool)[:live - most]]))
+    y = r.normal(size=(blk, 5)).astype(np.float32)
+    out = r.normal(size=(n, 5)).astype(np.float32)
+    want = out.copy()
+    np.add.at(want, rows, y[:live])
+    y[live:] = np.nan
+    rows = np.concatenate([rows, np.full(dead, n)]).astype(np.int32)
+    got = moe._add_by_token(jnp.asarray(out), jnp.asarray(rows),
+                            jnp.asarray(y), most)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+
 # -- window attention and the ring --------------------------------------------
 
 def _naive_window(q, k, v, window):

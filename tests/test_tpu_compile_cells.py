@@ -1,6 +1,8 @@
 """Compile the serving steps of the Laguna, Phi-4-mini-flash,
-Mistral-Small-4 and Ling-3.0-flash cells for a DESCRIBED TPU v5e (`tpu_compile_lib.py`): the cells' own programs, from
-their own configuration files. See `test_tpu_compile.py` for what such a
+Mistral-Small-4 and Ling-3.0-flash cells for a DESCRIBED TPU v5e
+(`tpu_compile_lib.py`): the cells' own programs, from their own
+configuration files (the dots3-note-prev pair is in
+`test_tpu_compile_serving.py`: no file of the four over 240 s). See `test_tpu_compile.py` for what such a
 compile can and cannot say.
 """
 from __future__ import annotations

@@ -1,12 +1,17 @@
 """Compile the serving steps of the OPT-family model and of the hybrid
 for a DESCRIBED TPU v5e (`tpu_compile_lib.py`): the programs
 `DecodePredictor` builds, at the widths the chip and the benchmark's
-cells run them at. See `test_tpu_compile.py` for what such a compile can
+cells run them at; and two cells' own programs that
+`test_tpu_compile_cells.py` has no room for (Ling's widest admission,
+the dots3-note-prev pair). See `test_tpu_compile.py` for what such a compile can
 and cannot say.
 """
 from __future__ import annotations
 
+import re
+
 import jax
+import numpy as np
 import pytest
 
 from paddle_tpu.ops import kv_cache as KV
@@ -226,3 +231,90 @@ def test_ling3_prefill_holds_one_scan_kernel_a_kda_layer(one_chip,
     for ln in scans:
         assert "f32[8,32,128,128]" in ln.split(" custom-call(")[0], ln[:400]
     assert mem.temp_size_in_bytes < 3.3 * 2**30, mem.temp_size_in_bytes
+
+
+_DOTS3_CASES = [
+    # id, kind, batch, seq: the dots3-note-prev serving cell's own
+    # programs (benchmark/configs/dots3-note-prev.json: 5 layers at
+    # published widths, 8 of 256 experts held, 32 slots of 16,384
+    # positions; the largest admission is one prompt of the 16,384 bucket)
+    ("decode-32x16384", "decode", 32, 16384),
+    ("prefill-1x16384", "prefill", 1, 16384),
+]
+
+
+@pytest.mark.parametrize("kind,batch,seq", [c[1:] for c in _DOTS3_CASES],
+                         ids=[c[0] for c in _DOTS3_CASES])
+def test_dots3_serving_step_compiles(one_chip, monkeypatch, kind, batch,
+                                     seq):
+    """The programs DecodePredictor builds for the dots3-note-prev cell
+    (two full layers: 128 heads of 128 + 64 query/key and 128 value
+    channels over a latent row of 576 floats, under an indexer of 64
+    heads of 128 that keeps 2,048 rows; three sliding layers: 64 heads
+    of 192 + 64 and 128 over a ring of 513 rows of 1,088; a sigmoid
+    router with a bias over 256 experts of width 1,536, 8 held; an
+    untied head over 19,008 ids): they compile for a v5e and fit it
+    beside each other. The largest admission holds the flash calls of
+    16 heads at a time (two under the indexer's int8 mask, three over
+    the window) and NOTHING of all 128 heads' q, k or v, nor a (64, T,
+    T) array of per-head index products, nor a sort; the step donates
+    its seven cache entries and holds no copy of a slab of 16,384
+    positions."""
+    from test_tpu_compile_cells import _cell_predictor
+
+    pred = _cell_predictor("dots3_lm", "dots3-note-prev.json", monkeypatch)
+    step_fn, feeds, state, n_cache = _serving_step(pred, kind, batch, seq,
+                                                   one_chip)
+    compiled = jax.jit(step_fn, donate_argnums=(0,)).lower(
+        feeds, state).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < HBM_BYTES, "does not fit one chip: %r" % (mem,)
+    weights = sum(int(np.prod(s.shape)) * 4 for s in state.values())
+    assert 7.28e9 < weights < 7.30e9, weights  # 1.822 B parameters
+    text = compiled.as_text()
+    spec = pred.cache_spec(32, 16384)
+    slabs = sum(e.nbytes for e in spec)
+    assert round(slabs / 1e9, 2) == 3.17
+    calls = re.findall(r"%([\w.-]+?)(?:\.\d+)? = [^\n]*"
+                       r'custom_call_target="tpu_custom_call"', text)
+    # the choice of 2,048 is no sort (the router's top-8 and the routed
+    # product's pairs are)
+    sorts = [ln for ln in text.splitlines() if " sort(" in ln]
+    assert sorts and all("ptpu.moe_" in ln for ln in sorts)
+    if kind == "prefill":
+        # one call a layer, inside the loop over groups of 16 heads
+        assert calls.count("ptpu.dsa_attend") == 2, calls
+        assert calls.count("ptpu.latent_ring_attend") == 3, calls
+        assert calls.count("ragged-dot-none") == 3 * 4
+        # 16 heads padded to 256 channels, never all 128 (or 64); v at
+        # its own 128
+        assert "bf16[1,16384,4096]" in text  # the kernel's operands
+        assert "bf16[1,16384,2048]" in text
+        assert "bf16[1,16384,32768]" not in text
+        assert "f32[1,16384,128,192]" not in text
+        assert "s8[1,32,16384,512]" in text  # the mask, a key block major
+        assert "f32[1,64,16384,16384]" not in text
+        # beside the weights, the slabs and rings and the step
+        assert weights + slabs + mem.temp_size_in_bytes + (
+            mem.output_size_in_bytes) < 14.5 * 2**30, mem
+        assert mem.temp_size_in_bytes < 3.0 * 2**30, mem.temp_size_in_bytes
+        return
+    # the attention under the choice: one kernel a full layer, over the
+    # transposed view of the latent slab where it lies
+    assert [c for c in calls if c.startswith("ptpu.")] == [
+        "ptpu.dsa_index_step", "ptpu.dsa_attend_step"] * 2, calls
+    assert "f32[32,128,16384]" not in text  # no scores of every row
+    assert "bf16[32,16384,128]" not in text  # no rounded copy of the keys
+    assert n_cache == len(spec) == 7
+    assert mem.alias_size_in_bytes >= slabs
+    # (a ring of 71 MB is another matter: the compiler moves each into
+    # its faster memory space for the step's fusions, a copy a layer)
+    for shape in ((32, 16384, 576), (32, 16384, 128)):
+        moved = [name for op, name, changed in _whole_slab_ops(text, shape)
+                 if op == "copy"]
+        assert not moved, (shape, moved)
+    # no expanded K or V: nothing of slots x positions x heads
+    assert "f32[32,16384,128," not in text
+    assert mem.temp_size_in_bytes < 400 * 2**20, mem.temp_size_in_bytes

@@ -45,6 +45,8 @@ CELLS = [
      [("decode", 32, 16384), ("prefill", 2, 2048)]),
     ("ling3_lm", "ling-3.0-flash.json",
      [("decode", 64, 16384), ("prefill", 2, 2048)]),
+    ("dots3_lm", "dots3-note-prev.json",
+     [("decode", 32, 16384), ("prefill", 4, 4096)]),
 ]
 
 
@@ -115,6 +117,10 @@ def main(argv=None):
         os.makedirs(out_text, exist_ok=True)
     table = {}
     for model, config, programs in CELLS:
+        if not os.path.exists(os.path.join(root, "benchmark", "configs",
+                                           config)):
+            print("%-38s absent from this tree" % config[:-5], flush=True)
+            continue
         for kind, batch, seq in programs:
             name = "%s %s %dx%d" % (config[:-5], kind, batch, seq)
             pred = _cell_predictor(model, config, _STEER)
