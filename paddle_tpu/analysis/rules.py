@@ -1218,13 +1218,21 @@ def _grouped_heads(ctx: InferContext, slots, axes):
     return VarInfo(qs, q.dtype)
 
 
-@register_infer("attn_window")
-def _infer_attn_window(ctx: InferContext):
-    """Q (B, T, H, Dh) x K/V (B, T, Hkv, Dh) -> Out = Q's shape."""
-    if int(ctx.attr("window", 0)) < 1:
-        raise InferError("window must be >= 1, got %r"
+@register_infer("prefill_attention")
+def _infer_prefill_attention(ctx: InferContext):
+    """Q (B, T, H, dq) x K (B, T, Hkv, dq), V (B, T, Hkv, dv) -> Out =
+    Q's shape at V's width."""
+    if int(ctx.attr("window", 0) or 0) < 0:
+        raise InferError("window must be >= 0, got %r"
                          % ctx.attr("window", None))
-    return {"Out": _grouped_heads(ctx, ("K", "V"), (0, 2, 3))}
+    out = _grouped_heads(ctx, ("K",), (0, 2, 3))
+    k, v = ctx.in_shape("K"), ctx.in_shape("V")
+    if out.shape is None or v is None:
+        return {"Out": out}
+    if len(v) != 4 or (k is not None and tuple(v[:3]) != tuple(k[:3])):
+        raise InferError("V%s does not hold K%s's rows and heads"
+                         % (render_shape(v), render_shape(k)))
+    return {"Out": VarInfo(tuple(out.shape[:-1]) + (v[-1],), out.dtype)}
 
 
 @register_infer("decode_attn_ring")

@@ -128,7 +128,7 @@ __all__ = [
     "moe_route",
     "moe_experts",
     "moe_shared",
-    "attn_window",
+    "prefill_attention",
     "ring_append",
     "ring_pack",
     "decode_attn_ring",
@@ -2658,16 +2658,30 @@ def moe_shared(x, w_gate, w_up, w_down, name=None):
     return out
 
 
-def attn_window(q, k, v, window, scale=None, name=None):
-    """Causal prefill attention of a sliding-window layer: q (B, T, H,
-    Dh), k/v (B, T, Hkv, Dh); a query sees the last ``window`` keys up
-    to its own."""
-    helper = LayerHelper("attn_window", name=name)
-    out = helper.create_variable_for_type_inference(q.dtype, shape=q.shape)
+def _qkv_inputs(q, k, v, lengths):
+    """The inputs of a prefill's attention op: Q, K, V and, where the
+    rows' live tokens are known, Lengths."""
+    inputs = {"Q": [q], "K": [k], "V": [v]}
+    if lengths is not None:
+        inputs["Lengths"] = [lengths]
+    return inputs
+
+
+def prefill_attention(q, k, v, lengths=None, window=0, scale=None,
+                      name=None):
+    """Causal attention of a serving prefill, forward only
+    (``ops/attention.py: prefill_attention``): q (B, T, H, dq), k (B, T,
+    Hkv, dq), v (B, T, Hkv, dv) -> (B, T, H, dv); with ``window`` a
+    query sees the last ``window`` keys up to its own; ``lengths`` (B,)
+    the rows' live tokens, for the kernel to skip what lies past
+    them."""
+    helper = LayerHelper("prefill_attention", name=name)
+    out = helper.create_variable_for_type_inference(
+        q.dtype, shape=tuple(q.shape[:-1]) + (v.shape[-1],))
     helper.append_op(
-        type="attn_window", inputs={"Q": [q], "K": [k], "V": [v]},
+        type="prefill_attention", inputs=_qkv_inputs(q, k, v, lengths),
         outputs={"Out": [out]},
-        attrs={"window": int(window), "scale": scale})
+        attrs={"window": int(window or 0), "scale": scale})
     return out
 
 
@@ -2725,14 +2739,15 @@ def _diff_inputs(lambdas, gain):
 
 
 def diff_attention(q, k, v, lambdas, gain, lam_init, window=0,
-                   epsilon=1e-5, name=None):
+                   epsilon=1e-5, lengths=None, name=None):
     """Differential attention of a prefill (ops/diff_attn.py): q (B, T,
     H, dh), k/v (B, T, Hkv dh) flat key/value rows, ``lambdas`` the
     layer's four vectors (lq1, lk1, lq2, lk2) of dh, ``gain`` (2 dh,);
-    causal, within ``window`` where given. -> (B, T, H / 2, 2 dh)."""
+    causal, within ``window`` where given; ``lengths`` (B,) the rows'
+    live tokens. -> (B, T, H / 2, 2 dh)."""
     helper = LayerHelper("diff_attention", name=name)
     out = _diff_out(helper, q)
-    inputs = {"Q": [q], "K": [k], "V": [v]}
+    inputs = _qkv_inputs(q, k, v, lengths)
     inputs.update(_diff_inputs(lambdas, gain))
     helper.append_op(
         type="diff_attention", inputs=inputs, outputs={"Out": [out]},
@@ -2853,14 +2868,13 @@ def mla_expand(rows, w_b, n_head, nope_dim, name=None):
     return k, v
 
 
-def mla_attend(q, k, v, scale, name=None):
-    """The expanded path's causal attention where the query/key head
-    width is not the value head's: q, k (B, T, H, dq), v (B, T, H, dv)
-    -> (B, T, H, dv) (``ops/mla.py: mla_attend``)."""
+def mla_attend(q, k, v, scale, lengths=None, name=None):
+    """The expanded path's causal attention: q, k (B, T, H, dq), v (B,
+    T, H, dv), dq == dv or not -> (B, T, H, dv); ``lengths`` (B,) the
+    rows' live tokens (``ops/mla.py: mla_attend``)."""
     helper = LayerHelper("mla_attend", name=name)
     out = helper.create_variable_for_type_inference(v.dtype, shape=v.shape)
-    helper.append_op(type="mla_attend",
-                     inputs={"Q": [q], "K": [k], "V": [v]},
+    helper.append_op(type="mla_attend", inputs=_qkv_inputs(q, k, v, lengths),
                      outputs={"Out": [out]}, attrs={"scale": float(scale)})
     return out
 

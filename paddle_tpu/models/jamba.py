@@ -228,10 +228,12 @@ def _attention_mixer(u, cfg, name, lengths, cache, i=0, kind="attention"):
     """Layer ``i``'s query heads on ``n_kv_head`` key/value heads, no
     bias; rotary positions where ``cfg.rope`` names this layer kind
     (a prefill rotates row t at t, a decode step its one row at
-    ``lengths``). ``cache`` is None (prefill: causal flash attention,
-    or ``attn_window`` on a sliding layer; the entries are this
-    prompt's k and v, packed into a ring on a sliding layer) or the
-    layer's two entries (one token: append at ``lengths``, or at
+    ``lengths``). ``cache`` is None (prefill: the forward-only
+    ``prefill_attention`` at the rows' ``lengths``, within the window
+    on a sliding layer: on a TPU the flash forward on bfloat16 operands,
+    ``ptpu.flash_fwd`` or ``ptpu.attn_window`` in a trace; the entries
+    are this prompt's k and v, packed into a ring on a sliding layer) or
+    the layer's two entries (one token: append at ``lengths``, or at
     ``lengths mod window`` into a ring, and attend). A per-head sigmoid
     gate from the layer's input scales the attention output where
     ``cfg.attn_gate`` asks. Under ``cfg.diff_attn`` the attention is
@@ -256,16 +258,14 @@ def _attention_mixer(u, cfg, name, lengths, cache, i=0, kind="attention"):
         q = layers.rope(q, at, **rot)
         k = layers.rope(k, at, **rot)
     if cache is None:
+        window = cfg.window if sliding else 0
         if diff:
             ctx = layers.diff_attention(
-                q, k, v, *diff, lam_init=lam0,
-                window=cfg.window if sliding else 0, epsilon=cfg.norm_eps)
-        elif sliding:
-            ctx = layers.attn_window(q, k, v, cfg.window)
+                q, k, v, *diff, lam_init=lam0, window=window,
+                epsilon=cfg.norm_eps, lengths=lengths)
         else:
             # the op repeats k and v for the query heads that share them
-            ctx = layers.fused_attention(q, k, v, causal=True,
-                                         layout="bthd")
+            ctx = layers.prefill_attention(q, k, v, lengths, window=window)
         if sliding:
             k = layers.ring_pack(k, lengths, cfg.window)
             v = layers.ring_pack(v, lengths, cfg.window)
@@ -293,11 +293,14 @@ def _attention_mixer(u, cfg, name, lengths, cache, i=0, kind="attention"):
 def _latent_mixer(u, cfg, name, lengths, cache):
     """Multi-head latent attention (``ops/mla.py``). ``cache`` is None
     (prefill: the EXPANDED path, K and V of every head from the latent
-    rows, the causal flash kernel at ``cfg.softmax_scale``; the entry
-    is the prompt's latent rows) or the layer's one entry (one token:
-    append its row at ``lengths``, then the ABSORBED path over the
-    slab). ``W_kvb`` is one parameter for both. Returns (out, (latent
-    rows or slab,))."""
+    rows, then ``mla_attend`` at ``cfg.softmax_scale``: the serving
+    prefills' one entry ``prefill_attention`` at the rows' ``lengths``,
+    whose flash kernel takes V at its own width, so one call serves a
+    query/key head as wide as the value head (128 / 128) and a wider
+    one (192 / 128); the entry is the prompt's latent rows) or the
+    layer's one entry (one token: append its row at ``lengths``, then
+    the ABSORBED path over the slab). ``W_kvb`` is one parameter for
+    both. Returns (out, (latent rows or slab,))."""
     B, T, _ = u.shape
     h, d = cfg.n_head, cfg.d_model
     nope, rdim, vdim = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
@@ -326,11 +329,7 @@ def _latent_mixer(u, cfg, name, lengths, cache):
                    w)
     if cache is None:
         k, v = layers.mla_expand(rows, w_kvb, h, nope)
-        if nope + rdim == vdim:
-            ctx = layers.fused_attention(q, k, v, causal=True, scale=scale,
-                                         layout="bthd")
-        else:  # a query/key head wider than the value head
-            ctx = layers.mla_attend(q, k, v, scale)
+        ctx = layers.mla_attend(q, k, v, scale, lengths)
     else:
         rows = layers.mla_append(cache[0], rows, lengths)
         kv_lengths = layers.elementwise_add(
@@ -499,7 +498,7 @@ def _cross_mixer(u, cfg, name, i, kv):
     else:
         ctx = layers.diff_attention(q, k, v, *diff,
                                     lam_init=_D.lambda_init(i),
-                                    epsilon=cfg.norm_eps)
+                                    epsilon=cfg.norm_eps, lengths=seen)
     return _proj(layers.reshape(ctx, shape=[B, T, h * dh]), cfg.d_model,
                  name + ".o", bias)
 

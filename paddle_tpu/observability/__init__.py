@@ -61,7 +61,7 @@ __all__ = [
     "TRANSPILE_OPS_REMOVED", "TRANSPILE_OPS_FUSED", "TRANSPILE_PASS_MS",
     "QUANT_CALIB_BATCHES", "QUANT_OPS", "QUANT_PARITY",
     "FUSED_HEAD_TRACES", "MLA_TRACES", "SSM_SCAN_TRACES", "KDA_SCAN_TRACES",
-    "MOE_TOKENS_ELSEWHERE",
+    "PREFILL_ATTN_TRACES", "MOE_TOKENS_ELSEWHERE",
 ]
 
 # -- the shared instrument set (registered once, process-wide) -----------
@@ -109,6 +109,16 @@ KDA_SCAN_TRACES = REGISTRY.counter(
     "over every chunk of the bucket: the CPU, a gate with no lower "
     "bound, a shape the kernel does not take). Counted when the op is "
     "traced: a program loaded from a cache adds 0")
+PREFILL_ATTN_TRACES = REGISTRY.counter(
+    "paddle_tpu_prefill_attn_traces_total",
+    "Traces of a serving prefill's causal attention (ops/attention.py: "
+    "prefill_attention), by path=kernel (the flash forward: a TPU, a "
+    "block-aligned bucket of 256 rows and up) | lax (the exact form "
+    "over (T, T) scores), operands=the type the products' operands "
+    "have (bfloat16 on the kernel path whatever came in, else the "
+    "caller's) and lengths=given (the kernel skips the q-blocks past a "
+    "row's length) | none. Counted when the op is traced: a program "
+    "loaded from a cache adds 0")
 CACHE_HITS = REGISTRY.counter(
     "paddle_tpu_compile_cache_hits_total",
     "Compile-cache hits, by kind, program fingerprint, and "

@@ -39,6 +39,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..framework.scope import current_device
 from ..framework.trace import current_trace_mesh, current_trace_plan
+from ..observability import PREFILL_ATTN_TRACES
 from .registry import register_op
 
 _NEG = -1e30
@@ -225,8 +226,11 @@ def _mha_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
     entry is 0 gets _NEG (``_mha_fwd_masked_kernel``). ``len_ref`` (B,)
     int32, scalar-prefetched (``_mha_fwd_lens_kernel``; the BTHD grid,
     whose first index is the batch row): a q-block wholly past its
-    row's length computes nothing and writes zeros. V's width may be
-    another than q's and K's; the output has V's."""
+    row's length computes nothing and writes zeros: rows of padding,
+    which no one reads (the callers are causal and pad a row at its
+    END, so no live row sees a padding row's key, and what a prefill
+    keeps of a row ends at its length). V's width may be another than
+    q's and K's; the output has V's."""
     qi = pl.program_id(pid_axis)
     if len_ref is not None:
         live = qi * block_q < len_ref[pl.program_id(0)]
@@ -622,7 +626,8 @@ def _lse_spec_bthd(h, t):
 
 
 def _mha_fwd_call_bthd(qs, k, v, h, causal, block_q, block_k, interpret,
-                       window=0, name=None, mask=None, lengths=None):
+                       window=0, name=None, mask=None, lengths=None,
+                       out_dtype=None):
     """``mask`` (B, T, tk) int8, the same for every head: 1 where the
     query attends the key (with ``causal``, which still bounds the key
     blocks a q-block walks: a mask here only ever takes keys away). The
@@ -633,7 +638,7 @@ def _mha_fwd_call_bthd(qs, k, v, h, causal, block_q, block_k, interpret,
     fetch no tile of the mask (a prefill's bucket is a power of two and
     the prompt in it on average two thirds of that: half the causal
     pairs). V (B, tk, h * dv) may be of another width than q and K; the
-    output is (B, T, h * dv)."""
+    output is (B, T, h * dv), in ``out_dtype`` (q's where not given)."""
     b, t, hd = qs.shape
     tk = k.shape[1]
     d, dv = hd // h, v.shape[2] // h
@@ -682,7 +687,7 @@ def _mha_fwd_call_bthd(qs, k, v, h, causal, block_q, block_k, interpret,
     return named_pallas_call(
         name or FLASH_FWD, kernel,
         out_shape=[
-            jax.ShapeDtypeStruct((b, t, h * dv), qs.dtype),
+            jax.ShapeDtypeStruct((b, t, h * dv), out_dtype or qs.dtype),
             jax.ShapeDtypeStruct((b * h, 1, t), jnp.float32),
         ],
         interpret=interpret,
@@ -934,13 +939,6 @@ def _attention_bthd(q, k, v, lengths, causal, scale, dropout_rate, block_k,
     return jnp.swapaxes(out, 1, 2)
 
 
-def causal_attention_bthd(q, k, v, scale=None, block_k=512):
-    """Causal attention of a prefill over (B, T, H, Dh) queries and (B,
-    T, Hkv, Dh) keys and values, as ``fused_attention`` dispatches it
-    at ``layout="bthd"``: for the ops that build on it."""
-    return _attention_bthd(q, k, v, None, True, scale, 0.0, block_k, None)
-
-
 def _attention_bhtd(q, k, v, lengths, causal, scale, dropout_rate, block_k,
                     rng):
     """The (B, H, T, Dh) dispatch: Pallas fwd+bwd kernels when eligible,
@@ -1031,11 +1029,13 @@ def _use_pallas(t, tk, lengths, dropout_rate) -> bool:
 ATTN_WINDOW = "ptpu.attn_window"
 
 
-def attn_window_reference(q, k, v, window, scale=None):
-    """Causal attention over a sliding window, exact, pure lax: q (B, T,
-    H, Dh), k/v (B, T, Hkv, Dh) with H = g * Hkv; key j is visible to
-    query t iff t - window < j <= t. Builds the (T, T) scores: the CPU
-    path and the numeric reference of the kernel."""
+def prefill_attention_reference(q, k, v, window=0, scale=None):
+    """Causal attention of a prefill, exact, pure lax: q (B, T, H, dq),
+    k (B, T, Hkv, dq), v (B, T, Hkv, dv) with H = g * Hkv -> (B, T, H,
+    dv); key j is visible to query t iff j <= t and, with ``window``,
+    t - window < j. Builds the (T, T) scores: the path of every device
+    but a TPU and of a bucket under the kernel's threshold, and the
+    numeric reference of the kernel."""
     b, t, h, d = q.shape
     hkv = k.shape[2]
     if scale is None:
@@ -1044,51 +1044,93 @@ def attn_window_reference(q, k, v, window, scale=None):
     s = jnp.einsum("btkgd,bskd->bkgts", qf, k.astype(jnp.float32))
     row = jnp.arange(t)[:, None]
     col = jnp.arange(t)[None, :]
-    seen = (col <= row) & (col > row - window)
+    seen = col <= row
+    if window:
+        seen &= col > row - window
     p = jax.nn.softmax(jnp.where(seen, s, _NEG), axis=-1)
     out = jnp.einsum("bkgts,bskd->btkgd", p, v.astype(jnp.float32))
-    return out.reshape(b, t, h, d).astype(q.dtype)
+    return out.reshape(b, t, h, v.shape[-1]).astype(q.dtype)
 
 
-def attn_window(q, k, v, window, scale=None, interpret=False):
-    """Prefill attention of a sliding-window layer over (B, T, H, Dh)
-    queries and (B, T, Hkv, Dh) keys/values. On a TPU with lane-aligned
-    heads and block-aligned sequences the flash forward kernel under
-    the name ``ptpu.attn_window``, which SKIPS the key blocks wholly
-    before a query block's window (and those above the diagonal) and
-    masks inside the two or three blocks left; the exact lax path
-    elsewhere. Forward only: no training graph has a window."""
-    b, t, h, d = q.shape
+def prefill_attention(q, k, v, lengths=None, window=0, scale=None, name=None,
+                      interpret=False):
+    """THE causal attention of a serving prefill, forward only: q (B, T,
+    H, dq), k (B, T, Hkv, dq), v (B, T, Hkv, dv) -> (B, T, H, dv) in
+    q's type; with ``window`` a query sees its last ``window`` keys.
+    ``lengths`` (B,): the rows' live tokens, padding at a row's END.
+
+    On a TPU at a block-aligned bucket (``_use_pallas``: 256 rows and
+    up) the flash forward kernel, ``name`` in a device trace
+    (``ptpu.attn_window`` where a window was asked for, else
+    ``ptpu.flash_fwd``):
+
+    - float32 operands are rounded to bfloat16 in HBM before the call
+      (and before K and V are repeated to the query heads): what
+      Mosaic's dot rounds float32 operands to at the default precision
+      anyway (on the chip the two give the same bits at the same MXU
+      time: PERF.md, PR 45), the arithmetic the lax paths compute in
+      and the serving configurations state. What the cast buys is
+      bytes: half of what a head's resident K and V take in vector
+      memory and of what the repeat writes. The statistics and sums
+      stay float32, and so does the output of float32 callers;
+    - ``lengths`` is scalar-prefetched: a q-block wholly past its row's
+      length computes nothing and gives zeros;
+    - q and k are padded with zero channels to a multiple of the 128
+      lanes and v to one of its own (192 / 192 / 128 -> 256 / 256 / 128),
+      and the output's first ``dv`` channels kept: exact.
+
+    Elsewhere the exact lax form, which computes every row: a padding
+    row's output differs between the two paths and no one reads it (the
+    attention is causal and padding is at the end, so no live row sees
+    a padding row's key). The differentiable ``fused_attention`` of the
+    training graphs shares the kernel BODY and nothing of this
+    dispatch."""
+    b, t, h, dq = q.shape
+    dv = v.shape[-1]
     window = int(window)
+    name = name or (ATTN_WINDOW if window else FLASH_FWD)
     if window >= t:
         window = 0  # every earlier key is inside it: plain causal
-    if not (interpret or (d % 128 == 0 and _use_pallas(t, t, None, 0.0))):
-        with jax.named_scope(ATTN_WINDOW):
-            return attn_window_reference(q, k, v, window or t, scale)
-    group = h // k.shape[2]
-    if group > 1:
-        # the kernel takes q, k, v of one head count (fused_attention)
-        k = jnp.repeat(k, group, axis=2)
-        v = jnp.repeat(v, group, axis=2)
+    kernel = interpret or _use_pallas(t, t, None, 0.0)
+    PREFILL_ATTN_TRACES.inc(
+        path="kernel" if kernel else "lax",
+        operands="bfloat16" if kernel else jnp.dtype(q.dtype).name,
+        lengths="none" if lengths is None else "given")
+    if not kernel:
+        with jax.named_scope(name):
+            return prefill_attention_reference(q, k, v, window, scale)
     if scale is None:
-        scale = 1.0 / math.sqrt(d)
+        scale = 1.0 / math.sqrt(dq)
+    group = h // k.shape[2]
+
+    def operand(x, repeat):
+        if x.dtype == jnp.float32:
+            x = x.astype(jnp.bfloat16)
+        width = _ceil_to(x.shape[-1], 128)
+        x = jnp.pad(x, ((0, 0),) * 3 + ((0, width - x.shape[-1]),))
+        if repeat > 1:
+            # the kernel takes q, k, v of one head count
+            x = jnp.repeat(x, repeat, axis=2)
+        return x.reshape(b, t, h * width)
+
     block_q = _fit_block(t, _env_block("PADDLE_TPU_FLASH_BQ", 512))
     block_k = _fit_block(t, _env_block("PADDLE_TPU_FLASH_BK", 512))
-    qs = (q * jnp.asarray(scale, q.dtype)).reshape(b, t, h * d)
-    out, _lse = _mha_fwd_call_bthd(
-        qs, k.reshape(b, t, h * d), v.reshape(b, t, h * d), h, True,
-        block_q, block_k, interpret, window=window, name=ATTN_WINDOW)
-    return out.reshape(b, t, h, d)
+    out, _ = _mha_fwd_call_bthd(
+        operand(q * jnp.asarray(scale, q.dtype), 1), operand(k, group),
+        operand(v, group), h, True, block_q, block_k, interpret,
+        window=window, name=name, lengths=lengths, out_dtype=q.dtype)
+    return out.reshape(b, t, h, -1)[..., :dv]
 
 
-@register_op("attn_window")
-def _attn_window_op(ctx):
-    """Inputs Q (B, T, H, Dh), K, V (B, T, Hkv, Dh); attrs window,
-    scale -> Out = Q's shape: causal attention in which a query sees
-    the last ``window`` keys up to its own."""
-    return {"Out": attn_window(ctx.input("Q"), ctx.input("K"),
-                               ctx.input("V"), int(ctx.attr("window")),
-                               scale=ctx.attr("scale", None))}
+@register_op("prefill_attention")
+def _prefill_attention_op(ctx):
+    """Inputs Q (B, T, H, dq), K (B, T, Hkv, dq), V (B, T, Hkv, dv),
+    optional Lengths (B,); attrs window (0: every earlier key), scale
+    -> Out (B, T, H, dv): ``prefill_attention``."""
+    return {"Out": prefill_attention(
+        ctx.input("Q"), ctx.input("K"), ctx.input("V"),
+        ctx.input("Lengths"), window=int(ctx.attr("window", 0) or 0),
+        scale=ctx.attr("scale", None))}
 
 
 @register_op("ring_attention")

@@ -205,10 +205,13 @@ def _attend_rows_lax(qp, k, v, lengths, scale):
     return jnp.stack(out, axis=1).reshape(b, 1, h, w).astype(qp.dtype)
 
 
-def diff_attention(q, k, v, lam, gain, lam_init, window=0, eps=1e-5):
+def diff_attention(q, k, v, lam, gain, lam_init, window=0, eps=1e-5,
+                   lengths=None):
     """A whole sequence: q (B, T, H, dh), k/v (B, T, Hkv dh) flat rows
     -> (B, T, H / 2, 2 dh). Key j is visible to query t iff j <= t, and
-    with ``window`` also j > t - window."""
+    with ``window`` also j > t - window. ``lengths`` (B,): the rows'
+    live tokens, for the kernel to skip what lies past them
+    (``attention.prefill_attention``)."""
     pairs, scale = _check(q, k, v)
     with jax.named_scope(DIFF_ATTN):
         qp = pair_queries(q)
@@ -216,10 +219,8 @@ def diff_attention(q, k, v, lam, gain, lam_init, window=0, eps=1e-5):
         # a reshape of a few megabytes costs
         k = k.reshape(k.shape[:2] + (pairs, -1))
         v = v.reshape(v.shape[:2] + (pairs, -1))
-        if window:
-            ctx = _A.attn_window(qp, k, v, int(window), scale=scale)
-        else:
-            ctx = _A.causal_attention_bthd(qp, k, v, scale=scale)
+        ctx = _A.prefill_attention(qp, k, v, lengths, window=int(window),
+                                   scale=scale)
         return diff_combine(ctx, lam, gain, lam_init, eps)
 
 
@@ -265,13 +266,14 @@ def _lam(ctx):
 @register_op("diff_attention")
 def _diff_attention_op(ctx):
     """Inputs Q (B, T, H, dh), K, V (B, T, Hkv dh), LQ1, LK1, LQ2, LK2
-    (dh,), Gain (2 dh,); attrs lam_init, window (0: causal), epsilon ->
-    Out (B, T, H / 2, 2 dh)."""
+    (dh,), Gain (2 dh,), optional Lengths (B,); attrs lam_init, window
+    (0: causal), epsilon -> Out (B, T, H / 2, 2 dh)."""
     return {"Out": diff_attention(
         ctx.input("Q"), ctx.input("K"), ctx.input("V"), _lam(ctx),
         ctx.input("Gain"), float(ctx.attr("lam_init")),
         window=int(ctx.attr("window", 0) or 0),
-        eps=float(ctx.attr("epsilon", 1e-5)))}
+        eps=float(ctx.attr("epsilon", 1e-5)),
+        lengths=ctx.input("Lengths"))}
 
 
 @register_op("diff_decode_attention")

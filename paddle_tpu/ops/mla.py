@@ -39,11 +39,11 @@ attention paths read it, and they compute the same numbers:
     the row, every row of every slot whatever its length.
 
 Six ops, one scope each: ``mla_attend`` (``ptpu.mla_attend``: the
-expanded path's causal attention where the query/key head is wider
-than the value head, through the flash kernel at a padded common
-width), ``mla_q`` (``ptpu.mla_q``: down projection,
-RMS norm, up projection, the rotation of each head's rope part, the
-position-dependent query scale), ``mla_kv`` (``ptpu.mla_kv``: down
+expanded path's causal attention, the serving prefills' one entry
+``attention.prefill_attention`` at the rows' lengths, whatever the
+query/key head's width beside the value head's), ``mla_q``
+(``ptpu.mla_q``: down projection, RMS norm, up projection, the
+rotation of each head's rope part, the position-dependent query scale), ``mla_kv`` (``ptpu.mla_kv``: down
 projection, RMS norm of ``c_kv``, rotation of ``k_r``: the row a
 position keeps), ``mla_expand`` (``ptpu.mla_expand``), ``mla_decode``
 (``ptpu.mla_decode``) and ``mla_append`` (``ptpu.mla_append``: one row
@@ -170,26 +170,18 @@ def mla_expand(rows, w_kvb, n_head, nope):
                 kv[..., nope:])
 
 
-def mla_attend(q, k, v, scale):
-    """The EXPANDED path's causal attention where a head's query/key
-    width is not its value width (192 / 128): q, k (B, T, H, dq), v (B,
-    T, H, dv) -> (B, T, H, dv). The flash kernels take ONE head width,
-    a multiple of the 128 lanes, so q, k and v are padded with zero
-    channels to the next such width (256) and the output's first ``dv``
-    channels are kept: exact (a zero channel adds nothing to a score,
-    and a zero value channel is a zero output channel), at 512 / 320 of
-    the products' FLOPs. A flash forward with a value width of its own
-    would save that; the kernels of the plain models stay as they
-    are."""
-    dq, dv = q.shape[-1], v.shape[-1]
+def mla_attend(q, k, v, scale, lengths=None):
+    """The EXPANDED path's causal attention: q, k (B, T, H, dq), v (B,
+    T, H, dv) -> (B, T, H, dv), a head's query/key width its value
+    width (128 / 128) or not (192 / 128). A view of the serving
+    prefills' one entry, ``attention.prefill_attention``: on a TPU at a
+    block-aligned bucket the flash forward kernel on bfloat16 operands
+    (float32 sums), q and k padded with zero channels to the next
+    multiple of the 128 lanes (256) and V AT ITS OWN WIDTH (128: three
+    quarters of the MXU passes of a common 256), the q-blocks past a
+    row's ``lengths`` skipped; the exact lax form elsewhere."""
     with jax.named_scope(MLA_ATTEND):
-        width = -(-max(dq, dv) // 128) * 128
-
-        def pad(x):
-            return jnp.pad(x, ((0, 0),) * 3 + ((0, width - x.shape[-1]),))
-
-        return _A.causal_attention_bthd(pad(q), pad(k), pad(v),
-                                        scale)[..., :dv]
+        return _A.prefill_attention(q, k, v, lengths, scale=scale)
 
 
 # heads a pass of ``latent_prefill``: 16 heads of 16,384 rows padded to
@@ -259,9 +251,10 @@ def latent_prefill(c_q, rows, w_qb, w_kvb, gate, w_o, n_head, nope, scale,
         def pad(x):
             # the kernel's operands in bfloat16: what the MXU would round
             # float32 operands to at the default precision anyway (the
-            # arithmetic the lax paths compute in), where a Mosaic dot of
-            # float32 operands runs several passes at ~13% of the peak;
-            # a head's channels a whole number of 128-lane tiles
+            # arithmetic the lax paths compute in; the same bits and the
+            # same MXU time on the chip, PERF.md PR 45), at half the
+            # bytes of a head's resident K and V; a head's channels a
+            # whole number of 128-lane tiles
             width = -(-x.shape[-1] // 128) * 128
             return jnp.pad(x, ((0, 0),) * 3 + ((0, width - x.shape[-1]),)
                            ).reshape(b, t, g * width).astype(jnp.bfloat16)
@@ -581,10 +574,12 @@ def _mla_expand_op(ctx):
 
 @register_op("mla_attend")
 def _mla_attend_op(ctx):
-    """Inputs Q, K (B, T, H, dq), V (B, T, H, dv), dq != dv. Attr scale
-    -> Out (B, T, H, dv): causal attention of a prefill."""
+    """Inputs Q, K (B, T, H, dq), V (B, T, H, dv), optional Lengths
+    (B,). Attr scale -> Out (B, T, H, dv): causal attention of a
+    prefill."""
     return {"Out": mla_attend(ctx.input("Q"), ctx.input("K"),
-                              ctx.input("V"), float(ctx.attr("scale")))}
+                              ctx.input("V"), float(ctx.attr("scale")),
+                              ctx.input("Lengths"))}
 
 
 @register_op("mla_decode")

@@ -376,3 +376,125 @@ def test_fused_bwd_over_budget_is_an_error_naming_the_shape(monkeypatch):
     monkeypatch.setattr(A, "_mha_bwd_fused_kernel", _boom)
     with pytest.raises(ValueError, match="seq_k=256, d_head=128"):
         jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+# -- the serving prefills' forward-only entry (`prefill_attention`) ------------
+
+_PREFILL_CASES = [
+    # id, T, (H, Hkv), (dq, dv), window, lengths of the two rows, dtype
+    ("full-whole-bucket", 512, (4, 4), (128, 128), 0, [512, 512], "float32"),
+    ("full-no-lengths", 256, (2, 2), (128, 128), 0, None, "float32"),
+    ("window", 512, (4, 4), (128, 128), 200, [512, 300], "float32"),
+    ("window-wider-than-bucket", 256, (2, 2), (128, 128), 4096, [256, 9],
+     "float32"),
+    ("fewer-kv-heads", 512, (6, 2), (128, 128), 0, [384, 512], "float32"),
+    ("v-narrower-192-128", 512, (4, 4), (192, 128), 0, [512, 257],
+     "float32"),
+    ("head-of-64-window-grouped", 512, (4, 2), (64, 64), 130, [500, 128],
+     "float32"),
+    ("length-0", 512, (4, 2), (128, 128), 0, [0, 130], "float32"),
+    ("length-inside-a-block", 512, (4, 4), (128, 128), 0, [129, 383],
+     "float32"),
+    ("bfloat16-inputs", 512, (4, 2), (128, 128), 0, [512, 200], "bfloat16"),
+    ("bfloat16-window-192-128", 512, (4, 4), (192, 128), 150, [300, 512],
+     "bfloat16"),
+]
+
+
+@pytest.mark.parametrize("t,heads,widths,window,lens,dtype",
+                         [c[1:] for c in _PREFILL_CASES],
+                         ids=[c[0] for c in _PREFILL_CASES])
+def test_prefill_attention_kernel_gives_the_live_rows_of_the_lax_form(
+        monkeypatch, t, heads, widths, window, lens, dtype):
+    """The flash forward under `prefill_attention` (interpret mode, blocks
+    of 128: bfloat16 operands, the rows' lengths scalar-prefetched, q
+    and k padded to whole lane tiles and V to its own) against the exact
+    lax form on each row's LIVE positions, at what bfloat16 operands
+    give; a padding row's output is whatever the kernel left, finite."""
+    from paddle_tpu.ops import attention as A
+
+    (h, hkv), (dq, dv) = heads, widths
+    r = np.random.default_rng(t + h + dq + window)
+    q, k, v = (jnp.asarray(r.normal(size=s), dtype) for s in
+               ((2, t, h, dq), (2, t, hkv, dq), (2, t, hkv, dv)))
+    lengths = None if lens is None else jnp.asarray(lens, jnp.int32)
+    monkeypatch.setenv("PADDLE_TPU_FLASH_BQ", "128")
+    monkeypatch.setenv("PADDLE_TPU_FLASH_BK", "128")
+    got = A.prefill_attention(q, k, v, lengths, window=window,
+                              interpret=True)
+    assert got.shape == (2, t, h, dv) and got.dtype == q.dtype
+    got = np.asarray(got, np.float32)
+    assert np.isfinite(got).all()
+    want = np.asarray(A.prefill_attention_reference(
+        *(a.astype(jnp.float32) for a in (q, k, v)), window))
+    for b in range(2):
+        n = t if lens is None else lens[b]
+        if not n:
+            continue
+        np.testing.assert_allclose(got[b, :n], want[b, :n], rtol=2e-2,
+                                   atol=2e-2)
+        err = np.linalg.norm(got[b, :n] - want[b, :n]) / np.linalg.norm(
+            want[b, :n])
+        assert err < 8e-3, err
+    if lens is not None:
+        # the q-blocks wholly past a row's length are skipped: zeros
+        for b, n in enumerate(lens):
+            first_dead = -(-max(n, 1) // 128) * 128 if n else 0
+            assert not got[b, first_dead:].any()
+
+
+def test_prefill_attention_counts_its_traces_and_names_its_kernel():
+    """`paddle_tpu_prefill_attn_traces_total{path, operands, lengths}`:
+    one a traced call; the lax form under the name a device trace would
+    show, `ptpu.attn_window` where a window was asked for."""
+    from paddle_tpu import observability as obs
+    from paddle_tpu.ops import attention as A
+
+    def count(**labels):
+        return obs.PREFILL_ATTN_TRACES.value(**labels)
+
+    q = jnp.ones((1, 256, 2, 128), jnp.float32)
+    lens = jnp.asarray([100], jnp.int32)
+    lax_none = dict(path="lax", operands="float32", lengths="none")
+    lax_given = dict(path="lax", operands="float32", lengths="given")
+    kernel = dict(path="kernel", operands="bfloat16", lengths="given")
+    before = [count(**c) for c in (lax_none, lax_given, kernel)]
+    text = jax.jit(lambda q: (A.prefill_attention(q, q, q),
+                              A.prefill_attention(q, q, q, lens, window=8))
+                   ).lower(q).as_text(debug_info=True)
+    assert "ptpu.flash_fwd" in text and "ptpu.attn_window" in text
+    A.prefill_attention(q, q, q, lens, interpret=True)
+    after = [count(**c) for c in (lax_none, lax_given, kernel)]
+    assert [a - b for a, b in zip(after, before)] == [1, 1, 1]
+
+
+def test_prefill_attention_through_the_layers_api():
+    """The op in a Program (V narrower than q and k, lengths fed): the
+    exact lax form on the CPU, the output at V's width, and its infer
+    rule."""
+    from paddle_tpu.analysis import infer_program
+    from paddle_tpu.ops import attention as A
+
+    r = np.random.default_rng(5)
+    feed = {"q": r.normal(size=(2, 12, 4, 24)).astype(np.float32),
+            "k": r.normal(size=(2, 12, 2, 24)).astype(np.float32),
+            "v": r.normal(size=(2, 12, 2, 16)).astype(np.float32),
+            "lens": np.array([12, 5], np.int32)}
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        d = {n: layers.data(name=n, shape=list(a.shape), dtype=str(a.dtype),
+                            append_batch_size=False)
+             for n, a in feed.items()}
+        full = layers.prefill_attention(d["q"], d["k"], d["v"], d["lens"])
+        win = layers.prefill_attention(d["q"], d["k"], d["v"], window=3,
+                                       scale=0.5)
+    assert tuple(full.shape) == tuple(win.shape) == (2, 12, 4, 16)
+    result = infer_program(main)
+    assert result.report.errors == [], result.report.errors
+    assert result.info(win.name).shape == (2, 12, 4, 16)
+    got = fluid.Executor(fluid.CPUPlace()).run(main, feed=feed,
+                                               fetch_list=[full, win])
+    np.testing.assert_allclose(got[0], A.prefill_attention_reference(
+        feed["q"], feed["k"], feed["v"]), rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(got[1], A.prefill_attention_reference(
+        feed["q"], feed["k"], feed["v"], 3, 0.5), rtol=1e-5, atol=1e-6)

@@ -460,7 +460,9 @@ def test_builders_refuse_what_no_graph_computes():
 # manifests as the parent commit (232c7a0) wrote them, and the content
 # fingerprints of the programs it built from them at prefill (2, 32) and
 # decode (4, 64), read on that commit: the AOT keys of the cells that
-# stand (their cached executables still load)
+# stand (their cached executables still load). The hybrid's prefill is
+# PR 45's: its attention layer's op became `prefill_attention` (the
+# serving prefills' forward-only entry), a program of another key.
 PARENT = {
     "opt": ({"d_inner": 64, "d_model": 32, "eos_id": None, "max_len": 64,
              "n_head": 4, "n_layer": 2, "prefix": "lm",
@@ -473,7 +475,7 @@ PARENT = {
                 "max_len": 64, "n_head": 4, "n_kv_head": 1, "n_layer": 4,
                 "norm": "rms_norm", "norm_eps": 1e-06, "positions": False,
                 "prefix": "lm", "tie_embeddings": True, "vocab_size": 97},
-               {"prefill": "149d8f09", "decode": "3a4ba361"}),
+               {"prefill": "53bffb68", "decode": "3a4ba361"}),
 }
 
 

@@ -660,7 +660,7 @@ COVERED_ELSEWHERE = {
     # attention and its ring: tests/test_rope_moe_ops.py (against the
     # plain reference, closed forms, both sides of the window, the four
     # shares of an expert-parallel deployment, infer rules)
-    "rope", "moe_route", "moe_experts", "moe_shared", "attn_window",
+    "rope", "moe_route", "moe_experts", "moe_shared", "prefill_attention",
     "ring_append", "ring_pack", "decode_attn_ring",
     # differential attention (full, windowed, one token over a slab and
     # over a wrapped ring, cross) and the gated memory unit against

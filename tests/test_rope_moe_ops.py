@@ -408,31 +408,35 @@ def _qkv(t, h, hkv, dh, seed=7, b=2):
 
 def test_attn_window_reference_is_the_banded_softmax():
     q, k, v = _qkv(21, 6, 2, 16)
-    got = attn_ops.attn_window(q, k, v, 8)
+    got = attn_ops.prefill_attention(q, k, v, window=8)
     for b in range(2):
         np.testing.assert_allclose(
             got[b], _naive_window(*(np.asarray(a[b]) for a in (q, k, v)), 8),
             rtol=1e-4, atol=1e-5)
     # a window the sequence never fills is plain causal attention
     np.testing.assert_allclose(
-        attn_ops.attn_window(q, k, v, 64),
-        attn_ops.attn_window_reference(q, k, v, 21), rtol=1e-5, atol=1e-6)
+        attn_ops.prefill_attention(q, k, v, window=64),
+        attn_ops.prefill_attention_reference(q, k, v, 21),
+        rtol=1e-5, atol=1e-6)
 
 
 def test_attn_window_kernel_skips_and_masks_like_the_reference():
     """The Pallas forward kernel in interpret mode at 512 positions,
     blocks of 128, window 200: q-blocks 2 and 3 start their loop past
-    block 0 (skipped whole) and mask inside the blocks they read."""
+    block 0 (skipped whole) and mask inside the blocks they read; at
+    what the kernel's bfloat16 operands give (float32 sums)."""
     q, k, v = _qkv(512, 4, 2, 128, b=1)
     os.environ["PADDLE_TPU_FLASH_BQ"] = os.environ[
         "PADDLE_TPU_FLASH_BK"] = "128"
     try:
-        got = attn_ops.attn_window(q, k, v, 200, interpret=True)
+        got = attn_ops.prefill_attention(q, k, v, window=200,
+                                         interpret=True)
     finally:
         del os.environ["PADDLE_TPU_FLASH_BQ"], os.environ[
             "PADDLE_TPU_FLASH_BK"]
-    want = attn_ops.attn_window_reference(q, k, v, 200)
-    np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
+    want = attn_ops.prefill_attention_reference(q, k, v, 200)
+    np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
+    assert np.linalg.norm(got - want) < 5e-3 * np.linalg.norm(want)
 
 
 @_cases("lens", [[5, 3], [8, 9], [21, 40]])
@@ -445,7 +449,7 @@ def test_ring_holds_the_window_through_prefill_and_decode(lens):
     t = 64
     q, k, v = _qkv(t, 6, 2, 16, seed=8)
     lengths = jnp.asarray(lens, jnp.int32)
-    want = attn_ops.attn_window_reference(q, k, v, w)
+    want = attn_ops.prefill_attention_reference(q, k, v, w)
     kr = kv_cache.ring_pack(k, lengths, w)
     vr = kv_cache.ring_pack(v, lengths, w)
     assert kr.shape == (2, w, 2, 16)
@@ -503,7 +507,7 @@ def test_layers_infer_their_shapes():
         rq = layers.rope(q, rotary_dim=8, theta=5e5, attention_factor=1.4,
                          yarn={"factor": 64, "original_max_position": 32,
                                "beta_fast": 4, "beta_slow": 1})
-        ctx = layers.attn_window(rq, kv, kv, 8)
+        ctx = layers.prefill_attention(rq, kv, kv, window=8)
         ring = layers.ring_pack(kv, lens, 8)
         q1 = _data("q1", (2, 1, 6, 16))
         new = layers.ring_append(ring, _data("row", (2, 1, 2, 16)), lens)
@@ -535,9 +539,9 @@ def test_layers_infer_their_shapes():
         _data("x", (2, 4, 32)), _data("i", (2, 4, 2), "int32"),
         _data("w", (2, 4, 2)), _data("g", (4, 16, 8)), _data("u", (4, 16, 8)),
         _data("d", (4, 8, 16))), "does not take X"),
-    (lambda: layers.attn_window(_data("q", (2, 4, 6, 16)),
-                                _data("k", (2, 4, 4, 16)),
-                                _data("v", (2, 4, 4, 16)), 8),
+    (lambda: layers.prefill_attention(_data("q", (2, 4, 6, 16)),
+                                      _data("k", (2, 4, 4, 16)),
+                                      _data("v", (2, 4, 4, 16)), window=8),
      "does not divide"),
     (lambda: layers.decode_attn_ring(
         _data("q", (2, 1, 4, 16)), _data("k", (2, 8, 2, 8)),
@@ -558,7 +562,7 @@ def test_ops_carry_their_scopes():
         y, _ = moe.moe_experts(x, idx, w, wg, wg, wd)
         y = y + moe.moe_shared(x, sg, sg, sd)
         r = rope.rope(q, None, rope.rope_inv_freq(16, 1e4))
-        c = attn_ops.attn_window(r, kv, kv, 4)
+        c = attn_ops.prefill_attention(r, kv, kv, window=4)
         ring = kv_cache.ring_append(kv_cache.ring_pack(kv, lens, 4),
                                     kv[:, :1], lens)
         return y, c, kv_cache.decode_attn_ring(q[:, :1], ring, ring, lens)

@@ -48,6 +48,39 @@ def _cell_predictor(model, config, monkeypatch):
     return pred
 
 
+_CALL = re.compile(r"%((?:ptpu\.flash_fwd|ptpu\.attn_window)[\w.]*) = [^\n]*?"
+                   r'custom_call_target="tpu_custom_call", '
+                   r"operand_layout_constraints="
+                   r"\{((?:[^{}]|\{[^{}]*\})*)\}")
+
+
+def _prefill_attention_calls(text):
+    """[(call's name, [its operands' types, in order])] of the flash
+    forward calls of a compiled prefill, `ptpu.flash_fwd` and
+    `ptpu.attn_window`."""
+    return [(name, re.findall(r"(\w+\[[\d,]*\])", operands))
+            for name, operands in _CALL.findall(text)]
+
+
+def _assert_bfloat16_operands_and_lengths(text, batch, v_width=None):
+    """Every flash forward of a serving prefill is handed the rows'
+    lengths (scalar-prefetched: the first operand) and q, k and v in
+    bfloat16 (`ops/attention.py: prefill_attention`); with `v_width`,
+    v as (batch, T, v_width) where q and k are wider."""
+    calls = _prefill_attention_calls(text)
+    assert calls
+    for name, operands in calls:
+        assert operands[0] == "s32[%d]" % batch, (name, operands)
+        assert len(operands) == 4 and all(
+            o.startswith("bf16[%d," % batch) for o in operands[1:]), (
+                name, operands)
+        if v_width:
+            assert operands[3].endswith(",%d]" % v_width), (name, operands)
+            assert operands[1] == operands[2] != operands[3], (name,
+                                                               operands)
+    return calls
+
+
 _LAGUNA_CASES = [
     # id, kind, batch, seq: the Laguna serving cell's own programs
     # (benchmark/configs/laguna-xs.2.json: 5 layers at published widths,
@@ -95,6 +128,7 @@ def test_laguna_serving_step_compiles(one_chip, monkeypatch, kind, batch,
             "ragged-dot-none"], sorted(set(calls))
         assert calls.count("ptpu.attn_window") == 3
         assert calls.count("ptpu.flash_fwd") == 2
+        assert len(_assert_bfloat16_operands_and_lengths(text, batch)) == 5
         assert calls.count("ragged-dot-none") == 3 * 4
         assert text.count(" while(") >= 4           # a loop a sparse layer
         slabs = sum(e.nbytes for e in pred.cache_spec(64, 4096))
@@ -164,6 +198,7 @@ def test_phi4flash_serving_step_compiles(one_chip, monkeypatch, kind, batch,
                            r'custom_call_target="tpu_custom_call"', text)
         assert calls.count("ptpu.attn_window") == 4, calls
         assert calls.count("ptpu.flash_fwd") == 1, calls
+        assert len(_assert_bfloat16_operands_and_lengths(text, batch)) == 5
         # a prefill's cross layers: one query row a prompt on its rows
         assert calls.count("ptpu.diff_attn_rows") == 3, calls
         assert text.count(" while(") >= 5           # a scan a Mamba layer
@@ -217,9 +252,9 @@ def test_mistral4_serving_step_compiles(one_chip, monkeypatch, kind, batch,
     TRANSPOSED view, a bitcast of it, and is found by the benchmark's
     reader of "an event that reads a latent slab"; no K or V of 32
     heads anywhere (no array of slots x 16,384 x 32 heads). The largest
-    admission holds one
-    flash forward a layer, whose resident K and V of 16,384 rows need
-    the raised scoped VMEM."""
+    admission holds one flash forward a layer on bfloat16 operands and
+    the row's length, whose resident K and V of 16,384 rows need the
+    raised scoped VMEM."""
     pred = _cell_predictor("mistral4_lm", "mistral-small-4.json",
                            monkeypatch)
     step_fn, feeds, state, n_cache = _serving_step(pred, kind, batch, seq,
@@ -239,6 +274,9 @@ def test_mistral4_serving_step_compiles(one_chip, monkeypatch, kind, batch,
                        r'custom_call_target="tpu_custom_call"', text)
     if kind == "prefill":
         assert calls.count("ptpu.flash_fwd") == 4, calls
+        # 32 heads of 128 / 128 / 128: nothing padded
+        assert _assert_bfloat16_operands_and_lengths(text, batch)[0][1][
+            1:] == ["bf16[1,16384,4096]"] * 3
         assert calls.count("ragged-dot-none") == 3 * 4
         assert weights + slabs + mem.temp_size_in_bytes + (
             mem.output_size_in_bytes) < 15.5 * 2**30, mem
@@ -303,8 +341,9 @@ def test_ling3_serving_step_compiles(one_chip, monkeypatch, kind, batch,
     transposed view is a bitcast and the step holds no copy of the
     slab; the five matrix states are donated and each comes back from
     ONE fusion, in the layout it came in. The largest admission holds
-    one flash forward (the latent layer's, at heads padded to 256) and
-    the chunked scans' loops."""
+    one flash forward (the latent layer's: bfloat16 q and k at heads
+    padded to 256, v at its own 128, the row's length) and the chunked
+    scans' kernels."""
     pred = _cell_predictor("ling3_lm", "ling-3.0-flash.json", monkeypatch)
     step_fn, feeds, state, n_cache = _serving_step(pred, kind, batch, seq,
                                                    one_chip)
@@ -325,8 +364,10 @@ def test_ling3_serving_step_compiles(one_chip, monkeypatch, kind, batch,
     if kind == "prefill":
         assert calls.count("ptpu.flash_fwd") == 1, calls
         assert calls.count("ragged-dot-none") == 3 * 4
-        # the padded heads: one array of 16,384 x 32 x 256 a q, k, v
-        assert "f32[1,16384,8192]" in text
+        # q and k padded to 32 heads of 256, v at its own 128
+        assert _assert_bfloat16_operands_and_lengths(
+            text, batch, v_width=32 * 128)[0][1][1:] == [
+                "bf16[1,16384,8192]"] * 2 + ["bf16[1,16384,4096]"]
         # beside the weights, the slabs and states and the step
         assert weights + slabs + mem.temp_size_in_bytes + (
             mem.output_size_in_bytes) < 15.5 * 2**30, mem
@@ -358,3 +399,44 @@ def test_ling3_serving_step_compiles(one_chip, monkeypatch, kind, batch,
     # no expanded K or V: nothing of slots x positions x heads
     assert "f32[64,16384,32," not in text
     assert mem.temp_size_in_bytes < 300 * 2**20, mem.temp_size_in_bytes
+
+
+# sha256 (16 digits) of the lowered text, Mosaic bodies written without
+# their Python locations (`tools/lowered_hashes.py: without_locations`),
+# of the gradient of `fused_attention`'s differentiable path at (2, 1024,
+# 8 heads of 128), READ ON THE PARENT OF PR 45 (62c9651): what the
+# training graphs hand the chip
+_TRAINING_ATTENTION = [("bfloat16", "282865ad66cd6e75"),
+                       ("float32", "9a0f4c0c6189ed47")]
+
+
+@pytest.mark.parametrize("dtype,want", _TRAINING_ATTENTION,
+                         ids=[c[0] for c in _TRAINING_ATTENTION])
+def test_training_attention_lowers_to_the_parents_text(one_chip, monkeypatch,
+                                                       dtype, want):
+    """The serving prefills' entry (`prefill_attention`: bfloat16
+    operands, lengths) shares `_mha_fwd_block` with the differentiable
+    kernels and none of their dispatch: forward and backward of the
+    training path lower, operand types included, to the very text they
+    lowered to before there was such an entry."""
+    import hashlib
+
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import attention as A
+
+    sys.path.insert(0, os.path.join(REPO, "tools"))
+    from lowered_hashes import without_locations
+
+    monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
+    a = jax.ShapeDtypeStruct((2, 1024, 8, 128), jnp.dtype(dtype),
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(A._attention_bthd(
+            q, k, v, None, True, None, 0.0, 512, None).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(a, a, a).as_text()
+    assert text.count("tpu_custom_call") == 3  # forward, dq, dk and dv
+    assert hashlib.sha256(without_locations(text).encode()).hexdigest()[
+        :16] == want

@@ -1,7 +1,10 @@
 """sha256 of the LOWERED text (StableHLO, no locations) of every serving
-cell's decode step and one prefill, lowered for a described ``v5e:2x2``
-with the Pallas paths steered on (``tests/test_tpu_compile_cells.py::
-_cell_predictor``): no chip, nothing compiled. Two trees whose tables
+cell's decode step and one prefill, and of one training step of the LM
+(``tests/test_tpu_compile.py``'s: chip_smoke.py's model at one layer,
+AMP O2, the differentiable flash kernels), lowered for a described
+``v5e:2x2`` with the Pallas paths steered on (``tests/
+test_tpu_compile_cells.py::_cell_predictor``): no chip, nothing
+compiled. Two trees whose tables
 agree hand the chip the same programs, Mosaic kernels included, so a
 refactoring of a kernel is checked here before a chip minute is spent.
 
@@ -116,6 +119,17 @@ def main(argv=None):
     if out_text:
         os.makedirs(out_text, exist_ok=True)
     table = {}
+
+    def keep(name, lowered):
+        text = without_locations(lowered, names=not args.no_kernel_names)
+        table[name] = [hashlib.sha256(text.encode()).hexdigest()[:16],
+                       len(text)]
+        if out_text:
+            with open(os.path.join(
+                    out_text, name.replace(" ", "_") + ".txt"), "w") as f:
+                f.write(text)
+        print("%-38s %s %9d" % (name, *table[name]), flush=True)
+
     for model, config, programs in CELLS:
         if not os.path.exists(os.path.join(root, "benchmark", "configs",
                                            config)):
@@ -125,17 +139,18 @@ def main(argv=None):
             name = "%s %s %dx%d" % (config[:-5], kind, batch, seq)
             pred = _cell_predictor(model, config, _STEER)
             fn, feeds, state, _ = _serving_step(pred, kind, batch, seq, chip)
-            text = without_locations(
-                jax.jit(fn, donate_argnums=(0,)).lower(
-                    feeds, state).as_text(),
-                names=not args.no_kernel_names)
-            table[name] = [hashlib.sha256(text.encode()).hexdigest()[:16],
-                           len(text)]
-            if out_text:
-                with open(os.path.join(
-                        out_text, name.replace(" ", "_") + ".txt"), "w") as f:
-                    f.write(text)
-            print("%-38s %s %9d" % (name, *table[name]), flush=True)
+            keep(name, jax.jit(fn, donate_argnums=(0,)).lower(
+                feeds, state).as_text())
+    os.environ["PADDLE_TPU_FORCE_PALLAS"] = "1"
+    from test_tpu_compile import _lm_programs, _train_step_avals
+
+    def on_chip(aval, batch=False):
+        return jax.ShapeDtypeStruct(aval.shape, aval.dtype, sharding=chip)
+
+    stepfn, avals = _train_step_avals(
+        *_lm_programs(1), lambda n, a: on_chip(a), on_chip)
+    keep("lm train 1 layer", jax.jit(stepfn, donate_argnums=(1,)).lower(
+        *avals).as_text())
     if out_json:
         with open(out_json, "w") as f:
             json.dump(table, f, indent=1)
