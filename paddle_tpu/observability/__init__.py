@@ -51,7 +51,7 @@ __all__ = [
     "FLEET_REQUEUED", "FLEET_MISVERSIONED", "FLEET_BACKPRESSURE_MS",
     "FLEET_SHED", "FLEET_PENDING", "FLEET_AUTOSCALE",
     "DECODE_TOKENS", "DECODE_STEPS", "DECODE_SLOTS", "DECODE_STEP_MS",
-    "DECODE_REQUESTS",
+    "DECODE_REQUESTS", "DECODE_PRELOAD",
     "DECODE_PREFIX_QUERIES", "DECODE_PREFIX_HITS", "DECODE_PREFIX_BYTES",
     "DECODE_SPEC_PROPOSED", "DECODE_SPEC_ACCEPTED",
     "CKPT_SAVES", "CKPT_BYTES", "CKPT_PENDING", "CKPT_SAVE_MS",
@@ -342,6 +342,17 @@ DECODE_REQUESTS = REGISTRY.counter(
     "paddle_tpu_decode_requests_total",
     "Decode-serving sequences, kind=admitted (entered a cache slot) | "
     "retired (finished and freed it); admitted - retired = in flight")
+DECODE_PRELOAD = REGISTRY.counter(
+    "paddle_tpu_decode_preload_total",
+    "Prefill executables a decode predictor's disk directory named when "
+    "its first server started (DecodePredictor.preload, on the caller's "
+    "thread, before the loop opens), by result=loaded (through "
+    "Engine.acquire: a path=warm record under the phase decode.preload) "
+    "| stale (the sidecar re-hashes to another key: another program, "
+    "environment or jax) | unreadable (the blob would not load: "
+    "quarantined, compiled again at its first admission). A shape "
+    "already in memory counts nothing, and nothing is ever compiled "
+    "here")
 DECODE_PREFIX_QUERIES = REGISTRY.counter(
     "paddle_tpu_decode_prefix_queries_total",
     "Shared-prefix store lookups at admission (one per admitted "

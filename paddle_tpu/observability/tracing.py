@@ -288,6 +288,9 @@ class _NoPhase:
     def __exit__(self, *exc):
         return False
 
+    def note(self, **counts):
+        pass
+
 
 class _Unsampled(_NoPhase):
     """An outermost phase that lost the sampling draw (rates strictly
@@ -335,6 +338,12 @@ class _Phase:
             self.acc = {}  # (parent name, name) -> [self_ms, ms, n, end]
         self.t0 = time.perf_counter()
         return self
+
+    def note(self, **counts):
+        """Counts known only once the phase has run (how many of what
+        it found it loaded): they join those it was opened with in its
+        record; the profiler's span keeps what it was opened with."""
+        self.counts.update(counts)
 
     def __exit__(self, *exc):
         self.t1 = t1 = time.perf_counter()

@@ -717,6 +717,12 @@ def _pairing_order(feed_names, fetch_names, spec_names):
 _Step = collections.namedtuple(
     "_Step", "fn program feed_names fetch_names traced take n_cache n_tail")
 
+# a signature made ready for the disk tier (``DecodePredictor._keyed``):
+# the executable's name, its step, the step program's Engine, its feed
+# structs and their signature, the key, and when the building began
+_Keyed = collections.namedtuple(
+    "_Keyed", "name step engine feed_structs feed_sig key t_build")
+
 
 # a decode step the serving loop has dispatched and not read: its
 # outputs (device values), the (slot, sequence, last) it ran for, where
@@ -900,6 +906,10 @@ class DecodePredictor:
             self._scope.set_var(n, self._state[n])
         self._compiled: Dict = {}
         self._lock = threading.Lock()
+        # `preload`'s counts once it has run (None: not yet), and the
+        # lock a second server's `start` waits on while the first loads
+        self._preloaded: Optional[Dict[str, int]] = None
+        self._preload_lock = threading.Lock()
         self.traces = 0
 
     def fingerprint(self) -> str:
@@ -1148,7 +1158,30 @@ class DecodePredictor:
         decode step at ``draft_n_layer`` depth. Prefill buckets at or
         past ``ring_prefill_min_seq`` build with ring attention —
         their programs fingerprint differently, so dense and ring
-        prefills coexist in the AOT cache."""
+        prefills coexist in the AOT cache.
+
+        Where a shape is acquired: a prefill that the disk tier held
+        when the predictor's first server started is in memory since
+        then (``preload``: loaded on that caller's thread before the
+        loop opened), so an admission's call here is a memory hit. What
+        still comes here lazily, on whatever thread asks first (a
+        server's loop thread at a bucket's first admission), is a shape
+        the disk did not hold: it is compiled, and stored for the next
+        process."""
+        ck = self._signature(kind, batch, seq, strategy, kv_dtype, window)
+        with self._lock:
+            hit = self._compiled.get(ck)
+        if hit is not None:
+            obs.CACHE_HITS.inc(kind=kind, tier="memory",
+                               program=self.fingerprint())
+            return hit
+        return self._acquire_keyed(ck, self._keyed(ck))
+
+    def _signature(self, kind, batch, seq, strategy=None,
+                   kv_dtype="float32", window=0) -> tuple:
+        """What names one executable of this predictor (the memory
+        cache's key, and ``_executable_name``'s arguments): the caller's
+        signature with what its kind ignores blanked."""
         strategy = strategy or self.strategy
         if kind not in ("decode", "draft"):
             kv_dtype = "float32"
@@ -1157,22 +1190,23 @@ class DecodePredictor:
         use_ring = bool(kind == "prefill"
                         and self.ring_prefill_min_seq is not None
                         and seq >= self.ring_prefill_min_seq)
-        ck = (kind, batch, seq,
-              strategy if kind in ("decode", "draft") else "",
-              kv_dtype, int(window),
-              self.draft_n_layer if kind == "draft" else 0, use_ring)
-        with self._lock:
-            hit = self._compiled.get(ck)
-        if hit is not None:
-            obs.CACHE_HITS.inc(kind=kind, tier="memory",
-                               program=self.fingerprint())
-            return hit
+        return (kind, batch, seq,
+                strategy if kind in ("decode", "draft") else "",
+                kv_dtype, int(window),
+                self.draft_n_layer if kind == "draft" else 0, use_ring)
+
+    def _keyed(self, ck) -> "_Keyed":
+        """A signature's step program, feed structs and the key its
+        executable has in the disk tier: all an acquisition needs before
+        it touches the tier, and all a preload needs to tell whether a
+        sidecar is this predictor's."""
         from .engine import Engine
 
         t_build = time.perf_counter()
+        kind, batch, seq, strategy, kv_dtype, window, _, use_ring = ck
         name = _executable_name(*ck)
-        step = self._step(kind, batch, seq, strategy, kv_dtype, window,
-                          use_ring, name=name)
+        step = self._step(kind, batch, seq, strategy or self.strategy,
+                          kv_dtype, window, use_ring, name=name)
         engine = Engine(step.program, disk=self._disk,
                         feed_names=step.feed_names,
                         fetch_names=step.fetch_names)
@@ -1181,8 +1215,18 @@ class DecodePredictor:
                          for n, s in sorted(feed_structs.items()))
         # keyed by the order that was TRACED: an executable of another
         # order under this key would hand back permuted slabs
+        key = engine.key(kind, feed_sig, tuple(step.traced))
+        return _Keyed(name, step, engine, feed_structs, feed_sig, key,
+                      t_build)
+
+    def _acquire_keyed(self, ck, keyed: "_Keyed", compile: bool = True):
+        """The Engine's acquisition of one keyed signature, into the
+        memory cache: (executable, fetch_names). ``compile=False`` (the
+        preload) takes what the disk tier holds or nothing: None where
+        the blob cannot be read."""
+        kind = ck[0]
+        name, step, engine, feed_structs, feed_sig, key, t_build = keyed
         traced = tuple(step.traced)
-        key = engine.key(kind, feed_sig, traced)
 
         def lower():
             # donate the feeds (the KV slabs dominate them) so XLA
@@ -1214,14 +1258,108 @@ class DecodePredictor:
             return {"cache_fed": step.n_cache, "cache_aliased": aliased}
 
         loaded, _path, _timings = engine.acquire(
-            kind, key, lower, meta=engine.meta(kind, feed_sig, traced),
+            kind, key, lower if compile else None,
+            meta=engine.meta(kind, feed_sig, traced),
             describe=pairing if kind != "draft" else None, name=name,
             build_ms=(time.perf_counter() - t_build) * 1e3)
+        if loaded is None:
+            return None
         exe = (loaded if step.take is None
                else _InFetchOrder(loaded, step.take))
         with self._lock:
             self._compiled[ck] = (exe, step.fetch_names)
         return exe, step.fetch_names
+
+    # -- preload ------------------------------------------------------------
+    def _prefill_signature_of(self, meta, env) -> Optional[tuple]:
+        """The signature a sidecar of the disk tier names, where it
+        names a prefill of this environment (``env``:
+        ``aot_cache.env_fingerprint()``): ``tokens``' shape in its
+        ``feed_sig`` is the prefill's (batch, bucket). None for any
+        other kind (a server's step, draft and verify window follow from
+        ITS slots and strategy, and ``DecodeServer.start`` acquires them
+        on the same thread), for a sidecar written under another
+        environment or jax (it could only re-hash to another key) and
+        for one that does not read as ``Engine.meta`` wrote it."""
+        try:
+            if (meta.get("kind") != "prefill"
+                    or meta.get("env") != env):
+                return None
+            (shape,) = [tuple(int(d) for d in shp)
+                        for n, shp, _ in meta["feed_sig"] if n == "tokens"]
+            batch, seq = shape
+        except (AttributeError, KeyError, TypeError, ValueError):
+            return None  # a sidecar is a pickle of anything
+        return self._signature("prefill", batch, seq)
+
+    def preload(self) -> Dict[str, int]:
+        """Load every prefill executable this predictor's disk directory
+        holds, once a predictor: ``DecodeServer.start`` calls it on its
+        caller's thread before the loop thread exists, which is where a
+        load from the disk tier is cheap: on a v5e the same blob
+        deserializes in 0.16 s on the main thread and in 2.2-2.5 s on a
+        thread started for the purpose, a server's loop under
+        ``decode.loop.admit`` among them, whatever else is resident
+        (PERF.md 7 i). An admission then finds its shape in memory.
+
+        The directory is walked by its sidecars, newest first. One that
+        names a prefill (``_prefill_signature_of``) has that signature's
+        key rebuilt as ``_acquire`` builds it; where the key IS the
+        sidecar's, the executable is acquired through ``Engine.acquire``
+        like any other: one ``path="warm"`` record with ``load_ms`` and
+        ``blob_bytes``, begun under the phase ``decode.preload``. A
+        sidecar of another program re-hashes to another key and costs
+        its step program, no more (``stale``); a blob that will not load
+        is ``AotDiskCache``'s never-a-crash path (quarantined,
+        ``unreadable``) and is compiled again at its first admission, as
+        any shape the disk does not hold. NOTHING IS COMPILED HERE, and
+        there is no cap: what a directory holds is what its servers
+        admitted, and the loop would load each at its first use anyway.
+        Unlike ``Predictor._preload_executables`` (eight signatures at
+        most, loaded past ``Engine.acquire``: no record, no counter).
+
+        Returns the counts (``found``, ``loaded``, ``resident``: in
+        memory already, ``stale``, ``unreadable``); a second call, from a
+        second server of the predictor, returns them again and does
+        nothing: twenty warm-up servers pay one walk."""
+        with self._preload_lock:
+            if self._preloaded is None:
+                with jax.default_device(self._device):  # as acquire()
+                    self._preloaded = self._preload()
+            return dict(self._preloaded)
+
+    def _preload(self) -> Dict[str, int]:
+        n = dict.fromkeys(
+            ("found", "loaded", "resident", "stale", "unreadable"), 0)
+        if not self._disk.enabled:
+            return n
+        held = {}  # signature -> the sidecars' keys, newest first
+        env = _aot.env_fingerprint()
+        for key, meta in self._disk.sidecars_by_recency():
+            ck = self._prefill_signature_of(meta, env)
+            if ck is not None:
+                held.setdefault(ck, []).append(key)
+        n["found"] = len(held)
+        if not held:
+            return n
+        with _tracing.phase("decode.preload", found=n["found"]) as ph:
+            for ck, keys in held.items():
+                with self._lock:
+                    resident = ck in self._compiled
+                if resident:
+                    n["resident"] += 1
+                    continue
+                keyed = self._keyed(ck)
+                if keyed.key not in keys:
+                    result = "stale"
+                elif self._acquire_keyed(ck, keyed, compile=False) is None:
+                    result = "unreadable"
+                else:
+                    result = "loaded"
+                n[result] += 1
+                obs.DECODE_PRELOAD.inc(result=result)
+            ph.note(loaded=n["loaded"], skipped=n["found"] - n["loaded"])
+        return n
 
     def _step(self, kind, batch, seq, strategy, kv_dtype="float32",
               window=0, use_ring=False, name="ptpu_step") -> _Step:
@@ -1902,6 +2040,19 @@ class DecodeServer:
 
     # -- lifecycle ---------------------------------------------------------
     def start(self):
+        """Open the serving loop. With ``prewarm`` (the default)
+        everything this server can have ready is made ready first, HERE,
+        on the caller's thread, before the loop thread exists (a load
+        made on the main thread costs a tenth of one made on the loop's:
+        ``DecodePredictor.preload``): every prefill executable the
+        predictor's disk directory holds is loaded (once a predictor,
+        so a second server of it pays nothing; nothing is compiled),
+        then the server's own step, the floor-bucket prefills and, where
+        it has them, the draft and the verify window are loaded or
+        compiled. What is left to the loop thread is a prompt bucket the
+        disk has never held: compiled at its first admission, stored,
+        and preloaded by the next process. ``prewarm=False`` leaves
+        everything to the first use: no preload either."""
         if self._thread is not None and self._thread.is_alive():
             return
         if self._prewarm:
@@ -1911,8 +2062,10 @@ class DecodeServer:
             # floor PROMPT bucket (_admit prefills at the prompts' own
             # pow2 bucket, so the floor is what typical short-prompt
             # traffic actually hits — longer prompts lazily warm their
-            # own bucket on first arrival)
+            # own bucket on first arrival, unless the disk tier held
+            # it: then the preload has it in memory already)
             t0 = time.perf_counter()
+            self.predictor.preload()
             self.predictor.acquire("decode", self.slots, self.seq,
                                    self.strategy, kv_dtype=self.kv_dtype)
             if not self.speculative:

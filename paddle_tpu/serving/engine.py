@@ -241,7 +241,11 @@ class Engine:
 
         ``lower`` may raise (program errors propagate exactly as the
         lazy-jit first call would); disk I/O failures are absorbed by
-        AotDiskCache per its never-a-crash contract."""
+        AotDiskCache per its never-a-crash contract. ``lower=None`` asks
+        for the disk tier alone (a preload, which compiles nothing):
+        where the tier has no loadable blob under ``key`` the answer is
+        ``(None, "absent", None)`` and nothing is recorded, since
+        nothing was acquired."""
         fp = self.fingerprint()
         use_disk = self.disk.enabled
         began_under = tracing.current_phase()
@@ -257,6 +261,8 @@ class Engine:
                     t = clock()
                     compiled = self.disk.load(key)
                     load_ms = (clock() - t) * 1e3
+            if compiled is None and lower is None:
+                return None, "absent", None
             path = "warm" if compiled is not None else "cold"
             if path == "warm":
                 parts.update(load_ms=load_ms, blob_bytes=blob)
