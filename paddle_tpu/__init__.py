@@ -43,6 +43,7 @@ from .ops import diff_attn as _k_diff_attn  # noqa: F401
 from .ops import mla as _k_mla  # noqa: F401
 from .ops import kda as _k_kda  # noqa: F401
 from .ops import dsa as _k_dsa  # noqa: F401
+from .ops import eva as _k_eva  # noqa: F401
 from .ops import detection as _k_detection  # noqa: F401
 
 from .framework import (  # noqa: F401

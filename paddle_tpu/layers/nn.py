@@ -148,6 +148,11 @@ __all__ = [
     "latent_prefill",
     "dsa_index_keys",
     "dsa_mask",
+    "eva_summaries",
+    "eva_prefill",
+    "eva_pack",
+    "eva_append",
+    "eva_decode",
 ]
 
 from .ops import elementwise_add  # re-export for parity
@@ -1154,7 +1159,10 @@ def sum(x):
     return sums(x if isinstance(x, (list, tuple)) else [x])
 
 
-def matmul(x, y, transpose_x=False, transpose_y=False, alpha=1.0, name=None):
+def matmul(x, y, transpose_x=False, transpose_y=False, alpha=1.0, name=None,
+           precision=None):
+    """``precision`` ("highest": float32 products whatever the device's
+    default) is written to the op only where given."""
     helper = LayerHelper("matmul", name=name)
     xs = list(x.shape)
     ys = list(y.shape)
@@ -1165,11 +1173,14 @@ def matmul(x, y, transpose_x=False, transpose_y=False, alpha=1.0, name=None):
     batch = xs[:-2] if len(xs) > 2 else (ys[:-2] if len(ys) > 2 else [])
     out_shape = tuple(batch) + ((xs[-2],) if len(xs) > 1 else ()) + ((ys[-1],) if len(ys) > 1 else ())
     out = helper.create_variable_for_type_inference(x.dtype, shape=out_shape)
+    attrs = {"transpose_X": transpose_x, "transpose_Y": transpose_y, "alpha": float(alpha)}
+    if precision:
+        attrs["precision"] = str(precision)
     helper.append_op(
         type="matmul",
         inputs={"X": [x], "Y": [y]},
         outputs={"Out": [out]},
-        attrs={"transpose_X": transpose_x, "transpose_Y": transpose_y, "alpha": float(alpha)},
+        attrs=attrs,
     )
     return out
 
@@ -2467,21 +2478,28 @@ def fused_lm_head_loss(input, label, size, param_attr=None, bias_attr=None,
 # ---------------------------------------------------------------------------
 
 
-def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
+def rms_norm(input, epsilon=1e-6, param_attr=None, name=None,
+             unit_offset=False):
     """Root-mean-square normalization over the last axis with a learned
     gain (no mean subtraction, no shift): gain * x / sqrt(mean(x^2) +
-    epsilon)."""
+    epsilon); under ``unit_offset`` the parameter is the gain's distance
+    from one, (1 + g) * x / sqrt(..), and starts at zero."""
     from ..initializer import ConstantInitializer
 
-    helper = LayerHelper("rms_norm", **locals())
+    helper = LayerHelper("rms_norm", input=input, epsilon=epsilon,
+                         param_attr=param_attr, name=name)
     gain = helper.create_parameter(
         attr=helper.param_attr, shape=[int(input.shape[-1])],
-        dtype=input.dtype, default_initializer=ConstantInitializer(1.0))
+        dtype=input.dtype, default_initializer=ConstantInitializer(
+            0.0 if unit_offset else 1.0))
     out = helper.create_variable_for_type_inference(
         input.dtype, shape=input.shape)
+    attrs = {"epsilon": epsilon}
+    if unit_offset:  # written only where set
+        attrs["unit_offset"] = True
     helper.append_op(
         type="rms_norm", inputs={"X": [input], "Scale": [gain]},
-        outputs={"Out": [out]}, attrs={"epsilon": epsilon})
+        outputs={"Out": [out]}, attrs=attrs)
     return out
 
 
@@ -3056,4 +3074,81 @@ def gmu(x, memory, w_in, w_out, name=None):
         inputs={"X": [x], "Memory": [memory], "WIn": [w_in],
                 "WOut": [w_out]},
         outputs={"Out": [out]}, attrs={})
+    return out
+
+
+def eva_summaries(k, v, phi, mu, chunk, name=None):
+    """EVA's pooled key and value a chunk (``ops/eva.py``): k (rotated),
+    v (B, T, H, D), phi, mu (H, D) -> (k~, v~) (B, T / chunk, H, D)."""
+    helper = LayerHelper("eva_summaries", name=name)
+    shape = (k.shape[0], k.shape[1] // int(chunk)) + tuple(k.shape[2:])
+    ks = helper.create_variable_for_type_inference(k.dtype, shape=shape)
+    vs = helper.create_variable_for_type_inference(v.dtype, shape=shape)
+    helper.append_op(
+        type="eva_summaries",
+        inputs={"K": [k], "V": [v], "Phi": [phi], "Mu": [mu]},
+        outputs={"KSum": [ks], "VSum": [vs]}, attrs={"chunk": int(chunk)})
+    return ks, vs
+
+
+def eva_prefill(q, k, v, ks, vs, lengths, window, chunk, name=None):
+    """A prompt's EVA attention: each window's queries on [the summaries
+    of the windows closed before it | its own rows, causal] -> (B, T, H,
+    D)."""
+    helper = LayerHelper("eva_prefill", name=name)
+    out = helper.create_variable_for_type_inference(q.dtype, shape=q.shape)
+    helper.append_op(
+        type="eva_prefill",
+        inputs={"Q": [q], "K": [k], "V": [v], "KSum": [ks], "VSum": [vs],
+                "Lengths": [lengths]},
+        outputs={"Out": [out]},
+        attrs={"window": int(window), "chunk": int(chunk)})
+    return out
+
+
+def eva_pack(x, xs, lengths, window, summary_rows, name=None):
+    """A prompt's rows and their summaries -> the entry (B, summary_rows
+    + window, H, D) an admission stores: summaries last first, then the
+    block of the window the next position lies in."""
+    helper = LayerHelper("eva_pack", name=name)
+    out = helper.create_variable_for_type_inference(
+        x.dtype, shape=(x.shape[0], int(summary_rows) + int(window))
+        + tuple(x.shape[2:]))
+    helper.append_op(
+        type="eva_pack",
+        inputs={"X": [x], "XSum": [xs], "Lengths": [lengths]},
+        outputs={"Out": [out]},
+        attrs={"window": int(window), "summary_rows": int(summary_rows)})
+    return out
+
+
+def eva_append(k_cache, v_cache, k, v, pos, phi, mu, window, chunk,
+               name=None):
+    """A step's row into both entries' blocks at ``pos mod window`` and,
+    where ``pos`` closes a chunk, that chunk's two summary rows."""
+    helper = LayerHelper("eva_append", name=name)
+    k_out = helper.create_variable_for_type_inference(
+        k_cache.dtype, shape=k_cache.shape)
+    v_out = helper.create_variable_for_type_inference(
+        v_cache.dtype, shape=v_cache.shape)
+    helper.append_op(
+        type="eva_append",
+        inputs={"KCache": [k_cache], "VCache": [v_cache], "K": [k],
+                "V": [v], "Pos": [pos], "Phi": [phi], "Mu": [mu]},
+        outputs={"KOut": [k_out], "VOut": [v_out]},
+        attrs={"window": int(window), "chunk": int(chunk)})
+    return k_out, v_out
+
+
+def eva_decode(q, k_cache, v_cache, pos, window, chunk, name=None):
+    """A step's EVA attention over an entry's live range: the visible
+    summaries and the block's rows up to ``pos``'s, one softmax."""
+    helper = LayerHelper("eva_decode", name=name)
+    out = helper.create_variable_for_type_inference(q.dtype, shape=q.shape)
+    helper.append_op(
+        type="eva_decode",
+        inputs={"Q": [q], "KCache": [k_cache], "VCache": [v_cache],
+                "Pos": [pos]},
+        outputs={"Out": [out]},
+        attrs={"window": int(window), "chunk": int(chunk)})
     return out

@@ -1,6 +1,6 @@
 """Decoder LMs of a DESCRIBED block: a mixer kind (``mamba`` |
 ``attention`` | ``sliding`` | ``gmu`` | ``cross`` | ``latent`` | ``kda`` |
-``latent_dsa`` | ``latent_ring``) times a feed-forward
+``latent_dsa`` | ``latent_ring`` | ``eva``) times a feed-forward
 kind (``dense`` | ``experts``) a layer, one normalization (RMS, or
 LayerNorm with bias) before every mixer and feed-forward and after the
 last layer, no biases but the convolution's and ``dt_proj``'s and,
@@ -50,7 +50,14 @@ where asked, the attention projections'.
   with rescaled latents and a gate a head, of so many heads that a
   prefill expands a group of them at a time (``ops/mla.py:
   latent_prefill``); a leading dense MLP, then routed experts under a
-  sigmoid router with a selection bias.
+  sigmoid router with a selection bias;
+- EvaByte (`model_type: evabyte`): a byte-level model whose every layer
+  is EVA (``eva``, ``ops/eva.py``): a query attends its own window of
+  2,048 bytes exactly and every earlier window through one pooled key
+  and value a chunk of 16 bytes (pooled by a head's learned ``phi``,
+  offset by its ``mu``), under one softmax; rotary positions, RMS norms
+  whose parameter is the gain's distance from one (``norm_offset``), a
+  gated-SiLU MLP, an untied head of 320 ids.
 
 The serving graphs only (serving/decode.py): ``hybrid_lm_prefill``
 walks padded prompts and returns every layer's cache entries AT EACH
@@ -84,7 +91,10 @@ delta rule's state: fixed size, replaced whole by an admission and
 rewritten whole by every step. A latent layer under an indexer keeps its
 ``latent_i`` and ``index_i`` (B, S, index_head_dim), a position's index
 key; a latent layer over a window ONE ``lring_i`` (B, window, its own
-kv_lora_rank + qk_rope_dim): position p at row p mod window.
+kv_lora_rank + qk_rope_dim): position p at row p mod window. An EVA
+layer keeps ``keva_i`` / ``veva_i`` (B, max_len / eva_chunk + window,
+n_head, d_head): the pooled rows, the last chunk first, and after them
+the window's block, position p at p mod window, live up to p.
 """
 from __future__ import annotations
 
@@ -94,6 +104,7 @@ from .. import layers
 from ..framework import default_main_program
 from ..initializer import ConstantInitializer, NormalInitializer
 from ..ops import diff_attn as _D
+from ..ops import eva as _EVA
 from ..ops import kv_cache as _KV
 from ..ops import mla as _MLA
 from ..ops.dsa import DSA_ATTEND
@@ -121,6 +132,8 @@ def cache_names(kind: str, i: int):
     if kind == "kda":
         return ["convq_%d" % i, "convk_%d" % i, "convv_%d" % i,
                 "kda_%d" % i]
+    if kind == "eva":
+        return ["keva_%d" % i, "veva_%d" % i]
     return ["kcache_%d" % i, "vcache_%d" % i]
 
 
@@ -133,8 +146,8 @@ def _proj(x, size, name, bias=False):
         bias_attr=ParamAttr(name=name + ".b") if bias else False)
 
 
-def _rms(x, name, eps):
-    return layers.rms_norm(x, epsilon=eps,
+def _rms(x, name, eps, unit_offset=False):
+    return layers.rms_norm(x, epsilon=eps, unit_offset=unit_offset,
                            param_attr=ParamAttr(name=name + ".w"))
 
 
@@ -145,7 +158,7 @@ def _norm(x, name, cfg):
             x, begin_norm_axis=len(x.shape) - 1, epsilon=cfg.norm_eps,
             param_attr=ParamAttr(name=name + ".w"),
             bias_attr=ParamAttr(name=name + ".b"))
-    return _rms(x, name, cfg.norm_eps)
+    return _rms(x, name, cfg.norm_eps, cfg.norm_offset)
 
 
 def _param(shape, name, init, is_bias=False):
@@ -210,6 +223,9 @@ def stream_view(cfg, seq, dtype="float32"):
     slabs of heads (OPT's block too), under the full layers' query
     heads, not the sliding layers'."""
     kinds = cfg.layer_kinds()
+    if "eva" in kinds:  # whatever ``seq``: the entry is max_len's
+        return _EVA.eva_view(sum(cfg.eva_rows), cfg.n_head, cfg.d_head,
+                             dtype)
     if {"latent", "latent_dsa"} & set(kinds):
         # under an indexer the one-pass kernel over the chosen rows
         view = (_MLA.chosen_view if "latent_dsa" in kinds
@@ -288,6 +304,46 @@ def _attention_mixer(u, cfg, name, lengths, cache, i=0, kind="attention"):
     out = _proj(layers.reshape(ctx, shape=[B, T, h * dh]), cfg.d_model,
                 name + ".o", bias)
     return out, (k, v)
+
+
+def _eva_mixer(u, cfg, name, lengths, cache):
+    """EVA (``ops/eva.py``): ``n_head`` heads on as many key/value
+    heads, q and k rotated by ``cfg.rope["full"]`` at their absolute
+    positions, a head's two learned vectors ``phi`` (what pools a
+    chunk's keys and values) and ``mu`` (added to the pooled key).
+    ``cache`` is None (prefill: every chunk's summaries, each window's
+    queries on [the summaries of the windows closed before it | its own
+    rows, causal]; the entries are the prompt's summaries and the block
+    of the window its next position lies in, packed as a step finds
+    them) or the layer's two entries (one token: its row into the block
+    and, where it closes a chunk, that chunk's summaries; then the live
+    range under one softmax). Returns (out, (k entry, v entry))."""
+    B, T, _ = u.shape
+    h, dh = cfg.n_head, cfg.d_head
+    w, c = int(cfg.window), int(cfg.eva_chunk)
+    q, k, v = (layers.reshape(_proj(u, h * dh, "%s.%s" % (name, part)),
+                              shape=[B, T, h, dh]) for part in "qkv")
+    vec = NormalInitializer(0.0, 0.02)
+    phi = _param([h, dh], name + ".phi", vec)
+    mu = _param([h, dh], name + ".mu", vec)
+    rot = (cfg.rope or {}).get("full")
+    if rot:
+        at = None if cache is None else lengths
+        q = layers.rope(q, at, **rot)
+        k = layers.rope(k, at, **rot)
+    if cache is None:
+        ks, vs = layers.eva_summaries(k, v, phi, mu, c)
+        ctx = layers.eva_prefill(q, k, v, ks, vs, lengths, w, c)
+        n_sum = cfg.eva_rows[0]
+        entries = (layers.eva_pack(k, ks, lengths, w, n_sum),
+                   layers.eva_pack(v, vs, lengths, w, n_sum))
+    else:
+        entries = layers.eva_append(cache[0], cache[1], k, v, lengths, phi,
+                                    mu, w, c)
+        ctx = layers.eva_decode(q, entries[0], entries[1], lengths, w, c)
+    out = _proj(layers.reshape(ctx, shape=[B, T, h * dh]), cfg.d_model,
+                name + ".o")
+    return out, tuple(entries)
 
 
 def _latent_mixer(u, cfg, name, lengths, cache):
@@ -575,6 +631,9 @@ def _layer(x, kind, i, cfg, lengths, cache=None, loads=None, shared=None):
                                             lengths, cache, kind)
     elif kind == "kda":
         mixed, entries = _kda_mixer(u, cfg, name + ".kda", lengths, cache)
+    elif kind == "eva":
+        mixed, entries = _eva_mixer(u, cfg, name + ".attention", lengths,
+                                    cache)
     else:
         mixed, entries = _attention_mixer(u, cfg, name + ".attention",
                                           lengths, cache, i, kind)
@@ -656,6 +715,18 @@ def _check(cfg):
                                                              or {}):
         raise ValueError("a latent layer under an indexer needs "
                          "rope['index'] = {theta, rotary_dim}")
+    if cfg.has_eva and (cfg.diff_attn or cfg.attn_biases or cfg.attn_gate
+                        or cfg.n_head_by_layer):
+        raise ValueError("an EVA layer is built without differential "
+                         "attention, biases, an output gate and head "
+                         "counts by layer")
+    if cfg.head_precision not in (None, "highest"):
+        raise ValueError("head_precision %r: the head is computed at the "
+                         "device's default precision (None) or 'highest'"
+                         % (cfg.head_precision,))
+    if cfg.norm_offset and cfg.norm != "rms_norm":
+        raise ValueError("norm_offset is an RMS norm's (1 + g); got norm=%r"
+                         % (cfg.norm,))
     if "kda" in cfg.layer_kinds() and cfg.kda_gate not in KDA_GATES:
         raise ValueError("kda_gate %r: a KDA layer's decay gate is %s"
                          % (cfg.kda_gate, " or ".join(KDA_GATES)))
@@ -674,13 +745,15 @@ def _embed(tokens, cfg):
 
 def _head(last, cfg):
     """(B, D) -> (B, V), no bias: through the tied table, or through
-    the head's own matrix ``head.w`` (D, V)."""
+    the head's own matrix ``head.w`` (D, V); in float32 products where
+    ``cfg.head_precision`` is "highest"."""
     if not cfg.tie_embeddings:
         return layers.matmul(last, _param(
             [cfg.d_model, cfg.vocab_size], cfg.prefix + ".head.w",
-            NormalInitializer(0.0, 0.02)))
+            NormalInitializer(0.0, 0.02)), precision=cfg.head_precision)
     emb = default_main_program().global_block().var(cfg.prefix + ".tok_emb")
-    return layers.matmul(last, emb, transpose_y=True)
+    return layers.matmul(last, emb, transpose_y=True,
+                         precision=cfg.head_precision)
 
 
 def hybrid_lm_prefill(tokens, lengths, cfg, extras=None,

@@ -316,6 +316,12 @@ MOE_EXPERT_PAIRS = REGISTRY.counter(
     "paddle_tpu_moe_expert_pairs_total",
     "Token-expert pairs the decode path routed to the experts it holds "
     "(every one computed: the serving expert layer drops none), by layer")
+EVA_ROWS = REGISTRY.counter(
+    "paddle_tpu_eva_rows_total",
+    "Rows one EVA layer's attention read in decode steps, by kind: "
+    "window (a live slot's exact keys, its own window's up to its "
+    "position) | summary (the pooled rows of the windows closed before "
+    "it, one a chunk)")
 MOE_TOKENS_ELSEWHERE = REGISTRY.counter(
     "paddle_tpu_moe_tokens_elsewhere_total",
     "Real tokens that sent the experts held here NO pair, by layer: "

@@ -1052,6 +1052,20 @@ def prefill_attention_reference(q, k, v, window=0, scale=None):
     return out.reshape(b, t, h, v.shape[-1]).astype(q.dtype)
 
 
+def flash_operand(x, repeat=1):
+    """x (B, T, H, D) as the forward-only flash call takes it: float32
+    rounded to bfloat16, D padded with zero channels to whole 128-lane
+    tiles, the heads repeated ``repeat`` times (the kernel takes q, k, v
+    of one head count), (B, T, heads x width)."""
+    if x.dtype == jnp.float32:
+        x = x.astype(jnp.bfloat16)
+    width = _ceil_to(x.shape[-1], 128)
+    x = jnp.pad(x, ((0, 0),) * 3 + ((0, width - x.shape[-1]),))
+    if repeat > 1:
+        x = jnp.repeat(x, repeat, axis=2)
+    return x.reshape(x.shape[0], x.shape[1], x.shape[2] * width)
+
+
 def prefill_attention(q, k, v, lengths=None, window=0, scale=None, name=None,
                       interpret=False):
     """THE causal attention of a serving prefill, forward only: q (B, T,
@@ -1103,21 +1117,11 @@ def prefill_attention(q, k, v, lengths=None, window=0, scale=None, name=None,
         scale = 1.0 / math.sqrt(dq)
     group = h // k.shape[2]
 
-    def operand(x, repeat):
-        if x.dtype == jnp.float32:
-            x = x.astype(jnp.bfloat16)
-        width = _ceil_to(x.shape[-1], 128)
-        x = jnp.pad(x, ((0, 0),) * 3 + ((0, width - x.shape[-1]),))
-        if repeat > 1:
-            # the kernel takes q, k, v of one head count
-            x = jnp.repeat(x, repeat, axis=2)
-        return x.reshape(b, t, h * width)
-
     block_q = _fit_block(t, _env_block("PADDLE_TPU_FLASH_BQ", 512))
     block_k = _fit_block(t, _env_block("PADDLE_TPU_FLASH_BK", 512))
     out, _ = _mha_fwd_call_bthd(
-        operand(q * jnp.asarray(scale, q.dtype), 1), operand(k, group),
-        operand(v, group), h, True, block_q, block_k, interpret,
+        flash_operand(q * jnp.asarray(scale, q.dtype)),
+        flash_operand(k, group), flash_operand(v, group), h, True, block_q, block_k, interpret,
         window=window, name=name, lengths=lengths, out_dtype=q.dtype)
     return out.reshape(b, t, h, -1)[..., :dv]
 

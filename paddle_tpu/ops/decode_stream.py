@@ -139,27 +139,53 @@ def last_block(lens, bi, rows):
     return jnp.maximum(lens[bi] + rows - 1, rows) // rows - 1
 
 
-def live_block(j, lens, bi, rows):
-    """The block step ``j`` of a pass over K reads: ``j``, and past the
-    slot's last live block that block again (not copied again)."""
+def live_block(j, lens, bi, rows, starts=None):
+    """The block step ``j`` of a pass over K reads: ``j`` (counted from
+    the block of the slot's first live row where ``starts`` says the
+    live rows do not begin at row 0), and past the slot's last live
+    block that block again (not copied again)."""
+    if starts is not None:
+        j = starts[bi] // rows + j
     return jnp.minimum(j, last_block(lens, bi, rows))
 
 
-def second_pass_block(j, lens, bi, rows, n_blk):
-    """The block step ``j`` of ``2 * n_blk`` reads of V: block 0 while K
-    streams, then as K's."""
-    return jnp.clip(j - n_blk, 0, last_block(lens, bi, rows))
+def second_pass_block(j, lens, bi, rows, n_blk, starts=None):
+    """The block step ``j`` of ``2 * n_blk`` reads of V: its first live
+    block (block 0 without ``starts``) while K streams, then as K's."""
+    if starts is None:
+        return jnp.clip(j - n_blk, 0, last_block(lens, bi, rows))
+    first = starts[bi] // rows
+    return jnp.clip(first + j - n_blk, first, last_block(lens, bi, rows))
 
 
-def _two_pass_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, s_ref, m_ref,
-                     l_ref, acc_ref, *, view, block_s, n_blk):
+def _two_pass_kernel(len_ref, *refs, view, block_s, n_blk, ranged=False):
     """One (slot, step) grid cell of the module's body. len_ref (B,)
-    int32; q_ref pre-scaled; ``s_ref`` (h, S), ``m_ref`` and ``l_ref``
-    (h, 1), ``acc_ref`` (h, Dv) live across the slot's steps (an
-    "arbitrary" axis)."""
+    int32, the row a slot's live rows END before; under ``ranged`` a
+    second prefetched (B,) int32 comes after it, the row they START at
+    (else row 0): a pass's step ``j`` then works on block ``first + j``,
+    ``first`` the block of that row, and the blocks before it are
+    neither fetched nor computed, as the blocks past the end are. q_ref
+    pre-scaled; ``s_ref`` (h, S), ``m_ref`` and ``l_ref`` (h, 1),
+    ``acc_ref`` (h, Dv) live across the slot's steps (an "arbitrary"
+    axis)."""
+    start_ref = None
+    if ranged:
+        start_ref, refs = refs[0], refs[1:]
+    q_ref, k_ref, v_ref, o_ref, s_ref, m_ref, l_ref, acc_ref = refs
     j = pl.program_id(1)
     length = len_ref[pl.program_id(0)]
+    first = 0
+    if ranged:
+        start = start_ref[pl.program_id(0)]
+        # an empty range is a slot of length 0: nothing live, zeros out
+        length = jnp.where(length > start, length, 0)
+        first = start // block_s
     live_blocks = (length + block_s - 1) // block_s
+
+    def block(step):
+        """The block a pass's step ``step`` works on."""
+        return first + step if ranged else step
+
     g = view.score_rows // view.groups
     heads = [(i, slice(i * g, (i + 1) * g)) for i in range(view.groups)]
 
@@ -168,12 +194,15 @@ def _two_pass_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, s_ref, m_ref,
         m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    @pl.when(j < live_blocks)
+    @pl.when(block(j) < live_blocks)
     def _():
-        col0 = pl.multiple_of(j * block_s, block_s)
+        col0 = pl.multiple_of(block(j) * block_s, block_s)
         for i, hh in heads:
             s = view.scores(i, hh, q_ref, k_ref)              # (g, BS)
-            live = col0 + lax.broadcasted_iota(jnp.int32, s.shape, 1) < length
+            col = col0 + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            live = col < length
+            if ranged:
+                live &= col >= start
             s = jnp.where(live, s, _NEG)
             s_ref[hh, pl.ds(col0, block_s)] = s
             m_ref[hh, :] = jnp.maximum(m_ref[hh, :],
@@ -185,12 +214,12 @@ def _two_pass_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, s_ref, m_ref,
             s = s_ref[:, pl.ds(pl.multiple_of(i * block_s, block_s), block_s)]
             return l + jnp.sum(jnp.exp(s - m_ref[...]), axis=1, keepdims=True)
 
-        l_ref[...] = lax.fori_loop(0, live_blocks, add,
+        l_ref[...] = lax.fori_loop(first, live_blocks, add,
                                    jnp.zeros(l_ref.shape, jnp.float32))
 
-    @pl.when((j >= n_blk) & (j - n_blk < live_blocks))
+    @pl.when((j >= n_blk) & (block(j - n_blk) < live_blocks))
     def _():
-        col0 = pl.multiple_of((j - n_blk) * block_s, block_s)
+        col0 = pl.multiple_of(block(j - n_blk) * block_s, block_s)
         for i, hh in heads:
             p = (jnp.exp(s_ref[hh, pl.ds(col0, block_s)] - m_ref[hh, :])
                  / jnp.maximum(l_ref[hh, :], 1e-30))
@@ -202,7 +231,7 @@ def _two_pass_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, s_ref, m_ref,
             o_ref.dtype)
 
 
-def stream_attend(view, lens, q, k, v, interpret=False):
+def stream_attend(view, lens, q, k, v, interpret=False, starts=None):
     """The body over ``view``: lens (B,) int32 live positions a slot, q
     (B,) + ``view.q_block[1:]`` pre-scaled, k and v the cache AS IT LIES,
     positions on axis 1 -> (B,) + ``view.o_block[1:]`` of q's type, zeros
@@ -211,7 +240,9 @@ def stream_attend(view, lens, q, k, v, interpret=False):
     v is k's array): a bitcast where the compiler laid the cache out
     so. A length past the slot's positions reads as "every row", as the
     lax paths read it (unclipped it would index past the score
-    scratch)."""
+    scratch). ``starts`` (B,) int32: a slot's live rows are ``[starts,
+    lens)`` and not ``[0, lens)`` (any view's: a second prefetched
+    scalar a slot; without it the call is the call it was)."""
     rows = block_positions(view)
     if rows is None:
         raise ValueError(
@@ -222,6 +253,8 @@ def stream_attend(view, lens, q, k, v, interpret=False):
     b, s, h, axis = q.shape[0], view.seq, view.score_rows, view.seq_axis
     n_blk = s // rows
     lens = jnp.clip(lens, 0, s)
+    ranged = starts is not None
+    prefetched = (lens, jnp.clip(starts, 0, s)) if ranged else (lens,)
     if axis != 1:
         shared = v is k
         k = jnp.swapaxes(k, 1, axis)
@@ -230,24 +263,27 @@ def stream_attend(view, lens, q, k, v, interpret=False):
     def at(block):
         """The index map of K's or V's blocks: the slot, ``block`` of
         (step, lengths, slot) at the sequence's index, 0 elsewhere."""
-        def index(bi, j, lens_ref):
+        def index(bi, j, lens_ref, *starts_ref):
             where = [0] * len(view.k_block)
-            where[0], where[axis] = bi, block(j, lens_ref, bi)
+            where[0] = bi
+            where[axis] = (block(j, lens_ref, bi, starts=starts_ref[0])
+                           if starts_ref else block(j, lens_ref, bi))
             return tuple(where)
         return index
 
-    def qo_block(bi, j, lens_ref):
+    def qo_block(bi, j, *prefetched_refs):
         return (bi,) + (0,) * (len(view.q_block) - 1)
 
     def blocked(shape):
         return shape[:axis] + (rows,) + shape[axis + 1:]
 
     kernel = functools.partial(_two_pass_kernel, view=view, block_s=rows,
-                               n_blk=n_blk)
+                               n_blk=n_blk, **({"ranged": True} if ranged
+                                               else {}))
     return named_pallas_call(
         view.name, kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=len(prefetched),
             grid=(b, 2 * n_blk),
             in_specs=[
                 pl.BlockSpec(view.q_block, qo_block),
@@ -264,4 +300,4 @@ def stream_attend(view, lens, q, k, v, interpret=False):
         out_shape=jax.ShapeDtypeStruct((b,) + view.o_block[1:], q.dtype),
         interpret=interpret,
         **_tpu_params("parallel", "arbitrary"),
-    )(lens, q, k, v)
+    )(*prefetched, q, k, v)

@@ -305,7 +305,8 @@ def _matmul(ctx):
         x = jnp.swapaxes(x, -1, -2) if x.ndim > 1 else x
     if ctx.attr("transpose_Y", False):
         y = jnp.swapaxes(y, -1, -2) if y.ndim > 1 else y
-    out = jnp.matmul(x, y)
+    # "highest": float32 products on a TPU too (a model's fp32 logits)
+    out = jnp.matmul(x, y, precision=ctx.attr("precision", None))
     alpha = ctx.attr("alpha", 1.0)
     if alpha != 1.0:
         out = out * alpha

@@ -67,20 +67,24 @@ _KERNEL_BLOCK_T = 128
 _KERNEL_BLOCK_D = 1024
 
 
-def rms_norm(x, scale, epsilon=1e-6):
+def rms_norm(x, scale, epsilon=1e-6, unit_offset=False):
     """scale * x / sqrt(mean(x^2) + eps) over the last axis, in
-    float32."""
+    float32; ``(1 + scale)`` for ``scale`` under ``unit_offset``."""
     with jax.named_scope(RMS_NORM):
         xf = x.astype(jnp.float32)
         ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+        if unit_offset:
+            scale = 1.0 + scale.astype(jnp.float32)
         return (xf * lax.rsqrt(ms + epsilon) * scale).astype(x.dtype)
 
 
 @register_op("rms_norm")
 def _rms_norm_op(ctx):
-    """Inputs X (..., D), Scale (D,); attr epsilon -> Out = X's shape."""
+    """Inputs X (..., D), Scale (D,); attrs epsilon, unit_offset -> Out
+    = X's shape."""
     return {"Out": rms_norm(ctx.input("X"), ctx.input("Scale"),
-                            float(ctx.attr("epsilon", 1e-6)))}
+                            float(ctx.attr("epsilon", 1e-6)),
+                            bool(ctx.attr("unit_offset", False)))}
 
 
 def _ssm_update(state, x, delta, a_t, b, c, d):

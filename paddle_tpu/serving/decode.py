@@ -197,7 +197,17 @@ class DecodeConfig:
     rotated by ``rope["latent_ring"]``; it keeps a RING of ``window``
     latent rows. ``latent_rescale`` multiplies the normalised latents by
     ``(d_model / rank)^1/2``, each kind by its own ranks.
-    ``latent_geometry(kind)`` is a latent kind's sizes."""
+    ``latent_geometry(kind)`` is a latent kind's sizes. An ``eva`` layer
+    is EvaByte's EVA attention (``ops/eva.py``): ``n_head`` heads on as
+    many key/value heads, rotated by ``rope["full"]``; a query attends
+    the keys of its own window of ``window`` positions exactly and each
+    earlier window through one pooled key and value a chunk of
+    ``eva_chunk`` positions (``window`` a multiple of it, ``max_len`` of
+    both), all under one softmax; it keeps ONE array for K and one for
+    V of ``max_len / eva_chunk + window`` rows, the summaries and the
+    window's block (``eva_rows``). ``norm_offset``: an RMS norm's
+    parameter is its gain's distance from one, ``(1 + g) x / rms(x)``;
+    ``head_precision`` "highest": the logits in float32 products."""
 
     FIELDS = ("vocab_size", "n_layer", "n_head", "d_model", "d_inner",
               "max_len", "tie_embeddings", "prefix", "eos_id")
@@ -227,9 +237,10 @@ class DecodeConfig:
                    ("router_topk_groups", 1), ("router_bias", False),
                    ("latent_ring", None), ("latent_rescale", False),
                    ("index_heads", 0), ("index_head_dim", 0),
-                   ("index_topk", 0))
+                   ("index_topk", 0), ("eva_chunk", 0),
+                   ("norm_offset", False), ("head_precision", None))
     MIXERS = ("mamba", "attention", "sliding", "gmu", "cross", "latent",
-              "kda", "latent_dsa", "latent_ring")
+              "kda", "latent_dsa", "latent_ring", "eva")
     # the kinds that keep latent rows
     LATENT_KINDS = ("latent", "latent_dsa", "latent_ring")
     RING_WIDTHS = ("n_head", "q_lora_rank", "kv_lora_rank", "qk_nope_dim",
@@ -292,6 +303,18 @@ class DecodeConfig:
         kinds = self.layer_kinds()
         if {"sliding", "latent_ring"} & set(kinds) and not self.window:
             raise ValueError("a sliding attention layer needs a window")
+        if "eva" in kinds:
+            w, c = int(self.window or 0), int(self.eva_chunk or 0)
+            if not (c > 0 and w > 0 and w % c == 0
+                    and self.max_len % c == 0
+                    and self.n_kv_head == self.n_head):
+                raise ValueError(
+                    "an eva layer needs eva_chunk, a window of whole "
+                    "chunks, a max_len of whole chunks and a key/value "
+                    "head a query head; got eva_chunk=%r window=%r "
+                    "max_len=%r n_kv_head=%r of %r"
+                    % (self.eva_chunk, self.window, self.max_len,
+                       self.n_kv_head, self.n_head))
         for i, kind in enumerate(kinds):
             if kind not in self.MIXERS:
                 raise ValueError("layer_types[%d] = %r is none of %s"
@@ -406,6 +429,12 @@ class DecodeConfig:
             rho(g["q_lora_rank"]), rho(g["kv_lora_rank"]))
 
     @property
+    def eva_rows(self):
+        """(summary rows, block rows) of an ``eva`` layer's entry: a row
+        a chunk of ``max_len`` positions, then the window's."""
+        return self.max_len // int(self.eva_chunk), int(self.window)
+
+    @property
     def tail_start(self) -> int:
         """The first layer from which on no layer owns a cache entry
         (``n_layer`` where the last layer owns one): a prefill runs the
@@ -447,6 +476,12 @@ class DecodeConfig:
         """Some layer keeps latent rows: a row per position that is
         neither K nor V (no head axis, one array a layer)."""
         return bool(set(self.LATENT_KINDS) & set(self.layer_kinds()))
+
+    @property
+    def has_eva(self) -> bool:
+        """Some layer keeps pooled rows and a window's block (kind
+        ``eva``): positions that left the window survive only pooled."""
+        return "eva" in self.layer_kinds()
 
     @property
     def extra_fetches(self) -> List[str]:
@@ -491,7 +526,10 @@ class CacheEntry(collections.namedtuple(
         "CacheEntry", "name shape dtype per_position")):
     """One array of a model's decode cache: its feed name, its shape
     at (slots, seq), its dtype, whether an admission writes it by rows
-    (``per_position``), and, read from those, its ``kind``:
+    (``per_position``); beside the four, ``stride``, the positions ONE
+    of its rows may stand for (1, but for an entry made by
+    ``CacheEntry.strided``: an ``eva`` entry, whose summary rows each
+    stand for a chunk); and, read from those, its ``kind``:
 
     - ``"rows"``: a row per position (a K/V slab, or an int8 slab's
       scales): axis 1 is the sequence, an admission writes ``[:sp]``
@@ -510,12 +548,32 @@ class CacheEntry(collections.namedtuple(
       kv_lora_rank + qk_rope_dim)`` of the layer kind's own widths;
     - ``"index"``: an indexer's keys, ``(slots, seq, index_head_dim)``:
       a row per position beside the latent slab of the same layer,
-      written and masked as that is (``ops/dsa.py``)."""
+      written and masked as that is (``ops/dsa.py``);
+    - ``"eva"``: an EVA layer's K or V, ``(slots, max_len / stride +
+      window, heads, width)`` whatever ``seq``: a row per CHUNK of
+      ``stride`` positions (pooled; the last chunk first) and after them
+      the window's block, position p at ``p mod window``, which restarts
+      empty when a window closes where a ring stays full
+      (``ops/eva.py``). A prefill hands it over as a step will find it
+      and an admission replaces it whole (``per_position`` false: a
+      caller that writes ``[:sp]`` rows or the whole entry needs to know
+      no more), a step writes one row of the block and, where its
+      position closes a chunk, one summary row."""
 
     __slots__ = ()
+    stride = 1
+
+    @classmethod
+    def strided(cls, stride: int, *fields) -> "CacheEntry":
+        """The entry ``fields`` whose rows may each stand for ``stride``
+        positions: the same four fields (it compares and sorts as
+        they do), the stride on its type."""
+        return _strided_entry(int(stride))(*fields)
 
     @property
     def kind(self) -> str:
+        if self.stride > 1:
+            return "eva"
         if self.per_position:
             # ``cache_names`` calls a latent layer's entry latent_i and
             # an indexer's keys index_i
@@ -531,6 +589,12 @@ class CacheEntry(collections.namedtuple(
         itemsize = _KV_ITEMSIZE.get(self.dtype) or np.dtype(
             self.dtype).itemsize  # numpy has no bfloat16
         return int(np.prod(self.shape)) * itemsize
+
+
+@functools.lru_cache(maxsize=None)
+def _strided_entry(stride: int):
+    return type("CacheEntry", (CacheEntry,),
+                {"__slots__": (), "stride": stride})
 
 
 def cache_spec(config: DecodeConfig, slots: int, seq: int,
@@ -573,7 +637,8 @@ def cache_spec(config: DecodeConfig, slots: int, seq: int,
             % (kv_dtype, ", ".join(sorted(set(
                 {"attention": "rows", "sliding": "ring", "mamba": "state",
                  "kda": "state", "latent": "latent",
-                 "latent_dsa": "latent", "latent_ring": "ring"}.get(
+                 "latent_dsa": "latent", "latent_ring": "ring",
+                 "eva": "eva"}.get(
                      k, "none")
                 for k in config.layer_kinds())))))
     from ..models.jamba import cache_names
@@ -601,6 +666,12 @@ def cache_spec(config: DecodeConfig, slots: int, seq: int,
         if kind == "sliding":
             ring = (slots, int(config.window)) + config.kv_row
             out += [CacheEntry(n, ring, "float32", False) for n in names]
+            continue
+        if kind == "eva":
+            rows = (slots, sum(config.eva_rows), config.n_head,
+                    config.d_head)
+            out += [CacheEntry.strided(config.eva_chunk, n, rows, "float32",
+                                       False) for n in names]
             continue
         if not names:  # reads another layer's entries, owns none
             continue
@@ -635,6 +706,15 @@ def _kept_pairs(prompts, k: int) -> int:
     choice of ``k``."""
     return sum(n * (n + 1) // 2 if n <= k else k * (k + 1) // 2 + (n - k) * k
                for n in map(len, prompts))
+
+
+def _eva_pairs(n: int, window: int, per: int) -> int:
+    """(query, key or summary) pairs of a prompt of ``n`` positions
+    under EVA: a query at t sees ``t mod window + 1`` keys of its own
+    window and ``per`` summaries a window closed before it."""
+    a, r = divmod(int(n), window)
+    return (a * window * (window + 1) // 2 + r * (r + 1) // 2
+            + per * (window * a * (a - 1) // 2 + a * r))
 
 
 @functools.lru_cache(maxsize=None)
@@ -955,6 +1035,16 @@ class DecodePredictor:
                 "neither K nor V: no verify window, row copy or int8 "
                 "quantisation is built over it"
                 % (what, self.config.latent_row))
+        if self.config.has_eva:
+            raise ValueError(
+                "%s needs a cache of rows per position (it rolls back by "
+                "length, or copies rows); this model's EVA layers keep a "
+                "window's block of %d rows that restarts when the window "
+                "closes and one pooled row a chunk of %d positions (cache "
+                "entries of kind 'eva'): a position that left its window "
+                "survives only pooled, so there are no rows to roll back "
+                "to or to share"
+                % (what, self.config.window, self.config.eva_chunk))
         if not self.config.is_opt_block:
             raise ValueError("%s is built for OPT's block only" % what)
 
@@ -1980,6 +2070,10 @@ class DecodeServer:
         # slot)
         self._index_topk = (int(cfg.index_topk)
                             if "latent_dsa" in kinds else 0)
+        # an EVA layer's (summary rows, window, chunk), or None: its
+        # step reads a slot's visible summaries and its window's rows
+        self._eva = (cfg.eva_rows[0], int(cfg.window), int(cfg.eva_chunk)
+                     ) if cfg.has_eva else None
 
     # -- submission (PredictorServer-compatible surface) -------------------
     def submit(self, sample: Sequence[np.ndarray]):
@@ -2193,6 +2287,7 @@ class DecodeServer:
     _has_tail = False
     _latent_row_bytes = 0
     _index_topk = 0
+    _eva = None
     _kda_state_bytes_per_slot = 0
     _ssm_layers = 0
 
@@ -2331,9 +2426,26 @@ class DecodeServer:
         causal mask, and those its attention kept (a query at position
         t keeps ``min(t + 1, index_topk)``). Of a model with latent
         layers over a window, ``window_pairs``: the pairs one such
-        layer attends (``min(t + 1, window)`` a query)."""
+        layer attends (``min(t + 1, window)`` a query). Of a model with
+        EVA layers, ``eva_window_rows`` and ``eva_summary_rows``: the
+        rows the admission leaves live in one such layer's entries (each
+        prompt's ``len mod window`` rows of its block and ``window /
+        eva_chunk`` summaries a window it closed), ``prompt_rows``,
+        ``bucket_rows``, ``prompts`` and ``attn_pairs``, here the
+        (query, key OR SUMMARY) pairs one such layer's prefill attends
+        (``_eva_pairs``)."""
         counts = {"entries": len(self._spec),
                   "state_slots": n if self._state_bytes_per_slot else 0}
+        if self._eva:
+            _, w, c = self._eva
+            counts.update(
+                eva_window_rows=sum(len(p) % w for p in prompts),
+                eva_summary_rows=sum(w // c * (len(p) // w)
+                                     for p in prompts),
+                prompt_rows=sum(len(p) for p in prompts),
+                bucket_rows=int(bucket_rows), prompts=len(prompts),
+                attn_pairs=sum(_eva_pairs(len(p), w, w // c)
+                               for p in prompts))
         if self._ring_window:
             counts["ring_rows"] = sum(
                 min(len(p), self._ring_window) for p in prompts)
@@ -2727,8 +2839,18 @@ class DecodeServer:
         ``streamed``: the kernels read a slot's live blocks, the lax
         forms every row of every slot), and ``rows_chosen``, the rows its
         attention kept: each live slot's ``min(length + 1,
-        index_topk)``."""
+        index_topk)``. Of a model with EVA layers, ``eva_window_rows``
+        and ``eva_summary_rows``: the rows one such layer's attention
+        reads, each live slot's ``length mod window + 1`` of its block
+        and ``window / eva_chunk`` summaries a closed window
+        (``attended`` is their sum, and ``streamed`` the rows of the
+        blocks that hold a slot's live range, every slot's, or every row
+        of every entry on the lax path), and ``eva_chunks_closed``: the
+        live slots whose position closes a chunk, which write its two
+        summary rows."""
         rows = self._stream_rows
+        if self._eva:
+            return self._eva_step_counts(lens, n_active)
         streamed = (self.slots * self.seq if rows is None
                     else int((lens // rows + 1).sum()) * rows)
         counts = {"active": n_active,
@@ -2754,6 +2876,29 @@ class DecodeServer:
             counts["kda_state_bytes"] = (
                 n_active * self._kda_state_bytes_per_slot)
         return counts
+
+    def _eva_step_counts(self, lens, n_active):
+        """``_step_counts`` of a model of EVA layers: a slot at position
+        p reads rows ``[n_sum - per (p // window), n_sum + p mod window]``
+        of each layer's two entries."""
+        n_sum, w, c = self._eva
+        per, rows = w // c, self._stream_rows
+        live = lens[lens > 0]
+        window_rows = int((live % w + 1).sum())
+        summary_rows = int((live // w).sum()) * per
+        if rows is None:
+            streamed = self.slots * (n_sum + w)
+        else:
+            first = np.maximum(n_sum - per * (lens // w), 0) // rows
+            streamed = int(((n_sum + lens % w) // rows + 1 - first).sum()
+                           ) * rows
+        obs.EVA_ROWS.inc(window_rows, kind="window")
+        obs.EVA_ROWS.inc(summary_rows, kind="summary")
+        return {"active": n_active, "attended": window_rows + summary_rows,
+                "streamed": streamed, "state_bytes": 0,
+                "eva_window_rows": window_rows,
+                "eva_summary_rows": summary_rows,
+                "eva_chunks_closed": int((live % c == c - 1).sum())}
 
     def _spec_round(self, drexe, vexe, caches, lens, active, n_active):
         """One speculative round across every active slot: spec_k draft

@@ -679,6 +679,11 @@ COVERED_ELSEWHERE = {
     # choice: tests/test_mla_ops.py (the grouped cases) and
     # tests/test_dots3_decode.py (select against lax.top_k)
     "latent_prefill", "dsa_index_keys", "dsa_mask",
+    # EVA (pooled chunks, windows under one softmax, the entry's packing,
+    # a step's appends and its live range): tests/test_evabyte_decode.py
+    # (against the plain reference through the server, the kernel's view
+    # against the lax path)
+    "eva_summaries", "eva_prefill", "eva_pack", "eva_append", "eva_decode",
     # in-graph sampling: tests/test_sampling_ops.py
     "greedy_sample", "top_k_sample", "top_p_sample",
     # metrics: tests/test_aux.py

@@ -318,3 +318,70 @@ def test_dots3_serving_step_compiles(one_chip, monkeypatch, kind, batch,
     # no expanded K or V: nothing of slots x positions x heads
     assert "f32[32,16384,128," not in text
     assert mem.temp_size_in_bytes < 400 * 2**20, mem.temp_size_in_bytes
+
+
+_EVABYTE_CASES = [
+    # id, kind, batch, seq: the EvaByte serving cell's own programs
+    # (benchmark/configs/evabyte.json: 4 layers at published widths, 16
+    # slots of 16,384 positions; the widest admissions are one prompt of
+    # the 16,384 bucket and four of the 4,096 one)
+    ("decode-16x16384", "decode", 16, 16384),
+    ("prefill-1x16384", "prefill", 1, 16384),
+]
+
+
+@pytest.mark.parametrize("kind,batch,seq", [c[1:] for c in _EVABYTE_CASES],
+                         ids=[c[0] for c in _EVABYTE_CASES])
+def test_evabyte_serving_step_compiles(one_chip, monkeypatch, kind, batch,
+                                       seq):
+    """The programs DecodePredictor builds for the EvaByte cell (32
+    heads of 128 on as many key/value heads, a window of 2,048 and
+    chunks of 16, an MLP of 11,008, a head of 320 ids): they compile for
+    a v5e and fit it beside each other. A prefill holds one causal flash
+    call over every window's own rows (the windows folded into the
+    batch) and one a window over the summaries it sees, seven at 16,384
+    rows, all `ptpu.eva_prefill` on bfloat16 operands, and no (T, T)
+    mask; the step donates its eight entries, attends each by ONE call
+    of `ptpu.eva_attn` (the two-pass body with a start) and holds no
+    copy of an entry: the rows a chunk is pooled from are sliced a slot
+    at a time (under `vmap` the gather made the compiler lay every entry
+    out anew: eight copies of 805 MB a step)."""
+    from test_tpu_compile_cells import _cell_predictor
+
+    pred = _cell_predictor("evabyte_lm", "evabyte.json", monkeypatch)
+    step_fn, feeds, state, n_cache = _serving_step(pred, kind, batch, seq,
+                                                   one_chip)
+    compiled = jax.jit(step_fn, donate_argnums=(0,)).lower(
+        feeds, state).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < HBM_BYTES, "does not fit one chip: %r" % (mem,)
+    weights = sum(int(np.prod(s.shape)) * 4 for s in state.values())
+    assert 3.24e9 < weights < 3.26e9, weights  # 812.2 M parameters
+    text = compiled.as_text()
+    spec = pred.cache_spec(16, 16384)
+    slabs = sum(e.nbytes for e in spec)
+    assert round(slabs / 1e9, 2) == 6.44
+    calls = re.findall(r"%([\w.-]+?)(?:\.\d+)? = [^\n]*"
+                       r'custom_call_target="tpu_custom_call"', text)
+    if kind == "prefill":
+        # a layer: the folded call and windows 1..7 over their summaries
+        assert calls.count("ptpu.eva_prefill") == 4 * 8, calls
+        assert "bf16[8,2048,4096]" in text      # the folded operands
+        assert "bf16[1,896,4096]" in text       # window 7's 896 summaries
+        assert "f32[1,32,16384,16384]" not in text and "s8[" not in text
+        assert "f32[1,3072,32,128]" in text     # an entry as a step finds it
+        # beside the weights, the entries and the step
+        assert weights + slabs + mem.temp_size_in_bytes + (
+            mem.output_size_in_bytes) < 14.5 * 2**30, mem
+        assert mem.temp_size_in_bytes < 2.5 * 2**30, mem.temp_size_in_bytes
+        return
+    assert [c for c in calls if c.startswith("ptpu.")] == [
+        "ptpu.eva_attn"] * 4, calls
+    assert n_cache == len(spec) == 8
+    assert mem.alias_size_in_bytes >= slabs
+    ops = _whole_slab_ops(text, (16, 3072, 32, 128))
+    assert ops and not [name for op, name, changed in ops
+                        if op in ("copy", "transpose") or changed], ops
+    assert mem.temp_size_in_bytes < 100 * 2**20, mem.temp_size_in_bytes

@@ -50,6 +50,8 @@ CELLS = [
      [("decode", 64, 16384), ("prefill", 2, 2048)]),
     ("dots3_lm", "dots3-note-prev.json",
      [("decode", 32, 16384), ("prefill", 4, 4096)]),
+    ("evabyte_lm", "evabyte.json",
+     [("decode", 16, 16384), ("prefill", 2, 8192)]),
 ]
 
 
