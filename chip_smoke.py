@@ -198,7 +198,8 @@ def phase_kda(cfg):
     rows handed to the step, then one step after another (outputs and
     the last state); a row's padding leaves its state alone. On a TPU
     the scan is the KERNEL (one `ptpu.kda_scan` call in the compiled
-    text, asserted); elsewhere the lax form."""
+    text, asserted) and so is the step (one `ptpu.kda_step` call, since
+    PR 48); elsewhere the lax forms."""
     import jax
     import jax.numpy as jnp
 
@@ -220,10 +221,17 @@ def phase_kda(cfg):
     text = scan.lower(q, k, v, g, beta, jnp.asarray(lens)).compile().as_text()
     calls = sum("%ptpu.kda_scan" in ln.split(" = ")[0]
                 and "tpu_custom_call" in ln for ln in text.splitlines())
+    step_path = ("kernel" if kda._use_step_kernel(h, d, d, jnp.float32)
+                 else "lax")
+    text = step.lower(*(a[:, :1] for a in (q, k, v, g, beta)), jnp.zeros(
+        (b, h, d, d), jnp.float32)).compile().as_text()
+    step_calls = sum("%ptpu.kda_step" in ln.split(" = ")[0]
+                     and "tpu_custom_call" in ln for ln in text.splitlines())
     if jax.devices()[0].platform == "tpu":
-        assert (path, calls) == ("kernel", 1), (
-            "the scan at %r: path %s, %d kernel calls" % ((b, t, h, d),
-                                                         path, calls))
+        assert (path, calls, step_path, step_calls) == (
+            "kernel", 1, "kernel", 1), (
+            "at %r: the scan's path %s, %d kernel calls; the step's %s, %d"
+            % ((b, t, h, d), path, calls, step_path, step_calls))
     o_all, s_all = scan(q, k, v, g, beta, jnp.asarray(lens))
     _, state = scan(q, k, v, g, beta, jnp.asarray(lens - steps))
     outs = []
@@ -241,7 +249,8 @@ def phase_kda(cfg):
         assert e <= TOL_KDA, ("kda_scan vs kda_step, %s: %.3g > %.3g"
                               % (name, e, TOL_KDA))
     _emit("kda", shape=[b, t, h, d], lengths=lens.tolist(), steps=steps,
-          path=path, kernel_calls=calls, rel_err=errs, ok=True)
+          path=path, kernel_calls=calls, step_path=step_path,
+          step_kernel_calls=step_calls, rel_err=errs, ok=True)
 
 
 # -- phase 2: train ---------------------------------------------------------

@@ -61,6 +61,7 @@ __all__ = [
     "TRANSPILE_OPS_REMOVED", "TRANSPILE_OPS_FUSED", "TRANSPILE_PASS_MS",
     "QUANT_CALIB_BATCHES", "QUANT_OPS", "QUANT_PARITY",
     "FUSED_HEAD_TRACES", "MLA_TRACES", "SSM_SCAN_TRACES", "KDA_SCAN_TRACES",
+    "KDA_STEP_TRACES",
     "PREFILL_ATTN_TRACES", "MOE_TOKENS_ELSEWHERE",
 ]
 
@@ -109,6 +110,15 @@ KDA_SCAN_TRACES = REGISTRY.counter(
     "over every chunk of the bucket: the CPU, a gate with no lower "
     "bound, a shape the kernel does not take). Counted when the op is "
     "traced: a program loaded from a cache adds 0")
+KDA_STEP_TRACES = REGISTRY.counter(
+    "paddle_tpu_kda_step_traces_total",
+    "Traces of the delta rule's one-token step (ops/kda.py), by "
+    "path=kernel (one Pallas call a layer: a block of heads' matrix "
+    "states read once, updated in vector memory and written once over "
+    "their own input) | lax (exact float32 lax that passes over a state "
+    "twice and a half: the CPU, a state that is not float32 or not whole "
+    "128 x 128 tiles). Counted when the op is traced: a program loaded "
+    "from a cache adds 0")
 PREFILL_ATTN_TRACES = REGISTRY.counter(
     "paddle_tpu_prefill_attn_traces_total",
     "Traces of a serving prefill's causal attention (ops/attention.py: "

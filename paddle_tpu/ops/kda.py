@@ -18,10 +18,18 @@ THREE forms that compute the same numbers:
 - token by token (a ``lax.scan`` of ``_update`` a token: the tests' and
   the reference's form, which no serving path runs and this file does
   not hold);
-- one token (``kda_step``, ``ptpu.kda_step``: a decode step's; the state
-  read once for the two products ``S^T [k, q]`` and once more for its
-  update, written once, all in float32 multiplies and adds: a
-  contraction would round the state to bfloat16 on a TPU);
+- one token (``kda_step``, ``ptpu.kda_step``: a decode step's, all in
+  float32 multiplies and adds: a contraction would round the state to
+  bfloat16 on a TPU). TWO PATHS of that arithmetic
+  (``paddle_tpu_kda_step_traces_total{path}``; ``_use_step_kernel``
+  chooses by shape, type and device): ``_update``, five lax lines that
+  XLA makes two fusions of (the state read once for the two products
+  ``S^T [k, q]`` and once more for its update, written once: the CPU's
+  path, a narrower state's, the kernel's reference and its backward),
+  and on a TPU one Pallas call a layer (``pallas_kda_step``, since
+  PR 48): a block of heads' states comes into vector memory once, the
+  whole token is done there, and the block goes out once over its own
+  input (the operand aliased to the result);
 - CHUNKED (``kda_scan``, ``ptpu.kda_scan``: a prefill's). With ``G_t``
   the log-decay summed from a chunk's start through token t, ``u_t =
   beta_t (v_t - S_{t-1}^T (alpha_t k_t))`` and ``S_t = Diag(alpha_t)
@@ -86,7 +94,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..observability import KDA_SCAN_TRACES
+from ..observability import KDA_SCAN_TRACES, KDA_STEP_TRACES
 from . import attention as _A
 from . import kv_cache as _KV
 from .registry import register_op
@@ -754,19 +762,136 @@ def kda_scan(q, k, v, g, beta, lengths=None, lower_bound=None, qk_norm=True,
                                      _guarded(lower_bound), qk_norm)
         return o.astype(v.dtype), state
 
-def kda_step(q, k, v, g, beta, state, qk_norm=True):
+# bytes of matrix states a grid cell of the step's kernel holds: the block
+# comes in and goes out double-buffered, four of it beside the compiler's
+# default 16 MiB of scoped vector memory
+_STEP_BLOCK_BYTES = 2 * 2**20
+
+
+def _step_heads(h, dk, dv):
+    """Heads a grid cell of the step's kernel takes: the most that
+    divide ``h``, fill whole sublane tiles of the (B, H, d) operands'
+    blocks (a multiple of 8, or all of them) and whose states are no
+    more than ``_STEP_BLOCK_BYTES``; None where no such block is."""
+    fits = [n for n in range(1, h + 1)
+            if h % n == 0 and (n % 8 == 0 or n == h)
+            and n * dk * dv * 4 <= _STEP_BLOCK_BYTES]
+    return max(fits) if fits else None
+
+
+def _use_step_kernel(h, dk, dv, dtype) -> bool:
+    """A step bound for a TPU (the rule every kernel shares,
+    ``kv_cache._use_pallas_decode``: PADDLE_TPU_NO_PALLAS opts out), a
+    float32 state of whole 128 x 128 tiles and a block of heads that
+    fits (``_step_heads``)."""
+    return (jnp.dtype(dtype) == jnp.float32
+            and _step_heads(h, dk, dv) is not None
+            and _KV._use_pallas_decode(dk, dv))
+
+
+def _kda_step_kernel(s_ref, q_ref, k_ref, g_ref, v_ref, b_ref, o_ref,
+                     so_ref):
+    """One (slot, block of heads) grid cell: s_ref, so_ref (1, hb, dk,
+    dv) the states' block, which comes in once and goes out once over
+    itself; q_ref, k_ref, g_ref (1, hb, dk), v_ref, o_ref (1, hb, dv),
+    b_ref (1, hb, 1). ``_update``'s float32 multiplies and adds a head
+    at a time; only the sums over ``dk`` run in another order. A state's
+    rows are its key channels (down the sublanes), so q, k and the decay
+    of a head are wanted as COLUMNS, each the same along the lanes: the
+    block's 3 x hb rows are transposed once, and a head's column is one
+    lane of the result, broadcast. (That costs nothing on the chip: the
+    kernel runs as fast with no column at all, at what an in-place
+    stream of the states reaches: ``tools/kda_step_probe.py``.)"""
+    hb, dk, dv = s_ref.shape[1:]
+    q, k, decay = q_ref[0], k_ref[0], jnp.exp(g_ref[0])
+    qk = jnp.sum(q * k, axis=-1, keepdims=True)               # (hb, 1)
+    turned = jnp.concatenate(
+        [q, k, decay, jnp.zeros((128 - 3 * hb, dk), jnp.float32)],
+        axis=0).T                                             # (dk, 128)
+
+    def column(j):
+        return jnp.broadcast_to(turned[:, j:j + 1], (dk, dv))
+
+    for h in range(hb):
+        k_c = column(hb + h)
+        s = s_ref[0, h] * column(2 * hb + h)
+        sk = jnp.sum(s * k_c, axis=0, keepdims=True)          # (1, dv)
+        sq = jnp.sum(s * column(h), axis=0, keepdims=True)
+        u = b_ref[0, h:h + 1, :] * (v_ref[0, h:h + 1, :] - sk)
+        so_ref[0, h] = s + k_c * u
+        o_ref[0, h:h + 1, :] = sq + qk[h:h + 1] * u
+
+
+def pallas_kda_step(state, q, k, v, g, beta, heads=None, interpret=False):
+    """``_update``'s contract through the kernel: ONE call, a block of
+    ``heads`` heads of one slot a grid cell (``_step_heads``), the state
+    operand aliased to the state result: no second copy of the states
+    exists, in the call or around it."""
+    bsz, h, dk, dv = state.shape
+    hb = _step_heads(h, dk, dv) if heads is None else heads
+    if hb is None or dk % 128 or dv % 128:
+        raise ValueError(
+            "no kernel for a step of (%d, %d, %d, %d) states; the lax form "
+            "runs it" % (bsz, h, dk, dv))
+    f32 = jnp.float32
+
+    def block(*tail):
+        return pl.BlockSpec((1, hb) + tail,
+                            lambda bi, hi: (bi, hi) + (0,) * len(tail))
+
+    return _A.named_pallas_call(
+        KDA_STEP, _kda_step_kernel,
+        grid=(bsz, h // hb),
+        in_specs=[block(dk, dv), block(dk), block(dk), block(dk), block(dv),
+                  block(1)],
+        out_specs=[block(dv), block(dk, dv)],
+        out_shape=[jax.ShapeDtypeStruct((bsz, h, dv), f32),
+                   jax.ShapeDtypeStruct((bsz, h, dk, dv), f32)],
+        input_output_aliases={0: 1},
+        interpret=interpret,
+        **_A._tpu_params("parallel", "parallel"),
+    )(state, q, k, g, v, beta[..., None])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _kda_step_kernel_path(state, q, k, v, g, beta, interpret):
+    return pallas_kda_step(state, q, k, v, g, beta, interpret=interpret)
+
+
+def _step_path_fwd(state, q, k, v, g, beta, interpret):
+    operands = (state, q, k, v, g, beta)
+    return pallas_kda_step(*operands, interpret=interpret), operands
+
+
+def _step_path_bwd(interpret, operands, cts):
+    # a gradient through a step is the lax form's
+    return jax.vjp(_update, *operands)[1](cts)
+
+
+_kda_step_kernel_path.defvjp(_step_path_fwd, _step_path_bwd)
+
+
+def kda_step(q, k, v, g, beta, state, qk_norm=True, interpret=False):
     """One token: q, k, g (B, 1, H, dk) or (B, H, dk), v (B, 1, H, dv),
     beta (B, 1, H), state (B, H, dk, dv) -> (o shaped as v, new state):
-    ``S <- alpha S; S <- S + beta k (v - S^T k)^T; o = S^T q``."""
+    ``S <- alpha S; S <- S + beta k (v - S^T k)^T; o = S^T q``. The
+    kernel where ``_use_step_kernel`` says so (``interpret``: the kernel
+    in interpret mode, whatever the device: the tests' way in), else
+    ``_update``."""
     bsz, h, dk, dv = state.shape
+    kernel = interpret or _use_step_kernel(h, dk, dv, state.dtype)
+    KDA_STEP_TRACES.inc(path="kernel" if kernel else "lax")
     with jax.named_scope(KDA_STEP):
         q, k = _prepare(q.reshape(bsz, h, dk), k.reshape(bsz, h, dk),
                         qk_norm)
-        o, new = _update(
-            state.astype(jnp.float32), q, k,
-            v.reshape(bsz, h, dv).astype(jnp.float32),
-            g.reshape(bsz, h, dk).astype(jnp.float32),
-            beta.reshape(bsz, h).astype(jnp.float32))
+        operands = (state.astype(jnp.float32), q, k,
+                    v.reshape(bsz, h, dv).astype(jnp.float32),
+                    g.reshape(bsz, h, dk).astype(jnp.float32),
+                    beta.reshape(bsz, h).astype(jnp.float32))
+        if kernel:
+            o, new = _kda_step_kernel_path(*operands, interpret)
+        else:
+            o, new = _update(*operands)
         return o.reshape(v.shape).astype(v.dtype), new.astype(state.dtype)
 
 
