@@ -511,8 +511,7 @@ class Executor:
         """Opt-in (``observability.TIMELINE.set_hlo_cost(True)``): lower +
         compile the jitted fn explicitly on abstract avals so the compile
         timeline event can split trace time from XLA compile time and
-        carry the executable's cost-analysis FLOPs/bytes estimates (the
-        numbers tools/hlo_stats.py mines from an xprof capture). Only the
+        carry the executable's cost-analysis FLOPs/bytes estimates. Only the
         LAZY-jit fallback path (disk tier disabled) uses this — it pays
         one extra compile per cache miss, which is why it is off by
         default; the AOT path gets the same split for free. Returns a

@@ -18,12 +18,15 @@ replays.
 """
 from __future__ import annotations
 
+import contextlib
+import re
+import threading
 from typing import Callable, Dict, List
 
 import jax
 import jax.numpy as jnp
 
-from ..ops.registry import OpContext, get_kernel
+from ..ops.registry import NAMES_DEVICE_CALLS, OpContext, get_kernel
 from .core import Block, Operator, grad_var_name
 
 # op types the tracer interprets (or skips) itself rather than via a kernel:
@@ -99,9 +102,6 @@ def _o2_eligible(op, block) -> bool:
 # in the incoming dtype — pinning it would stream fp32 copies of every
 # activation through HBM between bf16 convs (profiled on ResNet-50).
 
-
-import contextlib
-import threading
 
 # Mesh the step is being traced under (set by ParallelExecutor around the
 # first call of its jitted step). Kernels that have a distributed
@@ -185,7 +185,35 @@ def _apply_outputs(op: Operator, block: Block, env: Dict, result: Dict):
             env[name] = val
 
 
-def trace_op(op: Operator, block: Block, env: Dict, rng_fn, subblock_fn=None):
+_UNSCOPABLE = re.compile(r"[^\w.:@\-]")
+
+
+def op_scope(op: Operator, block: Block) -> str:
+    """``fl.<op.type>:<anchor>``, the ``jax.named_scope`` a Fluid op's
+    kernel is traced under: the anchor is the op's first persistable
+    input (a weight, a table: ``lm.l3.ffn.w1``), else its first output's
+    name. Every HLO instruction the kernel makes carries it in its
+    ``op_name``, which is how ``observability.scopes`` lays a device
+    operation under the op that made it."""
+    anchor = next(
+        (n for n in op.input_arg_names
+         if getattr(block._find_var_recursive(n), "persistable", False)),
+        next(iter(op.output_arg_names), ""))
+    return "fl.%s:%s" % (op.type, _UNSCOPABLE.sub("_", anchor))
+
+
+def trace_op(op: Operator, block: Block, env: Dict, rng_fn, subblock_fn=None,
+             differentiated: bool = False):
+    """Run ``op``'s kernel on ``env`` under ``op_scope``. Metadata only:
+    the lowered text without locations is the same with or without the
+    scope. ``differentiated`` (the replay inside an ``autodiff``'s vjp)
+    leaves the scope off an op whose kernel names its own device calls
+    (``ops.registry.NAMES_DEVICE_CALLS``): jax wraps ``jvp(..)`` around
+    the OUTERMOST scope under the transform, and the TPU compiler names
+    a Mosaic call after the innermost component, so with a scope between
+    the two ``jvp_ptpu.flash_fwd_.N`` would become ``ptpu.flash_fwd.N``
+    and the readers that tell a training step's forward kernel from its
+    backward by that name would find neither."""
     kernel = get_kernel(op.type)
     view = _EnvView(env, op)
     if getattr(block.program, "_amp", False):
@@ -197,8 +225,12 @@ def trace_op(op: Operator, block: Block, env: Dict, rng_fn, subblock_fn=None):
         elif op.type in _AMP_FP32_OPS:
             view = _CastEnvView(env, op, jnp.float32)
     ctx = OpContext(op, view, rng_fn, subblock_fn, block)
+    scope = (contextlib.nullcontext()
+             if differentiated and op.type in NAMES_DEVICE_CALLS
+             else jax.named_scope(op_scope(op, block)))
     try:
-        result = kernel(ctx)
+        with scope:
+            result = kernel(ctx)
     except (NotImplementedError,):
         raise
     except Exception as e:
@@ -264,18 +296,27 @@ class _CastEnvView(_EnvView):
         return v
 
 
-def trace_block(block: Block, env: Dict, rng: RngStream) -> Dict:
-    """Trace all ops of `block` into `env` (mutated in place and returned)."""
+def trace_block(block: Block, env: Dict, rng: RngStream,
+                differentiated: bool = False) -> Dict:
+    """Trace all ops of `block` into `env` (mutated in place and returned).
+    ``differentiated``: the block is a sub-block of an op that an
+    ``autodiff`` replays (``trace_op``)."""
     program = block.program
 
-    def subblock_fn(block_idx: int, sub_env: Dict, salt=None) -> Dict:
-        if salt is None:
-            return trace_block(program.block(block_idx), sub_env, rng)
-        rng.salts.append(salt)
-        try:
-            return trace_block(program.block(block_idx), sub_env, rng)
-        finally:
-            rng.salts.pop()
+    def subblocks(differentiated: bool):
+        def subblock_fn(block_idx: int, sub_env: Dict, salt=None) -> Dict:
+            sub = program.block(block_idx)
+            if salt is None:
+                return trace_block(sub, sub_env, rng, differentiated)
+            rng.salts.append(salt)
+            try:
+                return trace_block(sub, sub_env, rng, differentiated)
+            finally:
+                rng.salts.pop()
+        return subblock_fn
+
+    subblock_fn = subblocks(differentiated)
+    replayed_subblock_fn = subblocks(True)
 
     env_start = dict(env)
     # (op, op_idx) pairs replayed inside each vjp. Frozen at the first
@@ -303,7 +344,7 @@ def trace_block(block: Block, env: Dict, rng: RngStream) -> Dict:
                 continue
             trace_op(op, block, env, rng.for_op(block.idx,
                                                 _rng_idx(op, op_idx)),
-                     subblock_fn)
+                     subblock_fn, differentiated)
             continue
 
         # -- autodiff: differentiate loss wrt params over the full forward
@@ -316,7 +357,8 @@ def trace_block(block: Block, env: Dict, rng: RngStream) -> Dict:
             fenv = dict(env_start)
             fenv.update(pvals)
             for fop, fidx in replay:
-                trace_op(fop, block, fenv, rng.for_op(block.idx, fidx), subblock_fn)
+                trace_op(fop, block, fenv, rng.for_op(block.idx, fidx),
+                         replayed_subblock_fn, True)
             if loss_name not in fenv:
                 raise TraceError(
                     "loss %r is not computed by the forward ops preceding "

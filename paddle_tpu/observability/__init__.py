@@ -350,8 +350,11 @@ DECODE_SLOTS = REGISTRY.gauge(
     "slots or add replicas)")
 DECODE_STEP_MS = REGISTRY.histogram(
     "paddle_tpu_decode_step_ms",
-    "Wall time per decode iteration, stage=prefill (one admission "
-    "sub-batch) | step (one token across every active slot: from the "
+    "Wall time per decode iteration, stage=prefill (a server's "
+    "admission sub-batch, from its prefill's dispatch to its logits on "
+    "the host: the decode.loop.prefill and first_token phases' sum; "
+    "DecodePredictor.generate's own prefill: the dispatch alone) | step "
+    "(one token across every active slot: from the "
     "token before it reaching the host, or from its own dispatch where "
     "no step was in flight, to its token reaching the host)")
 DECODE_REQUESTS = REGISTRY.counter(

@@ -10,9 +10,8 @@ tier, lowered and compiled, or traced inside a first call through
 ``jax.jit``: ``observability.observe_acquire``, the one writer) appends
 a compile event with the executable's name, the path it came by, when
 it began, its wall time and the parts of it, and (when available) XLA
-cost-analysis FLOPs/bytes estimates — the same numbers
-tools/hlo_stats.py extracts from an xprof capture, obtained here
-straight from the compiled executable. Per-request serving latency is
+cost-analysis FLOPs/bytes estimates, straight from the compiled
+executable. Per-request serving latency is
 NOT a timeline event; it lives in the registry's
 ``paddle_tpu_predict_latency_ms`` histogram.
 
@@ -48,8 +47,8 @@ _COMPILE_CAP = 1024
 
 def hlo_cost_stats(compiled) -> Optional[Dict[str, float]]:
     """FLOPs / bytes-accessed estimates from a ``jax.stages.Compiled``
-    (the numbers tools/hlo_stats.py derives from a trace, minus the
-    runtime). Returns None when the backend exposes no cost analysis."""
+    (the compiler's own; no trace, no runtime). Returns None when the
+    backend exposes no cost analysis."""
     try:
         cost = compiled.cost_analysis()
         # some jax versions return a list with one dict per computation

@@ -876,7 +876,7 @@ def pallas_flash_fwd(q, k, v, causal=False, scale=None,
                                   interpret=interpret)
 
 
-@register_op("fused_attention")
+@register_op("fused_attention", names_device_calls=True)
 def _fused_attention(ctx):
     """Inputs Q,K,V: (B, H, T, Dh) — or (B, T, H, Dh) with attr
     layout="bthd" (+ optional Lengths for KV padding). Attrs: causal,

@@ -21,15 +21,23 @@ from __future__ import annotations
 from typing import Callable, Dict, List, Optional
 
 KERNELS: Dict[str, Callable] = {}
+# op types registered with ``names_device_calls`` (``register_op``)
+NAMES_DEVICE_CALLS: set = set()
 
 # ops that need train/test awareness, rng, etc. can inspect ctx freely.
 
 
-def register_op(op_type: str):
+def register_op(op_type: str, names_device_calls: bool = False):
+    """``names_device_calls``: the kernel names Mosaic calls that a
+    reader tells apart by the transform jax wraps around them
+    (``jvp_ptpu.flash_fwd_``): ``trace.trace_op`` then opens no scope
+    of its own around the kernel where it is differentiated."""
     def deco(fn):
         if op_type in KERNELS:
             raise ValueError("duplicate kernel for op %r" % op_type)
         KERNELS[op_type] = fn
+        if names_device_calls:
+            NAMES_DEVICE_CALLS.add(op_type)
         return fn
 
     return deco

@@ -633,7 +633,8 @@ def build_pipeline_step_fn(program: Program, fetch_names, state_in,
                 srng.salts = [dp_ix, mb_idx]
                 for op, idx in plan.prologue:
                     trace_op(op, block, penv,
-                             srng.for_op(block.idx, idx), subblock_err)
+                             srng.for_op(block.idx, idx), subblock_err,
+                             differentiated=True)
                 return mb_idx + 1, {n: penv[n] for n in pro_keep}
 
             xs_pro = {n: feeds_loc[n] for n in pro_feed}
@@ -668,7 +669,8 @@ def build_pipeline_step_fn(program: Program, fetch_names, state_in,
                 srng.salts = [dp_ix, mb_ix, rep_ix]
                 for op, idx in plan.template:
                     trace_op(op, block, renv,
-                             srng.for_op(block.idx, idx), subblock_err)
+                             srng.for_op(block.idx, idx), subblock_err,
+                             differentiated=True)
                 return renv[plan.carry_tpl_out]
 
             perm = [(i, (i + 1) % S) for i in range(S)]
@@ -771,7 +773,8 @@ def build_pipeline_step_fn(program: Program, fetch_names, state_in,
                 srng.salts = [dp_ix, mb_idx + 3]
                 for op, idx in plan.epilogue:
                     trace_op(op, block, eenv,
-                             srng.for_op(block.idx, idx), subblock_err)
+                             srng.for_op(block.idx, idx), subblock_err,
+                             differentiated=True)
                 return mb_idx + 1, {n: eenv[n] for n in epi_keep}
 
             xs_epi = (outs, {n: feeds_loc[n] for n in epi_feed},

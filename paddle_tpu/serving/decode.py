@@ -2253,11 +2253,14 @@ class DecodeServer:
         a 16-token prompt into a 1024-token slab must cost a 16-token
         forward (this is what lets continuous admission beat gang
         scheduling — a slab-sized prefill per admission would eat the
-        win) — run the prefill executable, and account it. Returns
-        ``(outs, sp)``: the raw executable outputs (logits + per-layer
-        float K/V sub-slabs) and the sequence bucket they are shaped
-        at. Raises what the acquire/execute raises — the caller owns
-        the admission-failure contract."""
+        win) — DISPATCH the prefill executable, and account its
+        tokens. Returns ``(outs, sp, t0)``: the raw executable outputs
+        (logits + per-layer float K/V sub-slabs, still on their way),
+        the sequence bucket they are shaped at, and the clock at the
+        dispatch: the caller waits for the logits under
+        ``decode.loop.first_token`` and hands ``t0`` to
+        ``_observe_prefill`` there. Raises what the acquire/execute
+        raises — the caller owns the admission-failure contract."""
         bb = _pow2_bucket(len(prompts))
         sp = min(_pow2_bucket(max(len(p) for p in prompts), floor=16),
                  self.seq)
@@ -2272,11 +2275,23 @@ class DecodeServer:
             outs = pexe({"tokens": tokens, "lengths": plens},
                         self.predictor._state)
         self.prefill_executions += 1
-        obs.DECODE_STEP_MS.observe(
-            ((ph.t1 or time.perf_counter()) - t0) * 1e3, stage="prefill")
         obs.DECODE_TOKENS.inc(int(plens[:len(prompts)].sum()),
                               kind="prefill")
-        return outs, sp
+        return outs, sp, t0
+
+    @staticmethod
+    def _observe_prefill(t0, waited) -> float:
+        """An admission's prefill, in ms, from its dispatch (``t0`` of
+        ``_prefill_prompts``) to its logits on the host (the end of the
+        ``first_token`` phase ``waited``): the sample of
+        ``paddle_tpu_decode_step_ms{stage="prefill"}`` and the
+        ``prefill_ms`` of the ``decode.admit`` span. ``pexe(...)`` alone
+        returns at once; the device's time shows where the host waits.
+        At rate 0 the phases read no clock and this reads the one that
+        closes the pair."""
+        ms = ((waited.t1 or time.perf_counter()) - t0) * 1e3
+        obs.DECODE_STEP_MS.observe(ms, stage="prefill")
+        return ms
 
     # a model without sliding-window or expert layers has neither, and
     # one whose every layer owns its cache entries no shared slab's
@@ -2340,9 +2355,7 @@ class DecodeServer:
             return self._admit_prefix(batch, free, caches, lens, active)
         n = len(batch)
         try:
-            t_pf = time.perf_counter()
-            outs, sp = self._prefill_prompts([b[1] for b in batch])
-            pf_ms = (time.perf_counter() - t_pf) * 1e3
+            outs, sp, t_pf = self._prefill_prompts([b[1] for b in batch])
         except Exception as e:
             # an admission that cannot prefill (compile error, device
             # OOM) fails ITS requests and leaves the server serving —
@@ -2350,7 +2363,7 @@ class DecodeServer:
             for rid, _p, _mn, _seed in batch:
                 self._fail(rid, e)
             return caches
-        with _tracing.phase("decode.loop.first_token"):
+        with _tracing.phase("decode.loop.first_token") as waited:
             # the host waits here for the prefill's logits
             first = np.array(self.predictor._sample_host(
                 outs[0], self.strategy, self._seed_ctr))  # writable copy
@@ -2365,6 +2378,7 @@ class DecodeServer:
                 if seed is not None and self.strategy not in ("greedy",):
                     first[i] = self.predictor._sample_host(
                         outs[0][i:i + 1], self.strategy, seed)[0]
+        pf_ms = self._observe_prefill(t_pf, waited)
         if self._moe_layers:
             # the prefill has ended (the host has its logits): no wait
             self._note_load(outs[-1])
@@ -2613,13 +2627,12 @@ class DecodeServer:
             uniq_eids: List[Optional[int]] = []
             pf_ms = 0.0
             if uniq_prompts:
-                t_pf = time.perf_counter()
-                outs, _sp = self._prefill_prompts(uniq_prompts)
-                pf_ms = (time.perf_counter() - t_pf) * 1e3
-                with _tracing.phase("decode.loop.first_token"):
+                outs, _sp, t_pf = self._prefill_prompts(uniq_prompts)
+                with _tracing.phase("decode.loop.first_token") as waited:
                     # the host waits for the prefill: logits AND rows
                     sub = [np.asarray(c) for c in outs[1:]]
                     logits_all = np.asarray(outs[0])
+                pf_ms = self._observe_prefill(t_pf, waited)
                 for i, p in enumerate(uniq_prompts):
                     rows = [s[i, :len(p)] for s in sub]
                     uniq_rows.append(rows)

@@ -38,7 +38,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from .. import observability as obs
-from ..observability import tracing
+from ..observability import scopes, tracing
 from ..runtime import aot_cache as _aot
 
 __all__ = ["Engine"]
@@ -237,7 +237,10 @@ class Engine:
         the acquisition is also a ``tracing.phase("acquire")`` with
         children ``acquire.load | .trace | .xla | .store``: a record of
         the flight recorder's process ring, and under a profiler session
-        a host span on the device events' clock.
+        a host span on the device events' clock. The executable is
+        registered under the record's name with ``observability.scopes``
+        (a dict insert: its text is rendered when a reader asks
+        ``scopes.maps()``, never here).
 
         ``lower`` may raise (program errors propagate exactly as the
         lazy-jit first call would); disk I/O failures are absorbed by
@@ -290,10 +293,12 @@ class Engine:
                     parts.update(describe(compiled))
                 parts["describe_ms"] = (clock() - t) * 1e3
         compile_ms = (t3 - t1) * 1e3 if timings else None
+        record = name or "%s/%s" % (kind, fp)
         obs.observe_acquire(
             kind, path, build_ms + (clock() - t0) * 1e3, program=fp,
-            name=name, ts=ts, phase=began_under,
+            name=record, ts=ts, phase=began_under,
             aot_ms=load_ms if path == "warm" else compile_ms,
             disk=use_disk,
             compile_ms=compile_ms if counts_compile else None, **parts)
+        scopes.register(record, compiled)
         return compiled, path, timings

@@ -377,7 +377,7 @@ def _host_in_the_loop(ref, prompt, max_new):
     pred, slots, seq = ref.predictor, ref.slots, ref.seq
     dexe, _ = pred.acquire("decode", slots, seq, ref.strategy,
                            kv_dtype=ref.kv_dtype)
-    outs, sp = ref._prefill_prompts([prompt])
+    outs, sp, _ = ref._prefill_prompts([prompt])
     tok = int(pred._sample_host(outs[0], ref.strategy, 0)[0])
     caches = ref._scatter_prefill(ref._fresh_slabs(), list(outs[1:]), [0],
                                   sp)

@@ -376,7 +376,7 @@ def test_an_admission_replaces_both_entries_whole(pred):
     and summaries are gone, the neighbour's untouched."""
     srv = DecodeServer(pred, slots=SLOTS, max_seq=SEQ, max_new_tokens=4)
     caches = [jnp.full(e.shape, 7.0, e.dtype) for e in srv._spec]
-    outs, sp = srv._prefill_prompts(_prompts([37], seed=5))
+    outs, sp, _ = srv._prefill_prompts(_prompts([37], seed=5))
     sub = list(outs[1:1 + len(srv._spec)])
     assert sp == 64 and all(s.shape == (1, ROWS, H, DH) for s in sub)
     new = srv._scatter_prefill(caches, sub, [2], sp)

@@ -19,6 +19,8 @@ fails instead of passing empty.
 """
 from __future__ import annotations
 
+import re
+
 import numpy as np
 import pytest
 
@@ -57,23 +59,71 @@ def _train_step_avals(main_p, startup, loss, place_state, place_other):
     return stepfn, (feeds, state, place_other(key), place_other(step))
 
 
+_ONE_CHIP_TEXTS = {}  # tie -> the compiled step's text: one compile a case
+
+
+def _one_chip_step_text(one_chip, monkeypatch, tie):
+    """The compiled text of the LM training step on one described chip,
+    2 layers at full width, AMP O2, fused backward, fused head."""
+    monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
+    monkeypatch.setenv("PADDLE_TPU_FLASH_FUSED_BWD", "1")
+    if tie not in _ONE_CHIP_TEXTS:
+        main_p, startup, loss = _lm_programs(2, tie=tie)
+
+        def on_chip(aval, batch=False):
+            return jax.ShapeDtypeStruct(aval.shape, aval.dtype,
+                                        sharding=one_chip)
+
+        stepfn, avals = _train_step_avals(
+            main_p, startup, loss, lambda n, a: on_chip(a), on_chip)
+        _ONE_CHIP_TEXTS[tie] = _compile(stepfn, *avals, donate_argnums=(1,))
+    return _ONE_CHIP_TEXTS[tie]
+
+
 @pytest.mark.parametrize("tie", [False, True], ids=["untied", "tied"])
 def test_training_step_compiles(one_chip, monkeypatch, tie):
     """The LM training step, 2 layers at full width, AMP O2, fused
     backward, fused head — as chip_smoke.py trains it at 12 layers."""
-    monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
-    monkeypatch.setenv("PADDLE_TPU_FLASH_FUSED_BWD", "1")
-    main_p, startup, loss = _lm_programs(2, tie=tie)
-
-    def on_chip(aval, batch=False):
-        return jax.ShapeDtypeStruct(aval.shape, aval.dtype,
-                                    sharding=one_chip)
-
-    stepfn, avals = _train_step_avals(
-        main_p, startup, loss, lambda n, a: on_chip(a), on_chip)
-    text = _compile(stepfn, *avals, donate_argnums=(1,))
+    text = _one_chip_step_text(one_chip, monkeypatch, tie)
     # per layer: flash fwd + fused bwd
     assert text.count("tpu_custom_call") >= 2 * 2
+
+
+def test_training_step_names_its_mosaic_calls_and_scopes_the_rest(
+        one_chip, monkeypatch):
+    """The tracer's scope around every Fluid op renames no Mosaic call:
+    the forward kernel is `jvp_ptpu.flash_fwd_.N` and the fused backward
+    `transpose_jvp_ptpu.flash_bwd_.N` as before it (the names
+    `flash_attn_roofline.lm` anchors on: `fused_attention` is traced
+    without a scope where it is differentiated), and the step's map
+    lays the rest under `fl.<type>:<anchor>`, forward and backward, with
+    the weights each fusion reads."""
+    from paddle_tpu.observability import scopes
+
+    text = _one_chip_step_text(one_chip, monkeypatch, False)
+    calls = re.findall(
+        r"%(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
+    fwd = [c for c in calls if re.match(r"jvp_ptpu\.flash_fwd_(\.\d+)?$", c)]
+    bwd = [c for c in calls
+           if re.match(r"transpose_jvp_ptpu\.flash_bwd\w*(\.\d+)?$", c)]
+    assert len(fwd) == 2 and len(bwd) == 2 and len(calls) == 4, calls
+    m = scopes.scope_map(text)
+    assert m["scoped"] and not any("fl.fused_attention" in s
+                                   for o in m["ops"].values()
+                                   for s in o["scope"] + o["members"])
+    named = [o for o in m["ops"].values() if o["scope"] or o["members"]]
+    assert {o["pass"] for o in named} == {"fwd", "bwd"}
+    # a layer's first FFN weight: read by a forward product under its
+    # own scope, and written by the optimizer's update of it
+    w = "state['lm.l0.ffn.fc1.w']"
+    assert m["params"][w] == D_MODEL * D_INNER * 4
+    leaf = "fl.mul:lm.l0.ffn.fc1.w"
+    assert any(w in o["reads"] and leaf in o["scope"][-1:] + o["members"]
+               for o in named if o["pass"] == "fwd")
+    assert any(w in o["reads"] and any(
+        s.startswith("fl.adam:lm.l0.ffn.fc1.w") for s in
+        o["scope"][-1:] + o["members"]) for o in named), [
+        o for o in named if w in o["reads"]]
 
 
 @pytest.mark.parametrize("tie", [False, True], ids=["untied", "tied"])

@@ -42,6 +42,38 @@ _SERVING_CASES = [
 ]
 
 
+_COMPILED = {}  # a case's values -> what `_compiled_case` gives: one compile
+
+
+def _compiled_case(one_chip, monkeypatch, kind, batch, seq, n_layer, n_head,
+                   d_model, d_inner, vocab, tied, step_kw):
+    """The function `_acquire` jits for one of `_SERVING_CASES`,
+    compiled for the described chip, feeds donated: (the graph builder,
+    the executable, the cache entries it is fed, its state's shapes)."""
+    from paddle_tpu.serving.decode import DecodeConfig, DecodePredictor
+
+    monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
+    monkeypatch.setattr(
+        KV, "_use_pallas_decode",
+        lambda s, d: d % 128 == 0 and s % 128 == 0 and s >= 128)
+    case = (kind, batch, seq, n_layer, n_head, d_model, d_inner, vocab, tied,
+            tuple(sorted(step_kw.items())))
+    if case not in _COMPILED:
+        pred = DecodePredictor.__new__(DecodePredictor)  # graph builder only
+        pred.config = DecodeConfig(vocab_size=vocab, n_layer=n_layer,
+                                   n_head=n_head, d_model=d_model,
+                                   d_inner=d_inner, max_len=max(T, seq),
+                                   tie_embeddings=tied)
+        pred.sample_k, pred.sample_p, pred.temperature = 40, 0.9, 1.0
+        pred.draft_n_layer = 1
+        step_fn, feeds, state, n_cache = _serving_step(
+            pred, kind, batch, seq, one_chip, **step_kw)
+        compiled = _compiled(step_fn, feeds, state, mosaic=kind != "verify",
+                             donate_argnums=(0,))
+        _COMPILED[case] = (pred, compiled, n_cache, state)
+    return _COMPILED[case]
+
+
 @pytest.mark.parametrize(
     "kind,batch,seq,n_layer,n_head,d_model,d_inner,vocab,tied,step_kw",
     [c[1:] for c in _SERVING_CASES], ids=[c[0] for c in _SERVING_CASES])
@@ -66,25 +98,11 @@ def test_serving_step_compiles(one_chip, monkeypatch, kind, batch, seq,
     pairing (there were 8 in the serving cell's step, 46% of its device
     time: PERF.md, PR 27), the aliased bytes cover the spec's, and the
     temporaries are a few MiB."""
-    from paddle_tpu.serving.decode import DecodeConfig, DecodePredictor
-
-    monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
-    monkeypatch.setattr(
-        KV, "_use_pallas_decode",
-        lambda s, d: d % 128 == 0 and s % 128 == 0 and s >= 128)
-    pred = DecodePredictor.__new__(DecodePredictor)  # graph builder only
-    pred.config = DecodeConfig(vocab_size=vocab, n_layer=n_layer,
-                               n_head=n_head, d_model=d_model,
-                               d_inner=d_inner, max_len=max(T, seq),
-                               tie_embeddings=tied)
-    pred.sample_k, pred.sample_p, pred.temperature = 40, 0.9, 1.0
-    pred.draft_n_layer = 1
-    step_fn, feeds, state, n_cache = _serving_step(pred, kind, batch, seq,
-                                                   one_chip, **step_kw)
+    pred, compiled, n_cache, _ = _compiled_case(
+        one_chip, monkeypatch, kind, batch, seq, n_layer, n_head, d_model,
+        d_inner, vocab, tied, step_kw)
     # a verify window attends through the lax path: no Mosaic call
     mosaic = kind != "verify"
-    compiled = _compiled(step_fn, feeds, state, mosaic=mosaic,
-                         donate_argnums=(0,))
     text = compiled.as_text()
     if mosaic:
         assert text.count("tpu_custom_call") >= n_layer  # one per layer
@@ -111,6 +129,70 @@ def test_serving_step_compiles(one_chip, monkeypatch, kind, batch, seq,
         # the kernel's views of K and V are free
         assert sum(op == "bitcast" for op, _, _ in ops) >= 2 * n_layer
     assert mem.temp_size_in_bytes < 16 * 2**20, mem.temp_size_in_bytes
+
+
+def test_serving_decode_step_maps_its_fusions_to_ops_and_weights(
+        one_chip, monkeypatch):
+    """`observability.scopes.scope_map` of the serving cell's own decode
+    step (OPT-6.7B widths, 4 layers) as the chip's compiler leaves it:
+    the Mosaic call keeps its name (`ptpu.decode_attn.N`) with the
+    tracer's scope around it, every parameter is a state array or a feed
+    at its own size, and ONE fusion holds both FFN products of a layer,
+    reading both matrices (537 MB a layer in float32: what the four
+    largest fusions of the cell's trace are)."""
+    from paddle_tpu.observability import scopes
+
+    case = next(c for c in _SERVING_CASES if c[0] == "decode-8x2048-h32")
+    pred, compiled, _, state = _compiled_case(one_chip, monkeypatch,
+                                              *case[1:])
+    m = scopes.scope_map(compiled)
+    assert m["scoped"] and m["module"].startswith("jit_")
+    kernels = {n: o for n, o in m["ops"].items() if "ptpu." in n}
+    assert len(kernels) == 4 and all(
+        re.match(r"ptpu\.decode_attn\.\d+$", n)
+        and o["scope"][0].startswith("fl.decode_attention:")
+        and o["scope"][1] == "ptpu.decode_attn"
+        for n, o in kernels.items()), kernels
+    # a slab is read where it is fed by the append that writes its row
+    # (the kernel then reads that fusion's result, not a parameter)
+    appends = [o for o in m["ops"].values() if o["scope"]
+               and o["scope"][-1].startswith("fl.cache_append:")
+               and any("cache_" in r for r in o["reads"])]
+    assert sorted(r for o in appends for r in o["reads"] if "cache_" in r) \
+        == sorted("feeds['%scache_%d']" % (kv, i)
+                  for kv in "kv" for i in range(4))
+    d, di = 4096, 16384
+    assert m["params"]["state['lm.l2.ffn.fc1.w']"] == d * di * 4
+    assert m["params"]["feeds['kcache_0']"] == 8 * 2048 * d * 4
+    # the parameters are the state arrays, each at its own size
+    assert {n: b for n, b in m["params"].items()
+            if n.startswith("state[")} == {
+        "state['%s']" % n: int(np.prod(a.shape)) * 4
+        for n, a in state.items()}
+    for layer in range(4):
+        w1, w2 = ("state['lm.l%d.ffn.fc%d.w']" % (layer, i) for i in (1, 2))
+        both = [o for o in m["ops"].values()
+                if w1 in o["reads"] and w2 in o["reads"]]
+        assert len(both) == 1, (layer, both)
+        assert {"fl.mul:lm.l%d.ffn.fc1.w" % layer,
+                "fl.mul:lm.l%d.ffn.fc2.w" % layer} <= set(
+            both[0]["members"]), both
+        # and streams them from HBM itself: no copy or prefetch between
+        assert not {w1, w2} & set(both[0]["copied"]), both
+    # whatever reaches its reader only through an operation that moves
+    # it (`copied`) is read in place by such an operation, an event of
+    # its own: a share of the HBM peak counts its bytes there or nowhere
+    movers = {r for n, o in m["ops"].items() for r in o["reads"]
+              if r not in o["copied"] and re.match(
+                  r"(copy|copy-start|slice-start|convert)[.\d]*$", n)}
+    assert {r for o in m["ops"].values() for r in o["copied"]} <= movers
+    named = sum(1 for o in m["ops"].values() if o["scope"] or o["members"])
+    unnamed = {re.sub(r"[.\d]+$", "", n) for n, o in m["ops"].items()
+               if not (o["scope"] or o["members"])}
+    # what carries no scope is the compiler's own moving of weights
+    assert unnamed <= {"copy-start", "copy-done", "slice-start",
+                       "slice-done", "custom-call", "copy"}, unnamed
+    assert named >= 100
 
 
 _HYBRID_CASES = [

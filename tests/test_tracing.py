@@ -532,6 +532,39 @@ def test_decode_server_gives_one_record_per_loop_iteration(decode_dir):
     assert snap["rings"]["request"]["recorded"] == 3 * 3  # submit/admit/retire
 
 
+@pytest.mark.parametrize("rate", [1.0, 0.0], ids=["traced", "rate0"])
+def test_prefill_instruments_run_from_dispatch_to_logits_on_the_host(
+        decode_dir, rate):
+    """`paddle_tpu_decode_step_ms{stage="prefill"}` and the
+    `decode.admit` span's `prefill_ms` are ONE number: from the
+    prefill's dispatch to its logits on the host, the `prefill` and
+    `first_token` phases' sum (`pexe(...)` alone only dispatches; the
+    host waits in `first_token`). At rate 0, where no phase reads a
+    clock, the admission still observes it once."""
+    r = np.random.RandomState(5)
+    prompts = [r.randint(1, DV, n).astype(np.int64) for n in (6, 4)]
+    _serve(decode_dir, prompts[:1], [2])  # compile, untraced
+    tracing.reset()
+    tracing.set_sample_rate(rate)
+    before = obs.DECODE_STEP_MS.stats(stage="prefill")
+    _serve(decode_dir, prompts, [3, 3])
+    tracing.set_sample_rate(0.0)
+    after = obs.DECODE_STEP_MS.stats(stage="prefill")
+    assert after["count"] - before["count"] == 1      # one admission wave
+    hist_ms = after["sum"] - before["sum"]
+    assert hist_ms > 0
+    if not rate:
+        return
+    (first,) = [s for s in _iters() if s.get("admitted")]
+    span = sum(_phase(first, name, "decode.loop.admit")["ms"]
+               for name in ("decode.loop.prefill", "decode.loop.first_token"))
+    assert hist_ms == pytest.approx(span, rel=0.02, abs=0.05)
+    admits = [s for s in tracing.get_recorder().spans()
+              if s["name"] == "decode.admit"]
+    assert len(admits) == 2 and all(
+        s["prefill_ms"] == pytest.approx(hist_ms, abs=0.002) for s in admits)
+
+
 @pytest.mark.parametrize("rows,lens,n_active,want", [
     # whole slabs: the lax paths and the per-head kernel
     (None, [5, 0, 70, 0], 2, {"attended": 77, "streamed": 4 * 256}),
