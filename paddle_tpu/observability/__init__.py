@@ -51,7 +51,7 @@ __all__ = [
     "FLEET_REQUEUED", "FLEET_MISVERSIONED", "FLEET_BACKPRESSURE_MS",
     "FLEET_SHED", "FLEET_PENDING", "FLEET_AUTOSCALE",
     "DECODE_TOKENS", "DECODE_STEPS", "DECODE_SLOTS", "DECODE_STEP_MS",
-    "DECODE_REQUESTS", "DECODE_PRELOAD",
+    "DECODE_REQUESTS", "DECODE_ADMIT_DEFERRED", "DECODE_PRELOAD",
     "DECODE_PREFIX_QUERIES", "DECODE_PREFIX_HITS", "DECODE_PREFIX_BYTES",
     "DECODE_SPEC_PROPOSED", "DECODE_SPEC_ACCEPTED",
     "CKPT_SAVES", "CKPT_BYTES", "CKPT_PENDING", "CKPT_SAVE_MS",
@@ -361,6 +361,13 @@ DECODE_REQUESTS = REGISTRY.counter(
     "paddle_tpu_decode_requests_total",
     "Decode-serving sequences, kind=admitted (entered a cache slot) | "
     "retired (finished and freed it); admitted - retired = in flight")
+DECODE_ADMIT_DEFERRED = REGISTRY.counter(
+    "paddle_tpu_decode_admit_deferred_total",
+    "Queued requests that had a free slot within an admission's room and "
+    "were left for the next iteration (DecodeServer._admit_group): their "
+    "prompt's length bucket is not the oldest request's, or the token "
+    "bound or the whole-batch rule cut the group; each keeps its place and "
+    "waits one decode step: the price of prefills without padding")
 DECODE_PRELOAD = REGISTRY.counter(
     "paddle_tpu_decode_preload_total",
     "Prefill executables a decode predictor's disk directory named when "

@@ -1992,6 +1992,11 @@ class DecodeServer:
         # prefill-execution count — the test-pinned "N users of one
         # prompt pay ONE prefill" observable
         self.prefill_executions = 0
+        # the rows those prefills ran on (power-of-two batch x sequence
+        # bucket, padding included) and the prompts' own among them:
+        # 1 - prompt / bucket is the padding share of a server's prefills
+        self.prefill_bucket_rows = 0
+        self.prefill_prompt_rows = 0
         self._chan = Channel(capacity)
         self._results: Dict[int, "_DecodeFuture"] = {}
         self._next_id = 0
@@ -2248,14 +2253,19 @@ class DecodeServer:
 
     def _prefill_prompts(self, prompts):
         """The ONE admission-prefill recipe (shared by ``_admit`` and
-        ``_admit_prefix``): bucket the prompts to a pow2 batch and
-        their OWN pow2 sequence length — not the slab length: admitting
-        a 16-token prompt into a 1024-token slab must cost a 16-token
-        forward (this is what lets continuous admission beat gang
-        scheduling — a slab-sized prefill per admission would eat the
-        win) — DISPATCH the prefill executable, and account its
-        tokens. Returns ``(outs, sp, t0)``: the raw executable outputs
-        (logits + per-layer float K/V sub-slabs, still on their way),
+        ``_admit_prefix``), run ONCE an admission (``_admit_group``
+        says why): bucket the prompts it is given to a pow2 batch and
+        the pow2 sequence length of the longest (``_admit_group`` hands
+        it prompts of one bucket, or of any under ``_ADMIT_FLOOR``) —
+        not the slab length: admitting a 16-token prompt into a
+        1024-token slab must cost a 16-token forward (this is what lets
+        continuous admission beat gang scheduling — a slab-sized
+        prefill per admission would eat the win) — DISPATCH the prefill
+        executable, and account its tokens and its rows
+        (``prefill_bucket_rows``, what the program ran on, and
+        ``prefill_prompt_rows``, the prompts' own). Returns ``(outs,
+        sp, t0)``: the raw executable outputs (logits + per-layer float
+        K/V sub-slabs, still on their way),
         the sequence bucket they are shaped at, and the clock at the
         dispatch: the caller waits for the logits under
         ``decode.loop.first_token`` and hands ``t0`` to
@@ -2274,9 +2284,11 @@ class DecodeServer:
             t0 = ph.t0 or time.perf_counter()
             outs = pexe({"tokens": tokens, "lengths": plens},
                         self.predictor._state)
+        rows = int(plens[:len(prompts)].sum())
         self.prefill_executions += 1
-        obs.DECODE_TOKENS.inc(int(plens[:len(prompts)].sum()),
-                              kind="prefill")
+        self.prefill_bucket_rows += bb * sp
+        self.prefill_prompt_rows += rows
+        obs.DECODE_TOKENS.inc(rows, kind="prefill")
         return outs, sp, t0
 
     @staticmethod
@@ -2310,47 +2322,101 @@ class DecodeServer:
     # and the bucketed tokens (power-of-two batch x the prompts' bucket)
     _ADMIT_MOST = 8
     _ADMIT_TOKENS = 16384
+    # the rows under which prompts of different buckets still share an
+    # admission. Under the ridge of the weight stream a prefill program
+    # costs its weights' read whatever its rows (the hybrid cell on a
+    # v5e, PERF.md, PR 49: one prompt in the 64 bucket 10.2 ms, 128 9.6,
+    # 256 11.0, 512 15.3, then ~25 us a row), so two short prompts in
+    # one program of the longer's bucket cost less than two programs;
+    # from 1024 rows on a row is paid for in full, and a shorter
+    # neighbour's padding with it
+    _ADMIT_FLOOR = 512
 
-    def _admit_room(self, free: int, pending=None) -> int:
-        """How many queued requests the next admission takes. Between
-        two decode steps at most ``_ADMIT_MOST``, and of ``pending``
-        (the queue, oldest first) no more than keep the prefill's
-        bucketed tokens within ``_ADMIT_TOKENS`` (never fewer than
-        one): an admission stalls every live sequence for its prefill,
-        whose temporaries grow with the tokens it holds (8 prompts of
-        2048 tokens: 1.9 GB at the widths of a 3 B hybrid model; 8 of
-        4096 with their repeated K/V and expert-sorted copies would not
-        fit beside a chip's weights and slabs), and the executables a
-        server has to have compiled stay the power-of-two batches up to
-        8 x 2048, 4 x 4096, 2 x 8192 and 1 x 16384 (the last two where
-        a slab is that long). The rest waits one decode step. A
-        gang-scheduled server (``continuous=False``) fills its slots at
-        once, as it always has."""
+    def _admit_room(self, free: int) -> int:
+        """The most requests one admission takes: between two decode
+        steps at most ``_ADMIT_MOST``, since an admission stalls every
+        live sequence for its prefill. A gang-scheduled server
+        (``continuous=False``) fills its slots at once, as it always
+        has."""
+        return min(free, self._ADMIT_MOST) if self.continuous else free
+
+    def _admit_group(self, free: int, pending) -> List[int]:
+        """Which of ``pending`` (the queue, oldest first) the next
+        admission takes, as indices into it: the oldest request, and of
+        the ``_admit_room(free) - 1`` behind it those that run in ITS
+        program without paying for rows they do not have.
+
+        The group's bucket is the oldest prompt's own power-of-two
+        bucket, never under ``_ADMIT_FLOOR``; a waiting request joins
+        when its bucket, floored the same way, is the group's. A 300-
+        and an 1,100-token prompt that free slots together are two
+        admissions one decode step apart (512 + 2,048 rows), not one
+        ``b2_s2048`` program of 4,096 rows for 1,400; prompts under the
+        floor share as they always have. What is passed over KEEPS ITS
+        PLACE in ``pending`` and is the head, or joins one, at the next
+        iteration. The oldest always goes, so a request is passed over
+        at most as many times as requests stood before it.
+
+        Of the group no more are taken than keep the prefill's bucketed
+        tokens (power-of-two batch x bucket) within ``_ADMIT_TOKENS``,
+        never fewer than one: a prefill's temporaries grow with the
+        tokens it holds (8 prompts of 2048 tokens: 1.9 GB at the widths
+        of a 3 B hybrid model; 8 of 4096 with their repeated K/V and
+        expert-sorted copies would not fit beside a chip's weights and
+        slabs), and the executables a server has to have compiled stay
+        the power-of-two batches up to 8 x 2048, 4 x 4096, 2 x 8192 and
+        1 x 16384 (the last two where a slab is that long). And a group
+        of 3 (5, 6, 7) whose program would pass ``_ADMIT_FLOOR`` rows
+        runs as 2 (4): ``_prefill_prompts`` pads a batch to a power of
+        two, and past the floor the dead prompts' rows are paid for like
+        live ones. So a batch of ``b`` holds ``b`` prompts (or lies
+        under the floor): a backlog of passed-over requests never makes
+        a wider program than as many arrivals at once would have, and a
+        server asks for no shape that arrival order could not.
+
+        The rest waits ONE decode step; it is not prefilled by a second
+        program at once. One admission is one prefill program, one
+        ``first_token`` wait and one ``scatter`` phase, in that order:
+        a trace's readers give a ``jit_ptpu_prefill_*`` event the
+        counts of the first ``scatter`` that opens after it started,
+        and a decode step between two prefills is what the live
+        sequences' token gap wants. Gang scheduling takes the queue's
+        head as it stands."""
+        n = min(self._admit_room(free), len(pending))
         if not self.continuous:
-            return free
-        n = min(free, self._ADMIT_MOST)
-        if pending is None:
-            return n  # the most an admission takes, whatever is queued
-        lens = [len(p[1]) for p in pending[:n]]
-        n = len(lens)
-        while n > 1 and _pow2_bucket(n) * min(
-                _pow2_bucket(max(lens[:n]), floor=16),
-                self.seq) > self._ADMIT_TOKENS:
-            n -= 1
-        return n
+            return list(range(n))
 
-    def _admit(self, pending, caches, lens, active):
-        """Prefill a sub-batch of queued requests into free slots.
-        ``pending`` entries are (rid, prompt, max_new, seed); returns
-        the updated caches (slab rows replaced via one scatter per
-        tensor). With a prefix store attached, admission first hashes
-        each prompt against it — hits seed from cached rows (full hit:
-        no model call at all; partial hit: suffix-only extension
-        through the verify window) and identical prompts inside one
-        sub-batch dedupe to a single prefill row."""
+        def bucket(i):
+            return min(_pow2_bucket(len(pending[i][1]), floor=16), self.seq)
+
+        def group(i):
+            return max(bucket(i), self._ADMIT_FLOOR)
+
+        take = [i for i in range(n) if group(i) == group(0)]
+
+        def rows(k):  # of the program the first k of the group run in
+            return _pow2_bucket(k) * max(bucket(i) for i in take[:k])
+
+        k = len(take)
+        while k > 1 and rows(k) > self._ADMIT_TOKENS:
+            k -= 1
+        if rows(k) > self._ADMIT_FLOOR:
+            # past the floor a dead row of the power-of-two batch is
+            # paid for like a live one: 3 (5, 6, 7) run as 2 (4)
+            k = _pow2_bucket(k + 1) // 2
+        return take[:k]
+
+    def _admit(self, batch, caches, lens, active):
+        """One admission: prefill ``batch``, the requests
+        ``_admit_group`` picked of the queue, into free slots, in ONE
+        program. Entries are (rid, prompt, max_new, seed); returns the
+        updated caches (slab rows replaced via one scatter per tensor).
+        With a prefix store attached, admission first hashes each prompt
+        against it — hits seed from cached rows (full hit: no model
+        call at all; partial hit: suffix-only extension through the
+        verify window) and identical prompts inside one sub-batch
+        dedupe to a single prefill row."""
         free = [i for i in range(self.slots) if active[i] is None]
-        batch = pending[:self._admit_room(len(free), pending)]
-        del pending[:len(batch)]
         if self._prefix is not None:
             return self._admit_prefix(batch, free, caches, lens, active)
         n = len(batch)
@@ -3124,7 +3190,11 @@ class DecodeServer:
                 n_active = sum(1 for a in active if a is not None)
                 free = self.slots - n_active
                 batch = []
-                drain = not closed and free > 0 and (
+                # what an admission passed over waits in ``pending``
+                # and counts against the free slots: queue and channel
+                # together never hold more than the slots can take
+                room = free - len(pending)
+                drain = not closed and room > 0 and (
                     self.continuous or n_active == 0)
                 if not closed and n_active == 0 and not pending \
                         and flight is None:
@@ -3138,7 +3208,7 @@ class DecodeServer:
                         # bounded by the free slots (leaving the rest
                         # in the channel keeps submit()'s backpressure
                         # intact)
-                        batch = self._chan.recv_batch(free, 0)
+                        batch = self._chan.recv_batch(room, 0)
                     if batch is None:
                         closed = True
                         batch = []
@@ -3162,10 +3232,19 @@ class DecodeServer:
                 admit_ok = (free > 0 and pending
                             and (self.continuous or n_active == 0))
                 if admit_ok:
+                    take = self._admit_group(free, pending)
+                    group = [pending[i] for i in take]
+                    # had a free slot in this admission's room, and wait
+                    deferred = min(self._admit_room(free),
+                                   len(pending)) - len(take)
+                    for i in reversed(take):
+                        del pending[i]
+                    if deferred:
+                        obs.DECODE_ADMIT_DEFERRED.inc(deferred)
                     with _tracing.phase("decode.loop.admit",
-                                        admitted=self._admit_room(
-                                            free, pending)):
-                        caches = self._admit(pending, caches, lens, active)
+                                        admitted=len(group),
+                                        deferred=deferred):
+                        caches = self._admit(group, caches, lens, active)
                     n_active = sum(1 for a in active if a is not None)
                 self._set_slot_gauges(n_active)
                 if self.speculative and n_active:

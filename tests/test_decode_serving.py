@@ -303,6 +303,139 @@ def test_server_per_request_budget_and_mixed_lengths(pred):
         np.testing.assert_array_equal(g, w[:mn])
 
 
+# -- an admission takes one length bucket (PR 50) --------------------------
+
+def _mixed_queue(n, seed):
+    """Prompts of the 16, 32 and 64 buckets in one queue, each with room
+    for 4 tokens in a 64-row slab."""
+    r = np.random.RandomState(seed)
+    lens = r.choice([5, 9, 14, 20, 27, 31, 40, 52, 60], size=n)
+    return [r.randint(1, V, k).astype(np.int64) for k in lens]
+
+
+class _WatchedPredictor:
+    """`pred`, noting the prefill shapes asked of it."""
+
+    def __init__(self, pred, shapes):
+        self._pred, self._shapes = pred, shapes
+
+    def acquire(self, kind, *args, **kw):
+        if kind == "prefill":
+            self._shapes.add(tuple(args[:2]))
+        return self._pred.acquire(kind, *args, **kw)
+
+    def __getattr__(self, name):
+        return getattr(self._pred, name)
+
+
+def _watched_server(pred, calls, shapes, **kw):
+    """A server whose floor is 16 rows (the tiny model's slab ends at
+    64, under the real floor of 512, where every bucket shares and a
+    batch is padded freely), that logs each admission's view of the
+    queue and each prefill shape it asks the predictor for."""
+    srv = DecodeServer(_WatchedPredictor(pred, shapes), slots=8, max_seq=64,
+                       max_new_tokens=4, **kw)
+    srv._ADMIT_FLOOR = 16
+    pick = srv._admit_group
+
+    def admit_group(free, pending):
+        take = pick(free, pending)
+        calls.append((free, [p[0] for p in pending],
+                      [len(p[1]) for p in pending], take))
+        return take
+
+    srv._admit_group = admit_group
+    return srv
+
+
+@pytest.fixture(scope="module")
+def warm_shapes(pred):
+    """The prefill shapes a warm-up of same-length bursts of 1, 2, 4 and
+    8 requests in every prompt bucket asks for: what the benchmark's
+    runner compiles before its window."""
+    shapes = set()
+    for plen in (16, 32, 60):
+        for n in (1, 2, 4, 8):
+            srv = _watched_server(pred, [], shapes)
+            futs = [srv.submit((np.ones((plen,), np.int64),
+                                np.array([2], np.int64)))
+                    for _ in range(n)]
+            srv.start()
+            for f in futs:
+                f.result(timeout=300)
+            srv.stop()
+    assert shapes == {(b, s) for b in (1, 2, 4, 8) for s in (16, 32, 64)}
+    return shapes
+
+
+@pytest.mark.parametrize("seed", [31, 32, 33])
+def test_mixed_queue_is_admitted_a_bucket_at_a_time(pred, warm_shapes, seed):
+    """A server fed prompts of three buckets: every admission holds the
+    oldest request and prompts of ITS bucket only; what it passes over
+    keeps its place and leads, or joins, the next iteration's; the
+    queue never holds more than the free slots take; no prefill shape
+    appears that a same-length warm-up did not make; and every reply is
+    the direct rollout's."""
+    from paddle_tpu import observability as obs
+
+    prompts = _mixed_queue(24, seed)
+    want = pred.generate(prompts, max_new_tokens=4)
+    calls, shapes = [], set()
+    srv = _watched_server(pred, calls, shapes)
+    before = obs.DECODE_ADMIT_DEFERRED.value()
+    futs = [srv.submit((p,)) for p in prompts]
+    srv.start()
+    got = [f.result(timeout=300)[0] for f in futs]
+    srv.stop()
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    assert shapes <= warm_shapes
+    assert len(calls) > 8  # 24 requests, three buckets, 8 slots
+    passed_over = 0
+    for k, (free, rids, lens, take) in enumerate(calls):
+        assert 0 < len(rids) <= free          # rule 4: pending <= free
+        assert take[0] == 0                   # the oldest always goes
+        buckets = {_pow2_bucket(lens[i], 16) for i in take}
+        assert len(buckets) == 1, (lens, take)
+        # nobody of the head's bucket is left behind within the room,
+        # but for a whole power-of-two batch (3 run as 2, 5-7 as 4)
+        same = [i for i in range(min(free, 8, len(lens)))
+                if _pow2_bucket(lens[i], 16) in buckets]
+        assert take == same[:_pow2_bucket(len(same) + 1) // 2]
+        left = [r for i, r in enumerate(rids) if i not in take]
+        passed_over += min(free, 8, len(rids)) - len(take)
+        if k + 1 < len(calls):
+            # in its place: the next queue opens with what was left
+            assert calls[k + 1][1][:len(left)] == left
+    assert passed_over > 0
+    assert obs.DECODE_ADMIT_DEFERRED.value() - before == passed_over
+    # one program an admission, and its rows: the padding share is one
+    # division on any server
+    assert srv.prefill_executions == len(calls)
+    assert srv.prefill_prompt_rows == sum(len(p) for p in prompts)
+    assert srv.prefill_bucket_rows == sum(
+        _pow2_bucket(len(t)) * _pow2_bucket(max(l[i] for i in t), 16)
+        for _f, _r, l, t in calls)
+
+
+def test_gang_scheduling_admits_the_queue_as_it_stands(pred, warm_shapes):
+    """`continuous=False` fills its slots with the head of the queue,
+    whatever the buckets, as it always has."""
+    prompts = _mixed_queue(12, seed=34)
+    want = pred.generate(prompts, max_new_tokens=4)
+    calls, shapes = [], set()
+    srv = _watched_server(pred, calls, shapes, continuous=False)
+    futs = [srv.submit((p,)) for p in prompts]
+    srv.start()
+    got = [f.result(timeout=300)[0] for f in futs]
+    srv.stop()
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    assert all(take == list(range(len(take))) for *_x, take in calls)
+    assert sum(len(take) for *_x, take in calls) == len(prompts)
+    assert srv.prefill_executions == len(calls) < 4
+
+
 def test_server_stop_is_zero_drop(pred):
     """stop() right after a submit burst: every request still completes
     (queued ones admitted as slots free, in-flight ones finished)."""

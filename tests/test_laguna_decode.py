@@ -321,20 +321,97 @@ def test_server_asks_stream_rows_with_the_slabs_own_heads(pred, monkeypatch):
 
 # -- an admission is bounded by tokens as well as by prompts ------------------
 
+def _waiting(lens):
+    return [(i, np.zeros((n,), np.int64), 4, None)
+            for i, n in enumerate(lens)]
+
+
 @_cases("lens,free,want", [
     ([2048] * 12, 12, 8),      # 8 x 2048 = 16,384: what stood, stands
     ([3000] * 12, 12, 4),      # 4 x 4096
-    ([3000, 100, 100, 100, 100, 100, 100, 100], 8, 4),
+    # the 100s ran in the 3000's program, 4 x 4096 rows for 3,300, until
+    # an admission took one length bucket: now the 3000 goes alone
+    ([3000, 100, 100, 100, 100, 100, 100, 100], 8, 1),
     ([100] * 5, 3, 3), ([4000], 8, 1)])
 def test_admit_room_bounds_bucketed_tokens(pred, lens, free, want):
     srv = DecodeServer(pred, slots=16, max_seq=SEQ)
     srv.seq = 4096  # the bound reads the server's slab length only
-    pending = [(i, np.zeros((n,), np.int64), 4, None)
-               for i, n in enumerate(lens)]
-    assert srv._admit_room(free, pending) == want
+    pending = _waiting(lens)
+    assert srv._admit_group(free, pending) == list(range(want))
     assert srv._admit_room(free) == min(free, 8)  # the most, unasked
     srv.continuous = False
-    assert srv._admit_room(free, pending) == free
+    assert srv._admit_room(free) == free
+    assert srv._admit_group(free, pending) == list(
+        range(min(free, len(lens))))
+
+
+# -- an admission takes one length bucket --------------------------------------
+
+@_cases("lens,free,seq,want", [
+    ([600, 900], 8, 4096, [0, 1]),              # one bucket: together
+    ([300, 1100], 8, 4096, [0]),                # 512 + 2048 rows, not 4096
+    ([1100, 300], 8, 4096, [0]),
+    ([20, 300, 100, 500], 8, 4096, [0, 1, 2, 3]),  # under the floor
+    ([100, 3000, 200, 513], 8, 4096, [0, 2]),   # the larger waits
+    ([3000, 100, 4000, 100], 8, 4096, [0, 2]),  # the smaller too
+    ([1500, 1500, 100, 1500], 2, 4096, [0, 1]),  # of the next free - 1
+    ([3000] * 12, 12, 4096, [0, 1, 2, 3]),      # the bound cuts a group
+    ([3000, 100, 2100, 100, 2500, 100, 4000, 3000], 8, 4096, [0, 2, 4, 6]),
+    ([9000, 9000, 9000], 8, 16384, [0]),        # 1 x 16384
+    ([5000, 100, 5000, 6000], 8, 16384, [0, 2]),  # 2 x 8192
+    ([3000, 1100, 300], 8, 1024, [0, 1]),       # buckets end at the slab
+    ([300, 20, 40], 8, 64, [0, 1, 2]),          # a slab under the floor
+    # a batch is padded to a power of two only under the floor's rows
+    ([700] * 3, 8, 4096, [0, 1]), ([700] * 7, 8, 4096, [0, 1, 2, 3]),
+    ([700] * 8, 8, 4096, list(range(8))),
+    ([200] * 3, 8, 4096, [0, 1]),               # 4 x 256 rows
+    ([100] * 3, 8, 4096, [0, 1, 2]),            # 4 x 128: free
+    ([100] * 5, 8, 4096, [0, 1, 2, 3]), ([60] * 5, 8, 4096, list(range(5))),
+    ([100, 3000, 200, 513, 512], 8, 4096, [0, 2])],
+    ids=["same", "300-1100", "1100-300", "under-512", "larger-waits",
+         "smaller-waits", "room", "tokens", "tokens-skipping",
+         "16384", "8192", "slab-cap", "short-slab", "3-as-2", "7-as-4",
+         "8", "3x256-as-2", "3x128", "5x128-as-4", "5x64", "3-of-5-as-2"])
+def test_an_admission_takes_the_oldest_and_its_buckets_neighbours(
+        pred, lens, free, seq, want):
+    """The choice as a function of (lengths waiting, free slots, slab
+    length): the oldest and, of the next `room - 1`, those of its
+    bucket; under the floor of 512 rows every bucket shares; and past
+    it 3 (5, 6, 7) run as 2 (4)."""
+    srv = DecodeServer(pred, slots=16, max_seq=SEQ)
+    srv.seq = seq
+    pending = _waiting(lens)
+    got = srv._admit_group(free, pending)
+    assert got == want and got[0] == 0   # the oldest always goes
+    assert len(pending) == len(lens)     # a choice: nothing is taken here
+    srv.continuous = False               # gang scheduling: the head
+    assert srv._admit_group(free, pending) == list(
+        range(min(free, len(lens))))
+
+
+def test_a_request_is_passed_over_at_most_as_often_as_it_had_elders(pred):
+    """Replay of the rule alone on a queue that refills: each
+    iteration's group leaves the rest in place, and request i is taken
+    by iteration i at the latest."""
+    srv = DecodeServer(pred, slots=16, max_seq=SEQ)
+    srv.seq = 4096
+    r = np.random.RandomState(5)
+    lens = [int(n) for n in np.exp(r.normal(np.log(700), 1.0, 64)).clip(
+        16, 3500)]
+    pending, taken_at = _waiting(lens[:8]), {}
+    arrivals = iter(_waiting(lens)[8:])
+    for it in range(len(lens)):
+        if not pending:
+            break
+        take = srv._admit_group(4, pending)
+        assert take[0] == 0 and take == sorted(set(take))
+        for i in take:
+            taken_at[pending[i][0]] = it
+        left = [p for i, p in enumerate(pending) if i not in take]
+        assert [p[0] for p in left] == sorted(p[0] for p in left)
+        pending = left + [p for _, p in zip(take, arrivals)]
+    assert sorted(taken_at) == list(range(len(lens)))
+    assert all(it <= rid for rid, it in taken_at.items())
 
 
 # -- the cache manager's one description --------------------------------------

@@ -369,3 +369,74 @@ def test_parallel_executor_module_run_stats_shape():
     stats = pe.run_stats()
     assert set(stats) == {"steps", "dispatches", "mean_step_ms"}
     assert stats["steps"] >= 0
+
+
+# -- decode serving: what an admission took and what it passed over ------
+
+@pytest.fixture(scope="module")
+def decode_dir(tmp_path_factory):
+    """A one-layer LM exported for decode serving (random weights do)."""
+    from paddle_tpu.models import transformer as T
+    from paddle_tpu.serving.decode import DecodeConfig, save_decode_model
+
+    d = str(tmp_path_factory.mktemp("obs_decode_model"))
+    prog, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(prog, startup), fluid.unique_name.guard():
+        ids = layers.data(name="ids", shape=[2, 16], dtype="int64",
+                          append_batch_size=False)
+        T.transformer_lm(ids, ids, 37, n_layer=1, n_head=2, d_model=16,
+                         d_inner=32, dropout_rate=0.0, max_len=64,
+                         fused_head=False)
+    exe = fluid.Executor(fluid.CPUPlace())
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+        save_decode_model(d, DecodeConfig(
+            vocab_size=37, n_layer=1, n_head=2, d_model=16, d_inner=32,
+            max_len=64), exe, scope=scope)
+    return d
+
+
+@pytest.mark.parametrize("floor,want", [
+    # (admitted, deferred) of each admission of the queue 5, 40, 9, 30
+    # the 40 and the 30 wait for the 5 and the 9's step, then go in turn
+    (16, [(2, 2), (1, 1), (1, 0)]),
+    (512, [(4, 0)])],         # under the floor every bucket shares
+    ids=["a-bucket-an-admission", "under-the-floor"])
+def test_admit_phase_counts_what_it_took_and_what_it_passed_over(
+        decode_dir, floor, want):
+    """`decode.loop.admit` carries the count REALLY admitted and the
+    count left waiting beside a free slot; the registry counts the
+    latter, and the server the rows its prefills ran on beside the
+    prompts' own."""
+    from paddle_tpu.observability import tracing
+    from paddle_tpu.serving.decode import DecodePredictor, DecodeServer
+
+    srv = DecodeServer(DecodePredictor(decode_dir), slots=8, max_seq=64,
+                       max_new_tokens=2)
+    srv._ADMIT_FLOOR = floor
+    lens = [5, 40, 9, 30]
+    futs = [srv.submit((np.arange(1, n + 1, dtype=np.int64),)) for n in lens]
+    before = (obs.DECODE_ADMIT_DEFERRED.value(),
+              obs.DECODE_REQUESTS.value(kind="admitted"))
+    tracing.reset()
+    tracing.set_sample_rate(1.0)
+    try:
+        srv.start()
+        for f in futs:
+            assert len(f.result(timeout=300)[0]) == 2
+        srv.stop()
+    finally:
+        tracing.set_sample_rate(0.0)
+    # a phase's counts ride on its iteration's record
+    admits = [s for s in tracing.get_recorder().spans()
+              if s["name"] == "decode.loop.iter" and "admitted" in s]
+    assert [(s["admitted"], s["deferred"]) for s in admits] == want
+    assert obs.DECODE_ADMIT_DEFERRED.value() - before[0] == sum(
+        d for _a, d in want)
+    assert obs.DECODE_REQUESTS.value(kind="admitted") - before[1] == len(lens)
+    assert "paddle_tpu_decode_admit_deferred_total" in export.to_prometheus()
+    assert srv.prefill_executions == len(want)
+    assert srv.prefill_prompt_rows == sum(lens)
+    # 2 x 16, 1 x 64 and 1 x 32 rows, or 4 x 64 at once, for 84 live ones
+    assert srv.prefill_bucket_rows == (128 if floor == 16 else 256)

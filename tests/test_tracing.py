@@ -648,7 +648,7 @@ def test_phases_land_on_the_profilers_host_plane(decode_dir, tmp_path):
     stats = [e[2] for e in sorted(events["dispatch"])]
     assert [(s["active"], s["attended"]) for s in stats] == [
         (s["active"], s["attended"]) for s in steps]
-    assert dict(events["admit"][0][2]) == {"admitted": 2}
+    assert dict(events["admit"][0][2]) == {"admitted": 2, "deferred": 0}
 
 
 # -- stable names on the device side ----------------------------------------
