@@ -217,7 +217,7 @@ def phase_kda(cfg):
     beta = jnp.asarray(r.uniform(0.05, 0.95, size=(b, t, h)), jnp.float32)
     scan = jax.jit(lambda *a: kda.kda_scan(*a, lower_bound=-5.0))
     step = jax.jit(kda.kda_step)
-    path = "kernel" if kda._use_kernel(t, d, d, -5.0) else "lax"
+    path = "kernel" if kda._use_kernel(t, d, d) else "lax"
     text = scan.lower(q, k, v, g, beta, jnp.asarray(lens)).compile().as_text()
     calls = sum("%ptpu.kda_scan" in ln.split(" = ")[0]
                 and "tpu_custom_call" in ln for ln in text.splitlines())

@@ -2897,21 +2897,23 @@ def mla_attend(q, k, v, scale, lengths=None, name=None):
     return out
 
 
-def kda_gate(f, b, a_log, dt_bias, kind, bound, name=None):
+def kda_gate(f, b, a_log, dt_bias, kind, bound, beta_max=1.0, name=None):
     """A KDA layer's log-decay and write strength: f (B, T, H * dk), b
     (B, T, H), a_log (H,), dt_bias (H * dk,) -> (g (B, T, H, dk), beta
-    (B, T, H)) (``ops/kda.py: kda_gate``)."""
+    (B, T, H) in (0, ``beta_max``)) (``ops/kda.py: kda_gate``)."""
     helper = LayerHelper("kda_gate", name=name)
     bsz, t, h = b.shape
     g = helper.create_variable_for_type_inference(
         "float32", shape=(bsz, t, h, int(f.shape[-1]) // int(h)))
     beta = helper.create_variable_for_type_inference("float32",
                                                      shape=b.shape)
+    attrs = {"kind": str(kind), "bound": float(bound)}
+    if float(beta_max) != 1.0:  # a program written before it existed has none
+        attrs["beta_max"] = float(beta_max)
     helper.append_op(
         type="kda_gate",
         inputs={"F": [f], "B": [b], "ALog": [a_log], "DtBias": [dt_bias]},
-        outputs={"G": [g], "Beta": [beta]},
-        attrs={"kind": str(kind), "bound": float(bound)})
+        outputs={"G": [g], "Beta": [beta]}, attrs=attrs)
     return g, beta
 
 

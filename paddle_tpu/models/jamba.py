@@ -108,7 +108,7 @@ from ..ops import eva as _EVA
 from ..ops import kv_cache as _KV
 from ..ops import mla as _MLA
 from ..ops.dsa import DSA_ATTEND
-from ..ops.kda import KDA_GATES
+from ..ops.kda import KDA_BETA_MAX, KDA_GATES
 from ..ops.moe import ROUTER_SCORES
 from ..param_attr import ParamAttr
 from .transformer import sample_next
@@ -479,15 +479,27 @@ def _wide_latent_mixer(u, cfg, name, lengths, cache, kind):
                          w_o), entries
 
 
-def _head_gate(ctx, u, cfg, name, h):
-    """ctx (B, T, h, dv) times a sigmoid gate a head from the layer's
-    input where ``cfg.attn_gate`` asks (``name.gate.w``)."""
-    if cfg.attn_gate != "per_head":
+def _head_gate(ctx, u, cfg, name, h, rank=0):
+    """ctx (B, T, h, dv) times a sigmoid gate from the layer's input
+    where ``cfg.attn_gate`` asks: "per_head", one a head (``name.gate.w``
+    (D, h)); "per_channel", one a channel of every head, through one
+    matrix (``name.gate.w`` (D, h * dv)) or, where ``rank``, through a
+    bottleneck (``name.gate_a.w`` (D, rank), ``name.gate_b.w`` (rank, h
+    * dv): Kimi Linear's low-rank output gate)."""
+    if cfg.attn_gate is None:
         return ctx
-    B, T = ctx.shape[0], ctx.shape[1]
-    gate = layers.sigmoid(_proj(u, h, name + ".gate"))
+    B, T, _, dv = ctx.shape
+    if cfg.attn_gate == "per_head":
+        gate = layers.sigmoid(_proj(u, h, name + ".gate"))
+        return layers.elementwise_mul(
+            ctx, layers.reshape(gate, shape=[B, T, h, 1]))
+    if rank:
+        gate = _proj(_proj(u, rank, name + ".gate_a"), h * dv,
+                     name + ".gate_b")
+    else:
+        gate = _proj(u, h * dv, name + ".gate")
     return layers.elementwise_mul(
-        ctx, layers.reshape(gate, shape=[B, T, h, 1]))
+        ctx, layers.reshape(layers.sigmoid(gate), shape=[B, T, h, dv]))
 
 
 def _kda_mixer(u, cfg, name, lengths, cache):
@@ -515,11 +527,16 @@ def _kda_mixer(u, cfg, name, lengths, cache):
         mixed.append(layers.reshape(layers.swish(x, beta=1.0),
                                     shape=[B, T, h, dk]))
         windows.append(window)
+    rank = int(cfg.kda_decay_rank)
+    if rank:  # Kimi Linear's bottleneck: (D, rank) and (rank, H * dk)
+        f = _proj(_proj(u, rank, name + ".f_a"), h * dk, name + ".f_b")
+    else:
+        f = _proj(u, h * dk, name + ".f")
     g, beta = layers.kda_gate(
-        _proj(u, h * dk, name + ".f"), _proj(u, h, name + ".beta"),
+        f, _proj(u, h, name + ".beta"),
         _param([h], name + ".A_log", ConstantInitializer(0.0)),
         _param([h * dk], name + ".dt_bias", ConstantInitializer(0.0)),
-        cfg.kda_gate, cfg.kda_gate_bound)
+        cfg.kda_gate, cfg.kda_gate_bound, cfg.kda_beta_max)
     if cache is None:
         o, state = layers.kda_scan(
             *mixed, g, beta, lengths,
@@ -527,7 +544,8 @@ def _kda_mixer(u, cfg, name, lengths, cache):
                          if cfg.kda_gate == "lower_bound_sigmoid" else None))
     else:
         o, state = layers.kda_step(*mixed, g, beta, cache[3])
-    o = _head_gate(_rms(o, name + ".o_norm", cfg.norm_eps), u, cfg, name, h)
+    o = _head_gate(_rms(o, name + ".o_norm", cfg.norm_eps), u, cfg, name, h,
+                   rank)
     out = _proj(layers.reshape(o, shape=[B, T, h * dk]), cfg.d_model,
                 name + ".o")
     return out, tuple(windows) + (state,)
@@ -671,9 +689,14 @@ def _check(cfg):
     if cfg.diff_attn and (cfg.rope or cfg.attn_gate):
         raise ValueError("differential attention is built without rotary "
                          "positions and without an output gate")
-    if cfg.attn_gate not in (None, "per_head"):
-        raise ValueError("attn_gate %r: only a sigmoid gate a query head "
-                         "('per_head') is built" % (cfg.attn_gate,))
+    if cfg.attn_gate not in (None, "per_head", "per_channel"):
+        raise ValueError("attn_gate %r: a sigmoid gate a query head "
+                         "('per_head') or a channel ('per_channel') is "
+                         "built" % (cfg.attn_gate,))
+    if cfg.attn_gate == "per_channel" and cfg.has_latent:
+        raise ValueError("attn_gate 'per_channel': beside a latent layer "
+                         "only a sigmoid gate a query head ('per_head') is "
+                         "built")
     if "experts" in cfg.ffn_kinds() and (
             cfg.router_score not in ROUTER_SCORES
             or not cfg.d_shared_expert):
@@ -730,6 +753,16 @@ def _check(cfg):
     if "kda" in cfg.layer_kinds() and cfg.kda_gate not in KDA_GATES:
         raise ValueError("kda_gate %r: a KDA layer's decay gate is %s"
                          % (cfg.kda_gate, " or ".join(KDA_GATES)))
+    if float(cfg.kda_beta_max) not in KDA_BETA_MAX:
+        raise ValueError("kda_beta_max %r: a KDA layer's write strength "
+                         "lies in (0, 1) or in (0, 2)" % (cfg.kda_beta_max,))
+    rank = cfg.kda_decay_rank
+    if rank and (int(rank) != rank or not
+                 0 < rank < cfg.kda_heads * cfg.kda_head_dim):
+        raise ValueError(
+            "kda_decay_rank %r: the decay's projection is one matrix (0) "
+            "or a bottleneck narrower than its %d outputs"
+            % (cfg.kda_decay_rank, cfg.kda_heads * cfg.kda_head_dim))
     if set(cfg.ffn_kinds()) - {"dense", "experts"} or set(
             cfg.attn_types or ()) - {"full", "sliding"}:
         raise ValueError("ffn_types %r / attn_types %r name a kind no "

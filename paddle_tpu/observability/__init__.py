@@ -107,9 +107,11 @@ KDA_SCAN_TRACES = REGISTRY.counter(
     "Traces of the chunked delta rule (ops/kda.py), by path=kernel (one "
     "Pallas call whose matrix state and chunk factors stay in vector "
     "memory and whose grid stops at a row's length) | lax (composed lax "
-    "over every chunk of the bucket: the CPU, a gate with no lower "
-    "bound, a shape the kernel does not take). Counted when the op is "
-    "traced: a program loaded from a cache adds 0")
+    "over every chunk of the bucket: the CPU, a shape the kernel does "
+    "not take) and by form=factored (a gate whose bound keeps 16 tokens "
+    "inside float32) | guarded (a gate with no such bound: a sub-chunk's "
+    "own block by e^(G_t - G_i) itself). Counted when the op is traced: "
+    "a program loaded from a cache adds 0")
 KDA_STEP_TRACES = REGISTRY.counter(
     "paddle_tpu_kda_step_traces_total",
     "Traces of the delta rule's one-token step (ops/kda.py), by "

@@ -2,7 +2,9 @@
 a linear-attention layer whose memory is ONE matrix a head, written by a
 delta rule under a decay a CHANNEL. A head keeps ``S`` (dk, dv); a token
 with key ``k`` (a unit vector), value ``v``, write strength ``beta`` in
-(0, 1) and log-decay ``g`` <= 0 a key channel (``alpha = exp(g)``) does
+(0, 1), or in (0, 2) where the transition may have a negative eigenvalue
+(``KDA_BETA_MAX``: ``I - beta k k^T`` has the eigenvalue ``1 - beta``),
+and log-decay ``g`` <= 0 a key channel (``alpha = exp(g)``) does
 
     S <- Diag(alpha) S                      (forget, a channel at a time)
     S <- S + beta k (v - S^T k)^T           (the delta rule: replace what
@@ -49,13 +51,14 @@ THREE forms that compute the same numbers:
   dropped. A, T, ``T Diag(beta) V`` and ``T Diag(beta) K~`` need no
   state; the state is touched once a chunk, by three matrix products.
   The chunked form has TWO PATHS of that one algebra
-  (``paddle_tpu_kda_scan_traces_total{path}`` says which a program was
-  traced with; ``_use_kernel`` chooses by shape, gate and device):
-  composed lax (``_kda_scan_lax``: the state-free parts built for
-  ``_BLOCK_CHUNKS`` chunks at once in HBM, a ``lax.scan`` a chunk: the
-  CPU's path, the guarded gate's, the kernel's reference and its
-  backward), and on a TPU one Pallas call a layer (``pallas_kda_scan``,
-  since PR 42): a head's (dk, dv) state and a block's factors, Grams
+  (``paddle_tpu_kda_scan_traces_total{path, form}`` says which a program
+  was traced with; ``_use_kernel`` chooses by shape and device, the
+  gate's bound chooses the FORM, below, on either path): composed lax
+  (``_kda_scan_lax``: the state-free parts built for ``_BLOCK_CHUNKS``
+  chunks at once in HBM, a ``lax.scan`` a chunk: the CPU's path, the
+  kernel's reference and its backward), and on a TPU one Pallas call a
+  layer (``pallas_kda_scan``, since PR 42; in the guarded form too since
+  PR 52): a head's (dk, dv) state and a block's factors, Grams
   and inverses stay in vector memory, the operands come in once as
   blocks of the (B, T, H * d) arrays where they lie, the L2 norms and
   the masking of dead positions are inside, and a block of positions
@@ -77,8 +80,12 @@ this). Where the gate has NO lower bound (``softplus``: Kimi Linear's
 published gate) the sub-chunk's own block is taken the GUARDED way:
 ``e^{G_t - G_i}`` for t >= i directly (the exponent is never positive)
 and the sum over channels as multiplies and adds, 16 exponentials a
-(token, channel) where the factored form takes 4 and a matrix product,
-a chunk at a time: safe for any decay, and slower.
+(token, channel) where the factored form takes 4 and a matrix product;
+the other blocks are factored at ``G`` BEFORE a sub-chunk's first token,
+so that both factors are <= 1: safe for any decay (a factor that
+underflows stands for a product smaller still), nothing clamped, and
+slower. Both paths have both forms: the lax form a chunk at a time, the
+kernel with the guarded block in vector memory (``own_blocks``).
 
 ``kda_gate`` (``ptpu.kda_gate``) makes ``g`` and ``beta`` from the
 layer's projections; the L2 norm of q and k (and q's ``dk^-1/2``) is
@@ -105,6 +112,10 @@ KDA_STEP = "ptpu.kda_step"
 
 # the gates ``kda_gate`` builds
 KDA_GATES = ("lower_bound_sigmoid", "softplus")
+# the write strength's range (0, max): with a unit key ``I - beta k k^T``
+# has the eigenvalue ``1 - beta``, in (0, 1) under 1 and in (-1, 1) under 2
+# (negative eigenvalues: Grazzi et al., arXiv:2411.12537)
+KDA_BETA_MAX = (1.0, 2.0)
 
 _CHUNK = 64         # tokens the state is touched once for
 _SUB = 16           # tokens factored at one reference point
@@ -125,15 +136,21 @@ _KERNEL_GROUP = 2
 _INTRA = lax.Precision.HIGH
 
 
-def kda_gate(f, b, a_log, dt_bias, kind="lower_bound_sigmoid", bound=-5.0):
+def kda_gate(f, b, a_log, dt_bias, kind="lower_bound_sigmoid", bound=-5.0,
+             beta_max=1.0):
     """f (B, T, H * dk) = u W_f, b (B, T, H) = u W_beta, a_log (H,),
     dt_bias (H * dk,) -> (g (B, T, H, dk) float32 log-decay <= 0, beta
     (B, T, H) float32). ``kind`` "lower_bound_sigmoid": ``g = bound x
     sigmoid(exp(A_log_h) (f + dt_bias))``, in (bound, 0); "softplus":
-    ``g = -exp(A_log_h) softplus(f + dt_bias)``, unbounded below."""
+    ``g = -exp(A_log_h) softplus(f + dt_bias)``, unbounded below.
+    ``beta = beta_max sigmoid(b)``, in (0, ``beta_max``): 1, or 2 where
+    the transition may have a negative eigenvalue (``KDA_BETA_MAX``)."""
     if kind not in KDA_GATES:
         raise ValueError("kda_gate: gate %r is not built (%s are)"
                          % (kind, ", ".join(KDA_GATES)))
+    if float(beta_max) not in KDA_BETA_MAX:
+        raise ValueError("kda_gate: a write strength in (0, %r) is not "
+                         "built (0 to 1 or 2 are)" % (beta_max,))
     bsz, t, h = b.shape
     with jax.named_scope(KDA_GATE):
         x = (f.astype(jnp.float32) + dt_bias.astype(jnp.float32)).reshape(
@@ -143,7 +160,8 @@ def kda_gate(f, b, a_log, dt_bias, kind="lower_bound_sigmoid", bound=-5.0):
             g = -a * jax.nn.softplus(x)
         else:
             g = jnp.float32(bound) * jax.nn.sigmoid(a * x)
-        return g, jax.nn.sigmoid(b.astype(jnp.float32))
+        beta = jax.nn.sigmoid(b.astype(jnp.float32))
+        return g, beta if float(beta_max) == 1.0 else float(beta_max) * beta
 
 
 def _l2(x, scale=1.0):
@@ -401,7 +419,7 @@ def _cumsum_rows(x, period):
 def _kda_scan_kernel(len_ref, q_ref, k_ref, v_ref, g_ref, b_ref, o_ref,
                      st_ref, s_ref, qd_ref, kd_ref, ke_ref, vb_ref, aqk_ref,
                      am_ref, mb_ref, xb_ref, de_ref, *, block_t, n_t, qk_norm,
-                     intra, state, group):
+                     intra, state, group, guarded=False):
     """One (row, head, block of positions) grid cell, the last axis
     sequential. q_ref, k_ref, g_ref (1, Tb, dk), v_ref, o_ref (1, Tb,
     dv): a head's lanes of the (B, T, H * d) arrays where they lie;
@@ -426,7 +444,11 @@ def _kda_scan_kernel(len_ref, q_ref, k_ref, v_ref, g_ref, b_ref, o_ref,
        factors, the two Grams (the 2 x 32 rows of q and k of a pair's
        a-th sub-chunks against the keys of the sub-chunks up to their
        own, four products a pair), ``Diag(beta) A`` and its diagonal
-       blocks;
+       blocks. ``guarded`` (a gate with no lower bound): the factors
+       are taken at ``G`` BEFORE a sub-chunk's first token, so that no
+       exponent is positive, the products run against the keys of the
+       sub-chunks before their own (three a pair), and a sub-chunk's
+       own block is ``own_blocks``: ``e^{G_t - G_i}`` itself;
     2, ONCE for the block: forward substitution on all its ``Tb / 16``
        diagonal blocks together, row i of every block a strided read:
        fifteen dependent steps on a few full registers where a block at
@@ -487,6 +509,32 @@ def _kda_scan_kernel(len_ref, q_ref, k_ref, v_ref, g_ref, b_ref, o_ref,
         return (pl.ds(pl.multiple_of(p * 2 * c_, 2 * c_), 2 * c_),
                 pl.ds(pl.multiple_of(p * c_, c_), c_))
 
+    def own_blocks(q, k, g_cum):
+        """The guarded form's diagonal blocks of a pair, (A_qk, A_kk)
+        side by side: ``sum_c x_t[c] k_i[c] e^{G_t[c] - G_i[c]}`` for i
+        <= t of one sub-chunk, the exponent taken as it is (never
+        positive), the sum over channels in float32 multiplies and adds.
+        Diagonal d of every block at once: the rows d tokens back come
+        by a roll down the sublanes (a row whose partner lies in the
+        sub-chunk before is masked), so a (token, channel) costs ``_SUB
+        - 1`` exponentials and no product on the matrix unit."""
+        sub_row = lax.broadcasted_iota(jnp.int32, (2 * c_, 1), 0) % s_
+        own = [jnp.zeros((c_, 2 * c_), f32)] * 2
+        for d in range(s_):
+            if d:
+                seen = sub_row >= d
+                k_back = jnp.where(seen, pltpu.roll(k, d, 0) * jnp.exp(
+                    jnp.where(seen, g_cum - pltpu.roll(g_cum, d, 0), 0.0)),
+                    0.0)
+            else:
+                k_back = k
+            on_diagonal = tr - tc == d
+            for i, x in enumerate((q, k)):
+                col = jnp.sum(x * k_back, axis=-1, keepdims=True)  # (2 C, 1)
+                own[i] = jnp.where(
+                    on_diagonal, side_by_side(col[:c_], col[c_:]), own[i])
+        return own
+
     def state_free(p):
         rows, half = pair_rows(p)
         live = live_rows(p * 2 * c_, 2 * c_)
@@ -500,19 +548,31 @@ def _kda_scan_kernel(len_ref, q_ref, k_ref, v_ref, g_ref, b_ref, o_ref,
         g_end = jnp.concatenate(
             [jnp.broadcast_to(g_cum[(j + 1) * c_ - 1:(j + 1) * c_], (c_, dk))
              for j in range(2)], axis=0)
-        # G at each sub-chunk's middle token (module doc)
-        mid = [g_cum[a * s_ + s_ // 2 - 1:a * s_ + s_ // 2]
-               for a in range(2 * ns)]
+        if guarded:
+            # G before each sub-chunk's first token: nothing at a chunk's
+            mid = [jnp.zeros((1, dk), f32) if a % ns == 0
+                   else g_cum[a * s_ - 1:a * s_] for a in range(2 * ns)]
+        else:
+            # G at each sub-chunk's middle token (module doc)
+            mid = [g_cum[a * s_ + s_ // 2 - 1:a * s_ + s_ // 2]
+                   for a in range(2 * ns)]
         e_row = jnp.exp(g_cum - jnp.concatenate(
             [jnp.broadcast_to(m, (s_, dk)) for m in mid], axis=0))
         qe, ke = q * e_row, k * e_row
         a_qk, a_kk = [], []
         for a in range(ns):
-            n = (a + 1) * s_
+            # the keys a sub-chunk's rows are multiplied with: up to its
+            # own, or (guarded) before its own
+            n = a * s_ if guarded else (a + 1) * s_
+            if not n:
+                a_qk.append(jnp.zeros((s_, 2 * c_), f32))
+                a_kk.append(jnp.zeros((s_, 2 * c_), f32))
+                continue
             x, k_col = [], []
             for j in range(2):          # the pair's two chunks
                 at = j * c_
-                x += [qe[at + a * s_:at + n], ke[at + a * s_:at + n]]
+                x += [qe[at + a * s_:at + (a + 1) * s_],
+                      ke[at + a * s_:at + (a + 1) * s_]]
                 k_col.append(k[at:at + n] * jnp.exp(
                     mid[j * ns + a] - g_cum[at:at + n]))
                 if n < c_:
@@ -521,6 +581,10 @@ def _kda_scan_kernel(len_ref, q_ref, k_ref, v_ref, g_ref, b_ref, o_ref,
                        jnp.concatenate(k_col, axis=0), ((1,), (1,)), intra)
             a_qk.append(side_by_side(gram[:s_], gram[2 * s_:3 * s_]))
             a_kk.append(side_by_side(gram[s_:2 * s_], gram[3 * s_:]))
+        if guarded:
+            own = own_blocks(q, k, g_cum)
+            a_qk = [jnp.concatenate(a_qk, axis=0) + own[0]]
+            a_kk = [jnp.concatenate(a_kk, axis=0) + own[1]]
         m = (side_by_side(beta[:c_], beta[c_:])
              * jnp.where(tr > tc, jnp.concatenate(a_kk, axis=0), 0.0))
         aqk_ref[half, :] = jnp.where(tr >= tc, jnp.concatenate(a_qk, axis=0),
@@ -624,22 +688,22 @@ def _kernel_block(t, dk, dv, block_t=_KERNEL_BLOCK_T):
     return block_t
 
 
-def _use_kernel(t, dk, dv, lower_bound) -> bool:
+def _use_kernel(t, dk, dv) -> bool:
     """A step bound for a TPU (PADDLE_TPU_NO_PALLAS opts out, as for
-    every kernel: ``kv_cache._use_pallas_decode``), the factored form (a
-    gate whose bound keeps ``_SUB`` tokens inside float32) and a shape
-    the kernel takes (``_kernel_block``)."""
-    return (not _guarded(lower_bound)
-            and _kernel_block(t, dk, dv) is not None
+    every kernel: ``kv_cache._use_pallas_decode``) and a shape the
+    kernel takes (``_kernel_block``), whatever the gate: the kernel has
+    both forms."""
+    return (_kernel_block(t, dk, dv) is not None
             and _KV._use_pallas_decode(t, dk))
 
 
 def pallas_kda_scan(q, k, v, g, beta, lens, qk_norm=True,
                     block_t=_KERNEL_BLOCK_T, intra=3, state=1,
-                    group=_KERNEL_GROUP, interpret=False):
-    """``_kda_scan_lax``'s contract (the factored form) through the
-    kernel: ONE call, the operands where they lie, a head a block of
-    lanes of the (B, T, H * d) views. ``lens`` is a scalar-prefetch
+                    group=_KERNEL_GROUP, interpret=False, guarded=False):
+    """``_kda_scan_lax``'s contract (the factored form, or the
+    ``guarded`` one) through the kernel: ONE call, the operands where
+    they lie, a head a block of lanes of the (B, T, H * d) views.
+    ``lens`` is a scalar-prefetch
     operand: a block of positions wholly past a row's length is neither
     fetched (its index waits at the row's last live block) nor computed.
     ``intra`` / ``state``: bfloat16 passes of the products inside a
@@ -668,7 +732,7 @@ def pallas_kda_scan(q, k, v, g, beta, lens, qk_norm=True,
 
     kernel = functools.partial(
         _kda_scan_kernel, block_t=block_t, n_t=n_t, qk_norm=bool(qk_norm),
-        intra=intra, state=state, group=group)
+        intra=intra, state=state, group=group, guarded=bool(guarded))
     o, st = _A.named_pallas_call(
         KDA_SCAN, kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -710,23 +774,24 @@ def pallas_kda_scan(q, k, v, g, beta, lens, qk_norm=True,
     return o.reshape(bsz, t, h, dv), st
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _kda_scan_kernel_path(q, k, v, g, beta, lens, qk_norm, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _kda_scan_kernel_path(q, k, v, g, beta, lens, qk_norm, interpret,
+                          guarded):
     return pallas_kda_scan(q, k, v, g, beta, lens, qk_norm,
-                           interpret=interpret)
+                           interpret=interpret, guarded=guarded)
 
 
-def _kernel_path_fwd(q, k, v, g, beta, lens, qk_norm, interpret):
+def _kernel_path_fwd(q, k, v, g, beta, lens, qk_norm, interpret, guarded):
     out = pallas_kda_scan(q, k, v, g, beta, lens, qk_norm,
-                          interpret=interpret)
+                          interpret=interpret, guarded=guarded)
     return out, (q, k, v, g, beta, lens)
 
 
-def _kernel_path_bwd(qk_norm, interpret, res, cts):
+def _kernel_path_bwd(qk_norm, interpret, guarded, res, cts):
     # no cell trains through a scan: the backward is the lax form's
     *operands, lens = res
     _, vjp = jax.vjp(
-        lambda *ops: _kda_scan_lax(*ops, lens, False, qk_norm), *operands)
+        lambda *ops: _kda_scan_lax(*ops, lens, guarded, qk_norm), *operands)
     return (*vjp(cts), None)
 
 
@@ -751,15 +816,18 @@ def kda_scan(q, k, v, g, beta, lengths=None, lower_bound=None, qk_norm=True,
     dv = v.shape[-1]
     lens = (jnp.full((bsz,), t, jnp.int32) if lengths is None
             else lengths.reshape(-1).astype(jnp.int32))
-    kernel = interpret or _use_kernel(t, dk, dv, lower_bound)
-    KDA_SCAN_TRACES.inc(path="kernel" if kernel else "lax")
+    kernel = interpret or _use_kernel(t, dk, dv)
+    guarded = _guarded(lower_bound)
+    KDA_SCAN_TRACES.inc(path="kernel" if kernel else "lax",
+                        form="guarded" if guarded else "factored")
     with jax.named_scope(KDA_SCAN):
         if kernel:
             o, state = _kda_scan_kernel_path(q, k, v, g, beta, lens,
-                                             bool(qk_norm), interpret)
+                                             bool(qk_norm), interpret,
+                                             guarded)
         else:
-            o, state = _kda_scan_lax(q, k, v, g, beta, lens,
-                                     _guarded(lower_bound), qk_norm)
+            o, state = _kda_scan_lax(q, k, v, g, beta, lens, guarded,
+                                     qk_norm)
         return o.astype(v.dtype), state
 
 # bytes of matrix states a grid cell of the step's kernel holds: the block
@@ -903,11 +971,13 @@ def _bound_attr(ctx):
 @register_op("kda_gate")
 def _kda_gate_op(ctx):
     """Inputs F (B, T, H * dk), B (B, T, H), ALog (H,), DtBias (H * dk,).
-    Attrs kind, bound -> G (B, T, H, dk), Beta (B, T, H)."""
+    Attrs kind, bound, beta_max (absent: 1) -> G (B, T, H, dk), Beta
+    (B, T, H)."""
     g, beta = kda_gate(ctx.input("F"), ctx.input("B"), ctx.input("ALog"),
                        ctx.input("DtBias"),
                        str(ctx.attr("kind", "lower_bound_sigmoid")),
-                       float(ctx.attr("bound", -5.0)))
+                       float(ctx.attr("bound", -5.0)),
+                       float(ctx.attr("beta_max", 1.0)))
     return {"G": g, "Beta": beta}
 
 

@@ -184,8 +184,16 @@ class DecodeConfig:
     ``kda_heads`` heads of ``kda_head_dim`` key and value channels,
     convolutions of ``kda_conv`` taps on q, k and v, a decay gate
     ``kda_gate`` ("lower_bound_sigmoid", log-decay in (``kda_gate_bound``,
-    0) | "softplus", unbounded); it keeps three windows and ONE matrix
-    state a head, all fixed-size. A ``latent_dsa`` layer is a latent
+    0): the chunked scan's FACTORED form | "softplus", unbounded: its
+    GUARDED form; on a TPU either is one Pallas call a layer), a write
+    strength in (0, ``kda_beta_max``) (1.0 | 2.0: negative eigenvalues),
+    the decay projected by one matrix (``kda_decay_rank`` 0) or through
+    a bottleneck of that rank (Kimi Linear's); it keeps three windows
+    and ONE matrix state a head, all fixed-size. ``attn_gate``
+    "per_channel" is a gate finer than a head: on an attention layer one
+    sigmoid a channel of every query head (a (D, h x d_head) matrix), on
+    a KDA layer the same through the bottleneck of ``kda_decay_rank``
+    (low-rank; one matrix where that is 0); no latent layer builds it. A ``latent_dsa`` layer is a latent
     layer UNDER A LEARNED INDEXER (``ops/dsa.py``): ``index_heads`` index
     queries of ``index_head_dim`` from the query latent (so
     ``q_lora_rank`` > 0), one index key a position, and a query attends
@@ -233,7 +241,8 @@ class DecodeConfig:
                    ("v_head_dim", 0), ("softmax_scale", None),
                    ("kda_heads", 0), ("kda_head_dim", 0), ("kda_conv", 4),
                    ("kda_gate", "lower_bound_sigmoid"),
-                   ("kda_gate_bound", -5.0), ("router_groups", 1),
+                   ("kda_gate_bound", -5.0), ("kda_beta_max", 1.0),
+                   ("kda_decay_rank", 0), ("router_groups", 1),
                    ("router_topk_groups", 1), ("router_bias", False),
                    ("latent_ring", None), ("latent_rescale", False),
                    ("index_heads", 0), ("index_head_dim", 0),
@@ -2496,7 +2505,8 @@ class DecodeServer:
         prefill are counted from. Of a model with KDA layers,
         ``kda_tokens`` and ``kda_pad_tokens``: the real rows each such
         layer's chunked scan walked, and the rows of the bucket beyond
-        them (scanned too, and leaving every state alone). Of a model
+        them (scanned too, and leaving every state alone), and the
+        latent layers' four whatever full layer stands beside them. Of a model
         with state-space layers, ``ssm_tokens`` and ``ssm_pad_tokens``:
         the same two of each selective scan (the kernel skips the
         blocks of positions wholly past a row's length, the lax form
@@ -2549,6 +2559,12 @@ class DecodeServer:
             counts["kda_tokens"] = sum(len(p) for p in prompts)
             counts["kda_pad_tokens"] = (int(bucket_rows)
                                         - counts["kda_tokens"])
+            # beside an attention layer too (no latent layer counts them)
+            counts.update(
+                prompt_rows=counts["kda_tokens"],
+                bucket_rows=int(bucket_rows), prompts=len(prompts),
+                attn_pairs=sum(len(p) * (len(p) + 1) // 2
+                               for p in prompts))
         if self._ssm_layers:
             counts["ssm_tokens"] = sum(len(p) for p in prompts)
             counts["ssm_pad_tokens"] = (int(bucket_rows)

@@ -2,8 +2,9 @@
 `pallas_kda_scan`) in interpret mode against its lax form: lengths
 inside a chunk, inside a sub-chunk, at a block's edge, a row far shorter
 than the bucket, whole chunks at the gate's bound; the gate
-(`_use_kernel`) by shape, gate and device; the counter's `path`; the
-`custom_vjp`'s backward."""
+(`_use_kernel`) by shape and device; the GUARDED form (a gate with no
+bound) against the guarded lax form; the counter's `path` and `form`;
+the `custom_vjp`'s backward."""
 import types
 
 import numpy as np
@@ -27,12 +28,19 @@ def _kernel_inputs(bsz, t, seed=0, decay="mixed"):
                                dk=_KD, dv=_KD)
     if decay == "bound_channels":   # whole chunks at the bound, some channels
         g[..., ::4] = -4.999
+    if decay == "deep":             # and channels no float could factor
+        g[..., ::7] = -1e4
     return tuple(jnp.asarray(a) for a in (q, k, v, g, beta))
 
 
-def _traces():
-    got = {k["path"]: v for k, v in obs.KDA_SCAN_TRACES.samples()}
-    return got.get("kernel", 0), got.get("lax", 0)
+def _traces(form=None):
+    """(kernel, lax) traces, of one ``form`` ("factored" | "guarded") or
+    of both."""
+    got = {"kernel": 0, "lax": 0}
+    for k, v in obs.KDA_SCAN_TRACES.samples():
+        if form in (None, k["form"]):
+            got[k["path"]] += v
+    return got["kernel"], got["lax"]
 
 
 _KERNEL_CASES = [
@@ -92,6 +100,79 @@ def test_kernel_equals_the_lax_form(t, lens, decay, passes):
         np.testing.assert_array_equal(got_o[bi, n:], 0.0)
 
 
+_GUARDED_CASES = [
+    # id, bucket, lengths, decay: the gate with no bound
+    ("softplus-inside-a-chunk", _BT, [200, _BT], "softplus"),
+    ("softplus-inside-a-sub-chunk", 2 * _BT, [_BT + 70, 9], "softplus"),
+    ("deep-far-shorter", 3 * _BT, [3 * _BT, 30, 0], "deep"),
+    ("every-channel-at-minus-5", _BT, [_BT, 129], "bound"),
+]
+
+
+@pytest.mark.parametrize(
+    "t,lens,decay,passes",
+    [c[1:] + (6,) for c in _GUARDED_CASES]
+    + [c[1:] + (None,) for c in _GUARDED_CASES[:2]],
+    ids=[c[0] + "-float32" for c in _GUARDED_CASES]
+    + [c[0] + "-as-run" for c in _GUARDED_CASES[:2]])
+def test_guarded_kernel_equals_the_guarded_lax_form(t, lens, decay, passes):
+    """The kernel's GUARDED form (interpret mode) against
+    ``_kda_scan_lax(guarded=True)``, the same algebra: a sub-chunk's own
+    block by ``e^{G_t - G_i}`` itself, the reference points before a
+    sub-chunk's first token. Kimi Linear's gate with beta in (0, 2) and
+    a row of decays below e^-40 a token; log-decays of -30 and channels
+    at -1e4, which no reference point could factor; lengths inside a
+    chunk, inside a sub-chunk, a row far shorter than the bucket and an
+    empty one. Nothing is clamped: the numbers are the lax form's, and
+    finite. Tolerances as ``test_kernel_equals_the_lax_form``; as run,
+    through ``kda_scan(lower_bound=None)``, whose counter says
+    ``form=guarded``."""
+    ops = _kernel_inputs(len(lens), t, seed=t, decay=decay)
+    ln = jnp.asarray(lens, jnp.int32)
+    with jax.default_matmul_precision("highest"):
+        want_o, want_s = jax.jit(
+            lambda *a: kda._kda_scan_lax(*a, True, True))(*ops, ln)
+    if passes is None:
+        before = _traces("guarded"), _traces("factored")
+        got_o, got_s = kda.kda_scan(*ops, ln, lower_bound=None,
+                                    interpret=True)
+        assert _traces("guarded") == (before[0][0] + 1, before[0][1])
+        assert _traces("factored") == before[1]
+        tol = dict(rtol=2e-2, atol=2e-2)
+    else:
+        got_o, got_s = jax.jit(lambda *a: kda.pallas_kda_scan(
+            *a, intra=passes, state=passes, interpret=True,
+            guarded=True))(*ops, ln)
+        tol = dict(rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(got_s, want_s, **tol)
+    got_o = np.asarray(got_o)
+    assert np.isfinite(got_o).all() and np.isfinite(np.asarray(got_s)).all()
+    for bi, n in enumerate(lens):
+        np.testing.assert_allclose(got_o[bi, :n], np.asarray(want_o)[bi, :n],
+                                   **tol)
+        np.testing.assert_array_equal(got_o[bi, n:], 0.0)
+
+
+@pytest.mark.parametrize("bound", [None, -8.0],
+                         ids=["a-gate-with-no-bound",
+                              "a-bound-too-low-to-factor"])
+def test_a_gate_the_factored_form_cannot_take_has_a_kernel(bound):
+    """What `_use_kernel` refused before the kernel had a guarded form:
+    a gate with no bound, and one whose bound lets 16 tokens leave
+    float32. Both now reach the kernel, in its guarded form."""
+    assert kda._guarded(bound) and not kda._guarded(-5.0)
+    ops = _kernel_inputs(1, _BT, seed=11)
+    ln = jnp.asarray([_BT - 3], jnp.int32)
+    before = _traces("guarded")
+    got_o, got_s = kda.kda_scan(*ops, ln, lower_bound=bound, interpret=True)
+    assert _traces("guarded") == (before[0] + 1, before[1])
+    with jax.default_matmul_precision("highest"):
+        want_o, want_s = kda._kda_scan_lax(*ops, ln, True, True)
+    np.testing.assert_allclose(got_s, want_s, rtol=2e-2, atol=2e-2)
+    np.testing.assert_allclose(got_o[0, :_BT - 3], want_o[0, :_BT - 3],
+                               rtol=2e-2, atol=2e-2)
+
+
 def test_kernel_without_the_norm_and_with_no_lengths():
     """``qk_norm`` off (q times ``dk^-1/2`` only) and ``lengths`` None
     (every position live) reach the kernel as they reach the lax form."""
@@ -134,16 +215,14 @@ def test_kernel_path_differentiates_as_the_lax_form():
 
 
 _GATE_CASES = [
-    # id, (t, dk, dv, lower_bound), whether the kernel takes it on a TPU
-    ("the-cells-16384", (16384, 128, 128, -5.0), True),
-    ("one-block", (_BT, 128, 128, -5.0), True),
-    ("wider-heads", (2 * _BT, 256, 128, -2.0), True),
-    ("under-one-block", (_BT // 2, 128, 128, -5.0), False),
-    ("not-whole-blocks", (_BT + 64, 128, 128, -5.0), False),
-    ("a-head-of-no-lane-tiles", (_BT, 64, 64, -5.0), False),
-    ("a-value-head-of-no-lane-tiles", (_BT, 128, 96, -5.0), False),
-    ("a-gate-with-no-bound", (_BT, 128, 128, None), False),
-    ("a-bound-too-low-to-factor", (_BT, 128, 128, -8.0), False),
+    # id, (t, dk, dv), whether the kernel takes it on a TPU
+    ("the-cells-16384", (16384, 128, 128), True),
+    ("one-block", (_BT, 128, 128), True),
+    ("wider-heads", (2 * _BT, 256, 128), True),
+    ("under-one-block", (_BT // 2, 128, 128), False),
+    ("not-whole-blocks", (_BT + 64, 128, 128), False),
+    ("a-head-of-no-lane-tiles", (_BT, 64, 64), False),
+    ("a-value-head-of-no-lane-tiles", (_BT, 128, 96), False),
 ]
 
 
@@ -152,9 +231,10 @@ _GATE_CASES = [
 def test_kernel_gate_answers_from_shape_gate_and_device(shape, takes,
                                                         monkeypatch):
     """``_use_kernel`` answers from what the op is handed (whole blocks
-    of positions, heads of whole lane tiles, a gate whose bound keeps
-    the factored form inside float32) and the device a step is bound
-    for: never the CPU, never under PADDLE_TPU_NO_PALLAS."""
+    of positions, heads of whole lane tiles; whatever the gate: the
+    kernel has the factored form and the guarded one) and the device a
+    step is bound for: never the CPU, never under
+    PADDLE_TPU_NO_PALLAS."""
     assert not kda._use_kernel(*shape)
     monkeypatch.setattr(KV, "current_device",
                         lambda: types.SimpleNamespace(platform="tpu"))
@@ -164,9 +244,12 @@ def test_kernel_gate_answers_from_shape_gate_and_device(shape, takes,
 
 
 def test_a_refused_shape_takes_the_lax_path(monkeypatch):
-    """A bucket that is not whole blocks, and a gate with no bound, on
-    a device the kernel runs on: the lax form, and the counter says so;
-    the kernel's own entry refuses the shape by name."""
+    """A bucket that is not whole blocks, under either gate, on a
+    device the kernel runs on: the lax form, and the counter says so,
+    form by form (a program traced onto the composed GUARDED path on a
+    TPU is what `tests/test_tpu_compile_serving.py` refuses of the
+    Solar-Open2 cell's prefills); the kernel's own entry refuses the
+    shape by name."""
     monkeypatch.setattr(KV, "current_device",
                         lambda: types.SimpleNamespace(platform="tpu"))
     ops = _kernel_inputs(1, 64, seed=1)
@@ -175,7 +258,9 @@ def test_a_refused_shape_takes_the_lax_path(monkeypatch):
     o, state = kda.kda_scan(*ops, ln, lower_bound=-5.0)
     assert _traces() == (k0, l0 + 1)
     assert o.shape == (1, 64, _KH, _KD) and state.shape == (1, _KH, _KD, _KD)
+    guarded = _traces("guarded")
     kda.kda_scan(*ops, ln, lower_bound=None)
     assert _traces() == (k0, l0 + 2)
+    assert _traces("guarded") == (guarded[0], guarded[1] + 1)
     with pytest.raises(ValueError, match="the lax form runs it"):
         kda.pallas_kda_scan(*ops, ln, interpret=True)

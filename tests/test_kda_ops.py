@@ -51,9 +51,23 @@ def _inputs(bsz, t, seed=0, decay="mixed", h=H, dk=DK, dv=DV):
         g = np.full((bsz, t, h, dk), -4.999, np.float32)
     elif decay == "deep":     # far below what 16 tokens could factor
         g = (-30.0 * r.uniform(size=(bsz, t, h, dk))).astype(np.float32)
+    elif decay == "softplus":  # Kimi Linear's gate from its published
+        # initialisation (a head's rate in [1, 16], a channel's step
+        # log-uniform in [1e-3, 0.1]) through `kda_gate`, and a ROW of
+        # decays below e^-40 a token
+        g = np.asarray(kda.kda_gate(
+            r.normal(size=(bsz, t, h * dk)).astype(np.float32),
+            np.zeros((bsz, t, h), np.float32),
+            np.log(r.uniform(1.0, 16.0, h)).astype(np.float32),
+            np.log(np.expm1(np.exp(r.uniform(
+                np.log(1e-3), np.log(0.1), h * dk)))).astype(np.float32),
+            "softplus")[0]).copy()
+        g[:, t // 3] = r.uniform(-100.0, -40.0, size=(bsz, h, dk))
     else:                     # fast and slow channels side by side
         g = (-5.0 * r.uniform(size=(bsz, t, h, dk)) ** 3).astype(np.float32)
-    beta = r.uniform(0.05, 0.95, size=(bsz, t, h)).astype(np.float32)
+    # beta in (0, 2) under the unbounded gate (`KDA_BETA_MAX`)
+    top = 1.95 if decay == "softplus" else 0.95
+    beta = r.uniform(0.05, top, size=(bsz, t, h)).astype(np.float32)
     return q, k, v, g, beta
 
 
@@ -79,8 +93,13 @@ def _both(args, lens, bound):
     (150, [150, 40], "bound", None),
     (150, [150, 113], "deep", None),     # log-decays no bound could hold
     (150, [150, 113], "mixed", -8.0),    # a bound too low to factor: guarded
+    # the softplus gate, beta in (0, 2), a row of decays below e^-40, a
+    # prompt that ends inside a chunk and one inside a sub-chunk
+    (150, [150, 113], "softplus", None),
+    (1100, [1100, 70], "softplus", None),
 ], ids=["mixed", "at_bound", "one_chunk", "at_bound_70", "blocks",
-        "guarded", "guarded_at_bound", "guarded_deep", "low_bound"])
+        "guarded", "guarded_at_bound", "guarded_deep", "low_bound",
+        "softplus_beta_2", "softplus_beta_2_blocks"])
 def test_chunked_equals_token_by_token(t, lens, decay, bound):
     args = _inputs(2, t, seed=t, decay=decay)
     o, s, o_ref, s_ref = _both(args, np.array(lens, np.int32), bound)
@@ -105,14 +124,16 @@ def test_padding_leaves_the_state_alone():
     np.testing.assert_allclose(s_noisy, s, atol=1e-6)
 
 
-@pytest.mark.parametrize("decay", ["mixed", "bound"])
+@pytest.mark.parametrize("decay", ["mixed", "bound", "softplus"])
 def test_step_after_step_equals_the_scan(decay):
     """A prefill's state handed to the step, then steps: the same
-    numbers as one scan over everything."""
+    numbers as one scan over everything (under the softplus gate with
+    beta in (0, 2): the guarded scan, then the same step)."""
     args = _inputs(2, 90, seed=9, decay=decay)
     o_all, s_all = kda_recurrent(*args)
     with jax.default_matmul_precision("highest"):
-        _, state = kda.kda_scan(*(a[:, :70] for a in args), None, -5.0)
+        _, state = kda.kda_scan(*(a[:, :70] for a in args), None,
+                                None if decay == "softplus" else -5.0)
     outs = []
     for i in range(70, 90):
         o, state = kda.kda_step(*(a[:, i:i + 1] for a in args), state)
@@ -148,6 +169,11 @@ def test_gate_kinds(kind):
             else -a * np.logaddexp(0.0, x))
     np.testing.assert_allclose(g, want, rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(beta, 1 / (1 + np.exp(-b)), rtol=1e-5)
+    # the write strength's factor: (0, 2) where the transition may have
+    # a negative eigenvalue; the decay is the same
+    g2, beta2 = kda.kda_gate(f, b, a_log, dt, kind, -5.0, beta_max=2.0)
+    np.testing.assert_allclose(beta2, 2 / (1 + np.exp(-b)), rtol=1e-5)
+    np.testing.assert_array_equal(g2, g)
     assert (np.asarray(g) <= 0).all()
     if kind == "lower_bound_sigmoid":
         assert (np.asarray(g) > -5.0).all()

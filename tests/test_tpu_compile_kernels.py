@@ -221,12 +221,19 @@ def test_ssm_scan_kernel_compiles(one_chip, b, t):
         2 * b * t * 128 * 4 + 2**16)
 
 
-@pytest.mark.parametrize("b,t", [(8, 2048), (2, 8192), (1, 16384)],
-                         ids=["cell-8x2048", "cell-2x8192", "cell-1x16384"])
-def test_kda_scan_kernel_compiles(one_chip, b, t):
+@pytest.mark.parametrize(
+    "b,t,h,guarded",
+    [(8, 2048, 32, False), (2, 8192, 32, False), (1, 16384, 32, False),
+     (8, 2048, 64, True), (4, 4096, 64, True)],
+    ids=["cell-8x2048", "cell-2x8192", "cell-1x16384",
+         "guarded-64-heads-8x2048", "guarded-64-heads-4x4096"])
+def test_kda_scan_kernel_compiles(one_chip, b, t, h, guarded):
     """The chunked delta rule's kernel (`ops/kda.py`) at Ling-3.0-flash's
     head sizes (32 heads of a 128 x 128 state), at the cell's widest,
-    its middle and its longest admission: Mosaic takes the strided
+    its middle and its longest admission, and in its GUARDED form (a
+    gate with no bound: the rolls down the sublanes and the masked
+    exponentials of a sub-chunk's own block) at Solar-Open2-250B's 64
+    heads and widest admissions: Mosaic takes the strided
     reads and writes of the forward substitution, the lane slices of the
     diagonal blocks, the stacked bfloat16 parts of the three-pass
     products and the dynamic loops over live chunks, inside the default
@@ -238,7 +245,7 @@ def test_kda_scan_kernel_compiles(one_chip, b, t):
     call: the only ones are o before its reshape and beta's copy."""
     from paddle_tpu.ops import kda as K
 
-    h, d = 32, 128
+    d = 128
     block_t = K._kernel_block(t, d, d)
     assert block_t == K._KERNEL_BLOCK_T
     # double-buffered blocks of q, k, v, g, o, beta (H lanes padded to
@@ -257,7 +264,8 @@ def test_kda_scan_kernel_compiles(one_chip, b, t):
 
     def scan(q, k, v, g, beta, lens):
         q, k, v, g = (a.reshape(b, t, h, d) for a in (q, k, v, g))
-        o, state = K.pallas_kda_scan(q, k, v, g, beta, lens)
+        o, state = K.pallas_kda_scan(q, k, v, g, beta, lens,
+                                     guarded=guarded)
         return o.reshape(b, t, h * d), state
 
     flat = sd((b, t, h * d))
@@ -267,12 +275,12 @@ def test_kda_scan_kernel_compiles(one_chip, b, t):
     line, = [ln for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
     assert "%ptpu.kda_scan" in line.split(" = ")[0], line
-    assert ",32,128,128]" in line.split(" custom-call(")[0], line[:400]
+    assert ",%d,128,128]" % h in line.split(" custom-call(")[0], line[:400]
     assert " while(" not in text
     wide = [(op, name) for op, name, _ in _whole_slab_ops(text, (b, t, h * d))
             if op not in ("parameter", "get-tuple-element", "bitcast")]
     assert not wide, "q, k, v, g or o copied outside the call: %r" % wide
-    for shape in (",64,64]", ",16,16]", ",4,4,16,", ",64,32,128]"):
+    for shape in (",64,64]", ",16,16]", ",4,4,16,", ",64,%d,128]" % h):
         assert shape not in text, shape
     assert compiled.memory_analysis().temp_size_in_bytes <= (
         b * t * 128 * 4 + 2**16)

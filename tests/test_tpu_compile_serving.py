@@ -275,6 +275,124 @@ def test_hybrid_serving_step_compiles(one_chip, monkeypatch, kind, batch,
     assert mem.temp_size_in_bytes < 200 * 2**20, mem.temp_size_in_bytes
 
 
+def _kda_scans_traced(form):
+    """(kernel, lax) traces of the chunked delta rule in one form
+    ("factored" | "guarded")."""
+    from paddle_tpu import observability as obs
+
+    got = {"kernel": 0, "lax": 0}
+    for k, v in obs.KDA_SCAN_TRACES.samples():
+        if k["form"] == form:
+            got[k["path"]] += v
+    return got["kernel"], got["lax"]
+
+
+_SOLAR_CASES = [
+    # id, kind, batch, seq: the Solar-Open2-250B serving cell's own
+    # programs (benchmark/configs/solar-open2-250b.json: 4 layers at
+    # published widths, 20 of 320 experts held, 32 slots of 8,192
+    # positions; the largest admissions hold 16,384 bucketed tokens)
+    ("decode-32x8192", "decode", 32, 8192),
+    ("prefill-4x4096", "prefill", 4, 4096),
+]
+
+
+@pytest.mark.parametrize("kind,batch,seq", [c[1:] for c in _SOLAR_CASES],
+                         ids=[c[0] for c in _SOLAR_CASES])
+def test_solar_open2_serving_step_compiles(one_chip, monkeypatch, kind,
+                                           batch, seq):
+    """The programs DecodePredictor builds for the Solar-Open2-250B cell
+    (three KDA layers: 64 heads of a 128 x 128 state under the UNBOUNDED
+    softplus gate, beta in (0, 2), the decay and the output gate through
+    bottlenecks of 128; one softmax layer: 64 query heads on 8 key/value
+    heads of 128, no positions, an elementwise gate; a softmax router
+    over 320 experts of width 1,280, 20 held; an untied head over 24,576
+    ids): they compile for a v5e and fit it beside each other. An
+    admission holds ONE `ptpu.kda_scan` call a KDA layer, the GUARDED
+    form's, with the final state at its interface shape in its own line
+    (the counter says `kernel`, `form=guarded`, three times, and `lax`
+    never: a program traced onto the composed guarded path on a TPU
+    fails here), beside the softmax layer's one flash forward on
+    bfloat16 operands (k and v repeated for the 8 query heads that share
+    each, as Laguna's prefill hands them). The step donates its
+    fourteen entries: each matrix state comes back from one call of the
+    step's kernel over itself (`ptpu.kda_step` takes 64 heads: 16 a grid
+    cell), the slab is read where it lies by one call of the grouped
+    kernel (64 query heads on 8, no rotation), and nothing of a state's
+    or the slab's size is copied."""
+    from paddle_tpu import observability as obs
+    from test_tpu_compile_cells import (
+        _assert_bfloat16_operands_and_lengths, _cell_predictor)
+
+    def steps_traced():
+        got = {"kernel": 0, "lax": 0}
+        for k, v in obs.KDA_STEP_TRACES.samples():
+            got[k["path"]] += v
+        return got["kernel"], got["lax"]
+
+    pred = _cell_predictor("solar_open2_lm", "solar-open2-250b.json",
+                           monkeypatch)
+    scans, steps = _kda_scans_traced("guarded"), steps_traced()
+    factored = _kda_scans_traced("factored")
+    step_fn, feeds, state, n_cache = _serving_step(pred, kind, batch, seq,
+                                                   one_chip)
+    compiled = jax.jit(step_fn, donate_argnums=(0,)).lower(
+        feeds, state).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < HBM_BYTES, "does not fit one chip: %r" % (mem,)
+    weights = sum(int(np.prod(s.shape)) * 4 for s in state.values())
+    assert 8.19e9 < weights < 8.21e9, weights  # 2.050 B parameters
+    text = compiled.as_text()
+    spec = pred.cache_spec(32, 8192)
+    slabs = sum(e.nbytes for e in spec)
+    assert round(slabs / 1e9, 2) == 2.58
+    calls = re.findall(r"%([\w.-]+?)(?:\.\d+)? = [^\n]*"
+                       r'custom_call_target="tpu_custom_call"', text)
+    if kind == "prefill":
+        assert _kda_scans_traced("guarded") == (scans[0] + 3, scans[1])
+        assert _kda_scans_traced("factored") == factored
+        assert calls.count("ptpu.kda_scan") == 3, calls
+        assert calls.count("ptpu.flash_fwd") == 1, calls
+        assert calls.count("ragged-dot-none") == 3 * 4
+        for ln in text.splitlines():
+            if ('custom_call_target="tpu_custom_call"' in ln
+                    and "%ptpu.kda_scan" in ln.split(" = ")[0]):
+                assert "f32[4,64,128,128]" in ln.split(" custom-call(")[
+                    0], ln[:400]
+        assert _assert_bfloat16_operands_and_lengths(text, batch)[0][1][
+            1:] == ["bf16[4,4096,8192]"] * 3
+        # beside the weights, the slots' entries and the step
+        assert weights + slabs + mem.temp_size_in_bytes + (
+            mem.output_size_in_bytes) < 15.5 * 2**30, mem
+        assert mem.temp_size_in_bytes < 4.4 * 2**30, mem.temp_size_in_bytes
+        return
+    assert steps_traced() == (steps[0] + 3, steps[1])
+    assert sorted(c for c in calls if c.startswith("ptpu.")) == [
+        "ptpu.decode_attn_grouped"] + ["ptpu.kda_step"] * 3, calls
+    kernels = [ln for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln
+               and "%ptpu.kda_step" in ln.split(" = ")[0]]
+    for ln in kernels:
+        assert ln.count("f32[32,64,128,128]{3,2,1,0") >= 2, ln[:600]
+        assert re.search(r"output_to_operand_aliasing=\{\{1\}: \(0, \{\}\)\}",
+                         ln), ln[:900]
+    assert n_cache == len(spec) == 14
+    assert mem.alias_size_in_bytes >= slabs
+    # a matrix state is written by one call a layer, never copied
+    # (the 4096 x 8192 projections hold as many elements, and the
+    # compiler rounds one to bfloat16 by a `copy`: told apart by type)
+    ops = [op for op, _, _ in _whole_slab_ops(text, (32, 64, 128, 128))]
+    assert ops.count("get-tuple-element") == 3, ops
+    assert not re.search(r"= f32\[32,64,128,128\]\S* copy\(", text)
+    # the slab: read and appended to where it lies
+    moved = [name for op, name, changed in _whole_slab_ops(
+        text, (32, 8192, 8, 128)) if op == "copy" or changed]
+    assert not moved, moved
+    assert mem.temp_size_in_bytes < 100 * 2**20, mem.temp_size_in_bytes
+
+
 def test_ling3_prefill_holds_one_scan_kernel_a_kda_layer(one_chip,
                                                          monkeypatch):
     """The Ling-3.0-flash cell's widest admission (8 prompts of the
@@ -292,14 +410,12 @@ def test_ling3_prefill_holds_one_scan_kernel_a_kda_layer(one_chip,
     step_fn, feeds, state, _ = _serving_step(pred, "prefill", 8, 2048,
                                              one_chip)
 
-    def scans_traced():
-        got = {k["path"]: v for k, v in obs.KDA_SCAN_TRACES.samples()}
-        return got.get("kernel", 0), got.get("lax", 0)
-
-    k0, l0 = scans_traced()
+    k0, l0 = _kda_scans_traced("factored")
+    guarded = _kda_scans_traced("guarded")
     compiled = jax.jit(step_fn, donate_argnums=(0,)).lower(
         feeds, state).compile()
-    assert scans_traced() == (k0 + 5, l0)
+    assert _kda_scans_traced("factored") == (k0 + 5, l0)
+    assert _kda_scans_traced("guarded") == guarded
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)

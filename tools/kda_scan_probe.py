@@ -8,7 +8,12 @@ against the Pallas kernel, at Ling-3.0-flash's head sizes (32 heads of a
 falls in the bucket). Prints us a token a layer for each, per BUCKET
 token and per LIVE token, how far the two forms are apart and how far
 each is from the lax form at `highest` precision; writes
-`chiprun_out/kda_scan_probe.json`.
+`chiprun_out/kda_scan_probe.json`. `--guarded 1` probes the GUARDED
+form of both (a gate with no bound: the Solar-Open2-250B cell's, with
+`--heads 64 --admissions 8x512,8x1024,8x2048,4x4096`): g as Kimi
+Linear's softplus gate makes it, a seventh of the channels at -40 to
+-100 a token, beta in (0, 2); `--lax-up-to` bucket tokens the composed
+lax form is run at all (a chunk at a time, it takes seconds at 16,384).
 
     chiprun -- python tools/kda_scan_probe.py [--blocks 256,512]
 
@@ -53,14 +58,26 @@ def mix_lengths(rng, bsz, t):
                     np.int32)
 
 
-def operands(rng, bsz, t):
+def operands(rng, bsz, t, guarded=False):
     """q, k, v as a SiLU's outputs, g as the gate's with `dt_bias`
     N(-4.6, 1.3), some channels at the bound, beta in (0, 1): flat
-    (B, T, H * d) as the projections leave them."""
+    (B, T, H * d) as the projections leave them. ``guarded``: g as the
+    unbounded gate's (a head's rate in [1, 16], a channel's step
+    log-uniform in [1e-3, 0.1] times e^N(0, 1)), a seventh of the
+    channels at -40 to -100 a token, beta in (0, 2)."""
     def silu(x):
         return x / (1.0 + np.exp(-x))
     q, k, v = (silu(rng.normal(size=(bsz, t, H * DK))).astype(np.float32)
                for _ in range(3))
+    if guarded:
+        rate = np.repeat(rng.uniform(1.0, 16.0, size=H), DK)
+        step = np.exp(rng.uniform(np.log(1e-3), np.log(0.1), size=H * DK)
+                      + rng.normal(size=(bsz, t, H * DK)))
+        g = (-rate * step).astype(np.float32)
+        deep = g[..., ::7]
+        g[..., ::7] = rng.uniform(-100.0, -40.0, size=deep.shape)
+        beta = rng.uniform(0.02, 1.98, size=(bsz, t, H)).astype(np.float32)
+        return tuple(jnp.asarray(a) for a in (q, k, v, g, beta))
     x = rng.normal(-4.6, 1.3, size=(1, 1, H * DK)) + rng.normal(
         size=(bsz, t, H * DK))
     g = (BOUND / (1.0 + np.exp(-x))).astype(np.float32)
@@ -103,12 +120,18 @@ def apart(got, want, mask):
 
 
 def main():
+    global H
     ap = argparse.ArgumentParser()
     ap.add_argument("--blocks", default=str(K._KERNEL_BLOCK_T))
     ap.add_argument("--state-passes", default="1")
     ap.add_argument("--admissions", default=ADMISSIONS)
     ap.add_argument("--seed", type=int, default=42)
+    ap.add_argument("--heads", type=int, default=H)
+    ap.add_argument("--guarded", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--lax-up-to", type=int, default=1 << 30)
     a = ap.parse_args()
+    H = a.heads
+    guarded = bool(a.guarded)
     dev = jax.devices()[0]
     print("device", dev.platform, dev.device_kind, flush=True)
     if dev.platform != "tpu":
@@ -119,34 +142,36 @@ def main():
     out = {"device": [dev.platform, dev.device_kind], "rows": []}
 
     def lax_form(*ops):
-        return K._kda_scan_lax(*ops, False, True)
+        return K._kda_scan_lax(*ops, guarded, True)
 
     lax_f = chained(lax_form)
     truth_f = chained(lax_form, 1)
     for adm in a.admissions.split(","):
         bsz, t = (int(v) for v in adm.split("x"))
         lens = mix_lengths(rng, bsz, t)
-        args = operands(rng, bsz, t) + (jnp.asarray(lens),)
+        args = operands(rng, bsz, t, guarded) + (jnp.asarray(lens),)
         live, bucket = int(lens.sum()), bsz * t
-        row = {"batch": bsz, "bucket": t, "live": live,
-               "lengths": lens.tolist()}
+        row = {"batch": bsz, "bucket": t, "live": live, "heads": H,
+               "guarded": guarded, "lengths": lens.tolist()}
         n = max(2, min(10, int(0.5 / (CHAIN * bucket * 2e-6))))
-        sec = timed(lax_f, args, n)
-        row["lax_us_per_bucket_tok"] = sec / bucket * 1e6
-        row["lax_us_per_live_tok"] = sec / live * 1e6
-        want_o, want_s = lax_f(*args)
-        with jax.default_matmul_precision("highest"):
-            true_o, true_s = truth_f(*args)
+        with_lax = bucket <= a.lax_up_to
         mask = (np.arange(t)[None, :] < lens[:, None])[..., None, None]
-        row["lax_o_from_highest"] = apart(want_o, true_o, mask)
-        row["lax_state_from_highest"] = apart(want_s, true_s, True)
+        if with_lax:
+            sec = timed(lax_f, args, n)
+            row["lax_us_per_bucket_tok"] = sec / bucket * 1e6
+            row["lax_us_per_live_tok"] = sec / live * 1e6
+            want_o, want_s = lax_f(*args)
+            with jax.default_matmul_precision("highest"):
+                true_o, true_s = truth_f(*args)
+            row["lax_o_from_highest"] = apart(want_o, true_o, mask)
+            row["lax_state_from_highest"] = apart(want_s, true_s, True)
         for bt in (int(v) for v in a.blocks.split(",")):
             for sp in (int(v) for v in a.state_passes.split(",")):
                 tag = "k%d_s%d" % (bt, sp)
                 if t % bt:
                     continue
                 f = chained(lambda *o, bt=bt, sp=sp: K.pallas_kda_scan(
-                    *o, block_t=bt, state=sp))
+                    *o, block_t=bt, state=sp, guarded=guarded))
                 try:
                     sec = timed(f, args, 2 * n)
                     got_o, got_s = f(*args)
@@ -155,11 +180,13 @@ def main():
                     continue
                 row[tag + "_us_per_bucket_tok"] = sec / bucket * 1e6
                 row[tag + "_us_per_live_tok"] = sec / live * 1e6
+                row[tag + "_finite"] = bool(jnp.all(jnp.isfinite(got_o)))
+                if not with_lax:
+                    continue
                 row[tag + "_o_from_lax"] = apart(got_o, want_o, mask)
                 row[tag + "_state_from_lax"] = apart(got_s, want_s, True)
                 row[tag + "_o_from_highest"] = apart(got_o, true_o, mask)
                 row[tag + "_state_from_highest"] = apart(got_s, true_s, True)
-                row[tag + "_finite"] = bool(jnp.all(jnp.isfinite(got_o)))
         out["rows"].append(row)
         print(json.dumps(row), flush=True)
     os.makedirs("chiprun_out", exist_ok=True)

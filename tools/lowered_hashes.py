@@ -52,6 +52,8 @@ CELLS = [
      [("decode", 32, 16384), ("prefill", 4, 4096)]),
     ("evabyte_lm", "evabyte.json",
      [("decode", 16, 16384), ("prefill", 2, 8192)]),
+    ("solar_open2_lm", "solar-open2-250b.json",
+     [("decode", 32, 8192), ("prefill", 4, 1024)]),
 ]
 
 
