@@ -817,7 +817,8 @@ def phase_parallel(cfg, place, steps=3):
                         shard_shapes=[list(s.data.shape) for s in shards],
                         shard_devices=[str(s.device) for s in shards])
             assert len({s.device for s in shards}) == 4, info
-            assert all(s.data.shape == (w.shape[0], w.shape[1] // 2)
+            # columns over mp; rows over dp, whose one rank owns the update
+            assert all(s.data.shape == (w.shape[0] // 2, w.shape[1] // 2)
                        for s in shards), info
             text = _parallel_step_text(pexe, feed)
             info["tpu_custom_calls"] = text.count("tpu_custom_call")

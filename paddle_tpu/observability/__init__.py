@@ -35,6 +35,7 @@ __all__ = [
     "hlo_cost_stats", "nbytes_of",
     # shared instruments
     "COMPILE_TOTAL", "COMPILE_LATENCY_MS", "CACHE_HITS", "CACHE_MISSES",
+    "DP_OWNED_STATE_BYTES",
     "CACHE_ENTRIES_FED", "CACHE_ENTRIES_ALIASED",
     "CACHE_EVICTIONS", "STEP_LATENCY_MS", "STEPS_TOTAL", "FEED_BYTES",
     "FETCH_BYTES", "RUN_LOOP_WINDOW_STEPS", "READER_PREFETCH_EVENTS",
@@ -139,6 +140,13 @@ CACHE_MISSES = REGISTRY.counter(
     "paddle_tpu_compile_cache_misses_total",
     "Compile-cache misses, by kind, program fingerprint, and "
     "tier=memory|disk")
+DP_OWNED_STATE_BYTES = REGISTRY.gauge(
+    "paddle_tpu_parallel_dp_owned_state_bytes",
+    "ParallelExecutor, set when a step is compiled, by program fingerprint: "
+    "of=state the bytes of the step's persistable state, of=owned those "
+    "of them that the plan splits over a batch axis wider than 1, so that "
+    "one data-parallel rank owns their update (0 on a replicated plan and "
+    "on a mesh with no such axis)")
 CACHE_EVICTIONS = REGISTRY.counter(
     "paddle_tpu_compile_cache_evictions_total",
     "Compile-cache LRU evictions (cap: PADDLE_TPU_COMPILE_CACHE_MAX)")

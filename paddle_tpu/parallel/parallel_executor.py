@@ -149,6 +149,7 @@ class ParallelExecutor:
         self._cache: Dict = {}
         self._step = 0
         self._base_keys: Dict = {}
+        self._owned_state = {"dp_owned_state_bytes": 0, "state_bytes": 0}
 
     @property
     def device_count(self) -> int:
@@ -241,6 +242,7 @@ class ParallelExecutor:
             for n, a in out_state_aval.items()
         }
         rep = plan.replicated()
+        self._observe_owned_state(state_aval, in_state_shardings)
 
         if loop:
             # device-side multi-step loop (see Executor.run_loop): the same
@@ -271,6 +273,27 @@ class ParallelExecutor:
                 donate_argnums=(1,),
             )
         return _ParCompiled(fn, state_in, state_out, fetch_names)
+
+    def _observe_owned_state(self, state_aval, shardings):
+        """How far the plan gave each update to ONE data-parallel rank:
+        bytes of the step's persistable state that a batch axis wider than
+        1 splits (the update of such a variable runs on 1/dp of it; dp
+        twins else run the same update on the same summed gradient), of
+        all its bytes. Read by ``run_stats()`` and the gauge."""
+        wide = {a for a in self._plan.batch_axes if self._mesh.shape[a] > 1}
+        total = owned = 0
+        for n, aval in state_aval.items():
+            nbytes = int(np.prod(aval.shape)) * np.dtype(aval.dtype).itemsize
+            total += nbytes
+            axes = {a for ax in shardings[n].spec if ax is not None
+                    for a in (ax if isinstance(ax, tuple) else (ax,))}
+            if axes & wide:
+                owned += nbytes
+        self._owned_state = {"dp_owned_state_bytes": owned,
+                             "state_bytes": total}
+        fp = obs.program_fp(self._program)
+        obs.DP_OWNED_STATE_BYTES.set(owned, program=fp, of="owned")
+        obs.DP_OWNED_STATE_BYTES.set(total, program=fp, of="state")
 
     # -- feed assembly ---------------------------------------------------
     def _assemble_feed(self, feed, feed_dict) -> Dict[str, np.ndarray]:
@@ -395,8 +418,10 @@ class ParallelExecutor:
     def run_stats(self):
         """Run statistics for the mesh-parallel path — see module-level
         ``run_stats()``; the registry series are process-global, so every
-        instance reports the same aggregate."""
-        return run_stats()
+        instance reports the same aggregate. ``dp_owned_state_bytes`` /
+        ``state_bytes`` are this executor's, of the program it compiled
+        last (``_observe_owned_state``; 0 / 0 before the first run)."""
+        return dict(run_stats(), **self._owned_state)
 
     def program_steps(self, program=None) -> int:
         """RNG step-fold position (Executor.program_steps twin; a

@@ -60,7 +60,8 @@ class ShardingPlan:
                   strict: bool = False) -> "ShardingPlan":
         """strict: a dim of more than one element that the spec's axes do
         not divide is an error naming the variable and its shape, where
-        other rules quietly leave such a dim whole (``spec``)."""
+        other rules quietly leave such a dim whole (``spec``). True holds
+        every axis of the spec to it, a tuple of axis names those alone."""
         self._regex.append((re.compile(pattern), spec, strict))
         return self
 
@@ -85,7 +86,9 @@ class ShardingPlan:
                     continue
                 axes = ax if isinstance(ax, tuple) else (ax,)
                 ways = int(np.prod([self.mesh.shape[a] for a in axes]))
-                if strict and shape[i] > 1 and shape[i] % ways:
+                held = strict is True or (
+                    strict and any(a in strict for a in axes))
+                if held and shape[i] > 1 and shape[i] % ways:
                     raise ValueError(
                         "%r of shape %s: dim %d does not divide over the %d "
                         "devices of mesh axis %r, and this plan's rule for "
@@ -185,13 +188,39 @@ def megatron_transformer_plan(
     iteration, and gathers the updated table every step). A V that mp
     does not divide is an error naming the shape, never a quiet fall back
     to a whole table.
+
+    On a mesh whose batch axes are wider than 1, ONE dp rank owns the
+    update of each matrix and table (ZeRO, what ``zero_plan`` does on a
+    plain dp mesh): the dimension mp leaves whole is split over the batch
+    axes, for the weight at rest and, their names beginning with its, the
+    optimizer's accumulators. GSPMD then lowers gradient -> update ->
+    weight as reduce-scatter -> Adam on 1/dp of the rows -> all-gather of
+    the bfloat16 cast where a matmul reads it, which the TPU compiler
+    runs asynchronously under the matmuls; dp twins no longer run the same
+    update on the same sums (the chip: 383.8 -> 359.8 ms a step of
+    `opt-6.7b-tp2`, 9.8 -> 4.9 GB a device; with the accumulators alone
+    split the weights come back by synchronous float32 all-gathers and
+    nothing is gained; PERF.md, PR 53). What the plan can see decides, and
+    nothing else: no batch axis in the mesh, one of size 1, or a dimension
+    it does not divide, gives the specs of a mesh without one (serving
+    plans, ``batch_axes=()``, dp=1). Biases and LayerNorm vectors stay
+    whole on every dp rank, and so do the untied table's rows and the
+    untied head: with the rows over dp GSPMD partitions the embedding's
+    gather by them and all-gathers the labels inside the head's loops.
     """
     plan = ShardingPlan(mesh, batch_axes=batch_axes)
     if mp_axis in mesh.axis_names:
         plan.tensor_axis = mp_axis
-    col_w = P(None, mp_axis)  # (in, out) split on out
-    row_w = P(mp_axis, None)  # (in, out) split on in
+    # the batch axes wider than 1 (docstring: one dp rank owns the update);
+    # None, and so the specs of a mesh without them, where there is none
+    own = tuple(a for a in plan.batch_axes
+                if a != mp_axis and mesh.shape[a] > 1)
+    own = own[0] if len(own) == 1 else (own or None)
+    col_w = P(own, mp_axis)  # (in, out) split on out
+    row_w = P(mp_axis, own)  # (in, out) split on in
     col_b = P(mp_axis)
+    # strict about mp alone: a dim the batch axes do not divide stays whole
+    held = (mp_axis,) if tied else False
     for pat, spec, strict in [
         # .qkv: the fused projection's columns are grouped per head
         # [h0:q,k,v | h1:q,k,v | ...], so a contiguous column split over
@@ -201,11 +230,12 @@ def megatron_transformer_plan(
         (r"\.(q|k|v|qkv|fc1)\.b", col_b, False),
         (r"\.(out|fc2)\.w", row_w, False),
         (r"\.(out|fc2)\.b", P(), False),
-        (r"pos_emb", P(None, mp_axis), False),
-        # tied: vocabulary rows, as the head reads them (docstring)
-        (r"tok_emb", row_w if tied else P(None, mp_axis), tied),
-        (r"\.head\.w", col_w, False),  # vocab-parallel output projection
-        (r"\.head\.b", col_b, tied),
+        (r"pos_emb", col_w, False),
+        # tied: vocabulary rows, as the head reads them (docstring);
+        # untied: rows whole, over the batch axes too
+        (r"tok_emb", row_w if tied else P(None, mp_axis), held),
+        (r"\.head\.w", P(None, mp_axis), False),  # vocab-parallel projection
+        (r"\.head\.b", col_b, held),
     ]:
         plan.set_regex(pat, spec, strict=strict)
     return plan

@@ -4,6 +4,8 @@ from __future__ import annotations
 
 import re
 
+import numpy as np
+
 _COMP = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{\s*$")
 _CALLEE = re.compile(
     r"(?:to_apply|calls|body|condition|branch_computations)=\{?%?([\w.\-]+)")
@@ -11,6 +13,30 @@ _COLLECTIVE = re.compile(
     r"%?([\w.\-]+) = (\(?[^=]*?\)?) "
     r"(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
     r"(?:-start)?\(")
+
+
+_GROUPS = re.compile(
+    r"replica_groups=(?:\{(\{[\d,{}]*\})\}"
+    r"|\[(\d+),(\d+)\]<=\[([\d,]+)\](?:T\(([\d,]+)\))?)")
+
+
+def replica_groups(line):
+    """The groups of a collective's line as a frozenset of frozensets of
+    device ids, from either form the compiler writes (`{{0,2},{1,3}}`,
+    `[2,2]<=[2,2]T(1,0)`); None where the line gives none."""
+    m = _GROUPS.search(line)
+    if not m:
+        return None
+    if m.group(1) is not None:
+        return frozenset(
+            frozenset(int(i) for i in g.split(",") if i)
+            for g in re.findall(r"\{([\d,]*)\}", m.group(1)))
+    ids = np.arange(int(m.group(2)) * int(m.group(3))).reshape(
+        [int(d) for d in m.group(4).split(",")])
+    if m.group(5):
+        ids = ids.transpose([int(d) for d in m.group(5).split(",")])
+    return frozenset(frozenset(int(i) for i in row) for row in ids.reshape(
+        int(m.group(2)), int(m.group(3))))
 
 
 def _computations(text):
@@ -27,10 +53,11 @@ def _computations(text):
 
 
 def collectives(text):
-    """[(opcode, result shapes without layouts, computation, while_body)]
-    of every collective in `text`; `while_body` names the loop body the
-    computation is (or is called from, fusions and reducers included),
-    or is None outside every loop."""
+    """[(opcode, result shapes without layouts, computation, while_body,
+    replica groups)] of every collective in `text`; `while_body` names the
+    loop body the computation is (or is called from, fusions and reducers
+    included), or is None outside every loop; the groups as
+    `replica_groups` gives them."""
     comps = _computations(text)
     inside = {}
     for lines in comps.values():
@@ -52,7 +79,8 @@ def collectives(text):
             m = _COLLECTIVE.search(line)
             if m:
                 shape = re.sub(r"\{[^}]*\}", "", m.group(2))
-                out.append((m.group(3), shape, name, inside.get(name)))
+                out.append((m.group(3), shape, name, inside.get(name),
+                            replica_groups(line)))
     return out
 
 

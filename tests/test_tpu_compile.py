@@ -166,7 +166,26 @@ def test_training_step_compiles_on_2x2_mesh(topo, monkeypatch, tie):
     whole = ("[%d,%d]" % (VOCAB, D_MODEL), "[%d,%d]" % (D_MODEL, VOCAB))
     assert not [c for c in found if c[0] == "all-gather"
                 and any(w in c[1] for w in whole)]
-    # an mp-split weight is half per device: fc1.w is (1024, 4096) f32
+    # one dp rank owns each matrix's update: fc1.w is (1024, 4096) f32, a
+    # quarter of it a device (rows over dp, columns over mp)
     fc1 = next(n for n in avals[1] if n.endswith(".fc1.w"))
     assert avals[1][fc1].sharding.shard_shape(avals[1][fc1].shape) == (
-        D_MODEL, D_INNER // 2)
+        D_MODEL // 2, D_INNER // 2)
+    # so no dp pair all-reduces a layer matrix's gradient (an mp rank's
+    # slice of it, any type): each is reduce-scattered, by a fusion that
+    # calls an `all-reduce-scatter` (whose own inner all-reduce does not
+    # count), and the weights come back by all-gathers of which the
+    # compiler runs some asynchronously, under the matmuls
+    ids = np.array([[d.id for d in row] for row in mesh.devices])
+    dp_pairs = frozenset(frozenset(int(i) for i in ids[:, j])
+                         for j in range(2))
+    grads = ["[%d,%d]" % s for s in (
+        (D_MODEL, D_MODEL // 2), (D_MODEL // 2, D_MODEL),
+        (D_MODEL, D_INNER // 2), (D_INNER // 2, D_MODEL))]
+    assert not [c for c in found
+                if c[0] == "all-reduce" and c[4] == dp_pairs
+                and not c[2].startswith("all-reduce-scatter")
+                and any(g in c[1] for g in grads)]
+    assert re.search(r"calls=%all-reduce-scatter", text)
+    assert [c for c in found if c[0] == "all-gather"
+            and "async_collective_fusion" in c[2]]

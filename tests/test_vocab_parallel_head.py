@@ -134,8 +134,12 @@ def test_local_path_holds_no_shard_map_and_no_collective(case):
 def test_megatron_plan_tied_shards_the_table_by_rows():
     mesh = make_mesh([2, 4], ("dp", "mp"))
     plan = megatron_transformer_plan(mesh, tied=True)
-    rows = P("mp", None)
+    # rows over mp; the columns over the batch axis, wider than 1 here, so
+    # that one dp rank owns the table's update (tests/test_dp_owned_update)
+    rows = P("mp", "dp")
     assert plan.spec("lm.tok_emb", shape=(128, 32)) == rows
+    assert megatron_transformer_plan(mesh, tied=True, batch_axes=()).spec(
+        "lm.tok_emb", shape=(128, 32)) == P("mp", None)
     # the moments inherit the table's rule; a (1,) power accumulator
     # cannot be split and stays whole, quietly
     for acc in ("lm.tok_emb_moment1_acc", "lm.tok_emb_moment2_acc"):
@@ -261,5 +265,7 @@ def test_tied_mesh_training_counts_a_vocab_parallel_trace():
         assert len(names) == 3, names
         for name in names:
             arr = scope.find_var(name)
-            assert arr.sharding.spec == P("mp", None), (name, arr.sharding)
-            assert arr.addressable_shards[0].data.shape == (v // 2, 32)
+            # rows over mp as the head reads them; columns over dp, whose
+            # one rank owns the update (tests/test_dp_owned_update.py)
+            assert arr.sharding.spec == P("mp", "dp"), (name, arr.sharding)
+            assert arr.addressable_shards[0].data.shape == (v // 2, 16)
