@@ -1237,8 +1237,38 @@ def _infer_prefill_attention(ctx: InferContext):
 
 @register_infer("decode_attn_ring")
 def _infer_decode_attn_ring(ctx: InferContext):
-    """Q (B, 1, H, Dh) x rings (B, W, Hkv, Dh) -> Out = Q's shape."""
-    return {"Out": _grouped_heads(ctx, ("KCache", "VCache"), (0, 2, 3))}
+    """Q (B, 1, H, Dh) x rings K (B, W, Hkv, Dh), V (B, W, Hkv, dv) ->
+    Out = Q's shape at V's width."""
+    out = _grouped_heads(ctx, ("KCache",), (0, 2, 3))
+    k, v = ctx.in_shape("KCache"), ctx.in_shape("VCache")
+    if out.shape is None or v is None:
+        return {"Out": out}
+    if len(v) != 4 or (k is not None and tuple(v[:3]) != tuple(k[:3])):
+        raise InferError("VCache%s does not hold KCache%s's rows and heads"
+                         % (render_shape(v), render_shape(k)))
+    return {"Out": VarInfo(tuple(out.shape[:-1]) + (v[-1],), out.dtype)}
+
+
+@register_infer("decode_attention_uneven")
+def _infer_decode_attention_uneven(ctx: InferContext):
+    """Q (B, 1, H, dk) x FLAT rows KCache (B, S, Hkv dk), VCache (B, S,
+    Hkv dv) -> Out (B, 1, H, dv)."""
+    q = ctx.in_info("Q")
+    qs, hkv = q.shape, int(ctx.attr("n_kv_head", 0) or 0)
+    k, v = ctx.in_shape("KCache"), ctx.in_shape("VCache")
+    if qs is None or k is None or v is None:
+        return {"Out": VarInfo(None, q.dtype)}
+    if len(qs) != 4 or len(k) != 3 or len(v) != 3:
+        raise InferError("Q%s must be rank 4 and KCache%s, VCache%s flat "
+                         "rows of rank 3" % (render_shape(qs),
+                                             render_shape(k),
+                                             render_shape(v)))
+    if (hkv <= 0 or qs[2] % hkv or k[-1] != hkv * qs[3] or v[-1] % hkv
+            or tuple(k[:2]) != tuple(v[:2])):
+        raise InferError(
+            "KCache%s, VCache%s are not %d key/value heads' rows under Q%s"
+            % (render_shape(k), render_shape(v), hkv, render_shape(qs)))
+    return {"Out": VarInfo(tuple(qs[:-1]) + (v[-1] // hkv,), q.dtype)}
 
 
 @register_infer("ring_append")

@@ -132,6 +132,7 @@ __all__ = [
     "ring_append",
     "ring_pack",
     "decode_attn_ring",
+    "decode_attention_uneven",
     "diff_attention",
     "diff_decode_attention",
     "attn_cross",
@@ -2686,18 +2687,22 @@ def _qkv_inputs(q, k, v, lengths):
 
 
 def prefill_attention(q, k, v, lengths=None, window=0, scale=None,
-                      name=None):
+                      sink=None, name=None):
     """Causal attention of a serving prefill, forward only
     (``ops/attention.py: prefill_attention``): q (B, T, H, dq), k (B, T,
     Hkv, dq), v (B, T, Hkv, dv) -> (B, T, H, dv); with ``window`` a
     query sees the last ``window`` keys up to its own; ``lengths`` (B,)
     the rows' live tokens, for the kernel to skip what lies past
-    them."""
+    them; ``sink`` (H,) a learned scalar a query head in the softmax's
+    denominator."""
     helper = LayerHelper("prefill_attention", name=name)
     out = helper.create_variable_for_type_inference(
         q.dtype, shape=tuple(q.shape[:-1]) + (v.shape[-1],))
+    inputs = _qkv_inputs(q, k, v, lengths)
+    if sink is not None:
+        inputs["Sink"] = [sink]
     helper.append_op(
-        type="prefill_attention", inputs=_qkv_inputs(q, k, v, lengths),
+        type="prefill_attention", inputs=inputs,
         outputs={"Out": [out]},
         attrs={"window": int(window or 0), "scale": scale})
     return out
@@ -2729,17 +2734,42 @@ def ring_pack(x, lengths, window, name=None):
     return out
 
 
-def decode_attn_ring(q, k_ring, v_ring, lengths, scale=None, name=None):
+def decode_attn_ring(q, k_ring, v_ring, lengths, scale=None, sink=None,
+                     name=None):
     """Single-query attention against a sliding-window layer's rings
-    (B, W, Hkv, Dh); ``lengths`` (B,) positions held including this
-    step's row."""
+    (B, W, Hkv, Dh) (V's heads of their own width where they have one);
+    ``lengths`` (B,) positions held including this step's row; ``sink``
+    (H,) a learned scalar a query head in the softmax's denominator."""
     helper = LayerHelper("decode_attn_ring", name=name)
-    out = helper.create_variable_for_type_inference(q.dtype, shape=q.shape)
+    out = helper.create_variable_for_type_inference(
+        q.dtype, shape=tuple(q.shape[:-1]) + (v_ring.shape[-1],))
+    inputs = {"Q": [q], "KCache": [k_ring], "VCache": [v_ring],
+              "Lengths": [lengths]}
+    if sink is not None:
+        inputs["Sink"] = [sink]
     helper.append_op(
-        type="decode_attn_ring",
-        inputs={"Q": [q], "KCache": [k_ring], "VCache": [v_ring],
-                "Lengths": [lengths]},
+        type="decode_attn_ring", inputs=inputs,
         outputs={"Out": [out]}, attrs={"scale": scale})
+    return out
+
+
+def decode_attention_uneven(q, k_rows, v_rows, lengths, n_kv_head,
+                            scale=None, name=None):
+    """Single-query attention against slabs of FLAT rows whose K and V
+    differ in width (``ops/kv_cache.py: decode_attention_uneven``): q
+    (B, 1, H, dk), k_rows (B, S, n_kv_head * dk), v_rows (B, S,
+    n_kv_head * dv) -> (B, 1, H, dv)."""
+    helper = LayerHelper("decode_attention_uneven", name=name)
+    dv = v_rows.shape[-1] // int(n_kv_head)
+    out = helper.create_variable_for_type_inference(
+        q.dtype, shape=tuple(q.shape[:-1]) + (dv,))
+    helper.append_op(
+        type="decode_attention_uneven",
+        inputs={"Q": [q], "KCache": [k_rows], "VCache": [v_rows],
+                "Lengths": [lengths]},
+        outputs={"Out": [out]},
+        attrs={"n_kv_head": int(n_kv_head), "scale": scale,
+               "block_s": _DEFAULT_ATTN_BLOCK_K})
     return out
 
 

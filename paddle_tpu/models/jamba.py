@@ -51,6 +51,14 @@ where asked, the attention projections'.
   prefill expands a group of them at a time (``ops/mla.py:
   latent_prefill``); a leading dense MLP, then routed experts under a
   sigmoid router with a selection bias;
+- Xiaomi's MiMo-V2-Flash (`model_type: mimo_v2_flash`): five sliding
+  layers of 128 positions to one full layer, 8 key/value heads on a
+  sliding layer and 4 on a full one, query and key heads of 192 channels
+  (the first 64 rotated, each kind by its own theta) over value heads of
+  128 scaled by 0.707, one learned SINK a query head in a sliding
+  layer's softmax (it joins the denominator and takes no value), no
+  gate; a leading dense MLP, then routed experts under a sigmoid router
+  with a selection bias and NO shared expert;
 - EvaByte (`model_type: evabyte`): a byte-level model whose every layer
   is EVA (``eva``, ``ops/eva.py``): a query attends its own window of
   2,048 bytes exactly and every earlier window through one pooled key
@@ -81,7 +89,12 @@ they are, never repeated for the query heads that share them. A
 sliding layer keeps ``kring_i`` / ``vring_i`` (B, window, n_kv_head,
 d_head): position p at row p mod window. Keys are stored ROTATED.
 Under differential attention a slab or ring row is FLAT, (B, S | window,
-n_kv_head * d_head) (``ops/diff_attn.py`` says why); a ``gmu`` or
+n_kv_head * d_head) (``ops/diff_attn.py`` says why); where the two
+attention kinds differ in their key/value heads or V's heads are
+narrower than K's (``DecodeConfig.uneven_kv``: MiMo-V2), a full layer's
+slabs are flat too, ``kcache_i`` (B, S, heads * d_head) beside
+``vcache_i`` (B, S, heads * v_head), and a ring keeps (B, window, its
+own heads, width); a ``gmu`` or
 ``cross`` layer keeps nothing. A latent layer keeps ONE ``latent_i`` (B,
 S, kv_lora_rank + qk_rope_dim): a position's ``[c_kv ; k_r]``, the
 latent normalised and the shared key row rotated, neither K nor V. A
@@ -219,9 +232,11 @@ def stream_view(cfg, seq, dtype="float32"):
     by the layer kind that keeps it, as ``_layer`` picks the op: a
     latent layer's ``mla_decode``; under differential attention the
     full layer's ``diff_decode_attention`` (and the cross layers'
-    ``attn_cross``) over flat rows; else ``decode_attention`` over
-    slabs of heads (OPT's block too), under the full layers' query
-    heads, not the sliding layers'."""
+    ``attn_cross``) over flat rows; where the key/value heads differ
+    by layer kind or V's width is its own, ``decode_attention_uneven``
+    over flat rows of two widths; else ``decode_attention`` over slabs
+    of heads (OPT's block too), under the full layers' query heads, not
+    the sliding layers'."""
     kinds = cfg.layer_kinds()
     if "eva" in kinds:  # whatever ``seq``: the entry is max_len's
         return _EVA.eva_view(sum(cfg.eva_rows), cfg.n_head, cfg.d_head,
@@ -237,14 +252,19 @@ def stream_view(cfg, seq, dtype="float32"):
     if cfg.diff_attn:
         return _D.rows_view(seq, heads, cfg.kv_row[0], 2 * cfg.d_head,
                             dtype)
+    if cfg.uneven_kv:
+        return _KV.uneven_view(seq, heads, cfg.kv_heads("attention"),
+                               cfg.d_head, cfg.v_head, dtype)
     return _KV.decode_view(seq, heads, cfg.n_kv_head, cfg.d_head, dtype)
 
 
 def _attention_mixer(u, cfg, name, lengths, cache, i=0, kind="attention"):
-    """Layer ``i``'s query heads on ``n_kv_head`` key/value heads, no
-    bias; rotary positions where ``cfg.rope`` names this layer kind
-    (a prefill rotates row t at t, a decode step its one row at
-    ``lengths``). ``cache`` is None (prefill: the forward-only
+    """Layer ``i``'s query heads on ``cfg.kv_heads(kind)`` key/value
+    heads (``n_kv_head``, or the layer kind's own), no bias, V's heads of
+    ``cfg.v_head`` channels times ``cfg.attn_value_scale``; rotary
+    positions where ``cfg.rope`` names this layer kind (a prefill
+    rotates row t at t, a decode step its one row at ``lengths``).
+    ``cache`` is None (prefill: the forward-only
     ``prefill_attention`` at the rows' ``lengths``, within the window
     on a sliding layer: on a TPU the flash forward on bfloat16 operands,
     ``ptpu.flash_fwd`` or ``ptpu.attn_window`` in a trace; the entries
@@ -254,18 +274,31 @@ def _attention_mixer(u, cfg, name, lengths, cache, i=0, kind="attention"):
     gate from the layer's input scales the attention output where
     ``cfg.attn_gate`` asks. Under ``cfg.diff_attn`` the attention is
     differential and k, v and the cache entries keep FLAT rows
-    (``cfg.kv_row``: the projection's own output). Returns (out, (k,
+    (``cfg.kv_row``: the projection's own output). Under
+    ``cfg.uneven_kv`` a full layer's slabs keep flat rows too, K's
+    wider than V's, read by ``decode_attention_uneven``, and a sliding
+    layer's softmax takes the learned sink ``name.sink`` (H,) into its
+    denominator where ``cfg.attn_sink`` names it. Returns (out, (k,
     v))."""
     B, T, _ = u.shape
-    h, hkv, dh = cfg.heads(i), cfg.n_kv_head, cfg.d_head
+    h, hkv, dh, dv = cfg.heads(i), cfg.kv_heads(kind), cfg.d_head, cfg.v_head
     bias = cfg.attn_biases
     sliding = kind == "sliding"
+    uneven = cfg.uneven_kv
+    k_row, v_row = cfg.kv_rows(kind)
     q = layers.reshape(_proj(u, h * dh, name + ".q", bias),
                        shape=[B, T, h, dh])
+    # under ``uneven_kv`` k and v are (heads, width) here; a full
+    # layer's rows go FLAT into its slabs below
     k = layers.reshape(_proj(u, hkv * dh, name + ".k", bias),
-                       shape=[B, T] + list(cfg.kv_row))
-    v = layers.reshape(_proj(u, hkv * dh, name + ".v", bias),
-                       shape=[B, T] + list(cfg.kv_row))
+                       shape=[B, T] + list((hkv, dh) if uneven else k_row))
+    v = _proj(u, hkv * dv, name + ".v", bias)
+    if cfg.attn_value_scale != 1.0:
+        v = layers.scale(v, scale=float(cfg.attn_value_scale))
+    v = layers.reshape(v, shape=[B, T] + list((hkv, dv) if uneven
+                                              else v_row))
+    sink = (_param([h], name + ".sink", ConstantInitializer(0.0))
+            if cfg.has_sink(kind) else None)
     diff = _diff_params(cfg, name) if cfg.diff_attn else None
     lam0 = _D.lambda_init(i)
     rot = (cfg.rope or {}).get("sliding" if sliding else "full")
@@ -273,6 +306,13 @@ def _attention_mixer(u, cfg, name, lengths, cache, i=0, kind="attention"):
         at = None if cache is None else lengths
         q = layers.rope(q, at, **rot)
         k = layers.rope(k, at, **rot)
+
+    def rows(x, row):
+        """x (B, T, heads, width) as the layer's cache keeps it."""
+        if uneven and not sliding:
+            return layers.reshape(x, shape=[B, T] + list(row))
+        return x
+
     if cache is None:
         window = cfg.window if sliding else 0
         if diff:
@@ -281,14 +321,16 @@ def _attention_mixer(u, cfg, name, lengths, cache, i=0, kind="attention"):
                 epsilon=cfg.norm_eps, lengths=lengths)
         else:
             # the op repeats k and v for the query heads that share them
-            ctx = layers.prefill_attention(q, k, v, lengths, window=window)
+            ctx = layers.prefill_attention(q, k, v, lengths, window=window,
+                                           sink=sink)
         if sliding:
             k = layers.ring_pack(k, lengths, cfg.window)
             v = layers.ring_pack(v, lengths, cfg.window)
+        k, v = rows(k, k_row), rows(v, v_row)
     else:
         append = layers.ring_append if sliding else layers.cache_append
-        k = append(cache[0], k, lengths)
-        v = append(cache[1], v, lengths)
+        k = append(cache[0], rows(k, k_row), lengths)
+        v = append(cache[1], rows(v, v_row), lengths)
         kv_lengths = layers.elementwise_add(
             layers.cast(lengths, "int32"),
             layers.fill_constant(shape=[B], dtype="int32", value=1))
@@ -296,12 +338,14 @@ def _attention_mixer(u, cfg, name, lengths, cache, i=0, kind="attention"):
             ctx = layers.diff_decode_attention(
                 q, k, v, kv_lengths, *diff, lam_init=lam0, ring=sliding,
                 epsilon=cfg.norm_eps)
+        elif sliding:
+            ctx = layers.decode_attn_ring(q, k, v, kv_lengths, sink=sink)
+        elif uneven:
+            ctx = layers.decode_attention_uneven(q, k, v, kv_lengths, hkv)
         else:
-            attend = (layers.decode_attn_ring if sliding
-                      else layers.decode_attention)
-            ctx = attend(q, k, v, kv_lengths)
+            ctx = layers.decode_attention(q, k, v, kv_lengths)
     ctx = _head_gate(ctx, u, cfg, name, h)
-    out = _proj(layers.reshape(ctx, shape=[B, T, h * dh]), cfg.d_model,
+    out = _proj(layers.reshape(ctx, shape=[B, T, h * dv]), cfg.d_model,
                 name + ".o", bias)
     return out, (k, v)
 
@@ -594,9 +638,9 @@ def _mlp(x, cfg, name):
 
 
 def _experts(x, cfg, name, lengths, decode):
-    """Routed experts with a shared one: x (B, T, D) -> (out, load
-    (experts held,) int32). The router scores all ``n_expert``; the
-    experts ``cfg.held`` are the ones computed."""
+    """Routed experts, with a shared one where ``d_shared_expert``: x
+    (B, T, D) -> (out, load (experts held,) int32). The router scores
+    all ``n_expert``; the experts ``cfg.held`` are the ones computed."""
     d, f = cfg.d_model, cfg.d_expert
     lo, hi = cfg.held
     w = NormalInitializer(0.0, 0.02)
@@ -615,6 +659,8 @@ def _experts(x, cfg, name, lengths, decode):
         expert_lo=lo, lengths=lengths, decode=decode,
         count_elsewhere=cfg.router_groups > 1)
     fs = cfg.d_shared_expert
+    if not fs:  # no shared expert: the routed part is the layer's
+        return routed, load
     shared = layers.moe_shared(
         x, _param([d, fs], name + ".shared.gate.w", w),
         _param([d, fs], name + ".shared.up.w", w),
@@ -699,12 +745,33 @@ def _check(cfg):
                          "built")
     if "experts" in cfg.ffn_kinds() and (
             cfg.router_score not in ROUTER_SCORES
-            or not cfg.d_shared_expert):
+            or int(cfg.d_shared_expert) < 0):
         raise ValueError(
             "an expert layer is built with %s scores and a shared "
-            "expert; got router_score=%r d_shared_expert=%r"
+            "expert of some width or none (0); got router_score=%r "
+            "d_shared_expert=%r"
             % (" or ".join(ROUTER_SCORES), cfg.router_score,
                cfg.d_shared_expert))
+    if cfg.uneven_kv and (cfg.diff_attn or cfg.attn_biases or cfg.has_eva
+                          or "cross" in cfg.layer_kinds()):
+        raise ValueError(
+            "key/value heads by layer kind (n_kv_head_by_kind) and a value "
+            "width of its own (v_head_dim) are built without differential "
+            "attention (its rows are one flat kv_row), biases, cross and "
+            "EVA layers")
+    if set(cfg.attn_sink or ()) - {"sliding"} or (cfg.attn_sink and (
+            cfg.diff_attn or "sliding" not in cfg.layer_kinds())):
+        raise ValueError(
+            "attn_sink %r: a learned sink is built in the softmax of "
+            "sliding layers alone, without differential attention"
+            % (cfg.attn_sink,))
+    if float(cfg.attn_value_scale) != 1.0 and (
+            cfg.diff_attn or not {"attention", "sliding"}
+            & set(cfg.layer_kinds())):
+        raise ValueError(
+            "attn_value_scale %r scales the values of full and sliding "
+            "layers, without differential attention"
+            % (cfg.attn_value_scale,))
     for kind, rot in (cfg.rope or {}).items():
         if kind == "index":
             # an indexer's queries and keys: their first channels, plain

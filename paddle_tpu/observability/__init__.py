@@ -63,7 +63,7 @@ __all__ = [
     "QUANT_CALIB_BATCHES", "QUANT_OPS", "QUANT_PARITY",
     "FUSED_HEAD_TRACES", "MLA_TRACES", "SSM_SCAN_TRACES", "KDA_SCAN_TRACES",
     "KDA_STEP_TRACES",
-    "PREFILL_ATTN_TRACES", "MOE_TOKENS_ELSEWHERE",
+    "PREFILL_ATTN_TRACES", "PREFILL_ATTN_FORMS", "MOE_TOKENS_ELSEWHERE",
 ]
 
 # -- the shared instrument set (registered once, process-wide) -----------
@@ -132,6 +132,14 @@ PREFILL_ATTN_TRACES = REGISTRY.counter(
     "caller's) and lengths=given (the kernel skips the q-blocks past a "
     "row's length) | none. Counted when the op is traced: a program "
     "loaded from a cache adds 0")
+PREFILL_ATTN_FORMS = REGISTRY.counter(
+    "paddle_tpu_prefill_attn_forms_total",
+    "Traces of a serving prefill's causal attention that are not the "
+    "plain form, beside paddle_tpu_prefill_attn_traces_total: sink="
+    "learned (a scalar a query head joins the softmax's denominator: the "
+    "output times sigmoid(lse - sink)) | none, value_width=own (V's "
+    "heads narrower than q's and K's: 192 / 192 / 128) | query. Counted "
+    "when the op is traced: a program loaded from a cache adds 0")
 CACHE_HITS = REGISTRY.counter(
     "paddle_tpu_compile_cache_hits_total",
     "Compile-cache hits, by kind, program fingerprint, and "

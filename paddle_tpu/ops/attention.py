@@ -39,7 +39,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..framework.scope import current_device
 from ..framework.trace import current_trace_mesh, current_trace_plan
-from ..observability import PREFILL_ATTN_TRACES
+from ..observability import PREFILL_ATTN_FORMS, PREFILL_ATTN_TRACES
 from .registry import register_op
 
 _NEG = -1e30
@@ -1029,11 +1029,13 @@ def _use_pallas(t, tk, lengths, dropout_rate) -> bool:
 ATTN_WINDOW = "ptpu.attn_window"
 
 
-def prefill_attention_reference(q, k, v, window=0, scale=None):
+def prefill_attention_reference(q, k, v, window=0, scale=None, sink=None):
     """Causal attention of a prefill, exact, pure lax: q (B, T, H, dq),
     k (B, T, Hkv, dq), v (B, T, Hkv, dv) with H = g * Hkv -> (B, T, H,
     dv); key j is visible to query t iff j <= t and, with ``window``,
-    t - window < j. Builds the (T, T) scores: the path of every device
+    t - window < j; ``sink`` (H,): a learned scalar a query head in the
+    softmax's denominator, which takes no value (``sink_share``).
+    Builds the (T, T) scores: the path of every device
     but a TPU and of a bucket under the kernel's threshold, and the
     numeric reference of the kernel."""
     b, t, h, d = q.shape
@@ -1047,9 +1049,24 @@ def prefill_attention_reference(q, k, v, window=0, scale=None):
     seen = col <= row
     if window:
         seen &= col > row - window
-    p = jax.nn.softmax(jnp.where(seen, s, _NEG), axis=-1)
+    s = jnp.where(seen, s, _NEG)
+    p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bkgts,bskd->btkgd", p, v.astype(jnp.float32))
-    return out.reshape(b, t, h, v.shape[-1]).astype(q.dtype)
+    out = out.reshape(b, t, h, v.shape[-1])
+    if sink is not None:
+        lse = jax.nn.logsumexp(s, axis=-1)                  # (B, Hkv, g, T)
+        out = out * sink_share(
+            jnp.moveaxis(lse.reshape(b, h, t), 1, 2), sink)[..., None]
+    return out.astype(q.dtype)
+
+
+def sink_share(lse, sink):
+    """What a softmax keeps of its mass when one learned scalar ``sink``
+    joins its denominator and takes no value: with ``lse`` the
+    log-sum-exp of a row's scores, ``exp(z_j) / (exp(sink) + sum_j'
+    exp(z_j')) = softmax_j z x sigmoid(lse - sink)``, exactly. ``sink``
+    broadcasts against ``lse`` (a prefill's (B, T, H) against (H,))."""
+    return jax.nn.sigmoid(lse - sink.astype(jnp.float32))
 
 
 def flash_operand(x, repeat=1):
@@ -1067,11 +1084,15 @@ def flash_operand(x, repeat=1):
 
 
 def prefill_attention(q, k, v, lengths=None, window=0, scale=None, name=None,
-                      interpret=False):
+                      interpret=False, sink=None):
     """THE causal attention of a serving prefill, forward only: q (B, T,
     H, dq), k (B, T, Hkv, dq), v (B, T, Hkv, dv) -> (B, T, H, dv) in
     q's type; with ``window`` a query sees its last ``window`` keys.
     ``lengths`` (B,): the rows' live tokens, padding at a row's END.
+    ``sink`` (H,): a learned scalar a query head that joins the
+    softmax's denominator and takes no value: the output times
+    ``sigmoid(lse - sink)``, from the log-sum-exp the kernel returns
+    beside its output (the lax form computes its own).
 
     On a TPU at a block-aligned bucket (``_use_pallas``: 256 rows and
     up) the flash forward kernel, ``name`` in a device trace
@@ -1110,31 +1131,41 @@ def prefill_attention(q, k, v, lengths=None, window=0, scale=None, name=None,
         path="kernel" if kernel else "lax",
         operands="bfloat16" if kernel else jnp.dtype(q.dtype).name,
         lengths="none" if lengths is None else "given")
+    if sink is not None or dv != dq:
+        PREFILL_ATTN_FORMS.inc(
+            sink="none" if sink is None else "learned",
+            value_width="query" if dv == dq else "own")
     if not kernel:
         with jax.named_scope(name):
-            return prefill_attention_reference(q, k, v, window, scale)
+            return prefill_attention_reference(q, k, v, window, scale, sink)
     if scale is None:
         scale = 1.0 / math.sqrt(dq)
     group = h // k.shape[2]
 
     block_q = _fit_block(t, _env_block("PADDLE_TPU_FLASH_BQ", 512))
     block_k = _fit_block(t, _env_block("PADDLE_TPU_FLASH_BK", 512))
-    out, _ = _mha_fwd_call_bthd(
+    out, lse = _mha_fwd_call_bthd(
         flash_operand(q * jnp.asarray(scale, q.dtype)),
         flash_operand(k, group), flash_operand(v, group), h, True, block_q, block_k, interpret,
         window=window, name=name, lengths=lengths, out_dtype=q.dtype)
-    return out.reshape(b, t, h, -1)[..., :dv]
+    out = out.reshape(b, t, h, -1)[..., :dv]
+    if sink is None:
+        return out
+    with jax.named_scope(name):
+        share = sink_share(jnp.swapaxes(lse.reshape(b, h, t), 1, 2), sink)
+        return (out * share[..., None]).astype(out.dtype)
 
 
 @register_op("prefill_attention")
 def _prefill_attention_op(ctx):
     """Inputs Q (B, T, H, dq), K (B, T, Hkv, dq), V (B, T, Hkv, dv),
-    optional Lengths (B,); attrs window (0: every earlier key), scale
+    optional Lengths (B,) and Sink (H,); attrs window (0: every earlier
+    key), scale
     -> Out (B, T, H, dv): ``prefill_attention``."""
     return {"Out": prefill_attention(
         ctx.input("Q"), ctx.input("K"), ctx.input("V"),
         ctx.input("Lengths"), window=int(ctx.attr("window", 0) or 0),
-        scale=ctx.attr("scale", None))}
+        scale=ctx.attr("scale", None), sink=ctx.input("Sink"))}
 
 
 @register_op("ring_attention")

@@ -64,7 +64,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..framework.scope import current_device
 from . import decode_stream as _DS
-from .attention import _fit_block, _tpu_params, named_pallas_call
+from .attention import (_fit_block, _tpu_params, named_pallas_call,
+                        sink_share)
 from .registry import register_op
 
 _NEG = -1e30
@@ -74,6 +75,9 @@ DECODE_ATTN = "ptpu.decode_attn"
 # a slab with fewer heads than the query: the kernel's call, or the lax
 # path's scope where the slab has no free view
 DECODE_ATTN_GROUPED = "ptpu.decode_attn_grouped"
+# a slab of FLAT rows whose K and V differ in width and whose few heads
+# fill no sublane tile: the kernel's call, or the lax path's scope
+DECODE_ATTN_UNEVEN = "ptpu.decode_attn_uneven"
 # a sliding-window layer's cache: a ring of `window` rows
 DECODE_ATTN_RING = "ptpu.decode_attn_ring"
 RING_APPEND = "ptpu.ring_append"
@@ -85,11 +89,15 @@ RING_PACK = "ptpu.ring_pack"
 # ---------------------------------------------------------------------------
 
 
-def decode_attention_reference(q, k_cache, v_cache, lengths, scale=None):
+def decode_attention_reference(q, k_cache, v_cache, lengths, scale=None,
+                               sink=None):
     """Pure-lax decode attention: q (B, 1, H, Dh), caches (B, S, Hkv, Dh)
     with H = g * Hkv (g = 1: as many key/value heads as query heads;
     g > 1: grouped queries, query head h reads K/V head h // g),
-    lengths (B,) valid rows per slot -> (B, 1, H, Dh). Exact; the CPU
+    lengths (B,) valid rows per slot -> (B, 1, H, Dh); V's heads may be
+    of another width than q's and K's, which the output then has;
+    ``sink`` (H,): a learned scalar a query head in the softmax's
+    denominator (``sink_share``). Exact; the CPU
     serving path, the numeric reference for the Pallas kernel, and on
     every device the path of a ring and of a grouped slab the in-place
     kernel has no free view of (``decode_view``: one key/value
@@ -120,7 +128,10 @@ def decode_attention_reference(q, k_cache, v_cache, lengths, scale=None):
     l = jnp.sum(p, axis=-1, keepdims=True)
     out = jnp.einsum("bkgs,bskd->bkgd", p / jnp.maximum(l, 1e-30),
                      v_cache.astype(jnp.float32))
-    return out.reshape(b, 1, h, d).astype(q.dtype)
+    if sink is not None:
+        lse = m + jnp.log(jnp.maximum(l, 1e-30))            # (B, Hkv, g, 1)
+        out = out * sink_share(lse, sink.reshape(1, hkv, h // hkv, 1))
+    return out.reshape(b, 1, h, v_cache.shape[-1]).astype(q.dtype)
 
 
 def _online_softmax_row(q, kb, vb, col0, length, acc, m, l):
@@ -331,6 +342,107 @@ def pallas_decode_attention(q, k_cache, v_cache, lengths, scale=None,
     return out.reshape(b, 1, h, d)
 
 
+def _uneven_windows(hkv, dk):
+    """Where key/value head i's ``dk`` channels lie in a flat row of
+    ``hkv * dk`` lanes, as lane-ALIGNED windows: (width, [(start, offset)
+    a head]): head i's channels are the lanes ``[start + offset, start +
+    offset + dk)`` of the ``width`` lanes from ``start`` on, ``start`` and
+    ``width`` whole 128-lane tiles. At dk = 192: 256 wide, heads at (0,
+    0), (128, 64), (384, 0), (512, 64); at a dk of whole tiles the
+    window is the head itself."""
+    at = [(i * dk // 128 * 128, i * dk % 128) for i in range(hkv)]
+    width = max(-(-(off + dk) // 128) * 128 for _, off in at)
+    # the last window may not pass the row's end
+    fit = [max(min(start, hkv * dk - width), 0) for start, _ in at]
+    return width, [(to, off + start - to)
+                   for to, (start, off) in zip(fit, at)]
+
+
+def uneven_queries(q, hkv):
+    """q (B, 1, H, dk) -> (B, 1, H, width): each query head's channels
+    at its key/value head's offset inside that head's lane-aligned
+    window of a flat K row (``_uneven_windows``), zeros around them, so
+    that ``q' . K[start : start + width] = q . k_i`` exactly (the zeros
+    add nothing) and the kernel slices K at whole tiles alone."""
+    b, t, h, dk = q.shape
+    width, at = _uneven_windows(hkv, dk)
+    g = h // hkv
+    return jnp.concatenate([
+        jnp.pad(q[:, :, i * g:(i + 1) * g],
+                ((0, 0),) * 3 + ((off, width - off - dk),))
+        for i, (_, off) in enumerate(at)], axis=2)
+
+
+def uneven_view(s, h, hkv, dk, dv, dtype, block_s=512):
+    """The view (``ops/decode_stream.py``) of a slab of FLAT rows, K (B,
+    s, hkv * dk) beside V (B, s, hkv * dv), under ``h`` query heads: a
+    (1, rows, row) block of each slab itself a step. Key/value head i's
+    keys are read as the lane-aligned window that holds them, against
+    the g query rows laid out to match (``uneven_queries``: 1.33x the
+    score product's FLOPs at dk = 192, no byte of the slab), its values
+    the lanes [i dv, (i + 1) dv). No copy where both rows are whole
+    128-lane tiles wide (4 x 192 = 768, 4 x 128 = 512)."""
+    f32 = jnp.float32
+    width, at = _uneven_windows(hkv, dk)
+
+    def scores(i, hh, q_ref, k_ref):
+        start = at[i][0]
+        return jnp.dot(q_ref[0, 0, hh, :],
+                       k_ref[0, :, start:start + width].T,
+                       preferred_element_type=f32)
+
+    def values(i, p, v_ref):
+        return jnp.dot(p, v_ref[0, :, i * dv:(i + 1) * dv],
+                       preferred_element_type=f32)
+
+    return _DS.StreamView(
+        DECODE_ATTN_UNEVEN, seq=s, dtype=dtype,
+        most=_DS.rows_within(hkv * dk * 4, block_s), score_rows=h,
+        whole_tiles=(hkv * dk) % 128 == 0 and dv % 128 == 0
+        and h % hkv == 0, lanes=hkv * dk,
+        q_block=(1, 1, h, width), k_block=(1, 1, hkv * dk),
+        v_block=(1, 1, hkv * dv), o_block=(1, 1, h, dv), groups=hkv,
+        scores=scores, values=values)
+
+
+def pallas_decode_attention_uneven(q, k_rows, v_rows, lengths, hkv,
+                                   scale=None, block_s=512,
+                                   interpret=False):
+    """``decode_attention_uneven`` through the kernel: the slabs are
+    handed over as they lie."""
+    b, _, h, dk = q.shape
+    if scale is None:
+        scale = 1.0 / math.sqrt(dk)
+    view = uneven_view(k_rows.shape[1], h, hkv, dk,
+                       v_rows.shape[2] // hkv, k_rows.dtype, block_s)
+    return _DS.stream_attend(
+        view, lengths.reshape(-1).astype(jnp.int32),
+        uneven_queries(q * jnp.asarray(scale, q.dtype), hkv), k_rows,
+        v_rows, interpret)
+
+
+def decode_attention_uneven(q, k_rows, v_rows, lengths, hkv, scale=None,
+                            block_s=512):
+    """q (B, 1, H, dk) against slabs of FLAT rows, K (B, S, hkv * dk)
+    and V (B, S, hkv * dv): key/value heads that fill no sublane tile,
+    of a key width that is no whole tile and a value width of its own
+    (4 heads of 192 beside 4 of 128) -> (B, 1, H, dv). On a TPU the
+    streamed two-pass body over the slot's LIVE blocks (``uneven_view``);
+    elsewhere, and where the view does not exist, the exact lax path
+    over the rows read as (S, hkv, width), under the same scope."""
+    dk = q.shape[-1]
+    view = uneven_view(k_rows.shape[1], q.shape[2], hkv, dk,
+                       v_rows.shape[2] // hkv, k_rows.dtype, block_s)
+    if decode_stream_rows(view) is not None:
+        return pallas_decode_attention_uneven(q, k_rows, v_rows, lengths,
+                                              hkv, scale, block_s)
+    b, s = k_rows.shape[:2]
+    with jax.named_scope(DECODE_ATTN_UNEVEN):
+        return decode_attention_reference(
+            q, k_rows.reshape(b, s, hkv, dk), v_rows.reshape(b, s, hkv, -1),
+            lengths, scale=scale)
+
+
 def _use_pallas_decode(s: int, d: int) -> bool:
     """A step bound for a TPU, lane-aligned head dim, block-aligned slab
     (mirrors ops/attention.py:_use_pallas; PADDLE_TPU_NO_PALLAS opts
@@ -376,6 +488,19 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
                                        scale=scale, block_s=block_s)
     return decode_attention_reference(q, k_cache, v_cache, lengths,
                                       scale=scale)
+
+
+@register_op("decode_attention_uneven")
+def _decode_attention_uneven_op(ctx):
+    """Inputs Q (B, 1, H, dk), KCache (B, S, Hkv * dk), VCache (B, S,
+    Hkv * dv) FLAT rows, Lengths (B,) valid rows per slot (including
+    the current token's); attrs n_kv_head, scale -> Out (B, 1, H,
+    dv)."""
+    return {"Out": decode_attention_uneven(
+        ctx.input("Q"), ctx.input("KCache"), ctx.input("VCache"),
+        ctx.input("Lengths"), int(ctx.attr("n_kv_head")),
+        scale=ctx.attr("scale", None),
+        block_s=int(ctx.attr("block_s", 512)))}
 
 
 @register_op("decode_attention")
@@ -490,9 +615,11 @@ def ring_pack(rows, lengths, window):
         return jax.vmap(one)(rows, start)
 
 
-def decode_attn_ring(q, k_ring, v_ring, lengths, scale=None):
-    """q (B, 1, H, Dh) against rings (B, W, Hkv, Dh); ``lengths`` (B,)
-    the positions held INCLUDING this step's freshly written row. The
+def decode_attn_ring(q, k_ring, v_ring, lengths, scale=None, sink=None):
+    """q (B, 1, H, Dh) against rings (B, W, Hkv, Dh) (V's heads of their
+    own width where they have one); ``lengths`` (B,) the positions held
+    INCLUDING this step's freshly written row; ``sink`` (H,): a learned
+    scalar a query head in the softmax's denominator. The
     exact grouped lax path over ``min(lengths, W)`` live rows, on every
     device: a ring is one block a slot and nearly all of it live, so
     the kernel has no dead rows to skip (0.42-0.45 ms a call through it
@@ -501,7 +628,7 @@ def decode_attn_ring(q, k_ring, v_ring, lengths, scale=None):
         w = k_ring.shape[1]
         live = jnp.minimum(lengths.reshape(-1).astype(jnp.int32), w)
         return decode_attention_reference(q, k_ring, v_ring, live,
-                                          scale=scale)
+                                          scale=scale, sink=sink)
 
 
 @register_op("ring_append")
@@ -525,8 +652,9 @@ def _ring_pack_op(ctx):
 @register_op("decode_attn_ring")
 def _decode_attn_ring_op(ctx):
     """Inputs Q (B, 1, H, Dh), KCache/VCache (B, W, Hkv, Dh), Lengths
-    (B,) positions held including the current token's -> Out = Q's
-    shape."""
+    (B,) positions held including the current token's, optional Sink
+    (H,) -> Out (B, 1, H, V's width)."""
     return {"Out": decode_attn_ring(
         ctx.input("Q"), ctx.input("KCache"), ctx.input("VCache"),
-        ctx.input("Lengths"), scale=ctx.attr("scale", None))}
+        ctx.input("Lengths"), scale=ctx.attr("scale", None),
+        sink=ctx.input("Sink"))}

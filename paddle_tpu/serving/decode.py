@@ -121,6 +121,13 @@ def _kv_dtype_from_env() -> str:
     return "int8" if raw in ("kv8", "int8") else "float32"
 
 
+def _attn_key(kind: str) -> str:
+    """The name a manifest's by-kind fields (``rope``,
+    ``n_kv_head_by_kind``, ``attn_sink``) give an ``attention`` |
+    ``sliding`` layer: "full" | "sliding"."""
+    return "sliding" if kind == "sliding" else "full"
+
+
 class DecodeConfig:
     """Architecture manifest for the decode-side graph builders.
     Everything else (batch, slab length, strategy) is a serving-time
@@ -215,7 +222,21 @@ class DecodeConfig:
     V of ``max_len / eva_chunk + window`` rows, the summaries and the
     window's block (``eva_rows``). ``norm_offset``: an RMS norm's
     parameter is its gain's distance from one, ``(1 + g) x / rms(x)``;
-    ``head_precision`` "highest": the logits in float32 products."""
+    ``head_precision`` "highest": the logits in float32 products.
+    Four fields describe attention layers whose two kinds differ in
+    more than their window (MiMo-V2): ``n_kv_head_by_kind`` ({"full":,
+    "sliding":}: the key/value heads of a full and of a sliding layer;
+    a kind it does not name keeps ``n_kv_head``), ``v_head_dim`` on a
+    model WITHOUT latent layers (the value head's own width under query
+    and key heads of ``head_dim``), ``attn_sink`` (the layer kinds,
+    "sliding" alone is built, whose softmax has one learned scalar a
+    query head in its denominator: ``a_ij = exp(z_ij) / (exp(s_h) +
+    sum_j' exp(z_ij'))``) and ``attn_value_scale`` (v is multiplied by
+    it). Where the first or the second is set (``uneven_kv``) a full
+    layer's slabs keep a position's row FLAT, K ``(n_kv x head_dim,)``
+    beside V ``(n_kv x v_head_dim,)``, read where they lie by
+    ``ptpu.decode_attn_uneven`` (``ops/kv_cache.py``); a ring keeps
+    ``(n_kv, width)`` rows of its own kind's head count."""
 
     FIELDS = ("vocab_size", "n_layer", "n_head", "d_model", "d_inner",
               "max_len", "tie_embeddings", "prefix", "eos_id")
@@ -247,7 +268,9 @@ class DecodeConfig:
                    ("latent_ring", None), ("latent_rescale", False),
                    ("index_heads", 0), ("index_head_dim", 0),
                    ("index_topk", 0), ("eva_chunk", 0),
-                   ("norm_offset", False), ("head_precision", None))
+                   ("norm_offset", False), ("head_precision", None),
+                   ("n_kv_head_by_kind", None), ("attn_sink", None),
+                   ("attn_value_scale", 1.0))
     MIXERS = ("mamba", "attention", "sliding", "gmu", "cross", "latent",
               "kda", "latent_dsa", "latent_ring", "eva")
     # the kinds that keep latent rows
@@ -292,11 +315,23 @@ class DecodeConfig:
                 if len(per_layer) != self.n_layer:
                     raise ValueError("%s names %d layers of %d"
                                      % (f, len(per_layer), self.n_layer))
-        for h in set(self.n_head_by_layer or ()) | {self.n_head}:
-            if h % self.n_kv_head:
+        if self.n_kv_head_by_kind is not None:
+            by_kind = dict(self.n_kv_head_by_kind)
+            if not by_kind or set(by_kind) - {"full", "sliding"}:
                 raise ValueError(
-                    "%d query heads do not divide over %d key/value heads"
-                    % (h, self.n_kv_head))
+                    "n_kv_head_by_kind %r names key/value heads of 'full' "
+                    "and 'sliding' layers" % (self.n_kv_head_by_kind,))
+            self.n_kv_head_by_kind = {k: int(by_kind[k])
+                                      for k in sorted(by_kind)}
+        if self.attn_sink is not None:
+            self.attn_sink = sorted(self.attn_sink)
+        for h in set(self.n_head_by_layer or ()) | {self.n_head}:
+            for hkv in {self.n_kv_head} | set(
+                    (self.n_kv_head_by_kind or {}).values()):
+                if hkv <= 0 or h % hkv:
+                    raise ValueError(
+                        "%d query heads do not divide over %d key/value "
+                        "heads" % (h, hkv))
         if self.experts_held is not None:
             self.experts_held = [int(e) for e in self.experts_held]
         if "experts" in (self.ffn_types or ()):
@@ -388,6 +423,45 @@ class DecodeConfig:
         """Query heads of layer ``i``."""
         return int(self.n_head_by_layer[i] if self.n_head_by_layer
                    else self.n_head)
+
+    def kv_heads(self, kind: str = "attention") -> int:
+        """Key/value heads of an ``attention`` (full) or a ``sliding``
+        layer: ``n_kv_head_by_kind``'s where it names the kind."""
+        return int((self.n_kv_head_by_kind or {}).get(
+            _attn_key(kind), self.n_kv_head))
+
+    @property
+    def v_head(self) -> int:
+        """Width of an attention layer's value head: ``v_head_dim``
+        where a model without latent layers sets it (a latent layer
+        reads that field for its own heads), else ``d_head``."""
+        if self.v_head_dim and not self.has_latent:
+            return int(self.v_head_dim)
+        return self.d_head
+
+    @property
+    def uneven_kv(self) -> bool:
+        """The attention layers' K and V rows differ by layer kind or
+        from each other: a full layer's slabs keep FLAT rows."""
+        return bool(self.n_kv_head_by_kind) or self.v_head != self.d_head
+
+    def kv_rows(self, kind: str = "attention"):
+        """(K row, V row): the shapes of one position's row of an
+        ``attention`` layer's slabs or a ``sliding`` layer's rings.
+        ``kv_row`` twice, but under ``uneven_kv``: a slab row FLAT, K
+        ``(heads x d_head,)`` beside V ``(heads x v_head,)``, a ring row
+        ``(heads, width)``, by the kind's own head count."""
+        if not self.uneven_kv:
+            return self.kv_row, self.kv_row
+        hkv = self.kv_heads(kind)
+        if kind == "sliding":
+            return (hkv, self.d_head), (hkv, self.v_head)
+        return (hkv * self.d_head,), (hkv * self.v_head,)
+
+    def has_sink(self, kind: str) -> bool:
+        """An ``attention`` | ``sliding`` layer's softmax has a learned
+        sink a query head."""
+        return _attn_key(kind) in (self.attn_sink or ())
 
     @property
     def held(self):
@@ -631,7 +705,10 @@ def cache_spec(config: DecodeConfig, slots: int, seq: int,
     a slab may so have several readers a step), a KDA layer's three
     windows ``convq_i``, ``convk_i``, ``convv_i`` (slots, K - 1, H *
     dk) and its ``kda_i`` (slots, H, dk, dv) matrix state; a slab's or a ring's
-    row is ``config.kv_row``, flat under differential attention; SORTED BY
+    row is ``config.kv_row``, flat under differential attention, or, where
+    the layer kinds differ in their key/value heads or V's width is its
+    own (``config.uneven_kv``), ``config.kv_rows(kind)``: K's beside
+    V's, a slab's flat, a ring's (heads, width); SORTED BY
     NAME: the order a dict of feeds flattens in, so that a
     donated feed pairs with its own updated output and a step compiles
     with no pairing copy (PERF.md 7a is what happens otherwise)."""
@@ -652,7 +729,6 @@ def cache_spec(config: DecodeConfig, slots: int, seq: int,
                 for k in config.layer_kinds())))))
     from ..models.jamba import cache_names
 
-    slab = (slots, seq) + config.kv_row
     out = []
     for i, kind in enumerate(config.layer_kinds()):
         names = cache_names(kind, i)
@@ -673,8 +749,9 @@ def cache_spec(config: DecodeConfig, slots: int, seq: int,
                            config.kda_head_dim), "float32", False))
             continue
         if kind == "sliding":
-            ring = (slots, int(config.window)) + config.kv_row
-            out += [CacheEntry(n, ring, "float32", False) for n in names]
+            out += [CacheEntry(n, (slots, int(config.window)) + row,
+                               "float32", False)
+                    for n, row in zip(names, config.kv_rows(kind))]
             continue
         if kind == "eva":
             rows = (slots, sum(config.eva_rows), config.n_head,
@@ -701,7 +778,8 @@ def cache_spec(config: DecodeConfig, slots: int, seq: int,
                            config.latent_geometry(kind).row),
                 "float32", False))
             continue
-        out += [CacheEntry(n, slab, kv_dtype, True) for n in names]
+        out += [CacheEntry(n, (slots, seq) + row, kv_dtype, True)
+                for n, row in zip(names, config.kv_rows(kind))]
         if kv_dtype == "int8":
             # the (slot, position) float32 scale of each int8 row
             out += [CacheEntry("%sscale_%d" % (kv, i), (slots, seq),
@@ -2044,6 +2122,7 @@ class DecodeServer:
         self._ssm_layers = cfg.layer_kinds().count("mamba")
         # a sliding-window layer's ring holds this many rows (0: none)
         self._ring_window = int(cfg.window) if cfg.has_ring else 0
+        self._uneven_kv = cfg.uneven_kv
         # layers that route over experts: a step and a prefill return
         # the pairs each held expert received (``moe_load``, last)
         self._moe_layers = [i for i, k in enumerate(cfg.ffn_kinds())
@@ -2326,6 +2405,7 @@ class DecodeServer:
     _eva = None
     _kda_state_bytes_per_slot = 0
     _ssm_layers = 0
+    _uneven_kv = False
 
     # prompts one admission prefills at most, while sequences are live,
     # and the bucketed tokens (power-of-two batch x the prompts' bucket)
@@ -2516,7 +2596,11 @@ class DecodeServer:
         causal mask, and those its attention kept (a query at position
         t keeps ``min(t + 1, index_topk)``). Of a model with latent
         layers over a window, ``window_pairs``: the pairs one such
-        layer attends (``min(t + 1, window)`` a query). Of a model with
+        layer attends (``min(t + 1, window)`` a query). Of a model
+        whose sliding and full layers differ in their key/value heads
+        or value width (``DecodeConfig.uneven_kv``), ``prompt_rows``,
+        ``bucket_rows``, ``prompts``, ``attn_pairs`` (one full layer's)
+        and ``window_pairs`` (one sliding layer's). Of a model with
         EVA layers, ``eva_window_rows`` and ``eva_summary_rows``: the
         rows the admission leaves live in one such layer's entries (each
         prompt's ``len mod window`` rows of its block and ``window /
@@ -2526,6 +2610,14 @@ class DecodeServer:
         (``_eva_pairs``)."""
         counts = {"entries": len(self._spec),
                   "state_slots": n if self._state_bytes_per_slot else 0}
+
+        def prefill_counts():
+            """What a prefill's model FLOPs are counted from."""
+            return dict(
+                prompt_rows=sum(len(p) for p in prompts),
+                bucket_rows=int(bucket_rows), prompts=len(prompts),
+                attn_pairs=sum(len(p) * (len(p) + 1) // 2 for p in prompts))
+
         if self._eva:
             _, w, c = self._eva
             counts.update(
@@ -2545,26 +2637,22 @@ class DecodeServer:
             counts["prompt_rows"] = sum(len(p) for p in prompts)
             counts["tail_rows"] = len(prompts)
         if self._latent_row_bytes:
-            counts["prompt_rows"] = sum(len(p) for p in prompts)
-            counts["bucket_rows"] = int(bucket_rows)
-            counts["prompts"] = len(prompts)
-            counts["attn_pairs"] = sum(len(p) * (len(p) + 1) // 2
-                                       for p in prompts)
+            counts.update(prefill_counts())
         if self._index_topk:
             counts["index_pairs"] = counts["attn_pairs"]
             counts["chosen_pairs"] = _kept_pairs(prompts, self._index_topk)
         if self._ring_window and self._latent_row_bytes:
             counts["window_pairs"] = _kept_pairs(prompts, self._ring_window)
+        if self._ring_window and self._uneven_kv:
+            # sliding layers beside full ones, each kind its own heads
+            counts.update(prefill_counts(), window_pairs=_kept_pairs(
+                prompts, self._ring_window))
         if self._kda_state_bytes_per_slot:
             counts["kda_tokens"] = sum(len(p) for p in prompts)
             counts["kda_pad_tokens"] = (int(bucket_rows)
                                         - counts["kda_tokens"])
             # beside an attention layer too (no latent layer counts them)
-            counts.update(
-                prompt_rows=counts["kda_tokens"],
-                bucket_rows=int(bucket_rows), prompts=len(prompts),
-                attn_pairs=sum(len(p) * (len(p) + 1) // 2
-                               for p in prompts))
+            counts.update(prefill_counts())
         if self._ssm_layers:
             counts["ssm_tokens"] = sum(len(p) for p in prompts)
             counts["ssm_pad_tokens"] = (int(bucket_rows)

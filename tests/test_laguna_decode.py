@@ -525,7 +525,9 @@ def test_builders_refuse_what_no_graph_computes():
             (dict(base, attn_gate="elementwise"), "per_head"),
             (dict(experts, router_score="tanh"),
              "sigmoid or softmax scores"),
-            (dict(experts, d_shared_expert=0), "a shared expert"),
+            # no shared expert (0) is a layer the program builds since
+            # PR 54 (MiMo-V2-Flash has none): a negative width is not
+            (dict(experts, d_shared_expert=-16), "a shared expert"),
             (dict(base, rope={"full": {"rotary_dim": 64}}), "rotation"),
             (dict(base, ffn_types=["glu"]), "no graph computes")]:
         with pytest.raises(ValueError, match=match):

@@ -662,6 +662,11 @@ COVERED_ELSEWHERE = {
     # shares of an expert-parallel deployment, infer rules)
     "rope", "moe_route", "moe_experts", "moe_shared", "prefill_attention",
     "ring_append", "ring_pack", "decode_attn_ring",
+    # a slab of flat rows, K's wider than V's, of heads that fill no
+    # tile: tests/test_mimo_v2_decode.py (the kernel in interpret mode
+    # against the lax path over lengths inside, at and past a block;
+    # through the server against the plain reference)
+    "decode_attention_uneven",
     # differential attention (full, windowed, one token over a slab and
     # over a wrapped ring, cross) and the gated memory unit against
     # plain statements of them: tests/test_diff_attn_ops.py

@@ -5,10 +5,15 @@ kernels (one K/V head a query head, and grouped queries on a slab of 8),
 the fused LM-head loss gradient, the kernel over a slab of flat rows,
 the absorbed latent attention's kernel over its slab's transposed
 view, the selective scan's kernel and the chunked delta rule's. See `test_tpu_compile.py` for what such a compile can and cannot
-say.
+say. The MiMo-V2-Flash cell's two programs are here too, around the one
+new kernel `ptpu.decode_attn_uneven`: `test_tpu_compile_serving.py` is
+at the gate's 240 s a file (ROADMAP D1) and this file is the lightest.
 """
 from __future__ import annotations
 
+import re
+
+import numpy as np
 import pytest
 
 import jax
@@ -18,7 +23,7 @@ from paddle_tpu.ops import attention as A
 from paddle_tpu.ops import kv_cache as KV
 
 from tpu_compile_lib import (B, D_MODEL, HBM_BYTES, T, VOCAB, _compile,
-                             _compiled, _whole_slab_ops)
+                             _compiled, _serving_step, _whole_slab_ops)
 from tpu_compile_lib import one_chip, topo  # noqa: F401  (fixtures)
 
 
@@ -284,3 +289,75 @@ def test_kda_scan_kernel_compiles(one_chip, b, t, h, guarded):
         assert shape not in text, shape
     assert compiled.memory_analysis().temp_size_in_bytes <= (
         b * t * 128 * 4 + 2**16)
+
+
+_MIMO_CASES = [
+    # id, kind, batch, seq: the MiMo-V2-Flash serving cell's own programs
+    # (benchmark/configs/mimo-v2-flash.json: 7 layers at published widths,
+    # 8 of 256 experts held, 16 slots of 16,384 positions; the largest
+    # admission is one prompt of the 16,384 bucket)
+    ("decode-16x16384", "decode", 16, 16384),
+    ("prefill-1x16384", "prefill", 1, 16384),
+]
+
+
+@pytest.mark.parametrize("kind,batch,seq", [c[1:] for c in _MIMO_CASES],
+                         ids=[c[0] for c in _MIMO_CASES])
+def test_mimo_v2_serving_step_compiles(one_chip, monkeypatch, kind, batch,
+                                       seq):
+    """The programs DecodePredictor builds for the MiMo-V2-Flash cell
+    (64 query heads of 192 over value heads of 128; a full layer on 4
+    key/value heads, a sliding one on 8 with a window of 128 and a
+    learned sink a head; a sigmoid router with a selection bias over 256
+    experts of width 2,048, 8 held, no shared one; an untied head over
+    19,072 ids): they compile for a v5e and fit it beside each other. A
+    prefill holds one flash forward a layer on bfloat16 operands, q and
+    k padded 192 -> 256 and v at 128 (`ptpu.flash_fwd` twice,
+    `ptpu.attn_window` five times). The step donates its fourteen
+    entries; each full layer's two slabs of FLAT rows (768 beside 512
+    floats a position) are read where they lie by one call of
+    `ptpu.decode_attn_uneven` and appended to in place: nothing of a
+    slab's size is copied, reshaped or transposed."""
+    from test_tpu_compile_cells import (
+        _assert_bfloat16_operands_and_lengths, _cell_predictor)
+
+    pred = _cell_predictor("mimo_v2_lm", "mimo-v2-flash.json", monkeypatch)
+    step_fn, feeds, state, n_cache = _serving_step(pred, kind, batch, seq,
+                                                   one_chip)
+    compiled = jax.jit(step_fn, donate_argnums=(0,)).lower(
+        feeds, state).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < HBM_BYTES, "does not fit one chip: %r" % (mem,)
+    weights = sum(int(np.prod(s.shape)) * 4 for s in state.values())
+    assert 8.88e9 < weights < 8.90e9, weights  # 2.222 B parameters
+    text = compiled.as_text()
+    spec = pred.cache_spec(16, 16384)
+    slabs = sum(e.nbytes for e in spec)
+    assert round(slabs / 1e9, 2) == 2.79
+    calls = re.findall(r"%([\w.-]+?)(?:\.\d+)? = [^\n]*"
+                       r'custom_call_target="tpu_custom_call"', text)
+    if kind == "prefill":
+        assert calls.count("ptpu.flash_fwd") == 2, calls
+        assert calls.count("ptpu.attn_window") == 5, calls
+        ops = _assert_bfloat16_operands_and_lengths(text, batch,
+                                                    v_width=64 * 128)
+        assert all(o[1:] == ["bf16[1,16384,16384]"] * 2
+                   + ["bf16[1,16384,8192]"] for _, o in ops), ops
+        # beside the weights, the slots' entries and the step
+        assert weights + slabs + mem.temp_size_in_bytes + (
+            mem.output_size_in_bytes) < 15.5 * 2**30, mem
+        # 2.89 GiB where ISSUE 54 allowed 3-4.5: 16 slots fit
+        assert mem.temp_size_in_bytes < 3.2 * 2**30, mem.temp_size_in_bytes
+        return
+    assert [c for c in calls if c.startswith("ptpu.")] == [
+        "ptpu.decode_attn_uneven"] * 2, calls
+    assert n_cache == len(spec) == 14
+    assert mem.alias_size_in_bytes >= slabs
+    for shape in ((16, 16384, 768), (16, 16384, 512)):
+        ops = _whole_slab_ops(text, shape)
+        moved = [name for op, name, changed in ops
+                 if op in ("copy", "transpose", "reshape") or changed]
+        assert ops and not moved, (shape, ops)
+    assert mem.temp_size_in_bytes < 200 * 2**20, mem.temp_size_in_bytes

@@ -54,6 +54,8 @@ CELLS = [
      [("decode", 16, 16384), ("prefill", 2, 8192)]),
     ("solar_open2_lm", "solar-open2-250b.json",
      [("decode", 32, 8192), ("prefill", 4, 1024)]),
+    ("mimo_v2_lm", "mimo-v2-flash.json",
+     [("decode", 16, 16384), ("prefill", 4, 1024)]),
 ]
 
 
