@@ -93,8 +93,11 @@ MLA_TRACES = REGISTRY.counter(
     "paddle_tpu_mla_traces_total",
     "Traces of latent attention (ops/mla.py), by path=expanded (a "
     "prefill: K and V of every head built from the latent rows) | "
-    "absorbed_kernel | absorbed (a step: attention ON the latent rows, "
-    "by the kernel over live blocks | the lax form over whole slabs). "
+    "absorbed_kernel_once | absorbed_kernel | absorbed (a step: attention "
+    "ON the latent rows, by the kernel over live blocks, each fetched once "
+    "and kept in VMEM between its two passes | the same, fetched a pass: "
+    "under a choice of rows, or a slot too large to keep | the lax form "
+    "over whole slabs). "
     "Counted when the op is traced: a program loaded from a cache adds 0")
 SSM_SCAN_TRACES = REGISTRY.counter(
     "paddle_tpu_ssm_scan_traces_total",

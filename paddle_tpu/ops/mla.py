@@ -49,8 +49,9 @@ position keeps), ``mla_expand`` (``ptpu.mla_expand``), ``mla_decode``
 (``ptpu.mla_decode``) and ``mla_append`` (``ptpu.mla_append``: one row
 a slot at its length, in place under donation).
 ``paddle_tpu_mla_traces_total{path}`` counts which path a program was
-traced with: ``expanded``, ``absorbed_kernel`` or ``absorbed`` (the
-lax form).
+traced with: ``expanded``, ``absorbed_kernel_once`` (the kernel, a
+slot's live rows fetched once), ``absorbed_kernel`` (the kernel, a
+slab too large to keep: fetched a pass) or ``absorbed`` (the lax form).
 """
 from __future__ import annotations
 
@@ -312,11 +313,13 @@ def _latent_attend_lax(q_row, slab, lens, rank, chosen=None):
 
 # positions (lanes) a block of the kernel: (320, 1024) float32 is 1.3 MB,
 # both passes' blocks double-buffered 4.6 MB beside 2 MB of scores. On
-# the chip, a call over 32 slots of 16,384 with ~121,000 rows live takes
-# 0.60 ms at 1,024 and at 2,048 lanes (which streams 15% more rows and
-# runs half the grid cells: a cell costs ~0.16 us, live or dead), 0.75
-# at 512 and 1.19 at 256; the lax form takes 1.67 whatever is live
-# (PERF.md, PR 39).
+# the chip, a call over 32 slots of 16,384 with ~121,000 rows live that
+# fetches them a pass takes 0.60 ms at 1,024 and at 2,048 lanes (which
+# streams 15% more rows and runs half the grid cells: a cell costs ~0.16
+# us, live or dead), 0.75 at 512 and 1.19 at 256; the lax form takes
+# 1.67 whatever is live (PERF.md, PR 39). Since PR 55 the rows are
+# fetched once (``decode_stream.kept_vmem_bytes``: K's blocks 2.6 MB, the
+# kept ``rank`` rows of a slot 16.8 MB).
 _LATENT_BLOCK_LANES = 1024
 
 
@@ -329,7 +332,9 @@ def latent_view(s, h, row, rank, dtype, block_s=_LATENT_BLOCK_LANES):
     one (h, row) x (row, BS) product of all heads; V's block (1, rank,
     BS) is the ``c_kv`` sublane rows of the same positions of the same
     array (the ``k_r`` rows take no part in the weighted sum), the
-    contraction on both operands' lanes. Row and rank have to fill
+    contraction on both operands' lanes: where a slot's (rank, s) fits
+    in VMEM the body keeps it from K's pass and fetches nothing twice
+    (``decode_stream.kept_vmem_bytes``). Row and rank have to fill
     whole 8-row sublane tiles of that view."""
     f32 = jnp.float32
     return _DS.StreamView(
@@ -477,10 +482,12 @@ def mla_decode(q, slab, lengths, w_kvb, scale, chosen=None,
     b, _, h, _ = q.shape
     s, rank = slab.shape[1], w_kvb.shape[0]
     nope = q.shape[-1] - (slab.shape[-1] - rank)
-    view = latent_view if chosen is None else chosen_view
-    kernel = _KV.decode_stream_rows(view(
-        s, h, slab.shape[-1], rank, slab.dtype)) is not None
-    MLA_TRACES.inc(path="absorbed_kernel" if kernel else "absorbed")
+    view = (latent_view if chosen is None else chosen_view)(
+        s, h, slab.shape[-1], rank, slab.dtype)
+    kernel = _KV.decode_stream_rows(view) is not None
+    once = kernel and _DS.kept_vmem_bytes(view) is not None
+    MLA_TRACES.inc(path="absorbed_kernel_once" if once else
+                   "absorbed_kernel" if kernel else "absorbed")
     with jax.named_scope(name):
         w_k, w_v = _split_kvb(w_kvb, h, nope)
         qf = q[:, 0].astype(jnp.float32)

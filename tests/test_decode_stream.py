@@ -4,7 +4,10 @@ body behind `ptpu.decode_attn_grouped`, `ptpu.diff_attn_rows` and
 here once: its index maps as plain functions, and every view in
 interpret mode against that view's own exact lax path (the parity cases
 the three op files used to hold, case for case, and each view at the
-lengths around a block's edges and past the slab).
+lengths around a block's edges and past the slab). Where K and V are
+ONE array that fits in VMEM the body fetches each live block once (PR
+55): which calls do, by shape and identity alone, and that their
+output is the two-read body's bit for bit.
 """
 from __future__ import annotations
 
@@ -17,6 +20,7 @@ import jax.numpy as jnp
 from paddle_tpu import observability as obs
 from paddle_tpu.ops import decode_stream as DS
 from paddle_tpu.ops import diff_attn as D
+from paddle_tpu.ops import eva
 from paddle_tpu.ops import kv_cache as KV
 from paddle_tpu.ops import mla
 
@@ -84,6 +88,116 @@ def test_the_rule_is_the_views_numbers_and_the_type():
     assert DS.block_positions(view(score_rows=257)) is None
 
 
+def test_the_kept_rows_rule_is_the_views_numbers():
+    """`kept_vmem_bytes`: V's part of every position of a slot, the
+    slot's scores and K's two blocks, under 64 MiB; None past it, and
+    where no kernel attends the view at all."""
+    f32 = jnp.float32
+    mistral = mla.latent_view(16384, 32, 320, 256, f32)
+    assert DS.kept_vmem_bytes(mistral) == 4 * (
+        256 * 16384 + 32 * 16384 + 2 * 320 * 1024)       # 21.5 MB
+    ling = mla.latent_view(16384, 32, 576, 512, f32)
+    assert DS.kept_vmem_bytes(ling) == 4 * (
+        512 * 16384 + 32 * 16384 + 2 * 576 * 1024)       # 40.4 MB
+    assert DS.kept_vmem_bytes(ling) < DS._KEPT_VMEM_CAP == 64 * 2**20
+    # the same rows on slots of 32,768: 71.3 MB
+    assert DS.kept_vmem_bytes(mla.latent_view(32768, 32, 576, 512,
+                                              f32)) is None
+    assert DS.kept_vmem_bytes(mla.latent_view(16384, 32, 320, 256,
+                                              jnp.bfloat16)) is None
+    assert DS.kept_vmem_bytes(mistral, rows=2048) == (
+        DS.kept_vmem_bytes(mistral) + 4 * 2 * 320 * 1024)
+
+
+# -- which calls read once -----------------------------------------------------------
+
+def _sd(*shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                yield from _pallas_calls(inner)
+
+
+def _latent_call(b, s, h, row, rank, two_arrays=False):
+    if two_arrays:
+        view = mla.latent_view(s, h, row, rank, jnp.float32)
+        return (lambda q, k, v, n: DS.stream_attend(view, n, q, k, v),
+                (_sd(b, h, row), _sd(b, s, row), _sd(b, s, row),
+                 _sd(b, dtype=jnp.int32)))
+    return (lambda q, c, n: mla.pallas_latent_attend(q, c, n, rank),
+            (_sd(b, h, row), _sd(b, s, row), _sd(b, dtype=jnp.int32)))
+
+
+# id -> (fn, its arguments' shapes, positions a slot, positions a block,
+#        floats of a slot kept in VMEM: 0 where each block is read a pass)
+_BODY_CASES = {
+    "latent-mistral-cell": _latent_call(32, 16384, 32, 320, 256) + (
+        16384, 1024, 256 * 16384),
+    "latent-ling-cell": _latent_call(64, 16384, 32, 576, 512) + (
+        16384, 1024, 512 * 16384),
+    "latent-smoke": _latent_call(8, 2048, 8, 320, 256) + (
+        2048, 1024, 256 * 2048),
+    # kept rows, scores and blocks of 71.3 MB: over the cap
+    "latent-slots-of-32768": _latent_call(4, 32768, 32, 576, 512) + (
+        32768, 1024, 0),
+    # the same numbers in two arrays: nothing says V lies inside K
+    "latent-two-arrays": _latent_call(2, 2048, 8, 320, 256, True) + (
+        2048, 1024, 0),
+    "grouped": (
+        lambda q, k, v, n: KV.pallas_decode_attention(q, k, v, n),
+        (_sd(2, 1, 48, 128), _sd(2, 1024, 8, 128), _sd(2, 1024, 8, 128),
+         _sd(2, dtype=jnp.int32)), 1024, 512, 0),
+    "uneven": (
+        lambda q, k, v, n: KV.pallas_decode_attention_uneven(q, k, v, n, 4),
+        (_sd(2, 1, 64, 192), _sd(2, 1024, 4 * 192), _sd(2, 1024, 4 * 128),
+         _sd(2, dtype=jnp.int32)), 1024, 512, 0),
+    "rows": (
+        lambda q, k, v, n: D.pallas_attend_rows(q, k, v, n, 0.125),
+        (_sd(2, 1, 16, 128), _sd(2, 1024, 512), _sd(2, 1024, 512),
+         _sd(2, dtype=jnp.int32)), 1024, 512, 0),
+    "eva": (
+        lambda q, k, v, n: DS.stream_attend(
+            eva.eva_view(1024, 8, 128, jnp.float32), n, q, k, v, starts=n),
+        (_sd(2, 1, 8, 128), _sd(2, 1024, 8, 128), _sd(2, 1024, 8, 128),
+         _sd(2, dtype=jnp.int32)), 1024, 512, 0),
+}
+
+
+@pytest.mark.parametrize("fn,avals,s,rows,kept", list(_BODY_CASES.values()),
+                         ids=list(_BODY_CASES))
+def test_one_array_that_fits_is_streamed_once(fn, avals, s, rows, kept):
+    """`v is k` and the shapes choose the body, nothing else: ONE array
+    whose V part of a slot fits in VMEM is the call's one streamed
+    operand, on a grid of `n_blk` steps a slot, with a scratch of the
+    slot's V part and the scoped VMEM raised to hold it; every view
+    that hands two arrays, and a slot too large to keep, keeps the grid
+    of `2 n_blk` steps and both operands, and asks for no VMEM."""
+    call, = _pallas_calls(jax.make_jaxpr(fn)(*avals).jaxpr)
+    grid = call.params["grid_mapping"]
+    b, n_blk = avals[0].shape[0], s // rows
+    streamed = [m for m in grid.block_mappings[:grid.num_inputs]
+                if any(getattr(d, "block_size", d) == rows
+                       for d in m.block_shape)]
+    scratch = [a.shape for a in grid.scratch_avals]
+    limit = call.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+    if kept:
+        assert grid.grid == (b, n_blk)
+        assert len(streamed) == 1 and grid.num_inputs == 2
+        assert int(np.prod(scratch[-1])) == kept and len(scratch) == 5
+        assert 4 * kept < limit < 4 * kept + 32 * 2**20
+    else:
+        assert grid.grid == (b, 2 * n_blk)
+        assert len(streamed) == 2 and grid.num_inputs == 3
+        assert len(scratch) == 4 and limit is None
+
+
 # -- every view against its lax path ------------------------------------------------
 
 def _rand(shape, seed=0):
@@ -148,6 +262,37 @@ def _latent(lengths, block, s=512, h=8, row=40, rank=32, seed=5):
     got = mla.pallas_latent_attend(q_row, slab, lens, rank, block_s=block,
                                    interpret=True)
     assert got.shape == (b, h, rank)
+    _same_bits_as_two_reads(
+        got, mla.latent_view(s, h, row, rank, slab.dtype, block), lens,
+        q_row, slab)
+    return got, want, None
+
+
+def _same_bits_as_two_reads(got, view, lens, q_row, slab, starts=None):
+    """`got`, the body's output under ONE array (read once, the V part
+    kept in VMEM), is bit for bit what it gives handed K and V as two
+    arrays of the same numbers (each live block read a pass)."""
+    assert DS.kept_vmem_bytes(view) is not None
+    twice = DS.stream_attend(view, lens, q_row, slab,
+                             jnp.array(slab, copy=True), True, starts)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(twice))
+
+
+def _latent_ranged(lengths, starts, block, s=512, h=8, row=40, rank=32):
+    """The latent view over rows `[starts, lengths)` of a slot, read
+    once: against the lax form under that choice of rows, and against
+    the two-read body bit for bit."""
+    r = np.random.default_rng(9)
+    b = len(lengths)
+    slab = jnp.asarray(r.normal(size=(b, s, row)).astype(np.float32))
+    q_row = jnp.asarray(r.normal(size=(b, h, row)) * 0.05, jnp.float32)
+    lens, first = (jnp.asarray(x, jnp.int32) for x in (lengths, starts))
+    view = mla.latent_view(s, h, row, rank, slab.dtype, block)
+    got = DS.stream_attend(view, lens, q_row, slab, slab, True, first)
+    _same_bits_as_two_reads(got, view, lens, q_row, slab, first)
+    want = mla._latent_attend_lax(
+        q_row, slab, lens, rank,
+        chosen=jnp.arange(s)[None, :] >= first[:, None])
     return got, want, None
 
 
@@ -179,7 +324,8 @@ def _mla_decode(lengths, block, monkeypatch):
     want = mla.mla_decode(q, slab, lens, w_kvb, 0.37)
     c1 = counts()
     assert c1["absorbed"] - c0.get("absorbed", 0) == 1
-    assert c1.get("absorbed_kernel", 0) == c0.get("absorbed_kernel", 0)
+    assert c1.get("absorbed_kernel_once", 0) == c0.get(
+        "absorbed_kernel_once", 0)
     monkeypatch.setattr(KV, "decode_stream_rows", lambda view: block)
     kernel = mla.pallas_latent_attend
     monkeypatch.setattr(
@@ -187,8 +333,11 @@ def _mla_decode(lengths, block, monkeypatch):
         lambda *a: kernel(*a, block_s=block, interpret=True))
     got = mla.mla_decode(q, slab, lens, w_kvb, 0.37)
     c2 = counts()
-    assert c2["absorbed_kernel"] - c1.get("absorbed_kernel", 0) == 1
+    # a slab of 512 rows of 40 is kept whole: the one-read body
+    assert c2["absorbed_kernel_once"] - c1.get("absorbed_kernel_once",
+                                               0) == 1
     assert c2["absorbed"] == c1["absorbed"]
+    assert c2.get("absorbed_kernel", 0) == c0.get("absorbed_kernel", 0)
     assert got.shape == want.shape == (b, 1, kh, dv)
     np.testing.assert_array_equal(np.asarray(want)[np.asarray(lens) == 0],
                                   0.0)
@@ -262,6 +411,17 @@ _CASES["rows-past-the-slab"] = (
     [256 + 5, 256, 7], lambda l, mp: _rows(l, 64))
 _CASES["latent-past-the-slab"] = (
     [512 + 5, 512, 7], lambda l, mp: _latent(l, 128))
+# the one-read body against the two-read one (`_latent` asks for the
+# same bits), a length a case and a batch that mixes them
+for _n in _edges(128, 512):
+    _CASES["latent-once-%d" % _n] = (
+        [_n, _n], lambda l, mp: _latent(l, 128, seed=8))
+_CASES["latent-once-mixed"] = (
+    _edges(256, 512) + _edges(128, 512)[2:5],
+    lambda l, mp: _latent(l, 256, seed=8))
+_CASES["latent-once-ranged"] = (
+    [0, 300, 512, 129, 128, 40],
+    lambda l, mp: _latent_ranged(l, [0, 7, 128, 128, 130, 40], 128))
 
 
 @pytest.mark.parametrize("lengths,run", list(_CASES.values()),

@@ -293,27 +293,37 @@ def test_absorbed_equals_expanded_at_unlike_widths():
 # cell's row of 576: `test_decode_stream.py`, the `latent-` cases)
 
 _RULE_CASES = [
-    # id, (s, h, row, rank, dtype), lanes a block or None
-    ("the-cell", (16384, 32, 320, 256, "float32"), mla._LATENT_BLOCK_LANES),
-    ("short-slab", (256, 32, 320, 256, "float32"), 256),
-    ("bfloat16", (16384, 32, 320, 256, "bfloat16"), None),
-    ("row-no-sublane-tiles", (16384, 32, 321, 256, "float32"), None),
-    ("rank-no-sublane-tiles", (16384, 32, 320, 250, "float32"), None),
-    ("no-128-lane-block", (192, 32, 320, 256, "float32"), None),
-    ("scores-over-budget", (16384, 128, 320, 256, "float32"), None),
+    # id, (s, h, row, rank, dtype), lanes a block or None, whether ONE
+    # array of that shape is read once (a slot's `rank` rows kept in VMEM)
+    ("the-cell", (16384, 32, 320, 256, "float32"), mla._LATENT_BLOCK_LANES,
+     True),
+    ("short-slab", (256, 32, 320, 256, "float32"), 256, True),
+    ("bfloat16", (16384, 32, 320, 256, "bfloat16"), None, False),
+    ("row-no-sublane-tiles", (16384, 32, 321, 256, "float32"), None, False),
+    ("rank-no-sublane-tiles", (16384, 32, 320, 250, "float32"), None, False),
+    ("no-128-lane-block", (192, 32, 320, 256, "float32"), None, False),
+    ("scores-over-budget", (16384, 128, 320, 256, "float32"), None, False),
+    ("the-ling-cell", (16384, 32, 576, 512, "float32"),
+     mla._LATENT_BLOCK_LANES, True),
+    # 64 MiB of kept rows alone: the kernel, each live block read a pass
+    ("kept-rows-over-the-cap", (32768, 32, 576, 512, "float32"),
+     mla._LATENT_BLOCK_LANES, False),
 ]
 
 
-@pytest.mark.parametrize("shape,lanes", [c[1:] for c in _RULE_CASES],
+@pytest.mark.parametrize("shape,lanes,once", [c[1:] for c in _RULE_CASES],
                          ids=[c[0] for c in _RULE_CASES])
 def test_latent_kernel_rule_is_the_slabs_shape_type_and_device(
-        monkeypatch, shape, lanes):
+        monkeypatch, shape, lanes, once):
     """`block_positions` of `latent_view` answers from shape and type
-    alone; `decode_stream_rows` adds the device: the CPU takes the lax path at
+    alone, and so does `kept_vmem_bytes` (which body attends ONE array:
+    the one that reads it once, where a slot's summed rows fit);
+    `decode_stream_rows` adds the device: the CPU takes the lax path at
     every shape, a TPU (stood in for by the decode kernels' own device
     rule, lifted) the kernel wherever the shape allows one."""
     view = mla.latent_view(*shape)
     assert DS.block_positions(view) == lanes
+    assert (DS.kept_vmem_bytes(view) is not None) == once
     assert KV.decode_stream_rows(view) is None  # the CPU
     monkeypatch.setattr(
         KV, "_use_pallas_decode",
@@ -325,6 +335,37 @@ def test_latent_kernel_rule_is_the_slabs_shape_type_and_device(
                 jnp.zeros((1, shape[1], shape[2]), jnp.float32),
                 jnp.zeros((1, shape[0], shape[2]), shape[4]),
                 jnp.zeros((1,), jnp.int32), shape[3])
+
+
+@pytest.mark.parametrize("s,chosen,path", [
+    (16384, False, "absorbed_kernel_once"),
+    (32768, False, "absorbed_kernel"),   # kept rows over the cap
+    (16384, True, "absorbed_kernel"),    # a choice of rows: one pass of its own
+], ids=["kept", "over-the-cap", "chosen"])
+def test_traces_counter_names_the_body_a_step_holds(monkeypatch, s, chosen,
+                                                    path):
+    """`paddle_tpu_mla_traces_total{path}` of a traced `mla_decode` at
+    the Ling cell's widths (the device rule lifted, nothing run): once
+    for the program, under the body its kernel has."""
+    monkeypatch.setattr(KV, "_use_pallas_decode", lambda s, d: True)
+    h, row, rank, dn, dv = 32, 576, 512, 128, 128
+
+    def sd(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    def counts():
+        return {k["path"]: v for k, v in obs.MLA_TRACES.samples()}
+
+    before = counts()
+    out = jax.eval_shape(
+        lambda q, slab, n, w, c: mla.mla_decode(q, slab, n, w, 0.1, c),
+        sd(2, 1, h, dn + row - rank), sd(2, s, row), sd(2, dtype=jnp.int32),
+        sd(rank, h * (dn + dv)), sd(2, s, dtype=jnp.bool_) if chosen else None)
+    assert out.shape == (2, 1, h, dv)
+    after = counts()
+    moved = {k: after[k] - before.get(k, 0) for k in after
+             if after[k] != before.get(k, 0)}
+    assert moved == {path: 1}
 
 
 # -- many heads, a group at a time; under a mask; over a window; a ring ---------

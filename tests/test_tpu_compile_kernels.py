@@ -147,25 +147,36 @@ def test_diff_attn_rows_kernel_compiles(one_chip, b, s, h, row):
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
 
 
-@pytest.mark.parametrize("b,s,h", [(32, 16384, 32), (8, 2048, 8)],
-                         ids=["cell-32x16384", "smoke-8x2048"])
-def test_latent_attention_kernel_compiles(one_chip, b, s, h):
+@pytest.mark.parametrize("b,s,h,row,rank", [(32, 16384, 32, 320, 256),
+                                            (8, 2048, 8, 320, 256),
+                                            (64, 16384, 32, 576, 512)],
+                         ids=["cell-32x16384", "smoke-8x2048",
+                              "ling-cell-64x16384"])
+def test_latent_attention_kernel_compiles(one_chip, b, s, h, row, rank):
     """The absorbed attention's kernel (`ops/mla.py`) at the
     Mistral-Small-4 cell's slab (the transposed view is (32, 320,
-    16384)) and at chip_smoke's: Mosaic takes a (320, lanes) block whose
-    contraction is no multiple of 128, the (rank, lanes) block of the
-    same operand and the (heads, S) score scratch inside the compiler's
-    default scoped VMEM (the call asks for no more); the
-    compiler keeps the slab's parameter in its sequence-minor layout
-    and hands the kernel a BITCAST of it: no copy, no transpose, no
-    temporaries outside the call."""
+    16384)), at chip_smoke's and at the Ling-3.0-flash cell's (64, 576,
+    16384): Mosaic takes a (row, lanes) block whose contraction is no
+    multiple of 128, the copy of its first `rank` sublane rows into the
+    (rank, S) scratch that keeps a slot's V part between the passes
+    (since PR 55 the call streams the slab ONCE: one operand, no second
+    half of the grid), the weighted sum over that scratch's lane blocks
+    and the (heads, S) score scratch, inside the scoped VMEM the call
+    asks for (21.5 MB and 40.4 MB held at the cells' shapes, 16 MiB
+    beside them; a v5e core has 128 MiB); the compiler keeps the slab's
+    parameter in its sequence-minor layout and hands the kernel a
+    BITCAST of it: no copy, no transpose, no temporaries outside the
+    call."""
     from paddle_tpu.ops import mla
-    from paddle_tpu.ops.decode_stream import block_positions
+    from paddle_tpu.ops.decode_stream import block_positions, kept_vmem_bytes
 
-    row, rank = 320, 256
     slab = (b, s, row)
-    assert block_positions(mla.latent_view(
-        s, h, row, rank, jnp.float32)) == min(s, mla._LATENT_BLOCK_LANES)
+    view = mla.latent_view(s, h, row, rank, jnp.float32)
+    assert block_positions(view) == min(s, mla._LATENT_BLOCK_LANES)
+    held = kept_vmem_bytes(view)
+    print("ptpu.mla_latent_attn %s: %.1f MB of VMEM held (kept rows, scores, "
+          "two blocks)" % (slab, held / 1e6))
+    assert held is not None and held >= 4 * rank * s
     compiled = _compiled(
         lambda q, c, n: mla.pallas_latent_attend(q, c, n, rank),
         jax.ShapeDtypeStruct((b, h, row), jnp.float32, sharding=one_chip),
@@ -175,7 +186,10 @@ def test_latent_attention_kernel_compiles(one_chip, b, s, h):
     line, = [ln for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
     assert "%ptpu.mla_latent_attn" in line.split(" = ")[0], line
-    assert line.count("f32[%d,%d,%d]{2,1,0" % (b, row, s)) >= 2, line
+    # the slab's transposed view is the call's ONE streamed operand
+    assert line.split("operand_layout_constraints={")[1].split(
+        "}}")[0].count("f32[%d,%d,%d]{2,1,0" % (b, row, s)) == 1, line
+    assert '"size":"%d"' % (held + 16 * 2**20) in line, line
     assert "f32[%d,%d,%d]{1,2,0" % slab in text
     ops = [op for op, _, _ in _whole_slab_ops(text, slab)]
     assert ops[0] == "parameter" and set(ops[1:]) == {"bitcast"}, ops
