@@ -40,7 +40,8 @@ _HERE = os.path.dirname(os.path.abspath(__file__))
 # the exported decode model (0.9 GB of random weights) and nothing else
 OUT_DIR = os.path.join(_HERE, ".chip_smoke_out")
 
-# The model, everywhere: bench.py's LM.
+# The model, everywhere: the LM of `benchmark/configs/opt-6.7b.json`'s
+# family at a width one chip trains 12 layers of.
 FULL = dict(vocab=32768, n_layer=12, n_head=8, d_model=1024, d_inner=4096,
             seq=1024, batch=16,
             # serve: 8 seeded prompts of 128-512 tokens, 32 new tokens each
@@ -122,8 +123,7 @@ def _rel_err(got, want):
 def phase_kernels(cfg):
     """Pallas BTHD attention (fwd, split bwd, fused bwd) against the XLA
     flash path on the same bf16 inputs; the Pallas decode kernel against
-    `decode_attention_reference`. What bench.py's smoke gate did as a
-    switch is an assertion here."""
+    `decode_attention_reference`."""
     import jax
     import jax.numpy as jnp
 
@@ -155,11 +155,14 @@ def phase_kernels(cfg):
 
     ref_out, ref_grads = value_and_grads(loss_xla)
     errs = {}
-    prev = os.environ.get("PADDLE_TPU_FLASH_FUSED_BWD")
+    assert A._fused_bwd_fits(t, d, 2), "this shape would never run fused"
+    budget = A._FUSED_BWD_VMEM_BUDGET
     try:
-        for tag, fused in (("split", "0"), ("fused", "1")):
-            # read at trace time; a fresh lambda is a fresh trace
-            os.environ["PADDLE_TPU_FLASH_FUSED_BWD"] = fused
+        # the backward is chosen at trace time from the shape and this
+        # constant: "split" gives it a budget nothing fits
+        for tag, limit in (("split", 1), ("fused", budget)):
+            A._FUSED_BWD_VMEM_BUDGET = limit
+            # a fresh lambda is a fresh trace
             out, grads = value_and_grads(lambda q, k, v: loss_pallas(q, k, v))
             errs["fwd"] = _rel_err(out, ref_out)
             assert errs["fwd"] <= TOL_ATTN_FWD, (
@@ -170,10 +173,7 @@ def phase_kernels(cfg):
                     "BTHD %s-backward d%s vs XLA at %s: %.3g > %.3g"
                     % (tag, name, (b, t, h, d), e, TOL_ATTN_GRAD))
     finally:
-        if prev is None:
-            os.environ.pop("PADDLE_TPU_FLASH_FUSED_BWD", None)
-        else:
-            os.environ["PADDLE_TPU_FLASH_FUSED_BWD"] = prev
+        A._FUSED_BWD_VMEM_BUDGET = budget
 
     slots = cfg["slots"]
     lengths = jnp.asarray(
@@ -294,13 +294,12 @@ def _executable_texts(exe):
 
 def phase_train(cfg, place):
     """5 `exe.run` steps on one fixed batch, then one `run_loop` window of
-    4 (the path bench.py times). AMP O2 and the fused flash backward, as
-    bench.py runs the LM."""
+    4. AMP O2; the flash backward is the fused one, which this shape
+    gets with nothing set (`ops/attention._fused_bwd_fits`)."""
     import jax
 
     import paddle_tpu as fluid
 
-    os.environ["PADDLE_TPU_FLASH_FUSED_BWD"] = "1"
     main_p, startup, loss = _build_lm(cfg)
     feed = _fixed_batch(cfg)
     scope = fluid.Scope()
@@ -789,7 +788,6 @@ def phase_parallel(cfg, place, steps=3):
 
     assert jax.device_count() >= 4, (
         "--chips 4 needs 4 devices, JAX sees %d" % jax.device_count())
-    os.environ["PADDLE_TPU_FLASH_FUSED_BWD"] = "1"
     feed = _fixed_batch(cfg)
 
     def run(parallel):

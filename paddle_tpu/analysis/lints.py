@@ -224,7 +224,8 @@ def lint_recompile_risk(ctx: LintContext):
     PR-5 AOT disk cache) key on the exact feed signature, and the PR-2
     serving path pre-warms power-of-two batch buckets. A feed var with a
     dynamic batch axis is fine IF batches are bucketed; flag it as info
-    so AOT-cache miss hunts (docs/performance.md) can start here. More
+    so AOT-cache miss hunts (docs/performance.md, "Cold-start &
+    compile caching") can start here. More
     than one dynamic axis multiplies signatures and is a warning."""
     gb = ctx.program.global_block()
     for name, var in gb.vars.items():

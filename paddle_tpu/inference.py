@@ -248,7 +248,7 @@ class Predictor:
             cap = int(os.environ.get("PADDLE_TPU_PRELOAD_MAX", 8))
         except ValueError:
             # preload is best-effort, never a crash: a malformed value
-            # falls back to the default (PADDLE_TPU_RING_CHUNK precedent)
+            # falls back to the default
             warnings.warn(
                 "PADDLE_TPU_PRELOAD_MAX=%r is not an integer; using 8"
                 % os.environ.get("PADDLE_TPU_PRELOAD_MAX"))

@@ -158,10 +158,8 @@ __all__ = [
 
 from .ops import elementwise_add  # re-export for parity
 
-import os as _os
-
-# default KV block for fused_attention, overridable for perf sweeps
-_DEFAULT_ATTN_BLOCK_K = int(_os.environ.get("PADDLE_TPU_ATTN_BLOCK_K", 512))
+# default KV block of fused_attention and decode_attention
+_DEFAULT_ATTN_BLOCK = 512
 
 
 def _prod(xs):
@@ -2139,7 +2137,7 @@ def fused_attention(q, k, v, causal=False, scale=None, sequence_length=None,
         outputs={"Out": [out]},
         attrs={"causal": causal, "scale": scale,
                "dropout_rate": dropout_rate,
-               "block_k": block_k or _DEFAULT_ATTN_BLOCK_K,
+               "block_k": block_k or _DEFAULT_ATTN_BLOCK,
                "layout": layout},
     )
     return out
@@ -2186,7 +2184,7 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None, block_s=None,
         inputs={"Q": [q], "KCache": [k_cache], "VCache": [v_cache],
                 "Lengths": [lengths]},
         outputs={"Out": [out]},
-        attrs={"scale": scale, "block_s": block_s or _DEFAULT_ATTN_BLOCK_K},
+        attrs={"scale": scale, "block_s": block_s or _DEFAULT_ATTN_BLOCK},
     )
     return out
 
@@ -2243,7 +2241,7 @@ def decode_attention_quant(q, k_cache, k_scales, v_cache, v_scales,
                 "VCache": [v_cache], "VScales": [v_scales],
                 "Lengths": [lengths]},
         outputs={"Out": [out]},
-        attrs={"scale": scale, "block_s": block_s or _DEFAULT_ATTN_BLOCK_K},
+        attrs={"scale": scale, "block_s": block_s or _DEFAULT_ATTN_BLOCK},
     )
     return out
 
@@ -2769,7 +2767,7 @@ def decode_attention_uneven(q, k_rows, v_rows, lengths, n_kv_head,
                 "Lengths": [lengths]},
         outputs={"Out": [out]},
         attrs={"n_kv_head": int(n_kv_head), "scale": scale,
-               "block_s": _DEFAULT_ATTN_BLOCK_K})
+               "block_s": _DEFAULT_ATTN_BLOCK})
     return out
 
 

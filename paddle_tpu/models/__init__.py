@@ -3,8 +3,8 @@
 built on the paddle_tpu layers API.
 
 Each module exposes the network builder plus a ``get_model(...)`` helper
-returning ``(avg_cost, aux-metric-or-None, feed_vars)`` for training scripts and
-bench.py.
+returning ``(avg_cost, aux-metric-or-None, feed_vars)`` for training
+scripts.
 """
 from . import mnist  # noqa: F401
 from . import vgg  # noqa: F401

@@ -11,8 +11,6 @@ TPU-first design notes:
 """
 from __future__ import annotations
 
-import os
-
 import numpy as np
 
 from .. import layers
@@ -61,8 +59,7 @@ def multi_head_attention(
     # internal transpose off-TPU or when d_head isn't lane-aligned, so
     # this is always numerically safe. Ring attention keeps BHTD (its
     # sequence axis must be the ppermute'd one).
-    bthd = (use_fused and not use_ring
-            and os.environ.get("PADDLE_TPU_ATTN_BTHD", "1") == "1")
+    bthd = use_fused and not use_ring
 
     def split_heads(x, T):
         x = layers.reshape(x, shape=[B, T, n_head, d_head])
@@ -271,9 +268,7 @@ def transformer_lm(
     Program still runs on one device (exact-attention fallback).
 
     fused_qkv=True packs each layer's self-attention q/k/v into one
-    (D, 3D) matmul (see multi_head_attention); bench.py flips it from
-    PADDLE_TPU_FUSED_QKV so Program construction itself stays
-    deterministic under a given argument list.
+    (D, 3D) matmul (see multi_head_attention).
 
     tie_embeddings=True shares the token-embedding table with the vocab
     projection (head logits = x @ emb^T): one less (V, D) parameter, so

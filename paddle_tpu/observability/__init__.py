@@ -4,9 +4,10 @@ The signals that matter for a framework whose whole Program executes as
 ONE fused XLA computation: compile events and compile-cache behavior
 (executor.py), per-step host/device time and feed/fetch volumes
 (Executor.run / run_loop / ParallelExecutor.run), serving latency and
-batch-size distribution (Predictor / PredictorServer), and bench phase
-accounting (bench.py). Everything records into one process-wide
-``MetricRegistry`` (metrics.py) and one bounded ``StepTimeline``
+batch-size distribution (Predictor / PredictorServer), and the
+benchmark's per-run accounting (benchmark/lib). Everything records into
+one process-wide ``MetricRegistry`` (metrics.py) and one bounded
+``StepTimeline``
 (timeline.py: a ring of steps and a ring of executable acquisitions);
 export.py renders Prometheus text / JSON, and
 ``PredictorServer.start_http()`` serves it at ``GET /metrics``.

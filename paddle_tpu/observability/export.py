@@ -5,8 +5,8 @@ format (version 0.0.4) — the payload ``PredictorServer``'s ``/metrics``
 endpoint serves and a scrape job ingests directly. ``to_json()`` bundles
 the same data with the step timeline for humans and dashboards.
 ``counters_state``/``delta_state`` give cheap before/after diffs so a
-caller (bench.py phases) can attach "what this block of work cost" without
-resetting anyone else's metrics.
+caller (a benchmark run, a test) can attach "what this block of work
+cost" without resetting anyone else's metrics.
 """
 from __future__ import annotations
 

@@ -152,8 +152,6 @@ def _tpu_params(*dimension_semantics, vmem_limit_bytes=None):
     the lse row in the fwd kernel, dk/dv in the fused backward).
     ``vmem_limit_bytes`` raises the kernel's scoped VMEM above the
     compiler's default where a caller knows its blocks need it."""
-    if os.environ.get("PADDLE_TPU_DIM_SEMANTICS", "1") == "0":
-        return {}  # kill-switch: restores the pre-semantics kernels
     more = ({} if vmem_limit_bytes is None
             else {"vmem_limit_bytes": int(vmem_limit_bytes)})
     return {"compiler_params": pltpu.CompilerParams(
@@ -432,43 +430,27 @@ def _mha_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
-def _fused_bwd_enabled() -> bool:
-    return os.environ.get("PADDLE_TPU_FLASH_FUSED_BWD", "0") == "1"
-
-
 # Scoped-VMEM budget for the fused kernel's per-(batch, head) residents:
 # k+v full rows (input dtype, double-buffered by Mosaic) plus the f32
 # dk/dv accumulators. 12 MB of the 16 MB scoped limit — the rest is
-# q/do/dq blocks, lse/delta rows, and Mosaic's own stack. Measured: the
-# fused kernel compiles at T=4096 (8 MB) and OOMs at T=8192 (16 MB+,
-# 'Scoped allocation with size 24.75M and limit 16.00M' on v5e).
+# q/do/dq blocks, lse/delta rows, and Mosaic's own stack.
 _FUSED_BWD_VMEM_BUDGET = 12 * 1024 * 1024
 
 
 def _fused_bwd_fits(tk: int, d: int, kv_itemsize: int) -> bool:
-    """True when the single-pass backward's whole-row VMEM residents
-    fit. Pure predicate — bench.py also calls it to label its config
-    record."""
+    """THE selector of the flash backward, from the shape alone: True
+    where the single-pass kernel's whole-row VMEM residents fit and it
+    runs, False where the split dq + dkv pair does. It rests on two
+    points measured on a v5e: the fused kernel compiles at T=4096,
+    d=128, bfloat16 (8 MB) and fails at T=8192 (16 MB+: 'Scoped
+    allocation with size 24.75M and limit 16.00M'). No option turns
+    either way; a test reaches the split pair by a shape over the budget
+    or by patching ``_FUSED_BWD_VMEM_BUDGET``."""
     kv_rows = 2 * tk * d * kv_itemsize * 2  # k+v, double-buffered
     acc_rows = 2 * tk * d * 4               # dk+dv f32 accumulators
     # strict <: a footprint exactly AT the budget (f32 rows, T=4096) has
     # never been measured on hardware — stay on the safe side of it
     return kv_rows + acc_rows < _FUSED_BWD_VMEM_BUDGET
-
-
-def _fused_bwd_dispatchable(tk: int, d: int, kv_itemsize: int) -> bool:
-    """Dispatch-site gate: the fused backward was asked for. Where its
-    VMEM residents do not fit, that is an error naming the shape — a
-    run labelled 'fused' never measures the split kernels."""
-    if not _fused_bwd_enabled():
-        return False
-    if not _fused_bwd_fits(tk, d, kv_itemsize):
-        raise ValueError(
-            "PADDLE_TPU_FLASH_FUSED_BWD=1 but the fused backward's VMEM "
-            "residents exceed the %.0f MB budget at seq_k=%d, d_head=%d, "
-            "itemsize=%d; unset it to use the split dq+dkv backward"
-            % (_FUSED_BWD_VMEM_BUDGET / 2**20, tk, d, kv_itemsize))
-    return True
 
 
 def _mha_fwd_call(qs, k, v, causal, block_q, block_k, interpret):
@@ -517,7 +499,7 @@ def _pallas_mha_bwd(causal, block_q, block_k, interpret, res, do):
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)[:, None, :]  # (BH, 1, T) — see lse layout note
 
-    if _fused_bwd_dispatchable(tk, d, k.dtype.itemsize):
+    if _fused_bwd_fits(tk, d, k.dtype.itemsize):
         kernel = functools.partial(
             _mha_bwd_fused_kernel, block_q=block_q, block_k=block_k,
             seq_k=tk, causal=causal)
@@ -724,7 +706,7 @@ def _pallas_mha_bthd_bwd(h, causal, block_q, block_k, interpret, res, do):
         * out.astype(jnp.float32).reshape(b, t, h, d),
         axis=-1).transpose(0, 2, 1).reshape(b * h, 1, t)
 
-    if _fused_bwd_dispatchable(tk, d, k.dtype.itemsize):
+    if _fused_bwd_fits(tk, d, k.dtype.itemsize):
         kernel = functools.partial(
             _mha_bwd_fused_kernel, block_q=block_q, block_k=block_k,
             seq_k=tk, causal=causal, pid_axis=2)
@@ -834,6 +816,12 @@ def pallas_flash_attention_bthd(q, k, v, causal=False, scale=None,
 
 
 
+# The q block of the flash kernels and, forward only, the k block too
+# (`_fit_block` halves it to a divisor of the sequence). A constant: a
+# test that wants several blocks of a short sequence patches it.
+_FLASH_BLOCK = 512
+
+
 def _fit_block(n: int, want: int) -> int:
     """Largest power-of-two block <= want that divides n (>=128 when
     possible — TPU lane granularity)."""
@@ -893,7 +881,8 @@ def _fused_attention(ctx):
     dropout_rate = float(ctx.attr("dropout_rate", 0.0) or 0.0)
     if ctx.is_test:
         dropout_rate = 0.0
-    block_k = int(ctx.attr("block_k", 512))
+    block_k = block_attr("fused_attention", "block_k",
+                         ctx.attr("block_k", 512))
     layout = str(ctx.attr("layout", "bhtd") or "bhtd").lower()
     rng = ctx.rng() if dropout_rate else None
     if layout == "bthd":
@@ -929,8 +918,7 @@ def _attention_bthd(q, k, v, lengths, causal, scale, dropout_rate, block_k,
     if d_head % 128 == 0 and _use_pallas(t, tk, lengths, dropout_rate):
         kern = functools.partial(
             pallas_flash_attention_bthd, causal=causal, scale=scale,
-            block_q=_env_block("PADDLE_TPU_FLASH_BQ", 512),
-            block_k=_env_block("PADDLE_TPU_FLASH_BK", block_k))
+            block_q=_FLASH_BLOCK, block_k=block_k)
         return _per_shard(kern, q, k, v, head_dim=2)
     out = _attention_bhtd(
         jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
@@ -944,11 +932,9 @@ def _attention_bhtd(q, k, v, lengths, causal, scale, dropout_rate, block_k,
     """The (B, H, T, Dh) dispatch: Pallas fwd+bwd kernels when eligible,
     XLA flash path (CPU, dropout, KV padding masks) otherwise."""
     if _use_pallas(q.shape[2], k.shape[2], lengths, dropout_rate):
-        # block sizes: env overrides (on-hardware sweeps) > op attr > 512
         kern = functools.partial(
             pallas_flash_attention, causal=causal, scale=scale,
-            block_q=_env_block("PADDLE_TPU_FLASH_BQ", 512),
-            block_k=_env_block("PADDLE_TPU_FLASH_BK", block_k))
+            block_q=_FLASH_BLOCK, block_k=block_k)
         return _per_shard(kern, q, k, v, head_dim=1)
     return flash_attention(
         q, k, v, causal=causal, scale=scale, lengths=lengths,
@@ -989,21 +975,15 @@ def _per_shard(kern, q, k, v, head_dim):
                          out_specs=spec, check_vma=False)(q, k, v)
 
 
-def _env_block(var: str, default: int) -> int:
-    """Env-tunable Pallas block size: must be a power-of-two >= 128
-    (TPU lane granularity; _fit_block halves from here). Fails fast with
-    the variable name so a bad sweep value doesn't surface as a cryptic
-    mid-trace error."""
-    raw = os.environ.get(var)
-    if raw is None:
-        return int(default)
-    try:
-        val = int(raw)
-    except ValueError:
-        raise ValueError("%s=%r is not an integer" % (var, raw))
+def block_attr(op: str, name: str, val) -> int:
+    """An op's block-size attribute as the kernels take it: a power of
+    two >= 128 (TPU lane granularity; `_fit_block` halves from here).
+    An attribute is input: a bad one fails here, naming the op, and not
+    as a cryptic error in the middle of a trace."""
+    val = int(val)
     if val < 128 or val & (val - 1):
         raise ValueError(
-            "%s=%d must be a power of two >= 128" % (var, val))
+            "%s: %s=%d must be a power of two >= 128" % (op, name, val))
     return val
 
 
@@ -1142,8 +1122,7 @@ def prefill_attention(q, k, v, lengths=None, window=0, scale=None, name=None,
         scale = 1.0 / math.sqrt(dq)
     group = h // k.shape[2]
 
-    block_q = _fit_block(t, _env_block("PADDLE_TPU_FLASH_BQ", 512))
-    block_k = _fit_block(t, _env_block("PADDLE_TPU_FLASH_BK", 512))
+    block_q = block_k = _fit_block(t, _FLASH_BLOCK)
     out, lse = _mha_fwd_call_bthd(
         flash_operand(q * jnp.asarray(scale, q.dtype)),
         flash_operand(k, group), flash_operand(v, group), h, True, block_q, block_k, interpret,
@@ -1194,16 +1173,8 @@ def _ring_attention_op(ctx):
     seed = (jax.random.key_data(ctx.rng()).astype(jnp.uint32)
             if dropout_rate else None)
     # per-rotation-step KV sub-chunking (transient-memory bound; see
-    # parallel/ring_attention.py): op attr, overridable per run for
-    # on-hardware sweeps; PADDLE_TPU_RING_CHUNK=0 means auto/whole-block
-    chunk = ctx.attr("chunk", None)
-    env_chunk = os.environ.get("PADDLE_TPU_RING_CHUNK")
-    if env_chunk:
-        try:
-            chunk = int(env_chunk) or None
-        except ValueError:
-            raise ValueError(
-                "PADDLE_TPU_RING_CHUNK=%r is not an integer" % env_chunk)
+    # parallel/ring_attention.py); None or 0 means auto/whole-block
+    chunk = int(ctx.attr("chunk", None) or 0) or None
     mesh = current_trace_mesh()
     if (mesh is not None and sp_axis in mesh.axis_names
             and mesh.shape[sp_axis] > 1):

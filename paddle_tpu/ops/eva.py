@@ -51,7 +51,7 @@ from jax import lax
 
 from . import decode_stream as _DS
 from . import kv_cache as _KV
-from .attention import (_env_block, _fit_block, _mha_fwd_call_bthd,
+from .attention import (_FLASH_BLOCK, _fit_block, _mha_fwd_call_bthd,
                         _use_pallas, flash_operand)
 from .registry import register_op
 
@@ -121,8 +121,8 @@ def _flash(q, k, v, lengths, causal, interpret):
     q-blocks wholly past ``lengths`` give zeros (out and lse)."""
     b, t, h, d = q.shape
     tk = k.shape[1]
-    block_q = _fit_block(t, _env_block("PADDLE_TPU_FLASH_BQ", 512))
-    block_k = _fit_block(tk, _env_block("PADDLE_TPU_FLASH_BK", 512))
+    block_q = _fit_block(t, _FLASH_BLOCK)
+    block_k = _fit_block(tk, _FLASH_BLOCK)
     out, lse = _mha_fwd_call_bthd(
         flash_operand(q), flash_operand(k), flash_operand(v), h, causal,
         block_q, block_k, interpret, name=EVA_PREFILL, lengths=lengths,

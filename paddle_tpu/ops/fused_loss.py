@@ -53,19 +53,6 @@ from .registry import register_op
 _NEG = -1e30
 
 
-def _unroll_chunks(nblk: int) -> bool:
-    """Sweep lever: PADDLE_TPU_LMHEAD_UNROLL=N
-    unrolls the vocab-chunk loop when nblk <= N. Off by default — the
-    rolled loop compiles faster and the win is hardware-dependent."""
-    import os
-
-    try:
-        limit = int(os.environ.get("PADDLE_TPU_LMHEAD_UNROLL", "0"))
-    except ValueError:
-        limit = 0
-    return 0 < nblk <= limit
-
-
 def _vary_like(val, *refs):
     """Inside shard_map, loop carries initialized from literals are
     unvaried over the manual mesh axes while the loop body mixes in
@@ -233,15 +220,7 @@ def _lm_head_fwd(block_v, transpose_w, axis, x, w, b, labels):
                  (jnp.full((n,), _NEG, jnp.float32),
                   jnp.zeros((n,), jnp.float32),
                   jnp.zeros((n,), jnp.float32)))
-    if _unroll_chunks(nblk):
-        # unrolled: XLA overlaps chunk matmuls with the next chunk's
-        # weight DMA instead of serializing through a while-loop barrier
-        carry = init
-        for j in range(nblk):
-            carry = body(j, carry)
-        m, s, picked = carry
-    else:
-        m, s, picked = lax.fori_loop(0, nblk, body, init)
+    m, s, picked = lax.fori_loop(0, nblk, body, init)
     if axis is not None:
         # the ranks' row statistics meet once, after the loop
         m_all = lax.pmax(m, axis)
@@ -293,13 +272,7 @@ def _lm_head_bwd(block_v, transpose_w, axis, res, g):
                  (jnp.zeros((n, d), jnp.float32),
                   jnp.zeros(dw_shape, jnp.float32),
                   jnp.zeros((pv,), jnp.float32)))
-    if _unroll_chunks(nblk):
-        carry = init
-        for j in range(nblk):
-            carry = body(j, carry)
-        dx, dw, db = carry
-    else:
-        dx, dw, db = lax.fori_loop(0, nblk, body, init)
+    dx, dw, db = lax.fori_loop(0, nblk, body, init)
     dw = dw[:v] if transpose_w else dw[:, :v]
     # as written dx is summed over the tensor axis in float32 and rounded
     # after (the TPU compiler moves the rounding first: PERF.md §6, PR 34)
@@ -315,18 +288,16 @@ _lm_head_loss.defvjp(_lm_head_fwd, _lm_head_bwd)
 def _fused_lm_head_loss(ctx):
     """Inputs X: (..., D), W: (D, V), Bias: (V,) optional, Label: (..., 1)
     or (...,) int. Output Loss: (N, 1) fp32 per-token loss, N = prod of
-    X's leading dims. Attr block_v: vocab chunk size (multiple of 128).
+    X's leading dims. Attr block_v: vocab chunk size (a power of two >= 128).
     Attr transpose_w: W is (V, D) — the tied-embedding layout, where W is
     the token-embedding table itself used in place."""
-    from .attention import _env_block
+    from .attention import block_attr
 
     x = ctx.input("X")
     w = ctx.input("W")
     labels = ctx.input("Label")
     transpose_w = bool(ctx.attr("transpose_w", False))
-    # env override for on-hardware sweeps,
-    # validated like the flash-attention block knobs
-    block_v = _env_block("PADDLE_TPU_LMHEAD_BLOCK",
+    block_v = block_attr("fused_lm_head_loss", "block_v",
                          ctx.attr("block_v", 4096))
     d = x.shape[-1]
     xf = x.reshape(-1, d)

@@ -232,48 +232,6 @@ def _mm2d(x2, y2):
     return out.astype(x2.dtype)
 
 
-@jax.custom_vjp
-def _mm2d_dwt(x2, y2):
-    """Same forward as _mm2d; the backward computes dY in TRANSPOSED form
-    (dY^T = g^T @ X, then a weight-sized transpose) instead of X^T @ g.
-    Sweep lever PADDLE_TPU_MUL_DWT=1: the profiled FFN-hidden relayout
-    copies (~4.7% of LM step time) are XLA's layout
-    assignment materializing a column-major view of the (B, T, d_inner)
-    activation for exactly the X^T @ g contraction; flipping the operand
-    order moves any relayout to the 4x-smaller gradient tensor, at the
-    cost of one (in, out)-sized transpose that fuses into the weight
-    update. Pure schedule change — identical math either way."""
-    return _mm2d(x2, y2)
-
-
-def _mm2d_dwt_fwd(x2, y2):
-    return _mm2d(x2, y2), (x2, y2)
-
-
-def _mm2d_dwt_bwd(res, g):
-    # a device-UNvaried y2 (replicated weight under a shard_map axis)
-    # needs its cotangent psum'd over the axes g/x2 vary on — same rule
-    # as fused_loss._grad_vma_like (GSPMD's grad all-reduce, manual mesh)
-    from .fused_loss import _grad_vma_like
-
-    x2, y2 = res
-    gx = g.astype(x2.dtype)
-    dx = (jnp.matmul(gx, y2.T, preferred_element_type=jnp.float32)
-          .astype(x2.dtype))
-    dyt = jnp.matmul(gx.T, x2, preferred_element_type=jnp.float32)
-    return (_grad_vma_like(dx, x2),
-            _grad_vma_like(dyt.T.astype(y2.dtype), y2))
-
-
-_mm2d_dwt.defvjp(_mm2d_dwt_fwd, _mm2d_dwt_bwd)
-
-
-def _mul_dwt_enabled():
-    import os
-
-    return os.environ.get("PADDLE_TPU_MUL_DWT", "0") == "1"
-
-
 def _mul_compute(x, y, xnc, ync):
     """The reference's `mul` computation: flatten X to 2-D by
     x_num_col_dims then matmul (reference: paddle/fluid/operators/
@@ -285,8 +243,7 @@ def _mul_compute(x, y, xnc, ync):
     xs, ys = x.shape, y.shape
     x2 = x.reshape((_math.prod(xs[:xnc]) if xnc else 1, -1))
     y2 = y.reshape((_math.prod(ys[:ync]), -1))
-    out = _mm2d_dwt(x2, y2) if _mul_dwt_enabled() else _mm2d(x2, y2)
-    return out.reshape(xs[:xnc] + ys[ync:])
+    return _mm2d(x2, y2).reshape(xs[:xnc] + ys[ync:])
 
 
 @register_op("mul")

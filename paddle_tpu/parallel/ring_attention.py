@@ -86,7 +86,7 @@ def full_attention(q, k, v, causal: bool = False, scale: Optional[float] = None,
         scale = q.shape[-1] ** -0.5
     B, H, Tq, _ = q.shape
     Tk = k.shape[2]
-    # match the ring path's score numerics exactly (ADVICE r4): fold the
+    # match the ring path's score numerics exactly: fold the
     # scale into q in the INPUT dtype (as _ring_fwd_impl does) and
     # accumulate the einsum in f32 — both halves matter for bf16 parity
     qs = (q * jnp.asarray(scale, q.dtype)).astype(q.dtype)

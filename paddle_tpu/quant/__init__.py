@@ -21,8 +21,7 @@ integer-arithmetic-only inference in the Jacob et al. CVPR'18 mold):
    KV slabs into int8 with per-(slot, position) scales (2x sequences
    per slab budget).
 4. **Verify** (``parity.py``): quantized-vs-float logits tolerance and
-   task-metric delta, the same A/B discipline as bench.py's O1-vs-O2
-   checks; ``tools/bench_quant.py`` is the measurement instrument.
+   task-metric delta: same feeds through both arms, one report.
 """
 from .calibrate import (  # noqa: F401
     CalibrationTable, activation_targets, calibrate, quantizable_targets,

@@ -1,7 +1,6 @@
 """Quantized-vs-float parity: the gate every int8 deployment runs.
 
-Modeled on bench.py's O1-vs-O2 loss sanity checks: same feeds through
-both serving paths, compared at two levels —
+Same feeds through both serving paths, compared at two levels —
 
 - **logits tolerance**: max/mean abs difference across every fetch (the
   raw numeric drift the int8 rounding introduced);
@@ -13,9 +12,7 @@ both serving paths, compared at two levels —
 ``parity_report`` drives two Predictors (or model dirs) and returns one
 JSON-able dict; the observed ``max_abs_diff`` also lands on the
 ``paddle_tpu_quant_parity_max_abs_diff`` gauge so a serving fleet can
-alert on quantization drift. ``tools/bench_quant.py`` embeds the same
-report in every bench line — a measurement that breaks parity reports
-it instead of banking a bogus speedup.
+alert on quantization drift.
 """
 from __future__ import annotations
 

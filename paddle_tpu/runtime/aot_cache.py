@@ -87,21 +87,13 @@ DEFAULT_MAX_BYTES = 1 << 30  # 1 GiB
 # Env vars consumed INSIDE op lowering (trace time): they change the HLO
 # without changing the Program fingerprint, so they must be part of the
 # key or a cached executable could silently carry the wrong kernel
-# configuration into a process with different knobs. Model-CONSTRUCTION
-# knobs (PADDLE_TPU_ATTN_BTHD, PADDLE_TPU_FUSED_QKV, ...) change the
-# program itself and are already covered by the fingerprint.
+# selection into a process that set them otherwise. The two compile
+# levers of docs/internals.md's table (tests/test_env_switches.py holds
+# the two lists equal); what a kernel is given is otherwise an op
+# attribute, which the Program fingerprint covers.
 _TRACE_ENV = (
-    "PADDLE_TPU_ATTN_BLOCK_K",
-    "PADDLE_TPU_DIM_SEMANTICS",
-    "PADDLE_TPU_FLASH_BQ",
-    "PADDLE_TPU_FLASH_BK",
-    "PADDLE_TPU_FLASH_FUSED_BWD",
     "PADDLE_TPU_FORCE_PALLAS",
     "PADDLE_TPU_NO_PALLAS",
-    "PADDLE_TPU_LMHEAD_BLOCK",
-    "PADDLE_TPU_LMHEAD_UNROLL",
-    "PADDLE_TPU_MUL_DWT",
-    "PADDLE_TPU_RING_CHUNK",
 )
 
 

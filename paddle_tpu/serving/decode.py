@@ -1039,9 +1039,6 @@ class DecodePredictor:
         # build their prefill graph with ring attention (sequence-
         # parallel under an sp mesh; exact-attention fallback on one
         # device, so the knob is portable). None = always dense.
-        env_ring = os.environ.get("PADDLE_TPU_RING_PREFILL_MIN_SEQ")
-        if ring_prefill_min_seq is None and env_ring:
-            ring_prefill_min_seq = int(env_ring)
         self.ring_prefill_min_seq = (None if not ring_prefill_min_seq
                                      else int(ring_prefill_min_seq))
         self._scope = Scope()
@@ -2093,9 +2090,9 @@ class DecodeServer:
         self._http_thread = None
         self._seed_ctr = 0
         # diagnostic: per-iteration active-slot counts (the continuous-
-        # vs-static fill story; bench_decode reads it). BOUNDED: a
-        # long-lived server must not grow an entry per decode step
-        # forever — 100k covers any bench window
+        # vs-static fill story; benchmark/lib/run_serve.py reads it).
+        # BOUNDED: a long-lived server must not grow an entry per decode
+        # step forever — 100k covers any benchmark window
         import collections
 
         self.step_active_counts: "collections.deque" = collections.deque(

@@ -21,7 +21,7 @@ assumed), and on small graphs bitwise-identical too — but XLA's CPU
 GEMM may pick a different reduction order for a different batch
 dimension, so large matmul chains can drift by reduction-order ulps
 (measured ≤3e-6 max-abs on the 200-wide mnist MLP, batch 9-in-16;
-tools/bench_transpile.py reports the observed bound per run). That is
+tests/test_passes.py holds the bound). That is
 the same numerical class as running the identical rows at a different
 batch size by hand; the parity gates compare padded-path outputs at
 ulp tolerance and everything else exactly.

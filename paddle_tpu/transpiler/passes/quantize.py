@@ -27,8 +27,8 @@ Per rewritten op:
   values untouched).
 
 Tolerance parity, not bit parity: quantization rounds by design
-(``exact=False``); ``quant/parity.py`` and ``tools/bench_quant.py``
-gate the drift against float serving.
+(``exact=False``); ``quant/parity.py`` gates the drift against float
+serving (``tests/test_quant.py``, ``tests/test_quant_decode.py``).
 """
 from __future__ import annotations
 

@@ -129,9 +129,9 @@ def test_env_mismatch_is_a_miss_not_a_load(tmp_path, monkeypatch):
     d = str(tmp_path / "cache")
     _run_once(d)
     n1 = len(_blobs(d))
-    # a trace-affecting env knob changes the key: the existing entries
-    # are unreachable (miss -> fresh compile + new entries), NOT loaded
-    monkeypatch.setenv("PADDLE_TPU_LMHEAD_BLOCK", "2048")
+    # a trace-time lever changes the key: the existing entries are
+    # unreachable (miss -> fresh compile + new entries), NOT loaded
+    monkeypatch.setenv("PADDLE_TPU_NO_PALLAS", "1")
     warm0 = obs.AOT_COMPILE_MS.stats(path="warm", kind="run")["count"]
     miss0 = obs.CACHE_MISSES.total()
     _run_once(d)
@@ -260,9 +260,7 @@ def test_second_process_reuses_training_executable(tmp_path):
 
     def child():
         proc = subprocess.run(
-            [sys.executable,
-             os.path.join(_REPO, "tools", "bench_coldstart.py"),
-             "--child", "--config", "mlp-tiny", "--loop-steps", "2"],
+            [sys.executable, os.path.join(_REPO, "tests", "_aot_child.py")],
             capture_output=True, text=True, timeout=600, env=env,
             cwd=_REPO)
         assert proc.returncode == 0, proc.stderr[-3000:]
@@ -319,9 +317,7 @@ def test_concurrent_cold_compile_same_key(tmp_path):
     d = str(tmp_path / "cache")
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                PADDLE_TPU_AOT_CACHE_DIR=d, PADDLE_TPU_AOT_CACHE="1")
-    cmd = [sys.executable,
-           os.path.join(_REPO, "tools", "bench_coldstart.py"),
-           "--child", "--config", "mlp-tiny", "--loop-steps", "2"]
+    cmd = [sys.executable, os.path.join(_REPO, "tests", "_aot_child.py")]
 
     procs = [subprocess.Popen(cmd, stdout=subprocess.PIPE,
                               stderr=subprocess.PIPE, text=True, env=env,

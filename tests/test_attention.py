@@ -1,4 +1,6 @@
 """Fused (flash) attention parity vs naive attention — values and grads."""
+import os
+
 import numpy as np
 import pytest
 
@@ -254,41 +256,10 @@ def test_fused_attention_bthd_layout_op_parity():
                                atol=1e-6)
 
 
-def test_transformer_lm_bthd_env_parity(monkeypatch):
-    """The model builds transpose-free graphs under PADDLE_TPU_ATTN_BTHD=1
-    (default); both layouts must train to identical losses on CPU."""
-    from paddle_tpu import models, optimizer
-
-    def train(flag):
-        monkeypatch.setenv("PADDLE_TPU_ATTN_BTHD", flag)
-        mp, sp = fluid.Program(), fluid.Program()
-        mp.random_seed = sp.random_seed = 5
-        scope = fluid.Scope()
-        with fluid.scope_guard(scope), fluid.program_guard(mp, sp):
-            with fluid.unique_name.guard():
-                ids = layers.data(name="ids", shape=[2, 64], dtype="int64",
-                                  append_batch_size=False)
-                labels = layers.data(name="labels", shape=[2, 64],
-                                     dtype="int64", append_batch_size=False)
-                loss, _ = models.transformer.transformer_lm(
-                    ids, labels, vocab_size=128, n_layer=2, n_head=2,
-                    d_model=32, d_inner=64, max_len=64)
-                optimizer.Adam(learning_rate=1e-3).minimize(loss)
-            exe = fluid.Executor(fluid.CPUPlace())
-            exe.run(sp)
-            r = np.random.RandomState(0)
-            feed = {"ids": r.randint(0, 128, (2, 64)).astype(np.int64),
-                    "labels": r.randint(0, 128, (2, 64)).astype(np.int64)}
-            vals = [float(exe.run(mp, feed=feed, fetch_list=[loss])[0])
-                    for _ in range(3)]
-        return vals
-
-    np.testing.assert_allclose(train("0"), train("1"), rtol=1e-5, atol=1e-6)
-
-
 @pytest.mark.parametrize("causal", [False, True])
 def test_fused_bwd_matches_split_bwd_bhtd(causal, monkeypatch):
     """Single-pass fused backward == split dq/dkv backward (BHTD)."""
+    from paddle_tpu.ops import attention as A
     from paddle_tpu.ops.attention import pallas_flash_attention
 
     r = np.random.RandomState(11)
@@ -303,10 +274,10 @@ def test_fused_bwd_matches_split_bwd_bhtd(causal, monkeypatch):
             return jnp.sum(jnp.sin(o))
         return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
-    monkeypatch.delenv("PADDLE_TPU_FLASH_FUSED_BWD", raising=False)
-    g_split = grads()
-    monkeypatch.setenv("PADDLE_TPU_FLASH_FUSED_BWD", "1")
     g_fused = grads()
+    # a budget nothing fits: the split pair
+    monkeypatch.setattr(A, "_FUSED_BWD_VMEM_BUDGET", 1)
+    g_split = grads()
     for a, b in zip(g_fused, g_split):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-5, atol=2e-6)
@@ -315,6 +286,7 @@ def test_fused_bwd_matches_split_bwd_bhtd(causal, monkeypatch):
 @pytest.mark.parametrize("causal", [False, True])
 def test_fused_bwd_matches_split_bwd_bthd(causal, monkeypatch):
     """Single-pass fused backward == split backward (BTHD layout)."""
+    from paddle_tpu.ops import attention as A
     from paddle_tpu.ops.attention import pallas_flash_attention_bthd
 
     r = np.random.RandomState(12)
@@ -329,10 +301,10 @@ def test_fused_bwd_matches_split_bwd_bthd(causal, monkeypatch):
             return jnp.sum(jnp.sin(o))
         return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
-    monkeypatch.delenv("PADDLE_TPU_FLASH_FUSED_BWD", raising=False)
-    g_split = grads()
-    monkeypatch.setenv("PADDLE_TPU_FLASH_FUSED_BWD", "1")
     g_fused = grads()
+    # a budget nothing fits: the split pair
+    monkeypatch.setattr(A, "_FUSED_BWD_VMEM_BUDGET", 1)
+    g_split = grads()
     for a, b in zip(g_fused, g_split):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-5, atol=2e-6)
@@ -351,31 +323,40 @@ def test_fused_bwd_vmem_gate_boundary():
     assert not _fused_bwd_fits(4096, 128, 4)   # f32 rows: 12 MB+4 MB acc
 
 
-def test_fused_bwd_over_budget_is_an_error_naming_the_shape(monkeypatch):
-    """With PADDLE_TPU_FLASH_FUSED_BWD=1 but a footprint over budget the
-    dispatch raises and names the shape — it neither runs the fused
-    kernel nor swaps in the split one. Shrink the budget so a small T
-    trips the gate."""
+@pytest.mark.parametrize("layout", ["bthd", "bhtd"])
+@pytest.mark.parametrize("t,d,dtype,fused", [
+    (2048, 128, "bfloat16", True),    # both training cells' shape: 4 MB
+    (4096, 128, "bfloat16", True),    # the measured pass: 8 MB
+    (4096, 128, "float32", False),    # AT the 12 MB budget: split
+    (8192, 128, "bfloat16", False),   # the measured failure: 16 MB
+])
+def test_backward_chosen_by_fit(layout, t, d, dtype, fused, monkeypatch):
+    """What `fused_attention` dispatches to for a TPU, with no option
+    set: the backward's kernels are chosen by `_fused_bwd_fits` alone,
+    the fused one where its residents fit, the split pair where they do
+    not, and nothing raises. Traced on shapes (no compile, nothing
+    runs); the kernels by the names a device trace shows."""
     from paddle_tpu.ops import attention as A
 
-    r = np.random.RandomState(13)
-    q, k, v = (jnp.asarray(r.randn(1, 256, 2, 128), jnp.float32) * 0.1
-               for _ in range(3))
+    for name in [n for n in os.environ if n.startswith("PADDLE_TPU_FLASH_")]:
+        monkeypatch.delenv(name)
+    monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
+    shape = (1, t, 2, d) if layout == "bthd" else (1, 2, t, d)
+    x = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
+    attend = A._attention_bthd if layout == "bthd" else A._attention_bhtd
 
-    def loss(q, k, v):
-        o = A.pallas_flash_attention_bthd(q, k, v, causal=True,
-                                          block_q=128, block_k=128,
-                                          interpret=True)
-        return jnp.sum(jnp.sin(o))
+    def grads(q, k, v):
+        out, vjp = jax.vjp(
+            lambda q, k, v: attend(q, k, v, None, True, None, 0.0, 512,
+                                   None), q, k, v)
+        return vjp(out)
 
-    monkeypatch.setenv("PADDLE_TPU_FLASH_FUSED_BWD", "1")
-    monkeypatch.setattr(A, "_FUSED_BWD_VMEM_BUDGET", 1)  # force the gate
-
-    def _boom(*a, **k):
-        raise AssertionError("fused kernel dispatched despite VMEM gate")
-    monkeypatch.setattr(A, "_mha_bwd_fused_kernel", _boom)
-    with pytest.raises(ValueError, match="seq_k=256, d_head=128"):
-        jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    kernels = [str(e.source_info.name_stack)
+               for e in jax.make_jaxpr(grads)(x, x, x).jaxpr.eqns
+               if e.primitive.name == "pallas_call"]
+    bwd = [A.FLASH_BWD] if fused else [A.FLASH_BWD_DQ, A.FLASH_BWD_DKV]
+    assert kernels == ["jvp(%s)" % A.FLASH_FWD] + [
+        "transpose(jvp(%s))" % n for n in bwd]
 
 
 # -- the serving prefills' forward-only entry (`prefill_attention`) ------------
@@ -418,8 +399,7 @@ def test_prefill_attention_kernel_gives_the_live_rows_of_the_lax_form(
     q, k, v = (jnp.asarray(r.normal(size=s), dtype) for s in
                ((2, t, h, dq), (2, t, hkv, dq), (2, t, hkv, dv)))
     lengths = None if lens is None else jnp.asarray(lens, jnp.int32)
-    monkeypatch.setenv("PADDLE_TPU_FLASH_BQ", "128")
-    monkeypatch.setenv("PADDLE_TPU_FLASH_BK", "128")
+    monkeypatch.setattr(A, "_FLASH_BLOCK", 128)
     got = A.prefill_attention(q, k, v, lengths, window=window,
                               interpret=True)
     assert got.shape == (2, t, h, dv) and got.dtype == q.dtype

@@ -26,8 +26,6 @@ TINY = dict(chip_smoke.FULL, vocab=256, n_layer=2, n_head=2, d_model=256,
 def _outputs_in_tmp(monkeypatch, tmp_path):
     monkeypatch.setattr(chip_smoke, "_HERE", str(tmp_path))
     monkeypatch.setattr(chip_smoke, "OUT_DIR", str(tmp_path / "out"))
-    # the train/parallel phases switch the fused backward on for good
-    monkeypatch.setenv("PADDLE_TPU_FLASH_FUSED_BWD", "0")
 
 
 def test_script_refuses_on_cpu():

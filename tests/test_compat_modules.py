@@ -325,7 +325,7 @@ def test_pipe_reader_abandoned_stream_terminates(tmp_path):
 
 
 def test_operator_factory_inplace_param_out():
-    # ADVICE r2: an UPPERCASE output slot bound to a var that already holds
+    # an UPPERCASE output slot bound to a var that already holds
     # data (in-place update shape) must still be classified as an output.
     import numpy as np
 
@@ -348,7 +348,7 @@ def test_operator_factory_inplace_param_out():
 
 
 def test_go_multiple_failures_aggregate():
-    # ADVICE r2: with >1 concurrent failure, join() raises an aggregate
+    # with >1 concurrent failure, join() raises an aggregate
     # naming every failed task instead of dropping all but the first.
     import pytest
 

@@ -380,7 +380,7 @@ def test_fake_quantize_abs_max():
                   "Scale": np.array([scale], np.float32)},
                  attrs={"max_range": 127.0})["Out"]
     np.testing.assert_allclose(np.asarray(deq), x, atol=scale / 127.0)
-    # ADVICE r2: abs_max with the window state wired (as reference QAT
+    # abs_max with the window state wired (as reference QAT
     # graphs declare it) zero-fills OutScales/OutCurrentIter
     got = run_op("fake_quantize",
                  {"X": x, "InScales": np.ones(4, np.float32),

@@ -42,31 +42,6 @@ def test_lm_head_loss_matches_naive_fwd_and_grad():
                                    rtol=1e-4, atol=1e-5)
 
 
-def test_lm_head_loss_unrolled_matches_rolled(monkeypatch):
-    """PADDLE_TPU_LMHEAD_UNROLL (sweep lever) is a pure schedule change:
-    unrolled chunk loop == fori_loop, forward and grads."""
-    r = np.random.RandomState(2)
-    n, d, v = 8, 16, 96
-    x = jnp.asarray(r.randn(n, d), jnp.float32)
-    w = jnp.asarray(r.randn(d, v) * 0.1, jnp.float32)
-    b = jnp.asarray(r.randn(v) * 0.1, jnp.float32)
-    labels = jnp.asarray(r.randint(0, v, (n,)), jnp.int32)
-
-    def f(x, w, b):
-        return jnp.mean(lm_head_loss(32, x, w, b, labels))
-
-    base = f(x, w, b)
-    gbase = jax.grad(f, argnums=(0, 1, 2))(x, w, b)
-    monkeypatch.setenv("PADDLE_TPU_LMHEAD_UNROLL", "16")
-    unr = f(x, w, b)
-    gunr = jax.grad(f, argnums=(0, 1, 2))(x, w, b)
-    np.testing.assert_allclose(np.asarray(unr), np.asarray(base),
-                               rtol=1e-6, atol=1e-6)
-    for a, e in zip(gunr, gbase):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(e),
-                                   rtol=1e-5, atol=1e-6)
-
-
 def test_lm_head_loss_transpose_w_matches_naive():
     """transpose_w=True reads a (V, D) table in place: same loss and
     grads as the naive x @ w^T head (the tied-embedding layout)."""

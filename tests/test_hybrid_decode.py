@@ -17,16 +17,6 @@ import jax.numpy as jnp
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _ROOT)
-# `benchmark/` has no __init__.py, and a plain module of a name beats
-# such a directory wherever it is on sys.path: with `tools/` there (other
-# tests put it there) `import benchmark` finds `tools/benchmark.py`. Bind
-# the name to the directory for this process.
-if os.path.join(_ROOT, "benchmark") not in list(getattr(
-        sys.modules.get("benchmark"), "__path__", [])):
-    import types
-
-    sys.modules["benchmark"] = types.ModuleType("benchmark")
-    sys.modules["benchmark"].__path__ = [os.path.join(_ROOT, "benchmark")]
 
 import paddle_tpu as fluid  # noqa: E402
 from paddle_tpu import observability as obs  # noqa: E402

@@ -23,13 +23,6 @@ import jax.numpy as jnp
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _ROOT)
-# see tests/test_hybrid_decode.py: bind `benchmark` to the directory
-if os.path.join(_ROOT, "benchmark") not in list(getattr(
-        sys.modules.get("benchmark"), "__path__", [])):
-    import types
-
-    sys.modules["benchmark"] = types.ModuleType("benchmark")
-    sys.modules["benchmark"].__path__ = [os.path.join(_ROOT, "benchmark")]
 
 import paddle_tpu as fluid  # noqa: E402
 from paddle_tpu import observability as obs  # noqa: E402

@@ -150,13 +150,17 @@ def test_ring_lm_clone_for_test_disables_attention_dropout():
         assert t1 != e1
 
 
-def _run_sp(monkeypatch, chunk_env, seed=3):
-    """One seeded training step on the 4-device sp mesh with
-    PADDLE_TPU_RING_CHUNK set — the env override must reach the CHUNKED
-    ring path (on a plain single-device Executor the ring op falls back
-    to full_attention and the env value is never consumed; ADVICE r4)."""
-    monkeypatch.setenv("PADDLE_TPU_RING_CHUNK", chunk_env)
+def _run_sp(chunk, seed=3):
+    """One seeded training step on the 4-device sp mesh with the ring
+    ops' `chunk` attribute set: the value must reach the CHUNKED ring
+    path (on a plain single-device Executor the ring op falls back to
+    full_attention and the chunk is never consumed)."""
     main, startup, scope, loss = _build(use_ring=True, seed=seed)
+    rings = [op for op in main.global_block().ops
+             if op.type == "ring_attention"]
+    assert rings
+    for op in rings:
+        op.set_attr("chunk", chunk)
     mesh = make_mesh([4], ("sp",), devices=jax.devices()[:4])
     with fluid.scope_guard(scope):
         fluid.Executor(fluid.CPUPlace()).run(startup)
@@ -166,21 +170,13 @@ def _run_sp(monkeypatch, chunk_env, seed=3):
         return float(pexe.run(feed=_feed(), fetch_list=[loss])[0])
 
 
-def test_ring_chunk_env_override(monkeypatch):
-    """PADDLE_TPU_RING_CHUNK through the op route on an sp mesh: 0 means
-    auto (not a crash), an explicit chunk is numerically invisible, junk
-    names the variable (code-review regression)."""
-    v0 = _run_sp(monkeypatch, "0")     # auto
+def test_ring_chunk_attribute():
+    """The ring op's `chunk` on an sp mesh: None and 0 mean auto (not a
+    crash), an explicit chunk is numerically invisible."""
+    v0 = _run_sp(None)     # auto, as the layer leaves it
     assert np.isfinite(v0)
-    v8 = _run_sp(monkeypatch, "8")     # T_local for seq 32 over 4 devices
+    assert _run_sp(0) == v0
+    v8 = _run_sp(8)        # T_local for seq 32 over 4 devices
     np.testing.assert_allclose(v8, v0, rtol=1e-5)  # chunking is invisible
-    v4 = _run_sp(monkeypatch, "4")     # genuine sub-chunking (2 per block)
+    v4 = _run_sp(4)        # genuine sub-chunking (2 per block)
     np.testing.assert_allclose(v4, v0, rtol=1e-5)
-
-    monkeypatch.setenv("PADDLE_TPU_RING_CHUNK", "abc")
-    main, startup, scope, loss = _build(use_ring=True, seed=3)
-    with fluid.scope_guard(scope):
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup)
-        with pytest.raises(Exception, match="PADDLE_TPU_RING_CHUNK"):
-            exe.run(main, feed=_feed(), fetch_list=[loss])

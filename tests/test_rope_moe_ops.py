@@ -15,12 +15,6 @@ import jax.numpy as jnp
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _ROOT)
-if os.path.join(_ROOT, "benchmark") not in list(getattr(
-        sys.modules.get("benchmark"), "__path__", [])):
-    import types
-
-    sys.modules["benchmark"] = types.ModuleType("benchmark")
-    sys.modules["benchmark"].__path__ = [os.path.join(_ROOT, "benchmark")]
 
 import paddle_tpu as fluid  # noqa: E402
 from paddle_tpu import layers  # noqa: E402
@@ -420,20 +414,14 @@ def test_attn_window_reference_is_the_banded_softmax():
         rtol=1e-5, atol=1e-6)
 
 
-def test_attn_window_kernel_skips_and_masks_like_the_reference():
+def test_attn_window_kernel_skips_and_masks_like_the_reference(monkeypatch):
     """The Pallas forward kernel in interpret mode at 512 positions,
     blocks of 128, window 200: q-blocks 2 and 3 start their loop past
     block 0 (skipped whole) and mask inside the blocks they read; at
     what the kernel's bfloat16 operands give (float32 sums)."""
     q, k, v = _qkv(512, 4, 2, 128, b=1)
-    os.environ["PADDLE_TPU_FLASH_BQ"] = os.environ[
-        "PADDLE_TPU_FLASH_BK"] = "128"
-    try:
-        got = attn_ops.prefill_attention(q, k, v, window=200,
-                                         interpret=True)
-    finally:
-        del os.environ["PADDLE_TPU_FLASH_BQ"], os.environ[
-            "PADDLE_TPU_FLASH_BK"]
+    monkeypatch.setattr(attn_ops, "_FLASH_BLOCK", 128)
+    got = attn_ops.prefill_attention(q, k, v, window=200, interpret=True)
     want = attn_ops.prefill_attention_reference(q, k, v, 200)
     np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
     assert np.linalg.norm(got - want) < 5e-3 * np.linalg.norm(want)

@@ -163,7 +163,8 @@ def test_megatron_plan_tied_shards_the_table_by_rows():
 def _mesh_step_text(tied, vocab=3 * BLOCK_V * 2, d_model=32):
     """Compiled text of a tiny transformer_lm + Adam step on a 2x2
     dp x mp mesh under the megatron plan, as ParallelExecutor jits it;
-    three vocabulary chunks a rank (the caller sets the chunk length)."""
+    three vocabulary chunks a rank (the head op's `block_v` set to
+    BLOCK_V)."""
     from paddle_tpu.executor import analyze_state, build_step_fn
 
     b, t = 4, 16
@@ -178,6 +179,9 @@ def _mesh_step_text(tied, vocab=3 * BLOCK_V * 2, d_model=32):
             ids, lbl, vocab_size=vocab, n_layer=1, n_head=2,
             d_model=d_model, d_inner=64, max_len=t, tie_embeddings=tied)
         fluid.optimizer.Adam(learning_rate=1e-3).minimize(loss)
+    head, = [op for op in main_p.global_block().ops
+             if op.type == "fused_lm_head_loss"]
+    head.set_attr("block_v", BLOCK_V)
     mesh = make_mesh([2, 2], ("dp", "mp"))
     plan = megatron_transformer_plan(mesh, tied=tied)
     sds = jax.ShapeDtypeStruct
@@ -208,13 +212,11 @@ def _mesh_step_text(tied, vocab=3 * BLOCK_V * 2, d_model=32):
 
 
 @pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
-def test_mesh_step_keeps_collectives_out_of_the_head_loops(tied,
-                                                           monkeypatch):
+def test_mesh_step_keeps_collectives_out_of_the_head_loops(tied):
     """The compiled 2x2 step as ParallelExecutor jits it: both chunk loops
     are there, no all-reduce, all-gather or reduce-scatter lies inside a
     loop body, and nothing gathers the (V, D) table (or its transpose):
     each mp rank reads and updates its own rows."""
-    monkeypatch.setenv("PADDLE_TPU_LMHEAD_BLOCK", str(BLOCK_V))
     text, (v, d) = _mesh_step_text(tied)
     assert len(while_bodies(text)) >= 2, "the chunk loops were unrolled"
     found = collectives(text)

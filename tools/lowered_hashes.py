@@ -1,11 +1,14 @@
 """sha256 of the LOWERED text (StableHLO, no locations) of every serving
 cell's decode step and one prefill, and of one training step of the LM
 (``tests/test_tpu_compile.py``'s: chip_smoke.py's model at one layer,
-AMP O2, the differentiable flash kernels), lowered for a described
-``v5e:2x2`` with the Pallas paths steered on (``tests/
+AMP O2, the differentiable flash kernels; the backward is the one its
+shape gets with nothing set, the fused kernel, which is what both OPT
+training cells run: since PR 56 this line is of the program a cell
+hands the chip, where before it it was of the split pair unless the
+caller set the environment switch that PR removed), lowered for a
+described ``v5e:2x2`` with the Pallas paths steered on (``tests/
 test_tpu_compile_cells.py::_cell_predictor``): no chip, nothing
-compiled. Two trees whose tables
-agree hand the chip the same programs, Mosaic kernels included, so a
+compiled. Two trees whose tables agree hand the chip the same programs, Mosaic kernels included, so a
 refactoring of a kernel is checked here before a chip minute is spent.
 
 A Mosaic call carries its kernel as serialized MLIR, and that carries

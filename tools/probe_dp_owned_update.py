@@ -42,11 +42,8 @@ import time
 import numpy as np
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-# `tools/benchmark.py` would shadow the `benchmark` directory's modules;
 # `tests/hlo_text.py` reads compiled texts for the tests and for this
-sys.path[:] = [HERE, os.path.join(HERE, "tests")] + [
-    p for p in sys.path
-    if os.path.abspath(p or ".") != os.path.join(HERE, "tools")]
+sys.path[:0] = [HERE, os.path.join(HERE, "tests")]
 from hlo_text import _computations, replica_groups  # noqa: E402
 
 FORMS = ("whole", "moments", "weights", "plan")
