@@ -139,11 +139,17 @@ PREFILL_ATTN_TRACES = REGISTRY.counter(
 PREFILL_ATTN_FORMS = REGISTRY.counter(
     "paddle_tpu_prefill_attn_forms_total",
     "Traces of a serving prefill's causal attention that are not the "
-    "plain form, beside paddle_tpu_prefill_attn_traces_total: sink="
-    "learned (a scalar a query head joins the softmax's denominator: the "
-    "output times sigmoid(lse - sink)) | none, value_width=own (V's "
-    "heads narrower than q's and K's: 192 / 192 / 128) | query. Counted "
-    "when the op is traced: a program loaded from a cache adds 0")
+    "plain form (a window under the bucket, a sink, fewer key/value "
+    "heads than query heads, or V narrower than q and K), beside "
+    "paddle_tpu_prefill_attn_traces_total: sink=learned (a scalar a "
+    "query head joins the softmax's denominator: on the kernel path the "
+    "last write times sigmoid(lse - sink), inside the call) | none, "
+    "value_width=own (V's heads narrower than q's and K's: 192 / 192 / "
+    "128) | query, kv=own (K and V handed over at their own head count, "
+    "query head hi reading head hi // group: nothing repeated) | query "
+    "(one head count), block_k=the kernel's key block, which follows the "
+    "window (128 under a window of 128, else 512) | none (the lax form). "
+    "Counted when the op is traced: a program loaded from a cache adds 0")
 CACHE_HITS = REGISTRY.counter(
     "paddle_tpu_compile_cache_hits_total",
     "Compile-cache hits, by kind, program fingerprint, and "

@@ -214,11 +214,19 @@ def _window_mask(s, row0, col0, window):
 
 def _mha_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
                     block_k, seq_k, causal, pid_axis=1, window=0,
-                    mask_ref=None, len_ref=None):
+                    mask_ref=None, len_ref=None, sink_ref=None, band=False):
     """``window`` > 0 (causal only): a query at ``t`` sees the keys
     ``(t - window, t]``; KV blocks wholly before a q-block's window are
     SKIPPED (the loop starts at the first block that holds a visible
-    key), as blocks above the diagonal are. ``mask_ref`` (1, seq_k /
+    key), as blocks above the diagonal are. ``sink_ref`` (H,) float32,
+    scalar-prefetched beside the lengths (the BTHD grid, whose second
+    index is the head): a learned scalar a head that joins the softmax's
+    denominator and takes no value, so the last write is the output
+    times ``sigmoid(lse - sink)`` (``sink_share``). ``band`` (a window,
+    lengths prefetched): ONE key block a q-block, the ``block_k`` =
+    ``block_q`` + window keys it sees, in one pass with no running
+    statistics (``_mha_fwd_band_block``; ``mask_ref`` is then its
+    additive bias). ``mask_ref`` (1, seq_k /
     block_k, block_q, block_k) int8: a mask that differs by (query,
     key), this q-block's rows against every key block; a key whose
     entry is 0 gets _NEG (``_mha_fwd_masked_kernel``). ``len_ref`` (B,)
@@ -230,6 +238,9 @@ def _mha_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
     keeps of a row ends at its length). V's width may be another than
     q's and K's; the output has V's."""
     qi = pl.program_id(pid_axis)
+    # the head's sink, read out here: a grid index is read at the
+    # kernel's top level, not under a `pl.when`
+    sink = None if sink_ref is None else sink_ref[pl.program_id(1)]
     if len_ref is not None:
         live = qi * block_q < len_ref[pl.program_id(0)]
 
@@ -239,17 +250,23 @@ def _mha_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
             lse_ref[0, 0, pl.ds(qi * block_q, block_q)] = jnp.zeros(
                 (block_q,), jnp.float32)
 
+        if band:
+            pl.when(live)(functools.partial(
+                _mha_fwd_band_block, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                mask_ref, qi, block_q, block_k, sink))
+            return
         pl.when(live)(functools.partial(
             _mha_fwd_block, q_ref, k_ref, v_ref, o_ref, lse_ref, mask_ref,
-            qi, block_q, block_k, seq_k, causal, window))
+            qi, block_q, block_k, seq_k, causal, window, sink))
         return
     _mha_fwd_block(q_ref, k_ref, v_ref, o_ref, lse_ref, mask_ref, qi,
-                   block_q, block_k, seq_k, causal, window)
+                   block_q, block_k, seq_k, causal, window, sink)
 
 
 def _mha_fwd_block(q_ref, k_ref, v_ref, o_ref, lse_ref, mask_ref, qi,
-                   block_q, block_k, seq_k, causal, window):
-    """``_mha_fwd_kernel``'s work on q-block ``qi``."""
+                   block_q, block_k, seq_k, causal, window, sink=None):
+    """``_mha_fwd_kernel``'s work on q-block ``qi``; ``sink``: the
+    head's scalar."""
     # keep matmul operands in the input dtype (bf16 under mixed precision:
     # the MXU runs bf16 x bf16 -> f32 at full rate; converting to f32 first
     # would halve MXU throughput AND double VMEM traffic); only the softmax
@@ -289,12 +306,65 @@ def _mha_fwd_block(q_ref, k_ref, v_ref, o_ref, lse_ref, mask_ref, qi,
     lower = (lax.max(qi * block_q - window + 1, 0) // block_k
              if window else 0)
     acc, m, l = lax.fori_loop(lower, upper, blk, init)
-    l = jnp.maximum(l, 1e-30)
-    o_ref[0] = (acc / l[:, None]).astype(o_ref.dtype)
+    _write_rows(o_ref, lse_ref, qi, block_q, acc, m, jnp.maximum(l, 1e-30),
+                sink)
+
+
+def _write_rows(o_ref, lse_ref, qi, block_q, acc, m, l, sink):
+    """A q-block's last write: the weighted sums over the weights' sum,
+    times the sink's share where the head has one, and the rows'
+    log-sum-exp."""
+    out = acc / l[:, None]
+    if sink is not None:
+        out = out * jax.nn.sigmoid(m + jnp.log(l) - sink)[:, None]
+    o_ref[0] = out.astype(o_ref.dtype)
     # lse is blocked as a full (1, T) row (TPU block-shape tiling rejects
     # (1, BQ) blocks); consecutive grid steps over j revisit the same row
     # block, so each writes its own BQ slice
     lse_ref[0, 0, pl.ds(qi * block_q, block_q)] = m + jnp.log(l)
+
+
+def _mha_fwd_band_block(q_ref, k_ref, v_ref, o_ref, lse_ref, bias_ref, qi,
+                        block_q, block_k, sink):
+    """``_mha_fwd_kernel``'s work on q-block ``qi`` under a window no
+    wider than ``back`` = ``block_k - block_q``: the block's rows see
+    only the ``block_k`` keys that end with its last row, so they are
+    ONE key block, read where it lies (its first row a multiple of 128;
+    the sequence's start clips it for the first ``back / block_q``
+    q-blocks), and the softmax is one pass: no running maximum, no
+    rescaling, one reduction a row where the walk over key blocks of
+    the window's size makes three. Which keys a row sees is a constant
+    of the block's place in its key block, so the causal and window
+    masks are one addition of ``bias_ref`` (G, block_q, block_k): 0
+    where visible, _NEG elsewhere, entry ``min(qi, G - 1)`` (the
+    clipped blocks first, ``band_bias``)."""
+    q = q_ref[0]  # (BQ, D), pre-scaled
+    start = pl.multiple_of(
+        lax.max(qi * block_q - (block_k - block_q), 0), 128)
+    kb = k_ref[0, pl.ds(start, block_k), :]
+    vb = v_ref[0, pl.ds(start, block_k), :]
+    s = lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+    s = s + bias_ref[lax.min(qi, bias_ref.shape[0] - 1)]
+    m = jnp.max(s, axis=1)
+    p = jnp.exp(s - m[:, None])
+    l = jnp.sum(p, axis=1)
+    acc = jnp.dot(p.astype(vb.dtype), vb, preferred_element_type=jnp.float32)
+    _write_rows(o_ref, lse_ref, qi, block_q, acc, m, l, sink)
+
+
+def band_bias(block_q, block_k, window):
+    """(G, block_q, block_k) float32 for ``_mha_fwd_band_block``: entry
+    ``g`` is of a q-block whose first row lies ``min(g x block_q,
+    back)`` rows into its key block (``back`` = ``block_k - block_q``;
+    the last entry is every unclipped block's); row ``i`` sees key
+    ``j`` iff ``0 <= that + i - j < window``."""
+    back = block_k - block_q
+    into = jnp.minimum(jnp.arange(-(-back // block_q) + 1) * block_q, back)
+    ahead = (into[:, None, None] + jnp.arange(block_q)[None, :, None]
+             - jnp.arange(block_k)[None, None, :])
+    return jnp.where((ahead >= 0) & (ahead < window), 0.0, _NEG).astype(
+        jnp.float32)
 
 
 def _mha_fwd_masked_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
@@ -304,12 +374,15 @@ def _mha_fwd_masked_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
                     **kw)
 
 
-def _mha_fwd_lens_kernel(len_ref, q_ref, k_ref, v_ref, *rest, **kw):
-    """``_mha_fwd_kernel`` with the rows' lengths scalar-prefetched, and
-    the mask among its operands where there is one."""
-    _mha_fwd_kernel(q_ref, k_ref, v_ref, rest[-2], rest[-1],
-                    mask_ref=rest[0] if len(rest) == 3 else None,
-                    len_ref=len_ref, **kw)
+def _mha_fwd_lens_kernel(len_ref, *refs, sinks=False, **kw):
+    """``_mha_fwd_kernel`` with the rows' lengths scalar-prefetched (and,
+    with ``sinks``, the heads' sinks beside them), and the mask or the
+    band's bias among its operands where there is one."""
+    sink_ref, refs = (refs[0], refs[1:]) if sinks else (None, refs)
+    q_ref, k_ref, v_ref, *mask, o_ref, lse_ref = refs
+    _mha_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
+                    mask_ref=mask[0] if mask else None,
+                    len_ref=len_ref, sink_ref=sink_ref, **kw)
 
 
 def _mha_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
@@ -609,7 +682,7 @@ def _lse_spec_bthd(h, t):
 
 def _mha_fwd_call_bthd(qs, k, v, h, causal, block_q, block_k, interpret,
                        window=0, name=None, mask=None, lengths=None,
-                       out_dtype=None):
+                       out_dtype=None, group=1, sink=None, band=False):
     """``mask`` (B, T, tk) int8, the same for every head: 1 where the
     query attends the key (with ``causal``, which still bounds the key
     blocks a q-block walks: a mask here only ever takes keys away). The
@@ -620,14 +693,30 @@ def _mha_fwd_call_bthd(qs, k, v, h, causal, block_q, block_k, interpret,
     fetch no tile of the mask (a prefill's bucket is a power of two and
     the prompt in it on average two thirds of that: half the causal
     pairs). V (B, tk, h * dv) may be of another width than q and K; the
-    output is (B, T, h * dv), in ``out_dtype`` (q's where not given)."""
+    output is (B, T, h * dv), in ``out_dtype`` (q's where not given).
+    ``group`` > 1: K and V hold ``h / group`` heads and query head
+    ``hi`` reads head ``hi // group``; the grid walks a head's q-blocks
+    inside the head, so a group's heads run against ONE resident copy (a
+    block whose index did not change is not copied again). ``sink``
+    (h,) float32, scalar-prefetched beside the lengths: the last write
+    times ``sigmoid(lse - sink)``. ``band`` (causal, a ``window`` no
+    wider than ``block_k - block_q``): a q-block's keys are one block
+    of ``block_k``, attended in one pass (``_mha_fwd_band_block``).
+    A sink or a band is not for a ``mask``, and takes every row as whole
+    where no lengths were given."""
     b, t, hd = qs.shape
     tk = k.shape[1]
-    d, dv = hd // h, v.shape[2] // h
+    hkv = h // group
+    d, dv = hd // h, v.shape[2] // hkv
+    if (sink is not None or band) and lengths is None:
+        lengths = jnp.full((b,), t, jnp.int32)
+    if lengths is not None:
+        kernel = functools.partial(_mha_fwd_lens_kernel,
+                                   sinks=sink is not None, band=band)
+    else:
+        kernel = _mha_fwd_kernel if mask is None else _mha_fwd_masked_kernel
     kernel = functools.partial(
-        _mha_fwd_lens_kernel if lengths is not None
-        else _mha_fwd_kernel if mask is None else _mha_fwd_masked_kernel,
-        block_q=block_q, block_k=block_k, seq_k=tk,
+        kernel, block_q=block_q, block_k=block_k, seq_k=tk,
         causal=causal, pid_axis=2, window=window)
     operands, mask_specs, mask_bytes = (qs, k, v), [], 0
 
@@ -638,7 +727,11 @@ def _mha_fwd_call_bthd(qs, k, v, h, causal, block_q, block_k, interpret,
             return qi
         return jnp.minimum(qi, jnp.maximum(lens[0][bi] - 1, 0) // block_q)
 
+    def kv_head(bi, hi, qi, *lens):
+        return bi, 0, hi if group == 1 else hi // group
+
     if mask is not None:
+        assert sink is None and not band, "no sink or band under a mask"
         nk = tk // block_k
         operands += (jnp.swapaxes(
             mask.astype(jnp.int8).reshape(b, t, nk, block_k), 1, 2),)
@@ -649,13 +742,21 @@ def _mha_fwd_call_bthd(qs, k, v, h, causal, block_q, block_k, interpret,
     limit = _kv_vmem_limit(tk, d, jnp.dtype(k.dtype).itemsize)
     if mask_bytes and limit is not None:
         limit += mask_bytes
+    if band:
+        assert causal and 0 < window <= block_k - block_q <= tk - block_q
+        bias = band_bias(block_q, block_k, window)
+        operands += (bias,)
+        # one block for the whole grid: fetched once, and buffered twice
+        mask_specs = [pl.BlockSpec(
+            bias.shape, lambda bi, hi, qi, *lens: (0, 0, 0))]
+        limit = (limit or 16 * 2**20) + 2 * bias.size * 4
     specs = dict(
         grid=(b, h, t // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bi, hi, qi, *lens: (
                 bi, live_q(bi, qi, lens), hi)),
-            pl.BlockSpec((1, tk, d), lambda bi, hi, qi, *lens: (bi, 0, hi)),
-            pl.BlockSpec((1, tk, dv), lambda bi, hi, qi, *lens: (bi, 0, hi)),
+            pl.BlockSpec((1, tk, d), kv_head),
+            pl.BlockSpec((1, tk, dv), kv_head),
         ] + mask_specs,
         out_specs=[
             pl.BlockSpec((1, block_q, dv),
@@ -663,9 +764,12 @@ def _mha_fwd_call_bthd(qs, k, v, h, causal, block_q, block_k, interpret,
             _lse_spec_bthd(h, t),
         ])
     if lengths is not None:
-        operands = (lengths.reshape(-1).astype(jnp.int32),) + operands
+        prefetch = (lengths.reshape(-1).astype(jnp.int32),)
+        if sink is not None:
+            prefetch += (sink.reshape(-1).astype(jnp.float32),)
+        operands = prefetch + operands
         specs = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, **specs))
+            num_scalar_prefetch=len(prefetch), **specs))
     return named_pallas_call(
         name or FLASH_FWD, kernel,
         out_shape=[
@@ -1049,18 +1153,52 @@ def sink_share(lse, sink):
     return jax.nn.sigmoid(lse - sink.astype(jnp.float32))
 
 
-def flash_operand(x, repeat=1):
+def flash_operand(x):
     """x (B, T, H, D) as the forward-only flash call takes it: float32
     rounded to bfloat16, D padded with zero channels to whole 128-lane
-    tiles, the heads repeated ``repeat`` times (the kernel takes q, k, v
-    of one head count), (B, T, heads x width)."""
+    tiles, (B, T, H x width); K and V at their own head count."""
     if x.dtype == jnp.float32:
         x = x.astype(jnp.bfloat16)
     width = _ceil_to(x.shape[-1], 128)
     x = jnp.pad(x, ((0, 0),) * 3 + ((0, width - x.shape[-1]),))
-    if repeat > 1:
-        x = jnp.repeat(x, repeat, axis=2)
     return x.reshape(x.shape[0], x.shape[1], x.shape[2] * width)
+
+
+# the q-block of a band (`prefill_blocks`): what
+# `tools/probe_prefill_window.py` read fastest on a v5e among 128 / 256 /
+# 512 (PERF.md, PR 57)
+_BAND_BLOCK_Q = 256
+
+
+def band_back(window: int) -> int:
+    """The keys a band's q-block reads before its first row: the
+    smallest power of two that holds ``window``, 128 at least."""
+    return max(128, 1 << (window - 1).bit_length())
+
+
+def prefill_blocks(window: int, t: int):
+    """(block_q, block_k, band) of a prefill's flash forward over a
+    bucket of ``t`` rows, from the layer's window and nothing else.
+
+    A window no wider than ``_FLASH_BLOCK`` under the bucket: a BAND.
+    A q-block's rows see only the keys from ``band_back(window)``
+    before its first row up to its last row, so its keys are ONE block of ``block_q +
+    back`` read in one pass (``_mha_fwd_band_block``), not ``2 x
+    _FLASH_BLOCK`` keys walked for a window of 128; the q-block is
+    ``_BAND_BLOCK_Q`` or ``back``, whichever is larger, halved while
+    the bucket does not hold ``block_q + back`` rows. Else (no window,
+    one the bucket is inside of, or a wider one) the walk over key
+    blocks: both blocks ``_FLASH_BLOCK``, halved to a divisor of ``t``
+    (``_fit_block``)."""
+    if 0 < window < t and window <= _FLASH_BLOCK:
+        back = band_back(window)
+        block_q = _fit_block(t, min(max(back, _BAND_BLOCK_Q), _FLASH_BLOCK))
+        while block_q > 128 and block_q + back > t:
+            block_q //= 2
+        if block_q + back <= t and t % block_q == 0:
+            return block_q, block_q + back, True
+    block = _fit_block(t, _FLASH_BLOCK)
+    return block, block, False
 
 
 def prefill_attention(q, k, v, lengths=None, window=0, scale=None, name=None,
@@ -1071,25 +1209,33 @@ def prefill_attention(q, k, v, lengths=None, window=0, scale=None, name=None,
     ``lengths`` (B,): the rows' live tokens, padding at a row's END.
     ``sink`` (H,): a learned scalar a query head that joins the
     softmax's denominator and takes no value: the output times
-    ``sigmoid(lse - sink)``, from the log-sum-exp the kernel returns
-    beside its output (the lax form computes its own).
+    ``sigmoid(lse - sink)``.
 
     On a TPU at a block-aligned bucket (``_use_pallas``: 256 rows and
-    up) the flash forward kernel, ``name`` in a device trace
-    (``ptpu.attn_window`` where a window was asked for, else
-    ``ptpu.flash_fwd``):
+    up) ONE call of the flash forward kernel at the layer's own shape,
+    with no pass of XLA's over K, V or the output beside it; ``name`` in
+    a device trace (``ptpu.attn_window`` where a window was asked for,
+    else ``ptpu.flash_fwd``):
 
-    - float32 operands are rounded to bfloat16 in HBM before the call
-      (and before K and V are repeated to the query heads): what
-      Mosaic's dot rounds float32 operands to at the default precision
-      anyway (on the chip the two give the same bits at the same MXU
-      time: PERF.md, PR 45), the arithmetic the lax paths compute in
-      and the serving configurations state. What the cast buys is
-      bytes: half of what a head's resident K and V take in vector
-      memory and of what the repeat writes. The statistics and sums
-      stay float32, and so does the output of float32 callers;
+    - float32 operands are rounded to bfloat16 in HBM before the call:
+      what Mosaic's dot rounds float32 operands to at the default
+      precision anyway (on the chip the two give the same bits at the
+      same MXU time: PERF.md, PR 45), the arithmetic the lax paths
+      compute in and the serving configurations state. What the cast
+      buys is bytes: half of what a head's resident K and V take in
+      vector memory. The statistics and sums stay float32, and so does
+      the output of float32 callers;
+    - K and V go in at their own ``Hkv`` heads: query head ``hi`` reads
+      head ``hi // group`` by the call's index map, and a group's heads
+      run against one resident copy (nothing is repeated in HBM);
+    - the blocks follow the window (``prefill_blocks``): under a
+      window of 128 a q-block of 256 rows reads the 384 keys it sees as
+      one block, in one pass;
     - ``lengths`` is scalar-prefetched: a q-block wholly past its row's
       length computes nothing and gives zeros;
+    - ``sink`` is scalar-prefetched beside them, and the kernel's last
+      write is the output times ``sigmoid(m + log l - sink[hi])`` in
+      float32, from the statistics it holds;
     - q and k are padded with zero channels to a multiple of the 128
       lanes and v to one of its own (192 / 192 / 128 -> 256 / 256 / 128),
       and the output's first ``dv`` channels kept: exact.
@@ -1106,33 +1252,30 @@ def prefill_attention(q, k, v, lengths=None, window=0, scale=None, name=None,
     name = name or (ATTN_WINDOW if window else FLASH_FWD)
     if window >= t:
         window = 0  # every earlier key is inside it: plain causal
+    group = h // k.shape[2]
     kernel = interpret or _use_pallas(t, t, None, 0.0)
     PREFILL_ATTN_TRACES.inc(
         path="kernel" if kernel else "lax",
         operands="bfloat16" if kernel else jnp.dtype(q.dtype).name,
         lengths="none" if lengths is None else "given")
-    if sink is not None or dv != dq:
+    block_q, block_k, band = prefill_blocks(window, t)
+    if sink is not None or dv != dq or window or group > 1:
         PREFILL_ATTN_FORMS.inc(
             sink="none" if sink is None else "learned",
-            value_width="query" if dv == dq else "own")
+            value_width="query" if dv == dq else "own",
+            kv="own" if group > 1 else "query",
+            block_k=str(block_k) if kernel else "none")
     if not kernel:
         with jax.named_scope(name):
             return prefill_attention_reference(q, k, v, window, scale, sink)
     if scale is None:
         scale = 1.0 / math.sqrt(dq)
-    group = h // k.shape[2]
-
-    block_q = block_k = _fit_block(t, _FLASH_BLOCK)
-    out, lse = _mha_fwd_call_bthd(
-        flash_operand(q * jnp.asarray(scale, q.dtype)),
-        flash_operand(k, group), flash_operand(v, group), h, True, block_q, block_k, interpret,
-        window=window, name=name, lengths=lengths, out_dtype=q.dtype)
-    out = out.reshape(b, t, h, -1)[..., :dv]
-    if sink is None:
-        return out
-    with jax.named_scope(name):
-        share = sink_share(jnp.swapaxes(lse.reshape(b, h, t), 1, 2), sink)
-        return (out * share[..., None]).astype(out.dtype)
+    out, _ = _mha_fwd_call_bthd(
+        flash_operand(q * jnp.asarray(scale, q.dtype)), flash_operand(k),
+        flash_operand(v), h, True, block_q, block_k, interpret,
+        window=window, name=name, lengths=lengths, out_dtype=q.dtype,
+        group=group, sink=sink, band=band)
+    return out.reshape(b, t, h, -1)[..., :dv]
 
 
 @register_op("prefill_attention")

@@ -423,6 +423,152 @@ def test_prefill_attention_kernel_gives_the_live_rows_of_the_lax_form(
             assert not got[b, first_dead:].any()
 
 
+def _rounded_reference(q, k, v, window, sink=None):
+    """The exact lax form on operands rounded as the kernel rounds them
+    (bfloat16, q after its scale)."""
+    from paddle_tpu.ops import attention as A
+
+    bf = lambda x: x.astype(jnp.bfloat16).astype(jnp.float32)  # noqa: E731
+    scale = 1.0 / np.sqrt(q.shape[-1])
+    return np.asarray(A.prefill_attention_reference(
+        bf(q * scale) / scale, bf(k), bf(v), window, sink=sink))
+
+
+@pytest.mark.parametrize("group", [1, 2, 8, 16])
+def test_prefill_attention_kernel_reads_k_and_v_at_their_own_heads(
+        monkeypatch, group):
+    """K and V go into the kernel at their own head count (nothing is
+    repeated: the index map `hi // group`), V narrower than q and K,
+    the rows' lengths given: against the lax form on the live rows, and
+    bit for bit against the same call on K and V repeated by hand."""
+    from paddle_tpu.ops import attention as A
+
+    hkv, t, (dq, dv) = (2 if group < 8 else 1), 256, (192, 128)
+    r = np.random.default_rng(group)
+    q, k, v = (jnp.asarray(r.normal(size=s), jnp.float32) for s in
+               ((2, t, group * hkv, dq), (2, t, hkv, dq), (2, t, hkv, dv)))
+    lens = [256, 130]
+    lengths = jnp.asarray(lens, jnp.int32)
+    monkeypatch.setattr(A, "_FLASH_BLOCK", 128)
+    got = np.asarray(A.prefill_attention(q, k, v, lengths, interpret=True))
+    assert got.shape == (2, t, group * hkv, dv)
+    want = _rounded_reference(q, k, v, 0)
+    for b, n in enumerate(lens):
+        np.testing.assert_allclose(got[b, :n], want[b, :n], rtol=2e-2,
+                                   atol=5e-3)
+    by_hand = np.asarray(A.prefill_attention(
+        q, jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2),
+        lengths, interpret=True))
+    np.testing.assert_array_equal(got, by_hand)
+
+
+_BAND_CASES = [
+    # id, T, (H, Hkv), (dq, dv), window, lengths, (block_q, block_k, band)
+    ("w128-t1024", 1024, (4, 2), (192, 128), 128, [1024, 700],
+     (256, 384, True)),
+    ("w128-t256-one-clipped-block-of-two", 256, (2, 2), (128, 128), 128,
+     [256, 100], (128, 256, True)),
+    ("w512-t1024", 1024, (2, 1), (128, 128), 512, [1024, 513],
+     (512, 1024, True)),
+    ("w200-t512", 512, (2, 2), (128, 128), 200, [512, 300],
+     (256, 512, True)),
+    ("w1-t512", 512, (2, 1), (128, 128), 1, [512, 257], (256, 384, True)),
+    ("w600-t1024-walks", 1024, (2, 1), (128, 128), 600, [1024, 601],
+     (512, 512, False)),
+]
+
+
+@pytest.mark.parametrize("t,heads,widths,window,lens,blocks",
+                         [c[1:] for c in _BAND_CASES],
+                         ids=[c[0] for c in _BAND_CASES])
+def test_prefill_attention_kernel_under_a_window(t, heads, widths, window,
+                                                 lens, blocks):
+    """The kernel at the blocks the window gives it, nothing patched: a
+    window up to 512 is a BAND (a q-block against the one key block it
+    sees, in one pass; the first q-blocks' key block clipped by the
+    sequence's start), a wider one the walk over key blocks of 512 with
+    the first q-block's clipped `lower`; against the lax form on the
+    live rows."""
+    from paddle_tpu.ops import attention as A
+
+    (h, hkv), (dq, dv) = heads, widths
+    assert A.prefill_blocks(window, t) == blocks
+    r = np.random.default_rng(t + window)
+    q, k, v = (jnp.asarray(r.normal(size=s), jnp.float32) for s in
+               ((2, t, h, dq), (2, t, hkv, dq), (2, t, hkv, dv)))
+    got = np.asarray(A.prefill_attention(
+        q, k, v, jnp.asarray(lens, jnp.int32), window=window,
+        interpret=True))
+    assert np.isfinite(got).all()
+    want = _rounded_reference(q, k, v, window)
+    for b, n in enumerate(lens):
+        np.testing.assert_allclose(got[b, :n], want[b, :n], rtol=2e-2,
+                                   atol=5e-3)
+        first_dead = -(-n // blocks[0]) * blocks[0]
+        assert not got[b, first_dead:].any()
+
+
+_BLOCK_RULE = [
+    # (window, T) -> (block_q, block_k, band)
+    ((0, 16384), (512, 512, False)),
+    ((0, 256), (256, 256, False)),
+    ((128, 16384), (256, 384, True)),      # MiMo-V2-Flash's sliding layers
+    ((128, 512), (256, 384, True)),
+    ((128, 256), (128, 256, True)),
+    ((128, 128), (128, 128, False)),       # the bucket is inside the window
+    ((100, 1024), (256, 384, True)),
+    ((130, 384), (128, 384, True)),
+    ((200, 512), (256, 512, True)),
+    ((512, 4096), (512, 1024, True)),      # Laguna's and Phi's
+    ((512, 1024), (512, 1024, True)),
+    ((512, 512), (512, 512, False)),
+    ((513, 4096), (512, 512, False)),      # wider than a block: the walk
+    ((4096, 16384), (512, 512, False)),
+]
+
+
+@pytest.mark.parametrize("shape,blocks", _BLOCK_RULE,
+                         ids=["w%d-t%d" % s for s, _ in _BLOCK_RULE])
+def test_prefill_blocks_follow_the_window_and_the_bucket(shape, blocks):
+    """`prefill_blocks`: a rule of (window, T) and of nothing else."""
+    from paddle_tpu.ops import attention as A
+
+    got = A.prefill_blocks(*shape)
+    assert got == blocks
+    block_q, block_k, band = got
+    assert shape[1] % block_q == 0 and block_k <= shape[1]
+    if band:
+        assert shape[0] <= block_k - block_q <= A._FLASH_BLOCK
+
+
+def test_prefill_attention_counts_the_forms_it_was_traced_in():
+    """`paddle_tpu_prefill_attn_forms_total{sink, value_width, kv,
+    block_k}`: one a traced call that is not the plain form; `kv=own`
+    where K and V have fewer heads than q, `block_k` the kernel's key
+    block (`none` on the lax path)."""
+    from paddle_tpu import observability as obs
+    from paddle_tpu.ops import attention as A
+
+    def count(**labels):
+        return obs.PREFILL_ATTN_FORMS.value(**labels)
+
+    q = jnp.ones((1, 512, 4, 192), jnp.float32)
+    k = jnp.ones((1, 512, 2, 192), jnp.float32)
+    v = jnp.ones((1, 512, 2, 128), jnp.float32)
+    sink = jnp.zeros((4,), jnp.float32)
+    band = dict(sink="learned", value_width="own", kv="own", block_k="384")
+    walk = dict(sink="none", value_width="query", kv="own", block_k="512")
+    lax_ = dict(sink="none", value_width="own", kv="own", block_k="none")
+    plain = dict(sink="none", value_width="query", kv="query", block_k="512")
+    before = [count(**c) for c in (band, walk, lax_, plain)]
+    A.prefill_attention(q, k, v, window=128, sink=sink, interpret=True)
+    A.prefill_attention(q, k, k, interpret=True)
+    A.prefill_attention(q, k, v)
+    A.prefill_attention(q, q, q, interpret=True)   # plain: not counted
+    after = [count(**c) for c in (band, walk, lax_, plain)]
+    assert [a - b for a, b in zip(after, before)] == [1, 1, 1, 0]
+
+
 def test_prefill_attention_counts_its_traces_and_names_its_kernel():
     """`paddle_tpu_prefill_attn_traces_total{path, operands, lengths}`:
     one a traced call; the lax form under the name a device trace would

@@ -252,6 +252,40 @@ def test_the_sink_in_the_prefill_kernel(window):
     assert float(jnp.max(jnp.abs(bare[0] - want[0]))) > 0.05
 
 
+@pytest.mark.parametrize("window", [0, 128], ids=["causal", "w128"])
+def test_the_sink_in_the_kernels_last_write_is_the_share_applied_outside(
+        window):
+    """The kernel's last write times `sigmoid(m + log l - sink)` is, to
+    float32's rounding, its output without a sink times `sink_share` of
+    the log-sum-exp it hands back: the pass of XLA's that PR 57 took out
+    of `prefill_attention`, applied here by hand; with a window (the
+    band's one pass) and without (the walk over key blocks)."""
+    q, k, v, sink = _sink_case()
+    b, t, h, _ = q.shape
+    lengths = jnp.asarray([256, 100], jnp.int32)
+    block_q, block_k, band = A.prefill_blocks(window, t)
+    assert band == bool(window)
+
+    def call(sink):
+        return A._mha_fwd_call_bthd(
+            A.flash_operand(q / np.sqrt(q.shape[-1])), A.flash_operand(k),
+            A.flash_operand(v), h, True, block_q, block_k, True,
+            window=window, lengths=lengths, out_dtype=jnp.float32,
+            group=h // k.shape[2], sink=sink, band=band)
+
+    inside, lse_in = call(sink)
+    bare, lse = call(None)
+    np.testing.assert_array_equal(lse_in, lse)
+    share = A.sink_share(jnp.swapaxes(lse.reshape(b, h, t), 1, 2), sink)
+    outside = bare.reshape(b, t, h, -1) * share[..., None]
+    np.testing.assert_allclose(inside.reshape(b, t, h, -1), outside,
+                               rtol=2e-6, atol=1e-7)
+    got = A.prefill_attention(q, k, v, lengths, window=window, sink=sink,
+                              interpret=True)
+    np.testing.assert_array_equal(
+        got, inside.reshape(b, t, h, -1)[..., :v.shape[-1]])
+
+
 @pytest.mark.parametrize("held", [3, 8, 21], ids=["part", "full", "wrapped"])
 def test_the_sink_in_the_ring_step(held):
     """One query against a ring of 8 rows holding `held` positions: the

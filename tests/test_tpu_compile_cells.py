@@ -61,8 +61,12 @@ def _assert_bfloat16_operands_and_lengths(text, batch, v_width=None):
     """Every flash forward of a serving prefill is handed the rows'
     lengths (scalar-prefetched: the first operand) and q, k and v in
     bfloat16 (`ops/attention.py: prefill_attention`); with `v_width`,
-    v as (batch, T, v_width) where q and k are wider."""
-    calls = _prefill_attention_calls(text)
+    v as (batch, T, v_width) where q and k are wider. A call's float32
+    operands (the heads' sinks, prefetched beside the lengths; a band's
+    bias, last) are left out of what is returned: [(name, [lengths, q,
+    k, v])]."""
+    calls = [(name, [o for o in operands if not o.startswith("f32[")])
+             for name, operands in _prefill_attention_calls(text)]
     assert calls
     for name, operands in calls:
         assert operands[0] == "s32[%d]" % batch, (name, operands)

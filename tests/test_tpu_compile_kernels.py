@@ -328,13 +328,15 @@ def test_mimo_v2_serving_step_compiles(one_chip, monkeypatch, kind, batch,
     19,072 ids): they compile for a v5e and fit it beside each other. A
     prefill holds one flash forward a layer on bfloat16 operands, q and
     k padded 192 -> 256 and v at 128 (`ptpu.flash_fwd` twice,
-    `ptpu.attn_window` five times). The step donates its fourteen
+    `ptpu.attn_window` five times), K and V at their own 4 or 8 heads,
+    a sliding layer's sink and band inside its call. The step donates its fourteen
     entries; each full layer's two slabs of FLAT rows (768 beside 512
     floats a position) are read where they lie by one call of
     `ptpu.decode_attn_uneven` and appended to in place: nothing of a
     slab's size is copied, reshaped or transposed."""
     from test_tpu_compile_cells import (
-        _assert_bfloat16_operands_and_lengths, _cell_predictor)
+        _assert_bfloat16_operands_and_lengths, _cell_predictor,
+        _prefill_attention_calls)
 
     pred = _cell_predictor("mimo_v2_lm", "mimo-v2-flash.json", monkeypatch)
     step_fn, feeds, state, n_cache = _serving_step(pred, kind, batch, seq,
@@ -356,15 +358,38 @@ def test_mimo_v2_serving_step_compiles(one_chip, monkeypatch, kind, batch,
     if kind == "prefill":
         assert calls.count("ptpu.flash_fwd") == 2, calls
         assert calls.count("ptpu.attn_window") == 5, calls
-        ops = _assert_bfloat16_operands_and_lengths(text, batch,
-                                                    v_width=64 * 128)
-        assert all(o[1:] == ["bf16[1,16384,16384]"] * 2
-                   + ["bf16[1,16384,8192]"] for _, o in ops), ops
+        # K and V at their own head count (PR 57): 8 x 256 / 8 x 128
+        # channels a token on a sliding layer, 4 x 256 / 4 x 128 on a
+        # full one, nothing repeated to the 64 query heads
+        q, own = "bf16[1,16384,16384]", {
+            "ptpu.attn_window": ["bf16[1,16384,2048]", "bf16[1,16384,1024]"],
+            "ptpu.flash_fwd": ["bf16[1,16384,1024]", "bf16[1,16384,512]"]}
+        for name, o in _assert_bfloat16_operands_and_lengths(text, batch):
+            assert o[1:] == [q] + own[name.rstrip(".0123456789")], (name, o)
+        # a sliding layer's call alone is handed the 64 sinks (beside the
+        # lengths) and its band's bias: a q-block of 256 rows against
+        # the one block of 384 keys it sees
+        for name, o in _prefill_attention_calls(text):
+            extra = [x for x in o if x.startswith("f32[")]
+            assert extra == (["f32[64]", "f32[2,256,384]"]
+                             if name.startswith("ptpu.attn_window")
+                             else []), (name, o)
+        # and under an attention kernel's scope there is nothing but the
+        # Mosaic call and its results: no pass of XLA's for the sink
+        # (the parent's was a multiply over the (T, 64, 128) output by
+        # sigmoid(lse - sink) from a transposed lse, five times)
+        stray = [ln.strip()[:200] for ln in text.splitlines()
+                 if re.search(r'op_name="[^"]*ptpu\.(attn_window|flash_fwd)',
+                              ln)
+                 and "tpu_custom_call" not in ln
+                 and not re.search(r" (get-tuple-element|bitcast)\(", ln)]
+        assert not stray, stray
         # beside the weights, the slots' entries and the step
         assert weights + slabs + mem.temp_size_in_bytes + (
             mem.output_size_in_bytes) < 15.5 * 2**30, mem
-        # 2.89 GiB where ISSUE 54 allowed 3-4.5: 16 slots fit
-        assert mem.temp_size_in_bytes < 3.2 * 2**30, mem.temp_size_in_bytes
+        # 2.53 GiB (2.89 with K and V repeated, before PR 57) where
+        # ISSUE 54 allowed 3-4.5: 16 slots fit
+        assert mem.temp_size_in_bytes < 2.7 * 2**30, mem.temp_size_in_bytes
         return
     assert [c for c in calls if c.startswith("ptpu.")] == [
         "ptpu.decode_attn_uneven"] * 2, calls

@@ -361,8 +361,9 @@ def test_solar_open2_serving_step_compiles(one_chip, monkeypatch, kind,
                     and "%ptpu.kda_scan" in ln.split(" = ")[0]):
                 assert "f32[4,64,128,128]" in ln.split(" custom-call(")[
                     0], ln[:400]
+        # 64 query heads on 8 key/value heads of 128, K and V at their own
         assert _assert_bfloat16_operands_and_lengths(text, batch)[0][1][
-            1:] == ["bf16[4,4096,8192]"] * 3
+            1:] == ["bf16[4,4096,8192]"] + ["bf16[4,4096,1024]"] * 2
         # beside the weights, the slots' entries and the step
         assert weights + slabs + mem.temp_size_in_bytes + (
             mem.output_size_in_bytes) < 15.5 * 2**30, mem
