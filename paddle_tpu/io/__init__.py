@@ -31,6 +31,7 @@ from ..checkpoint import (
 )
 from ..checkpoint import layout as _ckpt_layout
 from ..framework.core import Parameter, Program, Variable, default_main_program
+from ..framework.dtypes import as_numpy_dtype, convert_dtype
 from ..framework.scope import Scope, global_scope
 
 __all__ = [
@@ -327,6 +328,14 @@ def load_inference_model(
         with np.load(path) as npz:
             params = {key.replace("%2F", "/"): npz[key]
                       for key in npz.files}
+        # numpy has no bfloat16: a parameter held in it comes back from
+        # the archive as 2-byte voids, the same bytes
+        for name, val in params.items():
+            var = program.global_block()._find_var_recursive(name)
+            if (val.dtype.kind == "V" and val.dtype.itemsize == 2
+                    and var is not None
+                    and convert_dtype(var.dtype) == "bfloat16"):
+                params[name] = val.view(as_numpy_dtype("bfloat16"))
         for name, val in device_owned_tree(params).items():
             scope.set_var(name, val)
     fetch_targets = [program.global_block().var(n) for n in meta["fetch_names"]]

@@ -116,6 +116,8 @@ __all__ = [
     "cache_append_window",
     "cache_gather",
     "spec_accept",
+    "spec_pick",
+    "mtp_next_tokens",
     "greedy_sample",
     "top_k_sample",
     "top_p_sample",
@@ -2332,6 +2334,32 @@ def spec_accept(proposed, logits, name=None):
     return next_ids, accept
 
 
+def mtp_next_tokens(tokens, lengths, first, name=None):
+    """A prompt's next tokens, for a prediction layer: ``tokens`` (B, S)
+    shifted left by one with ``first`` (B,) at each row's last real
+    position (kernel: ops/speculative.py)."""
+    helper = LayerHelper("mtp_next_tokens", name=name)
+    out = helper.create_variable_for_type_inference(tokens.dtype,
+                                                    shape=tokens.shape)
+    helper.append_op(
+        type="mtp_next_tokens",
+        inputs={"Tokens": [tokens], "Lengths": [lengths], "First": [first]},
+        outputs={"Out": [out]}, attrs={})
+    return out
+
+
+def spec_pick(ids, accept, name=None):
+    """``ids`` (B, T) at column ``accept`` (B,) of each row -> (B,)
+    (kernel: ops/speculative.py)."""
+    helper = LayerHelper("spec_pick", name=name)
+    out = helper.create_variable_for_type_inference(
+        ids.dtype, shape=(ids.shape[0],))
+    helper.append_op(type="spec_pick",
+                     inputs={"Ids": [ids], "Accept": [accept]},
+                     outputs={"Out": [out]}, attrs={})
+    return out
+
+
 def greedy_sample(logits, name=None):
     """argmax token per row: (B, V) or (B, 1, V) -> (B,) int64 (kernel:
     ops/sampling.py)."""
@@ -2985,12 +3013,13 @@ def mla_decode(q, slab, lengths, w_b, scale, chosen=None, scope=None,
     slab (B, S, rank + rope) up to ``lengths`` (B,) rows -> (B, 1, H,
     v); no key or value of any head is built. ``chosen`` (B, S) bool:
     of a slot's live rows only those (``dsa_mask``); ``scope``: the
-    named scope where it is not ``ptpu.mla_decode``."""
+    named scope where it is not ``ptpu.mla_decode``. A window: q (B, T,
+    ...) under ``chosen`` (B, T, S), ``lengths`` the first row's."""
     helper = LayerHelper("mla_decode", name=name)
-    b, _, h, dq = q.shape
+    b, t, h, dq = q.shape
     nope = int(dq) - (int(slab.shape[-1]) - int(w_b.shape[0]))
     out = helper.create_variable_for_type_inference(
-        q.dtype, shape=(b, 1, h, int(w_b.shape[1]) // int(h) - nope))
+        q.dtype, shape=(b, t, h, int(w_b.shape[1]) // int(h) - nope))
     inputs = {"Q": [q], "Cache": [slab], "Lengths": [lengths], "WB": [w_b]}
     attrs = {"scale": float(scale)}
     if chosen is not None:
@@ -3033,6 +3062,17 @@ def latent_prefill(c_q, rows, w_qb, w_kvb, w_o, n_head, nope_dim, scale,
     return out
 
 
+def _index_rot(rot):
+    """Attributes of an indexer's rotation from ``DecodeConfig.
+    rope["index"]``: theta, rotary_dim and, written only where set (a
+    program without it is the program it was), interleave."""
+    attrs = {"theta": float(rot["theta"]),
+             "rotary_dim": int(rot["rotary_dim"])}
+    if rot.get("interleave"):
+        attrs["interleave"] = True
+    return attrs
+
+
 def dsa_index_keys(x, w, gain, bias, rot, positions=None, epsilon=1e-5,
                    name=None):
     """The index key a position keeps under a learned indexer: x (B, T,
@@ -3046,8 +3086,7 @@ def dsa_index_keys(x, w, gain, bias, rot, positions=None, epsilon=1e-5,
         inputs["Positions"] = [positions]
     helper.append_op(
         type="dsa_index_keys", inputs=inputs, outputs={"Out": [out]},
-        attrs={"epsilon": float(epsilon), "theta": float(rot["theta"]),
-               "rotary_dim": int(rot["rotary_dim"])})
+        attrs=dict(_index_rot(rot), epsilon=float(epsilon)))
     return out
 
 
@@ -3059,7 +3098,8 @@ def dsa_mask(c_q, x, w_q, w_w, keys, n_heads, topk, rot, positions=None,
     prompts' live tokens, the query rows past them are left unchosen),
     or with ``positions`` and ``lengths`` (B,) a step's (B, S) bool over
     the slab of keys (B, S, d): 1 where the query attends the
-    position."""
+    position; a WINDOW of T > 1 rows on the slab (``positions`` and
+    ``lengths`` the first row's) gives (B, T, S), a choice a row."""
     helper = LayerHelper("dsa_mask", name=name)
     b, t = c_q.shape[:2]
     inputs = {"CQ": [c_q], "X": [x], "WQ": [w_q], "WW": [w_w],
@@ -3071,19 +3111,19 @@ def dsa_mask(c_q, x, w_q, w_w, keys, n_heads, topk, rot, positions=None,
             "int8", shape=(b, t, t))
     else:
         out = helper.create_variable_for_type_inference(
-            "bool", shape=(b, int(keys.shape[1])))
+            "bool", shape=(b,) + ((int(t),) if int(t) > 1 else ())
+            + (int(keys.shape[1]),))
         inputs["Positions"] = [positions]
     helper.append_op(
         type="dsa_mask", inputs=inputs, outputs={"Out": [out]},
-        attrs={"n_heads": int(n_heads), "topk": int(topk),
-               "theta": float(rot["theta"]),
-               "rotary_dim": int(rot["rotary_dim"])})
+        attrs=dict(_index_rot(rot), n_heads=int(n_heads), topk=int(topk)))
     return out
 
 
 def mla_append(slab, row, pos, ring=False, name=None):
     """One latent row a slot: ``row`` (B, 1, W) at row ``pos[b]`` of
-    ``slab`` (B, S, W), at ``pos[b] mod S`` of a ``ring``."""
+    ``slab`` (B, S, W), at ``pos[b] mod S`` of a ``ring``; a window's
+    rows (B, T, W) at ``pos[b]..pos[b] + T - 1`` of a slab."""
     helper = LayerHelper("mla_append", name=name)
     out = helper.create_variable_for_type_inference(
         slab.dtype, shape=slab.shape)

@@ -67,6 +67,19 @@ where asked, the attention projections'.
   whose parameter is the gain's distance from one (``norm_offset``), a
   gated-SiLU MLP, an untied head of 320 ids.
 
+- Z.ai's GLM-5 (`model_type: glm_moe_dsa`): EVERY layer latent
+  attention under a learned indexer (``latent_dsa``; dots3's full layer
+  without the head gate and the rescale, value heads of 256 under
+  query/key heads of 192 + 64, the indexer's rotation INTERLEAVED),
+  three leading dense MLPs and then routed experts with a shared one
+  under a sigmoid router with a selection bias, matrices HELD in
+  bfloat16 (``matrix_dtype``), and ONE multi-token-prediction layer
+  (``n_predict_layers``; ``_mtp``: two norms, ``eh_proj``, a decoder
+  layer of the same kind with its own cache entries, a norm, the
+  SHARED table and head) that drafts the token after next:
+  ``hybrid_lm_round`` runs the model on a slot's current token and its
+  draft, two positions a slot, and the prediction layer behind it.
+
 The serving graphs only (serving/decode.py): ``hybrid_lm_prefill``
 walks padded prompts and returns every layer's cache entries AT EACH
 ROW'S LENGTH; ``hybrid_lm_decode`` advances them by one token. Both
@@ -107,7 +120,10 @@ key; a latent layer over a window ONE ``lring_i`` (B, window, its own
 kv_lora_rank + qk_rope_dim): position p at row p mod window. An EVA
 layer keeps ``keva_i`` / ``veva_i`` (B, max_len / eva_chunk + window,
 n_head, d_head): the pooled rows, the last chunk first, and after them
-the window's block, position p at p mod window, live up to p.
+the window's block, position p at p mod window, live up to p. A
+prediction layer is layer ``n_layer`` for its entries: ``index_<n_layer>``
+and ``latent_<n_layer>``, rows per position as any layer's under an
+indexer, written by a prefill, a step and a round alike.
 """
 from __future__ import annotations
 
@@ -150,8 +166,15 @@ def cache_names(kind: str, i: int):
     return ["kcache_%d" % i, "vcache_%d" % i]
 
 
-def _proj(x, size, name, bias=False):
-    """(B, T, in) -> (B, T, size); N(0, 0.02) weight ``name.w``."""
+def _proj(x, size, name, bias=False, dtype="float32"):
+    """(B, T, in) -> (B, T, size); N(0, 0.02) weight ``name.w``, held
+    in ``dtype`` (``DecodeConfig.matrix_dtype``; a matrix of another
+    type than its float32 input is its own parameter under a matmul:
+    ``ops/math.py: wmm``)."""
+    if dtype != "float32":
+        return layers.matmul(x, _param(
+            [int(x.shape[-1]), size], name + ".w",
+            NormalInitializer(0.0, 0.02), dtype=dtype))
     return layers.fc(
         x, size, num_flatten_dims=2,
         param_attr=ParamAttr(name=name + ".w",
@@ -174,9 +197,12 @@ def _norm(x, name, cfg):
     return _rms(x, name, cfg.norm_eps, cfg.norm_offset)
 
 
-def _param(shape, name, init, is_bias=False):
+def _param(shape, name, init, is_bias=False, dtype="float32"):
+    """A parameter of its own: float32, or a MATRIX in the type the
+    manifest holds matrices in (gains, biases, the router and its bias
+    stay float32 whatever that is)."""
     return layers.create_parameter(
-        shape=shape, dtype="float32", is_bias=is_bias,
+        shape=shape, dtype=dtype, is_bias=is_bias,
         attr=ParamAttr(name=name, initializer=init))
 
 
@@ -452,39 +478,48 @@ def _wide_latent_mixer(u, cfg, name, lengths, cache, kind):
     T, T) choice or over the window; the entries are the prompt's index
     keys and latent rows, or its last ``window`` rows packed into a
     ring) or the layer's entries (one token: append, then the absorbed
-    path over the chosen rows of the slab, or over the ring). Returns
-    (out, entries in ``cache_names`` order)."""
+    path over the chosen rows of the slab, or over the ring; under an
+    indexer also a WINDOW of T > 1 tokens at positions ``lengths ..
+    lengths + T - 1``: T rows appended to both slabs, each row's own
+    choice among the rows at or before it, the T x H query rows of a
+    slot on one stream of its live blocks). Returns (out, entries in
+    ``cache_names`` order)."""
     B, T, _ = u.shape
     geo, d, eps = cfg.latent_geometry(kind), cfg.d_model, cfg.norm_eps
     h, nope, rdim, vdim = geo.n_head, geo.nope, geo.rope, geo.v
     ring = kind == "latent_ring"
     w = NormalInitializer(0.0, 0.02)
     one = ConstantInitializer(1.0)
+    md = cfg.matrix_dtype
     rot = (cfg.rope or {}).get("latent_ring" if ring else "latent") or {}
     at = None if cache is None else lengths
-    c_q = _rms(_proj(u, geo.q_rank, name + ".q_a"), name + ".q_norm", eps)
+    c_q = _rms(_proj(u, geo.q_rank, name + ".q_a", dtype=md),
+               name + ".q_norm", eps)
     if geo.rho_q != 1.0:
         c_q = layers.scale(c_q, scale=geo.rho_q)
-    w_qb = _param([geo.q_rank, h * (nope + rdim)], name + ".q_b.w", w)
+    w_qb = _param([geo.q_rank, h * (nope + rdim)], name + ".q_b.w", w,
+                  dtype=md)
     rows = layers.mla_kv(
-        u, _param([d, geo.row], name + ".kv_a.w", w),
+        u, _param([d, geo.row], name + ".kv_a.w", w, dtype=md),
         _param([geo.rank], name + ".kv_norm.w", one),
         rdim, rot, positions=at, epsilon=eps, rescale=geo.rho_kv)
-    w_kvb = _param([geo.rank, h * (nope + vdim)], name + ".kv_b.w", w)
-    w_o = _param([h * vdim, d], name + ".o.w", w)
-    gate = (layers.sigmoid(_proj(u, h, name + ".gate"))
+    w_kvb = _param([geo.rank, h * (nope + vdim)], name + ".kv_b.w", w,
+                   dtype=md)
+    w_o = _param([h * vdim, d], name + ".o.w", w, dtype=md)
+    gate = (layers.sigmoid(_proj(u, h, name + ".gate", dtype=md))
             if cfg.attn_gate == "per_head" else None)
     if not ring:
         j, di = int(cfg.index_heads), int(cfg.index_head_dim)
         irot = cfg.rope["index"]
         keys = layers.dsa_index_keys(
-            u, _param([d, di], name + ".index.k.w", w),
+            u, _param([d, di], name + ".index.k.w", w, dtype=md),
             _param([di], name + ".index.k_norm.w", one),
             _param([di], name + ".index.k_norm.b", ConstantInitializer(0.0),
                    is_bias=True),
             irot, positions=at, epsilon=eps)
-        index = (_param([geo.q_rank, j * di], name + ".index.q.w", w),
-                 _param([d, j], name + ".index.weights.w", w))
+        index = (_param([geo.q_rank, j * di], name + ".index.q.w", w,
+                        dtype=md),
+                 _param([d, j], name + ".index.weights.w", w, dtype=md))
     scope = _MLA.LATENT_RING_ATTEND if ring else DSA_ATTEND
     if cache is None:
         mask = None if ring else layers.dsa_mask(
@@ -631,10 +666,12 @@ def _gmu_mixer(u, cfg, name, memory):
 
 
 def _mlp(x, cfg, name):
-    gate = layers.swish(_proj(x, cfg.d_inner, name + ".gate"), beta=1.0)
-    up = _proj(x, cfg.d_inner, name + ".up")
+    md = cfg.matrix_dtype
+    gate = layers.swish(_proj(x, cfg.d_inner, name + ".gate", dtype=md),
+                        beta=1.0)
+    up = _proj(x, cfg.d_inner, name + ".up", dtype=md)
     return _proj(layers.elementwise_mul(gate, up), cfg.d_model,
-                 name + ".down")
+                 name + ".down", dtype=md)
 
 
 def _experts(x, cfg, name, lengths, decode):
@@ -651,32 +688,37 @@ def _experts(x, cfg, name, lengths, decode):
                      NormalInitializer(0.0, 0.01))
               if cfg.router_bias else None),
         n_group=cfg.router_groups, topk_group=cfg.router_topk_groups)
+    md = cfg.matrix_dtype
     routed, load = layers.moe_experts(
         x, idx, weights,
-        _param([hi - lo, d, f], name + ".experts.gate.w", w),
-        _param([hi - lo, d, f], name + ".experts.up.w", w),
-        _param([hi - lo, f, d], name + ".experts.down.w", w),
+        _param([hi - lo, d, f], name + ".experts.gate.w", w, dtype=md),
+        _param([hi - lo, d, f], name + ".experts.up.w", w, dtype=md),
+        _param([hi - lo, f, d], name + ".experts.down.w", w, dtype=md),
         expert_lo=lo, lengths=lengths, decode=decode,
         count_elsewhere=cfg.router_groups > 1)
     fs = cfg.d_shared_expert
     if not fs:  # no shared expert: the routed part is the layer's
         return routed, load
     shared = layers.moe_shared(
-        x, _param([d, fs], name + ".shared.gate.w", w),
-        _param([d, fs], name + ".shared.up.w", w),
-        _param([fs, d], name + ".shared.down.w", w))
+        x, _param([d, fs], name + ".shared.gate.w", w, dtype=md),
+        _param([d, fs], name + ".shared.up.w", w, dtype=md),
+        _param([fs, d], name + ".shared.down.w", w, dtype=md))
     return layers.elementwise_add(routed, shared), load
 
 
-def _layer(x, kind, i, cfg, lengths, cache=None, loads=None, shared=None):
+def _layer(x, kind, i, cfg, lengths, cache=None, loads=None, shared=None,
+           ffn=None, prefix=None):
     """THE description of layer ``i``: x (B, T, D) -> (x, cache
     entries in ``cache_names(kind, i)`` order). Prefill and decode
     differ only in ``cache``. An expert layer appends its load to
-    ``loads``. ``shared`` (a dict) carries what a layer hands on to the
-    layers after it: a Mamba layer its ``memory`` (B, T, Di), a full
+    ``loads``. ``ffn`` names the feed-forward kind of a layer past the
+    model's ``n_layer`` and ``prefix`` what its parameters' names begin
+    with (a prediction layer's; ``cfg.ffn_kinds()[i]`` and
+    ``cfg.prefix`` otherwise). ``shared`` (a dict) carries what a layer
+    hands on to the layers after it: a Mamba layer its ``memory`` (B, T, Di), a full
     attention layer its ``kv`` = (k, v, rows seen): a ``gmu`` and a
     ``cross`` layer read the nearest before them and keep nothing."""
-    name = "%s.l%d" % (cfg.prefix, i)
+    name = "%s.l%d" % (prefix or cfg.prefix, i)
     shared = {} if shared is None else shared
     u = _norm(x, name + ".norm_in", cfg)
     entries = ()
@@ -711,7 +753,7 @@ def _layer(x, kind, i, cfg, lengths, cache=None, loads=None, shared=None):
             shared["kv"] = entries + (seen,)
     x = layers.elementwise_add(x, mixed)
     u = _norm(x, name + ".norm_ff", cfg)
-    if cfg.ffn_kinds()[i] == "experts":
+    if (ffn or cfg.ffn_kinds()[i]) == "experts":
         ffn, load = _experts(u, cfg, name + ".moe", lengths,
                              cache is not None)
         loads.append(load)
@@ -774,12 +816,16 @@ def _check(cfg):
             % (cfg.attn_value_scale,))
     for kind, rot in (cfg.rope or {}).items():
         if kind == "index":
-            # an indexer's queries and keys: their first channels, plain
-            if set(rot) != {"theta", "rotary_dim"} or not (
-                    0 < int(rot["rotary_dim"]) <= int(cfg.index_head_dim)):
+            # an indexer's queries and keys: their first channels, plain,
+            # half-split or on the pairs (2i, 2i+1) (``interleave``)
+            if (set(rot) - {"interleave"} != {"theta", "rotary_dim"}
+                    or not isinstance(rot.get("interleave", False), bool)
+                    or not (0 < int(rot["rotary_dim"])
+                            <= int(cfg.index_head_dim))):
                 raise ValueError(
-                    "rope['index'] = %r: an indexer's rotation is theta "
-                    "and a rotary_dim within index_head_dim" % (rot,))
+                    "rope['index'] = %r: an indexer's rotation is theta, "
+                    "a rotary_dim within index_head_dim and interleave"
+                    % (rot,))
             continue
         if kind in ("latent", "latent_ring"):
             # the whole rope part of a latent head turns; only YaRN
@@ -810,6 +856,29 @@ def _check(cfg):
         raise ValueError("an EVA layer is built without differential "
                          "attention, biases, an output gate and head "
                          "counts by layer")
+    if cfg.n_predict_layers and (
+            cfg.layer_kinds()[-1] != "latent_dsa" or cfg.tie_embeddings
+            or cfg.tail_start < cfg.n_layer):
+        raise ValueError(
+            "a prediction layer (n_predict_layers) is one more layer of "
+            "the model's last kind with entries of its own, built over a "
+            "last layer under an indexer ('latent_dsa': rows a position, "
+            "rolled back by length) and an untied head; got %r, "
+            "tie_embeddings=%r" % (cfg.layer_kinds()[-1],
+                                   cfg.tie_embeddings))
+    if cfg.matrix_dtype != "float32" and set(cfg.layer_kinds()) != {
+            "latent_dsa"}:
+        raise ValueError(
+            "matrix_dtype %r: matrices are held in bfloat16 for layers "
+            "under an indexer alone ('latent_dsa': the ops that meet them "
+            "round the activation, ops/math.py: wmm); got layers %s"
+            % (cfg.matrix_dtype, sorted(set(cfg.layer_kinds()))))
+    if cfg.matrix_dtype != "float32" and cfg.head_precision is not None:
+        raise ValueError(
+            "head_precision %r with matrix_dtype %r: a head held in "
+            "bfloat16 meets its rows rounded to bfloat16 (ops/math.py: "
+            "wmm); float32 products need a float32 head"
+            % (cfg.head_precision, cfg.matrix_dtype))
     if cfg.head_precision not in (None, "highest"):
         raise ValueError("head_precision %r: the head is computed at the "
                          "device's default precision (None) or 'highest'"
@@ -837,10 +906,14 @@ def _check(cfg):
 
 
 def _embed(tokens, cfg):
-    return layers.embedding(
-        input=tokens, size=[cfg.vocab_size, cfg.d_model],
+    """The table's rows of ``tokens``: float32 activations, whatever
+    type the table is held in."""
+    md = cfg.matrix_dtype
+    x = layers.embedding(
+        input=tokens, size=[cfg.vocab_size, cfg.d_model], dtype=md,
         param_attr=ParamAttr(name=cfg.prefix + ".tok_emb",
                              initializer=NormalInitializer(0.0, 0.02)))
+    return x if md == "float32" else layers.cast(x, "float32")
 
 
 def _head(last, cfg):
@@ -848,12 +921,58 @@ def _head(last, cfg):
     the head's own matrix ``head.w`` (D, V); in float32 products where
     ``cfg.head_precision`` is "highest"."""
     if not cfg.tie_embeddings:
+        # a second call (a prediction layer's logits) is handed the
+        # same parameter: ``create_parameter`` finds it by name
         return layers.matmul(last, _param(
             [cfg.d_model, cfg.vocab_size], cfg.prefix + ".head.w",
-            NormalInitializer(0.0, 0.02)), precision=cfg.head_precision)
+            NormalInitializer(0.0, 0.02), dtype=cfg.matrix_dtype),
+            precision=cfg.head_precision)
     emb = default_main_program().global_block().var(cfg.prefix + ".tok_emb")
     return layers.matmul(last, emb, transpose_y=True,
                          precision=cfg.head_precision)
+
+
+def _mtp(hidden, next_tokens, cfg, lengths, cache=None, loads=None):
+    """The multi-token-prediction layer (DeepSeek-V3, arXiv:2412.19437,
+    section 2.2; ``cfg.n_predict_layers`` 1): for position i, once
+    ``t_{i+1}`` is known, ``h'_i = W_eh [rms_e(Emb(t_{i+1})) ;
+    rms_h(h^_i)]`` (``hidden`` (B, T, D): the model's final hidden rows
+    AFTER its last norm; ``next_tokens`` (B, T) int), ONE decoder layer
+    of the model's own kind (its last layer's mixer and feed-forward)
+    with ITS OWN cache entries (layer index ``n_layer``), a norm, and
+    the model's table and head, SHARED. Every parameter of its own is
+    named ``<prefix>.mtp.*`` (its decoder layer ``<prefix>.mtp.l<n_layer>.
+    *``), so a device trace tells its operations by their scopes'
+    anchors. ``cache`` as ``_layer``'s.
+    Returns (x (B, T, D) normalised: ``_head`` of a row is the logits of
+    the token after next, ``t_{i+2}``; the layer's cache entries)."""
+    B, T, _ = hidden.shape
+    name = "%s.mtp" % cfg.prefix
+    i = cfg.n_layer
+    e = layers.reshape(_embed(next_tokens, cfg), shape=[B, T, cfg.d_model])
+    both = layers.concat([_norm(e, name + ".enorm", cfg),
+                          _norm(hidden, name + ".hnorm", cfg)], axis=-1)
+    x = _proj(both, cfg.d_model, name + ".eh_proj", dtype=cfg.matrix_dtype)
+    x, entries = _layer(x, cfg.layer_kinds()[-1], i, cfg, lengths,
+                        cache=cache, loads=loads, ffn=cfg.ffn_kinds()[-1],
+                        prefix=name)
+    return _norm(x, name + ".norm", cfg), entries
+
+
+def _predict(hidden, next_tokens, cfg, lengths, caches, new, loads, extras):
+    """Run the prediction layer inside a serving graph: on ``hidden``
+    and ``next_tokens`` (``_mtp``), its cache entries read from
+    ``caches`` (None: a prefill) and written into ``new``; ``extras``
+    then takes ``moe_load`` with the layer's row last. Returns its
+    normalised output (B, T, D)."""
+    names = cache_names(cfg.layer_kinds()[-1], cfg.n_layer)
+    m, entries = _mtp(
+        hidden, next_tokens, cfg, lengths, loads=loads,
+        cache=None if caches is None else tuple(caches[n] for n in names))
+    new.update(zip(names, entries))
+    if extras is not None and loads:
+        extras["moe_load"] = layers.stack(loads, axis=0)
+    return m
 
 
 def hybrid_lm_prefill(tokens, lengths, cfg, extras=None,
@@ -896,13 +1015,25 @@ def hybrid_lm_prefill(tokens, lengths, cfg, extras=None,
         x, entries = _layer(x, kind, i, cfg, lengths, loads=loads,
                             shared=shared)
         caches.update(zip(cache_names(kind, i), entries))
-    if extras is not None and loads:
+    if extras is not None and loads and not cfg.n_predict_layers:
         # (sparse layers, experts held) int32
         extras["moe_load"] = layers.stack(loads, axis=0)
     x = _norm(x, cfg.prefix + ".norm_f", cfg)
     if tail < cfg.n_layer:
         return _head(layers.reshape(x, shape=[B, cfg.d_model]), cfg), caches
-    return _head(last_rows(x), cfg), caches
+    logits = _head(last_rows(x), cfg)
+    if cfg.n_predict_layers:
+        # the prediction layer walks the prompt too: position i with the
+        # token after it, the last real position with the token the
+        # model has just chosen (greedy); its logits there are the first
+        # DRAFT's, for the token after that one
+        chosen = layers.argmax(logits, axis=-1)
+        m = _predict(x, layers.mtp_next_tokens(tokens, lengths, chosen),
+                     cfg, lengths, None, caches, loads, extras)
+        if extras is not None:
+            extras["draft"] = layers.argmax(_head(last_rows(m), cfg),
+                                            axis=-1)
+    return logits, caches
 
 
 def hybrid_lm_decode(tokens, lengths, caches, cfg, strategy="greedy",
@@ -911,8 +1042,16 @@ def hybrid_lm_decode(tokens, lengths, caches, cfg, strategy="greedy",
     """One token per slot: ``tokens`` (B, 1), ``lengths`` (B,) tokens
     each slot holds BEFORE this one, ``caches`` {feed name: entry} ->
     (next_ids (B,) or None, logits (B, V), {feed name: updated
-    entry})."""
+    entry}). A model with a prediction layer has ONE step graph,
+    ``hybrid_lm_round``: its plain step is a round (``serving/decode.py:
+    _StepOfRound``), and it is refused here."""
     _check(cfg)
+    if cfg.n_predict_layers:
+        raise ValueError(
+            "a model with a prediction layer steps by rounds "
+            "(hybrid_lm_round); its plain greedy step is the round with "
+            "the current token standing in for the draft, and no "
+            "sampling step is built over it")
     B = tokens.shape[0]
     # embedding squeezes the trailing ids dim of 1: restore the time axis
     x = layers.reshape(_embed(tokens, cfg), shape=[B, 1, cfg.d_model])
@@ -931,3 +1070,44 @@ def hybrid_lm_decode(tokens, lengths, caches, cfg, strategy="greedy",
     next_ids = sample_next(logits, strategy, seed, sample_k, sample_p,
                            temperature)
     return next_ids, logits, new
+
+
+def hybrid_lm_round(tokens, lengths, caches, cfg, extras=None):
+    """One ROUND of a model with a prediction layer: ``tokens`` (B, T),
+    a slot's current token and the T - 1 tokens drafted after it (T =
+    ``1 + cfg.n_predict_layers`` = 2), at positions ``lengths ..
+    lengths + T - 1``; ``caches`` {feed name: entry}, the prediction
+    layer's among them. The model runs on all T (T rows appended to each
+    layer's entries, each row's own choice among the rows at or before
+    it), ``spec_accept`` takes the drafted tokens the model itself would
+    have chosen, and the prediction layer runs on each position's hidden
+    row and the token the model chose after it. Greedy: the ids are the
+    model's argmaxes. Returns (next_ids (B, T): the model's choice after
+    each position, accept (B,) int32: drafted tokens taken, logits (B,
+    T, V), draft_logits (B, T, V): the prediction layer's, {feed name:
+    updated entry}); ``extras["draft"]`` (B,) is the draft for the NEXT
+    round, read at position ``accept``: the caller commits ``next_ids[:,
+    :accept + 1]`` and advances each slot's length by ``accept + 1``;
+    rows past that are hypotheses the next round overwrites."""
+    _check(cfg)
+    B, T = tokens.shape
+    D, V = cfg.d_model, cfg.vocab_size
+    x = layers.reshape(_embed(tokens, cfg), shape=[B, T, D])
+    new, loads, shared = {}, [], {}
+    for i, kind in enumerate(cfg.layer_kinds()):
+        names = cache_names(kind, i)
+        x, entries = _layer(x, kind, i, cfg, lengths,
+                            cache=tuple(caches[n] for n in names),
+                            loads=loads, shared=shared)
+        new.update(zip(names, entries))
+    x = _norm(x, cfg.prefix + ".norm_f", cfg)
+    logits = layers.reshape(
+        _head(layers.reshape(x, shape=[B * T, D]), cfg), shape=[B, T, V])
+    next_ids, accept = layers.spec_accept(tokens, logits)
+    m = _predict(x, next_ids, cfg, lengths, caches, new, loads, extras)
+    draft_logits = layers.reshape(
+        _head(layers.reshape(m, shape=[B * T, D]), cfg), shape=[B, T, V])
+    if extras is not None:
+        extras["draft"] = layers.spec_pick(
+            layers.argmax(draft_logits, axis=-1), accept)
+    return next_ids, accept, logits, draft_logits, new

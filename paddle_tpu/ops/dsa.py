@@ -52,6 +52,7 @@ from jax.experimental.pallas import tpu as pltpu
 from . import attention as _A
 from . import decode_stream as _DS
 from . import kv_cache as _KV
+from . import math as _W
 from . import rope as _R
 from .registry import register_op
 
@@ -72,12 +73,25 @@ def index_weight_scale(n_heads: int, head_dim: int) -> float:
     return float(n_heads) ** -0.5 * float(head_dim) ** -0.5
 
 
+def window_positions(positions, t):
+    """A cached step's positions: (B,) the position of a slot's FIRST
+    query row; a window of ``t`` > 1 rows stands at ``positions[b] +
+    0..t-1`` -> (B, t). None (a prefill: 0..T-1) and (B,) at one row
+    pass through."""
+    if positions is None or t == 1 or positions.ndim != 1:
+        return positions
+    return (positions.astype(jnp.int32)[:, None]
+            + jnp.arange(t, dtype=jnp.int32)[None, :])
+
+
 def _rotate_first(x, positions, rot):
     """x (B, T, H, d): its FIRST ``rot["rotary_dim"]`` channels rotated
-    in the half-split layout at ``positions`` (None: 0..T-1)."""
+    at ``positions`` (None: 0..T-1), in the half-split layout or, under
+    ``rot["interleave"]``, on the pairs (2i, 2i+1)."""
     inv = _R.rope_inv_freq(int(rot["rotary_dim"]),
                            float(rot.get("theta", 10000.0)))
-    return _R.rope(x, positions, inv)
+    return _R.rope(x, window_positions(positions, x.shape[1]), inv, 1.0,
+                   bool(rot.get("interleave", False)))
 
 
 def index_queries(c_q, u, w_iq, w_iw, positions, n_heads, rot):
@@ -86,10 +100,9 @@ def index_queries(c_q, u, w_iq, w_iw, positions, n_heads, rot):
     two inverse square roots)."""
     b, t, _ = c_q.shape
     with jax.named_scope(DSA_INDEX):
-        q_i = jnp.matmul(c_q, w_iq).reshape(b, t, int(n_heads), -1)
+        q_i = _W.wmm(c_q, w_iq).reshape(b, t, int(n_heads), -1)
         q_i = _rotate_first(q_i, positions, rot)
-        w = jnp.matmul(u, w_iw) * index_weight_scale(n_heads,
-                                                     q_i.shape[-1])
+        w = _W.wmm(u, w_iw) * index_weight_scale(n_heads, q_i.shape[-1])
         return q_i.astype(u.dtype), w.astype(jnp.float32)
 
 
@@ -97,7 +110,7 @@ def index_keys(u, w_ik, gain, bias, positions, eps, rot):
     """The index key a position keeps: u (B, T, D) -> (B, T, d) =
     LayerNorm(u W_Ik), its first channels rotated."""
     with jax.named_scope(DSA_INDEX):
-        k = jnp.matmul(u, w_ik).astype(jnp.float32)
+        k = _W.wmm(u, w_ik).astype(jnp.float32)
         mu = jnp.mean(k, axis=-1, keepdims=True)
         var = jnp.mean(jnp.square(k - mu), axis=-1, keepdims=True)
         k = (k - mu) * lax.rsqrt(var + eps) * gain + bias
@@ -221,21 +234,36 @@ def prefill_mask(q_i, w, k_i, topk, lengths=None, rows=_ROWS):
 _STEP_BLOCK = 1024
 
 
-def _step_scores_kernel(len_ref, q_ref, w_ref, k_ref, o_ref, *, block_s):
+def _step_scores_kernel(len_ref, q_ref, w_ref, k_ref, o_ref, *, block_s,
+                        n_q=1):
     """One (slot, block) grid cell of ``pallas_step_scores``: q_ref (1,
-    J, d), w_ref (1, J, 1), k_ref (1, BS, d) -> o_ref (1, 1, BS); zeros
-    for a block past the slot's live rows (never fetched)."""
+    n_q J, d), w_ref (1, n_q J, 1), k_ref (1, BS, d) -> o_ref (1, n_q,
+    BS); zeros for a block past the slot's live rows (never fetched).
+    A window's ``n_q`` query rows are scored one after the other on the
+    block ONE fetch brought in, each by the one-row body: the same
+    products in the same order as ``n_q`` steps."""
     j = pl.program_id(1)
     live_blocks = (len_ref[pl.program_id(0)] + block_s - 1) // block_s
+    heads = q_ref.shape[1] // n_q
+
+    def score(at):
+        """One query row's index heads on the block: ``at`` its rows of
+        q_ref and w_ref (None: all of them, the one-row step)."""
+        q = q_ref[0] if at is None else q_ref[0, at]
+        s = lax.dot_general(
+            q.astype(jnp.bfloat16), k_ref[0].astype(jnp.bfloat16),
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)               # (J, BS)
+        s = jnp.maximum(s, 0.0)
+        w = w_ref[0] if at is None else w_ref[0, at]
+        return jnp.sum(s * w, axis=0, keepdims=True)
 
     @pl.when(j < live_blocks)
     def _():
-        s = lax.dot_general(
-            q_ref[0].astype(jnp.bfloat16), k_ref[0].astype(jnp.bfloat16),
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)               # (J, BS)
-        o_ref[0] = jnp.sum(jnp.maximum(s, 0.0) * w_ref[0], axis=0,
-                           keepdims=True)
+        if n_q == 1:
+            o_ref[0] = score(None)
+        for t in range(n_q if n_q > 1 else 0):
+            o_ref[0, pl.ds(t, 1)] = score(pl.ds(t * heads, heads))
 
     @pl.when(j >= live_blocks)
     def _():
@@ -255,11 +283,14 @@ def step_block(s, d, dtype):
 
 
 def pallas_step_scores(q_i, w, keys, lens, block_s=_STEP_BLOCK,
-                       interpret=False):
+                       interpret=False, n_q=1):
     """``index_scores`` of ONE query row a slot through a kernel
     (``ptpu.dsa_index_step``): q_i (B, J, d), w (B, J), the slab of
     index keys (B, S, d) where it lies, ``lens`` (B,) live rows -> (B,
-    S) float32, zeros past a slot's last live block. A slot's live
+    S) float32, zeros past a slot's last live block. A window of
+    ``n_q`` query rows a slot: q_i (B, n_q J, d), w (B, n_q J), ``lens``
+    the LAST row's live rows -> (B, n_q, S): a block is fetched once
+    and scored ``n_q`` times. A slot's live
     blocks are read once, as float32, and rounded to bfloat16 in vector
     memory (the lax form converts the whole slab, every slot's every
     row, each step, and reads the copy once a chunk of heads); all
@@ -268,9 +299,11 @@ def pallas_step_scores(q_i, w, keys, lens, block_s=_STEP_BLOCK,
     s = keys.shape[1]
     rows = _DS.fit_block_rows(s, block_s)
     lens = jnp.clip(lens.reshape(-1).astype(jnp.int32), 0, s)
+    kernel = (functools.partial(_step_scores_kernel, block_s=rows)
+              if n_q == 1 else
+              functools.partial(_step_scores_kernel, block_s=rows, n_q=n_q))
     out = _A.named_pallas_call(
-        DSA_INDEX + "_step",
-        functools.partial(_step_scores_kernel, block_s=rows),
+        DSA_INDEX + "_step", kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, s // rows),
@@ -280,13 +313,13 @@ def pallas_step_scores(q_i, w, keys, lens, block_s=_STEP_BLOCK,
                 pl.BlockSpec((1, rows, d), lambda bi, i, lens_ref: (
                     bi, _DS.live_block(i, lens_ref, bi, rows), 0)),
             ],
-            out_specs=pl.BlockSpec((1, 1, rows),
+            out_specs=pl.BlockSpec((1, n_q, rows),
                                    lambda bi, i, lens_ref: (bi, 0, i))),
-        out_shape=jax.ShapeDtypeStruct((b, 1, s), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, n_q, s), jnp.float32),
         interpret=interpret,
         **_A._tpu_params("parallel", "arbitrary"),
     )(lens, q_i, w.astype(jnp.float32)[..., None], keys)
-    return out[:, 0]
+    return out[:, 0] if n_q == 1 else out
 
 
 def step_mask(q_i, w, keys, kv_lengths, topk):
@@ -294,26 +327,45 @@ def step_mask(q_i, w, keys, kv_lengths, topk):
     J), the slab of index keys (B, S, d) with ``kv_lengths`` (B,) live
     rows (this step's included) -> (B, S) bool. The scores by the
     kernel over a slot's live blocks where the device and the slab's
-    shape have one (``step_block``), by the lax form elsewhere."""
+    shape have one (``step_block``), by the lax form elsewhere. A WINDOW
+    of T > 1 query rows a slot (a round of a model with a prediction
+    layer): q_i (B, T, J, d), w (B, T, J), ``kv_lengths`` the FIRST
+    row's live rows, row t's ``kv_lengths + t`` -> (B, T, S): each row
+    its own choice of ``topk`` among its own live rows."""
+    b, t, j, d = q_i.shape
     s = keys.shape[1]
     lens = kv_lengths.reshape(-1).astype(jnp.int32)
-    live = jnp.arange(s, dtype=jnp.int32)[None, :] < lens[:, None]
-    if step_block(s, keys.shape[-1], keys.dtype) is not None:
-        scores = pallas_step_scores(q_i[:, 0], w[:, 0], keys, lens)
+    kernel = step_block(s, keys.shape[-1], keys.dtype) is not None
+    if t == 1:
+        live = jnp.arange(s, dtype=jnp.int32)[None, :] < lens[:, None]
+        if kernel:
+            scores = pallas_step_scores(q_i[:, 0], w[:, 0], keys, lens)
+        else:
+            scores = index_scores(q_i, w, keys)[:, 0]
+        return select(scores, live, topk)
+    row_lens = lens[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+    live = (jnp.arange(s, dtype=jnp.int32)[None, None, :]
+            < row_lens[:, :, None])
+    if kernel:
+        scores = pallas_step_scores(q_i.reshape(b, t * j, d),
+                                    w.reshape(b, t * j), keys,
+                                    lens + (t - 1), n_q=t)
     else:
-        scores = index_scores(q_i, w, keys)[:, 0]
+        scores = index_scores(q_i, w, keys)
     return select(scores, live, topk)
 
 
 def _index_rot(ctx):
     return {"theta": float(ctx.attr("theta", 10000.0)),
-            "rotary_dim": int(ctx.attr("rotary_dim"))}
+            "rotary_dim": int(ctx.attr("rotary_dim")),
+            "interleave": bool(ctx.attr("interleave", False))}
 
 
 @register_op("dsa_index_keys")
 def _index_keys_op(ctx):
     """Inputs X (B, T, D), W (D, d), Gain, Bias (d,), optional Positions
-    (B,) at T = 1. Attrs epsilon, theta, rotary_dim -> Out (B, T, d)."""
+    (B,): the first row's position. Attrs epsilon, theta, rotary_dim,
+    interleave -> Out (B, T, d)."""
     return {"Out": index_keys(
         ctx.input("X"), ctx.input("W"), ctx.input("Gain"), ctx.input("Bias"),
         ctx.input("Positions"), float(ctx.attr("epsilon", 1e-5)),
@@ -325,8 +377,9 @@ def _mask_op(ctx):
     """Inputs CQ (B, T, q_rank), X (B, T, D), WQ (q_rank, J * d), WW (D,
     J), Keys (B, T | S, d); a step's Positions (B,) and Lengths (B,) live
     rows; a prefill's optional Lengths (B,), the prompts' live tokens.
-    Attrs n_heads, topk, theta, rotary_dim -> Out: a prefill's (B, T, T)
-    int8 selection, or a step's (B, S) bool."""
+    Attrs n_heads, topk, theta, rotary_dim, interleave -> Out: a
+    prefill's (B, T, T) int8 selection, a step's (B, S) bool, or a
+    window's (B, T, S) bool (T > 1 query rows on the slab)."""
     pos = ctx.input("Positions")
     q_i, w = index_queries(ctx.input("CQ"), ctx.input("X"), ctx.input("WQ"),
                            ctx.input("WW"), pos, int(ctx.attr("n_heads")),

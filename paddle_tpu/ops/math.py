@@ -226,7 +226,30 @@ def _cumsum(ctx):
 # ---------------------------------------------------------------------------
 
 
+def wmm(x, w, precision=None):
+    """``x @ w`` where the matrix ``w`` may be HELD in bfloat16 beside
+    float32 activations (``DecodeConfig.matrix_dtype``): the activation
+    is rounded to bfloat16, the products summed in float32, the result
+    the activation's type. That is what a float32 matmul at the TPU's
+    default precision computes (it rounds both operands to bfloat16),
+    with the weight rounded once, when it was stored, and read at half
+    the bytes; no float32 copy of the matrix is made, so a ``precision``
+    is refused there and not dropped. Any other pair of types:
+    ``jnp.matmul`` at ``precision``."""
+    if w.dtype == jnp.bfloat16 and x.dtype != jnp.bfloat16:
+        if precision is not None:
+            raise ValueError(
+                "precision %r on a matrix held in bfloat16: the product "
+                "rounds the activation to bfloat16 whatever is asked"
+                % (precision,))
+        return jnp.matmul(x.astype(jnp.bfloat16), w,
+                          preferred_element_type=jnp.float32).astype(x.dtype)
+    return jnp.matmul(x, w, precision=precision)
+
+
 def _mm2d(x2, y2):
+    if y2.dtype == jnp.bfloat16 and x2.dtype != jnp.bfloat16:
+        return wmm(x2, y2)  # a matrix held in bfloat16
     out = (jnp.matmul(x2, y2, preferred_element_type=jnp.float32)
            if x2.dtype == jnp.bfloat16 else x2 @ y2)
     return out.astype(x2.dtype)
@@ -263,7 +286,7 @@ def _matmul(ctx):
     if ctx.attr("transpose_Y", False):
         y = jnp.swapaxes(y, -1, -2) if y.ndim > 1 else y
     # "highest": float32 products on a TPU too (a model's fp32 logits)
-    out = jnp.matmul(x, y, precision=ctx.attr("precision", None))
+    out = wmm(x, y, ctx.attr("precision", None))
     alpha = ctx.attr("alpha", 1.0)
     if alpha != 1.0:
         out = out * alpha

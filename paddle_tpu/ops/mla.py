@@ -70,6 +70,8 @@ from . import attention as _A
 from . import decode_stream as _DS
 from . import kv_cache as _KV
 from . import rope as _R
+from .dsa import window_positions as _window_positions
+from .math import wmm as _wmm
 from .ssm import rms_norm as _rms
 from .registry import register_op
 
@@ -108,7 +110,7 @@ def _rotate(x, positions, rot):
     attributes: theta, yarn, attention_factor, interleave)."""
     inv = _R.rope_inv_freq(x.shape[-1], rot.get("theta", 10000.0),
                            rot.get("yarn"))
-    return _R.rope(x, positions, inv,
+    return _R.rope(x, _window_positions(positions, x.shape[1]), inv,
                    float(rot.get("attention_factor", 1.0) or 1.0),
                    bool(rot.get("interleave", False)))
 
@@ -122,8 +124,8 @@ def mla_q(u, w_qa, g_q, w_qb, positions, n_head, rope_dim, eps, rot):
     set."""
     b, t, _ = u.shape
     with jax.named_scope(MLA_Q):
-        c_q = u if w_qa is None else _rms(jnp.matmul(u, w_qa), g_q, eps)
-        q = jnp.matmul(c_q, w_qb).reshape(b, t, n_head, -1)
+        c_q = u if w_qa is None else _rms(_wmm(u, w_qa), g_q, eps)
+        q = _wmm(c_q, w_qb).reshape(b, t, n_head, -1)
         nope = q.shape[-1] - rope_dim
         q = jnp.concatenate(
             [q[..., :nope], _rotate(q[..., nope:], positions, rot)], axis=-1)
@@ -140,7 +142,7 @@ def mla_kv(u, w_kva, g_kv, positions, rope_dim, eps, rot, rescale=1.0):
     times ``rescale`` where a model rescales it (``(d_model /
     kv_lora_rank)^1/2``: ``DecodeConfig.latent_rescale``)."""
     with jax.named_scope(MLA_KV):
-        row = jnp.matmul(u, w_kva)
+        row = _wmm(u, w_kva)
         rank = row.shape[-1] - rope_dim
         k_r = _rotate(row[:, :, None, rank:], positions, rot)[:, :, 0]
         c_kv = _rms(row[..., :rank], g_kv, eps)
@@ -270,18 +272,18 @@ def latent_prefill(c_q, rows, w_qb, w_kvb, gate, w_o, n_head, nope, scale,
         return out.reshape(b, t, g, -1)[..., :dv].astype(jnp.float32)
 
     def group(i, y):
-        q = jnp.matmul(c_q, lax.dynamic_slice_in_dim(
+        q = _wmm(c_q, lax.dynamic_slice_in_dim(
             w_qb, i * g * dq, g * dq, axis=1)).reshape(b, t, g, dq)
         q = jnp.concatenate(
             [q[..., :nope], _rotate(q[..., nope:], None, rot)], axis=-1)
-        kv = jnp.matmul(c_kv, lax.dynamic_slice_in_dim(
+        kv = _wmm(c_kv, lax.dynamic_slice_in_dim(
             w_kvb, i * g * per, g * per, axis=1)).reshape(b, t, g, per)
         k = jnp.concatenate([kv[..., :nope], k_r], axis=-1)
         ctx = attend(q.astype(c_q.dtype), k, kv[..., nope:])
         if gate is not None:
             ctx = ctx * lax.dynamic_slice_in_dim(gate, i * g, g,
                                                  axis=2)[..., None]
-        return y + jnp.matmul(
+        return y + _wmm(
             ctx.reshape(b, t, g * dv).astype(c_q.dtype),
             lax.dynamic_slice_in_dim(w_o, i * g * dv, g * dv, axis=0))
 
@@ -301,9 +303,18 @@ def _latent_attend_lax(q_row, slab, lens, rank, chosen=None):
     path of every shape and device ``latent_view`` has no block for."""
     s = slab.shape[1]
     scores = jnp.einsum("bhw,bsw->bhs", q_row, slab)
-    live = jnp.arange(s)[None, None, :] < lens[:, None, None]
-    if chosen is not None:
-        live = live & chosen[:, None, :]
+    if lens.ndim == 2:
+        # a window: lens (B, T), chosen (B, T, S); query row t H + h of
+        # q_row (B, T H, W) sees what window row t sees
+        h = q_row.shape[1] // lens.shape[1]
+        live = jnp.arange(s)[None, None, :] < lens[:, :, None]
+        if chosen is not None:
+            live = live & chosen
+        live = jnp.repeat(live, h, axis=1)
+    else:
+        live = jnp.arange(s)[None, None, :] < lens[:, None, None]
+        if chosen is not None:
+            live = live & chosen[:, None, :]
     scores = jnp.where(live, scores, _NEG)
     m = jnp.max(scores, axis=-1, keepdims=True)
     p = jnp.where(live, jnp.exp(scores - m), 0.0)
@@ -374,15 +385,21 @@ def chosen_view(s, h, row, rank, dtype, block_s=_LATENT_BLOCK_LANES):
 
 
 def _chosen_attend_kernel(len_ref, q_ref, k_ref, c_ref, o_ref, m_ref, l_ref,
-                          acc_ref, *, block_s, n_blk, rank):
-    """One (slot, block) grid cell: q_ref (1, H, row) pre-scaled, k_ref
-    (1, row, BS) a block of the slab's transposed view, c_ref (1, 1, BS)
-    1.0 where the position is chosen. An online softmax over the slot's
-    live blocks: ``m_ref``, ``l_ref`` (H, 1) and ``acc_ref`` (H, rank)
-    live across them. Both products on bfloat16 operands, what the lax
-    form's round to at the TPU's default precision; sums in float32."""
+                          acc_ref, *, block_s, n_blk, rank, n_q=1):
+    """One (slot, block) grid cell: q_ref (1, n_q H, row) pre-scaled,
+    k_ref (1, row, BS) a block of the slab's transposed view, c_ref (1,
+    n_q, BS) 1.0 where the position is chosen. An online softmax over
+    the slot's live blocks: ``m_ref``, ``l_ref`` (n_q H, 1) and
+    ``acc_ref`` (n_q H, rank) live across them. Both products on
+    bfloat16 operands, what the lax form's round to at the TPU's default
+    precision; sums in float32. A window's ``n_q`` query rows (each H
+    heads under its own choice) attend the block ONE fetch brought in
+    one after the other, each by the one-row body: the same products in
+    the same order as ``n_q`` steps (a block past a row's own live rows
+    is all masked for it and leaves its sums as they were)."""
     j = pl.program_id(1)
     live_blocks = (len_ref[pl.program_id(0)] + block_s - 1) // block_s
+    heads = q_ref.shape[1] // n_q
 
     @pl.when(j == 0)
     def _():
@@ -390,21 +407,30 @@ def _chosen_attend_kernel(len_ref, q_ref, k_ref, c_ref, o_ref, m_ref, l_ref,
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
+    def attend(k, q, keep, at):
+        """One query row's H heads on the block: ``at`` its rows of the
+        running sums."""
+        sc = jnp.where(keep, jnp.dot(
+            q.astype(jnp.bfloat16), k,
+            preferred_element_type=jnp.float32), _NEG)         # (H, BS)
+        m = jnp.maximum(m_ref[at], jnp.max(sc, axis=1, keepdims=True))
+        p = jnp.where(keep, jnp.exp(sc - m), 0.0)
+        corr = jnp.exp(m_ref[at] - m)
+        l_ref[at] = corr * l_ref[at] + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[at] = corr * acc_ref[at] + lax.dot_general(
+            p.astype(jnp.bfloat16), k[:rank], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[at] = m
+
     @pl.when(j < live_blocks)
     def _():
         k = k_ref[0].astype(jnp.bfloat16)                      # (row, BS)
-        keep = c_ref[0] > 0.5                                  # (1, BS)
-        sc = jnp.where(keep, jnp.dot(
-            q_ref[0].astype(jnp.bfloat16), k,
-            preferred_element_type=jnp.float32), _NEG)         # (H, BS)
-        m = jnp.maximum(m_ref[...], jnp.max(sc, axis=1, keepdims=True))
-        p = jnp.where(keep, jnp.exp(sc - m), 0.0)
-        corr = jnp.exp(m_ref[...] - m)
-        l_ref[...] = corr * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = corr * acc_ref[...] + lax.dot_general(
-            p.astype(jnp.bfloat16), k[:rank], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m
+        if n_q == 1:
+            keep = c_ref[0] > 0.5                              # (1, BS)
+            attend(k, q_ref[0], keep, Ellipsis)
+        for t in range(n_q if n_q > 1 else 0):
+            at = pl.ds(t * heads, heads)
+            attend(k, q_ref[0, at], c_ref[0, pl.ds(t, 1)] > 0.5, at)
 
     @pl.when(j == n_blk - 1)
     def _():
@@ -424,42 +450,55 @@ def pallas_chosen_attend(q_row, slab, lens, chosen, rank, name,
     heads on 16,384 positions, 8 MiB, do not wait in vector memory, and
     what an online softmax rounds otherwise (unnormalised weights to
     bfloat16: 0.002 of the output's norm, PERF.md, PR 32) the lax form
-    under a mask has no claim to either."""
-    b, h, w = q_row.shape
+    under a mask has no claim to either. A WINDOW: q_row (B, T H, W),
+    ``lens`` (B, T) and ``chosen`` (B, T, S): the T query rows of a slot
+    share ONE stream of its live blocks (as far as the last row's), each
+    under its own mask."""
+    b, hq, w = q_row.shape
     s = slab.shape[1]
-    rows = _DS.block_positions(chosen_view(s, h, w, rank, slab.dtype,
-                                            block_s))
+    n_q = 1 if lens.ndim == 1 else lens.shape[1]
+    rows = _DS.block_positions(chosen_view(s, hq // n_q, w, rank,
+                                            slab.dtype, block_s))
     if rows is None:
         raise ValueError("%s: no kernel for a slab of %d positions of %d %s"
                          % (name, s, w, jnp.dtype(slab.dtype).name))
     n_blk = s // rows
     lens = jnp.clip(lens, 0, s)
-    keep = chosen & (jnp.arange(s, dtype=jnp.int32)[None, :] < lens[:, None])
+    at = jnp.arange(s, dtype=jnp.int32)
+    if n_q == 1:
+        keep = chosen & (at[None, :] < lens[:, None])
+        kernel = functools.partial(_chosen_attend_kernel, block_s=rows,
+                                   n_blk=n_blk, rank=rank)
+    else:
+        keep = chosen & (at[None, None, :] < lens[:, :, None])
+        lens = lens[:, -1]  # the blocks streamed: the last row's
+        kernel = functools.partial(_chosen_attend_kernel, block_s=rows,
+                                   n_blk=n_blk, rank=rank, n_q=n_q)
 
     def block(bi, j, lens_ref):
         return (bi, 0, _DS.live_block(j, lens_ref, bi, rows))
 
     return _A.named_pallas_call(
-        name, functools.partial(_chosen_attend_kernel, block_s=rows,
-                                n_blk=n_blk, rank=rank),
+        name, kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, n_blk),
             in_specs=[
-                pl.BlockSpec((1, h, w), lambda bi, j, lens_ref: (bi, 0, 0)),
+                pl.BlockSpec((1, hq, w), lambda bi, j, lens_ref: (bi, 0, 0)),
                 pl.BlockSpec((1, w, rows), block),
-                pl.BlockSpec((1, 1, rows), block),
+                pl.BlockSpec((1, n_q, rows), block),
             ],
-            out_specs=pl.BlockSpec((1, h, rank),
+            out_specs=pl.BlockSpec((1, hq, rank),
                                    lambda bi, j, lens_ref: (bi, 0, 0)),
-            scratch_shapes=[pltpu.VMEM((h, 1), jnp.float32),
-                            pltpu.VMEM((h, 1), jnp.float32),
-                            pltpu.VMEM((h, rank), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((b, h, rank), q_row.dtype),
+            scratch_shapes=[pltpu.VMEM((hq, 1), jnp.float32),
+                            pltpu.VMEM((hq, 1), jnp.float32),
+                            pltpu.VMEM((hq, rank), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, hq, rank), q_row.dtype),
         interpret=interpret,
         **_A._tpu_params("parallel", "arbitrary"),
     )(lens, q_row, jnp.swapaxes(slab, 1, 2),
-      keep.astype(jnp.float32)[:, None, :])
+      keep.astype(jnp.float32)[:, None, :] if n_q == 1
+      else keep.astype(jnp.float32))
 
 
 def mla_decode(q, slab, lengths, w_kvb, scale, chosen=None,
@@ -479,19 +518,26 @@ def mla_decode(q, slab, lengths, w_kvb, scale, chosen=None,
     RING of latent rows (``latent_ring``) is a slab of ``window`` rows
     whose order the softmax does not see: ``lengths`` is then
     ``min(positions held, window)``."""
-    b, _, h, _ = q.shape
+    b, t, h, _ = q.shape
     s, rank = slab.shape[1], w_kvb.shape[0]
     nope = q.shape[-1] - (slab.shape[-1] - rank)
     view = (latent_view if chosen is None else chosen_view)(
         s, h, slab.shape[-1], rank, slab.dtype)
     kernel = _KV.decode_stream_rows(view) is not None
     once = kernel and _DS.kept_vmem_bytes(view) is not None
+    if t > 1 and chosen is None:
+        raise ValueError("mla_decode: a window of %d query rows is built "
+                         "under an indexer's choice alone" % t)
     MLA_TRACES.inc(path="absorbed_kernel_once" if once else
                    "absorbed_kernel" if kernel else "absorbed")
     with jax.named_scope(name):
         w_k, w_v = _split_kvb(w_kvb, h, nope)
+        if t > 1:
+            return _window_decode(
+                q, slab, lengths.reshape(-1).astype(jnp.int32), w_k, w_v,
+                scale, chosen, kernel, name)
         qf = q[:, 0].astype(jnp.float32)
-        q_lat = jnp.einsum("bhd,rhd->bhr", qf[..., :nope], w_k)
+        q_lat = _absorb(qf[..., :nope], w_k)
         q_row = jnp.concatenate([q_lat, qf[..., nope:]], axis=-1) * scale
         lens = lengths.reshape(-1).astype(jnp.int32)
         if kernel and chosen is not None:
@@ -501,14 +547,59 @@ def mla_decode(q, slab, lengths, w_kvb, scale, chosen=None,
             o_lat = pallas_latent_attend(q_row, slab, lens, rank)
         else:
             o_lat = _latent_attend_lax(q_row, slab, lens, rank, chosen)
-        out = jnp.einsum("bhr,rhd->bhd", o_lat, w_v)
+        out = _expand(o_lat, w_v)
         return out[:, None].astype(q.dtype)
+
+
+def _absorb(q_nope, w_k):
+    """``q~_h = q_nope_h W^K_h^T``: (B, H, nope) -> (B, H, rank); a
+    ``W_kvb`` held in bfloat16 meets the query rounded to it
+    (``math.wmm``'s rule)."""
+    if w_k.dtype == jnp.bfloat16:
+        return jnp.einsum("bhd,rhd->bhr", q_nope.astype(jnp.bfloat16), w_k,
+                          preferred_element_type=jnp.float32)
+    return jnp.einsum("bhd,rhd->bhr", q_nope, w_k)
+
+
+def _expand(o_lat, w_v):
+    """``o_h = o~_h W^V_h``: (B, H, rank) -> (B, H, v)."""
+    if w_v.dtype == jnp.bfloat16:
+        return jnp.einsum("bhr,rhd->bhd", o_lat.astype(jnp.bfloat16), w_v,
+                          preferred_element_type=jnp.float32)
+    return jnp.einsum("bhr,rhd->bhd", o_lat, w_v)
+
+
+def _window_decode(q, slab, lens, w_k, w_v, scale, chosen, kernel, name):
+    """``mla_decode`` of a window: q (B, T, H, nope + rope), ``lens``
+    (B,) the FIRST row's live rows (row t sees ``lens + t``), ``chosen``
+    (B, T, S). Row t's products are those of a step at its position:
+    ``_absorb`` and ``_expand`` a row at a time, the attention by the
+    step's kernel body a row (``pallas_chosen_attend``)."""
+    b, t, h, _ = q.shape
+    rank, nope = w_k.shape[0], w_k.shape[-1]
+    qf = q.astype(jnp.float32)
+    q_row = jnp.concatenate(
+        [jnp.concatenate([_absorb(qf[:, i, :, :nope], w_k),
+                          qf[:, i, :, nope:]], axis=-1) * scale
+         for i in range(t)], axis=1)                       # (B, T H, W)
+    row_lens = lens[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+    if kernel:
+        o_lat = pallas_chosen_attend(q_row, slab, row_lens, chosen, rank,
+                                     name + "_step")
+    else:
+        o_lat = _latent_attend_lax(q_row, slab, row_lens, rank, chosen)
+    o_lat = o_lat.reshape(b, t, h, rank)
+    return jnp.stack([_expand(o_lat[:, i], w_v) for i in range(t)],
+                     axis=1).astype(q.dtype)
 
 
 def mla_append(slab, row, pos, ring=False):
     """One latent row a slot: row (B, 1, W) at ``pos`` (B,) of slab (B,
     S, W), at ``pos mod S`` where the slab is a ``ring`` of the last S
-    positions. One dynamic-update-slice a slot and not ``kv_cache.
+    positions; a WINDOW's rows (B, T, W) at ``pos..pos + T - 1`` of a
+    slab (one update a slot: the form of ``ops/speculative.py:
+    cache_append_window`` without its scatter). One
+    dynamic-update-slice a slot and not ``kv_cache.
     cache_append``'s scatter: on the chip a row of 320 floats is no
     multiple of the 128 lanes, so the compiler lays the slab out with
     the SEQUENCE minor ({1,2,0}: 320 sublane rows of S lanes, no
@@ -516,19 +607,24 @@ def mla_append(slab, row, pos, ring=False):
     whole slab a step (compiled for a described v5e: PERF.md, PR 38); a
     dynamic-update-slice writes its column where the slab lies."""
     b, s = slab.shape[0], slab.shape[1]
+    t = 1
     if row.ndim == slab.ndim:
-        if row.shape[1] != 1:
-            raise ValueError("mla_append appends ONE row per sequence; "
-                             "New has time dim %d" % row.shape[1])
-        row = row[:, 0]
+        t = row.shape[1]
+        if t != 1 and ring:
+            raise ValueError("mla_append appends ONE row per sequence to "
+                             "a ring; New has time dim %d" % t)
+        if t == 1:
+            row = row[:, 0]
     pos = pos.reshape(-1).astype(jnp.int32)
-    pos = pos % s if ring else jnp.clip(pos, 0, s - 1)
+    # a window of T rows lands at pos..pos+T-1: a slot that has them
+    # (the server retires one that has not) is never clipped
+    pos = pos % s if ring else jnp.clip(pos, 0, s - t)
     zero = jnp.zeros((), jnp.int32)
     with jax.named_scope(MLA_APPEND):
         for i in range(b):
+            new = row[i][None, None, :] if t == 1 else row[i][None]
             slab = lax.dynamic_update_slice(
-                slab, row[i][None, None, :].astype(slab.dtype),
-                (jnp.int32(i), pos[i], zero))
+                slab, new.astype(slab.dtype), (jnp.int32(i), pos[i], zero))
         return slab
 
 

@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .math import wmm as _wmm
 from .registry import register_op
 
 MOE_ROUTE = "ptpu.moe_route"
@@ -145,6 +146,18 @@ def _add_by_token(out, rows, y, most):
         [total, jnp.zeros((1,) + ys.shape[1:], ys.dtype)])[first]
 
 
+def _grouped(x, w, sizes):
+    """``lax.ragged_dot`` of float32 rows on the held experts' matrices
+    (Eh, K, N); matrices HELD in bfloat16 (``DecodeConfig.
+    matrix_dtype``) meet the rows rounded to bfloat16 (the grouped
+    product wants operands of one type), sums in float32: what the
+    float32 product computes at the TPU's default precision."""
+    if w.dtype == jnp.bfloat16 and x.dtype != jnp.bfloat16:
+        return lax.ragged_dot(x.astype(jnp.bfloat16), w, sizes,
+                              preferred_element_type=jnp.float32)
+    return lax.ragged_dot(x, w, sizes)
+
+
 def _experts_grouped(x, local, w, token, w_gate, w_up, w_down, counts):
     """Pairs sorted by held expert, a grouped product a block of them."""
     n, d = x.shape
@@ -166,9 +179,8 @@ def _experts_grouped(x, local, w, token, w_gate, w_up, w_down, counts):
         sizes = (jnp.clip(ends, r0, r0 + blk)
                  - jnp.clip(starts, r0, r0 + blk)).astype(jnp.int32)
         xs = x[rows]
-        h = _silu(lax.ragged_dot(xs, w_gate, sizes)) * lax.ragged_dot(
-            xs, w_up, sizes)
-        y = lax.ragged_dot(h, w_down, sizes)
+        h = _silu(_grouped(xs, w_gate, sizes)) * _grouped(xs, w_up, sizes)
+        y = _grouped(h, w_down, sizes)
         # rows past the held pairs belong to no group and to no token:
         # whatever the product left there is not read
         live = (r0 + jnp.arange(blk)) < n_held
@@ -206,8 +218,7 @@ def moe_experts(x, idx, weights, w_gate, w_up, w_down, lo=0, valid=None,
 def moe_shared(x, w_gate, w_up, w_down):
     """The shared expert: (silu(x W_gate) * (x W_up)) W_down."""
     with jax.named_scope(MOE_SHARED):
-        return jnp.matmul(_silu(jnp.matmul(x, w_gate)) * jnp.matmul(x, w_up),
-                          w_down)
+        return _wmm(_silu(_wmm(x, w_gate)) * _wmm(x, w_up), w_down)
 
 
 @register_op("moe_route")

@@ -29,6 +29,13 @@ every later attention read and overwritten by later appends (the same
 discipline as prefill's past-length garbage rows). No slab copy, no
 scatter-undo: rollback is per-slot length truncation.
 
+A model that publishes a multi-token-prediction layer drafts for
+itself (``models/jamba.py: hybrid_lm_round``): ``spec_accept`` as it
+is, ``mtp_next_tokens`` (what that layer is fed beside a prompt) and
+``spec_pick`` (the next draft, read where the round stopped). Its rows
+per position are latent rows and index keys (``ops/mla.py: mla_append``
+of a window); the rollback contract is the one above.
+
 The same window graph doubles as the shared-prefix SUFFIX EXTENSION
 path (serving/prefix.py): a prompt whose header is prefix-cached feeds
 its remaining suffix through the verify executable chunk by chunk —
@@ -119,6 +126,45 @@ def spec_accept(proposed, logits):
                == next_ids[:, :-1]).astype(jnp.int32)           # (B, T-1)
     accept = jnp.sum(jnp.cumprod(matches, axis=1), axis=1).astype(jnp.int32)
     return next_ids, accept
+
+
+def mtp_next_tokens(tokens, lengths, first):
+    """What a prediction layer is fed beside a prompt's hidden rows:
+    tokens (B, S) shifted left by one (position i beside the token after
+    it), with ``first`` (B,), the token the model has just chosen, at
+    each row's last real position ``lengths - 1`` -> (B, S). Positions
+    past a row's length hold what the shift left there: rows no one
+    reads."""
+    b = tokens.shape[0]
+    nxt = jnp.concatenate([tokens[:, 1:], jnp.zeros_like(tokens[:, :1])],
+                          axis=1)
+    at = jnp.clip(lengths.reshape(-1).astype(jnp.int32) - 1, 0,
+                  tokens.shape[1] - 1)
+    return nxt.at[jnp.arange(b), at].set(first.reshape(-1).astype(nxt.dtype))
+
+
+def spec_pick(ids, accept):
+    """ids (B, T) read at column ``accept`` (B,) a row -> (B,): a
+    round's next draft is the prediction layer's choice at the LAST
+    position the round committed."""
+    at = jnp.clip(accept.reshape(-1, 1).astype(jnp.int32), 0,
+                  ids.shape[1] - 1)
+    return jnp.take_along_axis(ids, at, axis=1)[:, 0]
+
+
+@register_op("mtp_next_tokens")
+def _mtp_next_tokens_op(ctx):
+    """Inputs Tokens (B, S) int, Lengths (B,), First (B,) int -> Out (B,
+    S): ``mtp_next_tokens``."""
+    return {"Out": mtp_next_tokens(ctx.input("Tokens"), ctx.input("Lengths"),
+                                   ctx.input("First"))}
+
+
+@register_op("spec_pick")
+def _spec_pick_op(ctx):
+    """Inputs Ids (B, T), Accept (B,) int32 -> Out (B,): column
+    ``Accept`` of each row."""
+    return {"Out": spec_pick(ctx.input("Ids"), ctx.input("Accept"))}
 
 
 @register_op("cache_append_window")

@@ -236,7 +236,20 @@ class DecodeConfig:
     layer's slabs keep a position's row FLAT, K ``(n_kv x head_dim,)``
     beside V ``(n_kv x v_head_dim,)``, read where they lie by
     ``ptpu.decode_attn_uneven`` (``ops/kv_cache.py``); a ring keeps
-    ``(n_kv, width)`` rows of its own kind's head count."""
+    ``(n_kv, width)`` rows of its own kind's head count.
+    ``n_predict_layers`` (0 | 1): the model publishes a
+    multi-token-prediction layer (DeepSeek-V3's: one more decoder layer
+    of the last layer's kind behind two norms and a projection of
+    ``[embedding ; hidden]``, through the model's own table and head),
+    which keeps cache entries of its own as layer ``n_layer`` and drafts
+    the token after next: a server runs ROUNDS of two positions over it
+    (``DecodeServer``), greedy only, and the round is the model's one
+    step program (its plain greedy step is the round executable,
+    ``_StepOfRound``). ``matrix_dtype`` ("float32" | "bfloat16"): the
+    type the matrices (projections, experts, table, head) are HELD in;
+    gains, biases, the router and every cache entry stay float32.
+    ``rope["index"]`` may carry ``interleave``: the indexer rotates the
+    pairs (2i, 2i+1) where it is set."""
 
     FIELDS = ("vocab_size", "n_layer", "n_head", "d_model", "d_inner",
               "max_len", "tie_embeddings", "prefix", "eos_id")
@@ -270,7 +283,8 @@ class DecodeConfig:
                    ("index_topk", 0), ("eva_chunk", 0),
                    ("norm_offset", False), ("head_precision", None),
                    ("n_kv_head_by_kind", None), ("attn_sink", None),
-                   ("attn_value_scale", 1.0))
+                   ("attn_value_scale", 1.0), ("n_predict_layers", 0),
+                   ("matrix_dtype", "float32"))
     MIXERS = ("mamba", "attention", "sliding", "gmu", "cross", "latent",
               "kda", "latent_dsa", "latent_ring", "eva")
     # the kinds that keep latent rows
@@ -410,6 +424,14 @@ class DecodeConfig:
                 "%d experts in %r groups of which %r are kept do not hold "
                 "a top-%d" % (self.n_expert, self.router_groups,
                               self.router_topk_groups, self.expert_top_k))
+        if self.n_predict_layers not in (0, 1):
+            raise ValueError(
+                "n_predict_layers %r: one prediction layer (one draft a "
+                "round) is built, or none" % (self.n_predict_layers,))
+        if self.matrix_dtype not in ("float32", "bfloat16"):
+            raise ValueError(
+                "matrix_dtype %r: matrices are held in float32 or bfloat16"
+                % (self.matrix_dtype,))
         if self.diff_attn and self.n_kv_head % 2:
             raise ValueError(
                 "differential attention pairs its heads: %d key/value "
@@ -568,10 +590,31 @@ class DecodeConfig:
 
     @property
     def extra_fetches(self) -> List[str]:
-        """Names of what a prefill or a decode step returns AFTER its
-        cache entries: ``moe_load`` (sparse layers, experts held) int32
-        where a layer routes over experts."""
-        return ["moe_load"] if "experts" in self.ffn_kinds() else []
+        """Names of what a prefill, a decode step or a round returns
+        AFTER its cache entries: ``draft`` (B,) int64 where the model
+        has a prediction layer (its guess at the token after the one the
+        program chose), then ``moe_load`` (sparse layers, experts held)
+        int32 where a layer routes over experts."""
+        return ((["draft"] if self.n_predict_layers else [])
+                + (["moe_load"] if "experts" in self.ffn_kinds() else []))
+
+    def sparse_layers(self) -> List[int]:
+        """The layers that route over experts, in ``moe_load``'s row
+        order: the model's own, then a prediction layer (index
+        ``n_layer``) of that kind."""
+        kinds = self.ffn_kinds()
+        return ([i for i, k in enumerate(kinds) if k == "experts"]
+                + [self.n_layer + j for j in range(self.n_predict_layers)
+                   if kinds[-1] == "experts"])
+
+    def cache_layers(self):
+        """(index, mixer kind) of every layer that may own cache
+        entries: the model's, then a prediction layer as layer
+        ``n_layer`` of the last layer's kind."""
+        kinds = self.layer_kinds()
+        return list(enumerate(kinds)) + [
+            (self.n_layer + j, kinds[-1])
+            for j in range(self.n_predict_layers)]
 
     @property
     def is_opt_block(self) -> bool:
@@ -702,7 +745,9 @@ def cache_spec(config: DecodeConfig, slots: int, seq: int,
     its own row) whatever ``seq``, a Mamba layer's ``conv_i`` (slots, K - 1,
     d_inner) window and ``ssm_i`` (slots, d_inner, N) state, NOTHING for
     a ``gmu`` or a ``cross`` layer (it reads what another layer keeps;
-    a slab may so have several readers a step), a KDA layer's three
+    a slab may so have several readers a step), a prediction layer's
+    (``n_predict_layers``) two as layer ``n_layer``'s under an indexer,
+    a KDA layer's three
     windows ``convq_i``, ``convk_i``, ``convv_i`` (slots, K - 1, H *
     dk) and its ``kda_i`` (slots, H, dk, dv) matrix state; a slab's or a ring's
     row is ``config.kv_row``, flat under differential attention, or, where
@@ -730,7 +775,7 @@ def cache_spec(config: DecodeConfig, slots: int, seq: int,
     from ..models.jamba import cache_names
 
     out = []
-    for i, kind in enumerate(config.layer_kinds()):
+    for i, kind in config.cache_layers():
         names = cache_names(kind, i)
         if kind == "mamba":
             out.append(CacheEntry(
@@ -881,6 +926,22 @@ def _pairing_order(feed_names, fetch_names, spec_names):
     return traced, take, len(fed)
 
 
+def _accepted_tokens(next_row, accept: int, budget: int, eos):
+    """What one sequence takes of a speculative round (the OPT
+    self-draft's verify window, or a round over a prediction layer):
+    ``next_row[:accept + 1]``, the model's own choices, capped by
+    ``budget`` (tokens it may still take: its max_new and its slab's
+    room) and cut after an ``eos``. Returns (tokens, stopped at eos).
+    The acceptance counters are booked here, once a sequence a round."""
+    obs.DECODE_SPEC_ACCEPTED.inc(int(accept))
+    out = []
+    for j in range(max(min(int(accept) + 1, int(budget)), 0)):
+        out.append(int(next_row[j]))
+        if eos is not None and out[-1] == eos:
+            return out, True
+    return out, False
+
+
 _Step = collections.namedtuple(
     "_Step", "fn program feed_names fetch_names traced take n_cache n_tail")
 
@@ -913,6 +974,44 @@ class _InFetchOrder:
 
     def __getattr__(self, name):
         return getattr(self._loaded, name)
+
+
+class _StepOfRound:
+    """The plain one-token GREEDY step of a model with a prediction
+    layer: its ROUND executable, the current token standing in for the
+    draft as well. Position 0 of a round IS the plain step (position 1's
+    results are not read and its rows lie past the slot's length, where
+    the next step overwrites them), and being the SAME executable it is
+    so bit for bit: two programs of one mathematics (the step's dense
+    products at 16 rows, the round's at 32) differ in the order of their
+    float32 sums, a choice of 2,048 among thousands of scores turns a
+    difference in the last bit into another row now and then, and a
+    server's rounds would then leave the plain step's greedy tokens
+    after some tens of them (on the chip: 12 of 16 sequences within 140
+    tokens, PERF.md, PR 58). Called as a decode step is (``tokens`` (B,
+    1), a ``seed`` it ignores) and answering in a decode step's fetch
+    order: ids (B,), logits (B, V), the entries, the prediction layer's
+    choice at position 0, then the round's last fetches (``moe_load``
+    counts the stand-in position too). The round is the ONE step
+    program of such a model: a plain step pays for the second position,
+    and no sampling step is built (``hybrid_lm_decode`` refuses)."""
+
+    def __init__(self, round_exe, n_cache):
+        self._round, self._n_cache = round_exe, n_cache
+
+    def __call__(self, feeds, state):
+        feeds = {n: v for n, v in feeds.items() if n != "seed"}
+        tok = jnp.asarray(feeds["tokens"])
+        feeds["tokens"] = jnp.concatenate([tok, tok], axis=1)
+        outs = self._round(feeds, state)
+        ids, logits, draft_logits = outs[:3]
+        at = 3 + self._n_cache
+        draft = jnp.argmax(draft_logits[:, 0], axis=-1).astype(ids.dtype)
+        return ((ids[:, 0], logits[:, 0]) + tuple(outs[3:at]) + (draft,)
+                + tuple(outs[at + 1:]))
+
+    def __getattr__(self, name):
+        return getattr(self._round, name)
 
 
 _ALIAS_MAP = re.compile(r"input_output_alias=\{(.*?)\}, entry_computation")
@@ -984,9 +1083,15 @@ def save_decode_model(dirname: str, config: DecodeConfig, executor,
                                  dtype="int64", append_batch_size=False)
             lengths = layers.data(name="lengths", shape=[export_batch],
                                   dtype="int32", append_batch_size=False)
-            last_logits, _caches = _prefill_graph(config, tokens, lengths)
+            last_logits, rest = _prefill_graph(config, tokens, lengths)
+    targets = [last_logits]
+    if config.n_predict_layers:
+        # the first draft too: the export keeps what its targets need,
+        # and the prediction layer's parameters are needed by it alone
+        targets.append(rest[len(cache_spec(config, export_batch,
+                                           export_seq))])
     fluid_io.save_inference_model(
-        dirname, ["tokens", "lengths"], [last_logits], executor,
+        dirname, ["tokens", "lengths"], targets, executor,
         main_program=prog, scope=scope)
     with open(os.path.join(dirname, _DECODE_MANIFEST), "w") as f:
         json.dump(config.to_dict(), f, indent=2, sort_keys=True)
@@ -1087,9 +1192,15 @@ class DecodePredictor:
         decode executables take and return them (``cache_spec``)."""
         return cache_spec(self.config, slots, seq, kv_dtype)
 
-    def _rows_only(self, what: str):
+    def _rows_only(self, what: str, latent_rows: bool = False):
         """Levers that snapshot, roll back or reorder a cache work on
-        rows per position, through graphs written for OPT's block."""
+        rows per position. A state, a ring and an ``eva`` entry have
+        none to roll back to, whatever the graph. Latent rows and index
+        keys ARE rows per position: a window step built over them (a
+        ROUND of a model with a prediction layer, ``latent_rows``) rolls
+        back by length as one over K and V rows does; the levers written
+        for OPT's block (its verify window, row copies, int8 slabs)
+        read K and V and still refuse them."""
         if self.config.has_state:
             kinds = self.config.layer_kinds()
             raise ValueError(
@@ -1109,16 +1220,6 @@ class DecodePredictor:
                 "'ring'): a position that left the window is overwritten, "
                 "so there are no rows to roll back to or to share"
                 % (what, self.config.window))
-        if self.config.has_latent:
-            raise ValueError(
-                "%s is built for OPT's block only, through graphs that "
-                "read K and V rows; this model's latent layers keep one "
-                "row of %d floats a position (cache entries of kind "
-                "'latent'), which IS a row per position (it could be "
-                "rolled back by length or shared by prefix) but is "
-                "neither K nor V: no verify window, row copy or int8 "
-                "quantisation is built over it"
-                % (what, self.config.latent_row))
         if self.config.has_eva:
             raise ValueError(
                 "%s needs a cache of rows per position (it rolls back by "
@@ -1129,6 +1230,23 @@ class DecodePredictor:
                 "survives only pooled, so there are no rows to roll back "
                 "to or to share"
                 % (what, self.config.window, self.config.eva_chunk))
+        if latent_rows:
+            if not self.config.n_predict_layers:
+                raise ValueError(
+                    "%s needs a prediction layer to draft with "
+                    "(DecodeConfig.n_predict_layers); this model has none"
+                    % what)
+            return
+        if self.config.has_latent:
+            raise ValueError(
+                "%s is built for OPT's block only, through graphs that "
+                "read K and V rows; this model's latent layers keep one "
+                "row of %d floats a position (cache entries of kind "
+                "'latent'), which IS a row per position (a round of a "
+                "model with a prediction layer rolls it back by length) "
+                "but is neither K nor V: no OPT verify window, row copy "
+                "or int8 quantisation is built over it"
+                % (what, self.config.latent_row))
         if not self.config.is_opt_block:
             raise ValueError("%s is built for OPT's block only" % what)
 
@@ -1149,19 +1267,25 @@ class DecodePredictor:
         ``draft_n_layer`` depth — the speculative proposer, driven by
         the same loaded state), and "verify" (the ``window``-token
         speculative verify / prefix suffix-extension step: window
-        appends + staircase attention + in-graph accept)."""
+        appends + staircase attention + in-graph accept), and "round" (a
+        model with a prediction layer: its current token and its draft,
+        two positions a slot, the accept, and the prediction layer
+        behind them: ``models/jamba.py: hybrid_lm_round``)."""
         from .. import Program, layers, program_guard, unique_name
         from ..models import transformer as _T
 
         cfg = self.config
         if kind in ("draft", "verify"):
             self._rows_only("a %s step" % kind)
+        if kind == "round":
+            self._rows_only("a round of two positions", latent_rows=True)
         prog, startup = Program(), Program()
         with program_guard(prog, startup):
             with unique_name.guard():
-                if kind == "decode" and not cfg.is_opt_block:
+                if kind in ("decode", "round") and not cfg.is_opt_block:
                     return (prog,) + self._build_described_decode(
-                        batch, seq, strategy, kv_dtype)
+                        batch, seq, strategy, kv_dtype,
+                        round_=kind == "round")
                 if kind == "prefill":
                     tokens = layers.data(name="tokens", shape=[batch, seq],
                                          dtype="int64",
@@ -1271,35 +1395,60 @@ class DecodePredictor:
                         fetches = [next_ids.name] + fetches
         return prog, feeds, fetches
 
-    def _build_described_decode(self, batch, seq, strategy, kv_dtype):
+    def _build_described_decode(self, batch, seq, strategy, kv_dtype,
+                                round_=False):
         """The decode step of a block that is not OPT's, inside the
         caller's program guard: (feed_names, fetch_names). The cache
         feeds and the fetches of their updates both go in
-        ``cache_spec`` order (sorted names), no ``positions`` feed."""
+        ``cache_spec`` order (sorted names), no ``positions`` feed.
+        ``round_``: the ROUND of a model with a prediction layer in its
+        place: ``tokens`` (batch, 2), a slot's current token and its
+        draft, no ``seed`` (greedy); fetches ``ids`` (batch, 4) int64
+        = [the model's choice after each position | the drafted tokens
+        accepted (0 | 1) | the next round's draft], the logits and the
+        prediction layer's (batch, 2, V) each, the entries, then
+        ``config.extra_fetches``."""
         from .. import layers
         from ..models import jamba as _J
 
-        tokens = layers.data(name="tokens", shape=[batch, 1], dtype="int64",
-                             append_batch_size=False)
+        width = 1 + self.config.n_predict_layers if round_ else 1
+        tokens = layers.data(name="tokens", shape=[batch, width],
+                             dtype="int64", append_batch_size=False)
         lengths = layers.data(name="lengths", shape=[batch], dtype="int32",
                               append_batch_size=False)
-        seed = layers.data(name="seed", shape=[1], dtype="int64",
-                           append_batch_size=False)
+        if not round_:
+            seed = layers.data(name="seed", shape=[1], dtype="int64",
+                               append_batch_size=False)
         spec = self.cache_spec(batch, seq, kv_dtype)
         caches = {e.name: layers.data(name=e.name, shape=list(e.shape),
                                       dtype=e.dtype,
                                       append_batch_size=False)
                   for e in spec}
         extras = {}
-        next_ids, logits, new = _J.hybrid_lm_decode(
-            tokens, lengths, caches, self.config, strategy=strategy,
-            seed=seed, sample_k=self.sample_k, sample_p=self.sample_p,
-            temperature=self.temperature, extras=extras)
-        feeds = ["tokens", "lengths", "seed"] + [e.name for e in spec]
-        fetches = ([logits.name] + [new[e.name].name for e in spec]
+        if round_:
+            next_ids, accept, logits, draft_logits, new = _J.hybrid_lm_round(
+                tokens, lengths, caches, self.config, extras=extras)
+            # all a round's commit needs of it, in ONE array the host
+            # fetches: [next_ids | accept | the next draft]
+            ids = layers.concat(
+                [next_ids,
+                 layers.reshape(layers.cast(accept, "int64"),
+                                shape=[batch, 1]),
+                 layers.reshape(layers.cast(extras["draft"], "int64"),
+                                shape=[batch, 1])], axis=1)
+            head = [ids.name, logits.name, draft_logits.name]
+            feeds = ["tokens", "lengths"]
+        else:
+            next_ids, logits, new = _J.hybrid_lm_decode(
+                tokens, lengths, caches, self.config, strategy=strategy,
+                seed=seed, sample_k=self.sample_k, sample_p=self.sample_p,
+                temperature=self.temperature, extras=extras)
+            head = ([next_ids.name] if next_ids is not None else []) + [
+                logits.name]
+            feeds = ["tokens", "lengths", "seed"]
+        feeds += [e.name for e in spec]
+        fetches = (head + [new[e.name].name for e in spec]
                    + [extras[n].name for n in self.config.extra_fetches])
-        if next_ids is not None:
-            fetches = [next_ids.name] + fetches
         return feeds, fetches
 
     # -- compilation ------------------------------------------------------
@@ -1349,6 +1498,17 @@ class DecodePredictor:
             obs.CACHE_HITS.inc(kind=kind, tier="memory",
                                program=self.fingerprint())
             return hit
+        if (kind == "decode" and self.config.n_predict_layers
+                and ck[3] == "greedy" and ck[4] == "float32"):
+            # ONE step program for a model with a prediction layer: its
+            # greedy step is its round (``_StepOfRound`` says why)
+            rexe, _ = self._acquire("round", batch, seq, None, "float32", 0)
+            spec = [e.name for e in self.cache_spec(batch, seq)]
+            hit = (_StepOfRound(rexe, len(spec)),
+                   ["next_ids", "logits"] + spec + self.config.extra_fetches)
+            with self._lock:
+                self._compiled[ck] = hit
+            return hit
         return self._acquire_keyed(ck, self._keyed(ck))
 
     def _signature(self, kind, batch, seq, strategy=None,
@@ -1359,7 +1519,7 @@ class DecodePredictor:
         strategy = strategy or self.strategy
         if kind not in ("decode", "draft"):
             kv_dtype = "float32"
-        if kind == "draft":
+        if kind in ("draft", "round"):
             strategy = "greedy"  # proposals are always argmax
         use_ring = bool(kind == "prefill"
                         and self.ring_prefill_min_seq is not None
@@ -1828,17 +1988,11 @@ class DecodePredictor:
                 if finished[i]:
                     continue
                 a = int(accept[i])
-                obs.DECODE_SPEC_ACCEPTED.inc(a)
-                take = min(a + 1,
-                           max_new_tokens - len(generated[i]))
-                for j in range(take):
-                    tok = int(next_ids[i, j])
-                    generated[i].append(tok)
-                    emitted += 1
-                    if eos is not None and tok == eos:
-                        finished[i] = True
-                        break
-                if len(generated[i]) >= max_new_tokens:
+                toks, stopped = _accepted_tokens(
+                    next_ids[i], a, max_new_tokens - len(generated[i]), eos)
+                generated[i].extend(toks)
+                emitted += len(toks)
+                if stopped or len(generated[i]) >= max_new_tokens:
                     finished[i] = True
                 if not finished[i]:
                     # rollback by truncation: rows past lens+a are
@@ -2064,6 +2218,17 @@ class DecodeServer:
                                        block=prefix_block)
         else:
             self._prefix = None
+        # a model that publishes a prediction layer drafts for itself:
+        # its server runs ROUNDS of two positions a slot (the current
+        # token and the draft), ONE dispatch and ONE fetch each, without
+        # being asked. The round is the model's one step program
+        # (the OPT self-draft and an int8 slab were refused above)
+        self.rounds = bool(cfg.n_predict_layers)
+        if self.rounds and self.strategy != "greedy":
+            raise ValueError(
+                "a model with a prediction layer is served by rounds, "
+                "which are lossless for greedy only; the server strategy "
+                "is %r" % (self.strategy,))
         if (self.speculative or self._prefix is not None) \
                 and self.kv_dtype == "int8":
             raise ValueError(
@@ -2122,8 +2287,7 @@ class DecodeServer:
         self._uneven_kv = cfg.uneven_kv
         # layers that route over experts: a step and a prefill return
         # the pairs each held expert received (``moe_load``, last)
-        self._moe_layers = [i for i, k in enumerate(cfg.ffn_kinds())
-                            if k == "experts"]
+        self._moe_layers = cfg.sparse_layers()
         lo, hi = cfg.held
         self.moe_load_total = np.zeros((len(self._moe_layers), hi - lo),
                                        np.int64)
@@ -2136,6 +2300,9 @@ class DecodeServer:
         # verify window are lax paths of their own; so is a slab of
         # fewer heads than the query that the kernel has no view of)
         self._stream_rows = None
+        # tokens the last round committed (a round's dispatch carries
+        # it: what THIS one commits is known when its ids land)
+        self._round_committed = 0
         if self.kv_dtype == "float32" and not self.speculative:
             from ..models import jamba as _J
 
@@ -2250,9 +2417,13 @@ class DecodeServer:
             # it: then the preload has it in memory already)
             t0 = time.perf_counter()
             self.predictor.preload()
-            self.predictor.acquire("decode", self.slots, self.seq,
-                                   self.strategy, kv_dtype=self.kv_dtype)
-            if not self.speculative:
+            if self.rounds:
+                self.predictor.acquire("round", self.slots, self.seq)
+            else:
+                self.predictor.acquire("decode", self.slots, self.seq,
+                                       self.strategy,
+                                       kv_dtype=self.kv_dtype)
+            if not (self.speculative or self.rounds):
                 _chain_fn(self.slots, self.predictor._device)
             sp = min(16, self.seq)
             self.predictor.acquire("prefill", 1, sp)
@@ -2530,6 +2701,10 @@ class DecodeServer:
                 if seed is not None and self.strategy not in ("greedy",):
                     first[i] = self.predictor._sample_host(
                         outs[0][i:i + 1], self.strategy, seed)[0]
+            # a model with a prediction layer hands back the first DRAFT
+            # beside the first token: the entry after the cache entries
+            drafts = (np.asarray(outs[1 + len(self._spec)])
+                      if self.rounds else None)
         pf_ms = self._observe_prefill(t_pf, waited)
         if self._moe_layers:
             # the prefill has ended (the host has its logits): no wait
@@ -2545,6 +2720,8 @@ class DecodeServer:
             tok = int(first[i])
             st = {"rid": rid, "generated": [tok], "max_new": max_new,
                   "cur": tok, "count": 1}
+            if drafts is not None:
+                st["draft"] = int(drafts[i])
             lens[slot] = len(prompt)
             active[slot] = st
             _tracing.rid_span(rid, "decode.admit", kind="fresh",
@@ -3114,49 +3291,93 @@ class DecodeServer:
             return self._fail_all_active(active, lens, e)
         obs.DECODE_STEP_MS.observe((t1 - t0) * 1e3, stage="verify")
         with _tracing.phase("decode.loop.retire"):
-            return self._spec_commit(vouts, next_ids, accept, lens,
-                                     active, n_active)
+            return self._spec_commit(list(vouts[3:]), next_ids, accept,
+                                     lens, active, self.spec_k)
 
-    def _spec_commit(self, vouts, next_ids, accept, lens, active,
-                     n_active):
-        """The bookkeeping half of a speculative round: each slot takes
-        its accepted tokens, finished ones retire."""
-        k = self.spec_k
-        self.step_active_counts.append(n_active)
-        caches = list(vouts[3:])
-        obs.DECODE_SPEC_PROPOSED.inc(k * n_active)
+    def _mtp_round(self, rexe, caches, lens, active, n_active):
+        """One ROUND of a model with a prediction layer, every live slot
+        at once: the model on a slot's current token and its draft (two
+        positions, ``lens`` and ``lens + 1``), the accept, and the
+        prediction layer behind them, in ONE executable: one dispatch,
+        one fetch (the ids, the accept counts and the next drafts are
+        one array), where the OPT self-draft makes ``spec_k`` of each
+        before its verify call. The host stays in the loop: the accept
+        counts decide the next lengths. Greedy-lossless: what a slot
+        commits are the model's own argmaxes."""
+        with _tracing.phase("decode.loop.feeds"):
+            tokens = np.zeros((self.slots, 2), np.int64)
+            for i, st in enumerate(active):
+                if st is not None:
+                    tokens[i] = st["cur"], st["draft"]
+            feeds = {"tokens": tokens, "lengths": lens.copy()}
+            feeds.update(zip(self._cache_feed_names, caches))
+        try:
+            with _tracing.phase("decode.loop.dispatch",
+                                round_positions=2 * n_active,
+                                round_committed=self._round_committed,
+                                **self._step_counts(lens, n_active)) as ph:
+                t0 = ph.t0 or time.perf_counter()
+                outs = rexe(feeds, self.predictor._state)
+                outs[0].copy_to_host_async()
+                if self._moe_layers:
+                    outs[-1].copy_to_host_async()
+            obs.DECODE_STEPS.inc(in_flight="0")
+            with _tracing.phase("decode.loop.fetch") as ph:
+                ids = np.asarray(outs[0]).astype(np.int64)
+                if self._moe_layers:
+                    self._note_load(outs[-1])
+            t1 = ph.t1 or time.perf_counter()
+        except Exception as e:
+            return self._fail_all_active(active, lens, e)
+        obs.DECODE_STEP_MS.observe((t1 - t0) * 1e3, stage="round")
+        with _tracing.phase("decode.loop.retire"):
+            return self._spec_commit(
+                list(outs[3:3 + len(self._spec)]), ids[:, :2], ids[:, 2],
+                lens, active, 1, drafts=ids[:, 3])
+
+    def _spec_commit(self, caches, next_ids, accept, lens, active,
+                     proposed, drafts=None):
+        """The bookkeeping half of a speculative round, the OPT
+        self-draft's and a prediction layer's alike: each slot takes
+        its accepted tokens (``next_ids[i, :accept[i] + 1]``, of
+        ``proposed`` drafted ones), finished ones retire; ``drafts``
+        (a prediction layer's): each slot's draft for the next round.
+        ``step_active_counts`` takes the tokens the round delivered, as
+        ``_deliver`` gives it a step's. Returns ``caches``."""
+        n_active = sum(1 for a in active if a is not None)
+        obs.DECODE_SPEC_PROPOSED.inc(proposed * n_active)
         emitted = 0
         traced = _tracing.bound()
         for i, st in enumerate(active):
             if st is None:
                 continue
             a = int(accept[i])
-            obs.DECODE_SPEC_ACCEPTED.inc(a)
             if traced:
                 _tracing.rid_span(st["rid"], "decode.spec_round",
-                                  accepted=a, proposed=k)
+                                  accepted=a, proposed=proposed)
             # cap by budget and slab room: window position j needs rows
             # lens..lens+j resident, so at most seq - lens tokens
-            take = min(a + 1, st["max_new"] - st["count"],
-                       self.seq - int(lens[i]))
-            consumed = take
-            stopped = False
-            for j in range(take):
-                tok = int(next_ids[i, j])
-                st["generated"].append(tok)
-                st["cur"] = tok
-                st["count"] += 1
-                emitted += 1
-                if self.eos_id is not None and tok == self.eos_id:
-                    stopped = True
-                    consumed = j + 1
-                    break
-            lens[i] += consumed
+            toks, stopped = _accepted_tokens(
+                next_ids[i], a, min(st["max_new"] - st["count"],
+                                    self.seq - int(lens[i])), self.eos_id)
+            st["generated"].extend(toks)
+            st["count"] += len(toks)
+            if toks:
+                st["cur"] = toks[-1]
+            emitted += len(toks)
+            lens[i] += len(toks)
+            if drafts is not None:
+                # read at the last position the model accepted: a slot
+                # that took fewer (its budget, the slab's end, eos)
+                # retires below and never feeds it
+                st["draft"] = int(drafts[i])
             if stopped or st["count"] >= st["max_new"] \
                     or lens[i] + 1 >= self.seq:
                 self._retire(st)
                 active[i] = None
                 lens[i] = 0
+        self.step_active_counts.append(emitted)
+        self._round_committed = emitted
         obs.DECODE_TOKENS.inc(emitted, kind="decode")
         return caches
 
@@ -3267,15 +3488,18 @@ class DecodeServer:
         lens = np.zeros((self.slots,), np.int32)
         active: List[Optional[dict]] = [None] * self.slots
         pending: List[tuple] = []
-        dexe, _ = self.predictor.acquire("decode", self.slots, self.seq,
-                                         self.strategy,
-                                         kv_dtype=self.kv_dtype)
+        if self.rounds:
+            rexe, _ = self.predictor.acquire("round", self.slots, self.seq)
+        else:
+            dexe, _ = self.predictor.acquire("decode", self.slots,
+                                             self.seq, self.strategy,
+                                             kv_dtype=self.kv_dtype)
         if self.speculative:
             drexe, _ = self.predictor.acquire("draft", self.slots,
                                               self.seq)
             vexe, _ = self.predictor.acquire("verify", self.slots,
                                              self.seq, window=self._win)
-        else:
+        elif not self.rounds:
             chain = _chain_fn(self.slots, self.predictor._device)
         # the plain branch's step in flight (dispatched, its ids not
         # yet read), and when the last token reached the host
@@ -3348,9 +3572,12 @@ class DecodeServer:
                         caches = self._admit(group, caches, lens, active)
                     n_active = sum(1 for a in active if a is not None)
                 self._set_slot_gauges(n_active)
-                if self.speculative and n_active:
-                    caches = self._spec_round(drexe, vexe, caches, lens,
-                                              active, n_active)
+                if (self.speculative or self.rounds) and n_active:
+                    caches = (
+                        self._mtp_round(rexe, caches, lens, active, n_active)
+                        if self.rounds else
+                        self._spec_round(drexe, vexe, caches, lens, active,
+                                         n_active))
                     self._set_slot_gauges(
                         sum(1 for a in active if a is not None))
                     continue

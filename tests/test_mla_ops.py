@@ -165,8 +165,15 @@ def test_append_writes_one_row_a_slot_at_its_position():
     # a position past the slab's end is held to its last row
     got = np.asarray(mla.mla_append(slab, row, jnp.asarray([9, 5])))
     np.testing.assert_array_equal(got[0, 5], [-1, -2, -3])
+    # a window of rows lands at pos..pos + T - 1 (since PR 58: a round of
+    # a model with a prediction layer); a ring still takes ONE row
+    got = np.asarray(mla.mla_append(slab, jnp.ones((2, 2, 3)),
+                                    jnp.asarray([0, 3])))
+    np.testing.assert_array_equal(got[0, :2], np.ones((2, 3)))
+    np.testing.assert_array_equal(got[1, 3:5], np.ones((2, 3)))
     with pytest.raises(ValueError, match="ONE row"):
-        mla.mla_append(slab, jnp.zeros((2, 2, 3)), jnp.asarray([0, 0]))
+        mla.mla_append(slab, jnp.zeros((2, 2, 3)), jnp.asarray([0, 0]),
+                       ring=True)
 
 
 def test_ops_through_the_layers_api_and_the_traces_counter():

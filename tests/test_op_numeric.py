@@ -652,6 +652,9 @@ COVERED_ELSEWHERE = {
     # (window append, staircase attention, accept against the plain
     # decode loop); shapes and rules in tests/test_kv_cache_ops.py
     "cache_append_window", "decode_attention_window", "spec_accept",
+    # a prediction layer's next tokens and the next draft's pick
+    # (tests/test_glm5_decode.py::test_next_tokens_and_the_pick)
+    "mtp_next_tokens", "spec_pick",
     # state-space layers and RMS norm: tests/test_ssm_ops.py (numpy
     # recurrence, padded vs unpadded, scan-then-step, infer rules)
     "rms_norm", "ssm_scan", "ssm_step", "causal_conv1d",

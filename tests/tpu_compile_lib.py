@@ -117,6 +117,7 @@ def _serving_step(pred, kind, batch, seq, one_chip, **kw):
     function is `DecodePredictor._step`'s: the very one `_acquire` jits,
     its outputs in the order it traces them."""
     from paddle_tpu.executor import analyze_state
+    from paddle_tpu.framework.dtypes import as_numpy_dtype
 
     pred.traces = 0
     step = pred._step(kind, batch, seq, "greedy", **kw)
@@ -128,5 +129,8 @@ def _serving_step(pred, kind, batch, seq, one_chip, **kw):
     state = {}
     for n in analyze_state(step.program, set(step.feed_names))[0]:
         var = gb._find_var_recursive(n)
-        state[n] = sds(tuple(var.shape), np.float32, sharding=one_chip)
+        # in the type it is HELD in (float32, but for a manifest whose
+        # matrices are bfloat16)
+        state[n] = sds(tuple(var.shape), np.dtype(as_numpy_dtype(var.dtype)),
+                       sharding=one_chip)
     return step.fn, feeds, state, step.n_cache
