@@ -892,6 +892,58 @@ def _chain_fn(slots: int, device):
             jax.ShapeDtypeStruct((slots,), np.bool_), row, row).compile()
 
 
+@functools.lru_cache(maxsize=None)
+def _round_chain_fn(slots: int, seq: int, eos, device):
+    """The compiled select that builds a ROUND's ``tokens`` and
+    ``lengths`` feeds on the device while the round before it is still
+    in flight: (fresh, tokens, lengths, remaining, ids, prev) -> (tokens
+    (slots, 2), lengths (slots,), state (slots, 2)). A slot where
+    ``fresh`` (admitted since, or free) takes the host's three: its
+    current token and draft, its length, the tokens it may still take
+    (``max_new - count``; 0 for a free slot). Every other slot is
+    advanced from that round's ``ids`` ([a_1, a_2, accept, next draft])
+    and from what that round was fed (``prev``: this function's own
+    ``state`` a round ago, [length, remaining] a slot), by
+    ``_accepted_tokens``' arithmetic: it took ``min(1 + accept,
+    remaining, seq - length)`` tokens, cut after an ``eos``; the last of
+    them is its current token, ``ids[:, 3]`` its draft. A slot that
+    ended there (its budget, the slab's end, eos) is fed as a FREE slot
+    from then on (length 0, tokens 0, remaining 0), so no round reads or
+    writes a row at or past ``seq``; the host learns of it when it reads
+    the ids, a round late. ``state`` repeats the lengths in an array of
+    its own: the round DONATES its feeds, ``lengths`` among them. One
+    shape a server, compiled ahead like ``_chain_fn``."""
+    ids_t = jax.dtypes.canonicalize_dtype(np.int64)
+
+    def chain(fresh, tokens, lengths, remaining, ids, prev):
+        was, had = prev[:, 0], prev[:, 1]
+        take = jnp.clip(jnp.minimum(1 + ids[:, 2],
+                                    jnp.minimum(had, seq - was)), 0, 2)
+        stopped = jnp.zeros((slots,), jnp.bool_)
+        if eos is not None:
+            hit = ids[:, :2] == eos
+            take = jnp.where(hit[:, 0], jnp.minimum(take, 1), take)
+            stopped = (hit[:, 0] & (take >= 1)) | (hit[:, 1] & (take == 2))
+        length, left = was + take, had - take
+        live = (had > 0) & ~stopped & (left > 0) & (length + 1 < seq)
+        cur = jnp.where(take == 2, ids[:, 1], ids[:, 0])
+        chained = jnp.where(live[:, None],
+                            jnp.stack([cur, ids[:, 3]], axis=1), 0)
+        lengths = jnp.where(fresh, lengths, jnp.where(live, length, 0))
+        remaining = jnp.where(fresh, remaining, jnp.where(live, left, 0))
+        return (jnp.where(fresh[:, None], tokens, chained), lengths,
+                jnp.stack([lengths, remaining], axis=1))
+
+    chain.__name__ = chain.__qualname__ = "ptpu_chain_round"
+    row = jax.ShapeDtypeStruct((slots,), np.int32)
+    with jax.default_device(device):
+        return jax.jit(chain).lower(
+            jax.ShapeDtypeStruct((slots,), np.bool_),
+            jax.ShapeDtypeStruct((slots, 2), ids_t), row, row,
+            jax.ShapeDtypeStruct((slots, 4), ids_t),
+            jax.ShapeDtypeStruct((slots, 2), np.int32)).compile()
+
+
 def _pairing_order(feed_names, fetch_names, spec_names):
     """(traced, take, n): the order a step's outputs are TRACED in, the
     index into them of each of ``fetch_names`` (None where the two
@@ -952,11 +1004,14 @@ _Keyed = collections.namedtuple(
     "_Keyed", "name step engine feed_structs feed_sig key t_build")
 
 
-# a decode step the serving loop has dispatched and not read: its
-# outputs (device values), the (slot, sequence, last) it ran for, where
-# ``last`` says the host knew at dispatch that this token ends the
-# sequence, and when its dispatch began
-_Flight = collections.namedtuple("_Flight", "outs rows t0")
+# a decode step (or a round) the serving loop has dispatched and not
+# read: its outputs (device values), the (slot, sequence, last) it ran
+# for, where ``last`` says the host knew at dispatch that this token
+# ends the sequence, when its dispatch began, and of a round the
+# [length, remaining] a slot it was fed, on the device
+# (``_round_chain_fn`` advances the next round's from them)
+_Flight = collections.namedtuple("_Flight", "outs rows t0 state",
+                                 defaults=(None,))
 
 
 class _InFetchOrder:
@@ -2125,8 +2180,13 @@ class DecodeServer:
     at the dispatch of its last step; only an ``eos_id`` hit is learnt
     a step late, and that slot's one extra step is delivered to nobody
     (slots are independent rows, and an admission overwrites what it
-    scatters). Speculative rounds keep the host in the loop: their
-    accept counts decide the next feeds.
+    scatters). A server over a model with a prediction layer keeps one
+    ROUND in flight the same way: how far a round takes a slot is data,
+    so lengths, tokens and budgets are advanced on the device
+    (``_round_chain_fn``) and the host reads a round's ids one round
+    late; a slot that ended there runs as a free slot meanwhile. The
+    OPT self-draft's rounds (``spec_k`` draft dispatches, then a verify
+    call) keep the host in the loop.
 
     Requests ride the same zero-copy channel frames as PredictorServer
     (slot 0: int prompt ids; optional slot 1: [max_new_tokens] or
@@ -2220,9 +2280,10 @@ class DecodeServer:
             self._prefix = None
         # a model that publishes a prediction layer drafts for itself:
         # its server runs ROUNDS of two positions a slot (the current
-        # token and the draft), ONE dispatch and ONE fetch each, without
-        # being asked. The round is the model's one step program
-        # (the OPT self-draft and an int8 slab were refused above)
+        # token and the draft), ONE dispatch and ONE fetch each, one in
+        # flight behind another, without being asked. The round is the
+        # model's one step program (the OPT self-draft and an int8 slab
+        # were refused above)
         self.rounds = bool(cfg.n_predict_layers)
         if self.rounds and self.strategy != "greedy":
             raise ValueError(
@@ -2300,9 +2361,6 @@ class DecodeServer:
         # verify window are lax paths of their own; so is a slab of
         # fewer heads than the query that the kernel has no view of)
         self._stream_rows = None
-        # tokens the last round committed (a round's dispatch carries
-        # it: what THIS one commits is known when its ids land)
-        self._round_committed = 0
         if self.kv_dtype == "float32" and not self.speculative:
             from ..models import jamba as _J
 
@@ -2423,7 +2481,10 @@ class DecodeServer:
                 self.predictor.acquire("decode", self.slots, self.seq,
                                        self.strategy,
                                        kv_dtype=self.kv_dtype)
-            if not (self.speculative or self.rounds):
+            if self.rounds:
+                _round_chain_fn(self.slots, self.seq, self.eos_id,
+                                self.predictor._device)
+            elif not self.speculative:
                 _chain_fn(self.slots, self.predictor._device)
             sp = min(16, self.seq)
             self.predictor.acquire("prefill", 1, sp)
@@ -3294,54 +3355,11 @@ class DecodeServer:
             return self._spec_commit(list(vouts[3:]), next_ids, accept,
                                      lens, active, self.spec_k)
 
-    def _mtp_round(self, rexe, caches, lens, active, n_active):
-        """One ROUND of a model with a prediction layer, every live slot
-        at once: the model on a slot's current token and its draft (two
-        positions, ``lens`` and ``lens + 1``), the accept, and the
-        prediction layer behind them, in ONE executable: one dispatch,
-        one fetch (the ids, the accept counts and the next drafts are
-        one array), where the OPT self-draft makes ``spec_k`` of each
-        before its verify call. The host stays in the loop: the accept
-        counts decide the next lengths. Greedy-lossless: what a slot
-        commits are the model's own argmaxes."""
-        with _tracing.phase("decode.loop.feeds"):
-            tokens = np.zeros((self.slots, 2), np.int64)
-            for i, st in enumerate(active):
-                if st is not None:
-                    tokens[i] = st["cur"], st["draft"]
-            feeds = {"tokens": tokens, "lengths": lens.copy()}
-            feeds.update(zip(self._cache_feed_names, caches))
-        try:
-            with _tracing.phase("decode.loop.dispatch",
-                                round_positions=2 * n_active,
-                                round_committed=self._round_committed,
-                                **self._step_counts(lens, n_active)) as ph:
-                t0 = ph.t0 or time.perf_counter()
-                outs = rexe(feeds, self.predictor._state)
-                outs[0].copy_to_host_async()
-                if self._moe_layers:
-                    outs[-1].copy_to_host_async()
-            obs.DECODE_STEPS.inc(in_flight="0")
-            with _tracing.phase("decode.loop.fetch") as ph:
-                ids = np.asarray(outs[0]).astype(np.int64)
-                if self._moe_layers:
-                    self._note_load(outs[-1])
-            t1 = ph.t1 or time.perf_counter()
-        except Exception as e:
-            return self._fail_all_active(active, lens, e)
-        obs.DECODE_STEP_MS.observe((t1 - t0) * 1e3, stage="round")
-        with _tracing.phase("decode.loop.retire"):
-            return self._spec_commit(
-                list(outs[3:3 + len(self._spec)]), ids[:, :2], ids[:, 2],
-                lens, active, 1, drafts=ids[:, 3])
-
     def _spec_commit(self, caches, next_ids, accept, lens, active,
-                     proposed, drafts=None):
-        """The bookkeeping half of a speculative round, the OPT
-        self-draft's and a prediction layer's alike: each slot takes
-        its accepted tokens (``next_ids[i, :accept[i] + 1]``, of
-        ``proposed`` drafted ones), finished ones retire; ``drafts``
-        (a prediction layer's): each slot's draft for the next round.
+                     proposed):
+        """The bookkeeping half of the OPT self-draft's round: each
+        slot takes its accepted tokens (``next_ids[i, :accept[i] + 1]``,
+        of ``proposed`` drafted ones), finished ones retire.
         ``step_active_counts`` takes the tokens the round delivered, as
         ``_deliver`` gives it a step's. Returns ``caches``."""
         n_active = sum(1 for a in active if a is not None)
@@ -3351,35 +3369,38 @@ class DecodeServer:
         for i, st in enumerate(active):
             if st is None:
                 continue
-            a = int(accept[i])
-            if traced:
-                _tracing.rid_span(st["rid"], "decode.spec_round",
-                                  accepted=a, proposed=proposed)
-            # cap by budget and slab room: window position j needs rows
-            # lens..lens+j resident, so at most seq - lens tokens
-            toks, stopped = _accepted_tokens(
-                next_ids[i], a, min(st["max_new"] - st["count"],
-                                    self.seq - int(lens[i])), self.eos_id)
-            st["generated"].extend(toks)
-            st["count"] += len(toks)
-            if toks:
-                st["cur"] = toks[-1]
-            emitted += len(toks)
-            lens[i] += len(toks)
-            if drafts is not None:
-                # read at the last position the model accepted: a slot
-                # that took fewer (its budget, the slab's end, eos)
-                # retires below and never feeds it
-                st["draft"] = int(drafts[i])
+            n, stopped = self._take_accepted(
+                st, next_ids[i], int(accept[i]), proposed,
+                self.seq - int(lens[i]), traced)
+            emitted += n
+            lens[i] += n
             if stopped or st["count"] >= st["max_new"] \
                     or lens[i] + 1 >= self.seq:
                 self._retire(st)
                 active[i] = None
                 lens[i] = 0
         self.step_active_counts.append(emitted)
-        self._round_committed = emitted
         obs.DECODE_TOKENS.inc(emitted, kind="decode")
         return caches
+
+    def _take_accepted(self, st, next_row, accept, proposed, room, traced):
+        """One sequence's share of a speculative round, the OPT
+        self-draft's and a prediction layer's alike: its accepted tokens
+        (``_accepted_tokens``: capped by its budget and by ``room``, the
+        slab's: window position j needs rows length..length + j
+        resident, so at most ``seq - length`` tokens) join what it has
+        generated. Returns (how many, stopped at eos)."""
+        if traced:
+            _tracing.rid_span(st["rid"], "decode.spec_round",
+                              accepted=accept, proposed=proposed)
+        toks, stopped = _accepted_tokens(
+            next_row, accept, min(st["max_new"] - st["count"], room),
+            self.eos_id)
+        st["generated"].extend(toks)
+        st["count"] += len(toks)
+        if toks:
+            st["cur"] = toks[-1]
+        return len(toks), stopped
 
     def _dispatch(self, dexe, chain, caches, lens, active, n_active,
                   flight) -> _Flight:
@@ -3392,8 +3413,7 @@ class DecodeServer:
         from that step's ids on the device (``_chain_fn``), any other
         the host's (an admission's first token; 0 for a free slot)."""
         with _tracing.phase("decode.loop.feeds"):
-            chained = ({i for i, st, _last in flight.rows
-                        if active[i] is st} if flight is not None else ())
+            chained = self._chained(flight, active)
             first = np.zeros((self.slots,), np.int64)
             for i, st in enumerate(active):
                 if st is not None and i not in chained:
@@ -3411,18 +3431,42 @@ class DecodeServer:
                     self.slots, 1).astype(np.int64)
             self._seed_ctr += 1
             feeds.update(zip(self._cache_feed_names, caches))
+        outs, t0 = self._launch(dexe, feeds, flight,
+                                self._step_counts(lens, n_active))
+        return _Flight(outs, self._flight_rows(lens, active, chained), t0)
+
+    @staticmethod
+    def _chained(flight, active):
+        """The slots that continue from ``flight``, the step or round
+        still unread: live in it, and still the same sequence's."""
+        if flight is None:
+            return ()
+        return {i for i, st, _last in flight.rows if active[i] is st}
+
+    def _launch(self, exe, feeds, flight, counts):
+        """The ``dispatch`` phase of a step or a round: the call, and
+        its ids on their way to the host as soon as it ends, not when
+        the host comes to ask (an iteration later); the experts' loads
+        ride with them. Returns (outputs, when the dispatch began)."""
         in_flight = int(flight is not None)
         with _tracing.phase("decode.loop.dispatch", in_flight=in_flight,
-                            **self._step_counts(lens, n_active)) as ph:
+                            **counts) as ph:
             t0 = ph.t0 or time.perf_counter()
-            outs = dexe(feeds, self.predictor._state)
-            # the ids start for the host as soon as the step ends, not
-            # when the host comes to ask (a step later); the experts'
-            # loads ride with them
+            outs = exe(feeds, self.predictor._state)
             outs[0].copy_to_host_async()
             if self._moe_layers:
                 outs[-1].copy_to_host_async()
         obs.DECODE_STEPS.inc(in_flight=str(in_flight))
+        return outs, t0
+
+    def _flight_rows(self, lens, active, chained):
+        """What the host books of a step or a round at its dispatch,
+        without its ids: every live slot's length advances by one (a
+        round's LEAST advance: what it took beyond that is added when
+        its ids are read), and a sequence that reaches its budget or
+        the slab's end with one more token (beside the one of the
+        unread flight it is ``chained`` to) leaves its slot NOW.
+        Returns the flight's (slot, sequence, last)."""
         rows = []
         for i, st in enumerate(active):
             if st is None:
@@ -3434,7 +3478,57 @@ class DecodeServer:
             if last:
                 active[i] = None
                 lens[i] = 0
-        return _Flight(outs, rows, t0)
+        return rows
+
+    def _dispatch_round(self, rexe, chain, caches, lens, active, n_active,
+                        flight) -> _Flight:
+        """Dispatch one ROUND of a model with a prediction layer, every
+        live slot at once: the model on a slot's current token and its
+        draft (two positions, its length and the next), the accept, and
+        the prediction layer behind them, in ONE executable whose
+        ``ids`` are [a_1, a_2, accept, next draft]. ``flight`` is the
+        round before it, still unread: how far that round took a slot
+        the host does not know yet, so a slot that continues from it is
+        advanced on the device (``_round_chain_fn``: its token, draft,
+        length and budget from that round's ids), any other takes the
+        host's (an admission's first token and draft; zeros for a free
+        slot). The host books the round at its least advance, one token
+        a slot (``_flight_rows``): the counts on ``dispatch`` are at
+        most one row a slot short of an accepted draft, and a slot ends
+        here only where one token more ends it; what a round really
+        took, an ``eos`` and a budget met by an accepted draft are
+        learnt when it is read, a round late (``_deliver_round``), and
+        the device has by then run that slot as a free one.
+        Greedy-lossless: what a slot commits are the model's own
+        argmaxes."""
+        with _tracing.phase("decode.loop.feeds"):
+            chained = self._chained(flight, active)
+            fresh = np.ones((self.slots,), np.bool_)
+            tokens = np.zeros((self.slots, 2), np.int64)
+            remaining = np.zeros((self.slots,), np.int32)
+            for i, st in enumerate(active):
+                if st is None:
+                    continue
+                if i in chained:
+                    fresh[i] = False
+                else:
+                    tokens[i] = st["cur"], st["draft"]
+                    remaining[i] = st["max_new"] - st["count"]
+                    st["len"] = int(lens[i])  # the host's exact one
+            if flight is not None:
+                prev = flight.outs[0], flight.state
+            else:  # every slot is fresh: nothing is advanced from these
+                prev = (np.zeros((self.slots, 4), np.int64),
+                        np.zeros((self.slots, 2), np.int32))
+            tokens, lengths, state = chain(fresh, tokens, lens.copy(),
+                                           remaining, *prev)
+            feeds = {"tokens": tokens, "lengths": lengths}
+            feeds.update(zip(self._cache_feed_names, caches))
+        outs, t0 = self._launch(
+            rexe, feeds, flight, dict(self._step_counts(lens, n_active),
+                                      round_positions=2 * n_active))
+        return _Flight(outs, self._flight_rows(lens, active, chained), t0,
+                       state)
 
     def _fetch(self, flight: _Flight, t_token: float):
         """(ids, now): read a dispatched step's ids; the host waits here
@@ -3451,7 +3545,8 @@ class DecodeServer:
                 self._note_load(flight.outs[-1])
         now = ph.t1 or time.perf_counter()
         obs.DECODE_STEP_MS.observe(
-            (now - max(flight.t0, t_token)) * 1e3, stage="step")
+            (now - max(flight.t0, t_token)) * 1e3,
+            stage="round" if self.rounds else "step")
         return ids, now
 
     def _deliver(self, flight: _Flight, ids, lens, active):
@@ -3483,26 +3578,76 @@ class DecodeServer:
             self._set_slot_gauges(
                 sum(1 for a in active if a is not None))
 
+    def _deliver_round(self, flight: _Flight, ids, lens, active):
+        """Hand a landed ROUND's tokens to their sequences, one round
+        late, and retire the finished: each takes what
+        ``_accepted_tokens`` gives it of [a_1, a_2] (``_round_chain_fn``
+        has advanced the slot by as much on the device), and the host's
+        length gains what the round took beyond the one token its
+        dispatch booked. A sequence that ended in a round read before
+        this one (its budget or the slab's end met by an accepted
+        draft, an eos) ran here as a free slot: nothing is delivered
+        and nothing counted. The round's ``round_committed`` and its
+        ``decode.spec_round`` spans (one a live slot) are booked HERE,
+        once."""
+        with _tracing.phase("decode.loop.retire") as ph:
+            delivered = proposed = 0
+            traced = _tracing.bound()
+            for i, st, last in flight.rows:
+                if st.get("done"):
+                    continue
+                proposed += 1
+                n, stopped = self._take_accepted(
+                    st, ids[i], int(ids[i, 2]), 1, self.seq - st["len"],
+                    traced)
+                st["len"] += n
+                delivered += n
+                held = active[i] is st
+                if held:
+                    lens[i] += n - 1
+                if last or stopped or st["count"] >= st["max_new"] \
+                        or st["len"] + 1 >= self.seq:
+                    st["done"] = True
+                    self._retire(st)
+                    if held:
+                        active[i] = None
+                        lens[i] = 0
+            obs.DECODE_SPEC_PROPOSED.inc(proposed)
+            self.step_active_counts.append(delivered)
+            obs.DECODE_TOKENS.inc(delivered, kind="decode")
+            ph.note(round_committed=delivered)
+            self._set_slot_gauges(
+                sum(1 for a in active if a is not None))
+
     def _loop(self):
         caches = self._fresh_slabs()
         lens = np.zeros((self.slots,), np.int32)
         active: List[Optional[dict]] = [None] * self.slots
         pending: List[tuple] = []
+        # a server over a prediction layer runs its loop on ROUNDS as
+        # any other runs it on steps: a round is a step whose advance is
+        # data. Its entries follow ids and two logits where a step's
+        # follow ids and one
         if self.rounds:
-            rexe, _ = self.predictor.acquire("round", self.slots, self.seq)
+            dexe, _ = self.predictor.acquire("round", self.slots, self.seq)
+            chain = _round_chain_fn(self.slots, self.seq, self.eos_id,
+                                    self.predictor._device)
+            dispatch, deliver, at = (self._dispatch_round,
+                                     self._deliver_round, 3)
         else:
             dexe, _ = self.predictor.acquire("decode", self.slots,
                                              self.seq, self.strategy,
                                              kv_dtype=self.kv_dtype)
+            dispatch, deliver, at = self._dispatch, self._deliver, 2
+            if not self.speculative:
+                chain = _chain_fn(self.slots, self.predictor._device)
         if self.speculative:
             drexe, _ = self.predictor.acquire("draft", self.slots,
                                               self.seq)
             vexe, _ = self.predictor.acquire("verify", self.slots,
                                              self.seq, window=self._win)
-        elif not self.rounds:
-            chain = _chain_fn(self.slots, self.predictor._device)
-        # the plain branch's step in flight (dispatched, its ids not
-        # yet read), and when the last token reached the host
+        # the step (or round) in flight (dispatched, its ids not yet
+        # read), and when the last token reached the host
         flight: Optional[_Flight] = None
         t_token = 0.0
         closed = False
@@ -3572,18 +3717,16 @@ class DecodeServer:
                         caches = self._admit(group, caches, lens, active)
                     n_active = sum(1 for a in active if a is not None)
                 self._set_slot_gauges(n_active)
-                if (self.speculative or self.rounds) and n_active:
-                    caches = (
-                        self._mtp_round(rexe, caches, lens, active, n_active)
-                        if self.rounds else
-                        self._spec_round(drexe, vexe, caches, lens, active,
-                                         n_active))
+                if self.speculative and n_active:
+                    caches = self._spec_round(drexe, vexe, caches, lens,
+                                              active, n_active)
                     self._set_slot_gauges(
                         sum(1 for a in active if a is not None))
                     continue
-                # one token across every active slot, dispatched BEFORE
-                # the host reads the step in flight: all it needs of
-                # that step (the ids, the cache entries) is on the
+                # one token (one round) across every active slot,
+                # dispatched BEFORE the host reads the step in flight:
+                # all it needs of that step (the ids, the cache entries;
+                # of a round the lengths and budgets too) is on the
                 # device, and the host's part of an iteration runs
                 # beside the device's. With nothing live there is no
                 # step to put behind the one in flight, and it is read
@@ -3591,9 +3734,9 @@ class DecodeServer:
                 step = ids = None
                 try:
                     if n_active:
-                        step = self._dispatch(dexe, chain, caches, lens,
-                                              active, n_active, flight)
-                        caches = list(step.outs[2:2 + len(self._spec)])
+                        step = dispatch(dexe, chain, caches, lens, active,
+                                        n_active, flight)
+                        caches = list(step.outs[at:at + len(self._spec)])
                     if flight is not None:
                         ids, t_token = self._fetch(flight, t_token)
                 except Exception as e:
@@ -3611,7 +3754,7 @@ class DecodeServer:
                     flight = None
                     continue
                 if flight is not None:
-                    self._deliver(flight, ids, lens, active)
+                    deliver(flight, ids, lens, active)
                 flight = step
                 if n_active == 0 and closed and not pending:
                     return

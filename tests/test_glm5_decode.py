@@ -30,6 +30,7 @@ sys.path.insert(0, _ROOT)
 import paddle_tpu as fluid  # noqa: E402
 from paddle_tpu import observability as obs  # noqa: E402
 from paddle_tpu.ops import dsa, mla, speculative  # noqa: E402
+from paddle_tpu.serving import decode  # noqa: E402
 from paddle_tpu.serving.decode import (  # noqa: E402
     DecodeConfig, DecodePredictor, DecodeServer, cache_spec,
     save_decode_model)
@@ -453,25 +454,68 @@ def test_the_shares_of_a_layer_add_up(seeded):
 
 # -- the server ---------------------------------------------------------------
 
-def _serve(pred, prompts, max_new, made=None):
-    """The server's answers; `made(st, greedy)` sets a slot's draft
-    before each round where given."""
-    srv = DecodeServer(pred, slots=SLOTS, max_seq=SEQ, max_new_tokens=max_new,
-                       strategy="greedy")
-    if made is not None:
-        inner = srv._mtp_round
+class _ChainSpy:
+    """Stands where the server's compiled select stands
+    (`decode._round_chain_fn`): keeps every round's feeds as the select
+    built them (`fresh`, `tokens`, `lengths`, `remaining`), and where
+    `made(first, n)` is given writes its answer over each live slot's
+    draft, chained slots too: `first` is the sequence's first token,
+    `n` how many tokens it has once the round before this one is
+    counted, so the model's choice at the round's position 0 is the
+    sequence's token `n`."""
 
-        def patched(rexe, caches, lens, active, n_active):
-            for st in active:
-                if st is not None:
-                    made(st)
-            return inner(rexe, caches, lens, active, n_active)
+    def __init__(self, made=None):
+        self.made, self.feeds, self.who = made, [], {}
+        self._real = decode._round_chain_fn
+        self._fed = ()
 
-        srv._mtp_round = patched
-    srv.start()
-    futs = [srv.submit((p, np.array([max_new], np.int64))) for p in prompts]
-    outs = [np.asarray(f.result(timeout=600)[0]).reshape(-1) for f in futs]
-    srv.stop()
+    def __call__(self, slots, seq, eos, device):
+        inner = self._real(slots, seq, eos, device)
+
+        def chain(fresh, tokens, lengths, remaining, *prev):
+            # on the chip the round DONATES its feeds: what the select
+            # built a round ago is gone by now
+            for fed in self._fed:
+                fed.delete()
+            out, lens, state = inner(fresh, tokens, lengths, remaining,
+                                     *prev)
+            toks, at = np.array(out), np.asarray(lens)
+            for i in range(slots):
+                if fresh[i] and lengths[i] > 0:  # admitted since
+                    self.who[i] = int(tokens[i, 0]), int(lengths[i])
+                if at[i] > 0 and self.made is not None:
+                    first, prompt = self.who[i]
+                    toks[i, 1] = self.made(first, int(at[i]) - prompt + 1)
+            self.feeds.append({"fresh": np.array(fresh), "tokens": toks,
+                               "lengths": at.copy(),
+                               "remaining": np.array(state)[:, 1]})
+            self._fed = jnp.asarray(toks, out.dtype), lens
+            return self._fed + (state,)
+
+        return chain
+
+
+def _serve(pred, prompts, max_new, made=None, spy=None):
+    """The server's answers (`max_new`: one for all, or one a prompt);
+    `made(first, n)` sets every live slot's draft before each round where
+    given (`_ChainSpy`)."""
+    if np.isscalar(max_new):
+        max_new = [max_new] * len(prompts)
+    srv = DecodeServer(pred, slots=SLOTS, max_seq=SEQ,
+                       max_new_tokens=max(max_new), strategy="greedy")
+    spy = spy or _ChainSpy(made)
+    decode._round_chain_fn = spy
+    try:
+        srv.start()
+        futs = [srv.submit((p, np.array([m], np.int64)))
+                for p, m in zip(prompts, max_new)]
+        outs = [np.asarray(f.result(timeout=600)[0]).reshape(-1)
+                for f in futs]
+        srv.stop()
+    finally:
+        decode._round_chain_fn = spy._real
+    # no round reads or writes a row at or past the slab's end
+    assert all((f["lengths"] + 1 < SEQ).all() for f in spy.feeds)
     return srv, outs
 
 
@@ -515,11 +559,10 @@ def test_a_draft_made_to_agree_commits_two_tokens_a_round(pred, greedy,
         by_first.setdefault(int(w[0]), w)
     assert len(by_first) == len(want)
 
-    def made(st):
-        seq = by_first[st["generated"][0]]
-        n = len(st["generated"])
+    def made(first, n):
+        seq = by_first[first]
         nxt = int(seq[n]) if n < len(seq) else 0
-        st["draft"] = nxt if agree else (nxt + 1) % V
+        return nxt if agree else (nxt + 1) % V
 
     acc0 = obs.DECODE_SPEC_ACCEPTED.value()
     pro0 = obs.DECODE_SPEC_PROPOSED.value()
@@ -537,6 +580,220 @@ def test_a_draft_made_to_agree_commits_two_tokens_a_round(pred, greedy,
         assert max(srv.step_active_counts) <= SLOTS
 
 
+# -- a round in flight behind another ----------------------------------------
+
+# the select against the host's bookkeeping: a slot a case, one compile
+_EOS = 7
+_CASES = [(accept, left, room, fresh, eos_at)
+          for accept in (0, 1) for left in (1, 2, 3)
+          for room in (0, 1, 40)       # SEQ - (length + 2)
+          for fresh in (False, True) for eos_at in (None, 0, 1)]
+
+
+def _case_id(case):
+    accept, left, room, fresh, eos_at = case
+    return "accept%d-left%d-room%d-%s-eos%s" % (
+        accept, left, room, "fresh" if fresh else "chained", eos_at)
+
+
+@pytest.fixture(scope="module")
+def chained_cases():
+    """Every case as one slot of ONE call of the select: what the round
+    before was fed and answered, the host's values beside them, and
+    what the select made of them."""
+    n = len(_CASES)
+    ids = np.zeros((n, 4), np.int64)
+    fresh = np.zeros((n,), np.bool_)
+    prev_len, prev_left = np.zeros((n,), np.int32), np.zeros((n,), np.int32)
+    host = {"tokens": np.zeros((n, 2), np.int64),
+            "lengths": np.zeros((n,), np.int32),
+            "remaining": np.zeros((n,), np.int32)}
+    for i, (accept, left, room, is_fresh, eos_at) in enumerate(_CASES):
+        ids[i] = 100 + i, 300 + i, accept, 500 + i
+        if eos_at is not None:
+            ids[i, eos_at] = _EOS
+        fresh[i] = is_fresh
+        prev_len[i], prev_left[i] = SEQ - 2 - room, left
+        host["tokens"][i] = 700 + i, 900 + i
+        host["lengths"][i], host["remaining"][i] = 20 + i % 5, 1 + i % 4
+    chain = decode._round_chain_fn(n, SEQ, _EOS, jax.devices()[0])
+    tokens, lengths, state = chain(
+        fresh, host["tokens"], host["lengths"], host["remaining"], ids,
+        np.stack([prev_len, prev_left], axis=1))
+    assert (np.asarray(state)[:, 0] == np.asarray(lengths)).all()
+    return ids, prev_len, prev_left, host, [
+        np.asarray(tokens), np.asarray(lengths), np.asarray(state)[:, 1]]
+
+
+@pytest.mark.parametrize("case", range(len(_CASES)),
+                         ids=[_case_id(c) for c in _CASES])
+def test_the_select_advances_a_slot_as_the_host_would(chained_cases, case):
+    """`_accepted_tokens` and the round's commit as the oracle: what a
+    chained slot is fed next is its last taken token, the next draft,
+    and its length and budget advanced by the tokens taken; a slot that
+    ended (budget, the slab's end, eos) is fed as a free one; a fresh
+    slot is the host's."""
+    ids, prev_len, prev_left, host, (tokens, lengths, remaining) = \
+        chained_cases
+    accept, left, room, fresh, eos_at = _CASES[case]
+    if fresh:
+        assert tokens[case].tolist() == host["tokens"][case].tolist()
+        assert lengths[case] == host["lengths"][case]
+        assert remaining[case] == host["remaining"][case]
+        return
+    at = int(prev_len[case])
+    taken, stopped = decode._accepted_tokens(
+        ids[case], accept, min(left, SEQ - at), _EOS)
+    assert 1 <= len(taken) <= 2
+    at, left = at + len(taken), left - len(taken)
+    if stopped or left <= 0 or at + 1 >= SEQ:
+        want = [0, 0], 0, 0
+    else:
+        want = [taken[-1], int(ids[case, 3])], at, left
+    assert (tokens[case].tolist(), lengths[case], remaining[case]) == want
+    assert lengths[case] + 1 < SEQ
+
+
+def test_an_ended_slot_stays_a_free_slot(chained_cases):
+    """A slot the select ended is fed on as a free one, whatever the
+    rounds behind it answer, until the host hands it a fresh sequence."""
+    ids, _, _, host, (tokens, lengths, remaining) = chained_cases
+    n = len(_CASES)
+    chain = decode._round_chain_fn(n, SEQ, _EOS, jax.devices()[0])
+    ended = remaining == 0
+    assert ended.any() and not ended.all()
+    again = [np.asarray(g) for g in chain(
+        np.zeros((n,), np.bool_), host["tokens"], host["lengths"],
+        host["remaining"], ids, np.stack([lengths, remaining], axis=1))]
+    assert not again[0][ended].any() and not again[1][ended].any()
+    assert not again[2][ended].any()
+
+
+_MIXED = [  # (prompt's length, max_new): odd and even budgets, one token
+    # after the first, a reply that ends at the slab's last row, and two
+    # requests more than the slots, admitted behind a round in flight
+    (40, 7), (70, 10), (33, 3), (SEQ - 8, 8), (21, 2), (55, 5)]
+
+
+@pytest.fixture(scope="module")
+def mixed(pred):
+    prompts = _prompts([n for n, _ in _MIXED], seed=12)
+    outs = []
+    for at in range(0, len(prompts), SLOTS):
+        group = prompts[at:at + SLOTS]
+        caches, lens, first, _, _ = _admit(pred, group)
+        # seven steps: the one at the slab's end reads none past it
+        _, ids, _, _ = _plain(pred, caches, lens, first, 7)
+        for i in range(len(group)):
+            m = _MIXED[at + i][1]
+            outs.append(np.array([first[i]] + [step[i] for step in ids])[:m])
+    # ten tokens of the second: three plain steps more of it alone
+    caches, lens, first, _, _ = _admit(pred, prompts[1:2])
+    _, ids, _, _ = _plain(pred, caches, lens, first, 9)
+    outs[1] = np.array([first[0]] + [step[0] for step in ids])
+    return prompts, outs
+
+
+@pytest.mark.parametrize("draft", ["its_own", "agrees", "never"])
+def test_rounds_in_flight_give_the_plain_greedy_sequences(pred, mixed,
+                                                          draft):
+    """Budgets odd and even, a reply that ends at the slab's end, more
+    requests than slots: token for token the plain greedy sequences, cut
+    where the plain loop cuts them, whether a round commits one token
+    or two; and every round but a busy period's first went out behind
+    an unread one."""
+    prompts, want = mixed
+    by_first = {int(w[0]): w for w in want}
+    assert len(by_first) == len(want)
+
+    def made(first, n):
+        seq = by_first[first]
+        nxt = int(seq[n]) if n < len(seq) else 0
+        return nxt if draft == "agrees" else (nxt + 1) % V
+
+    spy = _ChainSpy(None if draft == "its_own" else made)
+    steps0 = {k: obs.DECODE_STEPS.value(in_flight=k) for k in "01"}
+    srv, got = _serve(pred, prompts, [m for _, m in _MIXED], spy=spy)
+    assert [g.tolist() for g in got] == [w.tolist() for w in want]
+    assert sum(srv.step_active_counts) == sum(len(w) - 1 for w in want)
+    # a sequence admitted while a round was in flight: fresh beside
+    # slots that are chained to it
+    assert any((f["fresh"] & (f["lengths"] > 0)).any()
+               and not f["fresh"].all() for f in spy.feeds)
+    # what the device was fed never passes what the sequence may take
+    assert all((f["remaining"] >= 0).all() and (
+        f["remaining"][f["lengths"] == 0] == 0).all() for f in spy.feeds)
+    steps = {k: obs.DECODE_STEPS.value(in_flight=k) - steps0[k]
+             for k in "01"}
+    assert steps["0"] + steps["1"] == len(spy.feeds)
+    assert steps["1"] >= len(spy.feeds) - 3, steps
+    if draft == "agrees":
+        assert max(srv.step_active_counts) > SLOTS
+
+
+def test_an_eos_ends_a_sequence_a_round_late(pred, mixed):
+    """The host learns of an `eos_id` when it reads the round: the
+    sequence is cut after it as the plain loop cuts it, and the round
+    behind ran its slot as a free one."""
+    prompts, want = mixed
+    eos = int(want[1][4])
+    cut = [w[:list(w).index(eos) + 1] if eos in w else w for w in want]
+    assert len(cut[1]) < len(want[1])
+    served = DecodePredictor.__new__(DecodePredictor)
+    served.__dict__.update(pred.__dict__)
+    served.eos_id = eos
+    spy = _ChainSpy()
+    srv, got = _serve(served, prompts, [m for _, m in _MIXED], spy=spy)
+    assert [g.tolist() for g in got] == [c.tolist() for c in cut]
+    assert sum(srv.step_active_counts) == sum(len(c) - 1 for c in cut)
+    # the second prompt's lengths are its own (70..79): none was fed
+    # once the round that chose the eos had run
+    fed = [int(n) for f in spy.feeds for n in f["lengths"] if 70 <= n < 80]
+    assert sorted(fed) == list(range(70, 70 + len(cut[1]) - 1))
+
+
+@pytest.mark.parametrize("where", ["dispatch", "fetch"])
+def test_a_round_that_fails_takes_the_round_in_flight_with_it(pred, mixed,
+                                                              where):
+    """A round that raises at its dispatch, and one whose ids raise when
+    they are read, with another round in flight either way: every live
+    sequence and every one that waited for its last round fails with
+    the error, what was queued is served, and the server serves on."""
+    prompts, want = mixed
+    srv = DecodeServer(pred, slots=SLOTS, max_seq=SEQ, max_new_tokens=10,
+                       strategy="greedy")
+    calls = []
+    real = srv._dispatch_round if where == "dispatch" else srv._fetch
+
+    def failing(*args):
+        calls.append(args)
+        if len(calls) == (3 if where == "dispatch" else 2):
+            raise RuntimeError("injected device failure")
+        return real(*args)
+
+    if where == "dispatch":
+        srv._dispatch_round = failing
+    else:
+        srv._fetch = failing
+    futs = [srv.submit((p, np.array([m], np.int64)))
+            for p, (_, m) in zip(prompts, _MIXED)]
+    srv.start()
+    failed = 0
+    for f, w in zip(futs, want):
+        try:
+            got = np.asarray(f.result(timeout=300)[0]).reshape(-1)
+        except RuntimeError as e:
+            assert "injected device failure" in str(e)
+            failed += 1
+        else:
+            assert got.tolist() == w.tolist()
+    assert 3 <= failed < len(futs)
+    late = srv.submit((prompts[1], np.array([10], np.int64)))
+    assert np.asarray(late.result(timeout=300)[0]).reshape(-1).tolist() == \
+        want[1].tolist()
+    srv.stop()
+
+
 def test_rounds_are_greedy_only(pred):
     """ONE step program: a server over a prediction layer runs rounds or
     is refused, and no sampling step is built over such a model."""
@@ -550,25 +807,51 @@ def test_rounds_are_greedy_only(pred):
 
 
 def test_a_rounds_dispatch_carries_its_positions(pred):
+    """A traced run: a round's `dispatch` carries its positions and
+    `in_flight`, 1 on every round dispatched behind an unread one (all
+    but the first after a park); what a round committed and its
+    `decode.spec_round` spans are booked when it is READ, once."""
     from paddle_tpu.observability import tracing
 
     prompts = _prompts([40, 33], seed=9)
+    steps0 = {k: obs.DECODE_STEPS.value(in_flight=k) for k in "01"}
     tracing.set_sample_rate(1.0)
     try:
         tracing.get_recorder().reset()
-        _serve(pred, prompts, 6)
+        spy = _ChainSpy()
+        _, outs = _serve(pred, prompts, 6, spy=spy)
         spans = tracing.get_recorder().spans()
     finally:
         tracing.set_sample_rate(0.0)
     # a phase's counts land on its iteration's record
-    iters = [s for s in spans if s["name"] == "decode.loop.iter"
-             and "round_positions" in s]
+    records = sorted((s for s in spans if s["name"] == "decode.loop.iter"),
+                     key=lambda s: s["ts"])
+    iters = [s for s in records if "round_positions" in s]
     assert iters and all(
         s["round_positions"] == 2 * s["active"] for s in iters)
-    assert any(s["round_committed"] > 0 for s in iters)
     assert all("rows_chosen" in s for s in iters)
+    # behind an unread round: whenever the iteration before dispatched one
+    behind = [int(i > 0 and "round_positions" in records[i - 1])
+              for i, s in enumerate(records) if "round_positions" in s]
+    assert [s["in_flight"] for s in iters] == behind
+    assert behind[0] == 0 and sum(behind) >= len(behind) - 2
+    steps = {k: obs.DECODE_STEPS.value(in_flight=k) - steps0[k]
+             for k in "01"}
+    assert steps == {"0": behind.count(0), "1": behind.count(1)}
+    # every round is read once: by the iteration that dispatched the next
+    # one, or by one that dispatched nothing (the last before a park)
+    delivered = sum(len(o) - 1 for o in outs)
+    assert sum(s.get("round_committed", 0) for s in records) == delivered
+    assert sum("round_committed" in s for s in records) == len(iters)
     rounds = [s for s in spans if s["name"] == "decode.spec_round"]
     assert rounds and all(s["proposed"] == 1 for s in rounds)
+    assert sum(1 + s["accepted"] for s in rounds) == delivered
+    # the counts are the host's lengths with the unread round at one
+    # token: where no draft was accepted, the rows the device was fed
+    if not any(s["accepted"] for s in rounds):
+        assert [s["attended"] for s in iters] == [
+            int(f["lengths"].sum()) + s["active"]
+            for f, s in zip(spy.feeds, iters)]
 
 
 # -- matrices held in bfloat16 -----------------------------------------------

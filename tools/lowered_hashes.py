@@ -38,7 +38,8 @@ import sys
 import types
 
 # (benchmark/models/<model>, benchmark/configs/<config>, programs): each
-# cell's decode step at its own (slots, positions) and one prefill
+# cell's decode step (or round) at its own (slots, positions) and one
+# prefill
 CELLS = [
     ("opt_lm", "opt-6.7b.json", [("decode", 8, 2048), ("prefill", 2, 512)]),
     ("jamba_lm", "jamba2-3b.json",
@@ -59,6 +60,8 @@ CELLS = [
      [("decode", 32, 8192), ("prefill", 4, 1024)]),
     ("mimo_v2_lm", "mimo-v2-flash.json",
      [("decode", 16, 16384), ("prefill", 4, 1024)]),
+    # its one step program is the ROUND of two positions a slot
+    ("glm5_lm", "glm-5.json", [("round", 16, 16384), ("prefill", 2, 4096)]),
 ]
 
 
